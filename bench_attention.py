@@ -4,7 +4,7 @@ flash kernel, bf16, causal, batch 4 x 8 heads x seq 4096 x head_dim 64.
 
 Prints one JSON line per variant: {"variant", "ms", "tflops"}.
 Methodology matches bench.py: dispatch a pipelined loop, force completion with
-one scalar fetch (reliable on tunneled transports), report amortized time.
+one scalar fetch, report amortized time.
 """
 import json
 import os

@@ -83,8 +83,8 @@ symbol.Custom = symbol._make_symbol_function("Custom")
 ndarray.Custom = ndarray._make_ndarray_function("Custom")
 
 # persistent cross-process compile cache (docs/compiler.md): wired at import
-# when MXNET_COMPILE_CACHE_DIR is set — jax's persistent-cache config must
-# land before the process's first compile
+# when JAX_COMPILATION_CACHE_DIR or MXNET_COMPILE_CACHE_DIR is set — jax's
+# persistent-cache config must land before the process's first compile
 compile_cache.maybe_enable_from_env()
 
 # server-role processes block here until the cluster shuts down

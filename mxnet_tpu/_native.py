@@ -6,13 +6,16 @@ here the library holds the host-side runtime: the threaded dependency engine
 (src/engine.cc), pooled host allocator (src/allocator.cc), sharded RecordIO
 reader (src/recordio.cc) and the parameter-server transport (src/ps.cc).
 
-Built on demand with `make` (g++) into mxnet_tpu/src/build/libmxtpu.so.
-``get_lib()`` returns None if no toolchain is available — callers fall back
-to pure-python paths so the framework stays importable anywhere.
+Built on demand with `make` (g++) into mxnet_tpu/src/build/libmxtpu.so, which
+git ignores: a fresh checkout builds it on first use. ``get_lib()`` returns
+None if no toolchain is available — callers fall back to pure-python paths so
+the framework stays importable anywhere — and ``status()`` says which of the
+three happened.
 """
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -23,6 +26,15 @@ _LIB_PATH = os.path.join(_SRC_DIR, "build", "libmxtpu.so")
 _lock = threading.Lock()
 _lib = None
 _tried = False
+_status = "untried"
+
+
+def status():
+    """How this process came by the native runtime: ``"loaded"`` (found
+    prebuilt), ``"built"`` (made by :func:`get_lib` in this process),
+    ``"absent"`` (switched off, or the build or load failed) or
+    ``"untried"`` (nothing has asked for it yet)."""
+    return _status
 
 
 def _build():
@@ -32,7 +44,11 @@ def _build():
             stdout=subprocess.PIPE, stderr=subprocess.PIPE, timeout=300,
         )
         return True
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as e:
+        logging.getLogger(__name__).warning(
+            "native runtime build failed (%s); using the pure-python paths: "
+            "%s", type(e).__name__,
+            (getattr(e, "stderr", None) or b"").decode(errors="replace")[-500:])
         return False
 
 
@@ -155,22 +171,23 @@ def _declare(lib):
 
 def get_lib():
     """Return the loaded native library, building it if needed, or None."""
-    global _lib, _tried
+    global _lib, _tried, _status
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_LIB_PATH):
+        _status = "absent"
+        built = not os.path.exists(_LIB_PATH)
+        if built:
             from .base import env_flag
 
-            if env_flag("MXNET_TPU_NO_NATIVE"):
-                return None
-            if not _build():
+            if env_flag("MXNET_TPU_NO_NATIVE") or not _build():
                 return None
         try:
             _lib = _declare(ctypes.CDLL(_LIB_PATH))
         except OSError:
-            _lib = None
+            return None
+        _status = "built" if built else "loaded"
         return _lib
 
 

@@ -7,7 +7,9 @@ process. This module makes compiled programs durable, keyed by the
 identity compileobs already computes: ``(post-pass graph digest, input
 signature, platform fingerprint)``.
 
-Two layers, both rooted at ``MXNET_COMPILE_CACHE_DIR``:
+Two layers, both rooted at the one directory :func:`resolve_dir` decides
+(``JAX_COMPILATION_CACHE_DIR``, else ``MXNET_COMPILE_CACHE_DIR``, else —
+for the entry points only — ``.compile_cache`` inside the checkout):
 
 * **AOT artifacts** (``<dir>/aot/<key>``): serialized XLA executables via
   ``jax.experimental.serialize_executable`` — loaded by single-signature
@@ -16,8 +18,10 @@ Two layers, both rooted at ``MXNET_COMPILE_CACHE_DIR``:
   Where jax doesn't expose executable serialization the layer degrades to
   the transparent one below (``compile.cache_errors`` counts the refusal,
   dispatch is untouched).
-* **jax's own persistent compilation cache**, wired underneath everything
-  else (``jax_compilation_cache_dir``): multi-signature and imperative-op
+* **jax's own persistent compilation cache**, underneath everything else
+  (``<dir>/jax``; where ``JAX_COMPILATION_CACHE_DIR`` names the directory
+  jax reads it itself and this module sets none): multi-signature and
+  imperative-op
   programs re-trace on a warm start but the XLA compile — the dominant
   cost — is a disk hit. The marker index (``<dir>/meta/<key>``) is how
   compileobs tells a warm disk hit from a cold compile:
@@ -42,6 +46,7 @@ import os
 import pickle
 import threading
 import time
+import zlib
 
 from . import telemetry
 from .base import env_bool as _env_bool
@@ -50,15 +55,21 @@ from .base import env_str as _env_str
 
 __all__ = [
     "enable", "disable", "enabled", "aot_enabled", "cache_dir",
-    "maybe_enable_from_env", "fingerprint", "make_key",
+    "resolve_dir", "maybe_enable_from_env", "fingerprint", "make_key",
     "classify_compile", "save_executable", "load_executable",
-    "prune", "stats", "ENV_DIR",
+    "prune", "stats", "ENV_DIR", "ENV_JAX_DIR", "DEFAULT_DIR",
 ]
 
 _log = logging.getLogger(__name__)
 
 ENV_DIR = "MXNET_COMPILE_CACHE_DIR"
-_CACHE_FORMAT = 1  # bump to invalidate every existing entry
+ENV_JAX_DIR = "JAX_COMPILATION_CACHE_DIR"
+# the entry points' default: a fixed path (the path is part of jax's cache
+# key, so a directory that moves never hits), git-ignored, in the checkout
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".compile_cache")
+_CACHE_FORMAT = 2  # bump to invalidate every existing entry
 
 _lock = threading.Lock()
 # race-ok: writes serialize under _lock; fast-path reads sample single
@@ -67,22 +78,37 @@ _state = {"dir": None, "aot": False, "wired": False}
 _fingerprint_cache = [None]
 
 
+def resolve_dir(explicit=None, entry_point=False):
+    """The one place the cache directory is decided.
+    ``JAX_COMPILATION_CACHE_DIR`` wins over everything: jax keeps its own
+    files there and ``aot/`` and ``meta/`` go beside them. Else an
+    ``explicit`` argument (a ``--cache-dir`` flag), else
+    ``MXNET_COMPILE_CACHE_DIR``, else :data:`DEFAULT_DIR` for an entry
+    point (``chip_smoke.py``, ``bench.py``, ``tools/serve.py``,
+    ``tools/bench_serving.py``) and None — cache off — for a library
+    import."""
+    return (_env_str(ENV_JAX_DIR) or explicit or _env_str(ENV_DIR)
+            or (DEFAULT_DIR if entry_point else None))
+
+
 def maybe_enable_from_env():
-    """Enable the cache when ``MXNET_COMPILE_CACHE_DIR`` is set (called
+    """Enable the cache when the environment names a directory (called
     once at package import, before any jit site exists — jax's
     persistent-cache config must land before the first compile)."""
-    d = _env_str(ENV_DIR)
-    if d:
-        enable(d)
-    return enabled()
+    return enable()
 
 
-def enable(directory, aot=None, max_mb=None, wire_jax=True):
-    """Turn the cache on at ``directory`` (created if absent). ``aot``
-    defaults from ``MXNET_COMPILE_CACHE_AOT`` (on), ``max_mb`` from
+def enable(directory=None, aot=None, max_mb=None, wire_jax=True,
+           entry_point=False):
+    """Turn the cache on at ``resolve_dir(directory, entry_point)`` (created
+    if absent; False when that names none). ``aot`` defaults from
+    ``MXNET_COMPILE_CACHE_AOT`` (on), ``max_mb`` from
     ``MXNET_COMPILE_CACHE_MAX_MB`` (2048). ``wire_jax=False`` skips the
     jax persistent-cache config (unit tests exercising the artifact store
     without touching process-global jax state)."""
+    directory = resolve_dir(directory, entry_point)
+    if not directory:
+        return False
     directory = os.path.abspath(directory)
     if aot is None:
         aot = _env_bool("MXNET_COMPILE_CACHE_AOT", True)
@@ -107,43 +133,32 @@ def enable(directory, aot=None, max_mb=None, wire_jax=True):
 
 
 def _wire_jax_cache(directory):
-    """Point jax's own persistent compilation cache underneath ours, with
+    """Put jax's own persistent compilation cache underneath ours, with
     the thresholds opened up (every program is cacheable — a 50ms
     executor program recompiled by 100 elastic relaunches is the same
-    wall as one big one). Unknown knobs on older jax degrade silently —
-    the AOT layer still works without them."""
+    wall as one big one, and a marker must never call a program jax
+    declined to store a hit). Where ``JAX_COMPILATION_CACHE_DIR`` chose
+    the directory jax has read it already and no directory is set here."""
     import jax
 
-    try:
+    if not _env_str(ENV_JAX_DIR):
         jax.config.update("jax_compilation_cache_dir",
                           os.path.join(directory, "jax"))
         _state["wired"] = True
-    except Exception:
-        telemetry.counter("compile.cache_errors").inc()
-        _log.warning("compile cache: this jax exposes no persistent "
-                     "compilation cache; only AOT artifacts will persist")
-        return
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0),
-                      ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(knob, val)
-        except Exception:  # fwlint: disable=swallowed-exception — optional threshold knob missing on older jax: defaults just cache less aggressively
-            pass
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 
 def disable():
-    """Forget the cache (test isolation). jax's persistent-cache config is
-    reset too when this process wired it."""
+    """Forget the cache (test isolation). jax's persistent-cache directory
+    is reset too when this process set it."""
     with _lock:
         was_wired = _state["wired"]
         _state.update(dir=None, aot=False, wired=False)
     if was_wired:
-        try:
-            import jax
+        import jax
 
-            jax.config.update("jax_compilation_cache_dir", None)
-        except Exception:  # fwlint: disable=swallowed-exception — teardown best-effort: a stale cache dir on a dying process is harmless
-            pass
+        jax.config.update("jax_compilation_cache_dir", None)
 
 
 def enabled():
@@ -293,8 +308,16 @@ def save_executable(key, compiled, program="?"):
         from jax.experimental import serialize_executable as _se
 
         payload, in_tree, out_tree = _se.serialize(compiled)
-        blob = pickle.dumps((payload, in_tree, out_tree),
-                            protocol=pickle.HIGHEST_PROTOCOL)
+        # the devices it runs on ride along: loaded without them it would
+        # span EVERY local device and refuse a one-device program's
+        # arguments on a multi-device host
+        device_ids = [d.id for d in
+                      compiled.runtime_executable().local_devices()]
+        # level 1: a TPU executable of a 12-layer serving bucket is
+        # 23-99 MB raw and a quarter of that deflated, in under a second
+        blob = zlib.compress(
+            pickle.dumps((payload, in_tree, out_tree, device_ids),
+                         protocol=pickle.HIGHEST_PROTOCOL), 1)
         path = _aot_path(key)
         tmp = path + ".tmp.%d" % os.getpid()
         with open(tmp, "wb") as f:
@@ -324,8 +347,14 @@ def load_executable(key, program="?"):
             blob = f.read()
         from jax.experimental import serialize_executable as _se
 
-        payload, in_tree, out_tree = pickle.loads(blob)
-        return _se.deserialize_and_load(payload, in_tree, out_tree)
+        import jax
+
+        payload, in_tree, out_tree, device_ids = pickle.loads(
+            zlib.decompress(blob))
+        by_id = {d.id: d for d in jax.local_devices()}
+        return _se.deserialize_and_load(
+            payload, in_tree, out_tree,
+            execution_devices=[by_id[i] for i in device_ids])
     except Exception:
         telemetry.counter("compile.cache_errors").inc()
         _log.warning("compile cache: corrupt/stale AOT artifact for "

@@ -805,22 +805,14 @@ def _jax_memory_stats():
     runs inside every telemetry read (dump/scrape/stall dump), and a
     host-only process — a PS server with a telemetry sink — must not pay
     backend init (or grab a process-exclusive TPU) for a scrape."""
-    import sys
+    # nothing of ours can be on a device before a program compiled or an
+    # NDArray was made, and both are this package's own records
+    from . import ndarray as nd
 
-    if "jax" not in sys.modules:
+    with _lock:
+        compiled = any(r.compile_count for r in _programs.values())
+    if not compiled and not nd.live_arrays():
         return {}
-    # "jax imported" is NOT the real gate — mxnet_tpu itself imports jax at
-    # package import, so that check alone is vacuous. What must not happen
-    # is backend INIT: jax.local_devices() on a never-initialized process
-    # pays full init and, on a TPU host, grabs the process-exclusive chip.
-    # Peek at jax's backend cache instead; if the private API is gone,
-    # accept the init cost rather than losing memory stats forever.
-    try:
-        from jax._src import xla_bridge
-        if not xla_bridge._backends:
-            return {}
-    except Exception:  # fwlint: disable=swallowed-exception — private-API probe: unknown jax internals degrade to the permissive path
-        pass
     out = {}
     try:
         import jax

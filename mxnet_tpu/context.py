@@ -15,6 +15,8 @@ from __future__ import annotations
 
 import threading
 
+from .base import MXNetError
+
 __all__ = ["Context", "cpu", "gpu", "tpu", "current_context"]
 
 
@@ -75,11 +77,29 @@ class Context:
             devs = [d for d in jax.devices() if d.platform == "cpu"]
             if not devs:
                 devs = jax.devices("cpu")
-        else:  # tpu / gpu alias
-            devs = [d for d in jax.devices() if d.platform != "cpu"]
-            if not devs:  # CPU-only environment: fall back (tests on host)
-                devs = jax.devices()
-        return devs[self.device_id % len(devs)]
+            return devs[self.device_id % len(devs)]
+        # tpu / gpu alias: a chip, or an error. Host devices stand in only
+        # where the caller pinned jax to the CPU platform (the test rig's
+        # JAX_PLATFORMS=cpu with virtual devices) — never because a chip
+        # failed to show up.
+        devs = [d for d in jax.devices() if d.platform != "cpu"]
+        if not devs and _pinned_to_cpu():
+            devs = jax.devices()
+            return devs[self.device_id % len(devs)]
+        if not 0 <= self.device_id < len(devs):
+            raise MXNetError(
+                "%s: jax sees %d accelerator device(s) (%s)" % (
+                    self, len(devs),
+                    ", ".join(str(d) for d in jax.devices())))
+        return devs[self.device_id]
+
+
+def _pinned_to_cpu():
+    """True when the caller restricted jax to the CPU platform
+    (``JAX_PLATFORMS=cpu`` or ``jax.config.update("jax_platforms", "cpu")``)."""
+    import jax
+
+    return (jax.config.jax_platforms or "").strip().lower() == "cpu"
 
 
 def _default_value():
@@ -112,13 +132,12 @@ def current_context():
 
 def num_tpus():
     """Number of attached accelerator chips (0 on CPU-only hosts) — the
-    analog of the reference's mx.context counting via cudaGetDeviceCount."""
-    try:
-        import jax
+    analog of the reference's mx.context counting via cudaGetDeviceCount.
+    A backend that fails to initialise raises: a chip that is expected and
+    absent must not read as "none attached"."""
+    import jax
 
-        return len([d for d in jax.devices() if d.platform != "cpu"])
-    except Exception:  # noqa: BLE001
-        return 0
+    return len([d for d in jax.devices() if d.platform != "cpu"])
 
 
 def auto(device_id=0):

@@ -423,12 +423,10 @@ class Module(BaseModule):
         if len(devtypes) != 1:
             return "mixed device types in context list"
         # contexts must land on DISTINCT jax devices (Context.jax_device wraps
-        # device ids modulo the platform's device count, e.g. cpu(3) on a
-        # 1-CPU process): a mesh with duplicates is not a valid SPMD target
-        try:
-            jax_devs = [c.jax_device for c in self._context]
-        except Exception:
-            return "contexts do not resolve to jax devices"
+        # host device ids modulo the device count, e.g. cpu(3) on a 1-CPU
+        # process): a mesh with duplicates is not a valid SPMD target. A
+        # context that names no device at all raises — no path can run it.
+        jax_devs = [c.jax_device for c in self._context]
         if len(set(jax_devs)) != len(jax_devs):
             return "contexts resolve to duplicate devices (no SPMD mesh)"
         devtype = devtypes.pop()

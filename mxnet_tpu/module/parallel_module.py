@@ -94,8 +94,6 @@ class ParallelLMModule(BaseModule):
         import jax
 
         n = self._num_devices or len(jax.devices())
-        # no explicit device list: build_mesh falls back to the virtual CPU
-        # devices when the default platform is a single chip
         self._mesh = build_mesh({self.mode: n})
         return self._mesh
 

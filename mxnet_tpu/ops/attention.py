@@ -46,18 +46,6 @@ def _scale(sm_scale, d):
     return 1.0 / np.sqrt(d) if sm_scale is None else sm_scale
 
 
-def _tpu_in_process():
-    """Whether a TPU backend exists in this process. Gates the Pallas
-    branch at TRACE time: ``lax.platform_dependent`` still picks the
-    platform at LOWERING time, but on this jax version it lowers every
-    offered branch — offering the Pallas kernel to a CPU-only process
-    fails its lowering outright ("Only interpret mode is supported on CPU
-    backend"), so a process without a TPU must not offer it at all."""
-    import jax
-
-    return jax.default_backend() == "tpu"
-
-
 def attention_reference(q, k, v, causal=False, sm_scale=None):
     """Naive softmax attention — the numeric oracle for tests (O(S^2) memory)."""
     sm_scale = _scale(sm_scale, q.shape[-1])
@@ -450,7 +438,7 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, block_k=256):
 
 def _forward_impl(q, k, v, causal, sm_scale, block_k):
     sm_scale = _scale(sm_scale, q.shape[-1])
-    if _pallas_shapes_ok(q, k) and _tpu_in_process():
+    if _pallas_shapes_ok(q, k):
         # platform selected at LOWERING time, not trace time: the same traced
         # function may compile for the TPU (Pallas kernel) or for CPU (scan) —
         # an array's placement isn't knowable from a tracer
@@ -473,7 +461,7 @@ def _fa_fwd(q, k, v, causal, sm_scale, block_k):
 def _fa_bwd(causal, sm_scale, block_k, res, g):
     q, k, v, out, lse = res
     scale = _scale(sm_scale, q.shape[-1])
-    if _pallas_shapes_ok(q, k) and _tpu_in_process():
+    if _pallas_shapes_ok(q, k):
         return lax.platform_dependent(
             q, k, v, out, lse, g,
             tpu=functools.partial(_pallas_backward, causal=causal, sm_scale=scale),
@@ -783,7 +771,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     Serving-only (no vjp): the decode path never differentiates.
     """
     sm_scale = _scale(sm_scale, q.shape[-1])
-    if _paged_shapes_ok(q, k_pages) and _tpu_in_process():
+    if _paged_shapes_ok(q, k_pages):
         return lax.platform_dependent(
             q, k_pages, v_pages, block_tables, context_lens,
             tpu=functools.partial(_paged_pallas, sm_scale=sm_scale),
@@ -847,7 +835,9 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
     (B, nb) grid and scalar-prefetch-steered K/V DMA, but the online-
     softmax state (m, l, acc) carries a T axis and masking is per lane
     (``context_lens`` is (B, T)). One extra row of VMEM scratch per lane —
-    still O(T·H·D), independent of pool size and sequence length. Blocks
+    still O(T·H·D), independent of pool size and sequence length. The
+    lanes take the decode kernel's step one after another (T is static and
+    small; their context lengths are scalar reads from SMEM). Blocks
     wholly past the LONGEST lane's context skip compute (``pl.when``);
     shorter lanes mask the tail of shared blocks with -1e30 like the
     single-query kernel masks ragged block tails.
@@ -870,35 +860,38 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
             l_ref[:] = jnp.zeros((tq, h), jnp.float32)
             acc_ref[:] = jnp.zeros((tq, h, d), jnp.float32)
 
-        ctx = cl_ref[i]                       # (T,) per-lane context
-        ctx_max = jnp.max(ctx)
+        # SMEM yields scalars only: one read per lane, T is static and small
+        ctx = [cl_ref[i, t] for t in range(tq)]
+        ctx_max = functools.reduce(jnp.maximum, ctx)
 
         @pl.when(j * bs < ctx_max)  # ragged early-out past every lane
         def _step():
-            qv = q_ref[0].astype(jnp.float32)   # (T, H, D)
             kv = k_ref[0].astype(jnp.float32)   # (bs, H, D)
             vv = v_ref[0].astype(jnp.float32)
-            s = jnp.einsum("thd,shd->tsh", qv, kv) * sm_scale  # (T, bs, H)
-            pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (tq, bs, h), 1)
-            s = jnp.where(pos < ctx[:, None, None], s, _NEG_INF)
-            m = m_ref[:]                                       # (T, H)
-            m_new = jnp.maximum(m, jnp.max(s, axis=1))
-            p = jnp.exp(s - m_new[:, None, :])
-            scale = jnp.exp(m - m_new)
-            m_ref[:] = m_new
-            l_ref[:] = l_ref[:] * scale + jnp.sum(p, axis=1)
-            acc_ref[:] = (acc_ref[:] * scale[:, :, None]
-                          + jnp.einsum("tsh,shd->thd", p, vv))
+            pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, h), 0)
+            for t in range(tq):  # the single-query kernel's step, per lane
+                qv = q_ref[0, t].astype(jnp.float32)            # (H, D)
+                s = jnp.sum(qv[None] * kv, axis=-1) * sm_scale  # (bs, H)
+                s = jnp.where(pos < ctx[t], s, _NEG_INF)
+                m = m_ref[t]
+                m_new = jnp.maximum(m, jnp.max(s, axis=0))
+                p = jnp.exp(s - m_new[None, :])
+                scale = jnp.exp(m - m_new)
+                m_ref[t] = m_new
+                l_ref[t] = l_ref[t] * scale + jnp.sum(p, axis=0)
+                acc_ref[t] = (acc_ref[t] * scale[:, None]
+                              + jnp.sum(p[:, :, None] * vv, axis=0))
 
         @pl.when(j == nb - 1)
         def _finish():
-            l = jnp.maximum(l_ref[:], 1e-30)
-            out = acc_ref[:] / l[:, :, None]
-            # a lane that never saw a valid position accumulated
-            # exp(-1e30 - -1e30) = 1 weights over garbage — pin it to the
-            # oracle's empty-lane zero
-            out = jnp.where((ctx > 0)[:, None, None], out, 0.0)
-            o_ref[0] = out.astype(o_ref.dtype)
+            for t in range(tq):
+                l = jnp.maximum(l_ref[t], 1e-30)
+                out = acc_ref[t] / l[:, None]
+                # a lane that never saw a valid position accumulated
+                # exp(-1e30 - -1e30) = 1 weights over garbage — pin it to
+                # the oracle's empty-lane zero
+                out = jnp.where(ctx[t] > 0, out, 0.0)
+                o_ref[0, t] = out.astype(o_ref.dtype)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -936,7 +929,7 @@ def paged_attention_multi(q, k_pages, v_pages, block_tables, context_lens,
     Serving-only (no vjp).
     """
     sm_scale = _scale(sm_scale, q.shape[-1])
-    if _paged_shapes_ok(q, k_pages) and _tpu_in_process():
+    if _paged_shapes_ok(q, k_pages):
         return lax.platform_dependent(
             q, k_pages, v_pages, block_tables, context_lens,
             tpu=functools.partial(_paged_pallas_multi, sm_scale=sm_scale),

@@ -320,7 +320,6 @@ class SPLMTrainer(_LMTrainerBase):
 
     def _build(self):
         import jax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         axis = self.axis
@@ -334,10 +333,10 @@ class SPLMTrainer(_LMTrainerBase):
             return jax.lax.pmean(local, axis)
 
         pspec = {n: P() for n in lm_param_names(**self.cfg)}
-        loss_fn = shard_map(
+        loss_fn = jax.shard_map(
             loss_local, mesh=self.mesh,
             in_specs=(pspec, tok_spec, tok_spec), out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
 
         def step(params, opt_state, tokens, labels, lr, t):
@@ -348,10 +347,10 @@ class SPLMTrainer(_LMTrainerBase):
 
         self._step = _obs_jit(step, "lm.step", "SPLMTrainer",
                               self.cfg, donate_argnums=(0, 1))
-        fwd_local = shard_map(
+        fwd_local = jax.shard_map(
             lambda p, tok: self._local_forward(p, tok),
             mesh=self.mesh, in_specs=(pspec, tok_spec),
-            out_specs=P(None, axis, None), check_rep=False,
+            out_specs=P(None, axis, None), check_vma=False,
         )
         self._fwd = _obs_jit(fwd_local, "lm.fwd", "SPLMTrainer", self.cfg)
 
@@ -537,7 +536,6 @@ class MoELMTrainer(_LMTrainerBase):
 
     def _build(self):
         import jax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         axis = self.axis
@@ -551,10 +549,10 @@ class MoELMTrainer(_LMTrainerBase):
             logits = self._local_forward(p, tok_local)
             return jax.lax.pmean(_xent(logits, lab_local), axis)
 
-        loss_fn = shard_map(
+        loss_fn = jax.shard_map(
             loss_local, mesh=self.mesh,
             in_specs=(pspec, tok_spec, tok_spec), out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
 
         def step(params, opt_state, tokens, labels, lr, t):
@@ -565,10 +563,10 @@ class MoELMTrainer(_LMTrainerBase):
 
         self._step = _obs_jit(step, "lm.step", "MoELMTrainer",
                               self.cfg, donate_argnums=(0, 1))
-        self._fwd = _obs_jit(shard_map(
+        self._fwd = _obs_jit(jax.shard_map(
             lambda p, tok: self._local_forward(p, tok),
             mesh=self.mesh, in_specs=(pspec, tok_spec),
-            out_specs=P(axis, None, None), check_rep=False,
+            out_specs=P(axis, None, None), check_vma=False,
         ), "lm.fwd", "MoELMTrainer", self.cfg)
 
     def step(self, params, opt_state, tokens, labels):

@@ -4,9 +4,11 @@ The reference's device set is an explicit list of Contexts handed to Module
 (python/mxnet/module/module.py ctx list); collective layout is implicit in
 KVStore type. On TPU the device set is a ``jax.sharding.Mesh`` with named axes,
 and every collective is an XLA op over an axis. These helpers build the standard
-meshes (data/tensor/pipeline/sequence) from either real chips or a virtual CPU
-mesh for tests (the analog of the reference's CPU-fake-device trick,
-tests/python/unittest/test_multi_device_exec.py:20-33).
+meshes (data/tensor/pipeline/sequence) over ``jax.devices()`` — real chips, or
+the virtual CPU devices of a process pinned to the CPU platform (the analog of
+the reference's CPU-fake-device trick,
+tests/python/unittest/test_multi_device_exec.py:20-33). A mesh that does not
+fit the default platform is an error, never a silent move to host devices.
 """
 from __future__ import annotations
 
@@ -23,39 +25,15 @@ def build_mesh(axis_sizes, devices=None):
     import jax
     from jax.sharding import Mesh
 
-    implicit = devices is None
-    if implicit:
+    if devices is None:
         devices = jax.devices()
     names = list(axis_sizes.keys())
 
-    def _resolve(n):
-        """Concrete sizes + device count for an n-device pool; -1 takes the
-        rest. Returns (sizes, total) — total 0 or > n means 'does not fit'."""
-        sizes = list(axis_sizes.values())
-        if -1 in sizes:
-            known = int(np.prod([s for s in sizes if s != -1])) if len(sizes) > 1 else 1
-            sizes[sizes.index(-1)] = n // known
-        return sizes, int(np.prod(sizes))
-
-    sizes, total = _resolve(len(devices))
-    if implicit and (total > len(devices) or total == 0):
-        # single-accelerator host asked for a bigger mesh: fall back to the
-        # virtual CPU devices (xla_force_host_platform_device_count), the
-        # same convention as dryrun_multichip and the example drivers
-        try:
-            cpus = jax.devices("cpu")
-        except RuntimeError:
-            cpus = []
-        c_sizes, c_total = _resolve(len(cpus))
-        if 0 < c_total <= len(cpus):
-            import logging
-
-            logging.info(
-                "build_mesh: %s does not fit the default platform's %d "
-                "device(s); using %d virtual CPU devices instead",
-                axis_sizes, len(devices), len(cpus),
-            )
-            devices, sizes, total = cpus, c_sizes, c_total
+    sizes = list(axis_sizes.values())
+    if -1 in sizes:  # -1 takes the rest
+        known = int(np.prod([s for s in sizes if s != -1])) if len(sizes) > 1 else 1
+        sizes[sizes.index(-1)] = len(devices) // known
+    total = int(np.prod(sizes))
     if total == 0 or total > len(devices):
         raise ValueError(
             "mesh %s needs %s devices, have %d" % (axis_sizes, total or "more",

@@ -101,7 +101,6 @@ def moe_ffn(x, gate_w, w1, w2, mesh, axis="ep", capacity_factor=1.25,
     ``axis``); w1/w2 may then be None.
     """
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = mesh.shape[axis]
@@ -119,11 +118,11 @@ def moe_ffn(x, gate_w, w1, w2, mesh, axis="ep", capacity_factor=1.25,
                                  capacity_factor=capacity_factor,
                                  expert_fn=expert_fn, expert_params=epp)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(axis), P(), ep_spec),
             out_specs=P(axis),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(x, gate_w, expert_params)
 
@@ -131,10 +130,10 @@ def moe_ffn(x, gate_w, w1, w2, mesh, axis="ep", capacity_factor=1.25,
         return moe_ffn_local(xl, gw, w1l, w2l, axis, n,
                              capacity_factor=capacity_factor)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P(), P(axis), P(axis)),
         out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, gate_w, w1, w2)

@@ -31,10 +31,10 @@ __all__ = ["pipeline_apply"]
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    from jax.experimental.shard_map import shard_map
+    import jax
 
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def pipeline_apply(stage_fn, stage_params, xs, mesh, axis="pp",

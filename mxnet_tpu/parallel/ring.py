@@ -37,13 +37,8 @@ __all__ = ["ring_attention", "ulysses_attention", "ring_attention_local", "ulyss
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is not None:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
-    # older jax: experimental module, and the kwarg is check_rep
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ------------------------------------------------------------------- ring core

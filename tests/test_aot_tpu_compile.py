@@ -1,0 +1,268 @@
+"""What the chip needs, checked without the chip.
+
+* The Pallas kernels of the main path compile for a DESCRIBED TPU v5e (the
+  TPU compiler is installed; no device is attached) at the serving smoke's
+  widths and at head_dim 128 — interpret mode cannot see what Mosaic
+  refuses. Also through the public entry points: a TPU lowering made from
+  this CPU-default process must hold the kernel (``tpu_custom_call``), not
+  the reference lowering.
+* ``chip_smoke.py`` fails fast and says so when jax has no TPU.
+* The compile-cache directory is decided by the environment, then by one
+  fixed path — never by the process.
+* ``mx.tpu(i)`` names a chip or raises; host devices stand in only under the
+  test rig's CPU pin.
+
+The file name sorts first on purpose: tier-1 runs against a clock.
+"""
+import functools
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu.ops import attention as A
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+# ---------------------------------------------------------------- kernels
+@pytest.fixture(scope="module")
+def v5e():
+    """One described v5e chip as a sharding. jax's persistent cache stays
+    off meanwhile: it would store these compiles, cannot read them back
+    without a chip, and warns about that on every later run."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here: nothing to compile with
+        pytest.skip("cannot describe a v5e topology: %s" % e)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compiled_text(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _flash_shapes(chip, d):
+    return [jax.ShapeDtypeStruct((2, 12, 1024, d), jnp.bfloat16,
+                                 sharding=chip)] * 3
+
+
+def _paged_shapes(chip, h, d, dtype, lanes=None):
+    """Serving shapes: B=32 streams, block size 16, 64 table slots."""
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+
+    q = (32, h, d) if lanes is None else (32, lanes, h, d)
+    ctx = (32,) if lanes is None else (32, lanes)
+    pages = s((2049, 16, h, d), dtype)
+    return (s(q, dtype), pages, pages, s((32, 64), jnp.int32),
+            s(ctx, jnp.int32))
+
+
+@pytest.mark.parametrize("d", [64, 128])
+def test_flash_forward_compiles_for_v5e(v5e, d):
+    text = _compiled_text(
+        functools.partial(A._pallas_forward, causal=True, sm_scale=0.125),
+        *_flash_shapes(v5e, d))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("d", [64, 128])
+def test_flash_backward_compiles_for_v5e(v5e, d):
+    q, k, v = _flash_shapes(v5e, d)
+    lse = jax.ShapeDtypeStruct(q.shape[:3], jnp.float32, sharding=v5e)
+    text = _compiled_text(
+        functools.partial(A._pallas_backward, causal=True, sm_scale=0.125),
+        q, k, v, q, lse, q)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("lanes", [None, 5], ids=["decode", "verify"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("h,d", [(12, 64), (16, 128)])
+def test_paged_kernels_compile_for_v5e(v5e, h, d, dtype, lanes):
+    kernel = A._paged_pallas if lanes is None else A._paged_pallas_multi
+    text = _compiled_text(functools.partial(kernel, sm_scale=0.125),
+                          *_paged_shapes(v5e, h, d, dtype, lanes))
+    assert "tpu_custom_call" in text
+
+
+def _flash_grad(q, k, v):
+    return jax.grad(lambda *a: A.flash_attention(*a, True)
+                    .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("entry", ["flash_attention", "flash_attention_grad",
+                                   "paged_attention",
+                                   "paged_attention_multi"])
+def test_public_entry_lowers_the_kernel_for_tpu(v5e, entry):
+    """No trace-time backend gate: lowered FOR the TPU from a process whose
+    default backend is the CPU, the public entry points hold the Pallas
+    kernel — and run on the CPU, the same call takes the reference branch."""
+    assert jax.default_backend() == "cpu"
+    pages = jnp.zeros((3, 16, 2, 64))
+    table, ones = jnp.zeros((2, 4), jnp.int32), jnp.ones((2, 5), jnp.int32)
+    fn, shapes, small = {
+        "flash_attention": (
+            functools.partial(A.flash_attention, causal=True),
+            _flash_shapes(v5e, 64), [jnp.zeros((1, 2, 128, 64))] * 3),
+        "flash_attention_grad": (
+            _flash_grad, _flash_shapes(v5e, 64),
+            [jnp.zeros((1, 2, 128, 64))] * 3),
+        "paged_attention": (
+            A.paged_attention, _paged_shapes(v5e, 12, 64, jnp.float32),
+            (jnp.zeros((2, 2, 64)), pages, pages, table, ones[:, 0])),
+        "paged_attention_multi": (
+            A.paged_attention_multi,
+            _paged_shapes(v5e, 12, 64, jnp.float32, lanes=5),
+            (jnp.zeros((2, 5, 2, 64)), pages, pages, table, ones)),
+    }[entry]
+    assert "tpu_custom_call" in _compiled_text(fn, *shapes)
+    jax.block_until_ready(jax.jit(fn)(*small))
+
+
+# ------------------------------------------------------------- chip_smoke
+def _run(code_or_script, env_extra=None, cwd=None, drop=()):
+    env = {k: v for k, v in os.environ.items() if k not in drop}
+    env.update(env_extra or {})
+    argv = ([sys.executable, code_or_script] if code_or_script.endswith(".py")
+            else [sys.executable, "-c", code_or_script])
+    return subprocess.run(argv, env=env, cwd=cwd, capture_output=True,
+                          text=True, timeout=120)
+
+
+def test_chip_smoke_fails_fast_without_a_tpu():
+    r = _run(os.path.join(ROOT, "chip_smoke.py"),
+             env_extra={"JAX_PLATFORMS": "cpu"})
+    assert r.returncode != 0
+    last = json.loads(r.stdout.strip().splitlines()[-1])
+    assert last["ok"] is False
+    assert last["device"]["platform"] == "cpu"
+    assert set(last) == {"ok", "device"}
+
+
+# ---------------------------------------------------------- compile cache
+_CACHE_ENVS = ("JAX_COMPILATION_CACHE_DIR", "MXNET_COMPILE_CACHE_DIR")
+
+_CACHE_PROBE = """
+import json, os, sys
+sys.path.insert(0, %r)
+import jax, numpy as np
+from mxnet_tpu import compile_cache, compileobs
+on_import = compile_cache.cache_dir()
+compile_cache.enable(entry_point=True)
+f = compileobs.jit(lambda x: x * 2 + 1, "probe", graph_key="probe-graph",
+                   aot=True)
+f(np.ones(4, np.float32))
+print(json.dumps({"on_import": on_import, "dir": compile_cache.cache_dir(),
+                  "jax": jax.config.jax_compilation_cache_dir,
+                  "stats": compile_cache.stats()}))
+""" % ROOT
+
+
+def _probe(tmp_path, **env):
+    r = _run(_CACHE_PROBE, env_extra=env, cwd=str(tmp_path), drop=_CACHE_ENVS)
+    assert r.returncode == 0, r.stderr[-2000:]
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+def test_cache_dir_follows_jax_env(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins over MXNET_COMPILE_CACHE_DIR, jax's
+    own setting is left exactly as the caller gave it, and the AOT
+    artifacts and markers land beside jax's files. A second process loads
+    the artifact: one hit, no miss, no error — with the rig's eight
+    devices, where an executable loaded onto every device would refuse a
+    one-device program."""
+    d = str(tmp_path / "given")
+    env = {"JAX_COMPILATION_CACHE_DIR": d,
+           "MXNET_COMPILE_CACHE_DIR": str(tmp_path / "loses")}
+    cold = _probe(tmp_path, **env)
+    assert cold["on_import"] == cold["dir"] == cold["jax"] == d
+    assert len(os.listdir(os.path.join(d, "aot"))) == 1
+    assert len(os.listdir(os.path.join(d, "meta"))) == 1
+    assert not os.path.exists(os.path.join(d, "jax"))
+    assert not os.path.exists(str(tmp_path / "loses"))
+    assert (cold["stats"]["misses"], cold["stats"]["errors"]) == (1, 0)
+    warm = _probe(tmp_path, **env)
+    assert [warm["stats"][k] for k in ("hits", "misses", "errors")] \
+        == [1, 0, 0]
+
+
+def test_cache_dir_default_is_one_fixed_path(tmp_path):
+    """Nothing set: a library import stays inert, and an entry point gets
+    the same path inside the checkout from any process and any cwd."""
+    code = ("import sys; sys.path.insert(0, %r)\n"
+            "from mxnet_tpu import compile_cache as c\n"
+            "print(c.enabled(), c.resolve_dir(), "
+            "c.resolve_dir(entry_point=True))" % ROOT)
+    outs = []
+    for cwd in (str(tmp_path), ROOT):
+        r = _run(code, cwd=cwd, drop=_CACHE_ENVS)
+        assert r.returncode == 0, r.stderr[-2000:]
+        outs.append(r.stdout.strip().splitlines()[-1])
+    assert outs[0] == outs[1] == "False None %s" % os.path.join(
+        ROOT, ".compile_cache")
+
+
+# ---------------------------------------------------------------- context
+class _Dev:
+    def __init__(self, platform):
+        self.platform = platform
+
+    def __repr__(self):
+        return "%s-device" % self.platform
+
+
+@pytest.fixture
+def unpinned():
+    """jax as a TPU host has it: not restricted to the CPU platform."""
+    assert mx.context._pinned_to_cpu()   # what tests/conftest.py set up
+    jax.config.update("jax_platforms", "")
+    yield
+    jax.config.update("jax_platforms", "cpu")
+
+
+def test_tpu_context_is_a_host_device_only_under_the_cpu_pin(monkeypatch):
+    assert mx.tpu(0).jax_device.platform == "cpu"
+    assert mx.gpu(1).jax_device.platform == "cpu"
+
+
+def test_tpu_context_without_accelerator_raises(monkeypatch, unpinned):
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev("cpu")])
+    for ctx in (mx.tpu(0), mx.gpu(0)):
+        with pytest.raises(mx.base.MXNetError, match="0 accelerator"):
+            ctx.jax_device
+
+
+def test_tpu_context_past_the_last_chip_raises(monkeypatch, unpinned):
+    chip = _Dev("tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: [chip])
+    assert mx.tpu(0).jax_device is chip
+    with pytest.raises(mx.base.MXNetError, match="1 accelerator"):
+        mx.tpu(1).jax_device
+
+
+def test_num_tpus_does_not_hide_a_backend_failure(monkeypatch):
+    def boom(*a):
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", boom)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        mx.context.num_tpus()

@@ -417,7 +417,8 @@ def test_elastic_sets_default_compile_cache_dir():
                                 launch_args=("--elastic",))
     assert rc == 0, (rc, out, err)
     line = [l for l in out.splitlines() if l.startswith("CACHE_DIR=")][0]
-    assert "mxnet-compile-cache-" in line, out
+    # one fixed path inside the checkout — never tempfile, a pid or the time
+    assert line == "CACHE_DIR=" + os.path.join(ROOT, ".compile_cache"), out
     # explicit value wins
     rc, out, err = _run_cluster(
         script, n_workers=1, timeout=60,

@@ -77,8 +77,8 @@ def main(argv=None):
                          "(MXNET_SERVING_DRAFT)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--cache-dir", default=None,
-                    help="persistent compile-cache directory (same as "
-                         "MXNET_COMPILE_CACHE_DIR): a second run warms "
+                    help="persistent compile-cache directory (default: "
+                         "compile_cache.resolve_dir): a second run warms "
                          "its bucket compiles from disk and the record's "
                          "warmup_s shows the cold-start win")
     args = ap.parse_args(argv)
@@ -86,8 +86,7 @@ def main(argv=None):
     from mxnet_tpu import compile_cache, compileobs, telemetry
     from mxnet_tpu.serving import ServingConfig, ServingEngine
 
-    if args.cache_dir:
-        compile_cache.enable(args.cache_dir)
+    compile_cache.enable(args.cache_dir, entry_point=True)
 
     cfg = ServingConfig(
         vocab_size=args.vocab, num_layers=args.num_layers,

@@ -52,16 +52,16 @@ def run_local(args):
     # elastic supervision exists to relaunch dead workers INTO a running
     # job — and a relaunch re-pays the full XLA compile wall unless the
     # compile cache persists across the incarnations. Default the cache
-    # dir on (per-user, stable across jobs so a second job also starts
-    # warm); an explicit MXNET_COMPILE_CACHE_DIR wins, and an explicit
-    # empty value ("") opts out.
+    # dir on, at the entry points' fixed path in the checkout
+    # (compile_cache.DEFAULT_DIR, spelled out because the launcher must not
+    # import the framework); a directory the environment names wins, and
+    # an explicit empty MXNET_COMPILE_CACHE_DIR opts out.
     elastic_cache_dir = None
-    if args.elastic and "MXNET_COMPILE_CACHE_DIR" not in os.environ:
-        import tempfile
-
+    if args.elastic and not any(k in os.environ for k in (
+            "JAX_COMPILATION_CACHE_DIR", "MXNET_COMPILE_CACHE_DIR")):
         elastic_cache_dir = os.path.join(
-            tempfile.gettempdir(),
-            "mxnet-compile-cache-%d" % os.getuid())
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".compile_cache")
 
     def spawn(role, idx, recovery=False):
         env = dict(os.environ)

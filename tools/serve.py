@@ -308,7 +308,7 @@ def make_server(engine, host, port, driver=None, drain_cb=None):
     return Server((host, port), Handler)
 
 
-def main(argv=None):
+def parse_args(argv=None):
     from mxnet_tpu.base import env_float, env_int
 
     ap = argparse.ArgumentParser(
@@ -333,13 +333,15 @@ def main(argv=None):
                     help="deterministic init seed when no checkpoint")
     ap.add_argument("--warmup", action="store_true",
                     help="compile the shape buckets before listening "
-                         "(first real requests pay no compile wall; with "
-                         "--cache-dir / MXNET_COMPILE_CACHE_DIR a warm "
-                         "replica LOADS them from disk instead)")
+                         "(first real requests pay no compile wall; a "
+                         "replica that finds them in the compile cache "
+                         "LOADS them from disk instead)")
     ap.add_argument("--cache-dir", default=None,
                     help="persistent compile-cache directory "
-                         "(docs/compiler.md; same as setting "
-                         "MXNET_COMPILE_CACHE_DIR)")
+                         "(docs/compiler.md: JAX_COMPILATION_CACHE_DIR "
+                         "wins over it, it wins over "
+                         "MXNET_COMPILE_CACHE_DIR; default "
+                         ".compile_cache in the checkout)")
     ap.add_argument("--top", action="store_true",
                     help="render live stat columns to stderr")
     ap.add_argument("--max-queue", type=int, default=None,
@@ -358,17 +360,18 @@ def main(argv=None):
                     default=env_float("MXNET_SERVING_DRAIN_S", 30.0),
                     help="seconds SIGTERM//drain waits for inflight work "
                          "before cancelling stragglers and exiting")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
-    if args.cache_dir:
-        from mxnet_tpu import compile_cache
 
-        compile_cache.enable(args.cache_dir)
+def main(argv=None):
+    args = parse_args(argv)
+
+    from mxnet_tpu import compile_cache
+
+    compile_cache.enable(args.cache_dir, entry_point=True)
     t0 = time.time()
     sup = build_supervisor(args)   # factory warms up when --warmup is set
     if args.warmup:
-        from mxnet_tpu import compile_cache
-
         cstats = compile_cache.stats()
         print("warmup: %.1fs (compile cache: %s)"
               % (time.time() - t0,
