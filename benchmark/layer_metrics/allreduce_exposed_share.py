@@ -1,0 +1,10 @@
+"""The part of the collectives' time during which no other op ran on that
+chip, over the traced window, mean over the chips."""
+
+
+def read(obs):
+    tr = obs.get("trace")
+    if not tr or obs["chips"] < 2:
+        return None
+    per = [c["exposed_s"] for c in tr["collectives"].values()]
+    return 100.0 * sum(per) / len(per) / tr["window_s"]
