@@ -8,7 +8,8 @@ whenever B is acquired while A is held:
 * lexically, via nested ``with`` statements;
 * sequentially, via manual ``lock.acquire()`` / ``lock.release()`` pairs
   (the acquire extends the held set for the rest of the enclosing block,
-  including a ``try``'s body when the release sits in its ``finally``)
+  including a ``try``'s body when the release sits in its ``finally``,
+  and past the end of a ``with`` whose body made it)
   and via ``stack.enter_context(lock)`` (ExitStack indirection — held for
   the rest of the block, released by the stack's own exit);
 * transitively, via calls made under a lock: ``self.method()`` resolves
@@ -532,8 +533,11 @@ class LockGraph:
                         scan_calls(item.context_expr, held)
                     if isinstance(item.optional_vars, ast.Name):
                         fn_bound.add(item.optional_vars.id)
-                block_walk(stmt.body, held + got)
-                return held
+                # a manual acquire inside the body (one taken under a span
+                # that times the wait, say) outlives the ``with``; the
+                # ``with``'s own locks do not
+                cur = block_walk(stmt.body, held + got)
+                return [h for h in cur if h in held or h not in got]
             if isinstance(stmt, ast.Assign):
                 lid = resolve_lock(stmt.value) if isinstance(
                     stmt.value, (ast.Name, ast.Attribute)) else None

@@ -471,15 +471,15 @@ class BaseModule:
                         t_step = time.perf_counter() if tel else 0.0
                         if monitor is not None:
                             monitor.tic()
-                        # span, not gated on `tel`: with the profiler running but
-                        # telemetry off, fit.step must still land on the chrome
-                        # trace (span() itself no-ops when BOTH are off)
+                        # span, not gated on `tel`: it is the step's annotation on
+                        # a jax.profiler trace, and with the MXNet profiler running
+                        # but telemetry off it must still land on the chrome trace
                         bad_reason = None
                         bad_applied = False
                         membership_changed = False
                         # epoch/nbatch args let trace_merge match the same
                         # BSP step across worker lanes in the merged trace
-                        with telemetry.span("fit.step", "fit",
+                        with telemetry.span("fit.step", "fit", step=nbatch,
                                             epoch=epoch, nbatch=nbatch):
                             try:
                                 self.forward_backward(data_batch)
@@ -574,7 +574,8 @@ class BaseModule:
                             # batch still advances
                         try:
                             # pre-fetch next batch to overlap host IO with device work
-                            next_data_batch = next(data_iter)
+                            with telemetry.span("fit.data_wait", "fit"):
+                                next_data_batch = next(data_iter)
                             next_state = _state_fn() if track_state else None
                             self.prepare(next_data_batch)
                         except StopIteration:

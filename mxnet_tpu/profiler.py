@@ -12,10 +12,10 @@ this profiler has two tiers:
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import threading
-import time
 
 __all__ = ["profiler_set_config", "profiler_set_state", "dump_profile",
            "emit_span", "is_running"]
@@ -129,45 +129,25 @@ def emit_span(name, category, wall_t0, dur_s, args=None, tid=None):
         _state["events"].append(ev)
 
 
-class _NullSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *a):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
-class _Span:
-    __slots__ = ("name", "category", "t0")
-
-    def __init__(self, name, category):
-        self.name = name
-        self.category = category
-
-    def __enter__(self):
-        self.t0 = time.time()
-        return self
-
-    def __exit__(self, *a):
-        emit_span(self.name, self.category, self.t0, time.time() - self.t0)
-        return False
+# what `record_span` hands out while it records nothing (reusable, shared)
+_OFF = contextlib.nullcontext()
 
 
 def record_span(name, category="operator"):
     """Context manager recording one span while the profiler runs; a shared
     no-op when stopped so the imperative hot path pays ~nothing. Mode
     "symbolic" records only executor spans (the reference's kOnlySymbolic);
-    "all" adds per-op imperative spans (kAllOperator, profiler.h:63-66)."""
+    "all" adds per-op imperative spans (kAllOperator, profiler.h:63-66).
+    These fire per operator, not per step: they feed the chrome trace only
+    and open no `jax.profiler` annotation and no histogram
+    (`telemetry.span` is the per-step kind)."""
     if not _state["running"]:
-        return _NULL_SPAN
+        return _OFF
     if _state["mode"] == "symbolic" and category == "operator":
-        return _NULL_SPAN
-    return _Span(name, category)
+        return _OFF
+    from .telemetry import _Span
+
+    return _Span(name, category, hist=False)
 
 
 def dump_profile():

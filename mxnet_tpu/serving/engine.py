@@ -40,6 +40,7 @@ from .scheduler import (CANCELLED, DECODING, FAILED, FINISHED, TIMED_OUT,
                         WAITING, Request, Scheduler)
 
 _SITE = "serving/engine.py"
+_CAT = "serving"   # the chrome-trace category of the engine's spans
 
 _engine_ids = itertools.count()
 
@@ -503,7 +504,23 @@ class ServingEngine:
         same contract: pending requests fail loudly, waiters wake, later
         submits refuse."""
         try:
-            with self._lock, telemetry.span("serving.step"):
+            # the step span opens BEFORE the lock: a driver queued behind
+            # submit() shows as serving.step.lock on the trace, not as a gap
+            # fwlint: disable=unguarded-shared-write — _steps is read for a label on the trace; only the stepping thread writes it
+            with telemetry.span("serving.step", _CAT, step=self._steps):
+                return self._step()
+        except Exception as exc:
+            self.abort(exc)
+            raise
+
+    def _step(self):
+        """The step's sections, each under its span (docs/observability.md
+        has the table): lock, schedule, prefills, schedule again after a
+        prefill, decode, retire."""
+        with telemetry.span("serving.step.lock", _CAT):
+            self._lock.acquire()
+        try:
+            with self._schedule_span():
                 # chaos: injected per-step latency (trips deadlines/SLOs
                 # without faking clocks) — docs/fault_tolerance.md
                 # fwlint: disable=lock-order — the injected delay models a slow device dispatch, which blocks under the step lock by design
@@ -521,11 +538,13 @@ class ServingEngine:
                 failed = self._drain_failed()
                 if plan.empty():
                     return failed
-                for req in plan.prefills:
-                    # fwlint: disable=lock-order — fault.hit("dispatch_error") in the callee can inject a delay; real dispatch blocks under the step lock identically
-                    self._run_prefill(req)
                 n_preempted = len(plan.preempted)
-                if plan.prefills:
+                decodes = () if plan.prefills else self._decodable()
+            for req in plan.prefills:
+                # fwlint: disable=lock-order — fault.hit("dispatch_error") in the callee can inject a delay; real dispatch blocks under the step lock identically
+                self._run_prefill(req)
+            if plan.prefills:
+                with self._schedule_span():
                     # a prompt that exactly filled its blocks writes its
                     # first decode token at a fresh block boundary — back
                     # that slot with a real block NOW or the write lands in
@@ -535,20 +554,18 @@ class ServingEngine:
                         self.obs.request_preempted(req)
                     n_preempted += len(late)
                     failed += self._drain_failed()
-                decodes = self.scheduler.decodable()
-                if decodes:
-                    # copy-on-write safety net: a write slot backed by a
-                    # SHARED block gets a private bit-exact copy first
-                    # (structurally unreachable — prefix matches cover
-                    # only full replay blocks, writes land past them —
-                    # but the pool invariant must hold unconditionally)
-                    self._cow_guard(decodes)
-                    if self._spec:
-                        # fwlint: disable=lock-order — injected dispatch fault may stall; matches real device-dispatch blocking under the step lock
-                        self._run_spec_decode(decodes)
-                    else:
-                        # fwlint: disable=lock-order — injected dispatch fault may stall; matches real device-dispatch blocking under the step lock
-                        self._run_decode(decodes)
+                    decodes = self._decodable()
+            if decodes and self._spec:
+                # fwlint: disable=lock-order — injected dispatch fault may stall; matches real device-dispatch blocking under the step lock
+                fetched = self._run_spec_decode(decodes)
+            elif decodes:
+                # fwlint: disable=lock-order — injected dispatch fault may stall; matches real device-dispatch blocking under the step lock
+                fetched = self._run_decode(decodes)
+            with telemetry.span("serving.retire", _CAT) as retire:
+                if decodes and self._spec:
+                    self._note_spec_decode(decodes, fetched)
+                elif decodes:
+                    self._note_decode(decodes, fetched)
                 finished = [r for r in list(self.scheduler.running)
                             if r.finished()]
                 for req in finished:
@@ -564,10 +581,28 @@ class ServingEngine:
                     running=len(self.scheduler.running),
                     kv_used=self.pool.used(), kv_free=self.pool.available(),
                     kv_frag_slots=self.scheduler.frag_slots())
+                retire.set(finished=len(finished) + len(failed))
                 return finished + failed
-        except Exception as exc:
-            self.abort(exc)
-            raise
+        finally:
+            self._lock.release()
+
+    def _schedule_span(self):
+        return telemetry.span("serving.schedule", _CAT,
+                              waiting=len(self.scheduler.waiting),
+                              running=len(self.scheduler.running))
+
+    def _decodable(self):
+        """The streams this step decodes, each write slot backed by a
+        private block (part of the schedule section)."""
+        decodes = self.scheduler.decodable()
+        if decodes:
+            # copy-on-write safety net: a write slot backed by a
+            # SHARED block gets a private bit-exact copy first
+            # (structurally unreachable — prefix matches cover
+            # only full replay blocks, writes land past them —
+            # but the pool invariant must hold unconditionally)
+            self._cow_guard(decodes)
+        return decodes
 
     def run_loop(self, stop_event=None, idle_wait_s=0.05):
         """Drive :meth:`step` until ``stop_event`` is set, sleeping on the
@@ -587,7 +622,10 @@ class ServingEngine:
                     # tokens/sec window here or it freezes at its last
                     # loaded value on a quiet server
                     self._refresh_throughput()
-                    self._work.wait(timeout=idle_wait_s)
+                    # an empty queue, by name: device idle under this span
+                    # is idle that no change to the loop can take away
+                    with telemetry.span("serving.loop.idle", _CAT):
+                        self._work.wait(timeout=idle_wait_s)
                     if not self.scheduler.has_work():
                         continue
             self.step()
@@ -796,91 +834,115 @@ class ServingEngine:
         replay = req.replay_tokens()
         L = len(replay)
         S = _bucket_for(L, cfg.prefill_buckets())
-        toks = np.zeros((1, S), np.int32)
-        toks[0, :L] = replay
-        table = self._table_row(req, S // cfg.block_size)
-        # prefix sharing: blocks mapped from the index already hold this
-        # prefix's K/V — route their WRITE entries to the trash block so
-        # the scatter cannot touch a shared block (copy-on-write contract;
-        # the logits are untouched, the table only steers the scatter)
-        write_table = table
-        if req.shared_blocks:
-            write_table = table.copy()
-            write_table[:min(req.shared_blocks, len(write_table))] = 0
-        # compile-tally delta around the dispatch: a bump means THIS call
-        # sat behind a cold prefill bucket — that wall is the request's
-        # compile_stall, not honest prefill time
-        jit = self._prefill_jits[S]
-        c0, s0 = jit.compile_totals()
-        s0 += self._draft_prefill_jits[S].compile_totals()[1] \
-            if self._spec else 0.0
-        # chaos: injected dispatch failure — escapes step(), which aborts
-        # the engine (the supervisor's restart trigger in the chaos e2e)
-        fault.hit("dispatch_error")
+        args = {"request_id": req.request_id, "prompt_len": L, "bucket": S}
+        with telemetry.span("serving.prefill.build", _CAT, **args):
+            toks = np.zeros((1, S), np.int32)
+            toks[0, :L] = replay
+            table = self._table_row(req, S // cfg.block_size)
+            # prefix sharing: blocks mapped from the index already hold
+            # this prefix's K/V — route their WRITE entries to the trash
+            # block so the scatter cannot touch a shared block
+            # (copy-on-write contract; the logits are untouched, the
+            # table only steers the scatter)
+            write_table = table
+            if req.shared_blocks:
+                write_table = table.copy()
+                write_table[:min(req.shared_blocks, len(write_table))] = 0
+            # compile-tally delta around the dispatch: a bump means THIS
+            # call sat behind a cold prefill bucket — that wall is the
+            # request's compile_stall, not honest prefill time
+            jit = self._prefill_jits[S]
+            c0, s0 = jit.compile_totals()
+            s0 += self._draft_prefill_jits[S].compile_totals()[1] \
+                if self._spec else 0.0
+            # chaos: injected dispatch failure — escapes step(), which
+            # aborts the engine (the supervisor's restart trigger in the
+            # chaos e2e)
+            fault.hit("dispatch_error")
         t0 = time.time()
-        tok, _logits, kp, vp = self._prefill_fn(
-            self.params, toks, np.int32(L), write_table,
-            self.pool.k_pages, self.pool.v_pages)
-        self.pool.k_pages, self.pool.v_pages = kp, vp
-        if self._spec:
-            # the draft caches the same replay through the same write
-            # table into its OWN pages (its K/V never mixes with the
-            # target's); shared blocks were draft-cached by the prefix's
-            # original prefill, same as the target pages
-            _dt, _dl, dkp, dvp = self._draft_prefill_fn(
-                self._draft_params, toks, np.int32(L), write_table,
-                self._draft_kp, self._draft_vp)
-            self._draft_kp, self._draft_vp = dkp, dvp
-        # the per-step token egress: serving's output IS this transfer
-        tok = int(np.asarray(tok)[0])  # fwlint: disable=device-escape — token egress to the client is the product, one scalar per prefill
+        with telemetry.span("serving.prefill.dispatch", _CAT, **args):
+            tok, _logits, kp, vp = self._prefill_fn(
+                self.params, toks, np.int32(L), write_table,
+                self.pool.k_pages, self.pool.v_pages)
+            self.pool.k_pages, self.pool.v_pages = kp, vp
+            if self._spec:
+                # the draft caches the same replay through the same write
+                # table into its OWN pages (its K/V never mixes with the
+                # target's); shared blocks were draft-cached by the
+                # prefix's original prefill, same as the target pages
+                _dt, _dl, dkp, dvp = self._draft_prefill_fn(
+                    self._draft_params, toks, np.int32(L), write_table,
+                    self._draft_kp, self._draft_vp)
+                self._draft_kp, self._draft_vp = dkp, dvp
+        with telemetry.span("serving.prefill.fetch", _CAT, **args):
+            # the per-step token egress: serving's output IS this transfer
+            tok = int(np.asarray(tok)[0])  # fwlint: disable=device-escape — token egress to the client is the product, one scalar per prefill
         wall = time.time() - t0
-        c1, s1 = jit.compile_totals()
-        s1 += self._draft_prefill_jits[S].compile_totals()[1] \
-            if self._spec else 0.0
-        stall = min(s1 - s0, wall) if c1 > c0 or s1 > s0 else 0.0
-        telemetry.histogram("serving.prefill_seconds").observe(wall)
-        telemetry.counter("serving.prefill_tokens").inc(L)
-        # register this prefix's full blocks for later admissions (first
-        # writer wins; the blocks it itself mapped shared are already in)
-        self.pool.prefix_insert(replay, req.blocks)
-        was_replay = req.pending_token is not None
-        req.context_len = L
-        req.state = DECODING
-        if not was_replay:
-            # fresh prompt: the prefill's greedy token is the first output
-            self._note_token(req, tok)
-        # else: preemption replay — the pending token was already produced
-        # (greedy replay recomputes the same cache; tok == pending_token)
-        self.obs.prefill_done(req, stall, was_replay)
+        with telemetry.span("serving.retire", _CAT,
+                            request_id=req.request_id):
+            c1, s1 = jit.compile_totals()
+            s1 += self._draft_prefill_jits[S].compile_totals()[1] \
+                if self._spec else 0.0
+            stall = min(s1 - s0, wall) if c1 > c0 or s1 > s0 else 0.0
+            telemetry.histogram("serving.prefill_seconds").observe(wall)
+            telemetry.counter("serving.prefill_tokens").inc(L)
+            # register this prefix's full blocks for later admissions
+            # (first writer wins; the blocks it itself mapped shared are
+            # already in)
+            self.pool.prefix_insert(replay, req.blocks)
+            was_replay = req.pending_token is not None
+            req.context_len = L
+            req.state = DECODING
+            if not was_replay:
+                # fresh prompt: the prefill's greedy token is the first
+                # output
+                self._note_token(req, tok)
+            # else: preemption replay — the pending token was already
+            # produced (greedy replay recomputes the same cache;
+            # tok == pending_token)
+            self.obs.prefill_done(req, stall, was_replay)
 
     def _run_decode(self, reqs):
+        """Build, dispatch and fetch one fused decode step; returns the
+        fetched next-token vector for :meth:`_note_decode`."""
         cfg = self.config
         B = _bucket_for(len(reqs), cfg.decode_buckets())
-        toks = np.zeros(B, np.int32)
-        poss = np.zeros(B, np.int32)
-        tables = np.zeros((B, self._nb_max), np.int32)
         ctx = np.ones(B, np.int32)
-        for i, req in enumerate(reqs):
-            toks[i] = req.pending_token
-            poss[i] = req.context_len
-            tables[i] = self._table_row(req, self._nb_max)
-            ctx[i] = req.context_len + 1
-        # compile-tally delta: a cold decode batch bucket stalls EVERY
-        # stream in the batch for the compile wall (serving/obs.py)
-        jit = self._decode_jits[B]
-        c0, s0 = jit.compile_totals()
-        fault.hit("dispatch_error")
+        ctx[:len(reqs)] = [req.context_len + 1 for req in reqs]
+        # each decode call's context length on the trace: what a roofline
+        # share of the paged kernel is computed from (PERF.md section 7)
+        args = {"batch": len(reqs), "bucket": B,
+                "ctx_tokens": int(ctx.sum()), "ctx_max": int(ctx.max())}
+        with telemetry.span("serving.decode.build", _CAT, **args):
+            toks = np.zeros(B, np.int32)
+            poss = np.zeros(B, np.int32)
+            tables = np.zeros((B, self._nb_max), np.int32)
+            for i, req in enumerate(reqs):
+                toks[i] = req.pending_token
+                poss[i] = req.context_len
+                tables[i] = self._table_row(req, self._nb_max)
+            # compile-tally delta: a cold decode batch bucket stalls EVERY
+            # stream in the batch for the compile wall (serving/obs.py)
+            jit = self._decode_jits[B]
+            c0, s0 = jit.compile_totals()
+            fault.hit("dispatch_error")
         t0 = time.time()
-        nxt, _logits, kp, vp = self._decode_fn(
-            self.params, toks, poss, tables, ctx,
-            self.pool.k_pages, self.pool.v_pages)
-        self.pool.k_pages, self.pool.v_pages = kp, vp
-        # the fused step's single device->host sync: the next-token vector
-        nxt = np.asarray(nxt)  # fwlint: disable=device-escape — token egress to clients is the product, B int32s per step
+        with telemetry.span("serving.decode.dispatch", _CAT, **args):
+            nxt, _logits, kp, vp = self._decode_fn(
+                self.params, toks, poss, tables, ctx,
+                self.pool.k_pages, self.pool.v_pages)
+            self.pool.k_pages, self.pool.v_pages = kp, vp
+        with telemetry.span("serving.decode.fetch", _CAT, **args):
+            # the fused step's single device->host sync: the next-token
+            # vector
+            nxt = np.asarray(nxt)  # fwlint: disable=device-escape — token egress to clients is the product, B int32s per step
         wall = time.time() - t0
         c1, s1 = jit.compile_totals()
         if c1 > c0:
             self.obs.decode_stall(reqs, min(s1 - s0, wall))
+        return nxt
+
+    def _note_decode(self, reqs, nxt):
         telemetry.histogram("serving.decode_batch").observe(len(reqs))
         for i, req in enumerate(reqs):
             req.context_len += 1
@@ -932,35 +994,45 @@ class ServingEngine:
         n = len(reqs)
         nb = self._nb_max
         base_ctx = [r.context_len for r in reqs]
-        tables = np.zeros((B, nb), np.int32)
-        for i, req in enumerate(reqs):
-            tables[i] = self._table_row(req, nb)
-        proposals = [[] for _ in range(n)]
-        cur = np.zeros(B, np.int32)
-        for i, req in enumerate(reqs):
-            cur[i] = req.pending_token
-        djit = self._draft_decode_jits[B]
-        c0, s0 = djit.compile_totals()
-        fault.hit("dispatch_error")
+        # the decode spans' names and arguments, told apart by spec/phase;
+        # ctx_* are the window's first lane's (a verify pass reads k more)
+        args = {"spec": 1, "batch": n, "bucket": B,
+                "ctx_tokens": sum(base_ctx) + B, "ctx_max": max(base_ctx) + 1}
+        with telemetry.span("serving.decode.build", _CAT, phase="draft",
+                            **args):
+            tables = np.zeros((B, nb), np.int32)
+            for i, req in enumerate(reqs):
+                tables[i] = self._table_row(req, nb)
+            proposals = [[] for _ in range(n)]
+            cur = np.zeros(B, np.int32)
+            for i, req in enumerate(reqs):
+                cur[i] = req.pending_token
+            djit = self._draft_decode_jits[B]
+            c0, s0 = djit.compile_totals()
+            fault.hit("dispatch_error")
         t0 = time.time()
-        for j in range(k + 1):
-            toks = cur.copy()
-            poss = np.zeros(B, np.int32)
-            ctx = np.ones(B, np.int32)
-            for i in range(n):
-                poss[i] = base_ctx[i] + j
-                ctx[i] = base_ctx[i] + j + 1
-            dnxt, _dl, dkp, dvp = self._draft_decode_fn(
-                self._draft_params, toks, poss, tables, ctx,
-                self._draft_kp, self._draft_vp)
-            self._draft_kp, self._draft_vp = dkp, dvp
-            if j < k:
-                # the proposal steers the NEXT inner step's input token —
-                # an unavoidable per-draft-step sync, B int32s
-                dnxt = np.asarray(dnxt)  # fwlint: disable=device-escape — draft proposals feed the next inner draft step, B int32s per step
+        # one span over the k+1 inner steps: each proposal's fetch steers
+        # the next dispatch, so the two cannot be told apart per step
+        with telemetry.span("serving.decode.dispatch", _CAT, phase="draft",
+                            **args):
+            for j in range(k + 1):
+                toks = cur.copy()
+                poss = np.zeros(B, np.int32)
+                ctx = np.ones(B, np.int32)
                 for i in range(n):
-                    proposals[i].append(int(dnxt[i]))
-                    cur[i] = dnxt[i]
+                    poss[i] = base_ctx[i] + j
+                    ctx[i] = base_ctx[i] + j + 1
+                dnxt, _dl, dkp, dvp = self._draft_decode_fn(
+                    self._draft_params, toks, poss, tables, ctx,
+                    self._draft_kp, self._draft_vp)
+                self._draft_kp, self._draft_vp = dkp, dvp
+                if j < k:
+                    # the proposal steers the NEXT inner step's input
+                    # token — an unavoidable per-draft-step sync, B int32s
+                    dnxt = np.asarray(dnxt)  # fwlint: disable=device-escape — draft proposals feed the next inner draft step, B int32s per step
+                    for i in range(n):
+                        proposals[i].append(int(dnxt[i]))
+                        cur[i] = dnxt[i]
         draft_wall = time.time() - t0
         c1, s1 = djit.compile_totals()
         draft_stall = min(s1 - s0, draft_wall) if c1 > c0 else 0.0
@@ -968,38 +1040,52 @@ class ServingEngine:
         # extend() pass — lane j consumes [pending, d_1..d_k][j] and its
         # greedy argmax is the token the stream emits if lane j is reached
         T = k + 1
-        toks2 = np.zeros((B, T), np.int32)
-        poss2 = np.zeros((B, T), np.int32)
-        ctx2 = np.ones((B, T), np.int32)
-        for i, req in enumerate(reqs):
-            toks2[i, 0] = req.pending_token
-            for j in range(k):
-                toks2[i, j + 1] = proposals[i][j]
-            for j in range(T):
-                poss2[i, j] = base_ctx[i] + j
-                ctx2[i, j] = base_ctx[i] + j + 1
-        vjit = self._verify_jits[B]
-        c0, s0 = vjit.compile_totals()
+        with telemetry.span("serving.decode.build", _CAT, phase="verify",
+                            **args):
+            toks2 = np.zeros((B, T), np.int32)
+            poss2 = np.zeros((B, T), np.int32)
+            ctx2 = np.ones((B, T), np.int32)
+            for i, req in enumerate(reqs):
+                toks2[i, 0] = req.pending_token
+                for j in range(k):
+                    toks2[i, j + 1] = proposals[i][j]
+                for j in range(T):
+                    poss2[i, j] = base_ctx[i] + j
+                    ctx2[i, j] = base_ctx[i] + j + 1
+            vjit = self._verify_jits[B]
+            c0, s0 = vjit.compile_totals()
         t0 = time.time()
-        nxt2, _logits, kp, vp = self._verify_fn(
-            self.params, toks2, poss2, tables, ctx2,
-            self.pool.k_pages, self.pool.v_pages)
-        self.pool.k_pages, self.pool.v_pages = kp, vp
-        nxt2 = np.asarray(nxt2)  # fwlint: disable=device-escape — token egress to clients is the product, B×(k+1) int32s per step
+        with telemetry.span("serving.decode.dispatch", _CAT, phase="verify",
+                            **args):
+            nxt2, _logits, kp, vp = self._verify_fn(
+                self.params, toks2, poss2, tables, ctx2,
+                self.pool.k_pages, self.pool.v_pages)
+            self.pool.k_pages, self.pool.v_pages = kp, vp
+        with telemetry.span("serving.decode.fetch", _CAT, phase="verify",
+                            **args):
+            nxt2 = np.asarray(nxt2)  # fwlint: disable=device-escape — token egress to clients is the product, B×(k+1) int32s per step
         verify_wall = time.time() - t0
         c1, s1 = vjit.compile_totals()
         verify_stall = min(s1 - s0, verify_wall) if c1 > c0 else 0.0
         if draft_stall or verify_stall:
             self.obs.decode_stall(reqs, draft_stall + verify_stall)
-        # greedy acceptance — emit the TARGET's token at every reached
-        # lane. Lane j+1 is reached only if the draft's proposal d_{j+1}
-        # MATCHED the target's lane-j output (the window's K/V past a
-        # mismatch encodes the draft's wrong token, so stop there; the
-        # stale writes are overwritten by the next step's lane 0).
+        self._spec_draft_s += draft_wall
+        self._spec_verify_s += verify_wall
+        return (nxt2, proposals, draft_wall - draft_stall,
+                verify_wall - verify_stall)
+
+    def _note_spec_decode(self, reqs, fetched):
+        """Greedy acceptance — emit the TARGET's token at every reached
+        lane. Lane j+1 is reached only if the draft's proposal d_{j+1}
+        MATCHED the target's lane-j output (the window's K/V past a
+        mismatch encodes the draft's wrong token, so stop there; the
+        stale writes are overwritten by the next step's lane 0)."""
+        nxt2, proposals, draft_s, verify_s = fetched
+        k = self.spec_k
         proposed = accepted = 0
         for i, req in enumerate(reqs):
             proposed += k
-            for j in range(T):
+            for j in range(k + 1):
                 tok = int(nxt2[i, j])
                 if tok < 0:
                     break   # overflow-poisoned lane (past max_len)
@@ -1011,11 +1097,8 @@ class ServingEngine:
                 accepted += 1
         self._spec_proposed += proposed
         self._spec_accepted += accepted
-        self._spec_draft_s += draft_wall
-        self._spec_verify_s += verify_wall
         telemetry.histogram("serving.decode_batch").observe(len(reqs))
-        self.obs.spec_step(reqs, draft_wall - draft_stall,
-                           verify_wall - verify_stall, proposed, accepted)
+        self.obs.spec_step(reqs, draft_s, verify_s, proposed, accepted)
 
     def _note_token(self, req, tok):
         now = time.time()

@@ -1,0 +1,339 @@
+"""The program's spans on the device trace's clock (docs/observability.md
+§Spans): `telemetry.span` opens a `jax.profiler` annotation, the serving
+engine step names its sections, and the benchmark's readers
+(`benchmark/layer_metrics/_gaps.py`) sort the device's idle gaps by them.
+
+One `jax.profiler` session records a tiny `ServingEngine` driven from a
+thread, with the options the benchmark's `TraceWindow` uses; the trace is
+read back with the benchmark's own `trace_reduce.load_xplane`.
+"""
+import importlib
+import json
+import os
+import subprocess
+import sys
+import threading
+import time
+import types
+
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu import profiler, telemetry
+from mxnet_tpu.serving import ServingConfig, ServingEngine
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark import trace_reduce  # noqa: E402
+from benchmark.layer_metrics import _gaps  # noqa: E402
+
+SECTIONS = ["serving.step.lock", "serving.schedule",
+            "serving.prefill.build", "serving.prefill.dispatch",
+            "serving.prefill.fetch", "serving.decode.build",
+            "serving.decode.dispatch", "serving.decode.fetch",
+            "serving.retire"]
+SPAN_NAMES = ["serving.loop.idle", "serving.step"] + SECTIONS
+READERS = {"decode_idle_host_share": "host",
+           "decode_idle_unnamed_share": "unnamed",
+           "prefill_idle_host_share": "host",
+           "prefill_idle_unnamed_share": "unnamed",
+           "prefill_idle_nowork_share": "no_work"}
+
+
+def trace_window(out_dir):
+    """The benchmark's own `TraceWindow` (host tracer on, Python tracer
+    off), writing under ``out_dir``: the options of the run that matters."""
+    from benchmark.harness import TraceWindow
+
+    return TraceWindow(types.SimpleNamespace(out_dir=str(out_dir),
+                                             platform="cpu"))
+
+
+def host_events(path):
+    """line name -> [(name, start_ns, dur_ns, stats)] of the host plane,
+    with each event's stats (the annotations' arguments)."""
+    from jax.profiler import ProfileData
+
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:CPU"):
+            for k, line in enumerate(plane.lines):
+                out["%s#%d" % (line.name, k)] = [
+                    (e.name, float(e.start_ns), float(e.duration_ns),
+                     dict(e.stats)) for e in line.events]
+    return out
+
+
+@pytest.fixture(scope="module")
+def serving_trace(tmp_path_factory):
+    """A few engine steps under the profiler: three prompts of different
+    lengths, six tokens each, submitted while the loop idles."""
+    cfg = ServingConfig(vocab_size=23, num_layers=2, model_dim=32,
+                        num_heads=2, ffn_dim=48, max_len=64, block_size=8,
+                        num_blocks=64, max_batch=8, prefills_per_step=4)
+    eng = ServingEngine(cfg, seed=3)
+    eng.warmup()
+    stop = threading.Event()
+    driver = threading.Thread(target=eng.run_loop, args=(stop,),
+                              name="driver")
+    window = trace_window(tmp_path_factory.mktemp("xplane"))
+    window.start()
+    try:
+        driver.start()
+        time.sleep(0.12)      # an empty queue first: serving.loop.idle
+        with eng._lock:       # all three before the driver's next step
+            reqs = [eng.submit(list(range(1, 6 + i)), 6,
+                               request_id="r%d" % i) for i in range(3)]
+        for r in reqs:
+            assert r.done_event.wait(60)
+    finally:
+        stop.set()
+        driver.join(60)
+        window.stop()
+    assert not driver.is_alive()
+    path = trace_reduce.newest_xplane(window.dir)
+    data = trace_reduce.load_xplane(path, rehearsal=True)
+    lines = host_events(path)
+    mine = [evs for evs in lines.values()
+            if any(e[0] == "serving.step" for e in evs)]
+    assert len(mine) == 1, "the driver thread is one line of the host plane"
+    others = [e[0] for evs in lines.values() if evs is not mine[0]
+              for e in evs if e[0].startswith("serving.")]
+    assert not others, "spans on a thread that is not the driver: %s" % others
+    return {"data": data, "driver": mine[0], "requests": reqs}
+
+
+@pytest.mark.parametrize("name", SPAN_NAMES)
+def test_span_is_on_the_driver_threads_line(serving_trace, name):
+    assert any(e[0] == name for e in serving_trace["driver"])
+    # the reducer the benchmark uses sees it by the same name
+    assert any(e[0] == name for evs in serving_trace["data"]["host"].values()
+               for e in evs)
+
+
+def test_sections_nest_in_the_step_and_do_not_overlap(serving_trace):
+    slack = 1e3   # ns; a span lasts tens of microseconds
+    evs = serving_trace["driver"]
+    steps = [(s, s + d) for n, s, d, _ in evs if n == "serving.step"]
+    sections = sorted((s, s + d, n) for n, s, d, _ in evs if n in SECTIONS)
+    # six tokens a request: one from the prefill, five decode steps, each
+    # step with its lock, schedule, three of decode and retire at least
+    assert len(steps) >= 5 and len(sections) >= 6 * len(steps)
+    for s0, s1, name in sections:
+        assert any(a - slack <= s0 and s1 <= b + slack for a, b in steps), \
+            "%s outside every serving.step" % name
+    for (_a0, a1, an), (b0, _b1, bn) in zip(sections, sections[1:]):
+        assert a1 <= b0 + slack, "%s overlaps %s" % (an, bn)
+    # the wait on an empty queue is outside every step
+    for n, s, d, _ in evs:
+        if n == "serving.loop.idle":
+            assert not any(a < s + d / 2 < b for a, b in steps)
+
+
+def test_arguments_are_readable_from_the_events_stats(serving_trace):
+    by_name = {}
+    for name, _s, _d, stats in serving_trace["driver"]:
+        by_name.setdefault(name, []).append(stats)
+    steps = [st["step_num"] for st in by_name["serving.step"]]
+    assert steps == list(range(steps[0], steps[0] + len(steps)))
+    for part in ("build", "dispatch", "fetch"):
+        pre = by_name["serving.prefill." + part]
+        assert sorted(st["request_id"] for st in pre) == ["r0", "r1", "r2"]
+        assert sorted(st["prompt_len"] for st in pre) == [5, 6, 7]
+        assert {st["bucket"] for st in pre} == {8}
+        dec = by_name["serving.decode." + part]
+        assert {st["batch"] for st in dec} == {3} \
+            and {st["bucket"] for st in dec} == {4}
+        # three streams of 5+6+7 tokens one token further each step, the
+        # padding lane's single token included
+        first = dec[0]
+        assert first["ctx_tokens"] == 5 + 6 + 7 + 3 + 1 \
+            and first["ctx_max"] == 8
+        assert [st["ctx_tokens"] for st in dec] == [
+            first["ctx_tokens"] + 3 * i for i in range(len(dec))]
+    sched = by_name["serving.schedule"][0]
+    assert sched["waiting"] == 3 and sched["running"] == 0
+    retired = [st["finished"] for st in by_name["serving.retire"]
+               if "finished" in st]
+    assert sum(retired) == 3 and retired[-1] == 3
+    assert sorted(st["request_id"] for st in by_name["serving.retire"]
+                  if "request_id" in st) == ["r0", "r1", "r2"]
+    assert all(len(r.generated) == 6 for r in serving_trace["requests"])
+
+
+def test_idle_gaps_carry_the_programs_labels(serving_trace):
+    gaps = trace_reduce.summarize(serving_trace["data"])["idle_gaps"]
+    labels = [label for label, _s in gaps]
+    assert "serving.loop.idle" in labels
+    assert any(_gaps.classify(label) == "host" for label in labels)
+    obs = {"trace": {"idle_gaps": gaps, "window_s": 1.0}}
+    assert _gaps.share(obs, "host") > 0 and _gaps.share(obs, "no_work") > 0
+
+
+def test_fit_step_is_a_step_annotation(tmp_path):
+    data = mx.sym.Variable("data")
+    net = mx.sym.FullyConnected(data, num_hidden=4, name="fc")
+    net = mx.sym.SoftmaxOutput(net, name="softmax")
+    it = mx.io.NDArrayIter(np.random.rand(12, 6).astype(np.float32),
+                           np.zeros(12, np.float32), batch_size=4)
+    mod = mx.mod.Module(net, context=mx.cpu())
+    window = trace_window(tmp_path)
+    window.start()
+    try:
+        mod.fit(it, num_epoch=1, optimizer="sgd")
+    finally:
+        window.stop()
+    lines = host_events(trace_reduce.newest_xplane(window.dir))
+    evs = [e for line in lines.values() for e in line]
+    steps = [st for n, _s, _d, st in evs if n == "fit.step"]
+    assert [st["step_num"] for st in steps] == [0, 1, 2]
+    assert steps[1]["epoch"] == 0 and steps[1]["nbatch"] == 1
+    assert any(n == "fit.data_wait" for n, _s, _d, _st in evs)
+
+
+# ------------------------------------------------------------ the readers --
+def obs_of(gaps, window_s=4.0):
+    return {"trace": {"idle_gaps": gaps, "window_s": window_s}}
+
+
+@pytest.mark.parametrize("label,kind", [
+    ("serving.schedule", "host"), ("serving.step", "host"),
+    ("serving.decode.build", "host"), ("serving.loop.idle", "no_work"),
+    (trace_reduce.UNTRACED, "unnamed"),
+    ("np.asarray(jax.Array)", "runtime"),
+    ("PjitFunction(_decode)", "runtime")])
+def test_gap_classes(label, kind):
+    assert _gaps.classify(label) == kind
+    obs = obs_of([(label, 0.2), ("serving.retire", 0.1)])
+    want = {"host": 2.5}
+    want[kind] = want.get(kind, 0.0) + 5.0
+    for k in ("host", "no_work", "unnamed", "runtime"):
+        assert _gaps.share(obs, k) == pytest.approx(want.get(k, 0.0))
+
+
+@pytest.mark.parametrize("name", sorted(READERS))
+def test_readers(name):
+    read = importlib.import_module("benchmark.layer_metrics." + name).read
+    assert read({"kind": "serve"}) is None          # an untraced run
+    assert read({"trace": None}) is None
+    gaps = [("serving.schedule", 0.08), (trace_reduce.UNTRACED, 0.02),
+            ("serving.loop.idle", 0.04), ("np.asarray(jax.Array)", 0.1),
+            ("serving.retire", 0.04)]
+    want = {"host": 3.0, "unnamed": 0.5, "no_work": 1.0}[READERS[name]]
+    assert read(obs_of(gaps)) == pytest.approx(want)
+    # a program from before the spans: its unnamed share reads, the two
+    # classes only spans can fill read nothing (not a zero)
+    parent = obs_of([(trace_reduce.UNTRACED, 0.24),
+                     ("np.asarray(jax.Array)", 0.112)])
+    if READERS[name] == "unnamed":
+        assert read(parent) == pytest.approx(6.0)
+    else:
+        assert read(parent) is None
+
+
+def test_readers_see_only_the_ten_largest_labels():
+    """`idle_gaps` keeps ten labels: a class can miss a sliver."""
+    ops = [("op", 100.0 * i, 10.0) for i in range(14)]   # 13 gaps of 90 ns
+    names = ["serving.s%d" % i for i in range(12)] + [None]
+    host = {"driver": [(n, 100.0 * i + 10.0, 90.0 + i)   # longer = ranked
+                       for i, n in enumerate(names) if n]}
+    gaps = trace_reduce.idle_gaps(ops, host)
+    assert len(gaps) == 10
+    # the gap under no event and two of the twelve spans fell below the cut
+    assert trace_reduce.UNTRACED in dict(trace_reduce.idle_gaps(
+        ops, host, top=13))
+    obs = obs_of(gaps, window_s=13 * 90e-9)
+    assert _gaps.share(obs, "host") == pytest.approx(100.0 * 10 / 13)
+    assert _gaps.share(obs, "unnamed") == 0.0
+
+
+def test_manifest_appends_the_five_readers():
+    manifest = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    tail = manifest["per_layer"][-5:]
+    assert [m["name"] for m in tail] == [
+        "decode_idle_host_share", "decode_idle_unnamed_share",
+        "prefill_idle_host_share", "prefill_idle_unnamed_share",
+        "prefill_idle_nowork_share"]
+    for m in tail:
+        assert (m["unit"], m["better"], m["source"], m["layer"]) == (
+            "%", "lower", "program_span", "Engine loop")
+        cell = ("gpt2m-chat-closed64" if m["name"].startswith("decode")
+                else "gpt2m-longprompt-open")
+        assert m["workloads"] == [cell]
+
+
+# ------------------------------------------------------- the span itself --
+def test_span_with_everything_off_is_only_an_annotation():
+    telemetry.disable()
+    assert not profiler.is_running()
+    before = set(telemetry.dump()["histograms"])
+    n = 20000
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with telemetry.span("spans.off", "test", batch=3):
+            pass
+    per_span = (time.perf_counter() - t0) / n
+    assert per_span < 5e-6, "a span costs %.2f us with all off" % (
+        per_span * 1e6)
+    s = telemetry.span("spans.off", "test")
+    with s:
+        pass
+    assert s._ann is not None and s._t0 is None      # no clock was read
+    assert set(telemetry.dump()["histograms"]) == before
+    assert not profiler._state["events"]             # no chrome event
+
+
+def test_span_feeds_the_chrome_trace_with_late_arguments(tmp_path):
+    fname = str(tmp_path / "chrome.json")
+    profiler.profiler_set_config(mode="all", filename=fname)
+    profiler.profiler_set_state("run")
+    try:
+        with telemetry.span("spans.step", "test", step=7, batch=2) as s:
+            s.set(finished=1)
+    finally:
+        profiler.profiler_set_state("stop")
+    profiler.dump_profile()
+    evs = [e for e in json.load(open(fname))["traceEvents"]
+           if e["name"] == "spans.step"]
+    assert len(evs) == 1 and evs[0]["cat"] == "test"
+    assert evs[0]["args"] == {"batch": 2, "finished": 1}
+
+
+def test_per_operator_spans_open_no_annotation(tmp_path):
+    assert profiler.record_span("op") is profiler._OFF
+    profiler.profiler_set_config(mode="all",
+                                 filename=str(tmp_path / "ops.json"))
+    profiler.profiler_set_state("run")
+    try:
+        s = profiler.record_span("op")
+        with s:
+            pass
+        assert type(s) is type(telemetry.span("x")) and s._ann is None
+        assert [e["name"] for e in profiler._state["events"]] == ["op"]
+    finally:
+        profiler.profiler_set_state("stop")
+    assert "op" not in telemetry.dump()["histograms"]
+
+
+def test_importing_telemetry_starts_no_backend():
+    code = ("import mxnet_tpu.telemetry, jax; "
+            "from jax._src import xla_bridge; "
+            "assert not xla_bridge._backends, xla_bridge._backends; "
+            "print('no backend')")
+    r = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
+                       capture_output=True, text=True, timeout=120,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert r.returncode == 0 and "no backend" in r.stdout, r.stderr[-2000:]
+
+
+def test_one_span_system():
+    """`TraceAnnotation` is named in telemetry.py alone."""
+    hits = []
+    for d, _dirs, files in os.walk(os.path.join(ROOT, "mxnet_tpu")):
+        for f in files:
+            if f.endswith(".py") and "TraceAnnotation" in open(
+                    os.path.join(d, f), encoding="utf-8").read():
+                hits.append(os.path.relpath(os.path.join(d, f), ROOT))
+    assert hits == ["mxnet_tpu/telemetry.py"]

@@ -1895,6 +1895,51 @@ def test_unbalanced_acquire_positive_and_handoff_negative():
     assert lint(src_ok, select=["unbalanced-acquire"]) == []
 
 
+def test_manual_acquire_inside_a_with_body_outlives_the_with():
+    """The engine step times its lock wait under a span
+    (``with span(...): self._lock.acquire()``): the lock is held AFTER
+    that ``with`` closes, so the guarded writes that follow are guarded —
+    while a lock the ``with`` itself took is dropped at its end."""
+    src = """
+    import threading
+    from contextlib import nullcontext
+
+    class Box:
+        def __init__(self):
+            self._lock = threading.Lock()
+            self.val = 0{init}
+
+        def _worker(self):
+            with nullcontext():
+                self._lock.acquire()
+            try:
+                self.val = 1
+            finally:
+                self._lock.release()
+
+        def start(self):
+            threading.Thread(target=self._worker).start()
+
+        def read(self):
+            with self._lock:
+                return self.val{more}
+    """
+    assert lint(src.format(init="", more=""),
+                select=["unguarded-shared-write", "unbalanced-acquire"]) == []
+    leaked = src.format(init="\n            self.n = 0", more="""
+
+        def bump(self):
+            with self._lock:
+                pass
+            self.n = self.n + 1
+
+        def start2(self):
+            threading.Thread(target=self.bump).start()
+            self.n = 5""")
+    found = lint(leaked, select=["unguarded-shared-write"])
+    assert [("Box.n" in f.message) for f in found] == [True]
+
+
 def test_guard_mismatch_positive_and_negative():
     src = """
     import threading

@@ -209,14 +209,20 @@ def test_spans_land_in_chrome_trace_dump(tmp_path):
     assert telemetry.histogram("unit.test_span").count == 1
 
 
-def test_span_is_noop_when_everything_off():
+def test_span_is_only_an_annotation_when_everything_off():
+    """With telemetry, the MXNet profiler and jax.profiler all off a span
+    is its (idle) profiler annotation: no clock read, no histogram, no
+    chrome-trace event."""
     telemetry.disable()
-    s = telemetry.span("off.span")
-    assert s is telemetry._NULL_SPAN
+    assert not profiler.is_running()
+    s = telemetry.span("off.span", "test", batch=3)
     with s:
         pass
+    assert s._ann is not None and s._t0 is None
     telemetry.enable()
     assert telemetry.histogram("off.span").count == 0
+    # the per-operator spans of the imperative path get no annotation
+    assert profiler.record_span("off.op") is profiler._OFF
 
 
 def test_concurrent_span_writers_and_profiler_toggle(tmp_path):
