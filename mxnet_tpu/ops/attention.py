@@ -630,8 +630,44 @@ get_op("_contrib_CachedMultiHeadAttention")._infer_shape = _cached_mha_infer
 
 
 # ------------------------------------------------------- paged (ragged) decode
+# Page format. A page row is ``(G, W)``: ``r = W // D`` consecutive heads of
+# ``D`` lanes side by side, ``G = H // r`` rows (``serving/kv_cache.py``
+# ``KVBlockPool.page_shape`` picks r so that W fills the TPU's 128 lanes;
+# r = 1 is the plain ``(H, D)`` row). ``(H, D) -> (G, W)`` is a row-major
+# reshape, so q, the new K/V rows and the output are packed and unpacked by
+# reshapes at the edges and r is read off the shapes. Pages come as one
+# layer's ``(N, bs, G, W)`` or as the whole pool ``(L, N, bs, G, W)`` with a
+# static ``layer``: the kernels address the pool in place, because a
+# ``k_pages[layer]`` slice is a copy of that layer on every step.
+def _heads_per_row(q, k_pages):
+    """r for q ``(.., H, D)`` against pages ``(.., bs, G, W)``."""
+    (h, d), (g, w) = q.shape[-2:], k_pages.shape[-2:]
+    r = w // d
+    if (g * r, r * d) != (h, w):
+        raise ValueError("pages with rows %s cannot hold q's %d heads of %d"
+                         % ((g, w), h, d))
+    return r
+
+
+def _whole_pool(k_pages, v_pages, layer):
+    """5-D pages and a layer index from either form."""
+    if k_pages.ndim == 4:
+        return k_pages[None], v_pages[None], 0
+    if layer is None:
+        raise ValueError("a 5-D page pool needs layer=")
+    return k_pages, v_pages, int(layer)
+
+
+def _gather_tokens(pages, block_tables, h, d):
+    """Each sequence's pages (N, bs, G, W) in position order, unpacked:
+    (B, T, H, D) f32."""
+    b, nb = block_tables.shape
+    x = jnp.take(pages, block_tables, axis=0)       # (B, nb, bs, G, W)
+    return x.reshape(b, nb * pages.shape[1], h, d).astype(jnp.float32)
+
+
 def paged_attention_reference(q, k_pages, v_pages, block_tables, context_lens,
-                              sm_scale=None):
+                              sm_scale=None, layer=None):
     """Pure-XLA paged decode attention — the numeric oracle and the CPU/CI
     lowering of the Pallas kernel below.
 
@@ -640,8 +676,9 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, context_lens,
     fixed-size blocks of a shared pool, named by a per-sequence block table.
 
     q:            (B, H, D)        — this step's query, one token per stream
-    k_pages:      (N, bs, H, D)    — the shared K pool: N blocks of bs slots
-    v_pages:      (N, bs, H, D)    — the shared V pool
+    k_pages:      (N, bs, G, W)    — the shared K pool: N blocks of bs slots,
+                                     or (L, N, bs, G, W) with ``layer``
+    v_pages:      the shared V pool, same shape
     block_tables: (B, nb) int32    — block ids per sequence, in position
                                      order; unused tail entries may point at
                                      any block (masked by context_lens)
@@ -654,115 +691,27 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, context_lens,
     row would otherwise go uniform and average the garbage), matching
     the Pallas kernel's empty-stream output.
     """
-    sm_scale = _scale(sm_scale, q.shape[-1])
-    b, h, d = q.shape
-    bs = k_pages.shape[1]
-    nb = block_tables.shape[1]
-    t = nb * bs
-    k = jnp.take(k_pages, block_tables, axis=0)  # (B, nb, bs, H, D)
-    v = jnp.take(v_pages, block_tables, axis=0)
-    k = k.reshape(b, t, h, d).astype(jnp.float32)
-    v = v.reshape(b, t, h, d).astype(jnp.float32)
-    s = jnp.einsum("bhd,bthd->bht", q.astype(jnp.float32), k,
-                   precision=lax.Precision.HIGHEST) * sm_scale
-    valid = jnp.arange(t)[None, :] < context_lens[:, None]  # (B, T)
-    s = jnp.where(valid[:, None, :], s, _NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    # an all-masked row (context_len == 0) softmaxes to uniform 1/T and
-    # would average the gathered garbage — pin the whole row to zero, the
-    # kernel's empty-stream output
-    p = jnp.where((context_lens > 0)[:, None, None], p, 0.0)
-    out = jnp.einsum("bht,bthd->bhd", p, v, precision=lax.Precision.HIGHEST)
-    return out.astype(q.dtype)
+    return paged_attention_multi_reference(
+        q[:, None], k_pages, v_pages, block_tables, context_lens[:, None],
+        sm_scale=sm_scale, layer=layer)[:, 0]
 
 
 def _paged_pallas(q, k_pages, v_pages, block_tables, context_lens, sm_scale,
-                  interpret=False):
-    """Pallas TPU ragged-paged-attention decode kernel.
-
-    Grid (B, nb) with the block axis innermost; the block TABLE and context
-    lengths ride in as scalar-prefetch args (``PrefetchScalarGridSpec``) so
-    the index_map can steer each step's K/V DMA straight at the sequence's
-    i-th pool block — the gather never materialises per-sequence contiguous
-    KV. Online-softmax state (m, l, acc) lives in VMEM scratch carried
-    across block steps; blocks wholly past context_len skip compute via
-    ``pl.when`` (ragged early-out). VMEM per core is O(bs·H·D), independent
-    of both sequence length and pool size.
-    """
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, h, d = q.shape
-    bs = k_pages.shape[1]
-    nb = block_tables.shape[1]
-
-    def kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
-               m_ref, l_ref, acc_ref):
-        i = pl.program_id(0)  # sequence
-        j = pl.program_id(1)  # block-table slot (innermost)
-
-        @pl.when(j == 0)
-        def _init():
-            m_ref[:] = jnp.full((h,), _NEG_INF, jnp.float32)
-            l_ref[:] = jnp.zeros((h,), jnp.float32)
-            acc_ref[:] = jnp.zeros((h, d), jnp.float32)
-
-        ctx = cl_ref[i]
-
-        @pl.when(j * bs < ctx)  # ragged early-out past the context
-        def _step():
-            qv = q_ref[0].astype(jnp.float32)   # (H, D)
-            kv = k_ref[0].astype(jnp.float32)   # (bs, H, D)
-            vv = v_ref[0].astype(jnp.float32)
-            s = jnp.sum(qv[None] * kv, axis=-1) * sm_scale  # (bs, H)
-            pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, h), 0)
-            s = jnp.where(pos < ctx, s, _NEG_INF)
-            m = m_ref[:]
-            m_new = jnp.maximum(m, jnp.max(s, axis=0))
-            p = jnp.exp(s - m_new[None, :])
-            scale = jnp.exp(m - m_new)
-            m_ref[:] = m_new
-            l_ref[:] = l_ref[:] * scale + jnp.sum(p, axis=0)
-            acc_ref[:] = (acc_ref[:] * scale[:, None]
-                          + jnp.sum(p[:, :, None] * vv, axis=0))
-
-        @pl.when(j == nb - 1)
-        def _finish():
-            l = jnp.maximum(l_ref[:], 1e-30)
-            o_ref[0] = (acc_ref[:] / l[:, None]).astype(o_ref.dtype)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, nb),
-        in_specs=[
-            pl.BlockSpec((1, h, d), lambda i, j, bt, cl: (i, 0, 0)),
-            pl.BlockSpec((1, bs, h, d), lambda i, j, bt, cl: (bt[i, j], 0, 0, 0)),
-            pl.BlockSpec((1, bs, h, d), lambda i, j, bt, cl: (bt[i, j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, h, d), lambda i, j, bt, cl: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h,), jnp.float32),
-            pltpu.VMEM((h,), jnp.float32),
-            pltpu.VMEM((h, d), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-      q, k_pages, v_pages)
+                  layer=None, interpret=False):
+    """The decode kernel: :func:`_paged_pallas_multi` with one query lane."""
+    return _paged_pallas_multi(q[:, None], k_pages, v_pages, block_tables,
+                               context_lens[:, None], sm_scale, layer=layer,
+                               interpret=interpret)[:, 0]
 
 
 def _paged_shapes_ok(q, k_pages):
-    # Mosaic pads sublanes/lanes of the trailing (H, D) tile; keep D
+    # Mosaic pads sublanes/lanes of the trailing (G, W) tile; keep D
     # lane-aligned. bs and nb are free (ragged tails are masked in-kernel).
     return q.shape[-1] % 8 == 0 and q.shape[-1] >= 8
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
-                    sm_scale=None):
+                    sm_scale=None, layer=None):
     """Paged ragged decode attention over a shared KV block pool.
 
     Platform selected at LOWERING time (like :func:`flash_attention`): the
@@ -770,21 +719,14 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     identical outputs, so a CPU CI run proves the math the TPU kernel runs.
     Serving-only (no vjp): the decode path never differentiates.
     """
-    sm_scale = _scale(sm_scale, q.shape[-1])
-    if _paged_shapes_ok(q, k_pages):
-        return lax.platform_dependent(
-            q, k_pages, v_pages, block_tables, context_lens,
-            tpu=functools.partial(_paged_pallas, sm_scale=sm_scale),
-            default=functools.partial(paged_attention_reference,
-                                      sm_scale=sm_scale),
-        )
-    return paged_attention_reference(q, k_pages, v_pages, block_tables,
-                                     context_lens, sm_scale=sm_scale)
+    return paged_attention_multi(q[:, None], k_pages, v_pages, block_tables,
+                                 context_lens[:, None], sm_scale=sm_scale,
+                                 layer=layer)[:, 0]
 
 
 # --------------------------------------------- paged multi-query (verify)
 def paged_attention_multi_reference(q, k_pages, v_pages, block_tables,
-                                    context_lens, sm_scale=None):
+                                    context_lens, sm_scale=None, layer=None):
     """Pure-XLA multi-query paged attention — q-length > 1 per sequence
     with PER-LANE context lengths. The speculative-decoding verify pass
     and the CPU/CI lowering of the Pallas kernel below.
@@ -795,58 +737,81 @@ def paged_attention_multi_reference(q, k_pages, v_pages, block_tables,
     only read pool positions < context_lens[b, t].
 
     q:            (B, T, H, D)     — T query tokens per stream
-    k_pages:      (N, bs, H, D)    — the shared K pool
-    v_pages:      (N, bs, H, D)    — the shared V pool
+    k_pages:      (N, bs, G, W)    — the shared K pool, or
+                                     (L, N, bs, G, W) with ``layer``
+    v_pages:      the shared V pool, same shape
     block_tables: (B, nb) int32    — ONE table per sequence (lanes share it)
     context_lens: (B, T) int32     — valid pool positions PER LANE
                                      (monotone over t for a causal window)
 
     Returns (B, T, H, D) in q.dtype. T == 1 with context_lens (B, 1)
-    reproduces :func:`paged_attention_reference` exactly. A lane with
-    context_len == 0 returns all zeros, like the single-query oracle.
+    is :func:`paged_attention_reference`. A lane with context_len == 0
+    returns all zeros, like the single-query oracle.
     """
     sm_scale = _scale(sm_scale, q.shape[-1])
-    b, tq, h, d = q.shape
-    bs = k_pages.shape[1]
-    nb = block_tables.shape[1]
-    t = nb * bs
-    k = jnp.take(k_pages, block_tables, axis=0)  # (B, nb, bs, H, D)
-    v = jnp.take(v_pages, block_tables, axis=0)
-    k = k.reshape(b, t, h, d).astype(jnp.float32)
-    v = v.reshape(b, t, h, d).astype(jnp.float32)
+    _heads_per_row(q, k_pages)
+    k_pages, v_pages, layer = _whole_pool(k_pages, v_pages, layer)
+    h, d = q.shape[-2:]
+    k = _gather_tokens(k_pages[layer], block_tables, h, d)   # (B, K, H, D)
+    v = _gather_tokens(v_pages[layer], block_tables, h, d)
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k,
                    precision=lax.Precision.HIGHEST) * sm_scale
-    valid = jnp.arange(t)[None, None, :] < context_lens[:, :, None]  # (B,T,K)
+    valid = (jnp.arange(k.shape[1])[None, None, :]
+             < context_lens[:, :, None])                     # (B, T, K)
     s = jnp.where(valid[:, None, :, :], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     # all-masked lanes (context_len == 0) softmax to uniform and would
-    # average gathered garbage — pin them to zero like the 1-query oracle
+    # average gathered garbage — pin them to zero
     p = jnp.where((context_lens > 0)[:, None, :, None], p, 0.0)
     out = jnp.einsum("bhqk,bkhd->bqhd", p, v,
                      precision=lax.Precision.HIGHEST)
     return out.astype(q.dtype)
 
 
-def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
-                        sm_scale, interpret=False):
-    """Pallas TPU multi-query ragged-paged-attention kernel.
+def _head_sums(x, r):
+    """x (bs, G, W) -> (bs, G, W): every lane holds the sum over its own
+    head's D = W // r lanes. One masked lane reduction per head of the row;
+    r = 1 is the plain sum over the row."""
+    if r == 1:
+        return jnp.broadcast_to(jnp.sum(x, axis=-1, keepdims=True), x.shape)
+    d = x.shape[-1] // r
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 2)
+    out = None
+    for i in range(r):
+        mine = (lane >= i * d) & (lane < (i + 1) * d)
+        part = jnp.sum(jnp.where(mine, x, 0.0), axis=-1, keepdims=True)
+        out = jnp.where(mine, part, 0.0 if out is None else out)
+    return out
 
-    The decode kernel generalized to T query lanes per sequence: the same
-    (B, nb) grid and scalar-prefetch-steered K/V DMA, but the online-
-    softmax state (m, l, acc) carries a T axis and masking is per lane
-    (``context_lens`` is (B, T)). One extra row of VMEM scratch per lane —
-    still O(T·H·D), independent of pool size and sequence length. The
-    lanes take the decode kernel's step one after another (T is static and
-    small; their context lengths are scalar reads from SMEM). Blocks
-    wholly past the LONGEST lane's context skip compute (``pl.when``);
-    shorter lanes mask the tail of shared blocks with -1e30 like the
-    single-query kernel masks ragged block tails.
+
+def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
+                        sm_scale, layer=None, interpret=False):
+    """Pallas TPU ragged-paged-attention kernel, T query lanes per sequence
+    (T = 1 is the decode step, T = k + 1 the speculative verify pass).
+
+    Grid (B, nb) with the block axis innermost; the block TABLE and context
+    lengths ride in as scalar-prefetch args (``PrefetchScalarGridSpec``) so
+    the index_map can steer each step's K/V DMA straight at the sequence's
+    i-th block of the pool's ``layer`` — the gather never materialises
+    per-sequence contiguous KV, and the pool is read where it lies.
+    Online-softmax state (m, l, acc) lives in VMEM scratch carried across
+    block steps, one (G, W) row set per lane: a head's score is summed over
+    its own D lanes and kept broadcast across them (:func:`_head_sums`), so
+    every line after it is elementwise on full (8, 128) registers whatever
+    r is. Masking is per lane (``context_lens`` is (B, T)); the lanes take
+    the step one after another (T is static and small; their context
+    lengths are scalar reads from SMEM). Blocks wholly past the LONGEST
+    lane's context skip compute (``pl.when``); shorter lanes mask the tail
+    of shared blocks with -1e30. VMEM per core is O((bs + T)·H·D),
+    independent of both sequence length and pool size.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    r = _heads_per_row(q, k_pages)
+    k_pages, v_pages, layer = _whole_pool(k_pages, v_pages, layer)
     b, tq, h, d = q.shape
-    bs = k_pages.shape[1]
+    bs, g, w = k_pages.shape[2:]
     nb = block_tables.shape[1]
 
     def kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
@@ -856,9 +821,9 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
 
         @pl.when(j == 0)
         def _init():
-            m_ref[:] = jnp.full((tq, h), _NEG_INF, jnp.float32)
-            l_ref[:] = jnp.zeros((tq, h), jnp.float32)
-            acc_ref[:] = jnp.zeros((tq, h, d), jnp.float32)
+            m_ref[:] = jnp.full((tq, g, w), _NEG_INF, jnp.float32)
+            l_ref[:] = jnp.zeros((tq, g, w), jnp.float32)
+            acc_ref[:] = jnp.zeros((tq, g, w), jnp.float32)
 
         # SMEM yields scalars only: one read per lane, T is static and small
         ctx = [cl_ref[i, t] for t in range(tq)]
@@ -866,65 +831,58 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
 
         @pl.when(j * bs < ctx_max)  # ragged early-out past every lane
         def _step():
-            kv = k_ref[0].astype(jnp.float32)   # (bs, H, D)
-            vv = v_ref[0].astype(jnp.float32)
-            pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, h), 0)
-            for t in range(tq):  # the single-query kernel's step, per lane
-                qv = q_ref[0, t].astype(jnp.float32)            # (H, D)
-                s = jnp.sum(qv[None] * kv, axis=-1) * sm_scale  # (bs, H)
+            kv = k_ref[0, 0].astype(jnp.float32)   # (bs, G, W)
+            vv = v_ref[0, 0].astype(jnp.float32)
+            pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, g, w), 0)
+            for t in range(tq):
+                qv = q_ref[0, t].astype(jnp.float32)            # (G, W)
+                s = _head_sums(qv[None] * kv, r) * sm_scale     # (bs, G, W)
                 s = jnp.where(pos < ctx[t], s, _NEG_INF)
                 m = m_ref[t]
                 m_new = jnp.maximum(m, jnp.max(s, axis=0))
-                p = jnp.exp(s - m_new[None, :])
+                p = jnp.exp(s - m_new[None])
                 scale = jnp.exp(m - m_new)
                 m_ref[t] = m_new
                 l_ref[t] = l_ref[t] * scale + jnp.sum(p, axis=0)
-                acc_ref[t] = (acc_ref[t] * scale[:, None]
-                              + jnp.sum(p[:, :, None] * vv, axis=0))
+                acc_ref[t] = acc_ref[t] * scale + jnp.sum(p * vv, axis=0)
 
         @pl.when(j == nb - 1)
         def _finish():
             for t in range(tq):
-                l = jnp.maximum(l_ref[t], 1e-30)
-                out = acc_ref[t] / l[:, None]
+                out = acc_ref[t] / jnp.maximum(l_ref[t], 1e-30)
                 # a lane that never saw a valid position accumulated
                 # exp(-1e30 - -1e30) = 1 weights over garbage — pin it to
                 # the oracle's empty-lane zero
                 out = jnp.where(ctx[t] > 0, out, 0.0)
                 o_ref[0, t] = out.astype(o_ref.dtype)
 
+    q_spec = pl.BlockSpec((1, tq, g, w), lambda i, j, bt, cl: (i, 0, 0, 0))
+    page_spec = pl.BlockSpec((1, 1, bs, g, w),
+                             lambda i, j, bt, cl: (layer, bt[i, j], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, nb),
-        in_specs=[
-            pl.BlockSpec((1, tq, h, d), lambda i, j, bt, cl: (i, 0, 0, 0)),
-            pl.BlockSpec((1, bs, h, d), lambda i, j, bt, cl: (bt[i, j], 0, 0, 0)),
-            pl.BlockSpec((1, bs, h, d), lambda i, j, bt, cl: (bt[i, j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tq, h, d),
-                               lambda i, j, bt, cl: (i, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((tq, h), jnp.float32),
-            pltpu.VMEM((tq, h), jnp.float32),
-            pltpu.VMEM((tq, h, d), jnp.float32),
-        ],
+        in_specs=[q_spec, page_spec, page_spec],
+        out_specs=q_spec,
+        scratch_shapes=[pltpu.VMEM((tq, g, w), jnp.float32)] * 3,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, tq, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, tq, g, w), q.dtype),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-      q, k_pages, v_pages)
+      q.reshape(b, tq, g, w), k_pages, v_pages)
+    return out.reshape(b, tq, h, d)
 
 
 def paged_attention_multi(q, k_pages, v_pages, block_tables, context_lens,
-                          sm_scale=None):
+                          sm_scale=None, layer=None):
     """Multi-query paged attention over a shared KV block pool: q is
     (B, T, H, D), context_lens (B, T) per lane — the speculative-decoding
     verify pass scores all T = k+1 window positions in this ONE dispatch.
 
-    Platform selected at LOWERING time like :func:`paged_attention`: the
+    Platform selected at LOWERING time like :func:`flash_attention`: the
     Pallas kernel on TPU, the pure-XLA gather reference everywhere else.
     Serving-only (no vjp).
     """
@@ -932,12 +890,14 @@ def paged_attention_multi(q, k_pages, v_pages, block_tables, context_lens,
     if _paged_shapes_ok(q, k_pages):
         return lax.platform_dependent(
             q, k_pages, v_pages, block_tables, context_lens,
-            tpu=functools.partial(_paged_pallas_multi, sm_scale=sm_scale),
+            tpu=functools.partial(_paged_pallas_multi, sm_scale=sm_scale,
+                                  layer=layer),
             default=functools.partial(paged_attention_multi_reference,
-                                      sm_scale=sm_scale),
+                                      sm_scale=sm_scale, layer=layer),
         )
     return paged_attention_multi_reference(q, k_pages, v_pages, block_tables,
-                                           context_lens, sm_scale=sm_scale)
+                                           context_lens, sm_scale=sm_scale,
+                                           layer=layer)
 
 
 @register(

@@ -308,8 +308,9 @@ class ServingEngine:
                     device=device)
             import jax.numpy as jnp
 
-            dshape = (dcfg.num_layers, cfg.num_blocks, cfg.block_size,
-                      dcfg.num_heads, dcfg.model_dim // dcfg.num_heads)
+            dshape = (dcfg.num_layers, cfg.num_blocks, cfg.block_size
+                      ) + KVBlockPool.page_shape(
+                          dcfg.num_heads, dcfg.model_dim // dcfg.num_heads)
             dk = jnp.zeros(dshape, cfg.kv_dtype)
             dv = jnp.zeros(dshape, cfg.kv_dtype)
             if device is not None:
@@ -1164,6 +1165,10 @@ class ServingEngine:
                 "kv_blocks_used": self.pool.used(),
                 "kv_blocks_frag_slots": self.scheduler.frag_slots(),
                 "kv_pool_bytes": self.pool.nbytes(),
+                # 1 = the plain (H, D) page row; at head_dim 64 that row
+                # is half a lane tile and every program copies the pool
+                "kv_heads_per_row": self.pool.heads_per_row,
+                "kv_page_shape": list(self.pool.k_pages.shape[-2:]),
                 "tokens_total": self._tokens_total,
                 "tokens_per_sec":
                     telemetry.gauge("serving.tokens_per_sec").value,

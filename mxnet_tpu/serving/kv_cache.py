@@ -10,8 +10,18 @@ pool blocks hold the request's tokens, in position order. Device memory
 scales with tokens actually cached, admission is a free-list pop, and
 release is O(blocks) with zero copying.
 
-Layout (one pool per engine): ``(num_layers, num_blocks, block_size,
-num_heads, head_dim)`` for K and V. Block 0 is the reserved TRASH block —
+Layout (one pool per engine): ``(num_layers, num_blocks, block_size, G, W)``
+for K and V, where a page row ``(G, W)`` holds a token's ``num_heads x
+head_dim`` values lane-dense: :meth:`KVBlockPool.page_shape` puts ``r``
+consecutive heads side by side so that ``W = r * head_dim`` fills the TPU's
+128 lanes (16 heads of 64 -> 8 rows of 128). A 64-wide minor dimension fills
+half of every (8, 128) tile: the device's default layout for such a pool is
+not row-major, the Pallas kernels want row-major, and every program that
+touched the pool copied all of it in and out (PERF.md, PR 25). With full
+lanes the default layout, the scatter's and the kernel's are one, and the
+donated pool is updated where it lies. ``(H, D) -> (G, W)`` is a row-major
+reshape, so a block's bytes are what they always were. Block 0 is the
+reserved TRASH block —
 padded table entries and padded batch rows point at it, so masked lanes of
 a bucketed step scatter their garbage somewhere no reader ever trusts
 (readers mask by context length; the pool hands block 0 to no request).
@@ -69,8 +79,11 @@ class KVBlockPool:
         self.head_dim = int(head_dim)
         self.dtype = np.dtype(dtype)
         self.prefix_cache = bool(prefix_cache)
+        rows, lanes = self.page_shape(self.num_heads, self.head_dim)
+        #: heads side by side in one page row (1 = the plain (H, D) row)
+        self.heads_per_row = self.num_heads // rows
         shape = (self.num_layers, self.num_blocks, self.block_size,
-                 self.num_heads, self.head_dim)
+                 rows, lanes)
         k = jnp.zeros(shape, self.dtype)
         v = jnp.zeros(shape, self.dtype)
         if device is not None:
@@ -101,13 +114,27 @@ class KVBlockPool:
         self.prefix_hit_blocks = 0
         self.cow_copies = 0
         telemetry.gauge("serving.kv_blocks_total").set(self.num_usable)
+        telemetry.gauge("serving.kv_heads_per_row").set(self.heads_per_row)
         # the pool may be constructed on a supervisor thread while handler
         # threads already poll the gauges of a predecessor — honor the
         # _locked suffix even on the init path
         with self._lock:
             self._refresh_gauges_locked()
 
-    # ---- capacity -------------------------------------------------------
+    # ---- format ---------------------------------------------------------
+    @staticmethod
+    def page_shape(num_heads, head_dim):
+        """``(G, W)``: the rows and lanes one token's K (or V) takes in a
+        page. ``r = 128 // head_dim`` consecutive heads share a row when
+        that fills the 128 lanes exactly and divides the heads; any other
+        shape (``head_dim`` >= 128, an odd head count) keeps ``(H, D)``,
+        r = 1. The one place the format is decided: the model and the
+        kernels read r off the pages they are handed."""
+        r = 128 // head_dim if head_dim < 128 and 128 % head_dim == 0 else 1
+        if num_heads % r:
+            r = 1
+        return num_heads // r, r * head_dim
+
     @property
     def num_usable(self):
         """Allocatable blocks (pool size minus the trash block)."""

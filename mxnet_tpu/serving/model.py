@@ -11,9 +11,16 @@ block uses it) — so a trained checkpoint's ``arg_params`` drop straight in
 and the paged decode reproduces the contiguous cached decoder to float
 tolerance (tests_tpu/test_serving.py pins it at <1e-5 for fp32).
 
-Both step functions are PURE (params and pages in, logits and pages out):
+The step functions are PURE (params and pages in, logits and pages out):
 the engine wraps them in ``compileobs.jit`` with the pool pages donated, so
-each shape bucket compiles exactly once and the pool never copies.
+each shape bucket compiles exactly once. The pages are the pool's
+``(L, N, bs, G, W)`` arrays in whatever row format they were built
+(``KVBlockPool.page_shape``; ``(H, D)`` rows are the r = 1 case): new K/V
+rows are reshaped to ``(G, W)`` before the scatter and attention reads the
+WHOLE pool at a static layer index, so that a lane-dense pool is scattered
+into and read where it lies — ``k_pages[i]`` is a copy of a layer, and a
+pool whose rows are narrower than the 128 lanes is copied whole, in and out
+of every program (tests/test_aot_tpu_compile.py guards both).
 
 Padded-lane safety contract: bucketed steps carry dead lanes (padded batch
 rows, padded prompt tail). Dead lanes write through the block table's
@@ -201,7 +208,7 @@ def prefill(params, tokens, length, block_table, k_pages, v_pages, cfg):
     length:      () int32 — true prompt length (1 <= length <= S)
     block_table: (S // block_size,) int32 — the request's allocated blocks
                  in position order; tail entries past the prompt = 0 (trash)
-    k/v_pages:   the pool pages, (L, N, bs, H, D) — donated by the engine
+    k/v_pages:   the pool pages, (L, N, bs, G, W) — donated by the engine
 
     Returns ``(next_token (1,) int32, logits (1, V), k_pages, v_pages)``:
     every layer's K/V for positions < S scattered into the pool through the
@@ -215,7 +222,7 @@ def prefill(params, tokens, length, block_table, k_pages, v_pages, cfg):
     _, S = tokens.shape
     m, hh = cfg.model_dim, cfg.num_heads
     hd = m // hh
-    bs = k_pages.shape[2]
+    bs, rows, lanes = k_pages.shape[2:]
     prec = fp32_precision(k_pages.dtype)
 
     x = jnp.take(params["embed_weight"], tokens, axis=0)       # (1, S, M)
@@ -231,8 +238,8 @@ def prefill(params, tokens, length, block_table, k_pages, v_pages, cfg):
         qkv = jnp.einsum("bsm,nm->bsn", h, params[p + "_attn_in_weight"],
                          precision=prec)
         q, k, v = jnp.split(qkv, 3, axis=-1)                   # (1, S, M)
-        k_all.append(k.reshape(S, hh, hd))
-        v_all.append(v.reshape(S, hh, hd))
+        k_all.append(k)
+        v_all.append(v)
         attn = flash_attention(split_heads(q), split_heads(k),
                                split_heads(v), True)
         attn = attn.transpose(0, 2, 1, 3).reshape(1, S, m)
@@ -244,8 +251,8 @@ def prefill(params, tokens, length, block_table, k_pages, v_pages, cfg):
 
     # scatter every layer's K/V through the block table (trash entries
     # absorb the padded tail)
-    kw = jnp.stack(k_all).reshape(cfg.num_layers, S // bs, bs, hh, hd)
-    vw = jnp.stack(v_all).reshape(cfg.num_layers, S // bs, bs, hh, hd)
+    kw = jnp.stack(k_all).reshape(cfg.num_layers, S // bs, bs, rows, lanes)
+    vw = jnp.stack(v_all).reshape(cfg.num_layers, S // bs, bs, rows, lanes)
     k_pages = k_pages.at[:, block_table].set(kw.astype(k_pages.dtype))
     v_pages = v_pages.at[:, block_table].set(vw.astype(v_pages.dtype))
 
@@ -281,7 +288,7 @@ def decode(params, tokens, positions, block_tables, context_lens,
     B = tokens.shape[0]
     m, hh = cfg.model_dim, cfg.num_heads
     hd = m // hh
-    bs = k_pages.shape[2]
+    bs, rows, lanes = k_pages.shape[2:]
     prec = fp32_precision(k_pages.dtype)
 
     in_range = positions < cfg.max_len
@@ -303,14 +310,14 @@ def decode(params, tokens, positions, block_tables, context_lens,
                          precision=prec)
         q, k_new, v_new = jnp.split(qkv, 3, axis=-1)           # (B, 1, M)
         q = q.reshape(B, hh, hd)
-        k_new = k_new.reshape(B, hh, hd)
-        v_new = v_new.reshape(B, hh, hd)
+        k_new = k_new.reshape(B, rows, lanes)
+        v_new = v_new.reshape(B, rows, lanes)
         k_pages = k_pages.at[i, page_ids, slots].set(
             k_new.astype(k_pages.dtype))
         v_pages = v_pages.at[i, page_ids, slots].set(
             v_new.astype(v_pages.dtype))
-        attn = paged_attention(q, k_pages[i], v_pages[i], block_tables,
-                               context_lens)                   # (B, H, hd)
+        attn = paged_attention(q, k_pages, v_pages, block_tables,
+                               context_lens, layer=i)          # (B, H, hd)
         attn = attn.reshape(B, 1, m)
         attn = jnp.einsum("bsm,nm->bsn", attn,
                           params[p + "_attn_out_weight"], precision=prec)
@@ -359,7 +366,7 @@ def extend(params, tokens, positions, block_tables, context_lens,
     B, T = tokens.shape
     m, hh = cfg.model_dim, cfg.num_heads
     hd = m // hh
-    bs = k_pages.shape[2]
+    bs, rows, lanes = k_pages.shape[2:]
     prec = fp32_precision(k_pages.dtype)
 
     in_range = positions < cfg.max_len                          # (B, T)
@@ -379,8 +386,8 @@ def extend(params, tokens, positions, block_tables, context_lens,
                          precision=prec)
         q, k_new, v_new = jnp.split(qkv, 3, axis=-1)            # (B, T, M)
         q = q.reshape(B, T, hh, hd)
-        k_new = k_new.reshape(B, T, hh, hd)
-        v_new = v_new.reshape(B, T, hh, hd)
+        k_new = k_new.reshape(B, T, rows, lanes)
+        v_new = v_new.reshape(B, T, rows, lanes)
         # window lanes write their K/V first (distinct slots per lane;
         # overflow lanes pile into trash), then every lane reads back
         # under its OWN context length — lane t cannot see lanes > t
@@ -388,8 +395,8 @@ def extend(params, tokens, positions, block_tables, context_lens,
             k_new.astype(k_pages.dtype))
         v_pages = v_pages.at[i, page_ids, slots].set(
             v_new.astype(v_pages.dtype))
-        attn = paged_attention_multi(q, k_pages[i], v_pages[i],
-                                     block_tables, context_lens)
+        attn = paged_attention_multi(q, k_pages, v_pages, block_tables,
+                                     context_lens, layer=i)
         attn = attn.reshape(B, T, m)
         attn = jnp.einsum("btm,nm->btn", attn,
                           params[p + "_attn_out_weight"], precision=prec)

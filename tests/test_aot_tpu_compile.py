@@ -6,6 +6,9 @@
   refuses. Also through the public entry points: a TPU lowering made from
   this CPU-default process must hold the kernel (``tpu_custom_call``), not
   the reference lowering.
+* The serving programs leave the KV pool where it lies: over a pool from
+  ``KVBlockPool.page_shape`` the compiled ``decode`` and ``prefill`` hold no
+  copy and no slice the size of the pool or of a layer of it.
 * ``chip_smoke.py`` fails fast and says so when jax has no TPU.
 * The compile-cache directory is decided by the environment, then by one
   fixed path — never by the process.
@@ -16,7 +19,9 @@ The file name sorts first on purpose: tier-1 runs against a clock.
 """
 import functools
 import json
+import math
 import os
+import re
 import subprocess
 import sys
 
@@ -63,14 +68,16 @@ def _flash_shapes(chip, d):
                                  sharding=chip)] * 3
 
 
-def _paged_shapes(chip, h, d, dtype, lanes=None):
-    """Serving shapes: B=32 streams, block size 16, 64 table slots."""
+def _paged_shapes(chip, h, d, dtype, lanes=None, rows=None, layers=None):
+    """Serving shapes: B=32 streams, block size 16, 64 table slots; page
+    rows ``(h, d)`` unless given, the whole pool if ``layers``."""
     def s(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
 
     q = (32, h, d) if lanes is None else (32, lanes, h, d)
     ctx = (32,) if lanes is None else (32, lanes)
-    pages = s((2049, 16, h, d), dtype)
+    pages = s(((layers,) if layers else ()) + (2049, 16) + (rows or (h, d)),
+              dtype)
     return (s(q, dtype), pages, pages, s((32, 64), jnp.int32),
             s(ctx, jnp.int32))
 
@@ -96,12 +103,98 @@ def test_flash_backward_compiles_for_v5e(v5e, d):
 @pytest.mark.parametrize("lanes", [None, 5], ids=["decode", "verify"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["fp32", "bf16"])
-@pytest.mark.parametrize("h,d", [(12, 64), (16, 128)])
-def test_paged_kernels_compile_for_v5e(v5e, h, d, dtype, lanes):
+@pytest.mark.parametrize("h,d,rows,layers", [
+    (12, 64, None, None), (16, 128, None, None),
+    (16, 64, (8, 128), None), (16, 64, (8, 128), 2)],
+    ids=["12x64", "16x128", "16x64-packed", "16x64-packed-pool"])
+def test_paged_kernels_compile_for_v5e(v5e, h, d, rows, layers, dtype, lanes):
     kernel = A._paged_pallas if lanes is None else A._paged_pallas_multi
-    text = _compiled_text(functools.partial(kernel, sm_scale=0.125),
-                          *_paged_shapes(v5e, h, d, dtype, lanes))
+    text = _compiled_text(
+        functools.partial(kernel, sm_scale=0.125,
+                          layer=1 if layers else None),
+        *_paged_shapes(v5e, h, d, dtype, lanes, rows, layers))
     assert "tpu_custom_call" in text
+
+
+# --------------------------------------------------- the pool stays put
+def _serving_program(chip, name, page):
+    """``model.decode`` (B=32) or ``model.prefill`` (S=512) at the
+    benchmark's widths cut to 2 layers, pool donated, compiled for the
+    chip. Returns (compiled, pool shape)."""
+    from mxnet_tpu.serving import model as M
+
+    def s(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(tuple(shape), dt, sharding=chip)
+
+    cfg = M.ModelConfig(50257, 2, 1024, 16, 4096, 1024)
+    params = {k: s(v, jnp.float32) for k, v in M.param_shapes(cfg).items()}
+    pool = s((cfg.num_layers, 513, 16) + page, jnp.float32)
+    if name == "decode":
+        fn, donate = M.decode, (5, 6)
+        args = (s((32,)), s((32,)), s((32, 64)), s((32,)))
+    else:
+        fn, donate = M.prefill, (4, 5)
+        args = (s((1, 512)), s(()), s((512 // 16,)))
+    jitted = jax.jit(functools.partial(fn, cfg=cfg), donate_argnums=donate)
+    return jitted.lower(params, *args, pool, pool).compile(), pool.shape
+
+
+def _pool_copies(text, pool_shape):
+    """The compiled program's copies and slices whose result is the pool or
+    one layer of it: ``(op name, shape)`` pairs."""
+    dims = [",".join(map(str, sh)) for sh in
+            (pool_shape, pool_shape[1:], (1,) + pool_shape[1:])]
+    hits = []
+    for m in re.finditer(r"^\s*(?:ROOT )?%([\w.\-]+) = \(*(f32\[([\d,]*)\]"
+                         r"\{[^}]*\})", text, re.M):
+        name, shape, d = m.groups()
+        if d in dims and re.search("copy|slice", name) \
+                and "update" not in name:
+            hits.append((name, shape))
+    return hits
+
+
+def _entry_layouts(text, pool_shape):
+    entry = next(l for l in text.splitlines()
+                 if "entry_computation_layout" in l)
+    return set(re.findall(
+        r"f32\[%s\]\{([\d,]*)" % ",".join(map(str, pool_shape)), entry))
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_serving_programs_leave_the_pool_in_place(v5e, name):
+    """The guard. A pool whose rows fill the 128 lanes has ONE layout — the
+    device's default, the scatter's and the kernel's — so a program takes
+    the donated pool, scatters into it and hands it back: no copy, no
+    per-layer slice, next to nothing in temporaries. (Rows of 64 lanes:
+    four copies of the padded pool a program, three times the pool in
+    temporaries; the last test shows this check sees them.)"""
+    from mxnet_tpu.serving.kv_cache import KVBlockPool
+
+    page = KVBlockPool.page_shape(16, 64)
+    assert page == (8, 128)
+    compiled, pool_shape = _serving_program(v5e, name, page)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert _pool_copies(text, pool_shape) == []
+    assert _entry_layouts(text, pool_shape) == {"4,3,2,1,0"}
+    pool_bytes = 2 * 4 * math.prod(pool_shape)        # K and V, fp32
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < pool_bytes / 10
+    assert ma.alias_size_in_bytes >= pool_bytes      # donated, taken
+
+
+def test_pool_guard_sees_the_copies_of_a_half_lane_pool(v5e):
+    """The same decode program over ``(H, D) = (16, 64)`` rows, as every
+    pool was built before PR 25: the guard's checks all fire."""
+    compiled, pool_shape = _serving_program(v5e, "decode", (16, 64))
+    text = compiled.as_text()
+    copies = [n for n, _s in _pool_copies(text, pool_shape)
+              if n.startswith("copy")]
+    assert len(copies) >= 4, copies
+    assert _entry_layouts(text, pool_shape) == {"1,4,3,2,0"}
+    pool_bytes = 2 * 4 * math.prod(pool_shape)
+    assert compiled.memory_analysis().temp_size_in_bytes > 2 * pool_bytes
 
 
 def _flash_grad(q, k, v):

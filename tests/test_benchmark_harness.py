@@ -4,6 +4,8 @@ of this module, so the reducer the per-layer readers stand on is tested
 with everything else."""
 import pytest
 
-pytest.register_assert_rewrite("benchmark.tests.test_benchmark_harness")
+pytest.register_assert_rewrite("benchmark.tests.test_benchmark_harness",
+                               "benchmark.tests.test_pool_copy_share")
 
 from benchmark.tests.test_benchmark_harness import *  # noqa: E402,F401,F403
+from benchmark.tests.test_pool_copy_share import *  # noqa: E402,F401,F403

@@ -1,0 +1,213 @@
+"""The lane-dense KV page format on the CPU: ``KVBlockPool.page_shape``
+puts ``r`` heads side by side in one page row, and everything that touches a
+page — the references, the Pallas kernels (interpret mode), the model's
+scatter, copy-on-write, the prefix index, the engine — gives what the plain
+``(H, D)`` row gives. What the chip's compiler makes of the format is
+``tests/test_aot_tpu_compile.py``'s.
+"""
+import numpy as np
+import pytest
+
+import jax.numpy as jnp
+
+from mxnet_tpu.ops import attention as A
+from mxnet_tpu.serving import ServingConfig, ServingEngine
+from mxnet_tpu.serving import model as smodel
+from mxnet_tpu.serving.kv_cache import KVBlockPool
+
+# r -> (heads, head_dim) that page_shape packs r to a row
+HEADS = {1: (3, 32), 2: (4, 64), 4: (4, 32)}
+DTYPES = {"fp32": (jnp.float32, 1e-6), "bf16": (jnp.bfloat16, 2e-2)}
+
+
+@pytest.mark.parametrize("heads,head_dim,want", [
+    (16, 64, (8, 128)),      # GPT-2 medium: two heads a row
+    (12, 64, (6, 128)),
+    (4, 32, (1, 128)),       # four heads a row
+    (2, 64, (1, 128)),       # the "small" draft preset
+    (2, 32, (2, 32)),        # the "tiny" draft preset: 2 % 4 != 0
+    (25, 64, (25, 64)),      # GPT-2 XL's odd head count
+    (3, 32, (3, 32)),
+    (16, 128, (16, 128)),    # already lane-dense
+    (8, 256, (8, 256)),
+    (4, 48, (4, 48)),        # 128 % 48 != 0
+])
+def test_page_shape(heads, head_dim, want):
+    assert KVBlockPool.page_shape(heads, head_dim) == want
+    pool = KVBlockPool(2, 3, 4, heads, head_dim)
+    assert pool.k_pages.shape == (2, 3, 4) + want == pool.v_pages.shape
+    assert pool.heads_per_row == heads // want[0]
+    assert pool.nbytes() == 2 * pool.k_pages.size * 4
+
+
+def _rand(r, dtype, lanes, whole_pool, seed=0, B=3, bs=16, N=7, nb=3, L=3):
+    """q (B, T, H, D) with pages packed r heads to a row, as one layer's
+    4-D pages or the 5-D pool with a layer index."""
+    rng = np.random.RandomState(seed)
+    H, D = HEADS[r]
+    q = rng.randn(B, lanes, H, D)
+    shape = ((L,) if whole_pool else ()) + (N, bs, H // r, D * r)
+    kp, vp = rng.randn(*shape), rng.randn(*shape)
+    bt = rng.randint(1, N, (B, nb)).astype(np.int32)
+    cl = rng.randint(0, nb * bs + 1, (B, lanes)).astype(np.int32)
+    cl[0, 0] = 0                       # an empty lane reads exact zeros
+    cl[-1, -1] = nb * bs               # and a full one reads every slot
+    q, kp, vp = (jnp.asarray(x, dtype) for x in (q, kp, vp))
+    return q, kp, vp, jnp.asarray(bt), jnp.asarray(cl), (1 if whole_pool
+                                                         else None)
+
+
+def _unpacked(pages, r):
+    H, D = HEADS[r]
+    return pages.reshape(pages.shape[:-2] + (H, D))
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("r", HEADS)
+def test_packed_reference_is_the_unpacked_reference(r, dtype):
+    """Packing is a reshape: bit for bit, single- and multi-query, one
+    layer's pages and the whole pool."""
+    for whole_pool in (False, True):
+        q, kp, vp, bt, cl, layer = _rand(r, DTYPES[dtype][0], 3, whole_pool)
+        got = A.paged_attention_multi_reference(q, kp, vp, bt, cl,
+                                                layer=layer)
+        want = A.paged_attention_multi_reference(
+            q, _unpacked(kp, r), _unpacked(vp, r), bt, cl, layer=layer)
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+        one = A.paged_attention_reference(q[:, 0], kp, vp, bt, cl[:, 0],
+                                          layer=layer)
+        want = A.paged_attention_reference(
+            q[:, 0], _unpacked(kp, r), _unpacked(vp, r), bt, cl[:, 0],
+            layer=layer)
+        np.testing.assert_array_equal(np.asarray(one, np.float32),
+                                      np.asarray(want, np.float32))
+        assert not np.asarray(one[0], np.float32).any()
+
+
+@pytest.mark.parametrize("whole_pool", [False, True], ids=["4d", "5d-layer"])
+@pytest.mark.parametrize("lanes", [None, 3], ids=["decode", "verify"])
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("r", HEADS)
+def test_pallas_interpret_matches_reference(r, dtype, lanes, whole_pool):
+    """The kernel program the TPU runs, interpreted on the CPU."""
+    dt, tol = DTYPES[dtype]
+    q, kp, vp, bt, cl, layer = _rand(r, dt, lanes or 1, whole_pool, seed=r)
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    if lanes is None:
+        q, cl = q[:, 0], cl[:, 0]
+        got = A._paged_pallas(q, kp, vp, bt, cl, scale, layer=layer,
+                              interpret=True)
+        want = A.paged_attention_reference(q, kp, vp, bt, cl, layer=layer)
+    else:
+        got = A._paged_pallas_multi(q, kp, vp, bt, cl, scale, layer=layer,
+                                    interpret=True)
+        want = A.paged_attention_multi_reference(q, kp, vp, bt, cl,
+                                                 layer=layer)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+    assert not np.asarray(got[0], np.float32).reshape(
+        -1, q.shape[-1])[:q.shape[-2]].any(), "empty lane must read zeros"
+
+
+def test_pages_must_hold_the_heads():
+    q, kp, vp, bt, cl, _ = _rand(2, jnp.float32, 1, False)
+    with pytest.raises(ValueError, match="cannot hold"):
+        A.paged_attention_multi(q[:, :, :3], kp, vp, bt, cl)
+    with pytest.raises(ValueError, match="layer="):
+        A._paged_pallas_multi(q, kp[None], vp[None], bt, cl, 0.125)
+
+
+def _lm(r):
+    H, D = HEADS[r]
+    return dict(vocab_size=29, num_layers=2, model_dim=H * D, num_heads=H,
+                ffn_dim=48, max_len=64)
+
+
+def _greedy(cfg, params, prompt, n, pages):
+    """Prompt + n greedy tokens through ``model.prefill`` / ``decode`` over
+    the given pool pages, one stream on blocks 1.."""
+    bs = pages[0].shape[2]
+    nb = cfg.max_len // bs
+    table = np.arange(1, nb + 1, dtype=np.int32)
+    S = -(-len(prompt) // bs) * bs
+    toks = np.zeros((1, S), np.int32)
+    toks[0, :len(prompt)] = prompt
+    nxt, logits, kp, vp = smodel.prefill(
+        params, toks, np.int32(len(prompt)), table[:S // bs], *pages, cfg)
+    out, all_logits = [int(nxt[0])], [np.asarray(logits[0])]
+    for t in range(len(prompt), len(prompt) + n - 1):
+        nxt, logits, kp, vp = smodel.decode(
+            params, np.array(out[-1:], np.int32), np.array([t], np.int32),
+            table[None], np.array([t + 1], np.int32), kp, vp, cfg)
+        out.append(int(nxt[0]))
+        all_logits.append(np.asarray(logits[0]))
+    return out, np.stack(all_logits)
+
+
+@pytest.mark.parametrize("r", HEADS)
+def test_model_reads_the_format_off_its_pages(r):
+    """``prefill`` and ``decode`` over a pool from ``page_shape`` and over
+    pages built ``(H, D)`` by hand: the same logits bit for bit."""
+    cfg = smodel.ModelConfig(**_lm(r))
+    H, D = HEADS[r]
+    params = smodel.as_device_params(smodel.random_params(cfg, seed=5), cfg)
+    prompt = list(np.random.RandomState(r).randint(0, cfg.vocab_size, 11))
+    packed = KVBlockPool(cfg.num_layers, 9, 8, H, D)
+    assert packed.heads_per_row == r
+    plain = jnp.zeros((cfg.num_layers, 9, 8, H, D), jnp.float32)
+    got, got_logits = _greedy(cfg, params, prompt, 6,
+                              (packed.k_pages, packed.v_pages))
+    want, want_logits = _greedy(cfg, params, prompt, 6, (plain, plain))
+    assert got == want
+    np.testing.assert_array_equal(got_logits, want_logits)
+
+
+@pytest.mark.parametrize("r", HEADS)
+def test_engine_serves_and_reports_the_format(r):
+    """An engine over each format — r = 1 here is an odd head count — emits
+    the tokens the model emits over hand-built pages, through a prefix hit
+    and a copy-on-write, and says which format it runs."""
+    H, D = HEADS[r]
+    cfg = ServingConfig(block_size=8, num_blocks=32, max_batch=4,
+                        prefills_per_step=1, prefix_cache=True, **_lm(r))
+    eng = ServingEngine(cfg, seed=5)
+    st = eng.stats()
+    assert st["kv_heads_per_row"] == r
+    assert st["kv_page_shape"] == list(KVBlockPool.page_shape(H, D))
+    assert st["kv_page_shape"] == ([H // r, 128] if r > 1 else [H, D])
+    shared = list(range(1, 17))                 # two full blocks
+    prompts = [shared + tail for tail in ([], [17], [18, 19])]
+    reqs = [eng.submit(p, 6) for p in prompts]
+    while any(not q.finished() for q in reqs):
+        eng.step()
+    assert eng.pool.prefix_stats()["hits"] >= 2
+    plain = jnp.zeros((cfg.num_layers, 9, 8, H, D), jnp.float32)
+    for p, q in zip(prompts, reqs):
+        assert list(q.generated) == _greedy(cfg, eng.params, p, 6,
+                                            (plain, plain))[0]
+    assert eng.pool.used() == 0
+
+
+@pytest.mark.parametrize("r", [2, 4])
+def test_cow_and_prefix_hit_on_a_packed_pool(r):
+    H, D = HEADS[r]
+    pool = KVBlockPool(2, 9, 4, H, D)
+    (b,) = pool.alloc(1)
+    rng = np.random.RandomState(0)
+    kv = rng.randn(2, 4, H, D).astype(np.float32)
+    rows = kv.reshape((2, 4) + KVBlockPool.page_shape(H, D))
+    pool.k_pages = pool.k_pages.at[:, b].set(rows)
+    pool.v_pages = pool.v_pages.at[:, b].set(2.0 * rows)
+    tokens = [5, 6, 7, 8, 9]
+    assert pool.prefix_insert(tokens, [b]) == 1
+    assert pool.prefix_match(tokens) == [b] and pool.refcount(b) == 2
+    nb = pool.cow(b)
+    assert nb != b and pool.refcount(b) == pool.refcount(nb) == 1
+    for blk in (b, nb):       # a block's bytes are the (H, D) rows' bytes
+        np.testing.assert_array_equal(
+            np.asarray(pool.k_pages[:, blk]).reshape(kv.shape), kv)
+        np.testing.assert_array_equal(
+            np.asarray(pool.v_pages[:, blk]).reshape(kv.shape), 2.0 * kv)
