@@ -47,7 +47,11 @@ _engine_ids = itertools.count()
 
 class ServingConfig(_model.ModelConfig):
     """Model shape + engine knobs. Engine knobs default from the
-    ``MXNET_SERVING_*`` environment (docs/env_var.md)."""
+    ``MXNET_SERVING_*`` environment (docs/env_var.md); ``**arch`` are
+    :class:`~.model.ModelConfig`'s structural fields (norm, pos,
+    rope_theta, qk_norm, head_dim, num_experts, experts_per_tok, bias),
+    passed through — they feed ``key()`` and so every graph and compile
+    cache key."""
 
     __slots__ = ("block_size", "num_blocks", "max_batch",
                  "prefills_per_step", "kv_dtype", "prefix_cache",
@@ -58,9 +62,9 @@ class ServingConfig(_model.ModelConfig):
                  block_size=None, num_blocks=None, max_batch=None,
                  prefills_per_step=None, kv_dtype=np.float32,
                  prefix_cache=None, spec_k=None, draft=None,
-                 max_queue=None, default_timeout_ms=None):
+                 max_queue=None, default_timeout_ms=None, **arch):
         super().__init__(vocab_size, num_layers, model_dim, num_heads,
-                         ffn_dim, max_len)
+                         ffn_dim, max_len, **arch)
         self.block_size = int(block_size if block_size is not None
                               else env_int("MXNET_SERVING_BLOCK_SIZE", 16))
         self.num_blocks = int(num_blocks if num_blocks is not None
@@ -109,6 +113,22 @@ class ServingConfig(_model.ModelConfig):
                 "prefill buckets and the decode block table are sized in "
                 "whole blocks" % (self.max_len, self.block_size))
 
+    @classmethod
+    def from_json(cls, obj):
+        """From the ``model`` and ``engine`` objects of a configuration
+        file (``benchmark/configs/*.json``, ``tools/serve.py
+        --model-config``): ``model`` holds the model fields under their
+        own names (``vocab`` for ``vocab_size``), ``engine`` the engine
+        knobs; a null or absent knob takes its default."""
+        model = dict(obj["model"])
+        model["vocab_size"] = model.pop("vocab")
+        engine = {k: v for k, v in obj["engine"].items() if v is not None}
+        if "kv_dtype" in engine:
+            import jax.numpy as jnp
+
+            engine["kv_dtype"] = jnp.dtype(engine["kv_dtype"])
+        return cls(**model, **engine)
+
     def decode_buckets(self):
         """Padded decode batch sizes: powers of two up to max_batch."""
         out = []
@@ -128,6 +148,48 @@ class ServingConfig(_model.ModelConfig):
             s *= 2
         out.append(self.max_len)
         return out
+
+
+def _pack_fetch(tokens, logits, k_pages, v_pages, load=None):
+    """A step program's FOUR results, whatever the block: a config with
+    experts appends the step's per-layer ``tokens_per_expert`` (L, E) to
+    the next-token vector, so that the load comes back in the one fetch
+    the step blocks on anyway (:func:`_unpack_fetch` splits it)."""
+    if load is None:
+        return tokens, logits, k_pages, v_pages
+    import jax.numpy as jnp
+
+    return (jnp.concatenate([tokens.reshape(-1), load.reshape(-1)]),
+            logits, k_pages, v_pages)
+
+
+def _unpack_fetch(fetched, shape, cfg):
+    """``(next tokens of shape, load (L, E) or None)`` from the fetched
+    vector of :func:`_pack_fetch`."""
+    n = int(np.prod(shape))
+    load = (fetched[n:].reshape(cfg.num_layers, cfg.num_experts)
+            if cfg.num_experts else None)
+    return fetched[:n].reshape(shape), load
+
+
+def _moe_args(load):
+    """The fetch spans' arguments for a step with experts."""
+    if load is None:
+        return {}
+    return {"pairs": int(load.sum()),
+            "experts_touched": int((load > 0).sum())}
+
+
+def _max_over_mean(load):
+    """Busiest expert's tokens over the mean expert's, per layer, averaged
+    over the layers of ``load`` (L, E); 0.0 for an empty step."""
+    if not load.size:
+        return 0.0
+    mean = load.mean(axis=1)
+    live = mean > 0
+    if not live.any():
+        return 0.0
+    return float((load.max(axis=1)[live] / mean[live]).mean())
 
 
 def _bucket_for(n, buckets):
@@ -150,7 +212,7 @@ class ServingEngine:
         self.params = _model.as_device_params(arg_params, cfg, device=device)
         self.pool = KVBlockPool(cfg.num_layers, cfg.num_blocks,
                                 cfg.block_size, cfg.num_heads,
-                                cfg.model_dim // cfg.num_heads,
+                                cfg.head_dim,
                                 dtype=cfg.kv_dtype, device=device,
                                 prefix_cache=cfg.prefix_cache)
         # speculative decoding writes spec_k+1 window slots per step, so
@@ -195,6 +257,13 @@ class ServingEngine:
         self._n_cancelled = 0
         self._n_shed = 0
         self._token_window = []   # one timestamp per token, for tokens/sec
+        # routed experts: per-layer, per-expert tokens since the engine
+        # started (the step's own (L, E) count rides in the token fetch)
+        self._moe_load = np.zeros((cfg.num_layers, cfg.num_experts),
+                                  np.int64)
+        self._moe_layer_steps = 0
+        self._moe_layer_tokens = 0
+        self._moe_touched = 0
         self._t_started = time.time()
         self._tokens_total = 0
         # per-engine identity: labels this engine's histograms/counters in
@@ -227,16 +296,17 @@ class ServingEngine:
         def _mk_prefill():
             def _prefill(params, tokens, length, block_table,
                          k_pages, v_pages):
-                return _model.prefill(params, tokens, length, block_table,
-                                      k_pages, v_pages, cfg)
+                return _pack_fetch(*_model.prefill(
+                    params, tokens, length, block_table, k_pages, v_pages,
+                    cfg))
             return _prefill
 
         def _mk_decode():
             def _decode(params, tokens, positions, block_tables,
                         context_lens, k_pages, v_pages):
-                return _model.decode(params, tokens, positions,
-                                     block_tables, context_lens,
-                                     k_pages, v_pages, cfg)
+                return _pack_fetch(*_model.decode(
+                    params, tokens, positions, block_tables, context_lens,
+                    k_pages, v_pages, cfg))
             return _decode
 
         if donate:
@@ -309,8 +379,8 @@ class ServingEngine:
             import jax.numpy as jnp
 
             dshape = (dcfg.num_layers, cfg.num_blocks, cfg.block_size
-                      ) + KVBlockPool.page_shape(
-                          dcfg.num_heads, dcfg.model_dim // dcfg.num_heads)
+                      ) + KVBlockPool.page_shape(dcfg.num_heads,
+                                                 dcfg.head_dim)
             dk = jnp.zeros(dshape, cfg.kv_dtype)
             dv = jnp.zeros(dshape, cfg.kv_dtype)
             if device is not None:
@@ -321,25 +391,25 @@ class ServingEngine:
             def _mk_draft_prefill():
                 def _dprefill(params, tokens, length, block_table,
                               k_pages, v_pages):
-                    return _model.prefill(params, tokens, length,
-                                          block_table, k_pages, v_pages,
-                                          dcfg)
+                    return _pack_fetch(*_model.prefill(
+                        params, tokens, length, block_table, k_pages,
+                        v_pages, dcfg))
                 return _dprefill
 
             def _mk_draft_decode():
                 def _ddecode(params, tokens, positions, block_tables,
                              context_lens, k_pages, v_pages):
-                    return _model.decode(params, tokens, positions,
-                                         block_tables, context_lens,
-                                         k_pages, v_pages, dcfg)
+                    return _pack_fetch(*_model.decode(
+                        params, tokens, positions, block_tables,
+                        context_lens, k_pages, v_pages, dcfg))
                 return _ddecode
 
             def _mk_verify():
                 def _verify(params, tokens, positions, block_tables,
                             context_lens, k_pages, v_pages):
-                    return _model.extend(  # fwlint: disable=trace-impure — module-level verify-step function, not a container mutation
+                    return _pack_fetch(*_model.extend(  # fwlint: disable=trace-impure — module-level verify-step function, not a container mutation
                         params, tokens, positions, block_tables,
-                        context_lens, k_pages, v_pages, cfg)
+                        context_lens, k_pages, v_pages, cfg))
                 return _verify
 
             dkey_base = dcfg.key() + (cfg.block_size, cfg.num_blocks,
@@ -708,14 +778,24 @@ class ServingEngine:
             self._work.notify_all()
         return req
 
-    def warmup(self):
+    def warmup(self, prefill_buckets=None):
         """Compile every prefill length bucket and decode batch bucket in
         one pass (one throwaway dispatch each, all-trash block tables, no
         requests involved) so the first real traffic pays zero compile
-        wall and the steady-state compile count is flat from step one."""
+        wall and the steady-state compile count is flat from step one.
+        ``prefill_buckets`` warms only those prompt-length buckets (a
+        deployment whose lengths cannot reach the others); the decode
+        buckets are always all warmed. The dispatches are asynchronous:
+        the pool's pages are ready when the last program has run."""
         cfg = self.config
+        if prefill_buckets is None:
+            prefill_buckets = cfg.prefill_buckets()
+        unknown = sorted(set(prefill_buckets) - set(cfg.prefill_buckets()))
+        if unknown:
+            raise ValueError("no prefill bucket %s (buckets %s)"
+                             % (unknown, cfg.prefill_buckets()))
         with self._lock:
-            for S in cfg.prefill_buckets():
+            for S in prefill_buckets:
                 toks = np.zeros((1, S), np.int32)
                 table = np.zeros(S // cfg.block_size, np.int32)
                 _t, _l, kp, vp = self._prefill_fn(
@@ -734,7 +814,7 @@ class ServingEngine:
             if self._spec:
                 # spec adds three program families — warm them too or the
                 # first spec step pays draft + verify compile wall at once
-                for S in cfg.prefill_buckets():
+                for S in prefill_buckets:
                     toks = np.zeros((1, S), np.int32)
                     table = np.zeros(S // cfg.block_size, np.int32)
                     _t, _l, dkp, dvp = self._draft_prefill_fn(
@@ -758,6 +838,30 @@ class ServingEngine:
                         self.params, toks2, poss2, tables, ctx2,
                         self.pool.k_pages, self.pool.v_pages)
                     self.pool.k_pages, self.pool.v_pages = kp, vp
+
+    def prefill_logits(self, tokens):
+        """The model's next-token logits ``(V,)`` float32 after ``tokens``,
+        from the prefill program of their length bucket: the served
+        arithmetic (types, kernels, experts) on a text of the caller's
+        choice — to score a fixed text, or to hold served logits against a
+        reference. No request is involved and nothing is cached or booked:
+        the program's K/V writes go to the trash block through an all-zero
+        table, as :meth:`warmup`'s do."""
+        cfg = self.config
+        n = len(tokens)
+        if not 1 <= n <= cfg.max_len:
+            raise ValueError("%d tokens: need 1..max_len (%d)"
+                             % (n, cfg.max_len))
+        S = _bucket_for(n, cfg.prefill_buckets())
+        toks = np.zeros((1, S), np.int32)
+        toks[0, :n] = tokens
+        table = np.zeros(S // cfg.block_size, np.int32)
+        with self._lock:
+            _t, logits, kp, vp = self._prefill_fn(
+                self.params, toks, np.int32(n), table,
+                self.pool.k_pages, self.pool.v_pages)
+            self.pool.k_pages, self.pool.v_pages = kp, vp
+        return np.asarray(logits, np.float32)[0]  # fwlint: disable=device-escape — the logits are what the caller asked for
 
     def generate(self, prompts, max_new_tokens, eos_id=None, timeout_s=None):
         """Convenience batch API: submit every prompt, drive steps until
@@ -875,12 +979,15 @@ class ServingEngine:
                     self._draft_params, toks, np.int32(L), write_table,
                     self._draft_kp, self._draft_vp)
                 self._draft_kp, self._draft_vp = dkp, dvp
-        with telemetry.span("serving.prefill.fetch", _CAT, **args):
+        with telemetry.span("serving.prefill.fetch", _CAT, **args) as fetch:
             # the per-step token egress: serving's output IS this transfer
-            tok = int(np.asarray(tok)[0])  # fwlint: disable=device-escape — token egress to the client is the product, one scalar per prefill
+            tok, load = _unpack_fetch(np.asarray(tok), (1,), cfg)  # fwlint: disable=device-escape — token egress to the client is the product, one scalar per prefill (+ the experts' load in the same array)
+            tok = int(tok[0])
+            fetch.set(**_moe_args(load))
         wall = time.time() - t0
         with telemetry.span("serving.retire", _CAT,
                             request_id=req.request_id):
+            self._note_moe(load, L)
             c1, s1 = jit.compile_totals()
             s1 += self._draft_prefill_jits[S].compile_totals()[1] \
                 if self._spec else 0.0
@@ -933,17 +1040,23 @@ class ServingEngine:
                 self.params, toks, poss, tables, ctx,
                 self.pool.k_pages, self.pool.v_pages)
             self.pool.k_pages, self.pool.v_pages = kp, vp
-        with telemetry.span("serving.decode.fetch", _CAT, **args):
+        with telemetry.span("serving.decode.fetch", _CAT, **args) as fetch:
             # the fused step's single device->host sync: the next-token
-            # vector
-            nxt = np.asarray(nxt)  # fwlint: disable=device-escape — token egress to clients is the product, B int32s per step
+            # vector (with the experts' load behind it, where there are
+            # experts)
+            fetched = _unpack_fetch(np.asarray(nxt), (B,), cfg)  # fwlint: disable=device-escape — token egress to clients is the product, B int32s per step
+            fetch.set(**_moe_args(fetched[1]))
         wall = time.time() - t0
         c1, s1 = jit.compile_totals()
         if c1 > c0:
             self.obs.decode_stall(reqs, min(s1 - s0, wall))
-        return nxt
+        return fetched
 
-    def _note_decode(self, reqs, nxt):
+    def _note_decode(self, reqs, fetched):
+        nxt, load = fetched
+        if load is not None:
+            self._note_moe(load, sum(req.context_len < self.config.max_len
+                                     for req in reqs))
         telemetry.histogram("serving.decode_batch").observe(len(reqs))
         for i, req in enumerate(reqs):
             req.context_len += 1
@@ -1030,7 +1143,7 @@ class ServingEngine:
                 if j < k:
                     # the proposal steers the NEXT inner step's input
                     # token — an unavoidable per-draft-step sync, B int32s
-                    dnxt = np.asarray(dnxt)  # fwlint: disable=device-escape — draft proposals feed the next inner draft step, B int32s per step
+                    dnxt = np.asarray(dnxt)[:B]  # fwlint: disable=device-escape — draft proposals feed the next inner draft step, B int32s per step (a draft with experts has its load behind them: not booked)
                     for i in range(n):
                         proposals[i].append(int(dnxt[i]))
                         cur[i] = dnxt[i]
@@ -1063,8 +1176,9 @@ class ServingEngine:
                 self.pool.k_pages, self.pool.v_pages)
             self.pool.k_pages, self.pool.v_pages = kp, vp
         with telemetry.span("serving.decode.fetch", _CAT, phase="verify",
-                            **args):
-            nxt2 = np.asarray(nxt2)  # fwlint: disable=device-escape — token egress to clients is the product, B×(k+1) int32s per step
+                            **args) as fetch:
+            nxt2, load = _unpack_fetch(np.asarray(nxt2), (B, T), cfg)  # fwlint: disable=device-escape — token egress to clients is the product, B×(k+1) int32s per step
+            fetch.set(**_moe_args(load))
         verify_wall = time.time() - t0
         c1, s1 = vjit.compile_totals()
         verify_stall = min(s1 - s0, verify_wall) if c1 > c0 else 0.0
@@ -1073,7 +1187,7 @@ class ServingEngine:
         self._spec_draft_s += draft_wall
         self._spec_verify_s += verify_wall
         return (nxt2, proposals, draft_wall - draft_stall,
-                verify_wall - verify_stall)
+                verify_wall - verify_stall, load)
 
     def _note_spec_decode(self, reqs, fetched):
         """Greedy acceptance — emit the TARGET's token at every reached
@@ -1081,8 +1195,12 @@ class ServingEngine:
         MATCHED the target's lane-j output (the window's K/V past a
         mismatch encodes the draft's wrong token, so stop there; the
         stale writes are overwritten by the next step's lane 0)."""
-        nxt2, proposals, draft_s, verify_s = fetched
+        nxt2, proposals, draft_s, verify_s, load = fetched
         k = self.spec_k
+        if load is not None:
+            self._note_moe(load, sum(
+                min(k + 1, max(0, self.config.max_len - req.context_len))
+                for req in reqs))
         proposed = accepted = 0
         for i, req in enumerate(reqs):
             proposed += k
@@ -1100,6 +1218,28 @@ class ServingEngine:
         self._spec_accepted += accepted
         telemetry.histogram("serving.decode_batch").observe(len(reqs))
         self.obs.spec_step(reqs, draft_s, verify_s, proposed, accepted)
+
+    def _note_moe(self, load, tokens):
+        """Book one program's per-layer ``tokens_per_expert`` (L, E):
+        pairs computed, layer-steps, the ``tokens`` live lanes the engine
+        sent through each layer (counted here, on the host, so that
+        ``pairs == experts_per_tok x layer_tokens`` checks the program),
+        experts with at least one token, and how uneven the last step was.
+        None (no experts): nothing."""
+        if load is None:
+            return
+        self._moe_load += load
+        self._moe_layer_steps += load.shape[0]
+        self._moe_layer_tokens += tokens * load.shape[0]
+        touched = int((load > 0).sum())
+        self._moe_touched += touched
+        telemetry.counter("serving.moe.pairs").inc(int(load.sum()))
+        telemetry.counter("serving.moe.layer_steps").inc(load.shape[0])
+        telemetry.counter("serving.moe.layer_tokens").inc(
+            tokens * load.shape[0])
+        telemetry.counter("serving.moe.experts_touched").inc(touched)
+        telemetry.gauge("serving.moe.load_max_over_mean").set(
+            _max_over_mean(load))
 
     def _note_token(self, req, tok):
         now = time.time()
@@ -1201,6 +1341,17 @@ class ServingEngine:
                     "draft_seconds": round(self._spec_draft_s, 6),
                     "verify_seconds": round(self._spec_verify_s, 6),
                 },
+                # only for a model with experts
+                **({"moe": {
+                    "num_experts": self.config.num_experts,
+                    "experts_per_tok": self.config.experts_per_tok,
+                    "pairs": int(self._moe_load.sum()),
+                    "layer_steps": self._moe_layer_steps,
+                    "layer_tokens": self._moe_layer_tokens,
+                    "experts_touched": self._moe_touched,
+                    "load_max_over_mean": _max_over_mean(self._moe_load),
+                    "tokens_per_expert": self._moe_load.tolist(),
+                }} if self.config.num_experts else {}),
                 "slo": self.obs.slo_snapshot(),
                 "phases": self.obs.phase_snapshot(),
                 "compiles": {n: {"count": p["compile_count"],

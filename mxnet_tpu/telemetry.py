@@ -750,6 +750,9 @@ METRIC_HELP = {
     "serving.kv_blocks_used": "KV pool blocks currently allocated to "
                               "requests",
     "serving.kv_blocks_free": "KV pool blocks on the free list",
+    "serving.kv_heads_per_row":
+        "heads side by side in one page row of the KV pool (1 = the plain "
+        "(heads, head_dim) row)",
     "serving.kv_blocks_frag_slots":
         "internal fragmentation: allocated-but-unused tail-block token "
         "slots across running requests",
@@ -783,6 +786,21 @@ METRIC_HELP = {
     "serving.prefill_tokens": "prompt+replay tokens prefilled",
     "serving.decode_batch": "live streams per fused decode step",
     "serving.generated_tokens": "tokens generated across all streams",
+    "serving.moe.pairs":
+        "token-expert pairs the routed FFN computed (live tokens x "
+        "experts per token x layers; nothing is dropped)",
+    "serving.moe.layer_steps":
+        "expert layers run: layers x programs (prefills and decode steps)",
+    "serving.moe.layer_tokens":
+        "live tokens the engine sent through an expert layer, summed over "
+        "layer-steps (counted on the host: pairs = experts per token x "
+        "this, exactly)",
+    "serving.moe.experts_touched":
+        "experts that received at least one live token, summed over "
+        "layer-steps",
+    "serving.moe.load_max_over_mean":
+        "busiest expert's tokens over the mean expert's, averaged over "
+        "the layers of the last step",
     "serving.ttft_seconds": "request time-to-first-token "
         "(bare = process-wide; engine label = per-engine)",
     "serving.request_latency_seconds": "request end-to-end latency "

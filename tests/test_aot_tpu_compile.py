@@ -117,26 +117,37 @@ def test_paged_kernels_compile_for_v5e(v5e, h, d, rows, layers, dtype, lanes):
 
 
 # --------------------------------------------------- the pool stays put
-def _serving_program(chip, name, page):
+def _serving_program(chip, name, page, arch="gpt2"):
     """``model.decode`` (B=32) or ``model.prefill`` (S=512) at the
     benchmark's widths cut to 2 layers, pool donated, compiled for the
-    chip. Returns (compiled, pool shape)."""
+    chip: GPT-2 medium in fp32 over blocks of 16, or OLMoE-1B-7B in bf16
+    over blocks of 64. Returns (compiled, pool shape, bytes an element)."""
     from mxnet_tpu.serving import model as M
 
     def s(shape, dt=jnp.int32):
         return jax.ShapeDtypeStruct(tuple(shape), dt, sharding=chip)
 
-    cfg = M.ModelConfig(50257, 2, 1024, 16, 4096, 1024)
-    params = {k: s(v, jnp.float32) for k, v in M.param_shapes(cfg).items()}
-    pool = s((cfg.num_layers, 513, 16) + page, jnp.float32)
+    if arch == "gpt2":
+        cfg, dtype, bs, blocks = (
+            M.ModelConfig(50257, 2, 1024, 16, 4096, 1024), jnp.float32, 16,
+            513)
+    else:
+        cfg, dtype, bs, blocks = (
+            M.ModelConfig(50304, 2, 2048, 16, 1024, 4096, norm="rms",
+                          pos="rope", qk_norm=True, head_dim=128,
+                          num_experts=64, experts_per_tok=8, bias=False),
+            jnp.bfloat16, 64, 129)
+    params = {k: s(v, dtype) for k, v in M.param_shapes(cfg).items()}
+    pool = s((cfg.num_layers, blocks, bs) + page, dtype)
     if name == "decode":
         fn, donate = M.decode, (5, 6)
-        args = (s((32,)), s((32,)), s((32, 64)), s((32,)))
+        args = (s((32,)), s((32,)), s((32, cfg.max_len // bs)), s((32,)))
     else:
         fn, donate = M.prefill, (4, 5)
-        args = (s((1, 512)), s(()), s((512 // 16,)))
+        args = (s((1, 512)), s(()), s((512 // bs,)))
     jitted = jax.jit(functools.partial(fn, cfg=cfg), donate_argnums=donate)
-    return jitted.lower(params, *args, pool, pool).compile(), pool.shape
+    return (jitted.lower(params, *args, pool, pool).compile(), pool.shape,
+            jnp.dtype(dtype).itemsize)
 
 
 def _pool_copies(text, pool_shape):
@@ -145,8 +156,8 @@ def _pool_copies(text, pool_shape):
     dims = [",".join(map(str, sh)) for sh in
             (pool_shape, pool_shape[1:], (1,) + pool_shape[1:])]
     hits = []
-    for m in re.finditer(r"^\s*(?:ROOT )?%([\w.\-]+) = \(*(f32\[([\d,]*)\]"
-                         r"\{[^}]*\})", text, re.M):
+    for m in re.finditer(r"^\s*(?:ROOT )?%([\w.\-]+) = \(*((?:f32|bf16)"
+                         r"\[([\d,]*)\]\{[^}]*\})", text, re.M):
         name, shape, d = m.groups()
         if d in dims and re.search("copy|slice", name) \
                 and "update" not in name:
@@ -158,36 +169,46 @@ def _entry_layouts(text, pool_shape):
     entry = next(l for l in text.splitlines()
                  if "entry_computation_layout" in l)
     return set(re.findall(
-        r"f32\[%s\]\{([\d,]*)" % ",".join(map(str, pool_shape)), entry))
+        r"(?:f32|bf16)\[%s\]\{([\d,]*)" % ",".join(map(str, pool_shape)),
+        entry))
 
 
+@pytest.mark.parametrize("arch,heads,head_dim,page", [
+    ("gpt2", 16, 64, (8, 128)), ("olmoe", 16, 128, (16, 128))])
 @pytest.mark.parametrize("name", ["decode", "prefill"])
-def test_serving_programs_leave_the_pool_in_place(v5e, name):
+def test_serving_programs_leave_the_pool_in_place(v5e, name, arch, heads,
+                                                  head_dim, page):
     """The guard. A pool whose rows fill the 128 lanes has ONE layout — the
     device's default, the scatter's and the kernel's — so a program takes
     the donated pool, scatters into it and hands it back: no copy, no
     per-layer slice, next to nothing in temporaries. (Rows of 64 lanes:
     four copies of the padded pool a program, three times the pool in
-    temporaries; the last test shows this check sees them.)"""
+    temporaries; the last test shows this check sees them.) Two formats:
+    GPT-2 medium's two heads of 64 a row in fp32, and OLMoE's plain
+    ``(16, 128)`` rows (r = 1) in bf16, whose programs also hold the
+    experts' grouped-matmul kernels."""
     from mxnet_tpu.serving.kv_cache import KVBlockPool
 
-    page = KVBlockPool.page_shape(16, 64)
-    assert page == (8, 128)
-    compiled, pool_shape = _serving_program(v5e, name, page)
+    assert KVBlockPool.page_shape(heads, head_dim) == page
+    compiled, pool_shape, itemsize = _serving_program(v5e, name, page, arch)
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 2
+    if arch == "olmoe":     # three grouped matmuls a layer, by their name
+        assert len(re.findall(r"%gmm[.\d]* = ", text)) == 6
     assert _pool_copies(text, pool_shape) == []
     assert _entry_layouts(text, pool_shape) == {"4,3,2,1,0"}
-    pool_bytes = 2 * 4 * math.prod(pool_shape)        # K and V, fp32
+    pool_bytes = 2 * itemsize * math.prod(pool_shape)         # K and V
     ma = compiled.memory_analysis()
-    assert ma.temp_size_in_bytes < pool_bytes / 10
+    # the experts' sorted rows and their float32 products are temporaries
+    # of their own (prefill at 512: 4,096 pairs), none the size of the pool
+    assert ma.temp_size_in_bytes < pool_bytes / (10 if arch == "gpt2" else 2)
     assert ma.alias_size_in_bytes >= pool_bytes      # donated, taken
 
 
 def test_pool_guard_sees_the_copies_of_a_half_lane_pool(v5e):
     """The same decode program over ``(H, D) = (16, 64)`` rows, as every
     pool was built before PR 25: the guard's checks all fire."""
-    compiled, pool_shape = _serving_program(v5e, "decode", (16, 64))
+    compiled, pool_shape, _ = _serving_program(v5e, "decode", (16, 64))
     text = compiled.as_text()
     copies = [n for n, _s in _pool_copies(text, pool_shape)
               if n.startswith("copy")]

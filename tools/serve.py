@@ -59,25 +59,44 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
 def build_engine(args):
+    """The engine of the flags: the six GPT-2 shape flags and the pool
+    flags, or --model-config, a configuration file whose ``model`` and
+    ``engine`` objects say all of that (``benchmark/configs/*.json``: any
+    block ``ModelConfig``'s structural fields describe). Weights are the
+    checkpoint's or seeded — in the file's ``weights_dtype`` if it has
+    one."""
     import numpy as np
 
     from mxnet_tpu.serving import ServingConfig, ServingEngine
+    from mxnet_tpu.serving import model as lm
 
-    cfg = ServingConfig(
-        vocab_size=args.vocab, num_layers=args.num_layers,
-        model_dim=args.model_dim, num_heads=args.num_heads,
-        ffn_dim=args.ffn_dim, max_len=args.max_len,
-        block_size=args.block_size, num_blocks=args.num_blocks,
-        max_batch=args.max_batch,
-        kv_dtype=np.dtype(args.kv_dtype),
-        max_queue=getattr(args, "max_queue", None),
-        default_timeout_ms=getattr(args, "default_timeout_ms", None))
-    arg_params = None
+    queue = dict(max_queue=getattr(args, "max_queue", None),
+                 default_timeout_ms=getattr(args, "default_timeout_ms", None))
+    weights_dtype = np.float32
+    if getattr(args, "model_config", None):
+        import jax.numpy as jnp
+
+        with open(args.model_config) as f:
+            obj = json.load(f)
+        cfg = ServingConfig.from_json(
+            dict(obj, engine=dict(obj["engine"], **queue)))
+        weights_dtype = jnp.dtype(obj.get("weights_dtype", "float32"))
+    else:
+        cfg = ServingConfig(
+            vocab_size=args.vocab, num_layers=args.num_layers,
+            model_dim=args.model_dim, num_heads=args.num_heads,
+            ffn_dim=args.ffn_dim, max_len=args.max_len,
+            block_size=args.block_size, num_blocks=args.num_blocks,
+            max_batch=args.max_batch,
+            kv_dtype=np.dtype(args.kv_dtype), **queue)
     if args.checkpoint:
         from mxnet_tpu import model as mxmodel
 
         _sym, arg_params, _aux = mxmodel.load_checkpoint(args.checkpoint,
                                                          args.epoch)
+    else:
+        arg_params = lm.random_params(cfg, seed=args.seed,
+                                      dtype=weights_dtype)
     return ServingEngine(cfg, arg_params=arg_params, seed=args.seed)
 
 
@@ -322,6 +341,11 @@ def parse_args(argv=None):
     ap.add_argument("--num-heads", type=int, default=2)
     ap.add_argument("--ffn-dim", type=int, default=128)
     ap.add_argument("--max-len", type=int, default=128)
+    ap.add_argument("--model-config", default=None, metavar="JSON",
+                    help="a configuration file (the `model` and `engine` "
+                         "objects of a benchmark/configs/*.json): serves "
+                         "any block the engine has, e.g. OLMoE's routed "
+                         "experts; replaces the shape and pool flags")
     ap.add_argument("--block-size", type=int, default=None)
     ap.add_argument("--num-blocks", type=int, default=None)
     ap.add_argument("--max-batch", type=int, default=None)
