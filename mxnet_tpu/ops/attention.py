@@ -689,7 +689,10 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables, context_lens,
     p = 0.0 in float32 — garbage in masked slots cannot leak in. A row
     with context_len == 0 returns all zeros (softmax over an all-masked
     row would otherwise go uniform and average the garbage), matching
-    the Pallas kernel's empty-stream output.
+    the Pallas kernel's empty-stream output. This lowering gathers every
+    table slot, (B, nb·bs, H, D) in float32, and masks; the kernel reads
+    ``ceil(context_len / bs)`` blocks a stream and nothing of the slots
+    past them, so their table entries need only be valid block ids here.
     """
     return paged_attention_multi_reference(
         q[:, None], k_pages, v_pages, block_tables, context_lens[:, None],
@@ -784,26 +787,65 @@ def _head_sums(x, r):
     return out
 
 
+# One fetch moves this much of K (and as much of V) into VMEM, counted as the
+# pages lie there. Two fetches are in flight or in use at a time.
+_PAGED_FETCH_BYTES = 512 * 1024
+
+
+def _paged_page_bytes(bs, g, w, dtype):
+    """Bytes of one page ``(bs, G, W)`` in VMEM: ``bs`` row sets ``(G, W)``,
+    each padded to the dtype's (sublane, 128) tile."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublanes = 32 // itemsize
+    return bs * -(-g // sublanes) * sublanes * -(-w // 128) * 128 * itemsize
+
+
+def _paged_blocks_per_fetch(bs, g, w, dtype, nb):
+    """c: how many blocks of K (and of V) one fetch of the paged kernel
+    moves, from the page it is handed: as many as fill
+    ``_PAGED_FETCH_BYTES`` (8 at GPT-2 medium's 64 KB fp32 pages, 2 at
+    OLMoE's 256 KB bf16 pages), at least one and never more than the
+    table holds. The scratch is ``4 c`` pages."""
+    page = _paged_page_bytes(bs, g, w, dtype)
+    return int(max(1, min(nb, _PAGED_FETCH_BYTES // page)))
+
+
 def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
                         sm_scale, layer=None, interpret=False):
     """Pallas TPU ragged-paged-attention kernel, T query lanes per sequence
     (T = 1 is the decode step, T = k + 1 the speculative verify pass).
 
-    Grid (B, nb) with the block axis innermost; the block TABLE and context
-    lengths ride in as scalar-prefetch args (``PrefetchScalarGridSpec``) so
-    the index_map can steer each step's K/V DMA straight at the sequence's
-    i-th block of the pool's ``layer`` — the gather never materialises
-    per-sequence contiguous KV, and the pool is read where it lies.
-    Online-softmax state (m, l, acc) lives in VMEM scratch carried across
-    block steps, one (G, W) row set per lane: a head's score is summed over
-    its own D lanes and kept broadcast across them (:func:`_head_sums`), so
-    every line after it is elementwise on full (8, 128) registers whatever
-    r is. Masking is per lane (``context_lens`` is (B, T)); the lanes take
-    the step one after another (T is static and small; their context
-    lengths are scalar reads from SMEM). Blocks wholly past the LONGEST
-    lane's context skip compute (``pl.when``); shorter lanes mask the tail
-    of shared blocks with -1e30. VMEM per core is O((bs + T)·H·D),
-    independent of both sequence length and pool size.
+    Grid ``(B,)``: one step a stream. The pool stays in HBM where it lies
+    (``memory_space=pl.ANY``, the whole 5-D pool with a static ``layer``);
+    the block TABLE and the context lengths ride in as scalar-prefetch
+    args (SMEM). A stream's step walks its LIVE blocks only:
+    ``ceil(max_t context_lens[i, t] / bs)`` of them (one for an empty
+    stream), read on the device, in fetches of ``c`` blocks
+    (:func:`_paged_blocks_per_fetch`: c follows from the page's bytes).
+    A fetch is one async copy per block of K and of V, block ids from the
+    table, into one of two slots of a VMEM scratch ``(2, c, bs, G, W)``;
+    the next fetch is started before the current one is waited for, and
+    the LAST fetch of stream i starts the first of stream i + 1 (scratch,
+    semaphores and the slot's parity live across grid steps), so only the
+    first stream of a call waits for a whole DMA. A table slot past the
+    stream's context costs nothing: no grid step, no copy, no compute.
+
+    Each block takes the per-block arithmetic as it arrives (its own
+    semaphore): online-softmax state (m, l, acc) in VMEM scratch, one
+    (G, W) row set per lane; a head's score is summed over its own D lanes
+    and kept broadcast across them (:func:`_head_sums`), so every line
+    after it is elementwise on full (8, 128) registers whatever r is.
+    Masking is per lane (``context_lens`` is (B, T)): the lanes take the
+    block one after another (T is static and small), the loop is bounded
+    by the LONGEST lane and shorter lanes mask the tail with -1e30.
+    float32 scores and accumulators; bf16 pages are read as bf16 and
+    widened on the chip. VMEM: ``4 c`` pages (2 MB at the default) plus
+    O(T·G·W), independent of sequence length, table width and pool size.
+
+    An async copy cuts HBM between whole (8, 128) tiles, so page rows with
+    ``G % 8`` or ``W % 128`` left over are padded at the edge — a copy of
+    the layer's pages a call; ``(8, 128)`` and ``(16, 128)`` rows (both
+    served configurations of the benchmark) are read where they lie.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -811,69 +853,133 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
     r = _heads_per_row(q, k_pages)
     k_pages, v_pages, layer = _whole_pool(k_pages, v_pages, layer)
     b, tq, h, d = q.shape
-    bs, g, w = k_pages.shape[2:]
+    rows = k_pages.shape[3:]
+    q = q.reshape((b, tq) + rows)
+    # rows that do not fill their tiles ((6, 128), (12, 64)): see above
+    g, w = -(-rows[0] // 8) * 8, -(-rows[1] // 128) * 128
+    if (g, w) != rows:
+        def pad(x):
+            return jnp.pad(x, [(0, 0)] * (x.ndim - 2)
+                           + [(0, g - rows[0]), (0, w - rows[1])])
+        q, k_pages, v_pages, layer = (pad(q), pad(k_pages[layer][None]),
+                                      pad(v_pages[layer][None]), 0)
+    bs = k_pages.shape[2]
     nb = block_tables.shape[1]
+    c = _paged_blocks_per_fetch(bs, g, w, k_pages.dtype, nb)
 
-    def kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
-               m_ref, l_ref, acc_ref):
+    def kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref,
+               k_buf, v_buf, sems, slot_ref, m_ref, l_ref, acc_ref):
         i = pl.program_id(0)  # sequence
-        j = pl.program_id(1)  # block-table slot (innermost)
 
-        @pl.when(j == 0)
-        def _init():
-            m_ref[:] = jnp.full((tq, g, w), _NEG_INF, jnp.float32)
-            l_ref[:] = jnp.zeros((tq, g, w), jnp.float32)
-            acc_ref[:] = jnp.zeros((tq, g, w), jnp.float32)
+        def live_blocks(seq):
+            # SMEM yields scalars only: one read per lane, T is static
+            longest = functools.reduce(
+                jnp.maximum, [cl_ref[seq, t] for t in range(tq)])
+            # one block for an empty stream; never past the table
+            return jnp.clip(pl.cdiv(longest, bs), 1, nb)
 
-        # SMEM yields scalars only: one read per lane, T is static and small
+        def copies(seq, blk, slot, j):
+            """The two async copies (K, V) of table slot ``blk`` of stream
+            ``seq`` into place ``j`` of ``slot``; one semaphore a place."""
+            page = bt_ref[seq, blk]
+            return [pltpu.make_async_copy(hbm.at[layer, page],
+                                          buf.at[slot, j], sems.at[slot, j])
+                    for hbm, buf in ((k_hbm, k_buf), (v_hbm, v_buf))]
+
+        def start_fetch(seq, it, slot, n_blk):
+            def start(j, _):
+                for cp in copies(seq, it * c + j, slot, j):
+                    cp.start()
+                return 0
+
+            jax.lax.fori_loop(0, jnp.minimum(c, n_blk - it * c), start, 0)
+
+        @pl.when(i == 0)
+        def _first():
+            slot_ref[0] = 0
+            start_fetch(0, 0, 0, live_blocks(0))
+
+        m_ref[:] = jnp.full((tq, g, w), _NEG_INF, jnp.float32)
+        l_ref[:] = jnp.zeros((tq, g, w), jnp.float32)
+        acc_ref[:] = jnp.zeros((tq, g, w), jnp.float32)
         ctx = [cl_ref[i, t] for t in range(tq)]
-        ctx_max = functools.reduce(jnp.maximum, ctx)
+        n_blk = live_blocks(i)
+        n_fetch = pl.cdiv(n_blk, c)
+        slot0 = slot_ref[0]
+        nxt = jnp.minimum(i + 1, b - 1)
+        nxt_blk = live_blocks(nxt)
 
-        @pl.when(j * bs < ctx_max)  # ragged early-out past every lane
-        def _step():
-            kv = k_ref[0, 0].astype(jnp.float32)   # (bs, G, W)
-            vv = v_ref[0, 0].astype(jnp.float32)
-            pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, g, w), 0)
-            for t in range(tq):
-                qv = q_ref[0, t].astype(jnp.float32)            # (G, W)
-                s = _head_sums(qv[None] * kv, r) * sm_scale     # (bs, G, W)
-                s = jnp.where(pos < ctx[t], s, _NEG_INF)
-                m = m_ref[t]
-                m_new = jnp.maximum(m, jnp.max(s, axis=0))
-                p = jnp.exp(s - m_new[None])
-                scale = jnp.exp(m - m_new)
-                m_ref[t] = m_new
-                l_ref[t] = l_ref[t] * scale + jnp.sum(p, axis=0)
-                acc_ref[t] = acc_ref[t] * scale + jnp.sum(p * vv, axis=0)
+        def fetch_step(it, _):
+            slot = (slot0 + it) % 2
 
-        @pl.when(j == nb - 1)
-        def _finish():
-            for t in range(tq):
-                out = acc_ref[t] / jnp.maximum(l_ref[t], 1e-30)
-                # a lane that never saw a valid position accumulated
-                # exp(-1e30 - -1e30) = 1 weights over garbage — pin it to
-                # the oracle's empty-lane zero
-                out = jnp.where(ctx[t] > 0, out, 0.0)
-                o_ref[0, t] = out.astype(o_ref.dtype)
+            # the next fetch into the other slot before this one is waited
+            # for: this stream's, or after its last the next stream's first
+            last = it + 1 == n_fetch
 
-    q_spec = pl.BlockSpec((1, tq, g, w), lambda i, j, bt, cl: (i, 0, 0, 0))
-    page_spec = pl.BlockSpec((1, 1, bs, g, w),
-                             lambda i, j, bt, cl: (layer, bt[i, j], 0, 0, 0))
+            @pl.when(jnp.logical_not(last) | (i + 1 < b))
+            def _prefetch():
+                start_fetch(jnp.where(last, nxt, i),
+                            jnp.where(last, 0, it + 1), 1 - slot,
+                            jnp.where(last, nxt_blk, n_blk))
+
+            def block_step(j, _):
+                blk = it * c + j
+                for cp in copies(i, blk, slot, j):
+                    cp.wait()
+                kv = k_buf[slot, j].astype(jnp.float32)   # (bs, G, W)
+                vv = v_buf[slot, j].astype(jnp.float32)
+                pos = blk * bs + jax.lax.broadcasted_iota(
+                    jnp.int32, (bs, g, w), 0)
+                for t in range(tq):
+                    qv = q_ref[0, t].astype(jnp.float32)            # (G, W)
+                    s = _head_sums(qv[None] * kv, r) * sm_scale  # (bs, G, W)
+                    s = jnp.where(pos < ctx[t], s, _NEG_INF)
+                    m = m_ref[t]
+                    m_new = jnp.maximum(m, jnp.max(s, axis=0))
+                    p = jnp.exp(s - m_new[None])
+                    scale = jnp.exp(m - m_new)
+                    m_ref[t] = m_new
+                    l_ref[t] = l_ref[t] * scale + jnp.sum(p, axis=0)
+                    acc_ref[t] = acc_ref[t] * scale + jnp.sum(p * vv, axis=0)
+                return 0
+
+            jax.lax.fori_loop(0, jnp.minimum(c, n_blk - it * c), block_step, 0)
+            return 0
+
+        jax.lax.fori_loop(0, n_fetch, fetch_step, 0)
+        slot_ref[0] = (slot0 + n_fetch) % 2
+        for t in range(tq):
+            out = acc_ref[t] / jnp.maximum(l_ref[t], 1e-30)
+            # a lane that never saw a valid position accumulated
+            # exp(-1e30 - -1e30) = 1 weights over garbage — pin it to
+            # the oracle's empty-lane zero
+            out = jnp.where(ctx[t] > 0, out, 0.0)
+            o_ref[0, t] = out.astype(o_ref.dtype)
+
+    q_spec = pl.BlockSpec((1, tq, g, w), lambda i, bt, cl: (i, 0, 0, 0))
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, nb),
-        in_specs=[q_spec, page_spec, page_spec],
+        grid=(b,),
+        in_specs=[q_spec, pool_spec, pool_spec],
         out_specs=q_spec,
-        scratch_shapes=[pltpu.VMEM((tq, g, w), jnp.float32)] * 3,
+        scratch_shapes=[pltpu.VMEM((2, c, bs, g, w), k_pages.dtype),
+                        pltpu.VMEM((2, c, bs, g, w), v_pages.dtype),
+                        pltpu.SemaphoreType.DMA((2, c)),
+                        pltpu.SMEM((1,), jnp.int32)]
+        + [pltpu.VMEM((tq, g, w), jnp.float32)] * 3,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, tq, g, w), q.dtype),
+        # the scratch carries one stream's prefetch into the next step
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-      q.reshape(b, tq, g, w), k_pages, v_pages)
-    return out.reshape(b, tq, h, d)
+      q, k_pages, v_pages)
+    return out[:, :, :rows[0], :rows[1]].reshape(b, tq, h, d)
 
 
 def paged_attention_multi(q, k_pages, v_pages, block_tables, context_lens,

@@ -264,6 +264,10 @@ class ServingEngine:
         self._moe_layer_steps = 0
         self._moe_layer_tokens = 0
         self._moe_touched = 0
+        # what the paged kernel walked (decode and verify passes): blocks
+        # that hold a live stream's context, of the table slots it holds
+        self._paged_live_blocks = 0
+        self._paged_table_slots = 0
         self._t_started = time.time()
         self._tokens_total = 0
         # per-engine identity: labels this engine's histograms/counters in
@@ -1020,7 +1024,8 @@ class ServingEngine:
         # each decode call's context length on the trace: what a roofline
         # share of the paged kernel is computed from (PERF.md section 7)
         args = {"batch": len(reqs), "bucket": B,
-                "ctx_tokens": int(ctx.sum()), "ctx_max": int(ctx.max())}
+                "ctx_tokens": int(ctx.sum()), "ctx_max": int(ctx.max()),
+                "live_blocks": self._note_paged(ctx[:len(reqs)])}
         with telemetry.span("serving.decode.build", _CAT, **args):
             toks = np.zeros(B, np.int32)
             poss = np.zeros(B, np.int32)
@@ -1110,8 +1115,12 @@ class ServingEngine:
         base_ctx = [r.context_len for r in reqs]
         # the decode spans' names and arguments, told apart by spec/phase;
         # ctx_* are the window's first lane's (a verify pass reads k more)
+        # live_blocks is the verify pass's: its kernel walks to the window's
+        # last lane (the draft's own pool is not booked)
         args = {"spec": 1, "batch": n, "bucket": B,
-                "ctx_tokens": sum(base_ctx) + B, "ctx_max": max(base_ctx) + 1}
+                "ctx_tokens": sum(base_ctx) + B, "ctx_max": max(base_ctx) + 1,
+                "live_blocks": self._note_paged(np.minimum(
+                    np.add(base_ctx, k + 1), cfg.max_len))}
         with telemetry.span("serving.decode.build", _CAT, phase="draft",
                             **args):
             tables = np.zeros((B, nb), np.int32)
@@ -1218,6 +1227,20 @@ class ServingEngine:
         self._spec_accepted += accepted
         telemetry.histogram("serving.decode_batch").observe(len(reqs))
         self.obs.spec_step(reqs, draft_s, verify_s, proposed, accepted)
+
+    def _note_paged(self, ctx):
+        """Book one decode or verify pass of the paged kernel from the
+        live streams' context lengths: the blocks it walks
+        (``ceil(ctx / block_size)`` a stream) and the table slots those
+        streams hold (``nb_max`` each; what the kernel's grid walked
+        before PR 27). Returns the blocks, for the step's spans."""
+        live = int((-(-ctx // self.config.block_size)).sum())
+        slots = len(ctx) * self._nb_max
+        self._paged_live_blocks += live
+        self._paged_table_slots += slots
+        telemetry.counter("serving.paged.live_blocks").inc(live)
+        telemetry.counter("serving.paged.table_slots").inc(slots)
+        return live
 
     def _note_moe(self, load, tokens):
         """Book one program's per-layer ``tokens_per_expert`` (L, E):
@@ -1340,6 +1363,13 @@ class ServingEngine:
                         if self._spec_proposed else 0.0,
                     "draft_seconds": round(self._spec_draft_s, 6),
                     "verify_seconds": round(self._spec_verify_s, 6),
+                },
+                "paged": {
+                    "live_blocks": self._paged_live_blocks,
+                    "table_slots": self._paged_table_slots,
+                    "live_share":
+                        (self._paged_live_blocks / self._paged_table_slots)
+                        if self._paged_table_slots else 0.0,
                 },
                 # only for a model with experts
                 **({"moe": {

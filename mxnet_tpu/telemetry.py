@@ -786,6 +786,12 @@ METRIC_HELP = {
     "serving.prefill_tokens": "prompt+replay tokens prefilled",
     "serving.decode_batch": "live streams per fused decode step",
     "serving.generated_tokens": "tokens generated across all streams",
+    "serving.paged.live_blocks":
+        "KV blocks the paged kernel walked: ceil(context / block size) a "
+        "live stream, summed over decode and verify passes",
+    "serving.paged.table_slots":
+        "block-table slots those streams held (live streams x table "
+        "width a pass): live_blocks / this = how full the tables ran",
     "serving.moe.pairs":
         "token-expert pairs the routed FFN computed (live tokens x "
         "experts per token x layers; nothing is dropped)",

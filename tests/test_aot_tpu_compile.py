@@ -68,15 +68,17 @@ def _flash_shapes(chip, d):
                                  sharding=chip)] * 3
 
 
-def _paged_shapes(chip, h, d, dtype, lanes=None, rows=None, layers=None):
-    """Serving shapes: B=32 streams, block size 16, 64 table slots; page
-    rows ``(h, d)`` unless given, the whole pool if ``layers``."""
+def _paged_shapes(chip, h, d, dtype, lanes=None, rows=None, layers=None,
+                  blocks=(2049, 16)):
+    """Serving shapes: B=32 streams, 64 table slots, ``blocks`` = (pool
+    blocks, block size); page rows ``(h, d)`` unless given, the whole pool
+    if ``layers``."""
     def s(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=chip)
 
     q = (32, h, d) if lanes is None else (32, lanes, h, d)
     ctx = (32,) if lanes is None else (32, lanes)
-    pages = s(((layers,) if layers else ()) + (2049, 16) + (rows or (h, d)),
+    pages = s(((layers,) if layers else ()) + blocks + (rows or (h, d)),
               dtype)
     return (s(q, dtype), pages, pages, s((32, 64), jnp.int32),
             s(ctx, jnp.int32))
@@ -103,17 +105,41 @@ def test_flash_backward_compiles_for_v5e(v5e, d):
 @pytest.mark.parametrize("lanes", [None, 5], ids=["decode", "verify"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["fp32", "bf16"])
-@pytest.mark.parametrize("h,d,rows,layers", [
-    (12, 64, None, None), (16, 128, None, None),
-    (16, 64, (8, 128), None), (16, 64, (8, 128), 2)],
-    ids=["12x64", "16x128", "16x64-packed", "16x64-packed-pool"])
-def test_paged_kernels_compile_for_v5e(v5e, h, d, rows, layers, dtype, lanes):
+@pytest.mark.parametrize("h,d,rows,layers,blocks", [
+    (12, 64, None, None, (2049, 16)), (16, 128, None, None, (2049, 16)),
+    (16, 64, (8, 128), None, (2049, 16)), (16, 64, (8, 128), 2, (2049, 16)),
+    (16, 64, (8, 128), 24, (513, 16)), (16, 128, None, 8, (1025, 64))],
+    ids=["12x64", "16x128", "16x64-packed", "16x64-packed-pool",
+         "gpt2-medium-pool", "olmoe-pool"])
+def test_paged_kernels_compile_for_v5e(v5e, h, d, rows, layers, blocks, dtype,
+                                       lanes):
+    """The last two are the benchmark's pools (fp32 and bf16 there)."""
     kernel = A._paged_pallas if lanes is None else A._paged_pallas_multi
     text = _compiled_text(
         functools.partial(kernel, sm_scale=0.125,
                           layer=1 if layers else None),
-        *_paged_shapes(v5e, h, d, dtype, lanes, rows, layers))
+        *_paged_shapes(v5e, h, d, dtype, lanes, rows, layers, blocks))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("page,dtype,c", [
+    ((16, 8, 128), jnp.float32, 8),      # GPT-2 medium: 64 KB pages
+    ((64, 16, 128), jnp.bfloat16, 2),    # OLMoE: 256 KB pages
+    ((16, 12, 64), jnp.float32, 4),      # unpacked rows, padded to (16, 128)
+    ((16, 8, 128), jnp.bfloat16, 8),     # half a bf16 tile of rows
+    ((8, 3, 32), jnp.float32, 16)],      # small rows fill whole tiles too
+    ids=["gpt2-medium", "olmoe", "12x64", "8x128-bf16", "tiny"])
+def test_blocks_per_fetch_follow_the_page(page, dtype, c):
+    """c is read off the page: a fetch of 128 KB or more, a scratch (two
+    slots of K and of V) under 8 MB, never more blocks than the table."""
+    bs, g, w = page
+    assert A._paged_blocks_per_fetch(bs, g, w, dtype, 64) == c
+    assert A._paged_blocks_per_fetch(bs, g, w, dtype, 3) == min(c, 3)
+    in_vmem = A._paged_page_bytes(bs, g, w, dtype)    # rows padded to tiles
+    assert 4 * c * in_vmem <= 8 << 20
+    assert c * in_vmem >= 128 << 10
+    if w >= 64:                                       # also as the rows count
+        assert c * bs * g * w * jnp.dtype(dtype).itemsize >= 128 << 10
 
 
 # --------------------------------------------------- the pool stays put

@@ -274,6 +274,12 @@ def test_speculative_engine_emits_the_target_stream():
     # the verify pass's lanes are counted like decode's tokens
     st = eng.stats()["moe"]
     assert st["pairs"] == 2 * st["layer_tokens"] > 0
+    # and its blocks, to the window's last lane (at most 19 + 10 + 3
+    # tokens: five blocks of 8), a stream and pass, over tables of 16
+    paged = eng.stats()["paged"]
+    lanes, rest = divmod(paged["table_slots"], 16)
+    assert rest == 0 and 2 < lanes < 2 * 10
+    assert lanes < paged["live_blocks"] <= 5 * lanes
 
 
 def test_an_engine_without_experts_reports_no_moe_block():

@@ -85,14 +85,90 @@ def test_packed_reference_is_the_unpacked_reference(r, dtype):
         assert not np.asarray(one[0], np.float32).any()
 
 
-@pytest.mark.parametrize("whole_pool", [False, True], ids=["4d", "5d-layer"])
-@pytest.mark.parametrize("lanes", [None, 3], ids=["decode", "verify"])
-@pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("r", HEADS)
-def test_pallas_interpret_matches_reference(r, dtype, lanes, whole_pool):
+# ---- what a loop over a stream's own blocks can get wrong (PR 27). Tables of
+# seven slots over blocks of 16, fetched two blocks at a time: contexts in
+# tokens per stream (decode) or per stream and lane (verify).
+_BS, _NB, _C = 16, 7, 2
+_FULL = _NB * _BS
+RAGGED = {
+    # nothing, one token, a block, a block and one, a fetch, a fetch and
+    # one, the whole table — in one batch
+    "boundaries": [[0], [1], [_BS], [_BS + 1], [_C * _BS], [_C * _BS + 1],
+                   [_FULL]],
+    # the prefetch across streams: the next stream's first fetch is
+    # started from the last fetch of this one, short or long
+    "last-longest": [[1], [_BS + 1], [_FULL]],
+    "first-longest": [[_FULL], [_BS], [1]],
+    # a padded batch row as the engine builds it: context 1, every table
+    # entry the trash block
+    "padded-row": [[3 * _BS + 2], [1], [_C * _BS + 5]],
+    # the loop is bounded by the longest lane, wherever it stands
+    "lanes": [[5, _FULL - 3, 0, _BS, _C * _BS + 1],
+              [_C * _BS, _C * _BS + 1, _C * _BS + 2, 1, 2],
+              [0, 0, 0, 0, 0],
+              [_FULL, 1, 1, 1, 1]],
+    # table entries past the context name a block of 1e30s: never read
+    "dead-1e30": [[_BS + 3], [_C * _BS], [1]],
+    # a context past the table (a verify window at max_len) stops at it
+    "past-the-table": [[_FULL + 9], [_FULL + _BS + 1], [2]],
+}
+ALIGNED = (16, 64)     # two heads a row fill (8, 128): no padding at the edge
+
+
+def _ragged(case, r, dtype, whole_pool, heads=None, N=12, L=2):
+    H, D = heads or HEADS[r]
+    rng = np.random.RandomState(len(case) + r)
+    cl = np.asarray(RAGGED[case], np.int32)
+    B, lanes = cl.shape
+    q = rng.randn(B, lanes, H, D)
+    shape = ((L,) if whole_pool else ()) + (N, _BS, H // r, D * r)
+    kp, vp = rng.randn(*shape), rng.randn(*shape)
+    bt = rng.randint(1, N - 1, (B, _NB)).astype(np.int32)
+    if case == "padded-row":
+        bt[1] = 0
+    if case == "dead-1e30":
+        kp[..., N - 1, :, :, :] = vp[..., N - 1, :, :, :] = 1e30
+        for i in range(B):
+            bt[i, -(-int(cl[i].max()) // _BS):] = N - 1
+    q, kp, vp = (jnp.asarray(x, dtype) for x in (q, kp, vp))
+    return q, kp, vp, jnp.asarray(bt), jnp.asarray(cl), (1 if whole_pool
+                                                         else None)
+
+
+_KERNEL_CASES = [
+    ("random", r, dt, lanes, pool)
+    for r in HEADS for dt in DTYPES for lanes in (None, 3)
+    for pool in (False, True)
+] + [
+    # every r with either page form and every dtype with either
+    (case, r, dt, None, (dt == "bf16") != (r == 2))
+    for case in RAGGED for r in HEADS for dt in DTYPES
+] + [("boundaries", "aligned", "fp32", None, True),
+     ("lanes", "aligned", "bf16", None, False)]
+
+
+@pytest.mark.parametrize(
+    "case,r,dtype,lanes,whole_pool", _KERNEL_CASES,
+    ids=["-".join([c, "r%s" % r, dt,
+                   "decode" if n is None and c != "lanes" else "verify",
+                   "5d-layer" if w else "4d"])
+         for c, r, dt, n, w in _KERNEL_CASES])
+def test_pallas_interpret_matches_reference(case, r, dtype, lanes, whole_pool,
+                                            monkeypatch):
     """The kernel program the TPU runs, interpreted on the CPU."""
     dt, tol = DTYPES[dtype]
-    q, kp, vp, bt, cl, layer = _rand(r, dt, lanes or 1, whole_pool, seed=r)
+    if case == "random":
+        q, kp, vp, bt, cl, layer = _rand(r, dt, lanes or 1, whole_pool,
+                                         seed=r)
+    else:
+        heads, r = (ALIGNED, 2) if r == "aligned" else (None, r)
+        q, kp, vp, bt, cl, layer = _ragged(case, r, dt, whole_pool, heads)
+        lanes = None if cl.shape[1] == 1 else cl.shape[1]
+        # two blocks a fetch at these small pages, as the benchmark's pages
+        # take eight (or two) of the table's 64
+        rows = kp.shape[-3:]
+        monkeypatch.setattr(A, "_PAGED_FETCH_BYTES", _C * 64 * 1024)
+        assert A._paged_blocks_per_fetch(*rows, dt, _NB) == _C
     scale = 1.0 / np.sqrt(q.shape[-1])
     if lanes is None:
         q, cl = q[:, 0], cl[:, 0]
@@ -105,11 +181,13 @@ def test_pallas_interpret_matches_reference(r, dtype, lanes, whole_pool):
         want = A.paged_attention_multi_reference(q, kp, vp, bt, cl,
                                                  layer=layer)
     assert got.shape == q.shape and got.dtype == q.dtype
+    assert np.isfinite(np.asarray(got, np.float32)).all()
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=tol, atol=tol)
-    assert not np.asarray(got[0], np.float32).reshape(
-        -1, q.shape[-1])[:q.shape[-2]].any(), "empty lane must read zeros"
+    if case in ("random", "boundaries"):
+        assert not np.asarray(got[0], np.float32).reshape(
+            -1, q.shape[-1])[:q.shape[-2]].any(), "empty lane must read zeros"
 
 
 def test_pages_must_hold_the_heads():

@@ -102,7 +102,8 @@ def serving_trace(tmp_path_factory):
     others = [e[0] for evs in lines.values() if evs is not mine[0]
               for e in evs if e[0].startswith("serving.")]
     assert not others, "spans on a thread that is not the driver: %s" % others
-    return {"data": data, "driver": mine[0], "requests": reqs}
+    return {"data": data, "driver": mine[0], "requests": reqs,
+            "stats": eng.stats(), "config": cfg}
 
 
 @pytest.mark.parametrize("name", SPAN_NAMES)
@@ -161,6 +162,31 @@ def test_arguments_are_readable_from_the_events_stats(serving_trace):
     assert sorted(st["request_id"] for st in by_name["serving.retire"]
                   if "request_id" in st) == ["r0", "r1", "r2"]
     assert all(len(r.generated) == 6 for r in serving_trace["requests"])
+
+
+def test_paged_counters_count_the_blocks_the_kernel_walks(serving_trace):
+    """`serving.paged.live_blocks` / `.table_slots` by hand: prompts of 5,
+    6 and 7 tokens, five decode steps each, blocks of 8, tables of 8."""
+    cfg = serving_trace["config"]
+    nb_max = cfg.max_len // cfg.block_size
+    steps = [[len(r.prompt) + 1 + s for r in serving_trace["requests"]]
+             for s in range(5)]
+    assert steps[0] == [6, 7, 8] and steps[-1] == [10, 11, 12]
+    by_step = [sum(-(-ctx // cfg.block_size) for ctx in step)
+               for step in steps]
+    assert by_step == [3, 4, 5, 6, 6]
+    paged = serving_trace["stats"]["paged"]
+    assert paged["live_blocks"] == sum(by_step) == 24
+    assert paged["table_slots"] == 5 * 3 * nb_max == 120
+    assert paged["live_share"] == pytest.approx(24 / 120)
+    for part in ("build", "dispatch", "fetch"):
+        assert [st["live_blocks"] for name, _s, _d, st
+                in serving_trace["driver"]
+                if name == "serving.decode." + part] == by_step
+    for name in ("serving.paged.live_blocks", "serving.paged.table_slots"):
+        assert name in telemetry.METRIC_HELP
+        assert "`%s`" % name in open(
+            os.path.join(ROOT, "docs", "observability.md")).read()
 
 
 def test_idle_gaps_carry_the_programs_labels(serving_trace):
