@@ -21,13 +21,13 @@
 #   server_ha parameter-server HA suite: replicated groups / failover /
 #             durable slots incl. the slow kill-a-primary e2e (host-only CPU mesh)
 #   serving   paged-KV serving engine: kernel numerics/allocator/scheduler/
-#             engine-vs-sequential equality (fast, host-only; the slow >=32-
-#             stream HTTP e2e runs when invoked directly)
+#             engine-vs-sequential equality (the files live in tests/, so
+#             `unit` runs their fast cases; this stage runs them alone and
+#             adds the slow >=32-stream HTTP e2e)
 #   lint      fwlint invariant analyzer (ratchets on ci/fwlint_baseline.json) + analysis suite
 #   deep      (opt-in, non-blocking) slow-marked deep-model compiles
 #   predict   C predict shim build + compiled-client test
 #   entry     driver contract: graft entry compile + multichip dryrun
-#   bench     (opt-in, needs a TPU) headline benchmark
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -208,15 +208,15 @@ run_serving() {
   # continuous-batching FCFS fairness + recompute preemption, the
   # graph-level cache-overflow contract on both decode paths, and the
   # compile-flat-after-warmup gate — plus the observability plane
-  # (tests_tpu/test_serving_obs.py): phase-clock attribution closure,
+  # (tests/test_serving_obs.py): phase-clock attribution closure,
   # two-engine stats isolation, SLO burn edge, and the serve.py HTTP
   # schemas — plus the prefix-sharing KV reuse plane
-  # (tests_tpu/test_serving_prefix.py): refcount/COW invariants,
+  # (tests/test_serving_prefix.py): refcount/COW invariants,
   # eviction-gain victim picking, sharing bit-identity — and the
-  # speculative-decoding plane (tests_tpu/test_serving_spec.py):
+  # speculative-decoding plane (tests/test_serving_spec.py):
   # multi-query verify numerics and the greedy-acceptance bit-identity
   # contract — and the resilience plane
-  # (tests_tpu/test_serving_resilience.py): deadlines/cancellation
+  # (tests/test_serving_resilience.py): deadlines/cancellation
   # freeing KV blocks (pool invariant), overload shed + Retry-After,
   # supervised warm restart bit-identical to a fault-free oracle,
   # permanent-failure classification, drain semantics, and the serving
@@ -227,15 +227,15 @@ run_serving() {
   # e2e — injected dispatch fault under concurrent HTTP load → warm
   # supervised restart + SIGTERM drain exit 0) run only when this
   # stage is invoked directly, like `elastic`.
-  JAX_PLATFORMS=cpu python -m pytest tests_tpu/test_serving.py \
-    tests_tpu/test_serving_obs.py tests_tpu/test_serving_prefix.py \
-    tests_tpu/test_serving_spec.py tests_tpu/test_serving_resilience.py \
+  JAX_PLATFORMS=cpu python -m pytest tests/test_serving.py \
+    tests/test_serving_obs.py tests/test_serving_prefix.py \
+    tests/test_serving_spec.py tests/test_serving_resilience.py \
     -q -m "not slow"
   if [ "${1:-}" = "with_slow" ]; then
-    JAX_PLATFORMS=cpu python -m pytest tests_tpu/test_serving.py \
-      tests_tpu/test_serving_obs.py tests_tpu/test_serving_prefix.py \
-      tests_tpu/test_serving_spec.py \
-      tests_tpu/test_serving_resilience.py -q -m slow
+    JAX_PLATFORMS=cpu python -m pytest tests/test_serving.py \
+      tests/test_serving_obs.py tests/test_serving_prefix.py \
+      tests/test_serving_spec.py \
+      tests/test_serving_resilience.py -q -m slow
   fi
 }
 
@@ -388,10 +388,6 @@ run_deep() {
   python -m pytest tests/ -q -m slow --durations=10
 }
 
-run_bench() {
-  python bench.py
-}
-
 run_package() {
   # installable-package leg (reference: python/setup.py + tools/pip_package):
   # build a wheel (with the prebuilt native libs), pip-install it into a
@@ -505,17 +501,16 @@ case "$stage" in
   predict) run_predict ;;
   predict_native) run_predict_native ;;
   entry) run_entry ;;
-  bench) run_bench ;;
   tpu) run_tpu ;;
   examples) run_examples ;;
   package) run_package ;;
   all) run_lint; run_native; run_predict; run_predict_native; run_entry;
        run_package; run_faults; run_telemetry; run_pipeline; run_perf;
-       run_guard; run_serving; run_compiler;
+       run_guard; run_compiler;
        JAX_PLATFORMS=cpu python -m pytest tests_tpu/test_elastic.py -q -m "not slow";
        JAX_PLATFORMS=cpu python -m pytest tests_tpu/test_server_ha.py -q -m "not slow";
        run_unit --ignore=tests/test_native.py --ignore=tests/test_kvstore_dist.py \
                 --ignore=tests/test_c_predict.py --ignore=tests/test_predict_native.py \
                 --ignore=tests/test_train_native.py ;;
-  *) echo "unknown stage: $stage (unit|native|compiler|faults|telemetry|pipeline|perf|guard|elastic|server_ha|serving|lint|deep|predict|predict_native|entry|bench|tpu|examples|package|all)"; exit 2 ;;
+  *) echo "unknown stage: $stage (unit|native|compiler|faults|telemetry|pipeline|perf|guard|elastic|server_ha|serving|lint|deep|predict|predict_native|entry|tpu|examples|package|all)"; exit 2 ;;
 esac

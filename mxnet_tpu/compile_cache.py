@@ -84,9 +84,9 @@ def resolve_dir(explicit=None, entry_point=False):
     files there and ``aot/`` and ``meta/`` go beside them. Else an
     ``explicit`` argument (a ``--cache-dir`` flag), else
     ``MXNET_COMPILE_CACHE_DIR``, else :data:`DEFAULT_DIR` for an entry
-    point (``chip_smoke.py``, ``bench.py``, ``tools/serve.py``,
-    ``tools/bench_serving.py``) and None — cache off — for a library
-    import."""
+    point (``chip_smoke.py``, ``benchmark/run.py``, ``tools/serve.py``,
+    the children of ``tools/launch.py --elastic``) and None — cache off —
+    for a library import."""
     return (_env_str(ENV_JAX_DIR) or explicit or _env_str(ENV_DIR)
             or (DEFAULT_DIR if entry_point else None))
 

@@ -754,8 +754,8 @@ def recompile_log():
 
 def summary(include_recompiles=True):
     """Compact compile summary: program count, total compile count/seconds,
-    total run seconds, and recompile attributions — embedded in bench.py's
-    BENCH json and in cluster-stats snapshots. ``include_recompiles=False``
+    total run seconds, and recompile attributions — embedded in
+    cluster-stats snapshots. ``include_recompiles=False``
     skips copying the bounded recompile log (periodic publishers that only
     want the counts pair it with :func:`last_recompile`)."""
     rows = program_table()

@@ -345,9 +345,9 @@ def histogram(name, buckets=None, **labels):
 
 # Input-pipeline stage attribution (docs/perf.md §pipeline, docs/
 # observability.md): every stage of the rec-file path records its wall into
-# ONE histogram name keyed by a `stage` label, so a dashboard (or
-# tools/bench_pipeline.py's attribution table) reads the whole ladder with
-# one query. Canonical stages:
+# ONE histogram name keyed by a `stage` label, so a dashboard reads the
+# whole ladder with one query (tests_tpu/test_pipeline_feed.py does).
+# Canonical stages:
 #   decode    per-record JPEG decode + augment (ImageRecordIter workers)
 #   assemble  per-batch host buffer fill (ImageRecordIter batcher)
 #   upload    per-batch host->device transfer + on-device wire decode
@@ -744,7 +744,6 @@ METRIC_HELP = {
     "guard.checkpoint_errors":
         "failed guard mid-epoch checkpoint writes (always-on)",
     "fault.injections": "fired fault-injection rules by point (always-on)",
-    "bench.imgs_per_sec": "bench.py headline throughput",
     "serving.kv_blocks_total": "usable KV pool blocks (pool size minus the "
                                "reserved trash block)",
     "serving.kv_blocks_used": "KV pool blocks currently allocated to "

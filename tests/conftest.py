@@ -20,12 +20,29 @@ if "xla_force_host_platform_device_count" not in flags:
     ).strip()
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _telemetry_flag_as_found():
+    """``ServingEngine()`` enables telemetry process-wide and nothing turns
+    it off again. ``--dist loadfile`` runs several files in one worker
+    process, so every file hands the flag on as it found it: its neighbour
+    starts where a process of its own would."""
+    from mxnet_tpu import telemetry
+
+    was = telemetry.enabled()
+    yield
+    (telemetry.enable if was else telemetry.disable)()
 
 
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "faults: fault-injection / robustness tests "
         "(ci/run_tests.sh faults tier; suite in tests_tpu/test_fault_tolerance.py)")
+    config.addinivalue_line(
+        "markers", "serving: paged-KV serving-engine tests "
+        "(ci/run_tests.sh serving runs them alone, slow cases included)")
     config.addinivalue_line("markers", "slow: long-running tests")

@@ -231,7 +231,11 @@ def test_matlab_call_sequence_matches_python(tmp_path):
     np.testing.assert_allclose(out_matlab, expected.T, rtol=1e-4, atol=1e-5)
 
     # artifact mode (model.load_artifact): same call sequence against the
-    # Python-free native runtime — PartialOut with 0 outputs must bind
+    # Python-free native runtime — PartialOut with 0 outputs must bind.
+    # That runtime needs a PJRT plugin (its default is libtpu).
+    if not os.environ.get("MXTPU_PJRT_PLUGIN"):
+        pytest.skip("the shim half passed; artifact mode needs a PJRT "
+                    "plugin (set MXTPU_PJRT_PLUGIN)")
     r = subprocess.run(["make", "c_predict_native"], cwd=SRC,
                        capture_output=True, text=True)
     if r.returncode != 0:

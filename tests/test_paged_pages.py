@@ -17,6 +17,11 @@ from mxnet_tpu.serving.kv_cache import KVBlockPool
 
 # r -> (heads, head_dim) that page_shape packs r to a row
 HEADS = {1: (3, 32), 2: (4, 64), 4: (4, 32)}
+# plain (H, D) rows built by hand, one head a row, as the serving engine's
+# own suites build them (the cases came from tests/test_serving.py and
+# tests/test_serving_spec.py): name -> heads and _rand's sizes
+PLAIN = {"plain-2x32": dict(heads=(2, 32), B=4, N=9),
+         "plain-2x8-bs4": dict(heads=(2, 8), bs=4, N=16, nb=4)}
 DTYPES = {"fp32": (jnp.float32, 1e-6), "bf16": (jnp.bfloat16, 2e-2)}
 
 
@@ -40,11 +45,12 @@ def test_page_shape(heads, head_dim, want):
     assert pool.nbytes() == 2 * pool.k_pages.size * 4
 
 
-def _rand(r, dtype, lanes, whole_pool, seed=0, B=3, bs=16, N=7, nb=3, L=3):
+def _rand(r, dtype, lanes, whole_pool, seed=0, B=3, bs=16, N=7, nb=3, L=3,
+          heads=None):
     """q (B, T, H, D) with pages packed r heads to a row, as one layer's
     4-D pages or the 5-D pool with a layer index."""
     rng = np.random.RandomState(seed)
-    H, D = HEADS[r]
+    H, D = heads or HEADS[r]
     q = rng.randn(B, lanes, H, D)
     shape = ((L,) if whole_pool else ()) + (N, bs, H // r, D * r)
     kp, vp = rng.randn(*shape), rng.randn(*shape)
@@ -144,12 +150,14 @@ _KERNEL_CASES = [
     (case, r, dt, None, (dt == "bf16") != (r == 2))
     for case in RAGGED for r in HEADS for dt in DTYPES
 ] + [("boundaries", "aligned", "fp32", None, True),
-     ("lanes", "aligned", "bf16", None, False)]
+     ("lanes", "aligned", "bf16", None, False),
+     ("random", "plain-2x32", "fp32", None, False),
+     ("random", "plain-2x8-bs4", "fp32", 3, False)]
 
 
 @pytest.mark.parametrize(
     "case,r,dtype,lanes,whole_pool", _KERNEL_CASES,
-    ids=["-".join([c, "r%s" % r, dt,
+    ids=["-".join([c, r if r in PLAIN else "r%s" % r, dt,
                    "decode" if n is None and c != "lanes" else "verify",
                    "5d-layer" if w else "4d"])
          for c, r, dt, n, w in _KERNEL_CASES])
@@ -157,7 +165,10 @@ def test_pallas_interpret_matches_reference(case, r, dtype, lanes, whole_pool,
                                             monkeypatch):
     """The kernel program the TPU runs, interpreted on the CPU."""
     dt, tol = DTYPES[dtype]
-    if case == "random":
+    if r in PLAIN:
+        q, kp, vp, bt, cl, layer = _rand(1, dt, lanes or 1, whole_pool,
+                                         seed=len(r), **PLAIN[r])
+    elif case == "random":
         q, kp, vp, bt, cl, layer = _rand(r, dt, lanes or 1, whole_pool,
                                          seed=r)
     else:

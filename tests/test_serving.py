@@ -7,8 +7,8 @@ driving >=32 concurrent variable-length HTTP requests through
 ``tools/serve.py`` and comparing byte-for-byte against single-stream
 decoding.
 
-Host-side only: runs on a CPU-only machine (tests_tpu/conftest.py exempts
-this file from the hardware gate). `ci/run_tests.sh serving` is the CI tier.
+Host-side only: part of tier-1 (tests/conftest.py pins jax to the CPU);
+`ci/run_tests.sh serving` runs the serving files alone, slow cases included.
 """
 import importlib
 import json
@@ -160,16 +160,10 @@ def test_paged_reference_empty_stream_reads_exact_zero():
     np.testing.assert_allclose(pal, out, rtol=1e-6, atol=1e-6)
 
 
-def test_paged_pallas_kernel_matches_reference():
-    """The Pallas kernel (interpret mode on CPU — same kernel program the
-    TPU runs) reproduces the pure-XLA reference."""
-    rng = np.random.RandomState(2)
-    q, kp, vp, bt, cl = _rand_paged(rng, B=4, H=2, D=32, bs=16, N=9, nb=3)
-    ref = A.paged_attention_reference(q, kp, vp, bt, cl)
-    pal = A._paged_pallas(q, kp, vp, bt, cl,
-                          1.0 / np.sqrt(q.shape[-1]), interpret=True)
-    np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
-                               rtol=1e-6, atol=1e-6)
+# (the Pallas kernel in interpret mode against this reference, ragged
+# contexts, both page forms: tests/test_paged_pages.py::
+# test_pallas_interpret_matches_reference, which took this file's case as
+# its "plain-2x32" parameter)
 
 
 def test_paged_masked_slots_contribute_exactly_zero():
@@ -618,8 +612,8 @@ def test_step_failure_aborts_not_strands():
 
 def test_step_failure_aborts_direct_drivers_too():
     """The abort-on-failure contract lives in step() itself, not run_loop:
-    a direct step() driver (generate(), tools/bench_serving.py's polling
-    loop) must also leave the engine aborted — on TPU the pool pages were
+    a direct step() driver (generate(), a polling loop) must also leave
+    the engine aborted — on TPU the pool pages were
     donated into the failed dispatch and cannot be dispatched again."""
     eng = ServingEngine(_config(), seed=SEED)
     boom = RuntimeError("boom: injected device failure")

@@ -9,9 +9,9 @@ by serving_report.py and trace_merge.py — capped by a slow e2e that
 drives a preemption + cold-bucket compiles through a telemetry JSONL
 sink and proves the waterfall/trace tools close the attribution.
 
-Host-side only: runs on a CPU-only machine (tests_tpu/conftest.py
-exempts this file from the hardware gate). `ci/run_tests.sh serving` is
-the CI tier.
+Host-side only: part of tier-1 (tests/conftest.py pins jax to the CPU);
+`ci/run_tests.sh serving` runs the serving files alone, slow cases
+included.
 """
 import json
 import os

@@ -4,8 +4,8 @@ index, eviction-gain victim picking, and the engine-level contracts —
 logits/token parity with sharing on, concurrency multiplication at a
 fixed pool size, and preemption invisibility with shared blocks in play.
 
-Host-side only (tests_tpu/conftest.py exempts this file from the hardware
-gate). ``ci/run_tests.sh serving`` is the CI tier.
+Host-side only: part of tier-1 (tests/conftest.py pins jax to the CPU);
+``ci/run_tests.sh serving`` runs the serving files alone.
 """
 import importlib
 import os

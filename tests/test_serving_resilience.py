@@ -12,9 +12,9 @@ compile cache, finishes every admitted request bit-identical to the
 oracle, sheds the overflow with clean 503s, and drains to exit 0 on
 SIGTERM.
 
-Host-side only: runs on a CPU-only machine (tests_tpu/conftest.py
-exempts this file from the hardware gate). `ci/run_tests.sh serving` is
-the CI tier.
+Host-side only: part of tier-1 (tests/conftest.py pins jax to the CPU);
+`ci/run_tests.sh serving` runs the serving files alone, slow cases
+included.
 """
 import collections
 import json

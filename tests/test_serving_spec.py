@@ -7,8 +7,9 @@ flat-compile-count gate, and acceptance accounting — capped by a slow
 e2e driving 32 concurrent shared-prefix HTTP streams with speculative
 decoding AND prefix sharing on.
 
-Host-side only (tests_tpu/conftest.py exempts this file from the
-hardware gate). ``ci/run_tests.sh serving`` is the CI tier.
+Host-side only: part of tier-1 (tests/conftest.py pins jax to the CPU);
+``ci/run_tests.sh serving`` runs the serving files alone, slow cases
+included.
 """
 import importlib
 import json
@@ -105,14 +106,9 @@ def test_multi_reference_matches_per_lane_single_query():
         np.testing.assert_allclose(out[:, t], ref, rtol=1e-5, atol=1e-5)
 
 
-def test_multi_pallas_interpret_matches_reference():
-    q, kp, vp, tables, ctx = _multi_case(seed=1)
-    want = np.asarray(A.paged_attention_multi_reference(q, kp, vp, tables,
-                                                        ctx))
-    got = np.asarray(A._paged_pallas_multi(q, kp, vp, tables, ctx,
-                                           sm_scale=q.shape[-1] ** -0.5,
-                                           interpret=True))
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+# (the multi-query Pallas kernel in interpret mode against the reference:
+# tests/test_paged_pages.py::test_pallas_interpret_matches_reference, which
+# took this file's case as its "plain-2x8-bs4" parameter)
 
 
 def test_multi_zero_context_lane_is_zero_pinned():

@@ -301,12 +301,16 @@ def test_span_with_everything_off_is_only_an_annotation():
     telemetry.disable()
     assert not profiler.is_running()
     before = set(telemetry.dump()["histograms"])
-    n = 20000
-    t0 = time.perf_counter()
-    for _ in range(n):
-        with telemetry.span("spans.off", "test", batch=3):
-            pass
-    per_span = (time.perf_counter() - t0) / n
+    # the best of five batches: the gate's other workers take the cores
+    # away for milliseconds at a time, which a single mean would book to
+    # the span
+    n, per_span = 4000, float("inf")
+    for _ in range(5):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            with telemetry.span("spans.off", "test", batch=3):
+                pass
+        per_span = min(per_span, (time.perf_counter() - t0) / n)
     assert per_span < 5e-6, "a span costs %.2f us with all off" % (
         per_span * 1e6)
     s = telemetry.span("spans.off", "test")

@@ -25,11 +25,8 @@ _HOST_ONLY_FILES = {"test_fault_tolerance.py", "test_telemetry.py",
                     "test_pipeline_feed.py", "test_guard.py",
                     "test_analysis.py", "test_elastic.py",
                     "test_cluster_obs.py", "test_native_decode.py",
-                    "test_compileobs.py", "test_serving.py",
-                    "test_serving_obs.py", "test_serving_prefix.py",
-                    "test_serving_spec.py", "test_serving_resilience.py",
-                    "test_kv_overlap.py", "test_graphpass.py",
-                    "test_server_ha.py"}
+                    "test_compileobs.py", "test_kv_overlap.py",
+                    "test_graphpass.py", "test_server_ha.py"}
 
 
 def pytest_configure(config):
@@ -48,8 +45,6 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "server_ha: parameter-server HA (replication / failover) "
                    "tests (host-only)")
-    config.addinivalue_line(
-        "markers", "serving: paged-KV serving-engine tests (host-only)")
     config.addinivalue_line(
         "markers", "perf: communication-overlap / perf-smoke tests "
                    "(host-only)")

@@ -232,7 +232,7 @@ def test_ndarrayiter_unseeded_shuffle_refuses_reshard():
 
 @pytest.fixture(scope="module")
 def small_rec(tmp_path_factory):
-    from tools.bench_pipeline import gen_dataset, pack
+    from rec_fixtures import gen_dataset, pack
 
     workdir = str(tmp_path_factory.mktemp("rec"))
     img_dir, lst = gen_dataset(workdir, n=24, size=32)

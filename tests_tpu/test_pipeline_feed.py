@@ -94,8 +94,7 @@ def test_wire_ndarrayiter_advertises_decoded_desc():
 
 def test_imagerecorditer_uint8_wire(tmp_path):
     pytest.importorskip("PIL")
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-    from tools.bench_pipeline import gen_dataset, pack
+    from rec_fixtures import gen_dataset, pack
 
     n, size = 16, 16
     img_dir, lst = gen_dataset(str(tmp_path), n, size)
@@ -230,8 +229,7 @@ def test_feed_propagates_inner_exception():
 # ---------------------------------------------------------------- telemetry
 def test_pipeline_stage_histograms_populate(tmp_path, monkeypatch):
     pytest.importorskip("PIL")
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-    from tools.bench_pipeline import gen_dataset, pack
+    from rec_fixtures import gen_dataset, pack
 
     n, size = 16, 16  # gen_dataset textures need size to be a multiple of 8
     img_dir, lst = gen_dataset(str(tmp_path), n, size)
