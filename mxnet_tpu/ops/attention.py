@@ -85,8 +85,9 @@ def _block_update(q, k_blk, v_blk, m, l, acc, sm_scale, mask=None,
     return m_new, l_new, acc_new
 
 
-def _scan_forward(q, k, v, causal, sm_scale, block_k):
-    """Pure-XLA flash forward: lax.scan over KV blocks. Returns (out, lse) f32."""
+def _scan_forward(q, k, v, causal, sm_scale, block_k, window=None):
+    """Pure-XLA flash forward: lax.scan over KV blocks. Returns (out, lse) f32.
+    ``window``: key j is visible to query i only if ``i - j < window``."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
     block_k = min(block_k, sk)
@@ -113,6 +114,8 @@ def _scan_forward(q, k, v, causal, sm_scale, block_k):
             mask = mask & (qi[:, None] >= ki[None, :])
         else:
             mask = jnp.broadcast_to(mask, (sq, block_k))
+        if window is not None:
+            mask = mask & (qi[:, None] - ki[None, :] < window)
         m, l, acc = _block_update(qf, k_blk, v_blk, m, l, acc, sm_scale, mask,
                                   precision=prec)
         return (m, l, acc), None
@@ -127,8 +130,14 @@ def _scan_forward(q, k, v, causal, sm_scale, block_k):
     return out, lse
 
 
-def _pallas_forward(q, k, v, causal, sm_scale, block_q=512, block_k=1024, interpret=False):
+def _pallas_forward(q, k, v, causal, sm_scale, block_q=512, block_k=1024,
+                    interpret=False, window=None):
     """Pallas TPU flash-attention forward.
+
+    ``window`` (sliding-window attention): key j is visible to query i only
+    if ``i - j < window``; KV blocks wholly behind a q block's band are
+    skipped like those above the diagonal, and the KV block is ``block_q``
+    long so that the band skips something.
 
     Grid (batch*heads, q_blocks, kv_blocks) with the KV axis innermost: TPU
     executes the grid sequentially along the last axis, so (m, l, acc) live in
@@ -142,6 +151,8 @@ def _pallas_forward(q, k, v, causal, sm_scale, block_q=512, block_k=1024, interp
 
     b, h, sq, d = q.shape
     sk = k.shape[2]
+    if window is not None:
+        block_k = min(block_k, block_q)
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     n_q = -(-sq // block_q)  # ragged tails are masked inside the kernel
@@ -160,6 +171,10 @@ def _pallas_forward(q, k, v, causal, sm_scale, block_q=512, block_k=1024, interp
         # causal: skip blocks strictly above the diagonal
         first_q_pos = qi_blk * block_q + block_q - 1  # last row of the q block
         run = (kj * block_k <= first_q_pos) if causal else True
+        if window is not None:
+            # the block's last key is inside the first row's window
+            run = run & (kj * block_k + block_k - 1
+                         > qi_blk * block_q - window)
 
         @pl.when(run)
         def _step():
@@ -172,6 +187,8 @@ def _pallas_forward(q, k, v, causal, sm_scale, block_q=512, block_k=1024, interp
             mask = k_pos < sk
             if causal:
                 mask = mask & (q_pos >= k_pos)
+            if window is not None:
+                mask = mask & (q_pos - k_pos < window)
             s = jnp.where(mask, s, _NEG_INF)
             m = m_ref[:]
             m_blk = jnp.max(s, axis=-1)
@@ -429,16 +446,29 @@ def _scan_backward(q, k, v, out, lse, g, causal, sm_scale, block_k):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def flash_attention(q, k, v, causal=False, sm_scale=None, block_k=256):
-    """Memory-efficient attention over (batch, heads, seq, head_dim)."""
-    out, _ = _forward_impl(q, k, v, causal, sm_scale, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def flash_attention(q, k, v, causal=False, sm_scale=None, block_k=256,
+                    window=None):
+    """Memory-efficient attention over (batch, heads, seq, head_dim).
+    ``window`` (forward only): key j is visible to query i only if
+    ``i - j < window`` — with ``causal``, a sliding window of ``window``
+    keys that ends at the query's own."""
+    out, _ = _forward_impl(q, k, v, causal, sm_scale, block_k, window)
     return out
 
 
-def _forward_impl(q, k, v, causal, sm_scale, block_k):
+def _forward_impl(q, k, v, causal, sm_scale, block_k, window=None):
     sm_scale = _scale(sm_scale, q.shape[-1])
-    if _pallas_shapes_ok(q, k):
+    if window is not None:
+        kw = {"causal": causal, "sm_scale": sm_scale, "window": int(window)}
+        if _pallas_shapes_ok(q, k):
+            out, lse = lax.platform_dependent(
+                q, k, v, tpu=functools.partial(_pallas_forward, **kw),
+                default=functools.partial(_scan_forward, block_k=block_k,
+                                          **kw))
+        else:
+            out, lse = _scan_forward(q, k, v, block_k=block_k, **kw)
+    elif _pallas_shapes_ok(q, k):
         # platform selected at LOWERING time, not trace time: the same traced
         # function may compile for the TPU (Pallas kernel) or for CPU (scan) —
         # an array's placement isn't knowable from a tracer
@@ -453,12 +483,15 @@ def _forward_impl(q, k, v, causal, sm_scale, block_k):
     return out.astype(q.dtype), lse
 
 
-def _fa_fwd(q, k, v, causal, sm_scale, block_k):
-    out, lse = _forward_impl(q, k, v, causal, sm_scale, block_k)
+def _fa_fwd(q, k, v, causal, sm_scale, block_k, window):
+    out, lse = _forward_impl(q, k, v, causal, sm_scale, block_k, window)
     return out, (q, k, v, out, lse)
 
 
-def _fa_bwd(causal, sm_scale, block_k, res, g):
+def _fa_bwd(causal, sm_scale, block_k, window, res, g):
+    if window is not None:
+        raise NotImplementedError(
+            "flash_attention(window=) is forward-only (serving prefill)")
     q, k, v, out, lse = res
     scale = _scale(sm_scale, q.shape[-1])
     if _pallas_shapes_ok(q, k):
@@ -639,9 +672,23 @@ get_op("_contrib_CachedMultiHeadAttention")._infer_shape = _cached_mha_infer
 # layer's ``(N, bs, G, W)`` or as the whole pool ``(L, N, bs, G, W)`` with a
 # static ``layer``: the kernels address the pool in place, because a
 # ``k_pages[layer]`` slice is a copy of that layer on every step.
-def _heads_per_row(q, k_pages):
-    """r for q ``(.., H, D)`` against pages ``(.., bs, G, W)``."""
+#
+# ``head_major`` pages are ``(.., N, G, bs, W)``: a block holds each of its G
+# rows as a ``(bs, W)`` slab, whole tiles for ANY G (ten rows of 128 lanes
+# would be padded to sixteen in ``(bs, G, W)`` order). They take r = 1 only:
+# a row is one head of W lanes. Grouped queries (several query heads reading
+# one K/V row) need no format of their own: they ride as extra query lanes
+# with the same context length.
+def _heads_per_row(q, k_pages, head_major=False):
+    """r for q ``(.., H, D)`` against pages ``(.., bs, G, W)``, or
+    ``(.., G, bs, W)`` if ``head_major``."""
     (h, d), (g, w) = q.shape[-2:], k_pages.shape[-2:]
+    if head_major:
+        g = k_pages.shape[-3]
+        if (g, w) != (h, d):
+            raise ValueError("head-major pages of %d rows of %d cannot hold "
+                             "q's %d heads of %d" % (g, w, h, d))
+        return 1
     r = w // d
     if (g * r, r * d) != (h, w):
         raise ValueError("pages with rows %s cannot hold q's %d heads of %d"
@@ -658,12 +705,14 @@ def _whole_pool(k_pages, v_pages, layer):
     return k_pages, v_pages, int(layer)
 
 
-def _gather_tokens(pages, block_tables, h, d):
+def _gather_tokens(pages, block_tables, h, d, head_major=False):
     """Each sequence's pages (N, bs, G, W) in position order, unpacked:
     (B, T, H, D) f32."""
     b, nb = block_tables.shape
     x = jnp.take(pages, block_tables, axis=0)       # (B, nb, bs, G, W)
-    return x.reshape(b, nb * pages.shape[1], h, d).astype(jnp.float32)
+    if head_major:                                  # (B, nb, G, bs, W)
+        x = x.transpose(0, 1, 3, 2, 4)
+    return x.reshape(b, -1, h, d).astype(jnp.float32)
 
 
 def paged_attention_reference(q, k_pages, v_pages, block_tables, context_lens,
@@ -729,7 +778,8 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
 
 # --------------------------------------------- paged multi-query (verify)
 def paged_attention_multi_reference(q, k_pages, v_pages, block_tables,
-                                    context_lens, sm_scale=None, layer=None):
+                                    context_lens, sm_scale=None, layer=None,
+                                    window=None, head_major=False):
     """Pure-XLA multi-query paged attention — q-length > 1 per sequence
     with PER-LANE context lengths. The speculative-decoding verify pass
     and the CPU/CI lowering of the Pallas kernel below.
@@ -747,20 +797,28 @@ def paged_attention_multi_reference(q, k_pages, v_pages, block_tables,
     context_lens: (B, T) int32     — valid pool positions PER LANE
                                      (monotone over t for a causal window)
 
+    window:       a lane reads only its last ``window`` positions, those
+                  ``>= context_len - window`` (table slots wholly behind
+                  every lane's window may name any block)
+    head_major:   pages are ``(.., N, G, bs, W)``
+
     Returns (B, T, H, D) in q.dtype. T == 1 with context_lens (B, 1)
     is :func:`paged_attention_reference`. A lane with context_len == 0
     returns all zeros, like the single-query oracle.
     """
     sm_scale = _scale(sm_scale, q.shape[-1])
-    _heads_per_row(q, k_pages)
+    _heads_per_row(q, k_pages, head_major)
     k_pages, v_pages, layer = _whole_pool(k_pages, v_pages, layer)
     h, d = q.shape[-2:]
-    k = _gather_tokens(k_pages[layer], block_tables, h, d)   # (B, K, H, D)
-    v = _gather_tokens(v_pages[layer], block_tables, h, d)
+    k = _gather_tokens(k_pages[layer], block_tables, h, d,
+                       head_major)                           # (B, K, H, D)
+    v = _gather_tokens(v_pages[layer], block_tables, h, d, head_major)
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k,
                    precision=lax.Precision.HIGHEST) * sm_scale
-    valid = (jnp.arange(k.shape[1])[None, None, :]
-             < context_lens[:, :, None])                     # (B, T, K)
+    pos = jnp.arange(k.shape[1])[None, None, :]
+    valid = pos < context_lens[:, :, None]                   # (B, T, K)
+    if window is not None:
+        valid = valid & (pos >= context_lens[:, :, None] - window)
     s = jnp.where(valid[:, None, :, :], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     # all-masked lanes (context_len == 0) softmax to uniform and would
@@ -811,7 +869,8 @@ def _paged_blocks_per_fetch(bs, g, w, dtype, nb):
 
 
 def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
-                        sm_scale, layer=None, interpret=False):
+                        sm_scale, layer=None, interpret=False, window=None,
+                        head_major=False):
     """Pallas TPU ragged-paged-attention kernel, T query lanes per sequence
     (T = 1 is the decode step, T = k + 1 the speculative verify pass).
 
@@ -845,42 +904,78 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
     An async copy cuts HBM between whole (8, 128) tiles, so page rows with
     ``G % 8`` or ``W % 128`` left over are padded at the edge — a copy of
     the layer's pages a call; ``(8, 128)`` and ``(16, 128)`` rows (both
-    served configurations of the benchmark) are read where they lie.
+    served configurations of the benchmark) are read where they lie. So
+    are ``head_major`` pages ``(G, bs, W)`` of any G (ten rows of 128): a
+    row's ``(bs, W)`` slab is whole tiles, the per-block arithmetic is the
+    same with the slots along the sublanes, and the state is
+    ``(T, G, 1, W)``.
+
+    ``window``: a stream's walk starts at the block that holds position
+    ``min_t context_lens[i, t] - window`` instead of at its first, and a
+    lane masks what lies before its own ``context_len - window``: table
+    slots behind the window are never read and may name any block.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    r = _heads_per_row(q, k_pages)
+    r = _heads_per_row(q, k_pages, head_major)
     k_pages, v_pages, layer = _whole_pool(k_pages, v_pages, layer)
     b, tq, h, d = q.shape
-    rows = k_pages.shape[3:]
-    q = q.reshape((b, tq) + rows)
-    # rows that do not fill their tiles ((6, 128), (12, 64)): see above
-    g, w = -(-rows[0] // 8) * 8, -(-rows[1] // 128) * 128
-    if (g, w) != rows:
-        def pad(x):
-            return jnp.pad(x, [(0, 0)] * (x.ndim - 2)
-                           + [(0, g - rows[0]), (0, w - rows[1])])
-        q, k_pages, v_pages, layer = (pad(q), pad(k_pages[layer][None]),
-                                      pad(v_pages[layer][None]), 0)
-    bs = k_pages.shape[2]
     nb = block_tables.shape[1]
-    c = _paged_blocks_per_fetch(bs, g, w, k_pages.dtype, nb)
+    if head_major:
+        g, bs, w = k_pages.shape[2:]
+        sublanes = 32 // jnp.dtype(k_pages.dtype).itemsize
+        if w % 128 or bs % sublanes:
+            raise ValueError("head-major pages need whole (%d, 128) tiles a "
+                             "row, not (%d, %d)" % (sublanes, bs, w))
+        rows = (g, w)
+        q = q.reshape(b, tq, g, 1, w)
+        # a row's slab is whole tiles: the page's bytes as they are
+        c = _paged_blocks_per_fetch(bs * g // sublanes, sublanes, w,
+                                    k_pages.dtype, nb)
+        page, state = (g, bs, w), (tq, g, 1, w)
+        slot_axis = 1
+    else:
+        rows = k_pages.shape[3:]
+        q = q.reshape((b, tq) + rows)
+        # rows that do not fill their tiles ((6, 128), (12, 64)): see above
+        g, w = -(-rows[0] // 8) * 8, -(-rows[1] // 128) * 128
+        if (g, w) != rows:
+            def pad(x):
+                return jnp.pad(x, [(0, 0)] * (x.ndim - 2)
+                               + [(0, g - rows[0]), (0, w - rows[1])])
+            q, k_pages, v_pages, layer = (pad(q), pad(k_pages[layer][None]),
+                                          pad(v_pages[layer][None]), 0)
+        bs = k_pages.shape[2]
+        c = _paged_blocks_per_fetch(bs, g, w, k_pages.dtype, nb)
+        page, state = (bs, g, w), (tq, g, w)
+        slot_axis = 0
 
     def kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref,
                k_buf, v_buf, sems, slot_ref, m_ref, l_ref, acc_ref):
         i = pl.program_id(0)  # sequence
+
+        def first_block(seq):
+            """The table slot a stream's walk starts at: the block of the
+            shortest lane's first visible position."""
+            shortest = functools.reduce(
+                jnp.minimum, [cl_ref[seq, t] for t in range(tq)])
+            return jnp.maximum(shortest - window, 0) // bs
 
         def live_blocks(seq):
             # SMEM yields scalars only: one read per lane, T is static
             longest = functools.reduce(
                 jnp.maximum, [cl_ref[seq, t] for t in range(tq)])
             # one block for an empty stream; never past the table
-            return jnp.clip(pl.cdiv(longest, bs), 1, nb)
+            n = jnp.clip(pl.cdiv(longest, bs), 1, nb)
+            return n if window is None else n - first_block(seq)
 
         def copies(seq, blk, slot, j):
-            """The two async copies (K, V) of table slot ``blk`` of stream
-            ``seq`` into place ``j`` of ``slot``; one semaphore a place."""
+            """The two async copies (K, V) of the ``blk``-th block of
+            stream ``seq``'s walk into place ``j`` of ``slot``; one
+            semaphore a place."""
+            if window is not None:
+                blk = blk + first_block(seq)
             page = bt_ref[seq, blk]
             return [pltpu.make_async_copy(hbm.at[layer, page],
                                           buf.at[slot, j], sems.at[slot, j])
@@ -899,9 +994,9 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
             slot_ref[0] = 0
             start_fetch(0, 0, 0, live_blocks(0))
 
-        m_ref[:] = jnp.full((tq, g, w), _NEG_INF, jnp.float32)
-        l_ref[:] = jnp.zeros((tq, g, w), jnp.float32)
-        acc_ref[:] = jnp.zeros((tq, g, w), jnp.float32)
+        m_ref[:] = jnp.full(state, _NEG_INF, jnp.float32)
+        l_ref[:] = jnp.zeros(state, jnp.float32)
+        acc_ref[:] = jnp.zeros(state, jnp.float32)
         ctx = [cl_ref[i, t] for t in range(tq)]
         n_blk = live_blocks(i)
         n_fetch = pl.cdiv(n_blk, c)
@@ -928,12 +1023,35 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
                     cp.wait()
                 kv = k_buf[slot, j].astype(jnp.float32)   # (bs, G, W)
                 vv = v_buf[slot, j].astype(jnp.float32)
+                if window is not None:
+                    blk = blk + first_block(i)
                 pos = blk * bs + jax.lax.broadcasted_iota(
-                    jnp.int32, (bs, g, w), 0)
+                    jnp.int32, page, slot_axis)
                 for t in range(tq):
                     qv = q_ref[0, t].astype(jnp.float32)            # (G, W)
+                    if head_major:      # the same lines, slots on axis 1
+                        s = _head_sums(qv * kv, r) * sm_scale    # (G, bs, W)
+                        seen = pos < ctx[t]
+                        if window is not None:
+                            seen = seen & (pos >= ctx[t] - window)
+                        s = jnp.where(seen, s, _NEG_INF)
+                        m = m_ref[t]                             # (G, 1, W)
+                        m_new = jnp.maximum(
+                            m, jnp.max(s, axis=1, keepdims=True))
+                        p = jnp.exp(s - m_new)
+                        scale = jnp.exp(m - m_new)
+                        m_ref[t] = m_new
+                        l_ref[t] = l_ref[t] * scale + jnp.sum(
+                            p, axis=1, keepdims=True)
+                        acc_ref[t] = acc_ref[t] * scale + jnp.sum(
+                            p * vv, axis=1, keepdims=True)
+                        continue
                     s = _head_sums(qv[None] * kv, r) * sm_scale  # (bs, G, W)
-                    s = jnp.where(pos < ctx[t], s, _NEG_INF)
+                    if window is None:
+                        s = jnp.where(pos < ctx[t], s, _NEG_INF)
+                    else:
+                        s = jnp.where((pos < ctx[t])
+                                      & (pos >= ctx[t] - window), s, _NEG_INF)
                     m = m_ref[t]
                     m_new = jnp.maximum(m, jnp.max(s, axis=0))
                     p = jnp.exp(s - m_new[None])
@@ -956,54 +1074,59 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
             out = jnp.where(ctx[t] > 0, out, 0.0)
             o_ref[0, t] = out.astype(o_ref.dtype)
 
-    q_spec = pl.BlockSpec((1, tq, g, w), lambda i, bt, cl: (i, 0, 0, 0))
+    q_spec = pl.BlockSpec((1,) + state,
+                          lambda i, bt, cl: (i,) + (0,) * len(state))
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
         in_specs=[q_spec, pool_spec, pool_spec],
         out_specs=q_spec,
-        scratch_shapes=[pltpu.VMEM((2, c, bs, g, w), k_pages.dtype),
-                        pltpu.VMEM((2, c, bs, g, w), v_pages.dtype),
+        scratch_shapes=[pltpu.VMEM((2, c) + page, k_pages.dtype),
+                        pltpu.VMEM((2, c) + page, v_pages.dtype),
                         pltpu.SemaphoreType.DMA((2, c)),
                         pltpu.SMEM((1,), jnp.int32)]
-        + [pltpu.VMEM((tq, g, w), jnp.float32)] * 3,
+        + [pltpu.VMEM(state, jnp.float32)] * 3,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, tq, g, w), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b,) + state, q.dtype),
         # the scratch carries one stream's prefetch into the next step
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
       q, k_pages, v_pages)
+    if head_major:
+        return out.reshape(b, tq, h, d)
     return out[:, :, :rows[0], :rows[1]].reshape(b, tq, h, d)
 
 
 def paged_attention_multi(q, k_pages, v_pages, block_tables, context_lens,
-                          sm_scale=None, layer=None):
+                          sm_scale=None, layer=None, window=None,
+                          head_major=False):
     """Multi-query paged attention over a shared KV block pool: q is
     (B, T, H, D), context_lens (B, T) per lane — the speculative-decoding
     verify pass scores all T = k+1 window positions in this ONE dispatch.
 
     Platform selected at LOWERING time like :func:`flash_attention`: the
     Pallas kernel on TPU, the pure-XLA gather reference everywhere else.
-    Serving-only (no vjp).
+    Serving-only (no vjp). ``window`` and ``head_major``: see
+    :func:`paged_attention_multi_reference`.
     """
     sm_scale = _scale(sm_scale, q.shape[-1])
+    kw = {"sm_scale": sm_scale, "layer": layer}
+    if window is not None or head_major:    # the existing calls' jaxprs stay
+        kw.update(window=window, head_major=head_major)
     if _paged_shapes_ok(q, k_pages):
         return lax.platform_dependent(
             q, k_pages, v_pages, block_tables, context_lens,
-            tpu=functools.partial(_paged_pallas_multi, sm_scale=sm_scale,
-                                  layer=layer),
-            default=functools.partial(paged_attention_multi_reference,
-                                      sm_scale=sm_scale, layer=layer),
+            tpu=functools.partial(_paged_pallas_multi, **kw),
+            default=functools.partial(paged_attention_multi_reference, **kw),
         )
     return paged_attention_multi_reference(q, k_pages, v_pages, block_tables,
-                                           context_lens, sm_scale=sm_scale,
-                                           layer=layer)
+                                           context_lens, **kw)
 
 
 @register(

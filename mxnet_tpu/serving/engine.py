@@ -44,14 +44,25 @@ _CAT = "serving"   # the chrome-trace category of the engine's spans
 
 _engine_ids = itertools.count()
 
+#: what a model with layer_kinds carries beside the full pool's pages, in
+#: the step programs' argument order: the window pool's K and V pages, the
+#: conv tails, the SSM states
+_AUX = ("wk", "wv", "conv", "ssm")
+
 
 class ServingConfig(_model.ModelConfig):
     """Model shape + engine knobs. Engine knobs default from the
     ``MXNET_SERVING_*`` environment (docs/env_var.md); ``**arch`` are
     :class:`~.model.ModelConfig`'s structural fields (norm, pos,
-    rope_theta, qk_norm, head_dim, num_experts, experts_per_tok, bias),
-    passed through — they feed ``key()`` and so every graph and compile
-    cache key."""
+    rope_theta, qk_norm, head_dim, num_experts, experts_per_tok, bias,
+    layer_kinds and what goes with it), passed through — they feed
+    ``key()`` and so every graph and compile cache key.
+
+    A model with "swa" layers has a second pool for them, sized for
+    ``max_batch`` streams of ``window + block_size`` tokens and the trash
+    block; one with "mamba" layers ``max_batch`` state slots and the trash
+    slot (``_hybrid_caches``). ``num_blocks`` stays the full-length
+    pool's."""
 
     __slots__ = ("block_size", "num_blocks", "max_batch",
                  "prefills_per_step", "kv_dtype", "prefix_cache",
@@ -80,7 +91,8 @@ class ServingConfig(_model.ModelConfig):
         # (refcounted, copy-on-write) instead of re-caching them
         self.prefix_cache = bool(
             prefix_cache if prefix_cache is not None
-            else env_bool("MXNET_SERVING_PREFIX_CACHE", True))
+            else not self.stateful
+            and env_bool("MXNET_SERVING_PREFIX_CACHE", True))
         # speculative decoding (docs/serving.md §speculative-decoding):
         # spec_k > 0 turns it on — a draft LM proposes spec_k tokens per
         # step, the target scores all spec_k+1 window positions in one
@@ -112,6 +124,18 @@ class ServingConfig(_model.ModelConfig):
                 "max_len (%d) must be a multiple of block_size (%d): "
                 "prefill buckets and the decode block table are sized in "
                 "whole blocks" % (self.max_len, self.block_size))
+        if self.stateful and self.prefix_cache:
+            raise ValueError(
+                "prefix_cache needs what a model with state or window "
+                "layers does not have yet: the recurrent state (and the "
+                "window's blocks) saved at block boundaries, to start a "
+                "stream from a shared prefix")
+        if self.stateful and self.spec_k:
+            raise ValueError(
+                "spec_k > 0 needs what a model with state or window layers "
+                "does not have yet: a roll-back of the state and of the "
+                "window's freed blocks when a speculated window is "
+                "rejected")
 
     @classmethod
     def from_json(cls, obj):
@@ -210,11 +234,16 @@ class ServingEngine:
         if arg_params is None:
             arg_params = _model.random_params(cfg, seed=seed)
         self.params = _model.as_device_params(arg_params, cfg, device=device)
-        self.pool = KVBlockPool(cfg.num_layers, cfg.num_blocks,
-                                cfg.block_size, cfg.num_heads,
-                                cfg.head_dim,
-                                dtype=cfg.kv_dtype, device=device,
-                                prefix_cache=cfg.prefix_cache)
+        if not cfg.hybrid:
+            self.pool = KVBlockPool(cfg.num_layers, cfg.num_blocks,
+                                    cfg.block_size, cfg.num_heads,
+                                    cfg.head_dim,
+                                    dtype=cfg.kv_dtype, device=device,
+                                    prefix_cache=cfg.prefix_cache)
+            self.window_pool = self.state = self.streams = None
+        else:
+            (self.pool, self.window_pool, self.state,
+             self.streams) = self._hybrid_caches(cfg, device)
         # speculative decoding writes spec_k+1 window slots per step, so
         # headroom lookahead covers the whole draft+verify window
         self._spec = cfg.spec_k > 0
@@ -222,7 +251,8 @@ class ServingEngine:
         self.scheduler = Scheduler(self.pool, max_batch=cfg.max_batch,
                                    prefills_per_step=cfg.prefills_per_step,
                                    lookahead=cfg.spec_k + 1,
-                                   max_positions=cfg.max_len)
+                                   max_positions=cfg.max_len,
+                                   streams=self.streams)
         self._nb_max = cfg.max_len // cfg.block_size
         self._lock = threading.RLock()
         # separate statement: lockgraph keys the lock to the ctor line
@@ -303,6 +333,18 @@ class ServingEngine:
                 return _pack_fetch(*_model.prefill(
                     params, tokens, length, block_table, k_pages, v_pages,
                     cfg))
+
+            if not cfg.hybrid:
+                return _prefill
+
+            # the same name: the trace's ops are `jit__prefill/...` for
+            # every model, which is how the benchmark's readers find them
+            def _prefill(params, tokens, length, block_table,  # noqa: F811
+                         k_pages, v_pages, wtable, slot, *arrays):
+                tok, logits, kp, vp, out = _model.prefill(
+                    params, tokens, length, block_table, k_pages, v_pages,
+                    cfg, dict(zip(_AUX, arrays), wtable=wtable, slot=slot))
+                return (tok, logits, kp, vp) + tuple(out[k] for k in _AUX)
             return _prefill
 
         def _mk_decode():
@@ -311,10 +353,25 @@ class ServingEngine:
                 return _pack_fetch(*_model.decode(
                     params, tokens, positions, block_tables, context_lens,
                     k_pages, v_pages, cfg))
+
+            if not cfg.hybrid:
+                return _decode
+
+            def _decode(params, tokens, positions, block_tables,  # noqa: F811
+                        context_lens, k_pages, v_pages, wtables, slots,
+                        *arrays):
+                tok, logits, kp, vp, out = _model.decode(
+                    params, tokens, positions, block_tables, context_lens,
+                    k_pages, v_pages, cfg,
+                    dict(zip(_AUX, arrays), wtables=wtables, slots=slots))
+                return (tok, logits, kp, vp) + tuple(out[k] for k in _AUX)
             return _decode
 
         if donate:
             decode_donate = {"donate_argnums": (5, 6)}
+            if cfg.hybrid:      # the window pages and the state slots too
+                donate = {"donate_argnums": (4, 5, 8, 9, 10, 11)}
+                decode_donate = {"donate_argnums": (5, 6, 9, 10, 11, 12)}
         else:
             decode_donate = {}
         # one wrapper per shape bucket: buckets are DESIGNED to each
@@ -333,6 +390,9 @@ class ServingEngine:
         # compiling it (tools/serve.py --warmup, bench_serving warmup_s).
         ckey_base = cfg.key() + (cfg.block_size, cfg.num_blocks,
                                  str(cfg.kv_dtype))
+        if cfg.hybrid:
+            # the window pool and the state slots are sized from it
+            ckey_base += (cfg.max_batch,)
         self._prefill_jits = {
             S: compileobs.jit(_mk_prefill(), "serving.prefill", site=_SITE,
                               graph_key=gkey + ("prefill", S), aot=True,
@@ -347,12 +407,13 @@ class ServingEngine:
             for B in cfg.decode_buckets()}
         # bucket dispatch: call sites pad to an exact bucket shape, so the
         # padded dims index the wrapper table directly
-        self._prefill_fn = lambda params, toks, L, table, kp, vp: \
+        self._prefill_fn = lambda params, toks, L, table, kp, vp, *aux: \
             self._prefill_jits[toks.shape[1]](params, toks, L, table,
-                                              kp, vp)
-        self._decode_fn = lambda params, toks, poss, tables, ctx, kp, vp: \
+                                              kp, vp, *aux)
+        self._decode_fn = \
+            lambda params, toks, poss, tables, ctx, kp, vp, *aux: \
             self._decode_jits[toks.shape[0]](params, toks, poss, tables,
-                                             ctx, kp, vp)
+                                             ctx, kp, vp, *aux)
 
         # ---- speculative decoding: draft model + verify pass ----------
         # two more compileobs program families riding the same nonce-free
@@ -732,6 +793,7 @@ class ServingEngine:
                         continue
                     was_running = req.state != WAITING
                     req.blocks = []   # pool accounting is moot post-abort
+                    req.wblocks, req.slot = [], None
                     req.shared_blocks = 0
                     req.context_len = 0
                     req.state = WAITING
@@ -747,6 +809,7 @@ class ServingEngine:
                 return
             for req in reqs:
                 req.blocks = []   # pool accounting is moot post-abort
+                req.wblocks, req.slot = [], None
                 req.state = FAILED
                 req.error = msg
                 req.finish_t = time.time()
@@ -802,19 +865,13 @@ class ServingEngine:
             for S in prefill_buckets:
                 toks = np.zeros((1, S), np.int32)
                 table = np.zeros(S // cfg.block_size, np.int32)
-                _t, _l, kp, vp = self._prefill_fn(
-                    self.params, toks, np.int32(1), table,
-                    self.pool.k_pages, self.pool.v_pages)
-                self.pool.k_pages, self.pool.v_pages = kp, vp
+                self._dispatch_prefill(toks, 1, table)
             for B in cfg.decode_buckets():
                 toks = np.zeros(B, np.int32)
                 poss = np.zeros(B, np.int32)
                 tables = np.zeros((B, self._nb_max), np.int32)
                 ctx = np.ones(B, np.int32)
-                _t, _l, kp, vp = self._decode_fn(
-                    self.params, toks, poss, tables, ctx,
-                    self.pool.k_pages, self.pool.v_pages)
-                self.pool.k_pages, self.pool.v_pages = kp, vp
+                self._dispatch_decode(toks, poss, tables, ctx)
             if self._spec:
                 # spec adds three program families — warm them too or the
                 # first spec step pays draft + verify compile wall at once
@@ -843,28 +900,71 @@ class ServingEngine:
                         self.pool.k_pages, self.pool.v_pages)
                     self.pool.k_pages, self.pool.v_pages = kp, vp
 
-    def prefill_logits(self, tokens):
+    def prefill_logits(self, tokens, decode_from=None):
         """The model's next-token logits ``(V,)`` float32 after ``tokens``,
         from the prefill program of their length bucket: the served
         arithmetic (types, kernels, experts) on a text of the caller's
         choice — to score a fixed text, or to hold served logits against a
         reference. No request is involved and nothing is cached or booked:
         the program's K/V writes go to the trash block through an all-zero
-        table, as :meth:`warmup`'s do."""
+        table, as :meth:`warmup`'s do.
+
+        With ``decode_from = n`` the first ``n`` tokens go through prefill
+        into a scratch stream (blocks and, for a model that has them, a
+        state slot and window blocks, booked for the call and returned
+        after it) and the rest one by one through the decode program of
+        batch 1 with the given tokens forced; the result is the last
+        step's logits: prefill-then-decode through every kind of
+        per-stream state, against a reference's full forward."""
         cfg = self.config
         n = len(tokens)
         if not 1 <= n <= cfg.max_len:
             raise ValueError("%d tokens: need 1..max_len (%d)"
                              % (n, cfg.max_len))
+        if decode_from is not None:
+            if not 1 <= decode_from < n:
+                raise ValueError("decode_from must be in 1..%d" % (n - 1))
+            return self._scratch_logits(tokens, int(decode_from))
         S = _bucket_for(n, cfg.prefill_buckets())
         toks = np.zeros((1, S), np.int32)
         toks[0, :n] = tokens
         table = np.zeros(S // cfg.block_size, np.int32)
         with self._lock:
-            _t, logits, kp, vp = self._prefill_fn(
-                self.params, toks, np.int32(n), table,
-                self.pool.k_pages, self.pool.v_pages)
-            self.pool.k_pages, self.pool.v_pages = kp, vp
+            _t, logits = self._dispatch_prefill(toks, n, table)
+        return np.asarray(logits, np.float32)[0]  # fwlint: disable=device-escape — the logits are what the caller asked for
+
+    def _scratch_logits(self, tokens, n):
+        """:meth:`prefill_logits` with ``decode_from = n``."""
+        cfg = self.config
+        scratch = Request(tokens[:n], 1)
+        S = _bucket_for(n, cfg.prefill_buckets())
+        toks = np.zeros((1, S), np.int32)
+        toks[0, :n] = tokens[:n]
+        with self._lock:
+            scratch.blocks = self.pool.alloc(
+                self.pool.blocks_for(len(tokens)))
+            try:
+                if self.streams is not None:
+                    self.streams.admit(scratch, n)
+                width = S // cfg.block_size
+                self._dispatch_prefill(
+                    toks, n, self._table_row(scratch.blocks, width),
+                    self._table_row(scratch.wblocks, width),
+                    scratch.slot or 0)
+                for pos in range(n, len(tokens)):
+                    if self.streams is not None:
+                        self.streams.ensure(scratch, pos)
+                    _t, logits = self._dispatch_decode(
+                        np.asarray(tokens[pos:pos + 1], np.int32),
+                        np.full(1, pos, np.int32),
+                        self._table_row(scratch.blocks, self._nb_max)[None],
+                        np.full(1, pos + 1, np.int32),
+                        self._table_row(scratch.wblocks, self._nb_max)[None],
+                        np.full(1, scratch.slot or 0, np.int32))
+            finally:
+                self.pool.free(scratch.blocks)
+                if self.streams is not None:
+                    self.streams.release(scratch)
         return np.asarray(logits, np.float32)[0]  # fwlint: disable=device-escape — the logits are what the caller asked for
 
     def generate(self, prompts, max_new_tokens, eos_id=None, timeout_s=None):
@@ -929,14 +1029,96 @@ class ServingEngine:
         return failed
 
     # ------------------------------------------------------------ internals
-    def _table_row(self, req, width):
+    @staticmethod
+    def _table_row(blocks, width):
         # the admission grant includes the first decode slot's headroom
         # block, so a boundary-length replay holds one block more than its
         # prefill bucket's table width — clip; prefill never reads it
         row = np.zeros(width, np.int32)
-        n = min(len(req.blocks), width)
-        row[:n] = req.blocks[:n]
+        n = min(len(blocks), width)
+        row[:n] = blocks[:n]
         return row
+
+    def _hybrid_caches(self, cfg, device):
+        """The caches of a model with ``layer_kinds``: the full-length
+        pool (one layer a "full" layer: the "cross" layers read
+        it and have none of their own), the window pool of the "swa"
+        layers, the state slots of the "mamba" layers, and the manager
+        that books a stream's share of the last two."""
+        from .kv_cache import StateSlots, StreamState
+
+        heads = rows = cfg.kv_rows()    # num_heads x head_dim = G x W
+        n_full = len(cfg.layers_of("full"))
+        n_win = len(cfg.layers_of("swa"))
+        n_ssm = len(cfg.layers_of("mamba"))
+        pool = KVBlockPool(max(n_full, 1), cfg.num_blocks, cfg.block_size,
+                           *heads, dtype=cfg.kv_dtype, device=device,
+                           prefix_cache=False, rows=rows)
+        # max_batch streams of window + one block of tokens, max_batch
+        # slots, and the trash of each: running <= max_batch, so neither
+        # runs short. A kind the model lacks keeps a two-block (two-slot)
+        # stand-in, so that the step programs have one signature
+        per_stream = -(-(cfg.window + cfg.block_size) // cfg.block_size)
+        window_pool = KVBlockPool(
+            max(n_win, 1), cfg.max_batch * per_stream + 1 if n_win else 2,
+            cfg.block_size, *heads, dtype=cfg.kv_dtype, device=device,
+            prefix_cache=False, rows=rows, gauges=False)
+        state = StateSlots(
+            max(n_ssm, 1), cfg.max_batch + 1 if n_ssm else 2,
+            (cfg.ssm_conv - 1) * cfg.d_inner, (cfg.ssm_state, cfg.d_inner),
+            conv_dtype=self.params["embed_weight"].dtype, device=device)
+        streams = StreamState(window_pool if n_win else None,
+                              state if n_ssm else None, cfg.window)
+        pool.extra_nbytes = window_pool.nbytes() + state.nbytes()
+        return pool, window_pool, state, streams
+
+    def _dispatch_prefill(self, toks, length, table, wtable=None, slot=0):
+        """Run the prefill program of ``toks``' bucket over the caches and
+        keep what it hands back; ``(next token, logits)``, both on the
+        device. ``wtable`` / ``slot``: a hybrid model's window-pool table
+        and state slot (default: all trash)."""
+        if self.streams is None:
+            tok, logits, kp, vp = self._prefill_fn(
+                self.params, toks, np.int32(length), table,
+                self.pool.k_pages, self.pool.v_pages)
+        else:
+            if wtable is None:
+                wtable = np.zeros_like(table)
+            tok, logits, kp, vp, *aux = self._prefill_fn(
+                self.params, toks, np.int32(length), table,
+                self.pool.k_pages, self.pool.v_pages, wtable,
+                np.int32(slot), *self._aux())
+            self._keep_aux(aux)
+        self.pool.k_pages, self.pool.v_pages = kp, vp
+        return tok, logits
+
+    def _dispatch_decode(self, toks, poss, tables, ctx, wtables=None,
+                         slots=None):
+        """The same for the decode program of ``toks``' batch bucket."""
+        if self.streams is None:
+            tok, logits, kp, vp = self._decode_fn(
+                self.params, toks, poss, tables, ctx,
+                self.pool.k_pages, self.pool.v_pages)
+        else:
+            if wtables is None:
+                wtables = np.zeros_like(tables)
+            if slots is None:
+                slots = np.zeros(len(toks), np.int32)
+            tok, logits, kp, vp, *aux = self._decode_fn(
+                self.params, toks, poss, tables, ctx,
+                self.pool.k_pages, self.pool.v_pages, wtables, slots,
+                *self._aux())
+            self._keep_aux(aux)
+        self.pool.k_pages, self.pool.v_pages = kp, vp
+        return tok, logits
+
+    def _aux(self):
+        return (self.window_pool.k_pages, self.window_pool.v_pages,
+                self.state.conv, self.state.ssm)
+
+    def _keep_aux(self, aux):
+        (self.window_pool.k_pages, self.window_pool.v_pages,
+         self.state.conv, self.state.ssm) = aux
 
     def _run_prefill(self, req):
         cfg = self.config
@@ -947,7 +1129,8 @@ class ServingEngine:
         with telemetry.span("serving.prefill.build", _CAT, **args):
             toks = np.zeros((1, S), np.int32)
             toks[0, :L] = replay
-            table = self._table_row(req, S // cfg.block_size)
+            table = self._table_row(req.blocks, S // cfg.block_size)
+            wtable = self._table_row(req.wblocks, S // cfg.block_size)
             # prefix sharing: blocks mapped from the index already hold
             # this prefix's K/V — route their WRITE entries to the trash
             # block so the scatter cannot touch a shared block
@@ -970,10 +1153,8 @@ class ServingEngine:
             fault.hit("dispatch_error")
         t0 = time.time()
         with telemetry.span("serving.prefill.dispatch", _CAT, **args):
-            tok, _logits, kp, vp = self._prefill_fn(
-                self.params, toks, np.int32(L), write_table,
-                self.pool.k_pages, self.pool.v_pages)
-            self.pool.k_pages, self.pool.v_pages = kp, vp
+            tok, _logits = self._dispatch_prefill(toks, L, write_table,
+                                                  wtable, req.slot or 0)
             if self._spec:
                 # the draft caches the same replay through the same write
                 # table into its OWN pages (its K/V never mixes with the
@@ -998,6 +1179,8 @@ class ServingEngine:
             stall = min(s1 - s0, wall) if c1 > c0 or s1 > s0 else 0.0
             telemetry.histogram("serving.prefill_seconds").observe(wall)
             telemetry.counter("serving.prefill_tokens").inc(L)
+            if self.streams is not None and self.streams.slots is not None:
+                telemetry.counter("serving.ssm.prefill_tokens").inc(L)
             # register this prefix's full blocks for later admissions
             # (first writer wins; the blocks it itself mapped shared are
             # already in)
@@ -1026,14 +1209,24 @@ class ServingEngine:
         args = {"batch": len(reqs), "bucket": B,
                 "ctx_tokens": int(ctx.sum()), "ctx_max": int(ctx.max()),
                 "live_blocks": self._note_paged(ctx[:len(reqs)])}
+        if self.streams is not None:
+            args.update(self._note_hybrid(ctx[:len(reqs)],
+                                          args["live_blocks"]))
         with telemetry.span("serving.decode.build", _CAT, **args):
             toks = np.zeros(B, np.int32)
             poss = np.zeros(B, np.int32)
             tables = np.zeros((B, self._nb_max), np.int32)
+            wtables = slots = None
+            if self.streams is not None:
+                wtables = np.zeros((B, self._nb_max), np.int32)
+                slots = np.zeros(B, np.int32)
             for i, req in enumerate(reqs):
                 toks[i] = req.pending_token
                 poss[i] = req.context_len
-                tables[i] = self._table_row(req, self._nb_max)
+                tables[i] = self._table_row(req.blocks, self._nb_max)
+                if self.streams is not None:
+                    wtables[i] = self._table_row(req.wblocks, self._nb_max)
+                    slots[i] = req.slot or 0
             # compile-tally delta: a cold decode batch bucket stalls EVERY
             # stream in the batch for the compile wall (serving/obs.py)
             jit = self._decode_jits[B]
@@ -1041,10 +1234,8 @@ class ServingEngine:
             fault.hit("dispatch_error")
         t0 = time.time()
         with telemetry.span("serving.decode.dispatch", _CAT, **args):
-            nxt, _logits, kp, vp = self._decode_fn(
-                self.params, toks, poss, tables, ctx,
-                self.pool.k_pages, self.pool.v_pages)
-            self.pool.k_pages, self.pool.v_pages = kp, vp
+            nxt, _logits = self._dispatch_decode(toks, poss, tables, ctx,
+                                                 wtables, slots)
         with telemetry.span("serving.decode.fetch", _CAT, **args) as fetch:
             # the fused step's single device->host sync: the next-token
             # vector (with the experts' load behind it, where there are
@@ -1125,7 +1316,7 @@ class ServingEngine:
                             **args):
             tables = np.zeros((B, nb), np.int32)
             for i, req in enumerate(reqs):
-                tables[i] = self._table_row(req, nb)
+                tables[i] = self._table_row(req.blocks, nb)
             proposals = [[] for _ in range(n)]
             cur = np.zeros(B, np.int32)
             for i, req in enumerate(reqs):
@@ -1242,6 +1433,20 @@ class ServingEngine:
         telemetry.counter("serving.paged.table_slots").inc(slots)
         return live
 
+    def _note_hybrid(self, ctx, full_live):
+        """Book one decode pass of a model with ``layer_kinds`` from the
+        live streams' context lengths: a state update a stream and "mamba"
+        layer, and the blocks a window layer's walk and a full-pool
+        reader's walk take. Returns the spans' two block counts."""
+        cfg = self.config
+        if self.streams.slots is not None:
+            telemetry.counter("serving.ssm.stream_steps").inc(len(ctx))
+        first = np.maximum(ctx - cfg.window, 0) // cfg.block_size
+        window_live = int((-(-ctx // cfg.block_size) - first).sum()) \
+            if self.streams.pool is not None else 0
+        return {"window_live_blocks": window_live,
+                "full_live_blocks": full_live}
+
     def _note_moe(self, load, tokens):
         """Book one program's per-layer ``tokens_per_expert`` (L, E):
         pairs computed, layer-steps, the ``tokens`` live lanes the engine
@@ -1303,6 +1508,26 @@ class ServingEngine:
             len(w) / span if span > 0 else 0.0)
 
     # ------------------------------------------------------------ stats
+    def _state_stats(self):
+        cfg, st = self.config, self.streams
+        return {
+            "slots": st.slots.num_usable if st.slots else 0,
+            "slots_used": st.slots.used() if st.slots else 0,
+            "slot_bytes": st.slots.slot_nbytes() if st.slots else 0,
+            "state_bytes": st.slots.nbytes() if st.slots else 0,
+            "window": cfg.window,
+            "window_blocks_total": st.pool.num_usable if st.pool else 0,
+            "window_blocks_used": st.pool.used() if st.pool else 0,
+            "window_pool_bytes": st.pool.nbytes() if st.pool else 0,
+            # the most window blocks any one stream held: never above
+            # ceil((window + block_size) / block_size)
+            "window_blocks_a_stream": st.max_blocks_held,
+            "window_blocks_freed": st.blocks_freed,
+            # model layers that read the full-length pool's K/V
+            "full_pool_readers": len(cfg.layers_of("full", "cross")),
+            "full_pool_layers": self.pool.num_layers,
+        }
+
     def stats(self):
         """One dashboard snapshot (serve.py columns, /stats endpoint).
 
@@ -1331,7 +1556,9 @@ class ServingEngine:
                 # 1 = the plain (H, D) page row; at head_dim 64 that row
                 # is half a lane tile and every program copies the pool
                 "kv_heads_per_row": self.pool.heads_per_row,
-                "kv_page_shape": list(self.pool.k_pages.shape[-2:]),
+                "kv_page_shape": list(self.pool.page_rows),
+                # a block is (G, bs, W): rows that do not fill their tiles
+                "kv_head_major": self.pool.is_head_major,
                 "tokens_total": self._tokens_total,
                 "tokens_per_sec":
                     telemetry.gauge("serving.tokens_per_sec").value,
@@ -1371,6 +1598,9 @@ class ServingEngine:
                         (self._paged_live_blocks / self._paged_table_slots)
                         if self._paged_table_slots else 0.0,
                 },
+                # only for a model with window or state layers
+                **({"state": self._state_stats()}
+                   if self.streams is not None else {}),
                 # only for a model with experts
                 **({"moe": {
                     "num_experts": self.config.num_experts,

@@ -66,7 +66,12 @@ class KVBlockPool:
 
     def __init__(self, num_layers, num_blocks, block_size, num_heads,
                  head_dim, dtype=np.float32, device=None,
-                 prefix_cache=True):
+                 prefix_cache=True, rows=None, gauges=True):
+        """``rows``: the page rows ``(G, W)`` where the model decides them
+        (``ModelConfig.kv_rows``: a differential-attention K/V pair a
+        row); ``num_heads x head_dim`` are then ``G x W``. ``gauges``: a
+        second pool of an engine (the window layers') leaves the
+        process's ``serving.kv_blocks_*`` gauges to the first."""
         if num_blocks < 2:
             raise ValueError("KVBlockPool needs >= 2 blocks (block 0 is the "
                              "reserved trash block)")
@@ -79,11 +84,22 @@ class KVBlockPool:
         self.head_dim = int(head_dim)
         self.dtype = np.dtype(dtype)
         self.prefix_cache = bool(prefix_cache)
-        rows, lanes = self.page_shape(self.num_heads, self.head_dim)
+        self._gauges = bool(gauges)
+        #: device bytes the engine holds beside this pool for the same
+        #: streams (a window pool, state slots): :meth:`nbytes` counts them
+        self.extra_nbytes = 0
+        #: a block is (G, bs, W), not (bs, G, W): only where the model
+        #: names its rows (the one-block models' programs are token-major)
+        self.is_head_major = rows is not None and self.head_major(*rows)
+        rows, lanes = (rows if rows is not None
+                       else self.page_shape(self.num_heads, self.head_dim))
         #: heads side by side in one page row (1 = the plain (H, D) row)
         self.heads_per_row = self.num_heads // rows
-        shape = (self.num_layers, self.num_blocks, self.block_size,
-                 rows, lanes)
+        #: (G, W), wherever the block's slots stand
+        self.page_rows = (rows, lanes)
+        shape = (self.num_layers, self.num_blocks) + (
+            (rows, self.block_size, lanes) if self.is_head_major
+            else (self.block_size, rows, lanes))
         k = jnp.zeros(shape, self.dtype)
         v = jnp.zeros(shape, self.dtype)
         if device is not None:
@@ -113,8 +129,10 @@ class KVBlockPool:
         self.prefix_hits = 0
         self.prefix_hit_blocks = 0
         self.cow_copies = 0
-        telemetry.gauge("serving.kv_blocks_total").set(self.num_usable)
-        telemetry.gauge("serving.kv_heads_per_row").set(self.heads_per_row)
+        if self._gauges:
+            telemetry.gauge("serving.kv_blocks_total").set(self.num_usable)
+            telemetry.gauge("serving.kv_heads_per_row").set(
+                self.heads_per_row)
         # the pool may be constructed on a supervisor thread while handler
         # threads already poll the gauges of a predecessor — honor the
         # _locked suffix even on the init path
@@ -135,6 +153,19 @@ class KVBlockPool:
             r = 1
         return num_heads // r, r * head_dim
 
+    @staticmethod
+    def head_major(rows, lanes):
+        """Whether a block of ``(rows, lanes)`` page rows is laid out
+        ``(G, bs, W)`` — each row a ``(bs, W)`` slab — instead of
+        ``(bs, G, W)``: when the lanes are full and the rows do not fill
+        their sublane tiles (ten rows of 128: twenty K/V heads of 64).
+        Token-major, such a block is padded to sixteen rows in HBM and
+        copied by every kernel call; head-major it is whole tiles for any
+        G. The formats that were, ``(8, 128)`` and ``(16, 128)``, stay
+        token-major, and so does every pool of a one-block model (their
+        step programs know no other order)."""
+        return lanes % 128 == 0 and rows % 8 != 0
+
     @property
     def num_usable(self):
         """Allocatable blocks (pool size minus the trash block)."""
@@ -149,10 +180,11 @@ class KVBlockPool:
             return self.num_usable - len(self._free)
 
     def nbytes(self):
-        """Device bytes the pool pins (K + V)."""
+        """Device bytes the pool pins (K + V), and what the engine holds
+        beside it for the same streams (``extra_nbytes``)."""
         per = (self.num_layers * self.num_blocks * self.block_size
                * self.num_heads * self.head_dim * self.dtype.itemsize)
-        return 2 * per
+        return 2 * per + self.extra_nbytes
 
     def block_nbytes(self):
         """Device bytes ONE block pins across layers (K + V) — the unit
@@ -381,6 +413,8 @@ class KVBlockPool:
 
     # ---- accounting -----------------------------------------------------
     def _refresh_gauges_locked(self):
+        if not self._gauges:
+            return
         telemetry.gauge("serving.kv_blocks_used").set(
             self.num_usable - len(self._free))
         telemetry.gauge("serving.kv_blocks_free").set(len(self._free))
@@ -399,3 +433,167 @@ class KVBlockPool:
             "trash block must never be refcounted or indexed"
         assert len(self._prefix) == len(self._block_digest), \
             "prefix index maps out of sync"
+
+
+class StateSlots:
+    """Per-stream recurrent state of the "mamba" layers: for each of ``Ls``
+    layers a conv tail ``((K - 1) Dn,)`` in the activations' type and an
+    SSM state ``(N, Dn)`` in float32, ``num_slots`` of each. A stream
+    holds ONE slot for all its layers, fixed in size whatever its length;
+    the step programs update the slots in place (donated, aliased). Slot 0
+    is the trash slot: padded batch rows and scratch programs point at it.
+
+    The conv tails are ``(Ls, NS, (K - 1) Dn)``: a slot is a row, so that
+    the three-row tail is not padded to a sixteen-row tile. The states are
+    ``(Ls, NS, N, Dn)``: states along the sublanes, channels along the
+    lanes (``ops/ssm.py``)."""
+
+    def __init__(self, num_layers, num_slots, conv_width, state_shape,
+                 conv_dtype=np.float32, device=None):
+        if num_slots < 2:
+            raise ValueError("StateSlots needs >= 2 slots (slot 0 is the "
+                             "reserved trash slot)")
+        import jax
+        import jax.numpy as jnp
+
+        self.num_layers = int(num_layers)
+        self.num_slots = int(num_slots)
+        conv = jnp.zeros((self.num_layers, self.num_slots, int(conv_width)),
+                         conv_dtype)
+        ssm = jnp.zeros((self.num_layers, self.num_slots)
+                        + tuple(state_shape), jnp.float32)
+        if device is not None:
+            conv, ssm = (jax.device_put(a, device) for a in (conv, ssm))
+        #: the device arrays; the engine REPLACES them after every step
+        self.conv, self.ssm = conv, ssm
+        self._free = list(range(self.num_slots - 1, 0, -1))
+        telemetry.gauge("serving.state_slots_used").set(0)
+
+    @property
+    def num_usable(self):
+        return self.num_slots - 1
+
+    def available(self):
+        return len(self._free)
+
+    def used(self):
+        return self.num_usable - len(self._free)
+
+    def slot_nbytes(self):
+        """Device bytes one stream's slot pins, over all layers."""
+        return self.nbytes() // self.num_slots
+
+    def nbytes(self):
+        return int(self.conv.size * self.conv.dtype.itemsize
+                   + self.ssm.size * 4)
+
+    def alloc(self):
+        if not self._free:
+            raise KVCacheOOM("no free state slot (%d in use)"
+                             % self.num_usable)
+        slot = self._free.pop()
+        telemetry.gauge("serving.state_slots_used").set(self.used())
+        return slot
+
+    def free(self, slot):
+        slot = int(slot)
+        if not 0 < slot < self.num_slots or slot in self._free:
+            raise ValueError("free of invalid or free state slot %d" % slot)
+        self._free.append(slot)
+        telemetry.gauge("serving.state_slots_used").set(self.used())
+
+
+class StreamState:
+    """What a stream of a model with window and state layers holds BESIDE
+    its full-pool blocks, booked by one manager so that admission is
+    atomic over the three kinds:
+
+    * a state slot (:class:`StateSlots`), if the model has "mamba" layers;
+    * window-pool blocks ``req.wblocks``, by position like ``req.blocks``
+      but only for the last ``window`` keys: entry ``j`` is the block of
+      positions ``j * bs ..``, or 0 once it lies wholly behind the window
+      (freed during decode; a long prompt's prefill never gets them). A
+      stream holds at most ``window + block_size`` tokens of them.
+
+    Called from the stepping thread only (the scheduler's and the
+    engine's, under the engine's lock)."""
+
+    def __init__(self, window_pool, slots, window):
+        self.pool = window_pool     # None: no window layers
+        self.slots = slots          # None: no state layers
+        self.window = int(window)
+        self.blocks_freed = 0
+        self.max_blocks_held = 0
+
+    def _span(self, ctx):
+        """(first, last) window-pool table entries a step with context
+        ``ctx`` (its own position included) touches."""
+        bs = self.pool.block_size
+        return max(ctx - self.window, 0) // bs, (ctx - 1) // bs
+
+    def blocks_needed(self, n_tokens):
+        """Window blocks a stream of ``n_tokens`` cached tokens is admitted
+        with: those its FIRST decode step reads and writes."""
+        if self.pool is None:
+            return 0
+        first, last = self._span(n_tokens + 1)
+        return last - first + 1
+
+    def can_admit(self, n_tokens):
+        return ((self.pool is None
+                 or self.blocks_needed(n_tokens) <= self.pool.available())
+                and (self.slots is None or self.slots.available() > 0))
+
+    def admit(self, req, n_tokens):
+        """Book a slot and the window blocks, or nothing
+        (:class:`KVCacheOOM`)."""
+        blocks = []
+        if self.pool is not None:
+            first, _last = self._span(n_tokens + 1)
+            blocks = [0] * first + self.pool.alloc(
+                self.blocks_needed(n_tokens))
+        if self.slots is not None:
+            try:
+                req.slot = self.slots.alloc()
+            except KVCacheOOM:
+                if self.pool is not None:
+                    self.pool.free([b for b in blocks if b])
+                raise
+        req.wblocks = blocks
+        self._note_held(req)
+
+    def ensure(self, req, pos):
+        """Before a decode step that writes position ``pos``: return the
+        blocks wholly behind the step's window to the pool, then back the
+        write slot (:class:`KVCacheOOM` if the pool is dry; what was freed
+        stays freed)."""
+        if self.pool is None:
+            return
+        first, last = self._span(pos + 1)
+        behind = [b for b in req.wblocks[:first] if b]
+        if behind:
+            self.pool.free(behind)
+            req.wblocks[:first] = [0] * min(first, len(req.wblocks))
+            self.blocks_freed += len(behind)
+            telemetry.counter("serving.window.blocks_freed").inc(len(behind))
+        while last >= len(req.wblocks):
+            req.wblocks.extend(self.pool.alloc(1))
+        self._note_held(req)
+
+    def release(self, req):
+        if self.pool is not None:
+            held = [b for b in req.wblocks if b]
+            if held:
+                self.pool.free(held)
+        req.wblocks = []
+        if req.slot is not None:
+            self.slots.free(req.slot)
+            req.slot = None
+
+    def _note_held(self, req):
+        self.max_blocks_held = max(self.max_blocks_held,
+                                   sum(1 for b in req.wblocks if b))
+
+    def nbytes(self):
+        return ((self.pool.nbytes() if self.pool is not None else 0)
+                + (self.slots.nbytes() if self.slots is not None else 0))

@@ -36,6 +36,7 @@ import numpy as np
 from ..ops.attention import flash_attention, paged_attention_multi
 from ..ops.moe import moe_ffn
 from ..ops.registry import fp32_precision
+from ..ops.ssm import ssm_scan, ssm_step
 
 #: parameter init scale matching models/transformer_lm.py's Normal(0.02)
 #: pos-embed init; used by random_params for self-contained serving runs
@@ -61,18 +62,62 @@ class ModelConfig:
     experts_per_tok experts a token is sent to (nothing is dropped)
     bias            biases on the FFN and the head (experts have none)
 
+    A model whose layers are not all that one block names each layer's
+    KIND in ``layer_kinds`` (None = every layer ``"attn"``, the block
+    above); the norm / residual / FFN skeleton stays the one body and only
+    the mixer differs:
+
+    ``"attn"``   the block above: its own K/V, read from the stream's start
+                 (the one-block model's only kind; ``layer_kinds`` cannot
+                 name it: the step programs of a model with kinds have no
+                 path for it)
+    ``"mamba"``  a Mamba-1 selective state-space layer (``ssm_*`` below):
+                 per-stream conv tail and float32 state, no K/V. The last
+                 one before the first ``"gmu"`` also hands its scan output
+                 of the same token to those layers
+    ``"swa"``    differential attention over a sliding window of ``window``
+                 keys (the query's own included), its own K/V
+    ``"full"``   the same with no window: its K/V is a full-length cache
+    ``"cross"``  a query projection only; reads the K/V of the last
+                 ``"full"`` layer before it and writes none
+    ``"gmu"``    a gated memory unit: ``W_out (silu(W_in h) * m)``, no state
+
+    num_kv_heads    K/V heads (default ``num_heads``); the ``swa`` / ``full``
+                    / ``cross`` kinds are DIFFERENTIAL attention: adjacent
+                    query heads pair, adjacent K/V heads pair, a query pair
+                    reads K/V pair ``p * kv_pairs // q_pairs`` and a head's
+                    value is its K/V pair's two heads side by side
+    window          keys a ``"swa"`` layer's query sees
+    attn_bias       biases on those kinds' q/k/v and output projections
+    ffn_gated       the dense FFN is ``W2 (silu(g) * u)``, ``[g, u] = W1 h``
+    tie_embed       the head is the embedding (no ``lm_head_weight``)
+    ssm_state, ssm_conv, ssm_expand, ssm_dt_rank
+                    Mamba's ``d_state``, ``d_conv``, ``d_inner / model_dim``
+                    and ``dt_rank`` (None: ``ceil(model_dim / 16)``)
+    pos             may then also be "none" (no position anywhere)
+
     ``max_len`` bounds every stream's total length (the position table's
     rows, or the positions the rotary model was trained for)."""
 
     __slots__ = ("vocab_size", "num_layers", "model_dim", "num_heads",
                  "ffn_dim", "max_len", "norm", "pos", "rope_theta",
                  "qk_norm", "head_dim", "num_experts", "experts_per_tok",
-                 "bias")
+                 "bias", "layer_kinds", "num_kv_heads", "window",
+                 "attn_bias", "ffn_gated", "tie_embed", "ssm_state",
+                 "ssm_conv", "ssm_expand", "ssm_dt_rank")
+    #: the fields of one-block models: their ``key()`` is these alone, so
+    #: that the programs' cache keys are what they were before ``layer_kinds``
+    _BLOCK_FIELDS = 14
+    #: what ``layer_kinds`` may name
+    KINDS = ("mamba", "swa", "full", "cross", "gmu")
 
     def __init__(self, vocab_size=32000, num_layers=4, model_dim=256,
                  num_heads=4, ffn_dim=1024, max_len=128, norm="layer",
                  pos="learned", rope_theta=10000.0, qk_norm=False,
-                 head_dim=None, num_experts=0, experts_per_tok=0, bias=True):
+                 head_dim=None, num_experts=0, experts_per_tok=0, bias=True,
+                 layer_kinds=None, num_kv_heads=None, window=0,
+                 attn_bias=False, ffn_gated=False, tie_embed=False,
+                 ssm_state=16, ssm_conv=4, ssm_expand=2, ssm_dt_rank=None):
         self.vocab_size = int(vocab_size)
         self.num_layers = int(num_layers)
         self.model_dim = int(model_dim)
@@ -90,18 +135,113 @@ class ModelConfig:
         self.num_experts = int(num_experts)
         self.experts_per_tok = int(experts_per_tok)
         self.bias = bool(bias)
+        self.layer_kinds = (None if layer_kinds is None
+                            else tuple(str(k) for k in layer_kinds))
+        self.num_kv_heads = int(num_kv_heads if num_kv_heads is not None
+                                else self.num_heads)
+        self.window = int(window)
+        self.attn_bias = bool(attn_bias)
+        self.ffn_gated = bool(ffn_gated)
+        self.tie_embed = bool(tie_embed)
+        self.ssm_state = int(ssm_state)
+        self.ssm_conv = int(ssm_conv)
+        self.ssm_expand = int(ssm_expand)
+        self.ssm_dt_rank = int(ssm_dt_rank if ssm_dt_rank is not None
+                               else -(-self.model_dim // 16))
         if self.norm not in ("layer", "rms"):
             raise ValueError("norm must be 'layer' or 'rms', not %r" % norm)
-        if self.pos not in ("learned", "rope"):
-            raise ValueError("pos must be 'learned' or 'rope', not %r" % pos)
+        if self.pos not in ("learned", "rope", "none"):
+            raise ValueError("pos must be 'learned', 'rope' or 'none', not %r"
+                             % pos)
         if self.pos == "rope" and self.head_dim % 2:
             raise ValueError("rotary position needs an even head_dim")
         if self.num_experts and not (
                 1 <= self.experts_per_tok <= self.num_experts):
             raise ValueError("experts_per_tok must be in 1..num_experts")
+        self._check_kinds()
+
+    def _check_kinds(self):
+        kinds = self.kinds()
+        if self.layer_kinds is None:
+            if (self.num_kv_heads != self.num_heads or self.pos == "none"
+                    or self.ffn_gated or self.tie_embed or self.attn_bias):
+                raise ValueError(
+                    "num_kv_heads, pos='none', ffn_gated, tie_embed and "
+                    "attn_bias belong to a model with layer_kinds")
+            return
+        if len(kinds) != self.num_layers or set(kinds) - set(self.KINDS):
+            raise ValueError("layer_kinds must name each of the %d layers "
+                             "one of %s, not %r"
+                             % (self.num_layers, self.KINDS, kinds))
+        if self.num_experts or self.qk_norm or self.pos == "rope":
+            raise ValueError("a model with layer_kinds takes no experts, "
+                             "QK-norm or rotary position")
+        if set(kinds) & {"swa", "full", "cross"}:
+            if self.num_heads % 2 or self.num_kv_heads % 2 or \
+                    (self.num_heads // 2) % (self.num_kv_heads // 2):
+                raise ValueError(
+                    "differential attention pairs adjacent heads: %d query "
+                    "and %d K/V heads do not pair up"
+                    % (self.num_heads, self.num_kv_heads))
+        if "swa" in kinds and self.window < 1:
+            raise ValueError("'swa' layers need window >= 1")
+        for i, kind in enumerate(kinds):
+            if kind == "cross" and "full" not in kinds[:i]:
+                raise ValueError("layer %d is 'cross' with no 'full' layer "
+                                 "before it" % i)
+            if kind == "gmu" and "mamba" not in kinds[:i]:
+                raise ValueError("layer %d is 'gmu' with no 'mamba' layer "
+                                 "before it" % i)
+
+    # ---- what the layers' kinds imply (static, python) ------------------
+    def kinds(self):
+        return self.layer_kinds or ("attn",) * self.num_layers
+
+    def layers_of(self, *kinds):
+        """Indices of the layers of these kinds, in order."""
+        return [i for i, k in enumerate(self.kinds()) if k in kinds]
+
+    @property
+    def hybrid(self):
+        """A model of several layer kinds: its step programs take the
+        window pool's pages and the state slots beside the full pool."""
+        return self.layer_kinds is not None
+
+    @property
+    def stateful(self):
+        """Per-stream state that no block-aligned prefix determines (a
+        recurrent state, a window that has slid): no prefix sharing, no
+        roll-back of a speculated window."""
+        return bool(self.layers_of("mamba", "swa"))
+
+    @property
+    def d_inner(self):
+        return self.ssm_expand * self.model_dim
+
+    @property
+    def memory_layer(self):
+        """The 'mamba' layer whose scan output the 'gmu' layers gate."""
+        gmu = self.layers_of("gmu")
+        return max(i for i in self.layers_of("mamba") if i < gmu[0]) \
+            if gmu else None
+
+    def kv_rows(self):
+        """``(G, W)``: the page rows of one token's K (or V). Differential
+        attention: a K/V pair is one row (``[v1, v2]`` is the row, ``k1``
+        and ``k2`` its halves)."""
+        from .kv_cache import KVBlockPool
+
+        if set(self.kinds()) & {"swa", "full", "cross"}:
+            return self.num_kv_heads // 2, 2 * self.head_dim
+        return KVBlockPool.page_shape(self.num_kv_heads, self.head_dim)
 
     def key(self):
-        return tuple(getattr(self, k) for k in ModelConfig.__slots__)
+        """What the programs are a function of. A one-block model's key is
+        its first fourteen fields, as before there were others."""
+        names = ModelConfig.__slots__
+        if not self.hybrid:
+            names = names[:self._BLOCK_FIELDS]
+        return tuple(getattr(self, k) for k in names)
 
     def _slot_names(self):
         # walk the whole MRO: on a subclass (ServingConfig) bare
@@ -131,21 +271,24 @@ def param_shapes(cfg):
     ``[expert, out, in]`` as a checkpoint has them)."""
     m, f, v = cfg.model_dim, cfg.ffn_dim, cfg.vocab_size
     hm = cfg.num_heads * cfg.head_dim
-    shapes = {"embed_weight": (v, m), "final_ln_gamma": (1, 1, m),
-              "lm_head_weight": (v, m)}
+    shapes = {"embed_weight": (v, m), "final_ln_gamma": (1, 1, m)}
+    if not cfg.tie_embed:
+        shapes["lm_head_weight"] = (v, m)
     if cfg.pos == "learned":
         shapes["pos_embed_weight"] = (1, cfg.max_len, m)
     if cfg.norm == "layer":
         shapes["final_ln_beta"] = (1, 1, m)
     if cfg.bias:
         shapes["lm_head_bias"] = (v,)
-    for i in range(cfg.num_layers):
+    for i, kind in enumerate(cfg.kinds()):
         p = "layer%d" % i
         shapes.update({
-            p + "_ln1_gamma": (1, 1, m), p + "_ln2_gamma": (1, 1, m),
-            p + "_attn_in_weight": (3 * hm, m),
-            p + "_attn_out_weight": (m, hm),
-        })
+            p + "_ln1_gamma": (1, 1, m), p + "_ln2_gamma": (1, 1, m)})
+        if kind == "attn":
+            shapes.update({p + "_attn_in_weight": (3 * hm, m),
+                           p + "_attn_out_weight": (m, hm)})
+        else:
+            shapes.update(_mixer_shapes(cfg, kind, p))
         if cfg.norm == "layer":
             shapes.update({p + "_ln1_beta": (1, 1, m),
                            p + "_ln2_beta": (1, 1, m)})
@@ -159,11 +302,45 @@ def param_shapes(cfg):
                            p + "_experts_up_weight": (e, f, m),
                            p + "_experts_down_weight": (e, m, f)})
         else:
-            shapes.update({p + "_ffn1_weight": (f, m),
-                           p + "_ffn2_weight": (m, f)})
+            shapes.update({
+                p + "_ffn1_weight": ((2 if cfg.ffn_gated else 1) * f, m),
+                p + "_ffn2_weight": (m, f)})
             if cfg.bias:
                 shapes.update({p + "_ffn1_bias": (f,),
                                p + "_ffn2_bias": (m,)})
+    return shapes
+
+
+def _mixer_shapes(cfg, kind, p):
+    """The weights of one layer's mixer, for the kinds other than "attn".
+    Matrices are ``(out, in)`` as everywhere; ``ssm_a_log`` is ``(N, Dn)``,
+    the state's own layout (a checkpoint's ``A_log`` transposed)."""
+    m, hd, dn, n = cfg.model_dim, cfg.head_dim, cfg.d_inner, cfg.ssm_state
+    hq, hkv = cfg.num_heads * hd, cfg.num_kv_heads * hd
+    if kind == "mamba":
+        return {p + "_ssm_in_weight": (2 * dn, m),
+                p + "_ssm_conv_weight": (cfg.ssm_conv, dn),
+                p + "_ssm_conv_bias": (dn,),
+                p + "_ssm_x_weight": (cfg.ssm_dt_rank + 2 * n, dn),
+                p + "_ssm_dt_weight": (dn, cfg.ssm_dt_rank),
+                p + "_ssm_dt_bias": (dn,), p + "_ssm_a_log": (n, dn),
+                p + "_ssm_d": (dn,), p + "_ssm_out_weight": (m, dn)}
+    if kind == "gmu":
+        return {p + "_gmu_in_weight": (dn, m), p + "_gmu_out_weight": (m, dn)}
+    shapes = {p + "_attn_out_weight": (m, hq),
+              p + "_diff_norm_gamma": (2 * hd,)}
+    shapes.update({p + "_diff_lambda_" + v: (hd,)
+                   for v in ("q1", "k1", "q2", "k2")})
+    if kind == "cross":
+        shapes[p + "_attn_q_weight"] = (hq, m)
+    else:
+        shapes[p + "_attn_in_weight"] = (hq + 2 * hkv, m)
+    if cfg.attn_bias:
+        shapes[p + "_attn_out_bias"] = (m,)
+        if kind == "cross":
+            shapes[p + "_attn_q_bias"] = (hq,)
+        else:
+            shapes[p + "_attn_in_bias"] = (hq + 2 * hkv,)
     return shapes
 
 
@@ -175,10 +352,21 @@ def random_params(cfg, seed=0, dtype=np.float32):
     rng = np.random.RandomState(seed)
     out = {}
     for name, shape in sorted(param_shapes(cfg).items()):
-        if name.endswith("_gamma"):
+        if name.endswith(("_gamma", "_ssm_d")):
             out[name] = np.ones(shape, dtype)
+        elif name.endswith("_ssm_dt_bias"):
+            # Mamba's init: dt log-uniform in [1e-3, 1e-1], through the
+            # inverse of the softplus
+            dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), shape))
+            out[name] = (dt + np.log(-np.expm1(-dt))).astype(dtype)
+        elif name.endswith("_ssm_a_log"):
+            out[name] = np.broadcast_to(np.log(np.arange(
+                1, shape[0] + 1, dtype=np.float64))[:, None],
+                shape).astype(dtype)
         elif name.endswith(("_beta", "_bias")):
             out[name] = np.zeros(shape, dtype)
+        elif "_diff_lambda_" in name:
+            out[name] = (rng.randn(*shape) * 0.1).astype(dtype)
         else:
             out[name] = (rng.randn(*shape) * _INIT_SCALE).astype(dtype)
     return out
@@ -229,7 +417,8 @@ def draft_config(cfg, spec):
     from ..models.transformer_lm import SERVING_DRAFT_PRESETS
 
     if spec == "self":
-        return ModelConfig(*cfg.key())
+        return ModelConfig(**{k: getattr(cfg, k)
+                              for k in ModelConfig.__slots__})
     if spec not in SERVING_DRAFT_PRESETS:
         raise ValueError(
             "unknown draft model %r: expected 'self' or one of %s "
@@ -302,7 +491,7 @@ def _embed(params, tokens, positions, cfg):
     import jax.numpy as jnp
 
     x = jnp.take(params["embed_weight"], tokens, axis=0)
-    if cfg.pos != "learned":
+    if cfg.pos != "learned":        # rotary, or no position at all
         return x
     if positions is None:
         return x + params["pos_embed_weight"][:, :tokens.shape[1]]
@@ -316,7 +505,14 @@ def _ffn(x2d, params, prefix, cfg, prec):
     f = jnp.dot(x2d, params[prefix + "_ffn1_weight"].T, precision=prec)
     if cfg.bias:
         f = f + params[prefix + "_ffn1_bias"]
-    f = jnp.dot(jnp.maximum(f, 0), params[prefix + "_ffn2_weight"].T,
+    if cfg.ffn_gated:                   # [gate, up] = W1 h
+        import jax
+
+        gate, up = jnp.split(f, 2, axis=-1)
+        f = jax.nn.silu(gate) * up
+    else:
+        f = jnp.maximum(f, 0)
+    f = jnp.dot(f, params[prefix + "_ffn2_weight"].T,
                 precision=prec)
     return f + params[prefix + "_ffn2_bias"] if cfg.bias else f
 
@@ -326,30 +522,17 @@ def _head(x2d, params, cfg, prec):
     weights' type."""
     import jax.numpy as jnp
 
-    logits = jnp.dot(x2d, params["lm_head_weight"].T, precision=prec,
+    head = params["embed_weight" if cfg.tie_embed else "lm_head_weight"]
+    logits = jnp.dot(x2d, head.T, precision=prec,
                      preferred_element_type=jnp.float32)
     return logits + params["lm_head_bias"] if cfg.bias else logits
 
 
-def _layer(x, params, i, cfg, prec, positions, valid, attend, state):
-    """THE layer body, shared by :func:`prefill`, :func:`decode` and
-    :func:`extend`: norm -> q/k/v projections (QK-norm, position) ->
-    ``attend`` -> output projection -> norm -> FFN or routed experts.
-
-    x:         (A, B, M) — (1, S, M) in prefill, (B, 1, M) in decode,
-               (B, T, M) in the verify pass
-    positions: (A, B) int32 absolute positions (rotary models read them)
-    valid:     (A, B) bool — live lanes, for the experts' load count
-    attend:    ``(i, q, k, v, state) -> (attn (A, B, H*hd), state)``: what
-               differs between the three steps — where this layer's K and
-               V go and which attention reads them. ``state`` is the
-               caller's (the pages, or the K/V collected so far).
-
-    Returns ``(x, state, tokens_per_expert (E,) or None)``."""
+def _mix_attn(h, params, p, i, cfg, prec, positions, attend, state):
+    """The "attn" kind: fused q/k/v projections (QK-norm, position) ->
+    ``attend`` -> output projection."""
     import jax.numpy as jnp
 
-    p = "layer%d" % i
-    h = _norm(x, params, p + "_ln1", cfg)
     qkv = jnp.einsum("bsm,nm->bsn", h, params[p + "_attn_in_weight"],
                      precision=prec)
     q, k, v = jnp.split(qkv, 3, axis=-1)
@@ -360,7 +543,171 @@ def _layer(x, params, i, cfg, prec, positions, valid, attend, state):
     attn, state = attend(i, q, k, v, state)
     attn = jnp.einsum("bsm,nm->bsn", attn, params[p + "_attn_out_weight"],
                       precision=prec)
-    x = x + attn
+    return attn, state
+
+
+def diff_lambda_init(i):
+    """Differential attention's ``lambda_init`` of layer ``i`` (0-indexed)."""
+    return 0.8 - 0.6 * float(np.exp(-0.3 * i))
+
+
+def _diff_lambda(params, p, i):
+    """``exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init``, float32."""
+    import jax.numpy as jnp
+
+    def term(a, b):
+        return jnp.exp(jnp.sum(
+            params[p + "_diff_lambda_" + a].astype(jnp.float32)
+            * params[p + "_diff_lambda_" + b].astype(jnp.float32)))
+
+    return term("q1", "k1") - term("q2", "k2") + diff_lambda_init(i)
+
+
+def _mix_diff(h, params, p, i, kind, cfg, prec, attend, state):
+    """The "swa" / "full" / "cross" kinds: differential attention.
+    ``attend`` returns, for every query pair, its two heads' softmax
+    attention over the pair's 2 hd-wide values: ``(A, B, P, 2, 2 hd)``.
+    The pair's output is ``a_1 V - lambda a_2 V``, RMS-normed over its
+    2 hd lanes and scaled by ``1 - lambda_init``; statistics in float32."""
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    hq, hkv = (cfg.num_heads * cfg.head_dim, cfg.num_kv_heads * cfg.head_dim)
+    if kind == "cross":
+        q = jnp.einsum("bsm,nm->bsn", h, params[p + "_attn_q_weight"],
+                       precision=prec)
+        if cfg.attn_bias:
+            q = q + params[p + "_attn_q_bias"]
+        k = v = None
+    else:
+        qkv = jnp.einsum("bsm,nm->bsn", h, params[p + "_attn_in_weight"],
+                         precision=prec)
+        if cfg.attn_bias:
+            qkv = qkv + params[p + "_attn_in_bias"]
+        q, k, v = jnp.split(qkv, [hq, hq + hkv], axis=-1)
+    att, state = attend(i, q, k, v, state)          # (A, B, P, 2, 2 hd)
+
+    lam_init = diff_lambda_init(i)
+    att = att.astype(f32)
+    o = att[..., 0, :] - _diff_lambda(params, p, i) * att[..., 1, :]
+    o = _rms_norm(o, params[p + "_diff_norm_gamma"]) * (1.0 - lam_init)
+    o = o.reshape(h.shape[:2] + (hq,)).astype(h.dtype)
+    out = jnp.einsum("bsm,nm->bsn", o, params[p + "_attn_out_weight"],
+                     precision=prec)
+    if cfg.attn_bias:
+        out = out + params[p + "_attn_out_bias"]
+    return out, state
+
+
+def _diff_q_rows(q, cfg):
+    """q ``(A, B, Hq hd)`` as rows that line up with the K/V pair rows
+    ``[k1 | k2]``: query head ``2 p`` becomes ``[q | 0]`` and head
+    ``2 p + 1`` ``[0 | q]``, so that a plain dot with the row is the head's
+    own score. Returns ``(A, B, G, R, 2 hd)`` with R = the query heads that
+    read K/V row g (pair-major: the two heads of the row's first query
+    pair, then of its second, ...)."""
+    import jax.numpy as jnp
+
+    a, b, _ = q.shape
+    hd, g = cfg.head_dim, cfg.num_kv_heads // 2
+    pairs = q.reshape(a, b, cfg.num_heads // 2, 2, hd)
+    zero = jnp.zeros_like(pairs[..., 0, :])
+    rows = jnp.stack([jnp.concatenate([pairs[..., 0, :], zero], -1),
+                      jnp.concatenate([zero, pairs[..., 1, :]], -1)], -2)
+    return rows.reshape(a, b, g, cfg.num_heads // g, 2 * hd)
+
+
+def _ssm_inputs(xc, params, p, cfg, prec):
+    """From the conv'd, silu'd input rows (R, Dn): ``dt`` before the
+    softplus (R, Dn) float32, ``B`` and ``C`` (R, N) float32."""
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    r, n = cfg.ssm_dt_rank, cfg.ssm_state
+    dbc = jnp.dot(xc, params[p + "_ssm_x_weight"].T, precision=prec,
+                  preferred_element_type=f32)
+    dt_low, b, c = jnp.split(dbc, [r, r + n], axis=-1)
+    dt = jnp.dot(dt_low.astype(xc.dtype), params[p + "_ssm_dt_weight"].T,
+                 precision=prec, preferred_element_type=f32)
+    return dt + params[p + "_ssm_dt_bias"].astype(f32), b, c
+
+
+def _conv_taps(window, params, p):
+    """silu(depthwise conv + bias) of ``window`` (.., K, Dn): the K taps
+    oldest first, the last the row's own. float32 in, the caller rounds."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    w = params[p + "_ssm_conv_weight"].astype(f32)              # (K, Dn)
+    return jax.nn.silu(jnp.sum(window.astype(f32) * w, axis=-2)
+                       + params[p + "_ssm_conv_bias"].astype(f32))
+
+
+def _mix_mamba(h, params, p, i, cfg, prec, recur, state):
+    """The "mamba" kind: ``[x, z] = W_in h`` -> ``recur`` (the conv, the
+    scan and the gate, over the step's own state) -> ``W_out``. The memory
+    layer leaves its scan output ``y`` (before the gate, ``D x`` included)
+    in ``state["m"]`` for the "gmu" layers of the same step."""
+    import jax.numpy as jnp
+
+    xz = jnp.einsum("bsm,nm->bsn", h, params[p + "_ssm_in_weight"],
+                    precision=prec)
+    xin, z = jnp.split(xz, 2, axis=-1)
+    y, out, state = recur(i, xin, z, state)
+    if i == cfg.memory_layer:
+        state = dict(state, m=y)
+    return jnp.einsum("bsm,nm->bsn", out, params[p + "_ssm_out_weight"],
+                      precision=prec), state
+
+
+def _mix_gmu(h, params, p, prec, state):
+    """The "gmu" kind: ``W_out (silu(W_in h) * m)``, ``m`` the memory
+    layer's scan output of the same token."""
+    import jax
+    import jax.numpy as jnp
+
+    g = jnp.einsum("bsm,nm->bsn", h, params[p + "_gmu_in_weight"],
+                   precision=prec)
+    return jnp.einsum("bsm,nm->bsn", jax.nn.silu(g) * state["m"],
+                      params[p + "_gmu_out_weight"], precision=prec)
+
+
+def _layer(x, params, i, cfg, prec, positions, valid, attend, state,
+           recur=None):
+    """THE layer body, shared by :func:`prefill`, :func:`decode` and
+    :func:`extend`: norm -> the layer's mixer -> norm -> FFN or routed
+    experts. The mixer is the layer's KIND's (``ModelConfig``): q/k/v
+    projections (QK-norm, position) -> ``attend`` -> output projection
+    for "attn", and :func:`_mix_diff`, :func:`_mix_mamba`,
+    :func:`_mix_gmu` for the others.
+
+    x:         (A, B, M) — (1, S, M) in prefill, (B, 1, M) in decode,
+               (B, T, M) in the verify pass
+    positions: (A, B) int32 absolute positions (rotary models read them)
+    valid:     (A, B) bool — live lanes, for the experts' load count
+    attend:    ``(i, q, k, v, state) -> (attn, state)``: what differs
+               between the three steps — where this layer's K and V go and
+               which attention reads them. ``state`` is the caller's (the
+               pages, or the K/V collected so far).
+    recur:     ``(i, x, z, state) -> (y, out, state)``: the same for a
+               "mamba" layer's conv tail and state.
+
+    Returns ``(x, state, tokens_per_expert (E,) or None)``."""
+    p = "layer%d" % i
+    h = _norm(x, params, p + "_ln1", cfg)
+    kind = cfg.kinds()[i]
+    if kind == "attn":
+        mix, state = _mix_attn(h, params, p, i, cfg, prec, positions, attend,
+                               state)
+    elif kind == "mamba":
+        mix, state = _mix_mamba(h, params, p, i, cfg, prec, recur, state)
+    elif kind == "gmu":
+        mix = _mix_gmu(h, params, p, prec, state)
+    else:
+        mix, state = _mix_diff(h, params, p, i, kind, cfg, prec, attend,
+                               state)
+    x = x + mix
     h = _norm(x, params, p + "_ln2", cfg)
     a, b, m = x.shape
     load = None
@@ -376,7 +723,8 @@ def _layer(x, params, i, cfg, prec, positions, valid, attend, state):
     return x + f.reshape(a, b, m), state, load
 
 
-def _layers(x, params, cfg, prec, positions, valid, attend, state):
+def _layers(x, params, cfg, prec, positions, valid, attend, state,
+            recur=None):
     """Every layer in turn: ``(x, state, loads)`` with ``loads`` the
     per-layer ``tokens_per_expert`` stacked (L, E), or () without
     experts."""
@@ -385,12 +733,13 @@ def _layers(x, params, cfg, prec, positions, valid, attend, state):
     loads = []
     for i in range(cfg.num_layers):
         x, state, load = _layer(x, params, i, cfg, prec, positions, valid,
-                                attend, state)
+                                attend, state, recur)
         loads.append(load)
     return x, state, ((jnp.stack(loads),) if cfg.num_experts else ())
 
 
-def prefill(params, tokens, length, block_table, k_pages, v_pages, cfg):
+def prefill(params, tokens, length, block_table, k_pages, v_pages, cfg,
+            aux=None):
     """Full-sequence prefill for ONE request at a padded bucket length.
 
     tokens:      (1, S) int32, S a bucket multiple of the pool block size
@@ -409,9 +758,19 @@ def prefill(params, tokens, length, block_table, k_pages, v_pages, cfg):
     ``flash_attention(causal=True)`` — padded tail rows compute garbage
     but cannot reach rows < length (causal mask) and their cache writes
     land in trash-table blocks.
+
+    A model with ``layer_kinds`` takes ``aux`` — ``wtable`` (S // bs,) the
+    stream's window-pool blocks (0 for those behind the window: a long
+    prompt keeps its tail only), ``slot`` () its state slot, and the
+    donated ``wk`` / ``wv`` (window pool pages), ``conv`` (Ls, NS, (K-1) Dn)
+    and ``ssm`` (Ls, NS, N, Dn) — and returns the four arrays as a fifth
+    result, a dict (:func:`_prefill_hybrid`).
     """
     import jax.numpy as jnp
 
+    if cfg.hybrid:
+        return _prefill_hybrid(params, tokens, length, block_table, k_pages,
+                               v_pages, cfg, aux)
     _, S = tokens.shape
     hh, hd = cfg.num_heads, cfg.head_dim
     bs, rows, lanes = k_pages.shape[2:]
@@ -446,8 +805,114 @@ def prefill(params, tokens, length, block_table, k_pages, v_pages, cfg):
     return (next_token, logits, k_pages, v_pages) + loads
 
 
+def _head_major(cfg):
+    from .kv_cache import KVBlockPool
+
+    return KVBlockPool.head_major(*cfg.kv_rows())
+
+
+def _cache_layers(cfg):
+    """Model layer -> its layer of the full pool, of the window pool, of
+    the state slots: three dicts (the pools' layers are not the model's)."""
+    return tuple({i: n for n, i in enumerate(cfg.layers_of(*kinds))}
+                 for kinds in (("full",), ("swa",), ("mamba",)))
+
+
+def _block_rows(t, bs, cfg):
+    """K or V of S tokens ``(S, Hkv hd)`` as S // bs blocks in the pool's
+    own order: ``(S // bs, bs, G, W)``, or ``(S // bs, G, bs, W)``."""
+    g, w = cfg.kv_rows()
+    t = t.reshape(t.shape[0] // bs, bs, g, w)
+    return t.transpose(0, 2, 1, 3) if _head_major(cfg) else t
+
+
+def _prefill_hybrid(params, tokens, length, block_table, k_pages, v_pages,
+                    cfg, aux):
+    """:func:`prefill` for a model with ``layer_kinds``. A layer's K/V is
+    scattered as the layer is computed ("swa" layers into the window pool
+    through ``wtable``, "full" layers into the full pool); "cross" layers
+    read the last "full" layer's K and V of this same program; a "mamba"
+    layer scans from a zero state (a prefill always starts a stream) and
+    leaves its final state and conv tail in the stream's ``slot``."""
+    import jax
+    import jax.numpy as jnp
+
+    _, S = tokens.shape
+    bs = k_pages.shape[3 if _head_major(cfg) else 2]
+    g, w = cfg.kv_rows()
+    rq = cfg.num_heads // g             # query heads reading one K/V row
+    prec = fp32_precision(k_pages.dtype)
+    positions = jnp.arange(S, dtype=jnp.int32)[None]
+    sm_scale = 1.0 / float(np.sqrt(cfg.head_dim))
+    full_at, win_at, ssm_at = _cache_layers(cfg)
+    wtable, slot = aux["wtable"], aux["slot"]
+    taps = cfg.ssm_conv
+
+    def put(pages, li, table, t):
+        """One layer's K or V of the S tokens into its blocks."""
+        rows = _block_rows(t, bs, cfg).astype(pages.dtype)
+        if S == bs:
+            # a scatter of ONE block is rewritten by the compiler into a
+            # form that copies the pool in and out; the slice update it is
+            return jax.lax.dynamic_update_slice(
+                pages, rows[None], (li, table[0], 0, 0, 0))
+        return pages.at[li, table].set(rows)
+
+    def attend(i, q, k, v, st):
+        kind = cfg.kinds()[i]
+        if kind == "cross":
+            k, v = st["kv"]
+        else:
+            if kind == "full":
+                st = dict(st, kv=(k, v))
+                kp, vp, table, li = "k", "v", block_table, full_at[i]
+            else:
+                kp, vp, table, li = "wk", "wv", wtable, win_at[i]
+            st = dict(st, **{kp: put(st[kp], li, table, k[0]),
+                             vp: put(st[vp], li, table, v[0])})
+        # (1, G R, S, W): query row r of K/V row g, against that row
+        # repeated for its R readers
+        qr = _diff_q_rows(q, cfg)[0].reshape(S, g * rq, w).transpose(1, 0, 2)
+        kr, vr = (jnp.repeat(t[0].reshape(S, g, w).transpose(1, 0, 2), rq,
+                             axis=0) for t in (k, v))
+        att = flash_attention(qr[None], kr[None], vr[None], True, sm_scale,
+                              256, cfg.window if kind == "swa" else None)
+        return (att[0].transpose(1, 0, 2).reshape(
+            1, S, cfg.num_heads // 2, 2, w), st)
+
+    def recur(i, xin, z, st):
+        p, li = "layer%d" % i, ssm_at[i]
+        # causal depthwise conv over time from an empty history
+        xp = jnp.pad(xin[0], ((taps - 1, 0), (0, 0)))       # (S + K - 1, Dn)
+        window = jnp.stack([xp[t:t + S] for t in range(taps)], axis=1)
+        xc = _conv_taps(window, params, p).astype(xin.dtype)
+        dt, b, c = _ssm_inputs(xc, params, p, cfg, prec)
+        a = -jnp.exp(params[p + "_ssm_a_log"].astype(jnp.float32))
+        y, out, h = ssm_scan(xc, dt, a, b, c, params[p + "_ssm_d"], z[0],
+                             jnp.zeros(a.shape, jnp.float32), length)
+        # the K - 1 inputs before position `length`
+        tail = jax.lax.dynamic_slice_in_dim(xp, length, taps - 1, axis=0)
+        st = dict(st,
+                  conv=st["conv"].at[li, slot].set(
+                      tail.reshape(-1).astype(st["conv"].dtype)),
+                  ssm=st["ssm"].at[li, slot].set(h))
+        return y[None], out[None], st
+
+    x = _embed(params, tokens, None, cfg)                      # (1, S, M)
+    state = {"k": k_pages, "v": v_pages, "wk": aux["wk"], "wv": aux["wv"],
+             "conv": aux["conv"], "ssm": aux["ssm"]}
+    x, state, _loads = _layers(x, params, cfg, prec, positions,
+                               positions < length, attend, state, recur)
+    x = _norm(x, params, "final_ln", cfg)
+    h_last = jnp.take(x[0], length - 1, axis=0)                # (M,)
+    logits = _head(h_last[None], params, cfg, prec)            # (1, V)
+    next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return (next_token, logits, state["k"], state["v"],
+            {k: state[k] for k in ("wk", "wv", "conv", "ssm")})
+
+
 def _paged_step(params, tokens, positions, block_tables, context_lens,
-                k_pages, v_pages, cfg):
+                k_pages, v_pages, cfg, aux=None):
     """:func:`decode` and :func:`extend` are one function of ``tokens``
     (B, T): T = 1 lane is the decode step, T = k + 1 the verify pass.
     Every lane writes its K/V into the stream's blocks first (distinct
@@ -455,6 +920,9 @@ def _paged_step(params, tokens, positions, block_tables, context_lens,
     its OWN context length — lane t cannot see lanes > t."""
     import jax.numpy as jnp
 
+    if cfg.hybrid:
+        return _paged_step_hybrid(params, tokens, positions, block_tables,
+                                  context_lens, k_pages, v_pages, cfg, aux)
     B, T = tokens.shape
     hh, hd = cfg.num_heads, cfg.head_dim
     bs, rows, lanes = k_pages.shape[2:]
@@ -494,8 +962,109 @@ def _paged_step(params, tokens, positions, block_tables, context_lens,
     return (next_tokens, logits, k_pages, v_pages) + loads
 
 
+def _paged_step_hybrid(params, tokens, positions, block_tables,
+                       context_lens, k_pages, v_pages, cfg, aux):
+    """:func:`_paged_step` for a model with ``layer_kinds``, one token a
+    stream. ``aux``: ``wtables`` (B, nb) the streams' window-pool blocks by
+    position (0 behind the window), ``slots`` (B,) their state slots (0,
+    the trash slot, for padded rows), and the donated ``wk`` / ``wv`` /
+    ``conv`` / ``ssm``. A "swa" layer writes into and reads from the window
+    pool, from the block that holds ``context_len - window``; the "full"
+    layer writes the full pool and the "cross" layers read it; a "mamba"
+    layer shifts its conv tail and updates its state in the stream's slot.
+    The four query heads of a K/V row ride as four query lanes of the
+    paged kernel. Returns :func:`_paged_step`'s four and the dict of the
+    four arrays."""
+    import jax.numpy as jnp
+
+    B, T = tokens.shape
+    if T != 1:
+        raise ValueError("a model with state or window layers decodes one "
+                         "token a step (no verify pass)")
+    hm = _head_major(cfg)
+    bs = k_pages.shape[3 if hm else 2]
+    g, w = cfg.kv_rows()
+    rq = cfg.num_heads // g
+    prec = fp32_precision(k_pages.dtype)
+    sm_scale = 1.0 / float(np.sqrt(cfg.head_dim))
+    full_at, win_at, ssm_at = _cache_layers(cfg)
+    wtables, slots = aux["wtables"], aux["slots"]
+    taps = cfg.ssm_conv
+
+    in_range = positions < cfg.max_len                          # (B, 1)
+    safe_pos = jnp.minimum(positions, cfg.max_len - 1)
+    at = jnp.where(in_range, safe_pos % bs, 0).reshape(-1)
+    valid = in_range & (block_tables[:, :1] > 0)
+    lanes_ctx = jnp.repeat(context_lens, rq, axis=1)            # (B, R)
+
+    def page_ids(tables):
+        ids = jnp.take_along_axis(tables, safe_pos // bs, axis=1)
+        return jnp.where(in_range, ids, 0).reshape(-1)  # overflow -> trash
+
+    def write(pages, li, ids, new):
+        new = new.reshape(B, g, w).astype(pages.dtype)
+        if hm:
+            # every (page, row, slot) named: the scatter's windows are the
+            # 128-lane rows themselves, which it writes where they lie (a
+            # (G, 1, W) window through the block is relaid out first: two
+            # copies of the pool a layer)
+            return pages.at[li, ids[:, None], jnp.arange(g)[None],
+                            at[:, None]].set(new)
+        return pages.at[li, ids, at].set(new)
+
+    def attend(i, q, k, v, st):
+        kind = cfg.kinds()[i]
+        if kind == "swa":
+            kp, vp, tables, li, window = ("wk", "wv", wtables, win_at[i],
+                                          cfg.window)
+        else:
+            full = i if kind == "full" else max(
+                j for j in cfg.layers_of("full") if j < i)
+            kp, vp, tables, li, window = ("k", "v", block_tables,
+                                          full_at[full], None)
+        if kind != "cross":
+            ids = page_ids(tables)
+            st = dict(st, **{kp: write(st[kp], li, ids, k),
+                             vp: write(st[vp], li, ids, v)})
+        # (B, R, G, W): the R query heads of a K/V row as R query lanes
+        qr = _diff_q_rows(q, cfg)[:, 0].transpose(0, 2, 1, 3)
+        att = paged_attention_multi(qr, st[kp], st[vp], tables, lanes_ctx,
+                                    sm_scale=sm_scale, layer=li,
+                                    window=window, head_major=hm)
+        return (att.transpose(0, 2, 1, 3).reshape(
+            B, 1, cfg.num_heads // 2, 2, w), st)
+
+    def recur(i, xin, z, st):
+        p, li = "layer%d" % i, ssm_at[i]
+        tail = st["conv"][li, slots].reshape(B, taps - 1, -1)
+        window = jnp.concatenate([tail.astype(xin.dtype), xin], axis=1)
+        xc = _conv_taps(window, params, p).astype(xin.dtype)     # (B, Dn)
+        dt, b, c = _ssm_inputs(xc, params, p, cfg, prec)
+        a = -jnp.exp(params[p + "_ssm_a_log"].astype(jnp.float32))
+        y, out, ssm = ssm_step(xc, dt, a, b, c, params[p + "_ssm_d"],
+                               z[:, 0], st["ssm"], slots, li)
+        st = dict(st, ssm=ssm, conv=st["conv"].at[li, slots].set(
+            window[:, 1:].reshape(B, -1).astype(st["conv"].dtype)))
+        return y[:, None], out[:, None], st
+
+    x = _embed(params, tokens, safe_pos, cfg)                   # (B, 1, M)
+    state = {"k": k_pages, "v": v_pages, "wk": aux["wk"], "wv": aux["wv"],
+             "conv": aux["conv"], "ssm": aux["ssm"]}
+    x, state, _loads = _layers(x, params, cfg, prec, safe_pos, valid, attend,
+                               state, recur)
+    x = _norm(x, params, "final_ln", cfg)
+    logits = _head(x.reshape(B, cfg.model_dim), params, cfg,
+                   prec).reshape(B, 1, -1)
+    next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    next_tokens = jnp.where(in_range, next_tokens, -1)
+    logits = jnp.where(in_range[:, :, None], logits,
+                       jnp.asarray(np.nan, logits.dtype))
+    return (next_tokens, logits, state["k"], state["v"],
+            {k: state[k] for k in ("wk", "wv", "conv", "ssm")})
+
+
 def decode(params, tokens, positions, block_tables, context_lens,
-           k_pages, v_pages, cfg):
+           k_pages, v_pages, cfg, aux=None):
     """The fused paged decode step: one token for every sequence in the
     padded batch, one XLA program per batch bucket.
 
@@ -513,11 +1082,12 @@ def decode(params, tokens, positions, block_tables, context_lens,
     config with experts. Out-of-range positions (>= max_len) honor the
     overflow contract: the write is routed to the trash block,
     ``next_token`` is -1, and the lane's logits are NaN — the cache cannot
-    be corrupted from the graph.
+    be corrupted from the graph. A model with ``layer_kinds`` takes ``aux``
+    and returns its arrays as a fifth result (:func:`_paged_step_hybrid`).
     """
     nxt, logits, *rest = _paged_step(
         params, tokens[:, None], positions[:, None], block_tables,
-        context_lens[:, None], k_pages, v_pages, cfg)
+        context_lens[:, None], k_pages, v_pages, cfg, aux)
     return (nxt[:, 0], logits[:, 0]) + tuple(rest)
 
 

@@ -70,7 +70,8 @@ class Request:
                  "state", "blocks", "shared_blocks", "context_len",
                  "generated", "pending_token", "arrival_t", "admitted_t",
                  "first_token_t", "preempted_t", "finish_t", "preemptions",
-                 "error", "done_event", "trace", "deadline_t", "cancelled")
+                 "error", "done_event", "trace", "deadline_t", "cancelled",
+                 "wblocks", "slot")
 
     def __init__(self, prompt, max_new_tokens, eos_id=None, rid=None,
                  request_id=None, timeout_s=None):
@@ -93,6 +94,10 @@ class Request:
                                   # index (refcounted, copy-on-write; the
                                   # prefill write table routes them to
                                   # trash — their K/V is already cached)
+        self.wblocks = []         # window-pool block ids by position, 0
+                                  # where behind the window (a model with
+                                  # "swa" layers; kv_cache.StreamState)
+        self.slot = None          # state slot (a model with "mamba" layers)
         self.context_len = 0      # tokens currently cached in the pool
         self.generated = []       # tokens produced so far (output stream)
         self.pending_token = None  # last generated token, not yet cached
@@ -160,8 +165,11 @@ class Scheduler:
     """FCFS continuous-batching scheduler over one :class:`KVBlockPool`."""
 
     def __init__(self, pool, max_batch=32, prefills_per_step=4,
-                 lookahead=1, max_positions=None):
+                 lookahead=1, max_positions=None, streams=None):
         self.pool = pool
+        # kv_cache.StreamState of a model with window or state layers: the
+        # slot and window blocks a stream holds beside its blocks of `pool`
+        self.streams = streams
         self.max_batch = int(max_batch)
         self.prefills_per_step = int(prefills_per_step)
         # write slots a decoding stream consumes per engine step: 1 for
@@ -226,10 +234,10 @@ class Scheduler:
                 # slots at/past the cap route to trash in-graph; backing
                 # them with real blocks would waste pool for nothing
                 last_pos = min(last_pos, self.max_positions - 1)
-            need_idx = last_pos // self.pool.block_size
-            while need_idx >= len(req.blocks):
+            while True:
                 try:
-                    req.blocks.extend(self.pool.alloc(1))
+                    self._back_slot(req, last_pos)
+                    break
                 except KVCacheOOM:
                     # evict the YOUNGEST decoding stream — possibly req
                     # itself (a younger request never steals blocks from
@@ -249,6 +257,27 @@ class Scheduler:
                     if victim is req:
                         break
         return preempted
+
+    def _back_slot(self, req, last_pos):
+        """Back ``req``'s write slots up to ``last_pos`` with blocks, in
+        the full pool and then in the window pool (whose blocks behind the
+        window are returned first); :class:`KVCacheOOM` where one is dry
+        (what was booked stays with the request)."""
+        need_idx = last_pos // self.pool.block_size
+        while need_idx >= len(req.blocks):
+            req.blocks.extend(self.pool.alloc(1))
+        if self.streams is not None:
+            self.streams.ensure(req, last_pos)
+
+    def _release(self, req):
+        """Everything ``req`` holds goes back: its blocks of the full pool
+        (one reference each) and its window blocks and state slot."""
+        if req.blocks:
+            self.pool.free(req.blocks)
+            req.blocks = []
+        req.shared_blocks = 0
+        if self.streams is not None:
+            self.streams.release(req)
 
     def _pick_victim(self, ensuring=None):
         """Youngest decoding stream whose eviction actually reclaims
@@ -276,10 +305,7 @@ class Scheduler:
         decrements refcounts: shared prefix blocks survive for their
         other holders, only sole-owner blocks return to the pool."""
         self.running.remove(req)
-        if req.blocks:
-            self.pool.free(req.blocks)
-            req.blocks = []
-        req.shared_blocks = 0
+        self._release(req)
         req.context_len = 0
         req.state = WAITING
         req.preemptions += 1
@@ -300,10 +326,7 @@ class Scheduler:
         each bumps its own counter."""
         if req in self.running:   # admission-time failures never joined
             self.running.remove(req)
-        if req.blocks:
-            self.pool.free(req.blocks)
-            req.blocks = []
-        req.shared_blocks = 0
+        self._release(req)
         req.state = state
         req.error = msg
         req.finish_t = time.time()
@@ -368,6 +391,11 @@ class Scheduler:
                                 "holds %d usable"
                            % (need, self.pool.num_usable))
                 continue
+            if self.streams is not None and not self.streams.can_admit(
+                    len(replay)):
+                # no state slot, or too few window blocks: the head waits
+                # (nothing is booked in part: not the full pool either)
+                break
             # prefix sharing: map the longest indexed block-aligned prefix
             # into the table (refcounted), allocate only the tail. The
             # match can never cover the first write slot — it spans full
@@ -393,6 +421,13 @@ class Scheduler:
                     self.pool.free(shared)
                 self._fail(req, "admission refused: %s" % e)
                 continue
+            if self.streams is not None:
+                try:
+                    self.streams.admit(req, len(replay))
+                except KVCacheOOM as e:     # as above: all or nothing
+                    self.pool.free(shared + fresh_blocks)
+                    self._fail(req, "admission refused: %s" % e)
+                    continue
             req.blocks = shared + fresh_blocks
             req.shared_blocks = len(shared)
             req.state = PREFILL
@@ -415,10 +450,7 @@ class Scheduler:
         """Retire a FINISHED/FAILED request and release its blocks."""
         if req in self.running:
             self.running.remove(req)
-        if req.blocks:
-            self.pool.free(req.blocks)
-            req.blocks = []
-        req.shared_blocks = 0
+        self._release(req)
         self._refresh_gauges()
 
     def frag_slots(self):

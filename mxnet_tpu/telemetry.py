@@ -806,6 +806,17 @@ METRIC_HELP = {
     "serving.moe.load_max_over_mean":
         "busiest expert's tokens over the mean expert's, averaged over "
         "the layers of the last step",
+    "serving.ssm.stream_steps":
+        "decode state updates: live streams a decode step of a model with "
+        "state-space layers (x its 'mamba' layers = slot updates)",
+    "serving.ssm.prefill_tokens":
+        "prompt+replay tokens scanned by the state-space layers' prefill",
+    "serving.window.blocks_freed":
+        "window-pool blocks returned during decode because they fell "
+        "wholly behind a stream's sliding window",
+    "serving.state_slots_used":
+        "state slots (conv tail + SSM state a 'mamba' layer) held by "
+        "running streams",
     "serving.ttft_seconds": "request time-to-first-token "
         "(bare = process-wide; engine label = per-engine)",
     "serving.request_latency_seconds": "request end-to-end latency "

@@ -244,6 +244,89 @@ def test_pool_guard_sees_the_copies_of_a_half_lane_pool(v5e):
     assert compiled.memory_analysis().temp_size_in_bytes > 2 * pool_bytes
 
 
+def _hybrid_program(chip, name):
+    """``model.decode`` (B=64) or ``model.prefill`` (S=1024, or one block:
+    a scatter of ONE block copied the window pool in and out, PR 31) of
+    Phi-4-mini-flash at published widths, cut to one layer of each kind
+    that holds state (mamba, swa, mamba as the memory layer, full, gmu,
+    cross), every pool and the state slots donated, compiled for the chip.
+    Returns (compiled, the four caches' shapes)."""
+    from mxnet_tpu.serving import model as M
+
+    def s(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(tuple(shape), dt, sharding=chip)
+
+    bf = jnp.bfloat16
+    cfg = M.ModelConfig(
+        200064, 6, 2560, 40, 10240, 4096, pos="none", bias=False,
+        head_dim=64, num_kv_heads=20, window=512, attn_bias=True,
+        ffn_gated=True, tie_embed=True, ssm_dt_rank=160,
+        layer_kinds=["mamba", "swa", "mamba", "full", "gmu", "cross"])
+    bs, (g, w) = 64, cfg.kv_rows()
+    caches = {"pool": s((1, 2561, g, bs, w), bf),
+              "window": s((1, 1153, g, bs, w), bf),
+              "conv": s((2, 513, 3 * cfg.d_inner), bf),
+              "ssm": s((2, 513, 16, cfg.d_inner), jnp.float32)}
+    params = {k: s(v, bf) for k, v in M.param_shapes(cfg).items()}
+    aux = ("wk", "wv", "conv", "ssm")
+    if name == "decode":
+        def fn(params, toks, poss, tables, ctx, kp, vp, wt, slots, *arrays):
+            return M.decode(params, toks, poss, tables, ctx, kp, vp, cfg,
+                            dict(zip(aux, arrays), wtables=wt, slots=slots))
+        args = (s((64,)), s((64,)), s((64, 64)), s((64,)))
+        more, donate = (s((64, 64)), s((64,))), (5, 6, 9, 10, 11, 12)
+    else:
+        def fn(params, toks, n, table, kp, vp, wt, slot, *arrays):
+            return M.prefill(params, toks, n, table, kp, vp, cfg,
+                             dict(zip(aux, arrays), wtable=wt, slot=slot))
+        S = 64 if name == "prefill-one-block" else 1024
+        args = (s((1, S)), s(()), s((S // bs,)))
+        more, donate = (s((S // bs,)), s(())), (4, 5, 8, 9, 10, 11)
+    compiled = jax.jit(fn, donate_argnums=donate).lower(
+        params, *args, caches["pool"], caches["pool"], *more,
+        caches["window"], caches["window"], caches["conv"],
+        caches["ssm"]).compile()
+    return compiled, {k: v.shape for k, v in caches.items()}
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill", "prefill-one-block"])
+def test_hybrid_programs_leave_every_cache_in_place(v5e, name):
+    """Twenty K/V heads of 64 are ten page rows of 128 lanes: token-major
+    they would be padded to sixteen and copied by every kernel call
+    (PR 27); head-major blocks ``(G, bs, W)`` are whole tiles and the
+    programs take the full pool, the window pool and the float32 states
+    where they lie — no copy or slice the size of one of them or of a
+    layer, everything donated and aliased, the state-space kernels by
+    their names."""
+    from mxnet_tpu.serving.kv_cache import KVBlockPool
+
+    assert KVBlockPool.head_major(10, 128)
+    compiled, shapes = _hybrid_program(v5e, name)
+    text = compiled.as_text()
+    kernel = "ssm_step" if name == "decode" else "ssm_scan"
+    assert len(re.findall(r"%%%s[.\d]* = " % kernel, text)) == 2
+    # + three attentions (a 64-token prefill's flash forward is the XLA scan)
+    assert text.count("tpu_custom_call") >= (
+        2 if name == "prefill-one-block" else 5)
+    # the conv tails (18 MB of the real 2.6 GB of caches) are the one array
+    # the compiler may stage through fast memory around its row scatters
+    # (an async slice in, a copy back; PERF.md section 6, PR 31): no layout
+    # of theirs is checked here
+    for key in ("pool", "window", "ssm"):
+        assert _pool_copies(text, shapes[key]) == [], key
+    for key in ("pool", "window"):
+        assert _entry_layouts(text, shapes[key]) == {"4,3,2,1,0"}
+    cache_bytes = (2 * 2 * (math.prod(shapes["pool"])
+                            + math.prod(shapes["window"]))
+                   + 2 * math.prod(shapes["conv"])
+                   + 4 * math.prod(shapes["ssm"]))
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= cache_bytes     # donated, all taken
+    # the prefill's temporaries are activations of 1,024 tokens, no cache
+    assert ma.temp_size_in_bytes < (64 << 20 if name == "decode"
+                                    else 512 << 20)
+
+
 def _flash_grad(q, k, v):
     return jax.grad(lambda *a: A.flash_attention(*a, True)
                     .astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)

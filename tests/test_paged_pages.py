@@ -300,3 +300,150 @@ def test_cow_and_prefix_hit_on_a_packed_pool(r):
             np.asarray(pool.k_pages[:, blk]).reshape(kv.shape), kv)
         np.testing.assert_array_equal(
             np.asarray(pool.v_pages[:, blk]).reshape(kv.shape), 2.0 * kv)
+
+
+# ---- head-major pages, a window, grouped query lanes (PR 31): ten rows of
+# 128 lanes as (G, bs, W) slabs; the kernel in interpret mode against the
+# gathered reference, and the reference against a dense computation.
+def _head_major(case, dtype, g=10, w=128, N=12, L=2, lanes=4):
+    cl = np.repeat(np.asarray(RAGGED[case], np.int32)[:, :1], lanes, axis=1)
+    rng = np.random.RandomState(len(case))
+    B = len(cl)
+    q = rng.randn(B, lanes, g, w)
+    kp, vp = rng.randn(2, L, N, g, _BS, w)
+    bt = rng.randint(1, N - 1, (B, _NB)).astype(np.int32)
+    if case == "dead-1e30":
+        kp[:, N - 1] = vp[:, N - 1] = 1e30
+        for i in range(B):
+            bt[i, -(-int(cl[i].max()) // _BS):] = N - 1
+    q, kp, vp = (jnp.asarray(x, dtype) for x in (q, kp, vp))
+    return q, kp, vp, jnp.asarray(bt), jnp.asarray(cl)
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("window", [None, 40, 16], ids=["full", "w40", "w16"])
+@pytest.mark.parametrize("case", ["boundaries", "last-longest",
+                                  "first-longest", "dead-1e30"])
+def test_head_major_kernel_is_the_reference(case, window, dtype):
+    """The same walk over ``(G, bs, W)`` blocks: four query lanes a stream
+    (the four query heads of a differential K/V row), from the block that
+    holds ``context - window``; slots behind the window are never read."""
+    dt, tol = DTYPES[dtype]
+    q, kp, vp, bt, cl = _head_major(case, dt)
+    if window is not None and case == "dead-1e30":
+        # blocks wholly behind every lane's window may name anything too
+        bt = np.array(bt)
+        for i in range(len(bt)):
+            bt[i, :max(int(cl[i].min()) - window, 0) // _BS] = kp.shape[1] - 1
+        bt = jnp.asarray(bt)
+    want = A.paged_attention_multi_reference(
+        q, kp, vp, bt, cl, sm_scale=0.125, layer=1, window=window,
+        head_major=True)
+    got = A._paged_pallas_multi(q, kp, vp, bt, cl, 0.125, layer=1,
+                                interpret=True, window=window,
+                                head_major=True)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=tol * 4,
+                               rtol=tol * 4)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+
+
+@pytest.mark.parametrize("window", [None, 24])
+def test_head_major_reference_is_dense_attention(window):
+    """The oracle's own oracle: token-major pages of the same numbers, and
+    a dense masked softmax over the gathered keys."""
+    q, kp, vp, bt, cl = _head_major("boundaries", jnp.float32, g=3, lanes=2)
+    got = A.paged_attention_multi_reference(
+        q, kp, vp, bt, cl, sm_scale=0.1, layer=0, window=window,
+        head_major=True)
+    flat = A.paged_attention_multi_reference(
+        q, kp.transpose(0, 1, 3, 2, 4), vp.transpose(0, 1, 3, 2, 4), bt, cl,
+        sm_scale=0.1, layer=0, window=window)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(flat))
+    k = np.asarray(kp[0])[np.asarray(bt)].transpose(0, 1, 3, 2, 4).reshape(
+        len(bt), -1, 3, 128)
+    v = np.asarray(vp[0])[np.asarray(bt)].transpose(0, 1, 3, 2, 4).reshape(
+        len(bt), -1, 3, 128)
+    for b in range(len(bt)):
+        ctx = int(cl[b, 0])
+        lo = max(ctx - window, 0) if window else 0
+        if ctx == 0:
+            assert not np.asarray(got[b]).any()
+            continue
+        s = np.einsum("thd,khd->thk", np.asarray(q[b]), k[b, lo:ctx]) * 0.1
+        p = np.exp(s - s.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        np.testing.assert_allclose(
+            np.asarray(got[b]), np.einsum("thk,khd->thd", p, v[b, lo:ctx]),
+            atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("window", [20, 64])
+def test_token_major_kernel_takes_a_window_too(window):
+    q, kp, vp, bt, cl, layer = _ragged("lanes", 2, jnp.float32, True,
+                                       heads=ALIGNED)
+    want = A.paged_attention_multi_reference(q, kp, vp, bt, cl, layer=layer,
+                                             window=window)
+    got = A._paged_pallas_multi(q, kp, vp, bt, cl, 0.125, layer=layer,
+                                interpret=True, window=window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6,
+                               rtol=2e-6)
+
+
+@pytest.mark.parametrize("window", [None, 100, 1])
+def test_flash_forward_window_is_the_masked_softmax(window):
+    rng = np.random.RandomState(3)
+    q, k, v = (jnp.asarray(rng.randn(1, 3, 256, 64), jnp.float32)
+               for _ in range(3))
+    s = np.einsum("bhqd,bhkd->bhqk", q, k) * 0.125
+    i, j = np.arange(256)[:, None], np.arange(256)[None]
+    seen = (j <= i) & ((i - j < window) if window else True)
+    s = np.where(seen, s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    want = np.einsum("bhqk,bhkd->bhqd", p / p.sum(-1, keepdims=True), v)
+    got = A.flash_attention(q, k, v, True, None, 64, window)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=2e-5)
+    kern, _lse = A._pallas_forward(q, k, v, True, 0.125, block_q=64,
+                                   block_k=128, interpret=True,
+                                   window=window)
+    np.testing.assert_allclose(np.asarray(kern), want, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("dn", [128, 1024])
+def test_ssm_kernels_are_their_xla_paths(dn):
+    """``ops/ssm.py``'s two Pallas kernels in interpret mode against the
+    ``lax.scan`` / gather-scatter lowerings the CPU runs."""
+    from mxnet_tpu.ops import ssm
+
+    rng = np.random.RandomState(dn)
+    S, N, B = 128, 16, 5
+    x, z = rng.randn(2, S, dn).astype(np.float32)
+    dt = rng.randn(S, dn).astype(np.float32) - 2
+    a = -np.exp(0.3 * rng.randn(N, dn)).astype(np.float32)
+    b, c = rng.randn(2, S, N).astype(np.float32)
+    d = rng.randn(dn).astype(np.float32)
+    h0 = rng.randn(N, dn).astype(np.float32)
+    for length in (S, 77, 1):
+        want = ssm.ssm_scan_reference(x, dt, a, b, c, d, z, h0,
+                                      jnp.int32(length))
+        got = ssm._scan_pallas(x, dt, a, b, c, d, z, h0, jnp.int32(length),
+                               interpret=True)
+        for g, w in zip(got[:2], want[:2]):          # y, out: the live rows
+            np.testing.assert_allclose(np.asarray(g)[:length],
+                                       np.asarray(w)[:length], atol=1e-4,
+                                       rtol=1e-4)
+        np.testing.assert_allclose(np.asarray(got[2]), np.asarray(want[2]),
+                                   atol=1e-4, rtol=1e-4)
+    state = jnp.asarray(rng.randn(2, 7, N, dn).astype(np.float32))
+    slots = jnp.asarray([3, 6, 0, 1, 0])        # two padded rows: the trash
+    want = ssm.ssm_step_reference(x[:B], dt[:B], a, b[:B], c[:B], d, z[:B],
+                                  state, slots, 1)
+    got = ssm._step_pallas(x[:B], dt[:B], a, b[:B], c[:B], d, z[:B], state,
+                           slots, 1, interpret=True)
+    for g, w in zip(got[:2], want[:2]):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-5,
+                                   rtol=1e-5)
+    g, w = np.asarray(got[2]), np.asarray(want[2])
+    np.testing.assert_allclose(g[:, 1:], w[:, 1:], atol=1e-5, rtol=1e-5)
+    assert (g[0] == np.asarray(state)[0]).all()         # the other layer
+    assert (g[1, [2, 4, 5]] == np.asarray(state)[1, [2, 4, 5]]).all()
