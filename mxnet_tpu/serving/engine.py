@@ -11,10 +11,13 @@ acceptance gate.
 
 Each :meth:`step` runs the scheduler's plan: admitted prompts prefill into
 the shared block pool (one call per request at its length bucket), then
-every decoding stream advances one token through the fused paged decode
-step at the batch bucket. The ONLY device->host sync per step is the tiny
-next-token vector — that read IS the product (tokens leave for clients);
-everything else stays device-resident, pool pages donated call to call.
+every decoding stream advances up to ``DECODE_CHUNK`` tokens through ONE
+dispatch of the fused paged decode program at the batch bucket, which
+loops over the decode step on the device. The ONLY device->host sync per
+dispatch is the tiny array of next tokens — that read IS the product
+(tokens leave for clients); everything else stays device-resident, pool
+pages donated call to call. The host is synchronous at that fetch: no
+program is in flight while it retires, frees, schedules and admits.
 
 Thread model: ``submit()`` is safe from any thread (HTTP handlers);
 ``step()``/``run_loop()`` must run on one driver thread. Per-request
@@ -48,6 +51,15 @@ _engine_ids = itertools.count()
 #: the step programs' argument order: the window pool's K and V pages, the
 #: conv tails, the SSM states
 _AUX = ("wk", "wv", "conv", "ssm")
+
+#: decode steps one dispatch may run on the device (``model.decode_chunk``):
+#: the host's gap between two dispatches — the blocking token fetch, jax's
+#: dispatch, the loop's own bookkeeping — is paid once a chunk. Like a
+#: kernel's block size: one value for every model, read on the chip at 2,
+#: 4 and 8 in the three closed-loop cells (PERF.md section 6, PR 32: 8 read
+#: best in all three, by less each doubling); a freed lane idles at most
+#: DECODE_CHUNK - 1 steps and an arrival waits at most that many more
+DECODE_CHUNK = 8
 
 
 class ServingConfig(_model.ModelConfig):
@@ -187,11 +199,12 @@ def _pack_fetch(tokens, logits, k_pages, v_pages, load=None):
             logits, k_pages, v_pages)
 
 
-def _unpack_fetch(fetched, shape, cfg):
+def _unpack_fetch(fetched, shape, cfg, steps=()):
     """``(next tokens of shape, load (L, E) or None)`` from the fetched
-    vector of :func:`_pack_fetch`."""
+    vector of :func:`_pack_fetch`; a decode chunk's load is one (L, E) a
+    step: ``steps = (chunk,)``."""
     n = int(np.prod(shape))
-    load = (fetched[n:].reshape(cfg.num_layers, cfg.num_experts)
+    load = (fetched[n:].reshape(steps + (cfg.num_layers, cfg.num_experts))
             if cfg.num_experts else None)
     return fetched[:n].reshape(shape), load
 
@@ -231,6 +244,9 @@ class ServingEngine:
         if enable_telemetry:
             telemetry.enable()
         self.config = cfg = config
+        # read once: the decode programs' results, the window pool and the
+        # scheduler's headroom all follow it for the engine's life
+        self._chunk = chunk = DECODE_CHUNK
         if arg_params is None:
             arg_params = _model.random_params(cfg, seed=seed)
         self.params = _model.as_device_params(arg_params, cfg, device=device)
@@ -244,13 +260,13 @@ class ServingEngine:
         else:
             (self.pool, self.window_pool, self.state,
              self.streams) = self._hybrid_caches(cfg, device)
-        # speculative decoding writes spec_k+1 window slots per step, so
-        # headroom lookahead covers the whole draft+verify window
+        # a step writes up to a decode chunk's slots a stream, or the
+        # spec_k+1 of a draft+verify window: headroom covers either
         self._spec = cfg.spec_k > 0
         self.spec_k = cfg.spec_k
         self.scheduler = Scheduler(self.pool, max_batch=cfg.max_batch,
                                    prefills_per_step=cfg.prefills_per_step,
-                                   lookahead=cfg.spec_k + 1,
+                                   lookahead=max(cfg.spec_k + 1, chunk),
                                    max_positions=cfg.max_len,
                                    streams=self.streams)
         self._nb_max = cfg.max_len // cfg.block_size
@@ -298,6 +314,9 @@ class ServingEngine:
         # that hold a live stream's context, of the table slots it holds
         self._paged_live_blocks = 0
         self._paged_table_slots = 0
+        # how often the chunk engages: device steps over dispatches
+        self._decode_dispatches = 0
+        self._decode_inner_steps = 0
         self._t_started = time.time()
         self._tokens_total = 0
         # per-engine identity: labels this engine's histograms/counters in
@@ -347,33 +366,33 @@ class ServingEngine:
                 return (tok, logits, kp, vp) + tuple(out[k] for k in _AUX)
             return _prefill
 
+        # the name `_decode` stays whatever runs inside: the trace's ops
+        # are `jit__decode/...`, and an op of the loop's body keeps its own
         def _mk_decode():
             def _decode(params, tokens, positions, block_tables,
-                        context_lens, k_pages, v_pages):
-                return _pack_fetch(*_model.decode(
+                        context_lens, steps_left, eos, n, k_pages, v_pages,
+                        *hybrid):
+                aux = None
+                if cfg.hybrid:
+                    wtables, slots, *arrays = hybrid
+                    aux = dict(zip(_AUX, arrays), wtables=wtables,
+                               slots=slots)
+                tok, logits, kp, vp, *rest = _model.decode_chunk(
                     params, tokens, positions, block_tables, context_lens,
-                    k_pages, v_pages, cfg))
-
-            if not cfg.hybrid:
-                return _decode
-
-            def _decode(params, tokens, positions, block_tables,  # noqa: F811
-                        context_lens, k_pages, v_pages, wtables, slots,
-                        *arrays):
-                tok, logits, kp, vp, out = _model.decode(
-                    params, tokens, positions, block_tables, context_lens,
-                    k_pages, v_pages, cfg,
-                    dict(zip(_AUX, arrays), wtables=wtables, slots=slots))
-                return (tok, logits, kp, vp) + tuple(out[k] for k in _AUX)
+                    steps_left, eos, n, k_pages, v_pages, cfg, chunk, aux)
+                if cfg.hybrid:
+                    return (tok, logits, kp, vp) + tuple(
+                        rest[0][k] for k in _AUX)
+                return _pack_fetch(tok, logits, kp, vp, *rest)
             return _decode
 
-        if donate:
-            decode_donate = {"donate_argnums": (5, 6)}
-            if cfg.hybrid:      # the window pages and the state slots too
-                donate = {"donate_argnums": (4, 5, 8, 9, 10, 11)}
-                decode_donate = {"donate_argnums": (5, 6, 9, 10, 11, 12)}
-        else:
-            decode_donate = {}
+        # the draft's one-token step and the verify pass keep the plain
+        # step's arguments (`_run_spec_decode` has its own loop)
+        step_donate = {"donate_argnums": (5, 6)} if donate else {}
+        decode_donate = {"donate_argnums": (8, 9)} if donate else {}
+        if donate and cfg.hybrid:   # the window pages and the state slots too
+            donate = {"donate_argnums": (4, 5, 8, 9, 10, 11)}
+            decode_donate = {"donate_argnums": (8, 9, 12, 13, 14, 15)}
         # one wrapper per shape bucket: buckets are DESIGNED to each
         # compile once, so a bucket's first compile must not diff against
         # another bucket's signature under a shared graph key — that would
@@ -391,8 +410,8 @@ class ServingEngine:
         ckey_base = cfg.key() + (cfg.block_size, cfg.num_blocks,
                                  str(cfg.kv_dtype))
         if cfg.hybrid:
-            # the window pool and the state slots are sized from it
-            ckey_base += (cfg.max_batch,)
+            # the window pool and the state slots are sized from them
+            ckey_base += (cfg.max_batch, chunk)
         self._prefill_jits = {
             S: compileobs.jit(_mk_prefill(), "serving.prefill", site=_SITE,
                               graph_key=gkey + ("prefill", S), aot=True,
@@ -401,19 +420,16 @@ class ServingEngine:
             for S in cfg.prefill_buckets()}
         self._decode_jits = {
             B: compileobs.jit(_mk_decode(), "serving.decode", site=_SITE,
-                              graph_key=gkey + ("decode", B), aot=True,
+                              graph_key=gkey + ("decode", B, chunk),
+                              aot=True,
                               cache_key=("serving.decode",) + ckey_base
-                              + (B,), **decode_donate)
+                              + (B, chunk), **decode_donate)
             for B in cfg.decode_buckets()}
         # bucket dispatch: call sites pad to an exact bucket shape, so the
         # padded dims index the wrapper table directly
         self._prefill_fn = lambda params, toks, L, table, kp, vp, *aux: \
             self._prefill_jits[toks.shape[1]](params, toks, L, table,
                                               kp, vp, *aux)
-        self._decode_fn = \
-            lambda params, toks, poss, tables, ctx, kp, vp, *aux: \
-            self._decode_jits[toks.shape[0]](params, toks, poss, tables,
-                                             ctx, kp, vp, *aux)
 
         # ---- speculative decoding: draft model + verify pass ----------
         # two more compileobs program families riding the same nonce-free
@@ -493,7 +509,7 @@ class ServingEngine:
                                   graph_key=gkey + ("draft.decode", B),
                                   aot=True,
                                   cache_key=("serving.draft.decode",)
-                                  + dkey_base + (B,), **decode_donate)
+                                  + dkey_base + (B,), **step_donate)
                 for B in cfg.decode_buckets()}
             self._verify_jits = {
                 B: compileobs.jit(_mk_verify(), "serving.verify",
@@ -501,7 +517,7 @@ class ServingEngine:
                                   graph_key=gkey + ("verify", B, cfg.spec_k),
                                   aot=True,
                                   cache_key=("serving.verify",) + ckey_base
-                                  + (B, cfg.spec_k), **decode_donate)
+                                  + (B, cfg.spec_k), **step_donate)
                 for B in cfg.decode_buckets()}
             self._draft_prefill_fn = \
                 lambda params, toks, L, table, kp, vp: \
@@ -701,7 +717,7 @@ class ServingEngine:
                 if decodes and self._spec:
                     self._note_spec_decode(decodes, fetched)
                 elif decodes:
-                    self._note_decode(decodes, fetched)
+                    retire.set(**self._note_decode(decodes, fetched))
                 finished = [r for r in list(self.scheduler.running)
                             if r.finished()]
                 for req in finished:
@@ -1054,11 +1070,13 @@ class ServingEngine:
         pool = KVBlockPool(max(n_full, 1), cfg.num_blocks, cfg.block_size,
                            *heads, dtype=cfg.kv_dtype, device=device,
                            prefix_cache=False, rows=rows)
-        # max_batch streams of window + one block of tokens, max_batch
-        # slots, and the trash of each: running <= max_batch, so neither
-        # runs short. A kind the model lacks keeps a two-block (two-slot)
-        # stand-in, so that the step programs have one signature
-        per_stream = -(-(cfg.window + cfg.block_size) // cfg.block_size)
+        # max_batch streams of window + one block of tokens and the slots
+        # a decode chunk writes beyond its first, max_batch slots, and the
+        # trash of each: running <= max_batch, so neither runs short. A
+        # kind the model lacks keeps a two-block (two-slot) stand-in, so
+        # that the step programs have one signature
+        per_stream = -(-(cfg.window + cfg.block_size + self._chunk - 1)
+                       // cfg.block_size)
         window_pool = KVBlockPool(
             max(n_win, 1), cfg.max_batch * per_stream + 1 if n_win else 2,
             cfg.block_size, *heads, dtype=cfg.kv_dtype, device=device,
@@ -1092,13 +1110,32 @@ class ServingEngine:
         self.pool.k_pages, self.pool.v_pages = kp, vp
         return tok, logits
 
+    def _decode_fn(self, params, toks, poss, tables, ctx, kp, vp, *aux,
+                   left=None, eos=None, n=1):
+        """The decode program of ``toks``' batch bucket: ``n`` steps of the
+        chunk, lane i for ``left[i]`` of them at most and until ``eos[i]``.
+        The defaults are ONE step of every lane, so that the plain
+        step's seven arguments (a warm-up's call) run, load or compile
+        the very executable a chunk runs. Four results (the chunk's rows
+        of tokens — with the experts' loads behind them —, logits, pages),
+        and a model with ``layer_kinds``' four arrays."""
+        B = toks.shape[0]
+        if left is None:
+            left = np.ones(B, np.int32)
+        if eos is None:
+            eos = np.full(B, -1, np.int32)
+        # fwlint: disable=recompile-hazard — B is toks' bucket (the call sites pad to one) and n a traced np scalar: data, one executable a bucket
+        return self._decode_jits[B](params, toks, poss, tables, ctx, left,
+                                    eos, np.int32(n), kp, vp, *aux)
+
     def _dispatch_decode(self, toks, poss, tables, ctx, wtables=None,
-                         slots=None):
-        """The same for the decode program of ``toks``' batch bucket."""
+                         slots=None, **chunk):
+        """The same for the decode program of ``toks``' batch bucket;
+        ``chunk``: :meth:`_decode_fn`'s ``left`` / ``eos`` / ``n``."""
         if self.streams is None:
             tok, logits, kp, vp = self._decode_fn(
                 self.params, toks, poss, tables, ctx,
-                self.pool.k_pages, self.pool.v_pages)
+                self.pool.k_pages, self.pool.v_pages, **chunk)
         else:
             if wtables is None:
                 wtables = np.zeros_like(tables)
@@ -1107,7 +1144,7 @@ class ServingEngine:
             tok, logits, kp, vp, *aux = self._decode_fn(
                 self.params, toks, poss, tables, ctx,
                 self.pool.k_pages, self.pool.v_pages, wtables, slots,
-                *self._aux())
+                *self._aux(), **chunk)
             self._keep_aux(aux)
         self.pool.k_pages, self.pool.v_pages = kp, vp
         return tok, logits
@@ -1198,23 +1235,22 @@ class ServingEngine:
             self.obs.prefill_done(req, stall, was_replay)
 
     def _run_decode(self, reqs):
-        """Build, dispatch and fetch one fused decode step; returns the
-        fetched next-token vector for :meth:`_note_decode`."""
+        """Build, dispatch and fetch one CHUNK of fused decode steps: up
+        to ``DECODE_CHUNK`` steps of the device's loop in one dispatch and
+        ONE blocking fetch, after which (and not before) the host retires,
+        frees and schedules. A lane runs for its own ``steps_left``; the
+        chunk is as long as its longest lane's, not cut to the first that
+        finishes (a stream ends every few steps of a full batch, and the
+        chunk would never form). Returns what :meth:`_note_decode` books."""
         cfg = self.config
         B = _bucket_for(len(reqs), cfg.decode_buckets())
-        ctx = np.ones(B, np.int32)
-        ctx[:len(reqs)] = [req.context_len + 1 for req in reqs]
-        # each decode call's context length on the trace: what a roofline
-        # share of the paged kernel is computed from (PERF.md section 7)
-        args = {"batch": len(reqs), "bucket": B,
-                "ctx_tokens": int(ctx.sum()), "ctx_max": int(ctx.max()),
-                "live_blocks": self._note_paged(ctx[:len(reqs)])}
-        if self.streams is not None:
-            args.update(self._note_hybrid(ctx[:len(reqs)],
-                                          args["live_blocks"]))
-        with telemetry.span("serving.decode.build", _CAT, **args):
+        args = {"batch": len(reqs), "bucket": B}
+        with telemetry.span("serving.decode.build", _CAT, **args) as build:
             toks = np.zeros(B, np.int32)
             poss = np.zeros(B, np.int32)
+            ctx = np.ones(B, np.int32)
+            left = np.zeros(B, np.int32)
+            eos = np.full(B, -1, np.int32)
             tables = np.zeros((B, self._nb_max), np.int32)
             wtables = slots = None
             if self.streams is not None:
@@ -1223,10 +1259,20 @@ class ServingEngine:
             for i, req in enumerate(reqs):
                 toks[i] = req.pending_token
                 poss[i] = req.context_len
+                ctx[i] = req.context_len + 1
+                left[i] = req.steps_left(cfg.max_len)
+                if req.eos_id is not None:
+                    eos[i] = req.eos_id
                 tables[i] = self._table_row(req.blocks, self._nb_max)
                 if self.streams is not None:
                     wtables[i] = self._table_row(req.wblocks, self._nb_max)
                     slots[i] = req.slot or 0
+            n = int(min(self._chunk, left.max()))
+            # the chunk's first step's context lengths on the trace (what
+            # its steps walked is booked after the fetch, on serving.retire)
+            args.update(steps=n, ctx_tokens=int(ctx.sum()),
+                        ctx_max=int(ctx.max()))
+            build.set(**args)
             # compile-tally delta: a cold decode batch bucket stalls EVERY
             # stream in the batch for the compile wall (serving/obs.py)
             jit = self._decode_jits[B]
@@ -1234,29 +1280,56 @@ class ServingEngine:
             fault.hit("dispatch_error")
         t0 = time.time()
         with telemetry.span("serving.decode.dispatch", _CAT, **args):
-            nxt, _logits = self._dispatch_decode(toks, poss, tables, ctx,
-                                                 wtables, slots)
+            nxt, _logits = self._dispatch_decode(
+                toks, poss, tables, ctx, wtables, slots, left=left, eos=eos,
+                n=n)
         with telemetry.span("serving.decode.fetch", _CAT, **args) as fetch:
-            # the fused step's single device->host sync: the next-token
-            # vector (with the experts' load behind it, where there are
-            # experts)
-            fetched = _unpack_fetch(np.asarray(nxt), (B,), cfg)  # fwlint: disable=device-escape — token egress to clients is the product, B int32s per step
-            fetch.set(**_moe_args(fetched[1]))
+            # the chunk's single device->host sync: its rows of next
+            # tokens (with the experts' load of each step behind them,
+            # where there are experts). Nothing is in flight after it
+            fetched = np.asarray(nxt)  # fwlint: disable=device-escape — token egress to clients is the product, chunk x B int32s per dispatch
+            nxt, load = _unpack_fetch(fetched, (self._chunk, B), cfg,
+                                      (self._chunk,))
+            fetch.set(**_moe_args(load))
         wall = time.time() - t0
         c1, s1 = jit.compile_totals()
         if c1 > c0:
             self.obs.decode_stall(reqs, min(s1 - s0, wall))
-        return fetched
+        return nxt, load, left, n
 
     def _note_decode(self, reqs, fetched):
-        nxt, load = fetched
-        if load is not None:
-            self._note_moe(load, sum(req.context_len < self.config.max_len
-                                     for req in reqs))
-        telemetry.histogram("serving.decode_batch").observe(len(reqs))
-        for i, req in enumerate(reqs):
-            req.context_len += 1
-            self._note_token(req, int(nxt[i]))
+        """Book a fetched chunk, inner step by inner step in order, each
+        as ONE decode step: the step's LIVE lanes (a lane's tokens up to
+        its death: its ``steps_left`` used up or its EOS) in
+        ``serving.decode_batch``, the blocks and states they walked, the
+        experts' load of that step against those lanes. Returns the
+        chunk's sums, for the step's ``serving.retire`` span."""
+        nxt, load, left, n = fetched
+        cfg = self.config
+        noted = {"steps": n, "lane_steps": 0, "live_blocks": 0}
+        for j in range(n):
+            live = [(i, req) for i, req in enumerate(reqs)
+                    if j < left[i] and req.state == DECODING]
+            if not live:
+                break       # every lane met its EOS: the rest ran dead
+            ctx = np.array([req.context_len + 1 for _i, req in live])  # fwlint: disable=device-escape — host integers, nothing of the device's
+            blocks = self._note_paged(ctx)
+            noted["lane_steps"] += len(live)
+            noted["live_blocks"] += blocks
+            if self.streams is not None:
+                for k, v in self._note_hybrid(ctx, blocks).items():
+                    noted[k] = noted.get(k, 0) + v
+            if load is not None:
+                self._note_moe(load[j], int((ctx <= cfg.max_len).sum()))
+            telemetry.histogram("serving.decode_batch").observe(len(live))
+            for i, req in live:
+                req.context_len += 1
+                self._note_token(req, int(nxt[j, i]))
+        self._decode_dispatches += 1
+        self._decode_inner_steps += n
+        telemetry.counter("serving.decode.dispatches").inc()
+        telemetry.counter("serving.decode.inner_steps").inc(n)
+        return noted
 
     def _cow_guard(self, reqs):
         """Give every write slot this step will touch a PRIVATE block.
@@ -1268,7 +1341,7 @@ class ServingEngine:
         contract must hold unconditionally (a future scheduler change
         must fail a unit test, not corrupt a neighbour's cache)."""
         bs = self.config.block_size
-        k = self.spec_k if self._spec else 0
+        k = self.spec_k if self._spec else self._chunk - 1
         for req in reqs:
             first = req.context_len // bs
             last = min(req.context_len + k, self.config.max_len - 1) // bs
@@ -1520,7 +1593,7 @@ class ServingEngine:
             "window_blocks_used": st.pool.used() if st.pool else 0,
             "window_pool_bytes": st.pool.nbytes() if st.pool else 0,
             # the most window blocks any one stream held: never above
-            # ceil((window + block_size) / block_size)
+            # ceil((window + block_size + DECODE_CHUNK - 1) / block_size)
             "window_blocks_a_stream": st.max_blocks_held,
             "window_blocks_freed": st.blocks_freed,
             # model layers that read the full-length pool's K/V
@@ -1597,6 +1670,13 @@ class ServingEngine:
                     "live_share":
                         (self._paged_live_blocks / self._paged_table_slots)
                         if self._paged_table_slots else 0.0,
+                },
+                "decode": {
+                    "dispatches": self._decode_dispatches,
+                    "inner_steps": self._decode_inner_steps,
+                    "steps_per_dispatch":
+                        (self._decode_inner_steps / self._decode_dispatches)
+                        if self._decode_dispatches else 0.0,
                 },
                 # only for a model with window or state layers
                 **({"state": self._state_stats()}

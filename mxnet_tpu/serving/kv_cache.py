@@ -513,7 +513,8 @@ class StreamState:
       but only for the last ``window`` keys: entry ``j`` is the block of
       positions ``j * bs ..``, or 0 once it lies wholly behind the window
       (freed during decode; a long prompt's prefill never gets them). A
-      stream holds at most ``window + block_size`` tokens of them.
+      stream holds at most ``window + block_size`` tokens of them, and
+      the slots a decode chunk writes beyond its first.
 
     Called from the stepping thread only (the scheduler's and the
     engine's, under the engine's lock)."""
@@ -562,14 +563,19 @@ class StreamState:
         req.wblocks = blocks
         self._note_held(req)
 
-    def ensure(self, req, pos):
-        """Before a decode step that writes position ``pos``: return the
-        blocks wholly behind the step's window to the pool, then back the
-        write slot (:class:`KVCacheOOM` if the pool is dry; what was freed
-        stays freed)."""
+    def ensure(self, req, pos, last_pos=None):
+        """Before decode steps that write positions ``pos .. last_pos``
+        (one dispatch; ``last_pos`` defaults to ``pos``): return the
+        blocks wholly behind the FIRST step's window to the pool — the
+        last step's window has slid past blocks the first still reads —,
+        then back the write slots up to the last step's
+        (:class:`KVCacheOOM` if the pool is dry; what was freed stays
+        freed)."""
         if self.pool is None:
             return
         first, last = self._span(pos + 1)
+        if last_pos is not None:
+            last = self._span(last_pos + 1)[1]
         behind = [b for b in req.wblocks[:first] if b]
         if behind:
             self.pool.free(behind)

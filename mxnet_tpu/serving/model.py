@@ -1091,6 +1091,85 @@ def decode(params, tokens, positions, block_tables, context_lens,
     return (nxt[:, 0], logits[:, 0]) + tuple(rest)
 
 
+def decode_chunk(params, tokens, positions, block_tables, context_lens,
+                 steps_left, eos, n, k_pages, v_pages, cfg, chunk, aux=None):
+    """Up to ``chunk`` :func:`decode` steps in ONE program: a loop on the
+    device with a TRACED trip count ``n`` (1 <= n <= chunk; data, so one
+    executable serves every n) whose body is the decode step, each step's
+    greedy token fed to the next. The host dispatches once and fetches once
+    a chunk; ``n = 1`` with every lane's ``steps_left`` 1 is the single
+    step.
+
+    tokens, positions, block_tables, context_lens: as :func:`decode`, for
+                  the chunk's FIRST step
+    steps_left:   (B,) int32 — steps each lane may still take (its length
+                  cap; 0 for a padded row)
+    eos:          (B,) int32 — the lane's end-of-sequence id, -1 for none
+    n:            () int32 — steps this dispatch runs
+    chunk:        static: the rows of the results
+
+    A lane is live while its ``steps_left`` lasts, it has produced no
+    ``eos`` and its next position is below ``max_len``. After its last step
+    it is DEAD for the rest of the chunk, which is a padded row's contract:
+    its table row reads all trash (block 0; slot 0 of the state slots), so
+    it writes nothing a live stream reads, ``valid`` is false so the
+    experts' load does not count it, and its result token is -1.
+
+    Returns ``(next_tokens (chunk, B)`` — row j the tokens of step j, -1
+    for a dead lane and for rows >= n —, ``logits (B, V)`` of each lane's
+    last live step, ``k_pages, v_pages)`` and a fifth result as
+    :func:`decode`: the experts' load PER STEP ``(chunk, L, E)``, or a
+    model with ``layer_kinds``' arrays."""
+    import jax
+    import jax.numpy as jnp
+
+    B = tokens.shape[0]
+    names = ("wk", "wv", "conv", "ssm") if cfg.hybrid else ()
+    state = {"j": jnp.int32(0), "tokens": tokens, "positions": positions,
+             "context_lens": context_lens, "left": steps_left,
+             "out": jnp.full((chunk, B), -1, jnp.int32),
+             "logits": jnp.zeros((B, cfg.vocab_size), jnp.float32),
+             "caches": dict({k: aux[k] for k in names}, k=k_pages,
+                            v=v_pages)}
+    if cfg.num_experts:
+        state["loads"] = jnp.zeros(
+            (chunk, cfg.num_layers, cfg.num_experts), jnp.int32)
+
+    def body(s):
+        alive = s["left"] > 0
+
+        def live(a, dead):
+            return jnp.where(alive.reshape((B,) + (1,) * (a.ndim - 1)), a,
+                             dead)
+
+        caches = s["caches"]
+        step_aux = dict({k: caches[k] for k in names},
+                        wtables=live(aux["wtables"], 0),
+                        slots=live(aux["slots"], 0)) if cfg.hybrid else None
+        nxt, logits, kp, vp, *rest = decode(
+            params, live(s["tokens"], 0), live(s["positions"], 0),
+            live(block_tables, 0), live(s["context_lens"], 1), caches["k"],
+            caches["v"], cfg, step_aux)
+        s = dict(s, caches=dict(rest[0] if cfg.hybrid else {}, k=kp, v=vp),
+                 out=s["out"].at[s["j"]].set(live(nxt, -1)),
+                 logits=live(logits, s["logits"]))
+        if cfg.num_experts:
+            s["loads"] = s["loads"].at[s["j"]].set(rest[0])
+        ended = ((nxt == eos) & (eos >= 0)) \
+            | (s["positions"] + 1 >= cfg.max_len)
+        return dict(s, j=s["j"] + 1, tokens=live(nxt, s["tokens"]),
+                    positions=s["positions"] + alive,
+                    context_lens=s["context_lens"] + alive,
+                    left=jnp.where(alive & ~ended, s["left"] - 1, 0))
+
+    n = jnp.minimum(n, chunk)       # a row past the results is no row
+    s = jax.lax.while_loop(lambda s: s["j"] < n, body, state)
+    caches = s["caches"]
+    fifth = ({k: caches[k] for k in names},) if cfg.hybrid else \
+        ((s["loads"],) if cfg.num_experts else ())
+    return (s["out"], s["logits"], caches["k"], caches["v"]) + fifth
+
+
 def extend(params, tokens, positions, block_tables, context_lens,
            k_pages, v_pages, cfg):
     """The speculative-decoding VERIFY step: :func:`decode` generalized to

@@ -132,6 +132,16 @@ class Request:
             else self.generated
         return self.prompt + gen_cached
 
+    def steps_left(self, max_positions=None):
+        """Decode steps the stream can still take: up to its length cap,
+        and to the position cap (EOS may end it sooner). A decodable
+        stream takes one at least — at the position cap the program routes
+        the write to trash and poisons the token."""
+        left = self.max_new_tokens - len(self.generated)
+        if max_positions is not None:
+            left = min(left, max_positions - self.context_len)
+        return max(left, 1)
+
     @property
     def num_new_tokens(self):
         return len(self.generated)
@@ -172,9 +182,10 @@ class Scheduler:
         self.streams = streams
         self.max_batch = int(max_batch)
         self.prefills_per_step = int(prefills_per_step)
-        # write slots a decoding stream consumes per engine step: 1 for
-        # plain decode, spec_k + 1 for speculative decoding (the draft +
-        # verify window writes positions context_len .. context_len+k)
+        # write slots a decoding stream may consume per engine step: the
+        # steps of a decode chunk, or spec_k + 1 for speculative decoding
+        # (the draft + verify window writes positions context_len ..
+        # context_len+k); a stream nearer its end than that consumes fewer
         self.lookahead = int(lookahead)
         # position cap (cfg.max_len): write slots at/past it route to the
         # trash block in-graph, so headroom past it is never allocated
@@ -229,10 +240,12 @@ class Scheduler:
             # state check also skips members the loop snapshot still holds
             if req.state != DECODING or req.pending_token is None:
                 continue
-            last_pos = req.context_len + self.lookahead - 1
+            # a stream writes no slot past its own last step, and slots
+            # at/past the position cap route to trash in-graph: backing
+            # those with real blocks would waste pool for nothing
+            last_pos = req.context_len + min(self.lookahead,
+                                             req.steps_left()) - 1
             if self.max_positions is not None:
-                # slots at/past the cap route to trash in-graph; backing
-                # them with real blocks would waste pool for nothing
                 last_pos = min(last_pos, self.max_positions - 1)
             while True:
                 try:
@@ -261,13 +274,13 @@ class Scheduler:
     def _back_slot(self, req, last_pos):
         """Back ``req``'s write slots up to ``last_pos`` with blocks, in
         the full pool and then in the window pool (whose blocks behind the
-        window are returned first); :class:`KVCacheOOM` where one is dry
-        (what was booked stays with the request)."""
+        next step's window are returned first); :class:`KVCacheOOM` where
+        one is dry (what was booked stays with the request)."""
         need_idx = last_pos // self.pool.block_size
         while need_idx >= len(req.blocks):
             req.blocks.extend(self.pool.alloc(1))
         if self.streams is not None:
-            self.streams.ensure(req, last_pos)
+            self.streams.ensure(req, req.context_len, last_pos)
 
     def _release(self, req):
         """Everything ``req`` holds goes back: its blocks of the full pool

@@ -783,7 +783,15 @@ METRIC_HELP = {
         "token bookkeeping and retirement after a prefill or a step (span)",
     "serving.prefill_seconds": "per-request prefill dispatch wall",
     "serving.prefill_tokens": "prompt+replay tokens prefilled",
-    "serving.decode_batch": "live streams per fused decode step",
+    "serving.decode_batch":
+        "live streams per fused decode step (one observation an inner "
+        "step of a decode chunk, dead lanes not counted)",
+    "serving.decode.dispatches":
+        "dispatches of the decode program (a chunk of decode steps in a "
+        "loop on the device, one blocking fetch each)",
+    "serving.decode.inner_steps":
+        "decode steps those dispatches ran on the device; / dispatches = "
+        "how often the chunk engages",
     "serving.generated_tokens": "tokens generated across all streams",
     "serving.paged.live_blocks":
         "KV blocks the paged kernel walked: ceil(context / block size) a "

@@ -38,6 +38,18 @@ def _telemetry_flag_as_found():
     (telemetry.enable if was else telemetry.disable)()
 
 
+@pytest.fixture(params=[1, 2, 4], ids="chunk{}".format)
+def chunk(request, monkeypatch):
+    """``serving.engine.DECODE_CHUNK`` at 1 (one decode step a dispatch),
+    2 and 4: an engine built in the test loops up to that many decode
+    steps on the device a dispatch. What such an engine serves, books and
+    leaves in its caches is the same at every value."""
+    from mxnet_tpu.serving import engine
+
+    monkeypatch.setattr(engine, "DECODE_CHUNK", request.param)
+    return request.param
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "faults: fault-injection / robustness tests "

@@ -144,7 +144,8 @@ def test_blocks_per_fetch_follow_the_page(page, dtype, c):
 
 # --------------------------------------------------- the pool stays put
 def _serving_program(chip, name, page, arch="gpt2"):
-    """``model.decode`` (B=32) or ``model.prefill`` (S=512) at the
+    """``model.decode`` (B=32), ``model.decode_chunk`` (the loop the engine
+    dispatches: four rows of results) or ``model.prefill`` (S=512) at the
     benchmark's widths cut to 2 layers, pool donated, compiled for the
     chip: GPT-2 medium in fp32 over blocks of 16, or OLMoE-1B-7B in bf16
     over blocks of 64. Returns (compiled, pool shape, bytes an element)."""
@@ -162,12 +163,20 @@ def _serving_program(chip, name, page, arch="gpt2"):
             M.ModelConfig(50304, 2, 2048, 16, 1024, 4096, norm="rms",
                           pos="rope", qk_norm=True, head_dim=128,
                           num_experts=64, experts_per_tok=8, bias=False),
-            jnp.bfloat16, 64, 129)
+            # the chunk over the benchmark's own 1,025 blocks: a pool of
+            # 129 is small enough for the compiler to stage a layer of it
+            # through fast memory inside the loop, which says nothing of
+            # the real one
+            jnp.bfloat16, 64, 1025 if name == "chunk" else 129)
     params = {k: s(v, dtype) for k, v in M.param_shapes(cfg).items()}
     pool = s((cfg.num_layers, blocks, bs) + page, dtype)
     if name == "decode":
         fn, donate = M.decode, (5, 6)
         args = (s((32,)), s((32,)), s((32, cfg.max_len // bs)), s((32,)))
+    elif name == "chunk":
+        fn, donate = functools.partial(M.decode_chunk, chunk=4), (8, 9)
+        args = (s((32,)), s((32,)), s((32, cfg.max_len // bs)), s((32,)),
+                s((32,)), s((32,)), s(()))
     else:
         fn, donate = M.prefill, (4, 5)
         args = (s((1, 512)), s(()), s((512 // bs,)))
@@ -201,7 +210,7 @@ def _entry_layouts(text, pool_shape):
 
 @pytest.mark.parametrize("arch,heads,head_dim,page", [
     ("gpt2", 16, 64, (8, 128)), ("olmoe", 16, 128, (16, 128))])
-@pytest.mark.parametrize("name", ["decode", "prefill"])
+@pytest.mark.parametrize("name", ["decode", "chunk", "prefill"])
 def test_serving_programs_leave_the_pool_in_place(v5e, name, arch, heads,
                                                   head_dim, page):
     """The guard. A pool whose rows fill the 128 lanes has ONE layout — the
@@ -212,7 +221,9 @@ def test_serving_programs_leave_the_pool_in_place(v5e, name, arch, heads,
     temporaries; the last test shows this check sees them.) Two formats:
     GPT-2 medium's two heads of 64 a row in fp32, and OLMoE's plain
     ``(16, 128)`` rows (r = 1) in bf16, whose programs also hold the
-    experts' grouped-matmul kernels."""
+    experts' grouped-matmul kernels. The decode CHUNK carries the pool
+    through a loop on the device: no copy inside or around the loop
+    either, and temporaries within 0.1 GB of the single step's."""
     from mxnet_tpu.serving.kv_cache import KVBlockPool
 
     assert KVBlockPool.page_shape(heads, head_dim) == page
@@ -229,6 +240,11 @@ def test_serving_programs_leave_the_pool_in_place(v5e, name, arch, heads,
     # of their own (prefill at 512: 4,096 pairs), none the size of the pool
     assert ma.temp_size_in_bytes < pool_bytes / (10 if arch == "gpt2" else 2)
     assert ma.alias_size_in_bytes >= pool_bytes      # donated, taken
+    if name == "chunk":
+        assert " while(" in text                # one program, a loop
+        step = _serving_program(v5e, "decode", page, arch)[0]
+        assert ma.temp_size_in_bytes \
+            - step.memory_analysis().temp_size_in_bytes < 100 << 20
 
 
 def test_pool_guard_sees_the_copies_of_a_half_lane_pool(v5e):
@@ -245,8 +261,9 @@ def test_pool_guard_sees_the_copies_of_a_half_lane_pool(v5e):
 
 
 def _hybrid_program(chip, name):
-    """``model.decode`` (B=64) or ``model.prefill`` (S=1024, or one block:
-    a scatter of ONE block copied the window pool in and out, PR 31) of
+    """``model.decode`` (B=64), ``model.decode_chunk`` (the loop over it,
+    four rows of results) or ``model.prefill`` (S=1024, or one block: a
+    scatter of ONE block copied the window pool in and out, PR 31) of
     Phi-4-mini-flash at published widths, cut to one layer of each kind
     that holds state (mamba, swa, mamba as the memory layer, full, gmu,
     cross), every pool and the state slots donated, compiled for the chip.
@@ -275,6 +292,15 @@ def _hybrid_program(chip, name):
                             dict(zip(aux, arrays), wtables=wt, slots=slots))
         args = (s((64,)), s((64,)), s((64, 64)), s((64,)))
         more, donate = (s((64, 64)), s((64,))), (5, 6, 9, 10, 11, 12)
+    elif name == "chunk":
+        def fn(params, toks, poss, tables, ctx, left, eos, n, kp, vp, wt,
+               slots, *arrays):
+            return M.decode_chunk(
+                params, toks, poss, tables, ctx, left, eos, n, kp, vp, cfg,
+                4, dict(zip(aux, arrays), wtables=wt, slots=slots))
+        args = (s((64,)), s((64,)), s((64, 64)), s((64,)), s((64,)),
+                s((64,)), s(()))
+        more, donate = (s((64, 64)), s((64,))), (8, 9, 12, 13, 14, 15)
     else:
         def fn(params, toks, n, table, kp, vp, wt, slot, *arrays):
             return M.prefill(params, toks, n, table, kp, vp, cfg,
@@ -289,7 +315,8 @@ def _hybrid_program(chip, name):
     return compiled, {k: v.shape for k, v in caches.items()}
 
 
-@pytest.mark.parametrize("name", ["decode", "prefill", "prefill-one-block"])
+@pytest.mark.parametrize("name", ["decode", "chunk", "prefill",
+                                  "prefill-one-block"])
 def test_hybrid_programs_leave_every_cache_in_place(v5e, name):
     """Twenty K/V heads of 64 are ten page rows of 128 lanes: token-major
     they would be padded to sixteen and copied by every kernel call
@@ -303,7 +330,7 @@ def test_hybrid_programs_leave_every_cache_in_place(v5e, name):
     assert KVBlockPool.head_major(10, 128)
     compiled, shapes = _hybrid_program(v5e, name)
     text = compiled.as_text()
-    kernel = "ssm_step" if name == "decode" else "ssm_scan"
+    kernel = "ssm_step" if name in ("decode", "chunk") else "ssm_scan"
     assert len(re.findall(r"%%%s[.\d]* = " % kernel, text)) == 2
     # + three attentions (a 64-token prefill's flash forward is the XLA scan)
     assert text.count("tpu_custom_call") >= (
@@ -323,8 +350,50 @@ def test_hybrid_programs_leave_every_cache_in_place(v5e, name):
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes >= cache_bytes     # donated, all taken
     # the prefill's temporaries are activations of 1,024 tokens, no cache
-    assert ma.temp_size_in_bytes < (64 << 20 if name == "decode"
-                                    else 512 << 20)
+    assert ma.temp_size_in_bytes < (512 << 20 if "prefill" in name
+                                    else 64 << 20)
+    if name == "chunk":     # the caches ride a loop on the device
+        assert " while(" in text
+
+
+def test_the_benchmarks_warm_up_call_warms_the_chunk_program():
+    """``benchmark/drivers/serve.py`` warms a decode bucket with the plain
+    step's call — ``eng._decode_fn`` with seven arguments, four results —
+    and no file of the benchmark may change: that call runs (loads, or
+    compiles) the very executable the engine's chunks dispatch, so that
+    steps of several decode steps a dispatch compile nothing."""
+    import numpy as np
+
+    from mxnet_tpu import compileobs
+    from mxnet_tpu.serving import ServingConfig, ServingEngine
+
+    cfg = ServingConfig(vocab_size=23, num_layers=2, model_dim=32,
+                        num_heads=2, ffn_dim=48, max_len=64, block_size=8,
+                        num_blocks=64, max_batch=4, prefills_per_step=4)
+    eng = ServingEngine(cfg, seed=3)
+    for S in (8, 16):                            # as `warm_engine` does
+        _t, _l, kp, vp = eng._prefill_fn(
+            eng.params, np.zeros((1, S), np.int32), np.int32(1),
+            np.zeros(S // 8, np.int32), eng.pool.k_pages, eng.pool.v_pages)
+        eng.pool.k_pages, eng.pool.v_pages = kp, vp
+    for B in cfg.decode_buckets():
+        ints = np.zeros(B, np.int32)
+        _t, _l, kp, vp = eng._decode_fn(
+            eng.params, ints, ints, np.zeros((B, eng._nb_max), np.int32),
+            np.ones(B, np.int32), eng.pool.k_pages, eng.pool.v_pages)
+        eng.pool.k_pages, eng.pool.v_pages = kp, vp
+
+    def compiles():
+        return {p["program"]: p["compile_count"]
+                for p in compileobs.program_table()
+                if p["program"].startswith("serving.")}
+
+    warm = compiles()
+    out = eng.generate([[1, 2, 3], [4, 5, 6, 7, 8, 9, 10], [11]],
+                       [9, 14, 6])
+    assert [len(o) for o in out] == [9, 14, 6]
+    assert eng.stats()["decode"]["steps_per_dispatch"] > 2
+    assert compiles() == warm
 
 
 def _flash_grad(q, k, v):

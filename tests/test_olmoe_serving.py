@@ -32,8 +32,11 @@ import pytest
 from mxnet_tpu import telemetry
 from mxnet_tpu.ops import moe
 from mxnet_tpu.serving import ServingConfig, ServingEngine
+from mxnet_tpu.serving import engine as E
 from mxnet_tpu.serving import model as M
 from mxnet_tpu.serving.kv_cache import KVBlockPool
+
+from chunk_cases import chunk_equals_single_steps, lane, tables_for
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -90,6 +93,14 @@ def engine(cfg, params=None, **kw):
                          arg_params=params or weights(cfg), **kw)
 
 
+@pytest.fixture
+def one_step(monkeypatch):
+    """Engines built in the test decode one step a dispatch, so that
+    :class:`Capture` sees every step's logits (a chunk hands back its
+    lanes' last). The executable is the chunk's own, run with n = 1."""
+    monkeypatch.setattr(E, "DECODE_CHUNK", 1)
+
+
 class Capture:
     """Record the logits of every prefill and decode an engine runs:
     ``rows[rid] = [(tokens in context, logits (V,)), ...]``."""
@@ -106,8 +117,8 @@ class Capture:
                 (int(length), np.asarray(out[1], np.float32)[0]))
             return out
 
-        def _decode_fn(params, toks, poss, tables, ctx, *rest):
-            out = decode_fn(params, toks, poss, tables, ctx, *rest)
+        def _decode_fn(params, toks, poss, tables, ctx, *rest, **chunk):
+            out = decode_fn(params, toks, poss, tables, ctx, *rest, **chunk)
             logits = np.asarray(out[1], np.float32)
             for i, req in enumerate(now["reqs"]):
                 self.rows[req.rid].append((int(ctx[i]), logits[i]))
@@ -186,7 +197,8 @@ SHAPES = {
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("shape", sorted(SHAPES))
-def test_prefill_and_paged_decode_match_the_reference(shape, dtype):
+def test_prefill_and_paged_decode_match_the_reference(shape, dtype,
+                                                      one_step):
     spec = SHAPES[shape]
     cfg = tiny(dtype, **spec.get("engine", {}))
     eng = engine(cfg)
@@ -215,7 +227,7 @@ def test_prefill_and_paged_decode_match_the_reference(shape, dtype):
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_prefix_cache_hit_matches_the_reference(dtype):
+def test_prefix_cache_hit_matches_the_reference(dtype, one_step):
     cfg = tiny(dtype)
     eng = engine(cfg)
     cap = Capture(eng)
@@ -264,6 +276,75 @@ def test_extend_is_four_decode_steps(dtype):
             np.asarray(a[:, 3:], np.float32), np.asarray(b[:, 3:],
                                                          np.float32),
             atol=1e-5 if dtype == "float32" else 0.1)
+
+
+def test_chunk_program_equals_single_steps(chunk):
+    """The decode chunk over routed experts == single steps of the same
+    executable: tokens, logits, pages, and each step's load — which counts
+    that step's LIVE lanes only (a lane past its length cap or its EOS,
+    and the padded row, are not in it)."""
+    cfg = tiny()
+    scfg = ServingConfig.from_json(cfg)
+    params = weights(cfg)
+    nb = scfg.max_len // scfg.block_size
+    lanes = [lane(5, 6, 9), lane(7, 21, 2), lane(9, 40, 9),
+             lane(2, scfg.max_len - 2, 9), lane(0, 0, 0)]
+    tables = tables_for(lanes, nb, scfg.block_size)
+    rng = np.random.RandomState(5)
+    shape = (2, 65, 8) + KVBlockPool.page_shape(4, 16)
+    caches = {k: jnp.asarray(rng.randn(*shape), jnp.float32) for k in "kv"}
+    step = jax.jit(lambda *a: M.decode_chunk(params, *a, scfg, chunk))
+
+    def program(tok, pos, ctx, left, eos, n, c):
+        rows, logits, kp, vp, loads = step(tok, pos, tables, ctx, left, eos,
+                                           np.int32(n), c["k"], c["v"])
+        return rows, logits, {"k": kp, "v": vp}, loads
+
+    rows, _ = chunk_equals_single_steps(program, scfg.max_len, lanes, caches,
+                                        chunk)
+    lanes[2] = lane(9, 40, 9, eos=int(rows[min(1, chunk - 1), 2]))
+    rows, loads = chunk_equals_single_steps(program, scfg.max_len, lanes,
+                                            caches, chunk)
+    live = (rows >= 0).sum(axis=1)           # lanes alive at each step
+    assert live[0] == 4 and live[-1] == (1 if chunk == 4 else 4)
+    # k experts a live lane and layer, a step: nothing more, nothing less
+    np.testing.assert_array_equal(loads.sum(axis=2), 2 * live[:, None]
+                                  * np.ones((1, 2), np.int64))
+
+
+def test_chunked_serving_drops_nothing_and_counts_live_lanes(
+        chunk, monkeypatch):
+    """The engine over chunks of 1, 2 and 4 serves the tokens of one step
+    a dispatch, and after EVERY step the experts' pairs are exactly k
+    times the tokens the host booked (the cell's `correct` holds a window
+    to it, to the unit) and `serving.decode_batch` has seen the live
+    lane-steps — with streams that end inside a chunk, one by its EOS."""
+    cfg = tiny(num_blocks=9)           # 8 blocks of 8: preempts and replays
+    prompts, n_new = prompts_of([9, 12, 10, 5]), [24, 17, 22, 7]
+    monkeypatch.setattr(E, "DECODE_CHUNK", 1)
+    want = engine(cfg).generate(prompts, n_new)
+    eos = want[1][9]
+    want[1] = want[1][:want[1].index(eos) + 1]
+    monkeypatch.setattr(E, "DECODE_CHUNK", chunk)
+    eng = engine(cfg)
+    batch0 = telemetry.totals("serving.decode_batch")
+    tok0 = telemetry.counter("serving.generated_tokens").value
+    reqs = [eng.submit(p, n, eos_id=eos if i == 1 else None)
+            for i, (p, n) in enumerate(zip(prompts, n_new))]
+    while any(not r.finished() for r in reqs):
+        eng.step()
+        st = eng.stats()
+        assert st["moe"]["pairs"] == 2 * st["moe"]["layer_tokens"] > 0
+        count, total = telemetry.totals("serving.decode_batch")
+        assert count - batch0[0] == st["decode"]["inner_steps"]
+        # a fresh prompt's first token is its prefill's; every other token
+        # (a replay's prefill emits none) came from a live decode lane
+        made = telemetry.counter("serving.generated_tokens").value - tok0
+        assert total - batch0[1] == made - sum(
+            r.first_token_t is not None for r in reqs)
+    assert [list(r.generated) for r in reqs] == want
+    assert eng.scheduler.preempt_count > 0
+    assert st["decode"]["steps_per_dispatch"] > (1 if chunk > 1 else 0)
 
 
 def test_speculative_engine_emits_the_target_stream():
@@ -618,9 +699,10 @@ def test_config_from_json_and_subset_warmup():
     assert compiles() == (p2, d2)
     with pytest.raises(ValueError):
         eng.warmup(prefill_buckets=[12])
-    # a step program hands back four results, experts or not
+    # a step program hands back four results, experts or not: the decode
+    # chunk's rows of tokens with each step's (L, E) load behind them
     out = eng._decode_fn(eng.params, np.zeros(1, np.int32),
                          np.zeros(1, np.int32), np.zeros((1, 16), np.int32),
                          np.ones(1, np.int32), eng.pool.k_pages,
                          eng.pool.v_pages)
-    assert len(out) == 4 and out[0].shape == (1 + 2 * 8,)
+    assert len(out) == 4 and out[0].shape == (E.DECODE_CHUNK * (1 + 2 * 8),)

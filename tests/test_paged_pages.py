@@ -269,13 +269,15 @@ def test_engine_serves_and_reports_the_format(r):
     assert st["kv_page_shape"] == ([H // r, 128] if r > 1 else [H, D])
     shared = list(range(1, 17))                 # two full blocks
     prompts = [shared + tail for tail in ([], [17], [18, 19])]
-    reqs = [eng.submit(p, 6) for p in prompts]
+    # twenty tokens: a stream outlives its first dispatch's decode chunk,
+    # so its blocks are still indexed when the next prompt is admitted
+    reqs = [eng.submit(p, 20) for p in prompts]
     while any(not q.finished() for q in reqs):
         eng.step()
     assert eng.pool.prefix_stats()["hits"] >= 2
     plain = jnp.zeros((cfg.num_layers, 9, 8, H, D), jnp.float32)
     for p, q in zip(prompts, reqs):
-        assert list(q.generated) == _greedy(cfg, eng.params, p, 6,
+        assert list(q.generated) == _greedy(cfg, eng.params, p, 20,
                                             (plain, plain))[0]
     assert eng.pool.used() == 0
 

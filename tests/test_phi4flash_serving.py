@@ -11,8 +11,11 @@ one 128-lane row, head-major blocks), window 32, blocks of 16.
   full forward; the benchmark's own probe and scorer over the same engine;
 * ``ssm_scan`` in chunks = ``ssm_step`` token by token = a plain loop;
 * the three kinds of per-stream state: a stream never holds more than
-  window + one block of tokens in the window pool, freed blocks are used
-  again, admission is atomic over blocks, window blocks and the state slot;
+  window + one block of tokens (and a decode chunk's later write slots) in
+  the window pool, freed blocks are used again, admission is atomic over
+  blocks, window blocks and the state slot;
+* the decode chunk (several steps a dispatch) leaves tokens, window pool,
+  conv tails and states as single steps do, across the window's edge;
 * recompute preemption and a supervisor's replay give bit-identical tokens;
   concurrent = sequential;
 * ``ServingConfig`` refuses prefix cache and speculation for such a model;
@@ -32,9 +35,12 @@ from mxnet_tpu import fault, telemetry
 from mxnet_tpu.ops import ssm
 from mxnet_tpu.serving import (EngineSupervisor, KVCacheOOM, ServingConfig,
                                ServingEngine)
+from mxnet_tpu.serving import engine as E
 from mxnet_tpu.serving import model as M
 from mxnet_tpu.serving.kv_cache import KVBlockPool, StateSlots, StreamState
 from mxnet_tpu.serving.scheduler import FINISHED, Request
+
+from chunk_cases import chunk_equals_single_steps, lane, tables_for
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -283,10 +289,16 @@ def test_scan_in_chunks_is_step_by_step_is_a_plain_loop():
 
 
 # ------------------------------------------------ the three kinds of state
-def test_window_blocks_are_freed_behind_the_window_and_used_again():
+def _window_blocks_a_stream(chunk):
+    """Window + one block of tokens, and the write slots a decode chunk
+    takes beyond its first: 3 blocks of 16 a step, 4 a chunk of 2 or 4."""
+    return -(-(WINDOW + BS + chunk - 1) // BS)
+
+
+def test_window_blocks_are_freed_behind_the_window_and_used_again(chunk):
     cfg = tiny()
     eng = ServingEngine(C.serving_config(cfg), seed=1)
-    per_stream = (WINDOW + BS) // BS                        # 3 blocks
+    per_stream = _window_blocks_a_stream(chunk)
     req = eng.submit(list(range(1, 41)), 150)
     seen, held = set(), 0
     while eng.has_work():
@@ -294,15 +306,18 @@ def test_window_blocks_are_freed_behind_the_window_and_used_again():
         live = [b for b in req.wblocks if b]
         seen.update(live)
         held = max(held, len(live))
-        # what is held covers the window and nothing behind it
+        # what is held covers the window of the dispatch's first step —
+        # which its last step's window has slid past by up to chunk - 1
+        # tokens — and nothing behind it
         if req.state == "decoding":
-            first = max(req.context_len - WINDOW, 0) // BS
-            assert all(b == 0 for b in req.wblocks[:first])
-            assert all(req.wblocks[first:])
+            ctx = req.context_len
+            assert all(b == 0 for b in req.wblocks[
+                :max(ctx - chunk + 1 - WINDOW, 0) // BS])
+            assert all(req.wblocks[max(ctx - WINDOW, 0) // BS:])
     assert req.state == FINISHED and len(req.generated) == 150
-    assert held == per_stream
+    assert (WINDOW + BS) // BS <= held <= per_stream
     st = eng.stats()["state"]
-    assert st["window_blocks_a_stream"] == per_stream
+    assert st["window_blocks_a_stream"] == held
     assert st["window_blocks_freed"] >= (40 + 150 - WINDOW) // BS - 1
     # 190 tokens went through 3 blocks at a time: blocks came back and
     # were handed out again (the free list is LIFO)
@@ -319,12 +334,13 @@ def test_window_blocks_are_freed_behind_the_window_and_used_again():
 def test_a_long_prompt_keeps_only_its_tail_in_the_window_pool():
     cfg = tiny()
     eng = ServingEngine(C.serving_config(cfg), seed=1)
-    req = eng.submit(list(range(1, 101)), 4)
+    req = eng.submit(list(range(1, 101)), 20)
     eng.step()
     # 100 cached tokens: the first decode step (context 101) reads from
-    # position 69, block 4; blocks 0..3 were never booked
+    # position 69, block 4; blocks 0..3 were never booked (block 6 backs
+    # 96..111: the first decode chunk's writes too)
     assert req.wblocks[:4] == [0, 0, 0, 0] and all(req.wblocks[4:])
-    assert len([b for b in req.wblocks if b]) == 3
+    assert len([b for b in req.wblocks if b]) == 3 and not req.finished()
     assert len(req.blocks) == 7                              # the full pool
     _drain(eng)
     assert req.state == FINISHED
@@ -358,7 +374,7 @@ def test_admission_is_atomic_over_the_three_kinds():
     # through the scheduler: two state slots left for three requests
     eng = ServingEngine(C.serving_config(tiny()), seed=1)
     hogged = [eng.state.alloc() for _ in range(2)]
-    reqs = [eng.submit([1 + i, 2, 3], 6) for i in range(3)]
+    reqs = [eng.submit([1 + i, 2, 3], 20) for i in range(3)]
     eng.step()
     assert [r.state for r in reqs] == ["decoding", "decoding", "waiting"]
     assert reqs[2].blocks == [] and reqs[2].wblocks == [] \
@@ -369,10 +385,11 @@ def test_admission_is_atomic_over_the_three_kinds():
     assert eng.state.used() == len(hogged)
 
 
-def test_a_dry_window_pool_preempts_the_youngest_and_replays_it():
+def test_a_dry_window_pool_preempts_the_youngest_and_replays_it(chunk):
     """Two streams outgrow the five window blocks left them: the younger is
     preempted (blocks, window blocks and slot returned), replayed through
-    prefill, and both streams' tokens are what an unpressed engine gives."""
+    prefill, and both streams' tokens are what an unpressed engine gives —
+    also with a decode chunk's longer headroom."""
     prompts = [list(range(1, 30)), list(range(40, 69))]
     oracle = ServingEngine(C.serving_config(tiny()), seed=2).generate(
         prompts, 40)
@@ -386,6 +403,83 @@ def test_a_dry_window_pool_preempts_the_youngest_and_replays_it():
     assert eng.window_pool.used() == len(hogged) and eng.state.used() == 0
 
 
+def test_chunk_program_equals_single_steps(chunk):
+    """The decode chunk over every kind of per-stream state == single
+    steps of the same executable: tokens, logits, full pool, window pool,
+    conv tails and SSM states, bit for bit — with a stream that crosses
+    the window's edge (32) inside the chunk, one that crosses a block
+    boundary, and lanes that die by their length cap, their EOS and at
+    ``max_len``; a dead lane touches the trash block and slot only."""
+    cfg = tiny()
+    scfg = C.serving_config(cfg)
+    eng = ServingEngine(scfg, seed=3)
+    nb = scfg.max_len // BS
+    lanes = [lane(5, 30, 9),                  # contexts 31..34: the edge
+             lane(7, 61, 2),                  # its length cap; 63 -> 64
+             lane(9, 100, 9),                 # its EOS (found below)
+             lane(2, scfg.max_len - 2, 9),    # the position cap
+             lane(0, 0, 0)]                   # a padded row
+    tables = tables_for(lanes, nb, BS)
+    wtables = tables_for(lanes, nb, BS)
+    slots = np.array([1, 2, 3, 4, 0], np.int32)
+    rng = np.random.RandomState(5)
+    caches = {k: jnp.asarray(rng.randn(*a.shape), a.dtype) for k, a in dict(
+        k=eng.pool.k_pages, v=eng.pool.v_pages, wk=eng.window_pool.k_pages,
+        wv=eng.window_pool.v_pages, conv=eng.state.conv,
+        ssm=eng.state.ssm).items()}
+    aux = ("wk", "wv", "conv", "ssm")
+
+    @jax.jit
+    def step(tok, pos, ctx, left, eos, n, c):
+        return M.decode_chunk(
+            eng.params, tok, pos, tables, ctx, left, eos, n, c["k"], c["v"],
+            scfg, chunk, dict({k: c[k] for k in aux}, wtables=wtables,
+                              slots=slots))
+
+    def program(tok, pos, ctx, left, eos, n, c):
+        rows, logits, kp, vp, out = step(tok, pos, ctx, left, eos,
+                                         np.int32(n), c)
+        return rows, logits, dict(out, k=kp, v=vp), None
+
+    rows, _ = chunk_equals_single_steps(program, scfg.max_len, lanes, caches,
+                                        chunk)
+    lanes[2] = lane(9, 100, 9, eos=int(rows[min(1, chunk - 1), 2]))
+    rows, _ = chunk_equals_single_steps(program, scfg.max_len, lanes, caches,
+                                        chunk)
+    assert list((rows >= 0).sum(axis=0)[[0, 1, 3, 4]]) == [
+        chunk, min(2, chunk), min(2, chunk), 0]
+
+
+def test_chunked_serving_is_one_step_a_dispatch(chunk, monkeypatch):
+    """Streams that start before the window's edge and end past it, of
+    lengths that end inside a chunk: the tokens of an engine that takes
+    one step a dispatch, `serving.ssm.stream_steps` and
+    `serving.decode_batch` booked for live lanes only, every cache
+    returned."""
+    rng = np.random.RandomState(8)
+    prompts = [list(rng.randint(0, VOCAB, k)) for k in (5, 27, 40, 31)]
+    n_new = [30, 11, 21, 6]
+    scfg = C.serving_config(tiny())
+    monkeypatch.setattr(E, "DECODE_CHUNK", 1)
+    want = ServingEngine(scfg, seed=3).generate(prompts, n_new)
+    monkeypatch.setattr(E, "DECODE_CHUNK", chunk)
+    eng = ServingEngine(scfg, seed=3)
+    steps0 = telemetry.counter("serving.ssm.stream_steps").value
+    batch0 = telemetry.totals("serving.decode_batch")
+    assert eng.generate(prompts, n_new) == want
+    lane_steps = sum(n - 1 for n in n_new)
+    assert telemetry.counter("serving.ssm.stream_steps").value - steps0 \
+        == telemetry.totals("serving.decode_batch")[1] - batch0[1] \
+        == lane_steps
+    dec = eng.stats()["decode"]
+    assert dec["inner_steps"] == -(-29 // chunk) * chunk - (-29 % chunk)
+    assert dec["dispatches"] == -(-29 // chunk)
+    assert eng.stats()["state"]["window_blocks_a_stream"] \
+        <= _window_blocks_a_stream(chunk)
+    assert (eng.pool.used(), eng.window_pool.used(), eng.state.used()) \
+        == (0, 0, 0)
+
+
 def test_concurrent_is_sequential():
     rng = np.random.RandomState(4)
     prompts = [list(rng.randint(0, VOCAB, k)) for k in (5, 17, 40, 28)]
@@ -395,7 +489,7 @@ def test_concurrent_is_sequential():
     assert together == [alone.generate([p], 30)[0] for p in prompts]
 
 
-def test_supervisor_replay_is_bit_identical():
+def test_supervisor_replay_is_bit_identical(chunk):
     scfg = C.serving_config(tiny())
     prompts = [list(range(1, 30)), [5, 6, 7], list(range(9, 30))]
     oracle = ServingEngine(scfg, seed=6).generate(prompts, 14)
@@ -403,7 +497,8 @@ def test_supervisor_replay_is_bit_identical():
     sup = EngineSupervisor(lambda: ServingEngine(scfg, seed=6),
                            max_restarts=3, backoff_s=0.02)
     stop = threading.Event()
-    with fault.inject("dispatch_error:raise=1,after=9,times=1"):
+    # the second decode dispatch fails: 2 to 5 tokens a stream salvaged
+    with fault.inject("dispatch_error:raise=1,after=4,times=1"):
         reqs = [sup.submit(p, 14) for p in prompts]
         t = threading.Thread(target=sup.run_loop, args=(stop, 0.01),
                              daemon=True)
@@ -432,9 +527,11 @@ def test_serving_config_refuses_what_state_cannot_do_yet():
         C.serving_config(tiny(spec_k=2))
     scfg = C.serving_config(tiny(prefix_cache=None))
     assert scfg.prefix_cache is False and scfg.stateful and scfg.hybrid
-    # sized from max_batch: streams of window + one block, and the trash
+    # sized from max_batch and the decode chunk: streams of window + one
+    # block and the chunk's later write slots, and the trash
     eng = ServingEngine(scfg, seed=1)
-    assert eng.window_pool.num_blocks == 4 * 3 + 1
+    assert eng.window_pool.num_blocks == \
+        4 * _window_blocks_a_stream(E.DECODE_CHUNK) + 1
     assert eng.state.num_slots == 4 + 1
     model = {k: v for k, v in cfg["model"].items() if k != "vocab"}
     with pytest.raises(ValueError, match="'cross' with no 'full'"):

@@ -31,6 +31,9 @@ from mxnet_tpu.serving import (  # noqa: E402
     KVBlockPool, KVCacheOOM, Request, Scheduler, ServingConfig, ServingEngine)
 from mxnet_tpu.serving import model as smodel  # noqa: E402
 
+from chunk_cases import (  # noqa: E402
+    chunk_equals_single_steps, lane, tables_for)
+
 pytestmark = pytest.mark.serving
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -505,7 +508,10 @@ def test_compile_count_flat_after_bucket_warmup(engine_pair):
     eng, ex = engine_pair
     rng = np.random.RandomState(12)
     prompts, n_new = _mixed_workload(8, rng)
-    eng.generate(prompts, n_new)   # warm every bucket this workload uses
+    # every bucket: which batch sizes a workload visits follows where its
+    # streams end inside the decode chunks
+    eng.warmup()
+    eng.generate(prompts, n_new)
     counts0 = {p["program"]: p["compile_count"]
                for p in compileobs.program_table()
                if p["program"].startswith("serving.")}
@@ -524,10 +530,12 @@ def test_engine_blocks_all_freed_after_drain(engine_pair):
         "drained engine must hold zero KV blocks"
 
 
-def test_preemption_invisible_in_outputs():
-    """A pool too small for the offered load forces evictions; preempted
-    requests replay deterministically and every output still equals
-    sequential decoding."""
+def test_preemption_invisible_in_outputs(chunk):
+    """A pool too small for the offered load forces evictions — also with
+    a decode chunk's longer headroom (``chunk`` write slots a stream
+    backed ahead of a dispatch); preempted requests replay
+    deterministically and every output still equals sequential
+    decoding."""
     cfg = _config(num_blocks=13, max_batch=4)   # 12 usable blocks
     eng = ServingEngine(cfg, seed=SEED)
     ex = _decode_executor(smodel.random_params(cfg, seed=SEED))
@@ -544,7 +552,7 @@ def test_preemption_invisible_in_outputs():
     assert eng.pool.used() == 0
 
 
-def test_block_boundary_first_decode_token_not_lost():
+def test_block_boundary_first_decode_token_not_lost(chunk):
     """A prompt that exactly fills its blocks writes its FIRST decode
     token at a fresh block boundary inside the same engine step. The
     engine must back that slot with a real block before the fused decode
@@ -557,8 +565,9 @@ def test_block_boundary_first_decode_token_not_lost():
     for L in (bs, 2 * bs):          # exactly 1 and exactly 2 full blocks
         rng = np.random.RandomState(40 + L)
         prompt = [int(x) for x in rng.randint(0, cfg.vocab_size, L)]
-        req = eng.submit(prompt, 4)
-        eng.step()                   # prefill + same-step first decode
+        req = eng.submit(prompt, 8)
+        eng.step()                   # prefill + same-step first decodes
+        assert req.context_len == L + min(chunk, 7) and not req.finished()
         assert len(req.blocks) == L // bs + 1, \
             "first decode slot must be backed by a real block"
         # the boundary position's K/V must live in the new block's slot 0,
@@ -574,6 +583,91 @@ def test_block_boundary_first_decode_token_not_lost():
         prompt = [int(x) for x in rng.randint(0, cfg.vocab_size, L)]
         got = eng.generate([prompt], [8])[0]
         assert got == _oracle_generate(ex, prompt, 8)
+
+
+# ---------------------------------------------------------------------------
+# the decode chunk: several steps a dispatch, the host synchronous at the fetch
+# ---------------------------------------------------------------------------
+
+
+def test_chunk_program_equals_single_steps(chunk):
+    """One dispatch of ``chunk`` steps == single steps of the same
+    executable, bit for bit in tokens, logits and pool, over a batch whose
+    lanes die inside the chunk by their length cap, by EOS and at
+    ``max_len``, with a write that crosses a block boundary and a padded
+    row; a dead lane's rows are -1 and it writes to trash only."""
+    import jax
+    import jax.numpy as jnp
+
+    cfg = _config()
+    params = smodel.as_device_params(smodel.random_params(cfg, seed=SEED),
+                                     cfg)
+    nb = cfg.max_len // cfg.block_size
+    lanes = [lane(5, 6, 10),                 # writes 6..9: crosses at 8
+             lane(7, 20, 2),                 # its length cap, mid-chunk
+             lane(9, 33, 10),                # its EOS (found below)
+             lane(2, cfg.max_len - 2, 10),   # the position cap
+             lane(0, 0, 0)]                  # a padded row
+    tables = tables_for(lanes, nb, cfg.block_size)
+    rng = np.random.RandomState(5)
+    shape = (cfg.num_layers, cfg.num_blocks, cfg.block_size) \
+        + KVBlockPool.page_shape(cfg.num_heads, cfg.head_dim)
+    caches = {k: jnp.asarray(rng.randn(*shape), jnp.float32) for k in "kv"}
+    step = jax.jit(lambda *a: smodel.decode_chunk(params, *a, cfg, chunk))
+
+    def program(tok, pos, ctx, left, eos, n, c):
+        rows, logits, kp, vp = step(tok, pos, tables, ctx, left, eos,
+                                    np.int32(n), c["k"], c["v"])
+        return rows, logits, {"k": kp, "v": vp}, None
+
+    rows, _ = chunk_equals_single_steps(program, cfg.max_len, lanes, caches,
+                                        chunk)
+    # lane 2 again, its second token now its EOS: it dies having produced it
+    lanes[2] = lane(9, 33, 10, eos=int(rows[min(1, chunk - 1), 2]))
+    rows, _ = chunk_equals_single_steps(program, cfg.max_len, lanes, caches,
+                                        chunk)
+    steps = (rows >= 0).sum(axis=0)
+    assert list(steps[[0, 1, 3, 4]]) == [chunk, min(2, chunk),
+                                         min(2, chunk), 0]
+    assert 1 <= steps[2] <= min(2, chunk) \
+        and rows[steps[2] - 1, 2] == lanes[2]["eos"]
+    assert (rows[steps[2]:, 2] == -1).all()
+
+
+def test_chunked_serving_equals_sequential_decoding(chunk):
+    """The engine over chunks of 1, 2 and 4: every output equals
+    single-stream contiguous-cache decoding — with streams that end inside
+    a chunk by length, by EOS and at ``max_len`` — and after EVERY step
+    ``serving.decode_batch`` has seen exactly the live lane-steps (a dead
+    lane counted would push a roofline share over 100%) and the chunk
+    counter says how often it engaged."""
+    cfg = _config(max_batch=4)
+    eng = ServingEngine(cfg, seed=SEED)
+    ex = _decode_executor(smodel.random_params(cfg, seed=SEED))
+    rng = np.random.RandomState(21)
+    prompts = [[int(x) for x in rng.randint(0, cfg.vocab_size, n)]
+               for n in (3, 7, 12, cfg.max_len - 9, 5, 9)]
+    n_new = [6, 13, 5, 9, 11, 14]            # the fourth ends AT max_len
+    want = [_oracle_generate(ex, p, n) for p, n in zip(prompts, n_new)]
+    eos = want[4][5]                          # the fifth meets it inside
+    want[4] = want[4][:want[4].index(eos) + 1]
+    batch0 = telemetry.totals("serving.decode_batch")
+    reqs = [eng.submit(p, n, eos_id=eos if i == 4 else None)
+            for i, (p, n) in enumerate(zip(prompts, n_new))]
+    while any(not r.finished() for r in reqs):
+        eng.step()
+        count, total = telemetry.totals("serving.decode_batch")
+        dec = eng.stats()["decode"]
+        # every token but a request's first came from a live decode lane
+        assert total - batch0[1] == sum(
+            max(len(r.generated) - 1, 0) for r in reqs)
+        assert count - batch0[0] == dec["inner_steps"]
+        assert dec["dispatches"] <= dec["inner_steps"] \
+            <= chunk * dec["dispatches"]
+    assert [list(r.generated) for r in reqs] == want
+    assert dec["steps_per_dispatch"] == \
+        dec["inner_steps"] / dec["dispatches"] > (1 if chunk > 1 else 0)
+    assert eng.pool.used() == 0
 
 
 def test_step_failure_aborts_not_strands():

@@ -346,22 +346,31 @@ def test_sharing_outputs_bit_identical_to_unshared():
         assert got == _oracle_generate(ex, p, 10)
 
 
-def test_sharing_multiplies_concurrent_streams_at_fixed_pool():
+def test_sharing_multiplies_concurrent_streams_at_fixed_pool(monkeypatch):
     """The capacity headline: at the SAME pool size, shared-prefix
     streams that cannot all fit privately DO all fit with the prefix
     cache on (>= 2x the unshared peak here — above the 1.8x bar)."""
-    prompt = list(range(1, 17))   # 2 blocks of prefix, tail in block 3
+    # 2 blocks of 16 of prefix, tail in block 3. One decode step a
+    # dispatch: a stream lives fifteen steps, so the fourth (a prefill a
+    # step) is admitted while the first decodes
+    from mxnet_tpu.serving import engine
+
+    monkeypatch.setattr(engine, "DECODE_CHUNK", 1)
+    prompt = [1 + i % 20 for i in range(32)]
     peaks = {}
     for share in (False, True):
-        cfg = _config(prefix_cache=share, num_blocks=8, max_batch=8,
-                      prefills_per_step=1)   # 7 usable blocks
+        cfg = _config(prefix_cache=share, block_size=16, num_blocks=8,
+                      max_batch=8, prefills_per_step=1)   # 7 usable blocks
         eng = ServingEngine(cfg, seed=SEED)
-        reqs = [eng.submit(prompt, 8) for _ in range(4)]
-        peak = 0
+        reqs = [eng.submit(prompt, 16) for _ in range(4)]
+        # streams decoding side by side (a stream may end inside the
+        # decode chunk of the step that admits the fourth)
+        batches, run_decode = [0], eng._run_decode
+        eng._run_decode = lambda dec: (batches.append(len(dec)),
+                                       run_decode(dec))[1]
         while any(not r.finished() for r in reqs):
             eng.step()
-            peak = max(peak, len(eng.scheduler.running))
-        peaks[share] = peak
+        peaks[share] = max(batches)
         assert all(r.state == "finished" for r in reqs)
         assert eng.pool.used() == 0
     # unshared: 3 blocks/stream -> 2 streams max in 7 blocks.

@@ -341,16 +341,18 @@ def _run_supervised(sup, reqs, timeout=300.0):
     return t
 
 
-def test_supervisor_restart_replays_bit_identical(telem):
+def test_supervisor_restart_replays_bit_identical(telem, chunk):
     """The acceptance core: a mid-decode dispatch fault aborts the
     engine; the supervisor rebuilds and replays, and every survivor's
-    tokens equal a fault-free run's exactly (greedy replay contract)."""
+    tokens equal a fault-free run's exactly (greedy replay contract) —
+    whatever the decode chunk: the fault is the second decode dispatch's,
+    with 2 to 5 tokens a stream salvaged."""
     prompts = [[1, 2, 3, 4], [5, 6, 7], [8, 9]]
-    n_new = 6
+    n_new = 12
     oracle = ServingEngine(_config(), seed=SEED).generate(prompts, n_new)
 
     sup = _supervised(max_restarts=3, backoff_s=0.02)
-    with fault.inject("dispatch_error:raise=1,after=2,times=1"):
+    with fault.inject("dispatch_error:raise=1,after=4,times=1"):
         reqs = [sup.submit(p, n_new) for p in prompts]
         _run_supervised(sup, reqs)
     assert sup.restarts == 1 and sup.failed is None

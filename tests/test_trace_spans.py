@@ -16,12 +16,14 @@ import threading
 import time
 import types
 
+import jax
 import numpy as np
 import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import profiler, telemetry
 from mxnet_tpu.serving import ServingConfig, ServingEngine
+from mxnet_tpu.serving.engine import DECODE_CHUNK
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -35,6 +37,11 @@ SECTIONS = ["serving.step.lock", "serving.schedule",
             "serving.decode.dispatch", "serving.decode.fetch",
             "serving.retire"]
 SPAN_NAMES = ["serving.loop.idle", "serving.step"] + SECTIONS
+#: the fixture's requests: ten tokens each, one from the prefill and nine
+#: decode steps, in dispatches of DECODE_CHUNK steps and the rest
+N_NEW = 10
+CHUNKS = [min(DECODE_CHUNK, N_NEW - 1 - s)
+          for s in range(0, N_NEW - 1, DECODE_CHUNK)]
 READERS = {"decode_idle_host_share": "host",
            "decode_idle_unnamed_share": "unnamed",
            "prefill_idle_host_share": "host",
@@ -69,7 +76,7 @@ def host_events(path):
 @pytest.fixture(scope="module")
 def serving_trace(tmp_path_factory):
     """A few engine steps under the profiler: three prompts of different
-    lengths, six tokens each, submitted while the loop idles."""
+    lengths, ten tokens each, submitted while the loop idles."""
     cfg = ServingConfig(vocab_size=23, num_layers=2, model_dim=32,
                         num_heads=2, ffn_dim=48, max_len=64, block_size=8,
                         num_blocks=64, max_batch=8, prefills_per_step=4)
@@ -81,10 +88,13 @@ def serving_trace(tmp_path_factory):
     window = trace_window(tmp_path_factory.mktemp("xplane"))
     window.start()
     try:
+        # a device op before the loop idles, whenever the warm-up's last
+        # program ended: the idle gap lies inside the traced device window
+        jax.block_until_ready(jax.numpy.zeros(8) + 1)
         driver.start()
         time.sleep(0.12)      # an empty queue first: serving.loop.idle
         with eng._lock:       # all three before the driver's next step
-            reqs = [eng.submit(list(range(1, 6 + i)), 6,
+            reqs = [eng.submit(list(range(1, 6 + i)), N_NEW,
                                request_id="r%d" % i) for i in range(3)]
         for r in reqs:
             assert r.done_event.wait(60)
@@ -119,9 +129,9 @@ def test_sections_nest_in_the_step_and_do_not_overlap(serving_trace):
     evs = serving_trace["driver"]
     steps = [(s, s + d) for n, s, d, _ in evs if n == "serving.step"]
     sections = sorted((s, s + d, n) for n, s, d, _ in evs if n in SECTIONS)
-    # six tokens a request: one from the prefill, five decode steps, each
-    # step with its lock, schedule, three of decode and retire at least
-    assert len(steps) >= 5 and len(sections) >= 6 * len(steps)
+    # a step a dispatch of the decode program, each with its lock,
+    # schedule, three of decode and retire at least
+    assert len(steps) >= len(CHUNKS) and len(sections) >= 6 * len(steps)
     for s0, s1, name in sections:
         assert any(a - slack <= s0 and s1 <= b + slack for a, b in steps), \
             "%s outside every serving.step" % name
@@ -147,42 +157,72 @@ def test_arguments_are_readable_from_the_events_stats(serving_trace):
         dec = by_name["serving.decode." + part]
         assert {st["batch"] for st in dec} == {3} \
             and {st["bucket"] for st in dec} == {4}
-        # three streams of 5+6+7 tokens one token further each step, the
-        # padding lane's single token included
+        # one dispatch a chunk; the contexts are its first step's: three
+        # streams of 5+6+7 tokens one token further each step, the padding
+        # lane's single token included
+        assert [st["steps"] for st in dec] == CHUNKS
         first = dec[0]
         assert first["ctx_tokens"] == 5 + 6 + 7 + 3 + 1 \
             and first["ctx_max"] == 8
         assert [st["ctx_tokens"] for st in dec] == [
-            first["ctx_tokens"] + 3 * i for i in range(len(dec))]
+            first["ctx_tokens"] + 3 * sum(CHUNKS[:i])
+            for i in range(len(dec))]
     sched = by_name["serving.schedule"][0]
     assert sched["waiting"] == 3 and sched["running"] == 0
     retired = [st["finished"] for st in by_name["serving.retire"]
                if "finished" in st]
     assert sum(retired) == 3 and retired[-1] == 3
+    # what a chunk's steps walked is booked after its fetch, on the retire
+    booked = [st for st in by_name["serving.retire"] if "steps" in st]
+    assert [st["steps"] for st in booked] == CHUNKS
+    assert [st["lane_steps"] for st in booked] == [3 * n for n in CHUNKS]
     assert sorted(st["request_id"] for st in by_name["serving.retire"]
                   if "request_id" in st) == ["r0", "r1", "r2"]
-    assert all(len(r.generated) == 6 for r in serving_trace["requests"])
+    assert all(len(r.generated) == N_NEW
+               for r in serving_trace["requests"])
+
+
+def test_no_dispatch_before_the_previous_fetch_returned(serving_trace):
+    """The host is synchronous at every fetch: a decode program is
+    dispatched only after the previous one's tokens were fetched (nothing
+    is in flight while the host retires, frees and schedules), and the
+    chunk counter says how many steps a dispatch ran."""
+    evs = sorted((s, s + d, n) for n, s, d, _ in serving_trace["driver"]
+                 if n in ("serving.decode.dispatch", "serving.decode.fetch"))
+    assert [n for _s, _e, n in evs] == [
+        "serving.decode.dispatch", "serving.decode.fetch"] * len(CHUNKS)
+    for (_s0, e0, _n0), (s1, _e1, _n1) in zip(evs, evs[1:]):
+        assert e0 <= s1
+    dec = serving_trace["stats"]["decode"]
+    assert dec == {"dispatches": len(CHUNKS), "inner_steps": N_NEW - 1,
+                   "steps_per_dispatch": (N_NEW - 1) / len(CHUNKS)}
+    for name in ("serving.decode.dispatches", "serving.decode.inner_steps"):
+        assert name in telemetry.METRIC_HELP
+        assert "`%s`" % name in open(
+            os.path.join(ROOT, "docs", "observability.md")).read()
 
 
 def test_paged_counters_count_the_blocks_the_kernel_walks(serving_trace):
     """`serving.paged.live_blocks` / `.table_slots` by hand: prompts of 5,
-    6 and 7 tokens, five decode steps each, blocks of 8, tables of 8."""
+    6 and 7 tokens, nine decode steps each, blocks of 8, tables of 8 —
+    booked a step, whatever the chunks the steps ran in."""
     cfg = serving_trace["config"]
     nb_max = cfg.max_len // cfg.block_size
     steps = [[len(r.prompt) + 1 + s for r in serving_trace["requests"]]
-             for s in range(5)]
-    assert steps[0] == [6, 7, 8] and steps[-1] == [10, 11, 12]
+             for s in range(N_NEW - 1)]
+    assert steps[0] == [6, 7, 8] and steps[-1] == [14, 15, 16]
     by_step = [sum(-(-ctx // cfg.block_size) for ctx in step)
                for step in steps]
-    assert by_step == [3, 4, 5, 6, 6]
+    assert by_step == [3, 4, 5, 6, 6, 6, 6, 6, 6]
     paged = serving_trace["stats"]["paged"]
-    assert paged["live_blocks"] == sum(by_step) == 24
-    assert paged["table_slots"] == 5 * 3 * nb_max == 120
-    assert paged["live_share"] == pytest.approx(24 / 120)
-    for part in ("build", "dispatch", "fetch"):
-        assert [st["live_blocks"] for name, _s, _d, st
-                in serving_trace["driver"]
-                if name == "serving.decode." + part] == by_step
+    assert paged["live_blocks"] == sum(by_step) == 48
+    assert paged["table_slots"] == 9 * 3 * nb_max == 216
+    assert paged["live_share"] == pytest.approx(48 / 216)
+    ends = np.cumsum(CHUNKS)
+    assert [st["live_blocks"] for name, _s, _d, st
+            in serving_trace["driver"]
+            if name == "serving.retire" and "live_blocks" in st] == [
+        sum(by_step[e - n:e]) for n, e in zip(CHUNKS, ends)]
     for name in ("serving.paged.live_blocks", "serving.paged.table_slots"):
         assert name in telemetry.METRIC_HELP
         assert "`%s`" % name in open(
@@ -301,11 +341,11 @@ def test_span_with_everything_off_is_only_an_annotation():
     telemetry.disable()
     assert not profiler.is_running()
     before = set(telemetry.dump()["histograms"])
-    # the best of five batches: the gate's other workers take the cores
+    # the best of twenty batches: the gate's other workers take the cores
     # away for milliseconds at a time, which a single mean would book to
-    # the span
-    n, per_span = 4000, float("inf")
-    for _ in range(5):
+    # the span (five batches read 6.3 us once under the gate, PR 32)
+    n, per_span = 2000, float("inf")
+    for _ in range(20):
         t0 = time.perf_counter()
         for _ in range(n):
             with telemetry.span("spans.off", "test", batch=3):
