@@ -37,7 +37,8 @@ from .registry import Param, fp32_precision, register
 
 __all__ = ["flash_attention", "attention_reference", "paged_attention",
            "paged_attention_reference", "paged_attention_multi",
-           "paged_attention_multi_reference"]
+           "paged_attention_multi_reference", "latent_paged",
+           "latent_paged_reference"]
 
 _NEG_INF = -1e30
 
@@ -87,9 +88,10 @@ def _block_update(q, k_blk, v_blk, m, l, acc, sm_scale, mask=None,
 
 def _scan_forward(q, k, v, causal, sm_scale, block_k, window=None):
     """Pure-XLA flash forward: lax.scan over KV blocks. Returns (out, lse) f32.
-    ``window``: key j is visible to query i only if ``i - j < window``."""
+    ``window``: key j is visible to query i only if ``i - j < window``.
+    The values may be of another width than the keys."""
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[3]
     block_k = min(block_k, sk)
     n_blk = -(-sk // block_k)
     pad = n_blk * block_k - sk
@@ -102,7 +104,7 @@ def _scan_forward(q, k, v, causal, sm_scale, block_k, window=None):
         vf = jnp.pad(vf, ((0, 0), (0, 0), (0, pad), (0, 0)))
     # (n_blk, B, H, block_k, D) scan-major layout
     kb = jnp.moveaxis(kf.reshape(b, h, n_blk, block_k, d), 2, 0)
-    vb = jnp.moveaxis(vf.reshape(b, h, n_blk, block_k, d), 2, 0)
+    vb = jnp.moveaxis(vf.reshape(b, h, n_blk, block_k, dv), 2, 0)
     qi = jnp.arange(sq)
 
     def step(carry, xs):
@@ -122,7 +124,7 @@ def _scan_forward(q, k, v, causal, sm_scale, block_k, window=None):
 
     m0 = jnp.full((b, h, sq), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, h, sq), jnp.float32)
-    acc0 = jnp.zeros((b, h, sq, d), jnp.float32)
+    acc0 = jnp.zeros((b, h, sq, dv), jnp.float32)
     (m, l, acc), _ = lax.scan(step, (m0, l0, acc0), (kb, vb, jnp.arange(n_blk)))
     l = jnp.maximum(l, 1e-30)
     out = acc / l[..., None]
@@ -144,13 +146,14 @@ def _pallas_forward(q, k, v, causal, sm_scale, block_q=512, block_k=1024,
     VMEM scratch carried across KV steps — per-core VMEM is O(block_q·d +
     block_k·d), independent of sequence length. Output is written on the last
     KV step. Returns (out, lse) float32, identical residuals to
-    ``_scan_forward``.
+    ``_scan_forward``. The values may be of another width ``dv`` than the
+    keys (latent attention's expanded heads: keys 192, values 128).
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[3]
     if window is not None:
         block_k = min(block_k, block_q)
     block_q = min(block_q, sq)
@@ -166,7 +169,7 @@ def _pallas_forward(q, k, v, causal, sm_scale, block_q=512, block_k=1024,
         def _init():
             m_ref[:] = jnp.full((block_q,), _NEG_INF, jnp.float32)
             l_ref[:] = jnp.zeros((block_q,), jnp.float32)
-            acc_ref[:] = jnp.zeros((block_q, d), jnp.float32)
+            acc_ref[:] = jnp.zeros((block_q, dv), jnp.float32)
 
         # causal: skip blocks strictly above the diagonal
         first_q_pos = qi_blk * block_q + block_q - 1  # last row of the q block
@@ -210,7 +213,7 @@ def _pallas_forward(q, k, v, causal, sm_scale, block_q=512, block_k=1024,
     bh = b * h
     qr = q.reshape(bh, sq, d)
     kr = k.reshape(bh, sk, d)
-    vr = v.reshape(bh, sk, d)
+    vr = v.reshape(bh, sk, dv)
     pad_q = n_q * block_q - sq
     pad_k = n_k * block_k - sk
     if pad_q:
@@ -225,24 +228,24 @@ def _pallas_forward(q, k, v, causal, sm_scale, block_q=512, block_k=1024,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda i, j, kk: (i, kk, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j, kk: (i, kk, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda i, j, kk: (i, kk, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda i, j, kk: (i, j, 0)),
             pl.BlockSpec((1, 1, block_q), lambda i, j, kk: (i, 0, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, n_q * block_q, d), jnp.float32),
+            jax.ShapeDtypeStruct((bh, n_q * block_q, dv), jnp.float32),
             jax.ShapeDtypeStruct((bh, 1, n_q * block_q), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         interpret=interpret,
     )(qr, kr, vr)
-    out = out[:, :sq].reshape(b, h, sq, d)
+    out = out[:, :sq].reshape(b, h, sq, dv)
     lse = lse[:, 0, :sq].reshape(b, h, sq)
     return out, lse
 
@@ -1127,6 +1130,232 @@ def paged_attention_multi(q, k_pages, v_pages, block_tables, context_lens,
         )
     return paged_attention_multi_reference(q, k_pages, v_pages, block_tables,
                                            context_lens, **kw)
+
+
+# ---------------------------------------------------------------------------
+# latent attention's decode step (DeepSeek-V3's MLA, absorbed form)
+# ---------------------------------------------------------------------------
+def _latent_layer(c_pages, r_pages, layer):
+    """The latent pool is 5-D ``(L, N, 1, bs, W)`` with a static ``layer``,
+    or one layer's 4-D pages."""
+    if c_pages.ndim == 4:
+        return c_pages[None], r_pages[None], 0
+    return c_pages, r_pages, int(layer)
+
+
+def latent_paged_reference(qc, qr, c_pages, r_pages, block_tables,
+                           context_lens, sm_scale, layer=None):
+    """The XLA lowering and the oracle of :func:`latent_paged`: gather each
+    stream's blocks, then plain masked attention of the H query rows over
+    the one cached row a token. float32 softmax."""
+    c_pages, r_pages, layer = _latent_layer(c_pages, r_pages, layer)
+    b, nb = block_tables.shape
+    bs = c_pages.shape[3]
+    prec = fp32_precision(qc.dtype)
+
+    def rows(pages):
+        t = jnp.take(pages[layer], block_tables, axis=0)   # (B, nb, 1, bs, W)
+        return t.reshape(b, nb * bs, t.shape[-1])
+
+    c, r = rows(c_pages), rows(r_pages)
+    s = (jnp.einsum("bhc,btc->bht", qc, c, precision=prec,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bhr,btr->bht", qr, r, precision=prec,
+                      preferred_element_type=jnp.float32)) * sm_scale
+    seen = jnp.arange(nb * bs)[None, None] < context_lens[:, None, None]
+    s = jnp.where(seen, s, _NEG_INF)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.where(seen, p, 0.0)
+    out = jnp.einsum("bht,btc->bhc", p.astype(c.dtype), c, precision=prec,
+                     preferred_element_type=jnp.float32)
+    out = out / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    return out.astype(qc.dtype)
+
+
+#: cached rows one fetch of the latent kernel moves and ONE pass of its
+#: arithmetic takes. A pass costs about a microsecond whatever it covers (two
+#: matmuls, two lane reductions and the state's update, each waiting for the
+#: last): at a block a pass the kernel ran at 9-32% of the HBM peak, the
+#: larger the block the better (PERF.md section 6, PR 33); the rows past a
+#: stream's last block are computed and masked
+_LATENT_FETCH_ROWS = 512
+
+
+def _latent_pallas(qc, qr, c_pages, r_pages, block_tables, context_lens,
+                   sm_scale, layer=None, interpret=False):
+    """Pallas TPU kernel of :func:`latent_paged`; the custom call is named
+    ``latent_paged``.
+
+    Grid ``(B,)``, one step a stream, and :func:`_paged_pallas_multi`'s
+    walk: the pools stay in HBM, the block table and the context lengths
+    ride in SMEM, a stream's LIVE blocks only are fetched, ``c`` blocks a
+    fetch into one of two VMEM slots, the next fetch (this stream's, or
+    the next stream's first) started before the current one is waited
+    for. What differs is the arithmetic: all H query heads read the SAME
+    cached row, so a FETCH (its ``c`` blocks side by side, ``c x bs`` rows)
+    is two matmuls on the MXU in the pages' type with float32 accumulation
+    — scores ``q_c (H, C) . c^T + q_r (H, R) . r^T`` -> ``(H, c bs)``, then
+    ``P (H, c bs) . c (c bs, C)``: the value is the latent itself — around
+    an online softmax whose state is ``(H,)`` and ``(H, C)`` float32. The
+    places of a fetch that a stream's last blocks do not fill keep an
+    earlier fetch's rows (zeros before the first): finite, and masked."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    c_pages, r_pages, layer = _latent_layer(c_pages, r_pages, layer)
+    b, h, wc = qc.shape
+    wr = qr.shape[2]
+    nb = block_tables.shape[1]
+    bs = c_pages.shape[3]
+    c = int(max(1, min(nb, _LATENT_FETCH_ROWS // bs)))
+    rows = c * bs
+    nt = (((1,), (1,)), ((), ()))       # contract both on their lanes
+
+    def kernel(bt_ref, cl_ref, qc_ref, qr_ref, c_hbm, r_hbm, o_ref,
+               c_buf, r_buf, sems, slot_ref, m_ref, l_ref, acc_ref):
+        i = pl.program_id(0)
+
+        def live_blocks(seq):
+            # one block for an empty stream; never past the table
+            return jnp.clip(pl.cdiv(cl_ref[seq], bs), 1, nb)
+
+        def copies(seq, blk, slot, j):
+            page = bt_ref[seq, blk]
+            return [pltpu.make_async_copy(hbm.at[layer, page, 0],
+                                          buf.at[slot, j], sems.at[n, slot, j])
+                    for n, (hbm, buf) in enumerate(((c_hbm, c_buf),
+                                                    (r_hbm, r_buf)))]
+
+        def start_fetch(seq, it, slot, n_blk):
+            def start(j, _):
+                for cp in copies(seq, it * c + j, slot, j):
+                    cp.start()
+                return 0
+
+            jax.lax.fori_loop(0, jnp.minimum(c, n_blk - it * c), start, 0)
+
+        @pl.when(i == 0)
+        def _first():
+            slot_ref[0] = 0
+            c_buf[...] = jnp.zeros(c_buf.shape, c_buf.dtype)
+            r_buf[...] = jnp.zeros(r_buf.shape, r_buf.dtype)
+            start_fetch(0, 0, 0, live_blocks(0))
+
+        m_ref[:] = jnp.full((h,), _NEG_INF, jnp.float32)
+        l_ref[:] = jnp.zeros((h,), jnp.float32)
+        acc_ref[:] = jnp.zeros((h, wc), jnp.float32)
+        ctx = cl_ref[i]
+        n_blk = live_blocks(i)
+        n_fetch = pl.cdiv(n_blk, c)
+        slot0 = slot_ref[0]
+        nxt = jnp.minimum(i + 1, b - 1)
+        nxt_blk = live_blocks(nxt)
+
+        def fetch_step(it, _):
+            slot = (slot0 + it) % 2
+            last = it + 1 == n_fetch
+
+            @pl.when(jnp.logical_not(last) | (i + 1 < b))
+            def _prefetch():
+                start_fetch(jnp.where(last, nxt, i),
+                            jnp.where(last, 0, it + 1), 1 - slot,
+                            jnp.where(last, nxt_blk, n_blk))
+
+            def wait(j, _):
+                for cp in copies(i, it * c + j, slot, j):
+                    cp.wait()
+                return 0
+
+            jax.lax.fori_loop(0, jnp.minimum(c, n_blk - it * c), wait, 0)
+            cv = c_buf[slot].reshape(rows, wc)
+            rv = r_buf[slot].reshape(rows, wr)
+            s = (jax.lax.dot_general(
+                qc_ref[0], cv, nt, preferred_element_type=jnp.float32)
+                + jax.lax.dot_general(
+                    qr_ref[0], rv, nt, preferred_element_type=jnp.float32)
+            ) * sm_scale                                 # (H, c bs)
+            pos = it * rows + jax.lax.broadcasted_iota(
+                jnp.int32, (h, rows), 1)
+            s = jnp.where(pos < ctx, s, _NEG_INF)
+            m = m_ref[:]
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            p = jnp.exp(s - m_new[:, None])
+            scale = jnp.exp(m - m_new)
+            m_ref[:] = m_new
+            l_ref[:] = l_ref[:] * scale + jnp.sum(p, axis=-1)
+            acc_ref[:] = acc_ref[:] * scale[:, None] + jnp.dot(
+                p.astype(cv.dtype), cv, preferred_element_type=jnp.float32)
+            return 0
+
+        jax.lax.fori_loop(0, n_fetch, fetch_step, 0)
+        slot_ref[0] = (slot0 + n_fetch) % 2
+        out = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)[:, None]
+        # a stream with no valid position: the oracle's zero
+        o_ref[0] = jnp.where(ctx > 0, out, 0.0).astype(o_ref.dtype)
+
+    def q_spec(w):
+        return pl.BlockSpec((1, h, w), lambda i, bt, cl: (i, 0, 0))
+
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[q_spec(wc), q_spec(wr), pool_spec, pool_spec],
+        out_specs=q_spec(wc),
+        scratch_shapes=[pltpu.VMEM((2, c, bs, wc), c_pages.dtype),
+                        pltpu.VMEM((2, c, bs, wr), r_pages.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2, c)),
+                        pltpu.SMEM((1,), jnp.int32),
+                        pltpu.VMEM((h,), jnp.float32),
+                        pltpu.VMEM((h,), jnp.float32),
+                        pltpu.VMEM((h, wc), jnp.float32)],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, wc), qc.dtype),
+        # the scratch carries one stream's prefetch into the next step
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="latent_paged",
+    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
+      qc, qr, c_pages, r_pages)
+
+
+def _latent_shapes_ok(qc, qr, c_pages):
+    """Whole tiles for the MXU and the copies: full lanes, a block of whole
+    sublane tiles, head-major pages of one row a token."""
+    sublanes = 32 // jnp.dtype(c_pages.dtype).itemsize
+    return (qc.shape[-1] % 128 == 0 and qr.shape[-1] % 128 == 0
+            and qc.shape[1] % 8 == 0 and c_pages.shape[-3] == 1
+            and c_pages.shape[-2] % max(sublanes, 8) == 0)
+
+
+def latent_paged(qc, qr, c_pages, r_pages, block_tables, context_lens,
+                 sm_scale, layer=None):
+    """One decode step of multi-head LATENT attention (MLA), absorbed: all
+    H query heads of a stream read the same cached row a token.
+
+    qc:  (B, H, C) — a head's query through ``W_uk``: against the latent
+    qr:  (B, H, R) — its rotary part, zero past the rotary lanes
+    c_pages / r_pages: the pool's ``k_pages`` / ``v_pages`` in the latent
+         format, ``(L, N, 1, bs, C)`` / ``(L, N, 1, bs, R)`` with a static
+         ``layer`` (or one layer's 4-D pages): the normalised latent and
+         the rotated key, R its 128-lane row
+    block_tables (B, nb), context_lens (B,)
+
+    Returns ``softmax((qc . c + qr . r) * sm_scale) c``: (B, H, C), to go
+    through ``W_uv``. The Pallas kernel on the TPU (``latent_paged`` on a
+    trace), the gather reference elsewhere; serving-only (no vjp)."""
+    kw = {"sm_scale": float(sm_scale), "layer": layer}
+    if _latent_shapes_ok(qc, qr, c_pages):
+        return lax.platform_dependent(
+            qc, qr, c_pages, r_pages, block_tables, context_lens,
+            tpu=functools.partial(_latent_pallas, **kw),
+            default=functools.partial(latent_paged_reference, **kw))
+    return latent_paged_reference(qc, qr, c_pages, r_pages, block_tables,
+                                  context_lens, **kw)
 
 
 @register(

@@ -103,7 +103,7 @@ class ServingConfig(_model.ModelConfig):
         # (refcounted, copy-on-write) instead of re-caching them
         self.prefix_cache = bool(
             prefix_cache if prefix_cache is not None
-            else not self.stateful
+            else not (self.stateful or self.latent)
             and env_bool("MXNET_SERVING_PREFIX_CACHE", True))
         # speculative decoding (docs/serving.md §speculative-decoding):
         # spec_k > 0 turns it on — a draft LM proposes spec_k tokens per
@@ -142,6 +142,14 @@ class ServingConfig(_model.ModelConfig):
                 "layers does not have yet: the recurrent state (and the "
                 "window's blocks) saved at block boundaries, to start a "
                 "stream from a shared prefix")
+        if self.latent and (self.prefix_cache or self.spec_k):
+            raise ValueError(
+                "%s needs what a model with 'mla' layers does not have yet: "
+                "an extend step over cached latents (several query lanes a "
+                "stream against the latent pool, for %s)"
+                % (("prefix_cache", "a prompt that starts from shared "
+                    "blocks") if self.prefix_cache
+                   else ("spec_k > 0", "the verify pass")))
         if self.stateful and self.spec_k:
             raise ValueError(
                 "spec_k > 0 needs what a model with state or window layers "
@@ -204,15 +212,19 @@ def _unpack_fetch(fetched, shape, cfg, steps=()):
     vector of :func:`_pack_fetch`; a decode chunk's load is one (L, E) a
     step: ``steps = (chunk,)``."""
     n = int(np.prod(shape))
-    load = (fetched[n:].reshape(steps + (cfg.num_layers, cfg.num_experts))
+    load = (fetched[n:].reshape(steps + (cfg.expert_layers,
+                                         cfg.num_experts))
             if cfg.num_experts else None)
     return fetched[:n].reshape(shape), load
 
 
-def _moe_args(load):
-    """The fetch spans' arguments for a step with experts."""
+def _moe_args(load, cfg):
+    """The fetch spans' arguments for a step with experts: what THIS
+    engine computed (the experts it holds)."""
     if load is None:
         return {}
+    first, count = cfg.experts_here
+    load = load[..., first:first + count]
     return {"pairs": int(load.sum()),
             "experts_touched": int((load > 0).sum())}
 
@@ -305,8 +317,12 @@ class ServingEngine:
         self._token_window = []   # one timestamp per token, for tokens/sec
         # routed experts: per-layer, per-expert tokens since the engine
         # started (the step's own (L, E) count rides in the token fetch)
-        self._moe_load = np.zeros((cfg.num_layers, cfg.num_experts),
+        self._moe_load = np.zeros((cfg.expert_layers, cfg.experts_here[1]),
                                   np.int64)
+        self._moe_routed = 0    # every choice the router made, here or not
+        # latent attention: what the decode kernel read
+        self._latent = {"ctx_tokens": 0, "lane_steps": 0, "live_blocks": 0,
+                        "prefill_tokens": 0}
         self._moe_layer_steps = 0
         self._moe_layer_tokens = 0
         self._moe_touched = 0
@@ -360,10 +376,11 @@ class ServingEngine:
             # every model, which is how the benchmark's readers find them
             def _prefill(params, tokens, length, block_table,  # noqa: F811
                          k_pages, v_pages, wtable, slot, *arrays):
-                tok, logits, kp, vp, out = _model.prefill(
+                tok, logits, kp, vp, out, *load = _model.prefill(
                     params, tokens, length, block_table, k_pages, v_pages,
                     cfg, dict(zip(_AUX, arrays), wtable=wtable, slot=slot))
-                return (tok, logits, kp, vp) + tuple(out[k] for k in _AUX)
+                return _pack_fetch(tok, logits, kp, vp, *load) + tuple(
+                    out[k] for k in _AUX)
             return _prefill
 
         # the name `_decode` stays whatever runs inside: the trace's ops
@@ -381,8 +398,8 @@ class ServingEngine:
                     params, tokens, positions, block_tables, context_lens,
                     steps_left, eos, n, k_pages, v_pages, cfg, chunk, aux)
                 if cfg.hybrid:
-                    return (tok, logits, kp, vp) + tuple(
-                        rest[0][k] for k in _AUX)
+                    return _pack_fetch(tok, logits, kp, vp, *rest[1:]) \
+                        + tuple(rest[0][k] for k in _AUX)
                 return _pack_fetch(tok, logits, kp, vp, *rest)
             return _decode
 
@@ -926,21 +943,18 @@ class ServingEngine:
         table, as :meth:`warmup`'s do.
 
         With ``decode_from = n`` the first ``n`` tokens go through prefill
-        into a scratch stream (blocks and, for a model that has them, a
-        state slot and window blocks, booked for the call and returned
-        after it) and the rest one by one through the decode program of
-        batch 1 with the given tokens forced; the result is the last
-        step's logits: prefill-then-decode through every kind of
-        per-stream state, against a reference's full forward."""
+        into a scratch stream and the rest one by one through the decode
+        program of batch 1 with the given tokens forced
+        (:meth:`decode_logits` of one text); the result is the last step's
+        logits: prefill-then-decode through every kind of per-stream state,
+        against a reference's full forward."""
         cfg = self.config
         n = len(tokens)
         if not 1 <= n <= cfg.max_len:
             raise ValueError("%d tokens: need 1..max_len (%d)"
                              % (n, cfg.max_len))
         if decode_from is not None:
-            if not 1 <= decode_from < n:
-                raise ValueError("decode_from must be in 1..%d" % (n - 1))
-            return self._scratch_logits(tokens, int(decode_from))
+            return self.decode_logits([tokens], [decode_from])[0]
         S = _bucket_for(n, cfg.prefill_buckets())
         toks = np.zeros((1, S), np.int32)
         toks[0, :n] = tokens
@@ -949,39 +963,77 @@ class ServingEngine:
             _t, logits = self._dispatch_prefill(toks, n, table)
         return np.asarray(logits, np.float32)[0]  # fwlint: disable=device-escape — the logits are what the caller asked for
 
-    def _scratch_logits(self, tokens, n):
-        """:meth:`prefill_logits` with ``decode_from = n``."""
+    def decode_logits(self, texts, starts):
+        """Each text's next-token logits ``(len(texts), V)`` float32, its
+        first ``starts[i]`` tokens through prefill into a scratch stream of
+        its own (blocks and, for a model that has them, a state slot and
+        window blocks, booked for the call and returned after it) and the
+        rest forced one step at a time through the decode program — every
+        text TOGETHER, a lane a text, at the batch bucket of ``len(texts)``:
+        ragged contexts side by side as a serving step has them, a lane
+        dead (``steps_left`` 0, a padded row's contract) once its text has
+        ended. Nothing is booked."""
         cfg = self.config
-        scratch = Request(tokens[:n], 1)
-        S = _bucket_for(n, cfg.prefill_buckets())
-        toks = np.zeros((1, S), np.int32)
-        toks[0, :n] = tokens[:n]
+        if not 1 <= len(texts) <= cfg.max_batch:
+            raise ValueError("%d texts: need 1..max_batch (%d)"
+                             % (len(texts), cfg.max_batch))
+        starts = [int(n) for n in starts]
+        for text, n in zip(texts, starts):
+            if not 1 <= n < len(text) <= cfg.max_len:
+                raise ValueError(
+                    "decode_from must be in 1..%d, and at most max_len (%d) "
+                    "tokens" % (len(text) - 1, cfg.max_len))
+        B = _bucket_for(len(texts), cfg.decode_buckets())
+        lanes = [Request(text[:n], 1) for text, n in zip(texts, starts)]
+        out = np.zeros((len(texts), cfg.vocab_size), np.float32)
         with self._lock:
-            scratch.blocks = self.pool.alloc(
-                self.pool.blocks_for(len(tokens)))
             try:
-                if self.streams is not None:
-                    self.streams.admit(scratch, n)
-                width = S // cfg.block_size
-                self._dispatch_prefill(
-                    toks, n, self._table_row(scratch.blocks, width),
-                    self._table_row(scratch.wblocks, width),
-                    scratch.slot or 0)
-                for pos in range(n, len(tokens)):
+                for req, text, n in zip(lanes, texts, starts):
+                    req.blocks = self.pool.alloc(
+                        self.pool.blocks_for(len(text)))
                     if self.streams is not None:
-                        self.streams.ensure(scratch, pos)
+                        self.streams.admit(req, n)
+                    S = _bucket_for(n, cfg.prefill_buckets())
+                    toks = np.zeros((1, S), np.int32)
+                    toks[0, :n] = text[:n]
+                    width = S // cfg.block_size
+                    self._dispatch_prefill(
+                        toks, n, self._table_row(req.blocks, width),
+                        self._table_row(req.wblocks, width), req.slot or 0)
+                for step in range(max(len(t) - n
+                                      for t, n in zip(texts, starts))):
+                    # fresh arrays a step: a dispatch may still read the last
+                    toks, poss, left, slots = (np.zeros(B, np.int32)
+                                               for _ in range(4))
+                    ctx = np.ones(B, np.int32)
+                    tables, wtables = (np.zeros((B, self._nb_max), np.int32)
+                                       for _ in range(2))
+                    for i, (req, text, n) in enumerate(
+                            zip(lanes, texts, starts)):
+                        pos = n + step
+                        if pos >= len(text):
+                            continue
+                        if self.streams is not None:
+                            self.streams.ensure(req, pos)
+                        toks[i], poss[i], ctx[i], left[i] = (
+                            text[pos], pos, pos + 1, 1)
+                        tables[i] = self._table_row(req.blocks, self._nb_max)
+                        wtables[i] = self._table_row(req.wblocks,
+                                                     self._nb_max)
+                        slots[i] = req.slot or 0
                     _t, logits = self._dispatch_decode(
-                        np.asarray(tokens[pos:pos + 1], np.int32),
-                        np.full(1, pos, np.int32),
-                        self._table_row(scratch.blocks, self._nb_max)[None],
-                        np.full(1, pos + 1, np.int32),
-                        self._table_row(scratch.wblocks, self._nb_max)[None],
-                        np.full(1, scratch.slot or 0, np.int32))
+                        toks, poss, tables, ctx, wtables, slots, left=left)
+                    ended = [i for i, (text, n) in enumerate(
+                        zip(texts, starts)) if n + step == len(text) - 1]
+                    if ended:
+                        out[ended] = np.asarray(logits, np.float32)[ended]  # fwlint: disable=device-escape — the logits are what the caller asked for
             finally:
-                self.pool.free(scratch.blocks)
-                if self.streams is not None:
-                    self.streams.release(scratch)
-        return np.asarray(logits, np.float32)[0]  # fwlint: disable=device-escape — the logits are what the caller asked for
+                for req in lanes:
+                    if req.blocks:
+                        self.pool.free(req.blocks)
+                    if self.streams is not None:
+                        self.streams.release(req)
+        return out
 
     def generate(self, prompts, max_new_tokens, eos_id=None, timeout_s=None):
         """Convenience batch API: submit every prompt, drive steps until
@@ -1064,12 +1116,14 @@ class ServingEngine:
         from .kv_cache import StateSlots, StreamState
 
         heads = rows = cfg.kv_rows()    # num_heads x head_dim = G x W
-        n_full = len(cfg.layers_of("full"))
+        n_full = len(cfg.layers_of("full", "mla"))
+        # the latent format: a second row shape for v_pages
+        latent = {"v_rows": cfg.v_rows()} if cfg.latent else {}
         n_win = len(cfg.layers_of("swa"))
         n_ssm = len(cfg.layers_of("mamba"))
         pool = KVBlockPool(max(n_full, 1), cfg.num_blocks, cfg.block_size,
                            *heads, dtype=cfg.kv_dtype, device=device,
-                           prefix_cache=False, rows=rows)
+                           prefix_cache=False, rows=rows, **latent)
         # max_batch streams of window + one block of tokens and the slots
         # a decode chunk writes beyond its first, max_batch slots, and the
         # trash of each: running <= max_batch, so neither runs short. A
@@ -1080,7 +1134,7 @@ class ServingEngine:
         window_pool = KVBlockPool(
             max(n_win, 1), cfg.max_batch * per_stream + 1 if n_win else 2,
             cfg.block_size, *heads, dtype=cfg.kv_dtype, device=device,
-            prefix_cache=False, rows=rows, gauges=False)
+            prefix_cache=False, rows=rows, gauges=False, **latent)
         state = StateSlots(
             max(n_ssm, 1), cfg.max_batch + 1 if n_ssm else 2,
             (cfg.ssm_conv - 1) * cfg.d_inner, (cfg.ssm_state, cfg.d_inner),
@@ -1205,11 +1259,14 @@ class ServingEngine:
             # the per-step token egress: serving's output IS this transfer
             tok, load = _unpack_fetch(np.asarray(tok), (1,), cfg)  # fwlint: disable=device-escape — token egress to the client is the product, one scalar per prefill (+ the experts' load in the same array)
             tok = int(tok[0])
-            fetch.set(**_moe_args(load))
+            fetch.set(**_moe_args(load, self.config))
         wall = time.time() - t0
         with telemetry.span("serving.retire", _CAT,
                             request_id=req.request_id):
             self._note_moe(load, L)
+            if cfg.latent:
+                self._latent["prefill_tokens"] += L
+                telemetry.counter("serving.latent.prefill_tokens").inc(L)
             c1, s1 = jit.compile_totals()
             s1 += self._draft_prefill_jits[S].compile_totals()[1] \
                 if self._spec else 0.0
@@ -1290,7 +1347,7 @@ class ServingEngine:
             fetched = np.asarray(nxt)  # fwlint: disable=device-escape — token egress to clients is the product, chunk x B int32s per dispatch
             nxt, load = _unpack_fetch(fetched, (self._chunk, B), cfg,
                                       (self._chunk,))
-            fetch.set(**_moe_args(load))
+            fetch.set(**_moe_args(load, self.config))
         wall = time.time() - t0
         c1, s1 = jit.compile_totals()
         if c1 > c0:
@@ -1319,6 +1376,8 @@ class ServingEngine:
             if self.streams is not None:
                 for k, v in self._note_hybrid(ctx, blocks).items():
                     noted[k] = noted.get(k, 0) + v
+            if cfg.latent:
+                self._note_latent(ctx, blocks)
             if load is not None:
                 self._note_moe(load[j], int((ctx <= cfg.max_len).sum()))
             telemetry.histogram("serving.decode_batch").observe(len(live))
@@ -1451,7 +1510,7 @@ class ServingEngine:
         with telemetry.span("serving.decode.fetch", _CAT, phase="verify",
                             **args) as fetch:
             nxt2, load = _unpack_fetch(np.asarray(nxt2), (B, T), cfg)  # fwlint: disable=device-escape — token egress to clients is the product, B×(k+1) int32s per step
-            fetch.set(**_moe_args(load))
+            fetch.set(**_moe_args(load, self.config))
         verify_wall = time.time() - t0
         c1, s1 = vjit.compile_totals()
         verify_stall = min(s1 - s0, verify_wall) if c1 > c0 else 0.0
@@ -1520,15 +1579,32 @@ class ServingEngine:
         return {"window_live_blocks": window_live,
                 "full_live_blocks": full_live}
 
+    def _note_latent(self, ctx, live_blocks):
+        """Book one decode step of the latent kernel, a call a "mla"
+        layer: the cached tokens the live lanes read (their context
+        lengths, this step's own token included), the lanes, the blocks."""
+        for name, n in (("ctx_tokens", int(ctx.sum())),
+                        ("lane_steps", len(ctx)),
+                        ("live_blocks", live_blocks)):
+            self._latent[name] += n
+            telemetry.counter("serving.latent." + name).inc(n)
+
     def _note_moe(self, load, tokens):
-        """Book one program's per-layer ``tokens_per_expert`` (L, E):
-        pairs computed, layer-steps, the ``tokens`` live lanes the engine
-        sent through each layer (counted here, on the host, so that
-        ``pairs == experts_per_tok x layer_tokens`` checks the program),
-        experts with at least one token, and how uneven the last step was.
-        None (no experts): nothing."""
+        """Book one program's per-layer ``tokens_per_expert`` (L, E), the
+        router's count over all E experts: every choice it made
+        (``routed_pairs``, so that ``routed_pairs == experts_per_tok x
+        layer_tokens`` checks the program: the ``tokens`` live lanes the
+        engine sent through each layer are counted here, on the host), and
+        of the experts THIS engine holds the pairs computed, the
+        layer-steps, the experts with at least one token, and how uneven
+        the last step was. None (no experts): nothing."""
         if load is None:
             return
+        routed = int(load.sum())
+        self._moe_routed += routed
+        telemetry.counter("serving.moe.routed_pairs").inc(routed)
+        first, count = self.config.experts_here
+        load = load[:, first:first + count]
         self._moe_load += load
         self._moe_layer_steps += load.shape[0]
         self._moe_layer_tokens += tokens * load.shape[0]
@@ -1597,7 +1673,7 @@ class ServingEngine:
             "window_blocks_a_stream": st.max_blocks_held,
             "window_blocks_freed": st.blocks_freed,
             # model layers that read the full-length pool's K/V
-            "full_pool_readers": len(cfg.layers_of("full", "cross")),
+            "full_pool_readers": len(cfg.layers_of("full", "cross", "mla")),
             "full_pool_layers": self.pool.num_layers,
         }
 
@@ -1681,10 +1757,16 @@ class ServingEngine:
                 # only for a model with window or state layers
                 **({"state": self._state_stats()}
                    if self.streams is not None else {}),
+                # only for a model with "mla" layers
+                **({"latent": dict(self._latent)}
+                   if self.config.latent else {}),
                 # only for a model with experts
                 **({"moe": {
                     "num_experts": self.config.num_experts,
                     "experts_per_tok": self.config.experts_per_tok,
+                    # this engine's share of them, (first, count)
+                    "experts_held": list(self.config.experts_here),
+                    "routed_pairs": self._moe_routed,
                     "pairs": int(self._moe_load.sum()),
                     "layer_steps": self._moe_layer_steps,
                     "layer_tokens": self._moe_layer_tokens,

@@ -66,12 +66,17 @@ class KVBlockPool:
 
     def __init__(self, num_layers, num_blocks, block_size, num_heads,
                  head_dim, dtype=np.float32, device=None,
-                 prefix_cache=True, rows=None, gauges=True):
+                 prefix_cache=True, rows=None, gauges=True, v_rows=None):
         """``rows``: the page rows ``(G, W)`` where the model decides them
         (``ModelConfig.kv_rows``: a differential-attention K/V pair a
         row); ``num_heads x head_dim`` are then ``G x W``. ``gauges``: a
         second pool of an engine (the window layers') leaves the
-        process's ``serving.kv_blocks_*`` gauges to the first."""
+        process's ``serving.kv_blocks_*`` gauges to the first.
+        ``v_rows``: the rows of ``v_pages`` where they differ — the LATENT
+        format (``ModelConfig.v_rows``): ``k_pages`` hold a token's
+        latent, one ``kv_rank``-wide row, ``v_pages`` its rotated key in
+        one 128-lane row; such a block is head-major (one row a token is
+        the ``(bs, W)`` slab either way)."""
         if num_blocks < 2:
             raise ValueError("KVBlockPool needs >= 2 blocks (block 0 is the "
                              "reserved trash block)")
@@ -90,18 +95,25 @@ class KVBlockPool:
         self.extra_nbytes = 0
         #: a block is (G, bs, W), not (bs, G, W): only where the model
         #: names its rows (the one-block models' programs are token-major)
-        self.is_head_major = rows is not None and self.head_major(*rows)
+        self.is_head_major = v_rows is not None or (
+            rows is not None and self.head_major(*rows))
         rows, lanes = (rows if rows is not None
                        else self.page_shape(self.num_heads, self.head_dim))
         #: heads side by side in one page row (1 = the plain (H, D) row)
         self.heads_per_row = self.num_heads // rows
         #: (G, W), wherever the block's slots stand
         self.page_rows = (rows, lanes)
-        shape = (self.num_layers, self.num_blocks) + (
-            (rows, self.block_size, lanes) if self.is_head_major
-            else (self.block_size, rows, lanes))
-        k = jnp.zeros(shape, self.dtype)
-        v = jnp.zeros(shape, self.dtype)
+        #: (G, W) of ``v_pages`` (the same as ``page_rows`` but for latents)
+        self.v_page_rows = tuple(v_rows) if v_rows is not None \
+            else self.page_rows
+
+        def pages(g, w):
+            return jnp.zeros((self.num_layers, self.num_blocks) + (
+                (g, self.block_size, w) if self.is_head_major
+                else (self.block_size, g, w)), self.dtype)
+
+        k = pages(*self.page_rows)
+        v = pages(*self.v_page_rows)
         if device is not None:
             import jax
 
@@ -182,15 +194,14 @@ class KVBlockPool:
     def nbytes(self):
         """Device bytes the pool pins (K + V), and what the engine holds
         beside it for the same streams (``extra_nbytes``)."""
-        per = (self.num_layers * self.num_blocks * self.block_size
-               * self.num_heads * self.head_dim * self.dtype.itemsize)
-        return 2 * per + self.extra_nbytes
+        return self.block_nbytes() * self.num_blocks + self.extra_nbytes
 
     def block_nbytes(self):
         """Device bytes ONE block pins across layers (K + V) — the unit
         every shared reference saves."""
-        return 2 * (self.num_layers * self.block_size * self.num_heads
-                    * self.head_dim * self.dtype.itemsize)
+        (g, w), (gv, wv) = self.page_rows, self.v_page_rows
+        return (self.num_layers * self.block_size * (g * w + gv * wv)
+                * self.dtype.itemsize)
 
     def blocks_for(self, num_tokens):
         """Blocks needed to hold ``num_tokens`` cache slots."""

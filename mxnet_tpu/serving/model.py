@@ -33,7 +33,8 @@ as ``_contrib_CachedMultiHeadAttention``.
 """
 import numpy as np
 
-from ..ops.attention import flash_attention, paged_attention_multi
+from ..ops.attention import (flash_attention, latent_paged,
+                             paged_attention_multi)
 from ..ops.moe import moe_ffn
 from ..ops.registry import fp32_precision
 from ..ops.ssm import ssm_scan, ssm_step
@@ -81,6 +82,16 @@ class ModelConfig:
     ``"cross"``  a query projection only; reads the K/V of the last
                  ``"full"`` layer before it and writes none
     ``"gmu"``    a gated memory unit: ``W_out (silu(W_in h) * m)``, no state
+    ``"mla"``    multi-head latent attention (DeepSeek-V3's): queries
+                 through a normalised ``q_rank`` bottleneck, keys and
+                 values expanded from a normalised ``kv_rank`` latent; a
+                 head is ``head_dim`` lanes without position plus
+                 ``rope_dim`` rotary lanes (ONE rotary key a token, shared
+                 by the heads), its value ``v_dim`` wide. The cache holds
+                 the latent and the rotated key, not per-head K and V;
+                 prefill attends over expanded heads, decode over the
+                 absorbed form. Needs ``pos="rope"``; takes no other
+                 attention kind beside it
 
     num_kv_heads    K/V heads (default ``num_heads``); the ``swa`` / ``full``
                     / ``cross`` kinds are DIFFERENTIAL attention: adjacent
@@ -95,6 +106,27 @@ class ModelConfig:
                     Mamba's ``d_state``, ``d_conv``, ``d_inner / model_dim``
                     and ``dt_rank`` (None: ``ceil(model_dim / 16)``)
     pos             may then also be "none" (no position anywhere)
+    q_rank, kv_rank, rope_dim, v_dim
+                    the "mla" kind's widths (above)
+    rope_yarn       None, or YaRN's ``(factor, original_len, beta_fast,
+                    beta_slow, mscale, mscale_all_dim)`` on the rotary lanes
+    norm_eps        the norms' epsilon
+
+    The FFN may differ by layer too, and a layer with experts may hold a
+    share of them:
+
+    first_dense     leading layers whose FFN is the dense one (of
+                    ``dense_ffn_dim``) in a model with experts
+    dense_ffn_dim   that FFN's width (default ``ffn_dim``, which stays the
+                    width of ONE expert)
+    shared_experts  experts every token goes through beside its routed
+                    ones (one gated FFN of ``shared_experts x ffn_dim``)
+    router          "softmax" (top-k of a softmax, not renormalised) or
+                    "sigmoid_group" (``ops.moe.route``) with ``n_group``,
+                    ``topk_group``, ``route_scale``
+    experts_held    None, or ``(first, count)``: this engine is ONE RANK
+                    of an expert-parallel layout and holds ``count`` of
+                    the ``num_experts`` the router chooses among
 
     ``max_len`` bounds every stream's total length (the position table's
     rows, or the positions the rotary model was trained for)."""
@@ -104,12 +136,15 @@ class ModelConfig:
                  "qk_norm", "head_dim", "num_experts", "experts_per_tok",
                  "bias", "layer_kinds", "num_kv_heads", "window",
                  "attn_bias", "ffn_gated", "tie_embed", "ssm_state",
-                 "ssm_conv", "ssm_expand", "ssm_dt_rank")
+                 "ssm_conv", "ssm_expand", "ssm_dt_rank", "q_rank", "kv_rank",
+                 "rope_dim", "v_dim", "rope_yarn", "norm_eps", "first_dense",
+                 "dense_ffn_dim", "shared_experts", "router", "n_group",
+                 "topk_group", "route_scale", "experts_held")
     #: the fields of one-block models: their ``key()`` is these alone, so
     #: that the programs' cache keys are what they were before ``layer_kinds``
     _BLOCK_FIELDS = 14
     #: what ``layer_kinds`` may name
-    KINDS = ("mamba", "swa", "full", "cross", "gmu")
+    KINDS = ("mamba", "swa", "full", "cross", "gmu", "mla")
 
     def __init__(self, vocab_size=32000, num_layers=4, model_dim=256,
                  num_heads=4, ffn_dim=1024, max_len=128, norm="layer",
@@ -117,7 +152,11 @@ class ModelConfig:
                  head_dim=None, num_experts=0, experts_per_tok=0, bias=True,
                  layer_kinds=None, num_kv_heads=None, window=0,
                  attn_bias=False, ffn_gated=False, tie_embed=False,
-                 ssm_state=16, ssm_conv=4, ssm_expand=2, ssm_dt_rank=None):
+                 ssm_state=16, ssm_conv=4, ssm_expand=2, ssm_dt_rank=None,
+                 q_rank=0, kv_rank=0, rope_dim=0, v_dim=None, rope_yarn=None,
+                 norm_eps=1e-5, first_dense=0, dense_ffn_dim=None,
+                 shared_experts=0, router="softmax", n_group=1, topk_group=1,
+                 route_scale=1.0, experts_held=None):
         self.vocab_size = int(vocab_size)
         self.num_layers = int(num_layers)
         self.model_dim = int(model_dim)
@@ -148,6 +187,21 @@ class ModelConfig:
         self.ssm_expand = int(ssm_expand)
         self.ssm_dt_rank = int(ssm_dt_rank if ssm_dt_rank is not None
                                else -(-self.model_dim // 16))
+        self.q_rank, self.kv_rank = int(q_rank), int(kv_rank)
+        self.rope_dim = int(rope_dim)
+        self.v_dim = int(v_dim if v_dim is not None else self.head_dim)
+        self.rope_yarn = (None if rope_yarn is None
+                          else tuple(float(v) for v in rope_yarn))
+        self.norm_eps = float(norm_eps)
+        self.first_dense = int(first_dense)
+        self.dense_ffn_dim = int(dense_ffn_dim if dense_ffn_dim is not None
+                                 else self.ffn_dim)
+        self.shared_experts = int(shared_experts)
+        self.router = str(router)
+        self.n_group, self.topk_group = int(n_group), int(topk_group)
+        self.route_scale = float(route_scale)
+        self.experts_held = (None if experts_held is None
+                             else tuple(int(v) for v in experts_held))
         if self.norm not in ("layer", "rms"):
             raise ValueError("norm must be 'layer' or 'rms', not %r" % norm)
         if self.pos not in ("learned", "rope", "none"):
@@ -159,23 +213,65 @@ class ModelConfig:
                 1 <= self.experts_per_tok <= self.num_experts):
             raise ValueError("experts_per_tok must be in 1..num_experts")
         self._check_kinds()
+        self._check_ffn()
+
+    def _check_ffn(self):
+        if self.router not in ("softmax", "sigmoid_group"):
+            raise ValueError("router must be 'softmax' or 'sigmoid_group', "
+                             "not %r" % self.router)
+        if not self.num_experts:
+            if self.first_dense or self.shared_experts or self.experts_held:
+                raise ValueError("first_dense, shared_experts and "
+                                 "experts_held belong to a model with experts")
+            return
+        if not 0 <= self.first_dense < self.num_layers:
+            raise ValueError("first_dense must leave a layer with experts")
+        if self.router == "sigmoid_group" and (
+                self.num_experts % self.n_group
+                or not 1 <= self.topk_group <= self.n_group
+                or self.experts_per_tok
+                > self.topk_group * (self.num_experts // self.n_group)):
+            raise ValueError("%d experts do not make %d groups of which %d "
+                             "hold %d experts a token"
+                             % (self.num_experts, self.n_group,
+                                self.topk_group, self.experts_per_tok))
+        if self.experts_held is not None:
+            first, count = self.experts_held
+            if count < 1 or first < 0 or first + count > self.num_experts:
+                raise ValueError("experts_held %r is no part of %d experts"
+                                 % (self.experts_held, self.num_experts))
 
     def _check_kinds(self):
         kinds = self.kinds()
+        by_layer = (self.first_dense or self.shared_experts
+                    or self.experts_held or self.router != "softmax"
+                    or self.rope_yarn or self.norm_eps != 1e-5)
         if self.layer_kinds is None:
             if (self.num_kv_heads != self.num_heads or self.pos == "none"
-                    or self.ffn_gated or self.tie_embed or self.attn_bias):
+                    or self.ffn_gated or self.tie_embed or self.attn_bias
+                    or by_layer):
                 raise ValueError(
-                    "num_kv_heads, pos='none', ffn_gated, tie_embed and "
-                    "attn_bias belong to a model with layer_kinds")
+                    "num_kv_heads, pos='none', ffn_gated, tie_embed, "
+                    "attn_bias, norm_eps, rope_yarn and an FFN that differs "
+                    "by layer belong to a model with layer_kinds")
             return
         if len(kinds) != self.num_layers or set(kinds) - set(self.KINDS):
             raise ValueError("layer_kinds must name each of the %d layers "
                              "one of %s, not %r"
                              % (self.num_layers, self.KINDS, kinds))
-        if self.num_experts or self.qk_norm or self.pos == "rope":
-            raise ValueError("a model with layer_kinds takes no experts, "
-                             "QK-norm or rotary position")
+        if self.qk_norm:
+            raise ValueError("a model with layer_kinds takes no QK-norm")
+        if "mla" in kinds:
+            if set(kinds) & {"swa", "full", "cross"}:
+                raise ValueError("'mla' layers share the full pool with no "
+                                 "other attention kind")
+            if self.pos != "rope" or self.rope_dim < 2 or self.rope_dim % 2 \
+                    or self.q_rank < 1 or self.kv_rank < 1:
+                raise ValueError("'mla' layers need pos='rope', an even "
+                                 "rope_dim, q_rank and kv_rank")
+        elif self.pos == "rope" or self.rope_yarn:
+            raise ValueError("of the layer kinds only 'mla' has rotary "
+                             "position")
         if set(kinds) & {"swa", "full", "cross"}:
             if self.num_heads % 2 or self.num_kv_heads % 2 or \
                     (self.num_heads // 2) % (self.num_kv_heads // 2):
@@ -215,6 +311,23 @@ class ModelConfig:
         return bool(self.layers_of("mamba", "swa"))
 
     @property
+    def latent(self):
+        """The cache holds "mla" layers' latents: a block's contents are no
+        per-head K and V, and no program extends over them yet (no prefix
+        sharing, no verify pass)."""
+        return bool(self.layers_of("mla"))
+
+    @property
+    def expert_layers(self):
+        """How many layers have experts (those behind ``first_dense``)."""
+        return self.num_layers - self.first_dense if self.num_experts else 0
+
+    @property
+    def experts_here(self):
+        """``(first, count)`` of the experts this engine holds."""
+        return self.experts_held or (0, self.num_experts)
+
+    @property
     def d_inner(self):
         return self.ssm_expand * self.model_dim
 
@@ -231,9 +344,22 @@ class ModelConfig:
         and ``k2`` its halves)."""
         from .kv_cache import KVBlockPool
 
+        if self.latent:         # k_pages hold the latent, one row a token
+            return 1, self.kv_rank
         if set(self.kinds()) & {"swa", "full", "cross"}:
             return self.num_kv_heads // 2, 2 * self.head_dim
         return KVBlockPool.page_shape(self.num_kv_heads, self.head_dim)
+
+    def v_rows(self):
+        """The rows of ``v_pages`` where they are not ``kv_rows()``: an
+        "mla" model keeps a token's rotated key there, its ``rope_dim``
+        lanes padded to whole 128-lane tiles (a 64-lane row is half a
+        tile: the pool's layout would not be row-major, PR 25; two tokens
+        a row would save a tenth of the cache's bytes and make every
+        decode write half a row)."""
+        if self.latent:
+            return 1, -(-self.rope_dim // 128) * 128
+        return self.kv_rows()
 
     def key(self):
         """What the programs are a function of. A one-block model's key is
@@ -295,18 +421,26 @@ def param_shapes(cfg):
         if cfg.qk_norm:
             shapes.update({p + "_q_norm_gamma": (hm,),
                            p + "_k_norm_gamma": (hm,)})
-        if cfg.num_experts:
-            e = cfg.num_experts
+        if cfg.num_experts and i >= cfg.first_dense:
+            e, held = cfg.num_experts, cfg.experts_here[1]
             shapes.update({p + "_router_weight": (e, m),
-                           p + "_experts_gate_weight": (e, f, m),
-                           p + "_experts_up_weight": (e, f, m),
-                           p + "_experts_down_weight": (e, m, f)})
+                           p + "_experts_gate_weight": (held, f, m),
+                           p + "_experts_up_weight": (held, f, m),
+                           p + "_experts_down_weight": (held, m, f)})
+            if cfg.router == "sigmoid_group":
+                shapes[p + "_router_bias"] = (e,)
+            if cfg.shared_experts:
+                fs = cfg.shared_experts * f
+                shapes.update({p + "_shared_gate_weight": (fs, m),
+                               p + "_shared_up_weight": (fs, m),
+                               p + "_shared_down_weight": (m, fs)})
         else:
+            fd = cfg.dense_ffn_dim
             shapes.update({
-                p + "_ffn1_weight": ((2 if cfg.ffn_gated else 1) * f, m),
-                p + "_ffn2_weight": (m, f)})
+                p + "_ffn1_weight": ((2 if cfg.ffn_gated else 1) * fd, m),
+                p + "_ffn2_weight": (m, fd)})
             if cfg.bias:
-                shapes.update({p + "_ffn1_bias": (f,),
+                shapes.update({p + "_ffn1_bias": (fd,),
                                p + "_ffn2_bias": (m,)})
     return shapes
 
@@ -327,6 +461,16 @@ def _mixer_shapes(cfg, kind, p):
                 p + "_ssm_d": (dn,), p + "_ssm_out_weight": (m, dn)}
     if kind == "gmu":
         return {p + "_gmu_in_weight": (dn, m), p + "_gmu_out_weight": (m, dn)}
+    if kind == "mla":
+        h, dr, dv = cfg.num_heads, cfg.rope_dim, cfg.v_dim
+        return {p + "_mla_q_down_weight": (cfg.q_rank, m),
+                p + "_mla_q_norm_gamma": (cfg.q_rank,),
+                p + "_mla_q_up_weight": (h * (hd + dr), cfg.q_rank),
+                p + "_mla_kv_down_weight": (cfg.kv_rank + dr, m),
+                p + "_mla_kv_norm_gamma": (cfg.kv_rank,),
+                # a head's rows: its key part without position, its value
+                p + "_mla_kv_up_weight": (h * (hd + dv), cfg.kv_rank),
+                p + "_attn_out_weight": (m, h * dv)}
     shapes = {p + "_attn_out_weight": (m, hq),
               p + "_diff_norm_gamma": (2 * hd,)}
     shapes.update({p + "_diff_lambda_" + v: (hd,)
@@ -363,6 +507,9 @@ def random_params(cfg, seed=0, dtype=np.float32):
             out[name] = np.broadcast_to(np.log(np.arange(
                 1, shape[0] + 1, dtype=np.float64))[:, None],
                 shape).astype(dtype)
+        elif name.endswith("_router_bias"):
+            # the selection's correction: zero would leave its path unseen
+            out[name] = (rng.randn(*shape) * 0.01).astype(dtype)
         elif name.endswith(("_beta", "_bias")):
             out[name] = np.zeros(shape, dtype)
         elif "_diff_lambda_" in name:
@@ -432,29 +579,30 @@ def draft_config(cfg, spec):
 _NORM_EPS = 1e-5
 
 
-def _layer_norm(x, gamma, beta):
+def _layer_norm(x, gamma, beta, eps=_NORM_EPS):
     import jax.numpy as jnp
 
     mean = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
-    return (x - mean) / jnp.sqrt(var + _NORM_EPS) * gamma + beta
+    return (x - mean) / jnp.sqrt(var + eps) * gamma + beta
 
 
-def _rms_norm(x, gamma):
+def _rms_norm(x, gamma, eps=_NORM_EPS):
     """t / sqrt(mean(t^2) + eps) * gamma, statistics in float32."""
     import jax
     import jax.numpy as jnp
 
     x32 = x.astype(jnp.float32)
     ms = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-    y = x32 * jax.lax.rsqrt(ms + _NORM_EPS) * gamma.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(ms + eps) * gamma.astype(jnp.float32)
     return y.astype(x.dtype)
 
 
 def _norm(x, params, name, cfg):
     if cfg.norm == "rms":
-        return _rms_norm(x, params[name + "_gamma"])
-    return _layer_norm(x, params[name + "_gamma"], params[name + "_beta"])
+        return _rms_norm(x, params[name + "_gamma"], cfg.norm_eps)
+    return _layer_norm(x, params[name + "_gamma"], params[name + "_beta"],
+                       cfg.norm_eps)
 
 
 def _rope(x, positions, cfg):
@@ -465,15 +613,61 @@ def _rope(x, positions, cfg):
 
     a, b, _ = x.shape
     hd = cfg.head_dim
-    half = hd // 2
     inv_freq = cfg.rope_theta ** (
-        -jnp.arange(half, dtype=jnp.float32) * 2.0 / hd)
+        -jnp.arange(hd // 2, dtype=jnp.float32) * 2.0 / hd)
+    return _rotate(x.reshape(a, b, cfg.num_heads, hd), positions,
+                   inv_freq).reshape(x.shape)
+
+
+def _rotate(x4, positions, inv_freq):
+    """Rotate-half on the last axis of ``x4`` (A, B, H, d) at ``positions``
+    (A, B) with ``inv_freq`` (d // 2,), in float32."""
+    import jax.numpy as jnp
+
+    half = x4.shape[-1] // 2
     ang = positions.astype(jnp.float32)[..., None, None] * inv_freq
     cos, sin = jnp.cos(ang), jnp.sin(ang)                 # (A, B, 1, half)
-    x4 = x.reshape(a, b, cfg.num_heads, hd).astype(jnp.float32)
-    x1, x2 = x4[..., :half], x4[..., half:]
+    x32 = x4.astype(jnp.float32)
+    x1, x2 = x32[..., :half], x32[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-    return out.reshape(x.shape).astype(x.dtype)
+    return out.astype(x4.dtype)
+
+
+def _yarn_mscale(factor, mscale):
+    return 0.1 * mscale * float(np.log(factor)) + 1.0 if factor > 1 else 1.0
+
+
+def mla_rope(cfg):
+    """``(inv_freq (rope_dim // 2,) float32, cos/sin scale)`` of the "mla"
+    kind's rotary lanes: plain RoPE, or YaRN's (arXiv:2309.00071) — the
+    frequencies below ``beta_slow`` rotations over the original length
+    interpolated by ``factor``, those above ``beta_fast`` kept, a linear
+    ramp between — with cos and sin scaled by ``mscale / mscale_all_dim``."""
+    d = cfg.rope_dim
+    extra = cfg.rope_theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    if cfg.rope_yarn is None:
+        return extra.astype(np.float32), 1.0
+    factor, orig, fast, slow, mscale, mscale_all = cfg.rope_yarn
+
+    def correction(rotations):
+        return d * np.log(orig / (rotations * 2 * np.pi)) / (
+            2 * np.log(cfg.rope_theta))
+
+    low = max(np.floor(correction(fast)), 0)
+    high = min(np.ceil(correction(slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0, 1)
+    inv_freq = extra / factor * ramp + extra * (1 - ramp)
+    return inv_freq.astype(np.float32), (_yarn_mscale(factor, mscale)
+                                         / _yarn_mscale(factor, mscale_all))
+
+
+def mla_sm_scale(cfg):
+    """The "mla" kind's softmax scale: ``(head_dim + rope_dim) ** -0.5``,
+    times YaRN's ``mscale(factor, mscale_all_dim) ** 2``."""
+    scale = float(cfg.head_dim + cfg.rope_dim) ** -0.5
+    if cfg.rope_yarn is not None and cfg.rope_yarn[5]:
+        scale *= _yarn_mscale(cfg.rope_yarn[0], cfg.rope_yarn[5]) ** 2
+    return scale
 
 
 def _position(q, k, positions, cfg):
@@ -497,6 +691,16 @@ def _embed(params, tokens, positions, cfg):
         return x + params["pos_embed_weight"][:, :tokens.shape[1]]
     pos_tab = params["pos_embed_weight"].reshape(cfg.max_len, cfg.model_dim)
     return x + jnp.take(pos_tab, positions, axis=0)
+
+
+def _gated(x2d, gate, up, down, prec):
+    """``W_down (silu(W_gate h) * (W_up h))``: the shared expert."""
+    import jax
+    import jax.numpy as jnp
+
+    f = jax.nn.silu(jnp.dot(x2d, gate.T, precision=prec)) \
+        * jnp.dot(x2d, up.T, precision=prec)
+    return jnp.dot(f, down.T, precision=prec)
 
 
 def _ffn(x2d, params, prefix, cfg, prec):
@@ -544,6 +748,42 @@ def _mix_attn(h, params, p, i, cfg, prec, positions, attend, state):
     attn = jnp.einsum("bsm,nm->bsn", attn, params[p + "_attn_out_weight"],
                       precision=prec)
     return attn, state
+
+
+def _mix_mla(h, params, p, i, cfg, prec, positions, attend, state):
+    """The "mla" kind: the query through its normalised bottleneck, the
+    latent and the one rotary key from ``W_dkv`` (the latent normalised,
+    the key rotated: what the cache holds) -> ``attend`` -> ``W_o``.
+    ``attend(i, (q_n, q_r), c, k_r, state)`` gets the heads' two query
+    parts ``(A, B, H, head_dim)`` / ``(A, B, H, rope_dim)``, the latent
+    ``(A, B, kv_rank)`` and the key ``(A, B, rope_dim)``, and returns the
+    heads' values side by side ``(A, B, H v_dim)``."""
+    import jax.numpy as jnp
+
+    a, b, _ = h.shape
+    hh, dn, dr = cfg.num_heads, cfg.head_dim, cfg.rope_dim
+    inv_freq, rscale = mla_rope(cfg)
+
+    def proj(t, name):
+        return jnp.einsum("bsm,nm->bsn", t, params[p + name], precision=prec)
+
+    cq = _rms_norm(proj(h, "_mla_q_down_weight"),
+                   params[p + "_mla_q_norm_gamma"], cfg.norm_eps)
+    q = proj(cq, "_mla_q_up_weight").reshape(a, b, hh, dn + dr)
+    c, kr = jnp.split(proj(h, "_mla_kv_down_weight"), [cfg.kv_rank], axis=-1)
+    c = _rms_norm(c, params[p + "_mla_kv_norm_gamma"], cfg.norm_eps)
+    qr = _rotate(q[..., dn:], positions, inv_freq)
+    kr = _rotate(kr[:, :, None], positions, inv_freq)[:, :, 0]
+    if rscale != 1.0:
+        qr, kr = qr * rscale, kr * rscale
+    att, state = attend(i, (q[..., :dn], qr), c, kr, state)
+    return proj(att, "_attn_out_weight"), state
+
+
+def _pad_lanes(t, lanes):
+    import jax.numpy as jnp
+
+    return jnp.pad(t, [(0, 0)] * (t.ndim - 1) + [(0, lanes - t.shape[-1])])
 
 
 def diff_lambda_init(i):
@@ -704,6 +944,9 @@ def _layer(x, params, i, cfg, prec, positions, valid, attend, state,
         mix, state = _mix_mamba(h, params, p, i, cfg, prec, recur, state)
     elif kind == "gmu":
         mix = _mix_gmu(h, params, p, prec, state)
+    elif kind == "mla":
+        mix, state = _mix_mla(h, params, p, i, cfg, prec, positions, attend,
+                              state)
     else:
         mix, state = _mix_diff(h, params, p, i, kind, cfg, prec, attend,
                                state)
@@ -711,30 +954,43 @@ def _layer(x, params, i, cfg, prec, positions, valid, attend, state,
     h = _norm(x, params, p + "_ln2", cfg)
     a, b, m = x.shape
     load = None
-    if cfg.num_experts:
+    h2 = h.reshape(a * b, m)
+    if cfg.num_experts and i >= cfg.first_dense:
+        how = {}
+        if cfg.router != "softmax":
+            how = dict(kind=cfg.router, bias=params[p + "_router_bias"],
+                       n_group=cfg.n_group, topk_group=cfg.topk_group,
+                       scale=cfg.route_scale)
+        if cfg.experts_held is not None:
+            how["held"] = cfg.experts_held
         f, load = moe_ffn(
-            h.reshape(a * b, m), params[p + "_router_weight"],
+            h2, params[p + "_router_weight"],
             params[p + "_experts_gate_weight"],
             params[p + "_experts_up_weight"],
             params[p + "_experts_down_weight"], cfg.experts_per_tok,
-            valid=valid.reshape(a * b))
+            valid=valid.reshape(a * b), **how)
+        if cfg.shared_experts:
+            f = f + _gated(h2, params[p + "_shared_gate_weight"],
+                           params[p + "_shared_up_weight"],
+                           params[p + "_shared_down_weight"], prec)
     else:
-        f = _ffn(h.reshape(a * b, m), params, p, cfg, prec)
+        f = _ffn(h2, params, p, cfg, prec)
     return x + f.reshape(a, b, m), state, load
 
 
 def _layers(x, params, cfg, prec, positions, valid, attend, state,
             recur=None):
     """Every layer in turn: ``(x, state, loads)`` with ``loads`` the
-    per-layer ``tokens_per_expert`` stacked (L, E), or () without
-    experts."""
+    expert layers' ``tokens_per_expert`` stacked ``(expert_layers, E)``,
+    or () without experts."""
     import jax.numpy as jnp
 
     loads = []
     for i in range(cfg.num_layers):
         x, state, load = _layer(x, params, i, cfg, prec, positions, valid,
                                 attend, state, recur)
-        loads.append(load)
+        if load is not None:
+            loads.append(load)
     return x, state, ((jnp.stack(loads),) if cfg.num_experts else ())
 
 
@@ -806,22 +1062,25 @@ def prefill(params, tokens, length, block_table, k_pages, v_pages, cfg,
 
 
 def _head_major(cfg):
+    """A latent's one row a token is head-major at any width: the block is
+    the ``(bs, W)`` slab either way."""
     from .kv_cache import KVBlockPool
 
-    return KVBlockPool.head_major(*cfg.kv_rows())
+    return cfg.latent or KVBlockPool.head_major(*cfg.kv_rows())
 
 
 def _cache_layers(cfg):
     """Model layer -> its layer of the full pool, of the window pool, of
     the state slots: three dicts (the pools' layers are not the model's)."""
     return tuple({i: n for n, i in enumerate(cfg.layers_of(*kinds))}
-                 for kinds in (("full",), ("swa",), ("mamba",)))
+                 for kinds in (("full", "mla"), ("swa",), ("mamba",)))
 
 
-def _block_rows(t, bs, cfg):
+def _block_rows(t, bs, cfg, rows=None):
     """K or V of S tokens ``(S, Hkv hd)`` as S // bs blocks in the pool's
-    own order: ``(S // bs, bs, G, W)``, or ``(S // bs, G, bs, W)``."""
-    g, w = cfg.kv_rows()
+    own order: ``(S // bs, bs, G, W)``, or ``(S // bs, G, bs, W)``;
+    ``rows``: the page rows where they are not ``kv_rows()``."""
+    g, w = rows or cfg.kv_rows()
     t = t.reshape(t.shape[0] // bs, bs, g, w)
     return t.transpose(0, 2, 1, 3) if _head_major(cfg) else t
 
@@ -848,9 +1107,9 @@ def _prefill_hybrid(params, tokens, length, block_table, k_pages, v_pages,
     wtable, slot = aux["wtable"], aux["slot"]
     taps = cfg.ssm_conv
 
-    def put(pages, li, table, t):
+    def put(pages, li, table, t, rows=None):
         """One layer's K or V of the S tokens into its blocks."""
-        rows = _block_rows(t, bs, cfg).astype(pages.dtype)
+        rows = _block_rows(t, bs, cfg, rows).astype(pages.dtype)
         if S == bs:
             # a scatter of ONE block is rewritten by the compiler into a
             # form that copies the pool in and out; the slice update it is
@@ -858,8 +1117,32 @@ def _prefill_hybrid(params, tokens, length, block_table, k_pages, v_pages,
                 pages, rows[None], (li, table[0], 0, 0, 0))
         return pages.at[li, table].set(rows)
 
+    def attend_mla(i, q, c, kr, st):
+        """Cache the latent and the rotated key; attend over the prompt's
+        EXPANDED heads, as published: keys ``[k_n | k_r]`` (the one rotary
+        key under every head), values ``v_dim`` wide."""
+        hh, dv = cfg.num_heads, cfg.v_dim
+        li = full_at[i]
+        st = dict(st, k=put(st["k"], li, block_table, c[0]),
+                  v=put(st["v"], li, block_table,
+                        _pad_lanes(kr[0], cfg.v_rows()[1]), cfg.v_rows()))
+        kv = jnp.einsum("sc,nc->sn", c[0],
+                        params["layer%d_mla_kv_up_weight" % i],
+                        precision=prec).reshape(S, hh, cfg.head_dim + dv)
+        keys = jnp.concatenate(
+            [kv[..., :cfg.head_dim],
+             jnp.broadcast_to(kr[0][:, None], (S, hh, cfg.rope_dim))], -1)
+        qs = jnp.concatenate(q, -1)[0]                  # (S, H, dn + dr)
+        att = flash_attention(qs.transpose(1, 0, 2)[None],
+                              keys.transpose(1, 0, 2)[None],
+                              kv[..., cfg.head_dim:].transpose(1, 0, 2)[None],
+                              True, mla_sm_scale(cfg))  # (1, H, S, dv)
+        return att[0].transpose(1, 0, 2).reshape(1, S, hh * dv), st
+
     def attend(i, q, k, v, st):
         kind = cfg.kinds()[i]
+        if kind == "mla":
+            return attend_mla(i, q, k, v, st)
         if kind == "cross":
             k, v = st["kv"]
         else:
@@ -901,14 +1184,14 @@ def _prefill_hybrid(params, tokens, length, block_table, k_pages, v_pages,
     x = _embed(params, tokens, None, cfg)                      # (1, S, M)
     state = {"k": k_pages, "v": v_pages, "wk": aux["wk"], "wv": aux["wv"],
              "conv": aux["conv"], "ssm": aux["ssm"]}
-    x, state, _loads = _layers(x, params, cfg, prec, positions,
-                               positions < length, attend, state, recur)
+    x, state, loads = _layers(x, params, cfg, prec, positions,
+                              positions < length, attend, state, recur)
     x = _norm(x, params, "final_ln", cfg)
     h_last = jnp.take(x[0], length - 1, axis=0)                # (M,)
     logits = _head(h_last[None], params, cfg, prec)            # (1, V)
     next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return (next_token, logits, state["k"], state["v"],
-            {k: state[k] for k in ("wk", "wv", "conv", "ssm")})
+            {k: state[k] for k in ("wk", "wv", "conv", "ssm")}) + loads
 
 
 def _paged_step(params, tokens, positions, block_tables, context_lens,
@@ -1001,7 +1284,8 @@ def _paged_step_hybrid(params, tokens, positions, block_tables,
         ids = jnp.take_along_axis(tables, safe_pos // bs, axis=1)
         return jnp.where(in_range, ids, 0).reshape(-1)  # overflow -> trash
 
-    def write(pages, li, ids, new):
+    def write(pages, li, ids, new, rows=None):
+        g, w = rows or cfg.kv_rows()
         new = new.reshape(B, g, w).astype(pages.dtype)
         if hm:
             # every (page, row, slot) named: the scatter's windows are the
@@ -1012,8 +1296,36 @@ def _paged_step_hybrid(params, tokens, positions, block_tables,
                             at[:, None]].set(new)
         return pages.at[li, ids, at].set(new)
 
+    def attend_mla(i, q, c, kr, st):
+        """Cache this token's latent and rotated key, then the ABSORBED
+        form, the published arithmetic re-associated: a head's query goes
+        through ``W_uk`` (``head_dim`` -> ``kv_rank``) and reads the cached
+        latents as they lie, all heads ONE row a token; the heads'
+        ``kv_rank``-wide results go through ``W_uv``."""
+        li = full_at[i]
+        ids = page_ids(block_tables)
+        vrows = cfg.v_rows()
+        st = dict(st, k=write(st["k"], li, ids, c),
+                  v=write(st["v"], li, ids, _pad_lanes(kr, vrows[1]), vrows))
+        dn, dv = cfg.head_dim, cfg.v_dim
+        # W_ukv by head, (H, dn + dv, kv_rank), taken WHOLE on both sides —
+        # the query's value lanes are zeros, the result's key lanes are
+        # dropped — so that no step slices (and so copies) the weight
+        w = params["layer%d_mla_kv_up_weight" % i].reshape(
+            cfg.num_heads, dn + dv, cfg.kv_rank)
+        qn, qr = (t[:, 0] for t in q)                   # (B, H, dn) / dr
+        qc = jnp.einsum("bhe,hec->bhc", _pad_lanes(qn, dn + dv), w,
+                        precision=prec)
+        out = latent_paged(qc, _pad_lanes(qr, vrows[1]), st["k"], st["v"],
+                           block_tables, context_lens[:, 0],
+                           mla_sm_scale(cfg), layer=li)  # (B, H, kv_rank)
+        out = jnp.einsum("bhc,hec->bhe", out, w, precision=prec)[..., dn:]
+        return out.reshape(B, 1, cfg.num_heads * dv), st
+
     def attend(i, q, k, v, st):
         kind = cfg.kinds()[i]
+        if kind == "mla":
+            return attend_mla(i, q, k, v, st)
         if kind == "swa":
             kp, vp, tables, li, window = ("wk", "wv", wtables, win_at[i],
                                           cfg.window)
@@ -1050,8 +1362,8 @@ def _paged_step_hybrid(params, tokens, positions, block_tables,
     x = _embed(params, tokens, safe_pos, cfg)                   # (B, 1, M)
     state = {"k": k_pages, "v": v_pages, "wk": aux["wk"], "wv": aux["wv"],
              "conv": aux["conv"], "ssm": aux["ssm"]}
-    x, state, _loads = _layers(x, params, cfg, prec, safe_pos, valid, attend,
-                               state, recur)
+    x, state, loads = _layers(x, params, cfg, prec, safe_pos, valid, attend,
+                              state, recur)
     x = _norm(x, params, "final_ln", cfg)
     logits = _head(x.reshape(B, cfg.model_dim), params, cfg,
                    prec).reshape(B, 1, -1)
@@ -1060,7 +1372,7 @@ def _paged_step_hybrid(params, tokens, positions, block_tables,
     logits = jnp.where(in_range[:, :, None], logits,
                        jnp.asarray(np.nan, logits.dtype))
     return (next_tokens, logits, state["k"], state["v"],
-            {k: state[k] for k in ("wk", "wv", "conv", "ssm")})
+            {k: state[k] for k in ("wk", "wv", "conv", "ssm")}) + loads
 
 
 def decode(params, tokens, positions, block_tables, context_lens,
@@ -1119,7 +1431,8 @@ def decode_chunk(params, tokens, positions, block_tables, context_lens,
     for a dead lane and for rows >= n —, ``logits (B, V)`` of each lane's
     last live step, ``k_pages, v_pages)`` and a fifth result as
     :func:`decode`: the experts' load PER STEP ``(chunk, L, E)``, or a
-    model with ``layer_kinds``' arrays."""
+    model with ``layer_kinds``' arrays (then the load, if it has experts
+    too, is a sixth)."""
     import jax
     import jax.numpy as jnp
 
@@ -1133,7 +1446,7 @@ def decode_chunk(params, tokens, positions, block_tables, context_lens,
                             v=v_pages)}
     if cfg.num_experts:
         state["loads"] = jnp.zeros(
-            (chunk, cfg.num_layers, cfg.num_experts), jnp.int32)
+            (chunk, cfg.expert_layers, cfg.num_experts), jnp.int32)
 
     def body(s):
         alive = s["left"] > 0
@@ -1154,7 +1467,7 @@ def decode_chunk(params, tokens, positions, block_tables, context_lens,
                  out=s["out"].at[s["j"]].set(live(nxt, -1)),
                  logits=live(logits, s["logits"]))
         if cfg.num_experts:
-            s["loads"] = s["loads"].at[s["j"]].set(rest[0])
+            s["loads"] = s["loads"].at[s["j"]].set(rest[-1])
         ended = ((nxt == eos) & (eos >= 0)) \
             | (s["positions"] + 1 >= cfg.max_len)
         return dict(s, j=s["j"] + 1, tokens=live(nxt, s["tokens"]),
@@ -1165,9 +1478,9 @@ def decode_chunk(params, tokens, positions, block_tables, context_lens,
     n = jnp.minimum(n, chunk)       # a row past the results is no row
     s = jax.lax.while_loop(lambda s: s["j"] < n, body, state)
     caches = s["caches"]
-    fifth = ({k: caches[k] for k in names},) if cfg.hybrid else \
-        ((s["loads"],) if cfg.num_experts else ())
-    return (s["out"], s["logits"], caches["k"], caches["v"]) + fifth
+    more = (({k: caches[k] for k in names},) if cfg.hybrid else ()) \
+        + ((s["loads"],) if cfg.num_experts else ())
+    return (s["out"], s["logits"], caches["k"], caches["v"]) + more
 
 
 def extend(params, tokens, positions, block_tables, context_lens,

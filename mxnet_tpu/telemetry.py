@@ -802,6 +802,10 @@ METRIC_HELP = {
     "serving.moe.pairs":
         "token-expert pairs the routed FFN computed (live tokens x "
         "experts per token x layers; nothing is dropped)",
+    "serving.moe.routed_pairs":
+        "every choice the router made for a live token, on an expert this "
+        "engine holds or not (= experts per token x layer_tokens, exactly; "
+        "pairs is the part computed here)",
     "serving.moe.layer_steps":
         "expert layers run: layers x programs (prefills and decode steps)",
     "serving.moe.layer_tokens":
@@ -814,6 +818,15 @@ METRIC_HELP = {
     "serving.moe.load_max_over_mean":
         "busiest expert's tokens over the mean expert's, averaged over "
         "the layers of the last step",
+    "serving.latent.ctx_tokens":
+        "cached tokens the latent decode kernel read: the live lanes' "
+        "context lengths summed over decode steps (x 'mla' layers = rows)",
+    "serving.latent.lane_steps":
+        "live lanes of a model with 'mla' layers summed over decode steps",
+    "serving.latent.live_blocks":
+        "latent-pool blocks the decode kernel walked, summed over steps",
+    "serving.latent.prefill_tokens":
+        "prompt+replay tokens whose latents a prefill cached",
     "serving.ssm.stream_steps":
         "decode state updates: live streams a decode step of a model with "
         "state-space layers (x its 'mamba' layers = slot updates)",

@@ -356,6 +356,111 @@ def test_hybrid_programs_leave_every_cache_in_place(v5e, name):
         assert " while(" in text
 
 
+def _latent_program(chip, name):
+    """``model.decode_chunk`` (B=128, eight rows) or ``model.prefill``
+    (S=2048) of dots.vlm1's block at published widths, cut to the leading
+    dense layer and ONE expert layer (16 of 256 experts held, the shared
+    expert), the latent pool of the benchmark's configuration (262,144
+    tokens in blocks of 128) donated, compiled for the chip. Returns
+    (compiled, the two page arrays' shapes)."""
+    from mxnet_tpu.serving import model as M
+
+    def s(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(tuple(shape), dt, sharding=chip)
+
+    bf, bs, layers = jnp.bfloat16, 128, 2
+    cfg = M.ModelConfig(
+        16160, layers, 7168, 128, 2048, 3072, norm="rms", pos="rope",
+        bias=False, head_dim=128, layer_kinds=["mla"] * layers,
+        ffn_gated=True, q_rank=1536, kv_rank=512, rope_dim=64, v_dim=128,
+        rope_yarn=(40, 4096, 32, 1, 1, 1), norm_eps=1e-6, first_dense=1,
+        dense_ffn_dim=18432, num_experts=256, experts_per_tok=8,
+        shared_experts=1, router="sigmoid_group", n_group=8, topk_group=4,
+        route_scale=2.5, experts_held=(0, 16))
+    assert (cfg.kv_rows(), cfg.v_rows()) == ((1, 512), (1, 128))
+    pages = {"k": s((layers, 2049, 1, bs, 512), bf),
+             "v": s((layers, 2049, 1, bs, 128), bf)}
+    # the kinds this model lacks keep their two-block stand-ins
+    stand_ins = (s((1, 2, 1, bs, 512), bf), s((1, 2, 1, bs, 128), bf),
+                 s((1, 2, 3 * cfg.d_inner), bf),
+                 s((1, 2, 16, cfg.d_inner), jnp.float32))
+    params = {k: s(v, bf) for k, v in M.param_shapes(cfg).items()}
+    aux = ("wk", "wv", "conv", "ssm")
+    nb = cfg.max_len // bs
+    if name == "chunk":
+        def fn(params, toks, poss, tables, ctx, left, eos, n, kp, vp, wt,
+               slots, *arrays):
+            return M.decode_chunk(
+                params, toks, poss, tables, ctx, left, eos, n, kp, vp, cfg,
+                8, dict(zip(aux, arrays), wtables=wt, slots=slots))
+        args = (s((128,)), s((128,)), s((128, nb)), s((128,)), s((128,)),
+                s((128,)), s(()))
+        more, donate = (s((128, nb)), s((128,))), (8, 9)
+    else:
+        def fn(params, toks, n, table, kp, vp, wt, slot, *arrays):
+            return M.prefill(params, toks, n, table, kp, vp, cfg,
+                             dict(zip(aux, arrays), wtable=wt, slot=slot))
+        args = (s((1, 2048)), s(()), s((2048 // bs,)))
+        more, donate = (s((2048 // bs,)), s(())), (4, 5)
+    compiled = jax.jit(fn, donate_argnums=donate).lower(
+        params, *args, pages["k"], pages["v"], *more, *stand_ins).compile()
+    return compiled, {k: v.shape for k, v in pages.items()}
+
+
+@pytest.mark.parametrize("name", ["chunk", "prefill"])
+def test_latent_programs_leave_the_pool_in_place(v5e, name):
+    """The latent format — one 512-lane row a token in ``k_pages``, the
+    rotary key's 128-lane row in ``v_pages``, head-major — is whole tiles:
+    no program copies or slices a page array or a layer of one, both are
+    donated and aliased, and the decode kernel and the grouped matmuls
+    are on the trace by their names. The prefill's temporaries are a
+    2,048-token prompt's (the sorted pairs' buffers are sized ``T x k``
+    though a sixteenth is used): with 9.1 GB of weights and the 1.7 GB
+    pool they fit the chip."""
+    compiled, shapes = _latent_program(v5e, name)
+    text = compiled.as_text()
+    assert len(re.findall(r"%gmm[.\d]* = ", text)) == 3    # one layer's
+    if name == "chunk":
+        assert len(re.findall(r"%latent_paged[.\d]* = ", text)) == 2
+        assert " while(" in text
+    else:       # the flash forward over expanded heads, keys 192 wide
+        assert text.count("tpu_custom_call") >= 5
+    for key in ("k", "v"):
+        assert _pool_copies(text, shapes[key]) == [], key
+        assert _entry_layouts(text, shapes[key]) == {"4,3,2,1,0"}
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= 2 * (math.prod(shapes["k"])
+                                          + math.prod(shapes["v"]))
+    assert ma.temp_size_in_bytes < (1536 << 20 if name == "prefill"
+                                    else 64 << 20)
+
+
+@pytest.mark.parametrize("bs", [64, 128, 256])
+def test_latent_kernel_compiles_for_v5e(v5e, bs):
+    """The block-size ladder's three rungs, 128 streams of 128 heads."""
+    def s(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+
+    n = 262144 // bs + 1
+    text = _compiled_text(
+        functools.partial(A._latent_pallas, sm_scale=0.135, layer=3),
+        s((128, 128, 512)), s((128, 128, 128)), s((5, n, 1, bs, 512)),
+        s((5, n, 1, bs, 128)), s((128, 3072 // bs), jnp.int32),
+        s((128,), jnp.int32))
+    assert len(re.findall(r"%latent_paged[.\d]* = ", text)) == 1
+
+
+def test_flash_forward_with_two_widths_compiles_for_v5e(v5e):
+    def s(d):
+        return jax.ShapeDtypeStruct((1, 128, 2048, d), jnp.bfloat16,
+                                    sharding=v5e)
+
+    text = _compiled_text(
+        functools.partial(A._pallas_forward, causal=True, sm_scale=0.135),
+        s(192), s(192), s(128))
+    assert "tpu_custom_call" in text
+
+
 def test_the_benchmarks_warm_up_call_warms_the_chunk_program():
     """``benchmark/drivers/serve.py`` warms a decode bucket with the plain
     step's call — ``eng._decode_fn`` with seven arguments, four results —

@@ -449,3 +449,102 @@ def test_ssm_kernels_are_their_xla_paths(dn):
     np.testing.assert_allclose(g[:, 1:], w[:, 1:], atol=1e-5, rtol=1e-5)
     assert (g[0] == np.asarray(state)[0]).all()         # the other layer
     assert (g[1, [2, 4, 5]] == np.asarray(state)[1, [2, 4, 5]]).all()
+
+
+# ------------------------------------------------------ the latent format
+def _latent_case(case, dtype, seed=0, B=4, H=8, C=128, R=128, bs=16, N=12,
+                 nb=4, L=2):
+    """Absorbed queries over a latent pool ``(L, N, 1, bs, C)`` /
+    ``(L, N, 1, bs, R)``; the rotary part fills 8 of R's lanes."""
+    rng = np.random.RandomState(seed)
+    qc, qr = rng.randn(B, H, C), np.zeros((B, H, R))
+    qr[..., :8] = rng.randn(B, H, 8)
+    cp, rp = rng.randn(L, N, 1, bs, C), np.zeros((L, N, 1, bs, R))
+    rp[..., :8] = rng.randn(L, N, 1, bs, 8)
+    bt = rng.randint(1, N, (B, nb)).astype(np.int32)
+    cl = {"boundaries": [1, bs, bs + 1, nb * bs],
+          "last-longest": [3, 17, 30, nb * bs],
+          "first-longest": [nb * bs, 40, 2, 1],
+          "padded-rows": [33, 1, 1, 1]}[case]
+    cast = lambda a: jnp.asarray(a, dtype)  # noqa: E731
+    return (cast(qc), cast(qr), cast(cp), cast(rp), jnp.asarray(bt),
+            jnp.asarray(np.asarray(cl, np.int32)))
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("case", ["boundaries", "last-longest",
+                                  "first-longest", "padded-rows"])
+def test_latent_kernel_is_the_reference(case, dtype):
+    """``latent_paged``'s Pallas kernel (interpret mode) against its XLA
+    path: streams that end on and just past a block boundary, the longest
+    first and last (the prefetch hands over between streams), padded rows
+    of one token; the whole pool with a layer index."""
+    dt, tol = DTYPES[dtype]
+    args = _latent_case(case, dt)
+    want = A.latent_paged_reference(*args, sm_scale=0.2, layer=1)
+    got = A._latent_pallas(*args, sm_scale=0.2, layer=1, interpret=True)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=max(tol, 2e-5) * 4, rtol=tol)
+    # one layer's 4-D pages: the same rows
+    one = A._latent_pallas(args[0], args[1], args[2][1], args[3][1],
+                           *args[4:], sm_scale=0.2, interpret=True)
+    np.testing.assert_array_equal(np.asarray(one), np.asarray(got))
+
+
+def test_latent_reference_is_dense_attention_over_the_cached_rows():
+    qc, qr, cp, rp, bt, cl = _latent_case("last-longest", jnp.float32)
+    got = np.asarray(A.latent_paged(qc, qr, cp, rp, bt, cl, 0.2, layer=0))
+    for i in range(qc.shape[0]):
+        rows = np.asarray(cp[0])[np.asarray(bt[i])].reshape(-1, 128)
+        keys = np.asarray(rp[0])[np.asarray(bt[i])].reshape(-1, 128)
+        n = int(cl[i])
+        s = (np.asarray(qc[i]) @ rows[:n].T
+             + np.asarray(qr[i]) @ keys[:n].T) * 0.2
+        p = np.exp(s - s.max(-1, keepdims=True))
+        want = (p / p.sum(-1, keepdims=True)) @ rows[:n]
+        np.testing.assert_allclose(got[i], want, atol=2e-5)
+
+
+def test_latent_pool_format():
+    """``k_pages`` one ``kv_rank``-wide row a token, ``v_pages`` the rotary
+    key's 128-lane row, both head-major (a block is the ``(bs, W)`` slab);
+    the bytes are the two arrays'."""
+    pool = KVBlockPool(5, 9, 16, 1, 512, dtype=jnp.bfloat16,
+                       prefix_cache=False, rows=(1, 512), v_rows=(1, 128))
+    assert pool.k_pages.shape == (5, 9, 1, 16, 512)
+    assert pool.v_pages.shape == (5, 9, 1, 16, 128)
+    assert pool.is_head_major and pool.page_rows == (1, 512)
+    assert pool.nbytes() == pool.k_pages.nbytes + pool.v_pages.nbytes \
+        == 5 * 9 * 16 * 640 * 2
+    assert pool.block_nbytes() == 5 * 16 * 640 * 2
+    cfg = smodel.ModelConfig(
+        97, 1, 64, 8, 32, 256, norm="rms", pos="rope", bias=False,
+        head_dim=128, layer_kinds=["mla"], q_rank=48, kv_rank=512,
+        rope_dim=64, v_dim=128)
+    assert cfg.kv_rows() == (1, 512) and cfg.v_rows() == (1, 128)
+    # every other pool keeps two arrays of one shape and its old bytes
+    plain = KVBlockPool(2, 3, 4, 16, 64)
+    assert plain.v_page_rows == plain.page_rows == (8, 128)
+    assert plain.nbytes() == 2 * plain.k_pages.size * 4
+
+
+@pytest.mark.parametrize("path", ["scan", "pallas"])
+def test_flash_forward_takes_a_key_width_other_than_its_value_width(path):
+    """Latent attention's expanded heads: keys 192 wide (128 + 64 rotary),
+    values 128: causal softmax(q k^T) v, by both lowerings."""
+    rng = np.random.RandomState(3)
+    q, k = (jnp.asarray(rng.randn(1, 2, 256, 192) * 0.3, jnp.float32)
+            for _ in range(2))
+    v = jnp.asarray(rng.randn(1, 2, 256, 128), jnp.float32)
+    if path == "scan":
+        got = A.flash_attention(q, k, v, True, 0.1)
+    else:
+        got, _lse = A._pallas_forward(q, k, v, True, 0.1, interpret=True)
+    s = np.einsum("bhqd,bhkd->bhqk", np.asarray(q), np.asarray(k)) * 0.1
+    s = np.where(np.tril(np.ones((256, 256), bool)), s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    want = np.einsum("bhqk,bhkd->bhqd", p / p.sum(-1, keepdims=True),
+                     np.asarray(v))
+    assert got.shape == (1, 2, 256, 128)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)

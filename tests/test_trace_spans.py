@@ -83,23 +83,44 @@ def serving_trace(tmp_path_factory):
     eng = ServingEngine(cfg, seed=3)
     eng.warmup()
     stop = threading.Event()
-    driver = threading.Thread(target=eng.run_loop, args=(stop,),
+    # an idle wait far longer than the fixture: an empty queue is ONE
+    # `serving.loop.idle` span, ended by a submit's or the stop's notify
+    driver = threading.Thread(target=eng.run_loop, args=(stop, 120.0),
                               name="driver")
+    bump, x = jax.jit(lambda x: x + 1), jax.numpy.zeros(8)
+    jax.block_until_ready(bump(x))                  # compiled, and the
+    jax.block_until_ready(eng.pool.k_pages)         # warm-up has ended
     window = trace_window(tmp_path_factory.mktemp("xplane"))
     window.start()
     try:
-        # a device op before the loop idles, whenever the warm-up's last
-        # program ended: the idle gap lies inside the traced device window
-        jax.block_until_ready(jax.numpy.zeros(8) + 1)
-        driver.start()
-        time.sleep(0.12)      # an empty queue first: serving.loop.idle
-        with eng._lock:       # all three before the driver's next step
+        # both Python threads' lines are named alike and the reducer keeps
+        # ONE of a name, the one with fewer events: this thread's must be
+        # the busier (the compile it used to do in here made it so)
+        for _ in range(400):
+            x = bump(x)
+        jax.block_until_ready(x)
+        with eng._lock:       # all three before the driver's first step
             reqs = [eng.submit(list(range(1, 6 + i)), N_NEW,
                                request_id="r%d" % i) for i in range(3)]
+        driver.start()
         for r in reqs:
             assert r.done_event.wait(60)
+        # the device idle under `serving.loop.idle` by construction, not by
+        # the warm-up's timing: the span opens before the loop parks on its
+        # condition, so once it waits there a device program a quarter of
+        # a second later ends a gap of which the span is all but the
+        # step's last microseconds
+        while not eng._work._waiters:
+            time.sleep(0.001)
+        time.sleep(0.25)
+        # a program of the engine's own: it runs on the client's thread,
+        # where the reducer looks for device ops (`bump` runs inline)
+        eng.warmup(prefill_buckets=[8])
+        jax.block_until_ready(eng.pool.k_pages)
     finally:
         stop.set()
+        with eng._work:
+            eng._work.notify_all()
         driver.join(60)
         window.stop()
     assert not driver.is_alive()

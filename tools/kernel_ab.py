@@ -142,3 +142,150 @@ def device_time_us_chained(body_fn, args, iters=30):
         shutil.rmtree(tmp, ignore_errors=True)
     per = {n: us / iters for n, us in totals.items() if not is_envelope(n)}
     return sum(per.values()), dict(sorted(per.items(), key=lambda kv: -kv[1]))
+
+
+# ---------------------------------------------------------------------------
+# python3 tools/kernel_ab.py --latent: the latent decode kernel against its
+# XLA path over the block-size ladder, and the flash forward at two widths
+# ---------------------------------------------------------------------------
+def latent_case(bs, ctx, streams=128, heads=128, rank=512, rope=64,
+                max_len=3072, seed=0):
+    """One layer's latent pages for ``streams`` streams of ``ctx`` cached
+    tokens in blocks of ``bs`` (distinct blocks, block 0 trash), and the
+    absorbed queries: ``(qc, qr, c_pages, r_pages, tables, context_lens)``
+    in bfloat16."""
+    import jax.numpy as jnp
+
+    key = jax.random.PRNGKey(seed)
+    per = -(-ctx // bs)
+    n = streams * per + 1
+    ks = jax.random.split(key, 4)
+    bf = jnp.bfloat16
+    c_pages = jax.random.normal(ks[0], (n, 1, bs, rank), bf)
+    r_pages = jnp.pad(jax.random.normal(ks[1], (n, 1, bs, rope), bf),
+                      ((0, 0), (0, 0), (0, 0), (0, 128 - rope)))
+    qc = jax.random.normal(ks[2], (streams, heads, rank), bf)
+    qr = jnp.pad(jax.random.normal(ks[3], (streams, heads, rope), bf),
+                 ((0, 0), (0, 0), (0, 128 - rope)))
+    tables = np.zeros((streams, max_len // bs), np.int32)
+    tables[:, :per] = 1 + np.arange(streams * per).reshape(streams, per)
+    return (qc, qr, c_pages, r_pages, jnp.asarray(tables),
+            jnp.full((streams,), ctx, jnp.int32))
+
+
+def latent_ladder(block_sizes=(64, 128, 256), contexts=(512, 2048),
+                  iters=10, xla=True, fetch_rows=(None,)):
+    """``latent_paged``'s kernel and its XLA path, chained (memory-bound):
+    one JSON-able row a rung with microseconds a call, the call's bytes
+    (``ctx`` rows of 1,280 B a stream) over the HBM peak's time, and the
+    two paths' largest difference."""
+    import functools
+
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import attention as A
+
+    rows = []
+    for bs in block_sizes:
+        for ctx in contexts:
+            qc, qr, cp, rp, bt, cl = latent_case(bs, ctx)
+
+            def body(path, i, qr, cp, rp, bt, cl, qc):
+                return path(qc * (1 + 1e-3 * i).astype(qc.dtype), qr, cp, rp,
+                            bt, cl, sm_scale=0.135)
+
+            row = {"block_size": bs, "ctx": ctx, "streams": qc.shape[0]}
+            def at_rows(r):
+                """The kernel traced with ``r`` rows a fetch (the module's
+                constant, for as long as the call traces)."""
+                def path(*a, **kw):
+                    was, A._LATENT_FETCH_ROWS = A._LATENT_FETCH_ROWS, r
+                    try:
+                        return A._latent_pallas(*a, **kw)
+                    finally:
+                        A._LATENT_FETCH_ROWS = was
+                return path
+
+            paths = [("kernel", A._latent_pallas) if r is None
+                     else ("kernel_%d" % r, at_rows(r)) for r in fetch_rows]
+            if xla:
+                paths.append(("xla", A.latent_paged_reference))
+            for name, path in paths:
+                us, _per = device_time_us_chained(
+                    functools.partial(body, path), (qr, cp, rp, bt, cl, qc),
+                    iters=iters)
+                row[name + "_us"] = round(us, 1)
+            nbytes = qc.shape[0] * ctx * 1280
+            row["hbm_peak_us"] = round(nbytes / 819e9 * 1e6, 1)
+            row["kernel_share_of_peak"] = round(
+                row["hbm_peak_us"] / row[paths[0][0] + "_us"], 3)
+            got = A._latent_pallas(qc, qr, cp, rp, bt, cl, sm_scale=0.135)
+            want = A.latent_paged_reference(qc, qr, cp, rp, bt, cl,
+                                            sm_scale=0.135)
+            row["max_diff"] = float(jnp.max(jnp.abs(
+                got.astype(jnp.float32) - want.astype(jnp.float32))))
+            rows.append(row)
+    return rows
+
+
+def flash_two_widths(seq=2048, heads=128, dk=192, dv=128, iters=5):
+    """The flash forward at latent attention's expanded heads (keys ``dk``,
+    values ``dv``): microseconds a call, kernel and XLA scan."""
+    import functools
+
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import attention as A
+
+    ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    bf = jnp.bfloat16
+    q, k = (jax.random.normal(kk, (1, heads, seq, dk), bf) for kk in ks[:2])
+    v = jax.random.normal(ks[2], (1, heads, seq, dv), bf)
+    out = {"seq": seq, "heads": heads, "dk": dk, "dv": dv}
+    for name, fn in (
+            ("kernel", functools.partial(A._pallas_forward, causal=True,
+                                         sm_scale=0.135)),
+            ("xla", functools.partial(A._scan_forward, causal=True,
+                                      sm_scale=0.135, block_k=256))):
+        us, _per = device_time_us(lambda q, k, v, fn=fn: fn(q, k, v)[0],
+                                  (q, k, v), iters=iters)
+        out[name + "_us"] = round(us, 1)
+    out["causal_flops"] = 2 * heads * seq * seq * (dk + dv) // 2
+    return out
+
+
+def main(argv=None):
+    import argparse
+
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--latent", action="store_true",
+                    help="latent_paged against its XLA path, the ladder")
+    ap.add_argument("--flash", action="store_true",
+                    help="the flash forward at keys 192 / values 128")
+    ap.add_argument("--no-xla", action="store_true")
+    ap.add_argument("--block-sizes", default="64,128,256")
+    ap.add_argument("--contexts", default="512,2048")
+    ap.add_argument("--fetch-rows", default=None,
+                    help="comma-separated rows a fetch to time the kernel "
+                         "at (default: the kernel's own)")
+    args = ap.parse_args(argv)
+    if args.latent:
+        ints = lambda t: tuple(int(v) for v in t.split(","))  # noqa: E731
+        for row in latent_ladder(
+                ints(args.block_sizes), ints(args.contexts),
+                xla=not args.no_xla,
+                fetch_rows=ints(args.fetch_rows) if args.fetch_rows
+                else (None,)):
+            print(json.dumps(dict(row, kab="latent_paged")), flush=True)
+    if args.flash:
+        print(json.dumps(dict(flash_two_widths(), kab="flash_forward")),
+              flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    sys.exit(main())

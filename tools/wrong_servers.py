@@ -1,0 +1,381 @@
+#!/usr/bin/env python3
+"""Wrong servers for a latent-attention, shared-expert configuration: plant
+ONE fault in the served program (or its weights), run the configuration's
+own dense probe over it, print what ``correct`` would compare.
+
+    python3 tools/wrong_servers.py --config benchmark/configs/dots-vlm1-ep16-bf16.json \\
+        --faults sound,float8_experts,zeroed_expert,no_shared --seed 7 [--gaps]
+
+Faults of the WEIGHTS share one process, one engine and one set of programs
+(the weights are altered in place after the reference has read them, and
+drawn anew afterwards); a fault of the PROGRAM is planted by patching a
+function of ``mxnet_tpu`` before the engine traces it, so give each its own
+process, which then runs WITHOUT the compile cache (the engine's AOT lane
+keys on the configuration: with the cache on, six patched servers read the
+sound server's numbers to the last digit; PERF.md section 6, PRs 31 and 33).
+Each result is one JSON line: the fault, the probe's numbers against the
+configuration module's three probe bands, and with ``--gaps`` the largest
+"same token" gap of one generated request against ``LOGIT_RTOL``.
+``tests/test_dotsvlm1_serving.py`` plants the same faults at a tiny size.
+
+With ``--cell`` the fault is planted under the HARNESS instead, and
+``benchmark/run.py`` runs the cell over it, once a seed of ``--seeds``: the
+comparison that reads ``correct`` false is then the driver's own, and the
+run's last line says so. A fault of the program runs with the engine's AOT lane
+off (it keys on the configuration); jax's own cache keys on a program's text,
+so a wrong program compiles and is found again by the next seed.
+``--probe-seeds`` are read afterwards by the probe alone, in the same
+process over the same planted programs (a run costs a window and its drain):
+
+    python3 tools/wrong_servers.py --cell dotsvlm1-chat-closed256 \\
+        --faults latent_not_written --seeds 11 --probe-seeds 12,13
+"""
+import argparse
+import contextlib
+import importlib.util
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+WEIGHTS = ("sound", "float8_all", "float8_experts", "zeroed_expert",
+           "no_shared")
+PROGRAM = ("no_group_limit", "no_renorm", "no_scale", "no_mscale",
+           "unrotated_key", "latent_before_norm", "latent_not_written")
+
+
+def load_config(path):
+    cfg = json.load(open(path))
+    here = os.path.dirname(os.path.abspath(path))
+    mod_path = os.path.join(here, cfg.get(
+        "module", os.path.basename(path)[:-len(".json")] + ".py"))
+    spec = importlib.util.spec_from_file_location("served_config", mod_path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return cfg, mod
+
+
+_MADE = {}
+
+
+def _made(cfg, mod, what):
+    """The configuration's probe or scorer, made (and compiled) once a
+    process: a change of ``init`` draws other weights for the same
+    programs."""
+    key = (what, json.dumps({k: cfg[k] for k in ("model", "reference")},
+                            sort_keys=True))
+    if key not in _MADE:
+        _MADE[key] = getattr(mod, what)(cfg)
+    return _MADE[key]
+
+
+def alter(name, *holders):
+    """Plant a fault of the weights in ``holders`` (dicts that hold the
+    same arrays), IN PLACE, one array at a time (a second copy of the held
+    experts does not fit beside the first at the published widths)."""
+    import jax.numpy as jnp
+
+    def change(key, fn):
+        new = fn(holders[0][key])
+        for d in holders:
+            d[key] = new
+
+    for key in list(holders[0]):
+        if (name == "float8_experts" and "_experts_" in key) or (
+                name == "float8_all" and key.endswith("_weight")):
+            # the nearest precision below the one the configuration states
+            change(key, lambda w: w.astype(jnp.float8_e4m3fn).astype(w.dtype))
+        if name == "zeroed_expert" and key.endswith("_experts_down_weight"):
+            change(key, lambda w: w.at[w.shape[0] // 2].set(0))  # one held
+        if name == "no_shared" and key.endswith("_shared_down_weight"):
+            change(key, jnp.zeros_like)
+
+
+@contextlib.contextmanager
+def planted(name, model):
+    """A fault of the program, for as long as the engine traces and runs.
+    ``model``: the configuration file's ``model`` object."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import moe
+    from mxnet_tpu.serving import model as M
+    from mxnet_tpu.serving.engine import ServingEngine
+
+    undo = []
+
+    def patch(owner, attr, new):
+        undo.append((owner, attr, getattr(owner, attr)))
+        setattr(owner, attr, new)
+
+    sound_route = moe.route
+    if name == "no_group_limit":        # plain top-k of s + b
+        patch(moe, "route", lambda x, router, k, **kw: sound_route(
+            x, router, k, **dict(kw, n_group=1, topk_group=1)))
+    elif name in ("no_renorm", "no_scale"):
+        def route(x, router, k, **kw):
+            w, e = sound_route(x, router, k, **kw)
+            if name == "no_scale":
+                return w / kw["scale"], e
+            s = jax.nn.sigmoid(jnp.dot(
+                x, router.T, preferred_element_type=jnp.float32))
+            return jnp.take_along_axis(s, e, axis=1) * kw["scale"], e
+        patch(moe, "route", route)
+    elif name == "no_mscale":
+        patch(M, "mla_sm_scale", lambda cfg: float(
+            cfg.head_dim + cfg.rope_dim) ** -0.5)
+    elif name == "unrotated_key":       # the one-head call is the key's
+        sound = M._rotate
+        patch(M, "_rotate", lambda x4, pos, inv: x4 if x4.shape[2] == 1
+              else sound(x4, pos, inv))
+    elif name == "latent_before_norm":  # in the decode program only
+        sound = M._rms_norm
+
+        def rms(x, gamma, eps=M._NORM_EPS):
+            if x.ndim == 3 and x.shape[1] == 1 \
+                    and x.shape[-1] == model["kv_rank"]:
+                return x
+            return sound(x, gamma, eps)
+        patch(M, "_rms_norm", rms)
+    elif name == "latent_not_written":  # every decode step's latent is lost
+        sound = ServingEngine._dispatch_decode
+
+        def dispatch(self, *a, **kw):
+            kept = jnp.copy(self.pool.k_pages)   # the argument is donated
+            out = sound(self, *a, **kw)
+            self.pool.k_pages = kept
+            return out
+        patch(ServingEngine, "_dispatch_decode", dispatch)
+    elif name not in WEIGHTS:
+        raise ValueError("no fault %r (weights: %s; program: %s)"
+                         % (name, WEIGHTS, PROGRAM))
+    try:
+        yield
+    finally:
+        for owner, attr, was in reversed(undo):
+            setattr(owner, attr, was)
+
+
+def refused_by(mod, seen):
+    """Which of the configuration module's probe bands refuse a probe's
+    numbers (``drivers/serve_share.py`` holds a run to the same three)."""
+    limits = (("quartile", seen["quartile"], mod.PROBE_RTOL),
+              ("median", seen["median"], mod.PROBE_MEDIAN_RTOL),
+              ("held_quartile", seen["held"]["quartile"],
+               mod.PROBE_HELD_RTOL))
+    return [what for what, got, band in limits if not got <= band]
+
+
+def reading(cfg, mod, params, name, seed, gaps=False, eng=None,
+            consume=False):
+    """The probe's numbers of an engine (``eng``, or one built here) with
+    fault ``name`` planted. The reference runs over the SOUND weights: a
+    fault of the weights is planted after the reference's rows are
+    computed, before the probe's first question to the engine. ``consume``:
+    ``params`` (drawn from ``seed``) may be altered in place and is drawn
+    anew afterwards; otherwise a copy of the dict is altered and
+    ``params`` keeps the sound arrays alive."""
+    import gc
+
+    import numpy as np
+
+    from mxnet_tpu.serving import ServingEngine
+
+    work = params if consume else dict(params)
+    with planted(name, cfg["model"]):
+        if eng is None:
+            eng = ServingEngine(mod.serving_config(cfg), arg_params=work,
+                                seed=seed)
+
+        def plant():
+            eng.params = work
+            alter(name, work)
+
+        seen = _made(cfg, mod, "make_probe")(work, eng, seed,
+                                             before_serving=plant)
+        refused = refused_by(mod, seen)
+        out = {"fault": name, "seed": seed, "band": mod.PROBE_RTOL,
+               "median_band": mod.PROBE_MEDIAN_RTOL,
+               "held_band": mod.PROBE_HELD_RTOL, "refused_by": refused,
+               "correct_by_probe": not refused}
+        out.update(seen)
+        tokens = None
+        if gaps:
+            rng = np.random.RandomState(seed % 2 ** 32)
+            prompt = [int(t) for t in rng.randint(
+                0, cfg["model"]["vocab"], 100)]
+            n = cfg["reference"]["gen_max"]
+            tokens = eng.generate([prompt], n)[0]
+    if consume and name in WEIGHTS and name != "sound":
+        eng.params = None
+        work.clear()
+        gc.collect()
+        work.update(mod.init_params(cfg, seed))
+        eng.params = work
+    if tokens is not None:
+        g = _made(cfg, mod, "make_reference").gaps(params, prompt, tokens)
+        out.update(gap_worst=float(g.max()), gap_median=float(np.median(g)),
+                   gap_top=[round(float(v), 4) for v in sorted(g)[-5:]],
+                   gap_over_band=int((g > mod.LOGIT_RTOL).sum()),
+                   gap_band=mod.LOGIT_RTOL, gap_tokens=len(tokens))
+    return out
+
+
+def through_run(name, cell, seeds, seconds, rehearsal=False,
+                probe_seeds=()):
+    """``benchmark/run.py`` over the cell with fault ``name`` planted, once
+    a seed of ``seeds``; then, in the same process and over the same
+    planted programs, the probe alone for each of ``probe_seeds`` (a run
+    costs a window and its drain, a probe does not). ``rehearsal``: the
+    cell of ``benchmark/rehearsal/``, on any platform."""
+    import gc
+    import io
+
+    from benchmark import run
+
+    manifest = run.load_json(
+        os.path.join(ROOT, "benchmark", "rehearsal", cell + ".json")
+        if rehearsal else os.path.join(ROOT, "BENCHMARK.json"))
+    entry = next(c for c in manifest["configs"]
+                 if c["name"] == run.find_cell(manifest, cell)["config"])
+    cfg_path = os.path.join(ROOT, entry["file"])
+    model = run.load_json(cfg_path)["model"]
+    if name in PROGRAM:
+        # the engine's AOT lane keys on the CONFIGURATION and would hand
+        # back a sound server's programs; jax's own cache keys on the
+        # program's text, so a wrong program compiles and a sound one loads
+        os.environ["MXNET_COMPILE_CACHE_AOT"] = "0"
+    load = run.load_module
+
+    def load_planted(path, modname):
+        """The configuration's module, its probe planting the fault of the
+        weights once the reference has read them."""
+        mod = load(path, modname)
+        make = mod.make_probe
+
+        def make_probe(cfg):
+            probe = make(cfg)
+            return lambda params, eng, seed: probe(
+                params, eng, seed,
+                before_serving=lambda: alter(name, params, eng.params))
+        mod.make_probe = make_probe
+        return mod
+
+    if name in WEIGHTS and name != "sound":
+        run.load_module = load_planted
+    try:
+        with planted(name, model):
+            for seed in seeds:
+                out = io.StringIO()
+                with contextlib.redirect_stdout(_Tee(sys.stdout, out)):
+                    run.main(["--workload", cell, "--seed", str(seed),
+                              "--seconds", str(seconds), "--trace", "0"]
+                             + ["--rehearsal"] * rehearsal)
+                last = json.loads(out.getvalue().strip().splitlines()[-1])
+                print(json.dumps({"fault": name, "seed": seed, "cell": cell,
+                                  "through": "benchmark/run.py",
+                                  "correct": last["correct"]}), flush=True)
+                gc.collect()        # an engine is a cycle: free its pool
+    finally:
+        run.load_module = load
+    if probe_seeds:
+        probes(name, cfg_path, probe_seeds)
+
+
+def probes(names, cfg_path, seeds, gaps=False, gains=None):
+    """The probe alone: one engine and one set of programs for every fault
+    of the weights in ``names`` and every seed (a fault of the program:
+    ``names`` is that one); a JSON line a reading."""
+    import jax
+
+    from mxnet_tpu.serving import ServingEngine
+
+    names = [names] if isinstance(names, str) else names
+    cfg, mod = load_config(cfg_path)
+    gains = gains or [cfg.get("init", {}).get("expert_gain", 1.0)]
+    eng = None
+    for gain, seed in ((g, s) for g in gains for s in seeds):
+        cfg["init"] = dict(cfg.get("init", {}), expert_gain=gain)
+        params = mod.init_params(cfg, seed)
+        jax.block_until_ready(params)
+        if eng is None:
+            with planted(names[0], cfg["model"]):
+                eng = ServingEngine(mod.serving_config(cfg),
+                                    arg_params=params, seed=seed)
+        eng.params = params
+        for name in names:
+            out = reading(cfg, mod, params, name, seed, gaps, eng=eng,
+                          consume=True)
+            print(json.dumps(dict(out, expert_gain=gain)), flush=True)
+        eng.params = None
+        params.clear()
+
+
+class _Tee:
+    def __init__(self, *streams):
+        self.streams = streams
+
+    def write(self, text):
+        for f in self.streams:
+            f.write(text)
+
+    def flush(self):
+        for f in self.streams:
+            f.flush()
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--config")
+    ap.add_argument("--cell", help="run the benchmark's cell of this name "
+                                   "over the fault, through benchmark/run.py")
+    ap.add_argument("--faults", default="sound")
+    ap.add_argument("--seed", type=int, default=7)
+    ap.add_argument("--seeds", default=None,
+                    help="comma-separated; each is read in turn")
+    ap.add_argument("--probe-seeds", default=None,
+                    help="with --cell: seeds read by the probe alone, after "
+                         "the runs, over the same planted programs")
+    ap.add_argument("--seconds", type=float, default=3.0,
+                    help="the window of a --cell run")
+    ap.add_argument("--rehearsal", action="store_true",
+                    help="with --cell: the cell of benchmark/rehearsal/")
+    ap.add_argument("--gaps", action="store_true")
+    ap.add_argument("--expert-gains", default=None,
+                    help="comma-separated init.expert_gain values to read "
+                         "the faults of the weights at (a study of the draw)")
+    args = ap.parse_args(argv)
+    names = args.faults.split(",")
+    seeds = [args.seed] if args.seeds is None \
+        else [int(x) for x in args.seeds.split(",") if x]
+    probe_seeds = [int(x) for x in (args.probe_seeds or "").split(",") if x]
+    if bool(args.cell) == bool(args.config):
+        raise SystemExit("give --config (the probe alone) or --cell (the "
+                         "harness's run)")
+    if set(names) & set(PROGRAM) or args.cell:
+        if len(names) > 1:
+            raise SystemExit("a fault of the program, and a run of the "
+                             "cell, needs a process of its own")
+    if args.cell:
+        through_run(names[0], args.cell, seeds, args.seconds,
+                    args.rehearsal, probe_seeds)
+        return 0
+    if set(names) & set(PROGRAM):
+        # and no compile cache: the engine's AOT lane keys on the
+        # CONFIGURATION and would hand back a sound server's programs (the
+        # chip's machine names a cache directory in the environment)
+        for var in ("JAX_COMPILATION_CACHE_DIR", "MXNET_COMPILE_CACHE_DIR"):
+            os.environ.pop(var, None)
+        from mxnet_tpu import compile_cache
+
+        compile_cache.disable()
+    probes(names, args.config, seeds, args.gaps,
+           [float(g) for g in args.expert_gains.split(",")]
+           if args.expert_gains else None)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
