@@ -333,6 +333,9 @@ class ServingEngine:
         # how often the chunk engages: device steps over dispatches
         self._decode_dispatches = 0
         self._decode_inner_steps = 0
+        # how often a step's prefills form a group: prompts over groups
+        self._prefill_prompts = 0
+        self._prefill_groups = 0
         self._t_started = time.time()
         self._tokens_total = 0
         # per-engine identity: labels this engine's histograms/counters in
@@ -709,10 +712,9 @@ class ServingEngine:
                     return failed
                 n_preempted = len(plan.preempted)
                 decodes = () if plan.prefills else self._decodable()
-            for req in plan.prefills:
-                # fwlint: disable=lock-order — fault.hit("dispatch_error") in the callee can inject a delay; real dispatch blocks under the step lock identically
-                self._run_prefill(req)
             if plan.prefills:
+                # fwlint: disable=lock-order — fault.hit("dispatch_error") in the callee can inject a delay; real dispatch blocks under the step lock identically
+                self._run_prefills(plan.prefills)
                 with self._schedule_span():
                     # a prompt that exactly filled its blocks writes its
                     # first decode token at a fresh block boundary — back
@@ -1211,7 +1213,34 @@ class ServingEngine:
         (self.window_pool.k_pages, self.window_pool.v_pages,
          self.state.conv, self.state.ssm) = aux
 
-    def _run_prefill(self, req):
+    def _run_prefills(self, reqs):
+        """Run the step's admitted prompts as ONE GROUP: every prompt's
+        program is dispatched, in plan order, before the first blocking
+        fetch. Prompt i+1's program queues behind prompt i's on the device
+        (the pages and a hybrid model's caches chain from one call into
+        the next, device to device), the host builds prompt i+1's arrays
+        while the device runs prompt i, and books prompt i while it runs
+        prompt i+1: a group of k exposes one host gap, after its last
+        program, where k dispatch-fetch pairs exposed k. Every first token
+        is on the host when this returns: nothing is in flight when the
+        step schedules its decode. A group of one is a dispatch and its
+        fetch, as a prompt alone always was."""
+        grouped = len(reqs) > 1
+        flights = [self._start_prefill(req, grouped) for req in reqs]
+        for flight in flights:
+            self._finish_prefill(*flight)
+        self._prefill_prompts += len(reqs)
+        self._prefill_groups += 1
+        telemetry.histogram("serving.prefill.group").observe(len(reqs))
+        telemetry.counter("serving.prefill.groups").inc()
+        telemetry.counter("serving.prefill.syncs_saved").inc(len(reqs) - 1)
+
+    def _start_prefill(self, req, grouped):
+        """Build and dispatch ``req``'s prefill; nothing of it is waited
+        for. Returns what :meth:`_finish_prefill` takes. ``grouped``:
+        other prompts' programs run before the fetch, so the first
+        token's copy to the host is asked for now, to start when the
+        program ends and not when the host gets round to it."""
         cfg = self.config
         replay = req.replay_tokens()
         L = len(replay)
@@ -1231,13 +1260,14 @@ class ServingEngine:
             if req.shared_blocks:
                 write_table = table.copy()
                 write_table[:min(req.shared_blocks, len(write_table))] = 0
-            # compile-tally delta around the dispatch: a bump means THIS
-            # call sat behind a cold prefill bucket — that wall is the
-            # request's compile_stall, not honest prefill time
-            jit = self._prefill_jits[S]
-            c0, s0 = jit.compile_totals()
-            s0 += self._draft_prefill_jits[S].compile_totals()[1] \
-                if self._spec else 0.0
+            # compile-tally delta around the dispatch CALL (jax compiles
+            # inside it): a bump means THIS call sat behind a cold prefill
+            # bucket — that wall is the request's compile_stall, not
+            # honest prefill time, and no part of it is the device time of
+            # the prompts queued before it
+            jits = [self._prefill_jits[S]] + (
+                [self._draft_prefill_jits[S]] if self._spec else [])
+            s0 = sum(j.compile_totals()[1] for j in jits)
             # chaos: injected dispatch failure — escapes step(), which
             # aborts the engine (the supervisor's restart trigger in the
             # chaos e2e)
@@ -1255,6 +1285,18 @@ class ServingEngine:
                     self._draft_params, toks, np.int32(L), write_table,
                     self._draft_kp, self._draft_vp)
                 self._draft_kp, self._draft_vp = dkp, dvp
+            if grouped:
+                tok.copy_to_host_async()
+        stall = min(sum(j.compile_totals()[1] for j in jits) - s0,
+                    time.time() - t0)
+        return req, replay, args, tok, t0, stall
+
+    def _finish_prefill(self, req, replay, args, tok, t0, stall):
+        """Fetch a started prefill's first token (the blocking sync: it
+        returns when THIS prompt's program has ended, whatever is queued
+        behind it) and book the request."""
+        cfg = self.config
+        L = len(replay)
         with telemetry.span("serving.prefill.fetch", _CAT, **args) as fetch:
             # the per-step token egress: serving's output IS this transfer
             tok, load = _unpack_fetch(np.asarray(tok), (1,), cfg)  # fwlint: disable=device-escape — token egress to the client is the product, one scalar per prefill (+ the experts' load in the same array)
@@ -1267,10 +1309,6 @@ class ServingEngine:
             if cfg.latent:
                 self._latent["prefill_tokens"] += L
                 telemetry.counter("serving.latent.prefill_tokens").inc(L)
-            c1, s1 = jit.compile_totals()
-            s1 += self._draft_prefill_jits[S].compile_totals()[1] \
-                if self._spec else 0.0
-            stall = min(s1 - s0, wall) if c1 > c0 or s1 > s0 else 0.0
             telemetry.histogram("serving.prefill_seconds").observe(wall)
             telemetry.counter("serving.prefill_tokens").inc(L)
             if self.streams is not None and self.streams.slots is not None:
@@ -1753,6 +1791,17 @@ class ServingEngine:
                     "steps_per_dispatch":
                         (self._decode_inner_steps / self._decode_dispatches)
                         if self._decode_dispatches else 0.0,
+                },
+                "prefill": {
+                    "prompts": self._prefill_prompts,
+                    "groups": self._prefill_groups,
+                    "prompts_per_group":
+                        (self._prefill_prompts / self._prefill_groups)
+                        if self._prefill_groups else 0.0,
+                    # blocking fetches that no longer expose a host gap:
+                    # every prompt of a group but its last
+                    "syncs_saved":
+                        self._prefill_prompts - self._prefill_groups,
                 },
                 # only for a model with window or state layers
                 **({"state": self._state_stats()}

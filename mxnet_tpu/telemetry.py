@@ -774,14 +774,28 @@ METRIC_HELP = {
     "serving.loop.idle": "driver thread's waits on an empty queue (span)",
     "serving.schedule": "engine step's scheduling section (span)",
     "serving.prefill.build": "prefill host-input build (span)",
-    "serving.prefill.dispatch": "prefill program call (span)",
-    "serving.prefill.fetch": "prefill first-token blocking fetch (span)",
+    "serving.prefill.dispatch":
+        "prefill program call; a group's dispatches precede its fetches "
+        "(span)",
+    "serving.prefill.fetch":
+        "prefill first-token blocking fetch, after the group's last "
+        "dispatch (span)",
+    "serving.prefill.group":
+        "prompts in a step's group of prefills (dispatched back to back, "
+        "fetched after the last dispatch); one observation a step that "
+        "admitted any",
+    "serving.prefill.groups": "groups of prefills run",
+    "serving.prefill.syncs_saved":
+        "prefill fetches that exposed no host gap of their own: a group's "
+        "prompts less one, summed",
     "serving.decode.build": "decode step host-input build (span)",
     "serving.decode.dispatch": "decode program call (span)",
     "serving.decode.fetch": "decode next-token blocking fetch (span)",
     "serving.retire":
         "token bookkeeping and retirement after a prefill or a step (span)",
-    "serving.prefill_seconds": "per-request prefill dispatch wall",
+    "serving.prefill_seconds":
+        "per-request prefill wall, own dispatch to the end of its fetch "
+        "(in a group: with what was left of the prompts queued before it)",
     "serving.prefill_tokens": "prompt+replay tokens prefilled",
     "serving.decode_batch":
         "live streams per fused decode step (one observation an inner "

@@ -109,7 +109,7 @@ class Capture:
         self.rows = {}
         now = {}
         prefill_fn, decode_fn = eng._prefill_fn, eng._decode_fn
-        run_prefill, run_decode = eng._run_prefill, eng._run_decode
+        start_prefill, run_decode = eng._start_prefill, eng._run_decode
 
         def _prefill_fn(params, toks, length, *rest):
             out = prefill_fn(params, toks, length, *rest)
@@ -124,16 +124,16 @@ class Capture:
                 self.rows[req.rid].append((int(ctx[i]), logits[i]))
             return out
 
-        def _run_prefill(req):
+        def _start_prefill(req, grouped):
             now["req"] = req
-            return run_prefill(req)
+            return start_prefill(req, grouped)
 
         def _run_decode(reqs):
             now["reqs"] = list(reqs)
             return run_decode(reqs)
 
         eng._prefill_fn, eng._decode_fn = _prefill_fn, _decode_fn
-        eng._run_prefill, eng._run_decode = _run_prefill, _run_decode
+        eng._start_prefill, eng._run_decode = _start_prefill, _run_decode
 
 
 def serve(eng, prompts, n_new):

@@ -670,6 +670,231 @@ def test_chunked_serving_equals_sequential_decoding(chunk):
     assert eng.pool.used() == 0
 
 
+# ---------------------------------------------------------------------------
+# a step's prefills are one group: dispatched back to back, then fetched
+# ---------------------------------------------------------------------------
+
+
+class _Tok:
+    """A prefill's token result that says when the host touched it."""
+
+    def __init__(self, calls, value):
+        self.calls, self.value = calls, value
+
+    def copy_to_host_async(self):
+        self.calls.append("copy")
+
+    def __array__(self, dtype=None, copy=None):
+        self.calls.append("fetch")
+        return np.asarray(self.value)
+
+
+@pytest.mark.parametrize("k", [1, 3])
+def test_a_groups_dispatches_all_precede_its_first_fetch(k):
+    """``k`` prompts admitted in one step: every prefill program is
+    dispatched (and, in a group, its token's copy to the host asked for)
+    before the first blocking fetch; the fetches follow in plan order, and
+    every request is DECODING with its first token before the decode
+    program is dispatched. A group of ONE is a dispatch and its fetch and
+    nothing else: the sequence of calls a prompt alone always was."""
+    eng = ServingEngine(_config(), seed=SEED)
+    calls = []
+    dispatch_prefill, dispatch_decode = (eng._dispatch_prefill,
+                                         eng._dispatch_decode)
+
+    def prefill(toks, length, *rest):
+        calls.append("dispatch")
+        tok, logits = dispatch_prefill(toks, length, *rest)
+        return _Tok(calls, tok), logits
+
+    def decode(*a, **kw):
+        calls.append("decode")
+        assert [r.state for r in reqs] == ["decoding"] * k
+        assert all(len(r.generated) == 1 for r in reqs)
+        return dispatch_decode(*a, **kw)
+
+    eng._dispatch_prefill, eng._dispatch_decode = prefill, decode
+    reqs = [eng.submit(list(range(1, 4 + i)), 3) for i in range(k)]
+    eng.step()
+    group = ["dispatch", "copy"] * k + ["fetch"] * k
+    assert calls == (group if k > 1 else ["dispatch", "fetch"]) + ["decode"]
+    # the first tokens are the prompts' own (plan order = fetch order)
+    alone = ServingEngine(_config(), seed=SEED)
+    assert [r.generated[0] for r in reqs] == [
+        alone.generate([list(r.prompt)], 1)[0][0] for r in reqs]
+
+
+def _family(name, **engine):
+    """A tiny ``ServingConfig`` of one of the four layer families, in
+    float32: GPT-2's block, routed experts (OLMoE's), ``layer_kinds`` with
+    window and state layers (Phi-4-mini-flash's), latent attention beside a
+    share of the experts (dots.vlm1's)."""
+    if name == "gpt2":
+        return _config(max_batch=4, **engine)
+    path = os.path.join(ROOT, "benchmark", "rehearsal", "configs",
+                        name + "-tiny.json")
+    with open(path) as f:
+        cfg = json.load(f)
+    cfg["engine"].update(kv_dtype="float32", **engine)
+    cfg["weights_dtype"] = "float32"
+    return ServingConfig.from_json(cfg)
+
+
+def _serve_spied(scfg, prompts, n_new, hog):
+    """Serve ``prompts`` from a pool with ``hog`` blocks left (None: all of
+    it); the requests, the engine and each step's group as
+    ``[(index of the prompt, is it a preemption's replay)]``."""
+    eng = ServingEngine(scfg, seed=SEED)
+    groups, run = [], eng._run_prefills
+
+    def spy(reqs):
+        groups.append([(r.rid, r.pending_token is not None) for r in reqs])
+        return run(reqs)
+
+    eng._run_prefills = spy
+    if hog is not None:
+        eng.pool.alloc(eng.pool.available() - hog)
+    reqs = [eng.submit(p, n) for p, n in zip(prompts, n_new)]
+    first = reqs[0].rid
+    while eng.has_work():
+        eng.step()
+    assert [r.state for r in reqs] == ["finished"] * len(reqs)
+    return eng, reqs, [[(rid - first, rp) for rid, rp in g] for g in groups]
+
+
+@pytest.mark.parametrize("pool", ["roomy", "tight"])
+@pytest.mark.parametrize("family",
+                         ["gpt2", "olmoe", "phi4flash", "dotsvlm1"])
+def test_grouped_prefills_serve_the_tokens_of_one_prompt_a_step(family,
+                                                                pool):
+    """Up to four prompts a step, their prefills dispatched back to back
+    and fetched after the last dispatch, against ``prefills_per_step=1``
+    (a dispatch and its fetch a step: the sequence before groups): the
+    tokens are the same to the bit, for each layer family. Two prompts
+    with a common two-block prefix are admitted in the SAME step (neither
+    can map the other's blocks: the index is read at admission and written
+    after the fetch), a third with that prefix later; in the tight pool
+    streams are preempted and their replays run inside a group."""
+    scfg = _family(family)
+    bs, rng = scfg.block_size, np.random.RandomState(5)
+    draw = lambda n: [int(t) for t in rng.randint(0, scfg.vocab_size, n)]
+    common = draw(2 * bs)
+    prompts = [draw(bs + 3), common + draw(5), common + draw(3), draw(9),
+               draw(bs - 1), common + draw(7)]
+    # the first and the fourth end early: the last two are admitted while
+    # both prompts of the prefix still hold their blocks
+    n_new = [bs, 3 * bs, 3 * bs, bs + 2, 2 * bs, 2 * bs]
+    hog = 14 if pool == "tight" else None
+    served = {}
+    for pps in (4, 1):
+        eng, reqs, groups = _serve_spied(
+            _family(family, prefills_per_step=pps), prompts, n_new, hog)
+        served[pps] = [list(r.generated) for r in reqs]
+        stats = eng.stats()["prefill"]
+        assert stats["prompts"] == sum(len(g) for g in groups)
+        assert stats["groups"] == len(groups)
+        assert stats["syncs_saved"] == stats["prompts"] - stats["groups"]
+        if pps == 1:
+            assert {len(g) for g in groups} == {1}
+            assert stats["prompts_per_group"] == 1.0
+            continue
+        # the first step admits the batch's four: both prompts of the
+        # common prefix among them, fresh, side by side
+        assert groups[0] == [(i, False) for i in range(4)]
+        assert stats["prompts_per_group"] > 1.5
+        replays = [g for g in groups if len(g) > 1
+                   and any(replay for _i, replay in g)]
+        if pool == "tight":
+            assert replays, groups
+            assert sum(r.preemptions for r in reqs) >= 1
+        elif eng.pool.prefix_stats()["enabled"]:
+            # the late third prompt of the prefix maps the two blocks the
+            # first of the group wrote (its own writes to them go to trash)
+            assert eng.pool.prefix_stats()["hit_blocks"] >= 2
+    assert served[4] == served[1]
+
+
+def test_a_dispatch_error_inside_a_group_fails_the_whole_group():
+    """``dispatch_error`` at the second of three starts: the first
+    prompt's program is already queued on donated pages, so the engine
+    aborts — all three requests failed, none DECODING, later submits
+    refused."""
+    from mxnet_tpu import fault
+
+    eng = ServingEngine(_config(), seed=SEED)
+    reqs = [eng.submit(list(range(1, 5 + i)), 4) for i in range(3)]
+    started, start = [], eng._start_prefill
+
+    def spy(req, grouped):
+        started.append(req)
+        return start(req, grouped)
+
+    eng._start_prefill = spy
+    with fault.inject("dispatch_error:raise=1,after=1,times=1"):
+        with pytest.raises(fault.InjectedFault):
+            eng.step()
+    assert started == reqs[:2]            # the third was never built
+    assert [r.state for r in reqs] == ["failed"] * 3
+    assert all("aborted" in r.error for r in reqs)
+    assert all(r.done_event.is_set() for r in reqs)
+    assert eng.aborted and not eng.has_work()
+    assert eng.stats()["prefill"]["groups"] == 0     # no group completed
+    with pytest.raises(RuntimeError, match="aborted"):
+        eng.submit([1], 1)
+
+
+def test_prefill_group_stats_by_hand():
+    """Five prompts over a batch of eight at four admissions a step: a
+    group of four, then a group of one. ``stats()["prefill"]``, the
+    histogram and the two counters say so."""
+    eng = ServingEngine(_config(), seed=SEED)
+    g0 = telemetry.totals("serving.prefill.group")
+    n0 = telemetry.counter("serving.prefill.groups").value
+    s0 = telemetry.counter("serving.prefill.syncs_saved").value
+    assert eng.stats()["prefill"] == {
+        "prompts": 0, "groups": 0, "prompts_per_group": 0.0,
+        "syncs_saved": 0}
+    eng.generate([[1 + i, 2, 3] for i in range(5)], 3)
+    assert eng.stats()["prefill"] == {
+        "prompts": 5, "groups": 2, "prompts_per_group": 2.5,
+        "syncs_saved": 3}
+    g1 = telemetry.totals("serving.prefill.group")
+    assert (g1[0] - g0[0], g1[1] - g0[1]) == (2, 5.0)
+    assert telemetry.counter("serving.prefill.groups").value - n0 == 2
+    assert telemetry.counter("serving.prefill.syncs_saved").value - s0 == 3
+    doc = open(os.path.join(ROOT, "docs", "observability.md")).read()
+    for name in ("serving.prefill.group", "serving.prefill.groups",
+                 "serving.prefill.syncs_saved"):
+        assert name in telemetry.METRIC_HELP
+        assert "`%s`" % name in doc
+
+
+def test_a_cold_buckets_compile_stall_is_its_own_requests_alone():
+    """Three prompts in one group, the middle one's prefill bucket cold:
+    the compile happens inside ITS dispatch call, after the first prompt
+    was dispatched and before it was fetched. The stall is charged to the
+    middle request alone, and never more than its own dispatch call."""
+    cfg = _config()
+    eng = ServingEngine(cfg, seed=SEED)
+    eng.warmup(prefill_buckets=[8])        # and every decode bucket
+    walls = []
+    start = eng._start_prefill
+
+    def timed(req, grouped):
+        t0 = time.time()
+        out = start(req, grouped)
+        walls.append(time.time() - t0)
+        return out
+
+    eng._start_prefill = timed
+    reqs = [eng.submit(list(range(1, 1 + n)), 2) for n in (5, 12, 6)]
+    eng.step()
+    stalls = [r.trace.phases["compile_stall"] for r in reqs]
+    assert stalls[0] == 0.0 and stalls[2] == 0.0
+    assert 0.0 < stalls[1] <= walls[1]
+    assert all(len(r.generated) >= 1 for r in reqs)
+
+
 def test_step_failure_aborts_not_strands():
     """A device error escaping step() must fail every pending request and
     wake its waiters — a silently dead driver thread would strand HTTP

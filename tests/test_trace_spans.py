@@ -214,6 +214,11 @@ def test_no_dispatch_before_the_previous_fetch_returned(serving_trace):
         "serving.decode.dispatch", "serving.decode.fetch"] * len(CHUNKS)
     for (_s0, e0, _n0), (s1, _e1, _n1) in zip(evs, evs[1:]):
         assert e0 <= s1
+    # nor before the step's group of prefills was fetched to its last: the
+    # first tokens are all on the host when the chunk is built
+    last_fetch = max(s + d for n, s, d, _ in serving_trace["driver"]
+                     if n == "serving.prefill.fetch")
+    assert last_fetch <= evs[0][0]
     dec = serving_trace["stats"]["decode"]
     assert dec == {"dispatches": len(CHUNKS), "inner_steps": N_NEW - 1,
                    "steps_per_dispatch": (N_NEW - 1) / len(CHUNKS)}
@@ -221,6 +226,31 @@ def test_no_dispatch_before_the_previous_fetch_returned(serving_trace):
         assert name in telemetry.METRIC_HELP
         assert "`%s`" % name in open(
             os.path.join(ROOT, "docs", "observability.md")).read()
+
+
+def test_a_groups_dispatches_precede_its_fetches(serving_trace):
+    """The three prompts were admitted in one step: their prefills are one
+    group. On the trace the step reads `build, dispatch` x 3 and then
+    `fetch, retire` x 3, each half in plan order: every dispatch has
+    returned before the first blocking fetch starts, so one host gap is
+    exposed to the device where three were."""
+    evs = sorted((s, s + d, n, st.get("request_id"))
+                 for n, s, d, st in serving_trace["driver"]
+                 if n.startswith("serving.prefill.")
+                 or (n == "serving.retire" and "request_id" in st))
+    rids = ["r0", "r1", "r2"]
+    assert [(n, rid) for _s, _e, n, rid in evs] == [
+        (n, rid) for rid in rids
+        for n in ("serving.prefill.build", "serving.prefill.dispatch")] + [
+        (n, rid) for rid in rids
+        for n in ("serving.prefill.fetch", "serving.retire")]
+    dispatched = max(e for _s, e, n, _r in evs
+                     if n == "serving.prefill.dispatch")
+    assert dispatched <= min(s for s, _e, n, _r in evs
+                             if n == "serving.prefill.fetch")
+    assert serving_trace["stats"]["prefill"] == {
+        "prompts": 3, "groups": 1, "prompts_per_group": 3.0,
+        "syncs_saved": 2}
 
 
 def test_paged_counters_count_the_blocks_the_kernel_walks(serving_trace):
