@@ -37,13 +37,19 @@ from ..analysis import witness
 from ..base import env_bool, env_int, env_str
 from . import model as _model
 from .kv_cache import KVBlockPool
-from .obs import ServingObs
+from .obs import ServingObs, open_record
 from .resilience import ServingOverloadError, retry_after_s
 from .scheduler import (CANCELLED, DECODING, FAILED, FINISHED, TIMED_OUT,
                         WAITING, Request, Scheduler)
 
 _SITE = "serving/engine.py"
 _CAT = "serving"   # the chrome-trace category of the engine's spans
+
+
+def _no_clock():
+    """The clock of a step that keeps no record (``ServingEngine._clock``)."""
+    return 0.0
+
 
 _engine_ids = itertools.count()
 
@@ -336,6 +342,15 @@ class ServingEngine:
         # how often a step's prefills form a group: prompts over groups
         self._prefill_prompts = 0
         self._prefill_groups = 0
+        # the loop's record of the step under way (obs.LoopRecord's fields;
+        # None outside a step and while telemetry is off) and what its two
+        # gaps are measured from: the host's clock at the last blocking
+        # fetch's return, the seconds under serving.loop.idle since, and
+        # which gap the next dispatch closes ("chunk", "group" or None)
+        self._rec = None
+        self._fetch_end = None
+        self._idle_s = 0.0
+        self._gap_after = None
         self._t_started = time.time()
         self._tokens_total = 0
         # per-engine identity: labels this engine's histograms/counters in
@@ -688,11 +703,22 @@ class ServingEngine:
     def _step(self):
         """The step's sections, each under its span (docs/observability.md
         has the table): lock, schedule, prefills, schedule again after a
-        prefill, decode, retire."""
-        with telemetry.span("serving.step.lock", _CAT):
+        prefill, decode, retire. While telemetry is on, a non-empty step
+        also leaves ONE record of itself (``obs.LoopRecord``: each
+        section's seconds from its span, the two host gaps) in the
+        engine's ring and on its ``serving.step_timeline`` event."""
+        with telemetry.span("serving.step.lock", _CAT) as lock:
             self._lock.acquire()
         try:
-            with self._schedule_span():
+            # opened with the lock held: a window over the records' ``ts``
+            # and two reads of stats() (under this lock) cut the steps at
+            # the same places
+            rec = self._rec = open_record() if telemetry.enabled() else None
+            if rec is None:
+                self._fetch_end = None   # no gap across a stretch unrecorded
+            self._gap_after = "chunk"
+            self._book("lock_s", lock)
+            with self._schedule_span() as sched:
                 # chaos: injected per-step latency (trips deadlines/SLOs
                 # without faking clocks) — docs/fault_tolerance.md
                 # fwlint: disable=lock-order — the injected delay models a slow device dispatch, which blocks under the step lock by design
@@ -712,10 +738,11 @@ class ServingEngine:
                     return failed
                 n_preempted = len(plan.preempted)
                 decodes = () if plan.prefills else self._decodable()
+            self._book("schedule_s", sched)
             if plan.prefills:
                 # fwlint: disable=lock-order — fault.hit("dispatch_error") in the callee can inject a delay; real dispatch blocks under the step lock identically
                 self._run_prefills(plan.prefills)
-                with self._schedule_span():
+                with self._schedule_span() as sched:
                     # a prompt that exactly filled its blocks writes its
                     # first decode token at a fresh block boundary — back
                     # that slot with a real block NOW or the write lands in
@@ -726,6 +753,7 @@ class ServingEngine:
                     n_preempted += len(late)
                     failed += self._drain_failed()
                     decodes = self._decodable()
+                self._book("schedule_s", sched)
             if decodes and self._spec:
                 # fwlint: disable=lock-order — injected dispatch fault may stall; matches real device-dispatch blocking under the step lock
                 fetched = self._run_spec_decode(decodes)
@@ -733,29 +761,87 @@ class ServingEngine:
                 # fwlint: disable=lock-order — injected dispatch fault may stall; matches real device-dispatch blocking under the step lock
                 fetched = self._run_decode(decodes)
             with telemetry.span("serving.retire", _CAT) as retire:
-                if decodes and self._spec:
-                    self._note_spec_decode(decodes, fetched)
-                elif decodes:
-                    retire.set(**self._note_decode(decodes, fetched))
-                finished = [r for r in list(self.scheduler.running)
-                            if r.finished()]
-                for req in finished:
-                    self.scheduler.finish(req)
-                    self._retire(req)
+                if decodes:
+                    if self._spec:
+                        noted = self._note_spec_decode(decodes, fetched)
+                    else:
+                        noted = self._note_decode(decodes, fetched)
+                    retire.set(**noted)
+                    if rec is not None:
+                        rec["chunk_steps"] = noted.pop("steps")
+                        rec.update(noted)
+                # one stretch, so a span of its own: the trace and the
+                # idle gaps' labels name it too
+                with telemetry.span("serving.retire.finish", _CAT) as finish:
+                    finished = [r for r in list(self.scheduler.running)
+                                if r.finished()]
+                    for req in finished:
+                        self.scheduler.finish(req)
+                        self._retire(req)
+                self._book("retire_finish_s", finish)
                 self._steps += 1
+                clock = self._clock()
+                t0 = clock()
                 self._refresh_throughput()
-                self.obs.step_timeline(
-                    step=self._steps, occupancy=len(decodes),
-                    admitted=len(plan.prefills), preempted=n_preempted,
-                    finished=len(finished) + len(failed),
-                    queue=len(self.scheduler.waiting),
-                    running=len(self.scheduler.running),
-                    kv_used=self.pool.used(), kv_free=self.pool.available(),
-                    kv_frag_slots=self.scheduler.frag_slots())
-                retire.set(finished=len(finished) + len(failed))
-                return finished + failed
+                n_finished = len(finished) + len(failed)
+                retire.set(finished=n_finished)
+                if rec is not None:
+                    rec["retire_counters_s"] += clock() - t0
+            # the record is closed after the counters are booked and its
+            # last section has ended, under the same lock: what it costs
+            # (obs.step_timeline: the tuple, the sums, the event) is in the
+            # NEXT step's gap_chunk_s and in no section of this one
+            self._book("retire_s", retire)
+            if rec is not None:
+                rec.update(step=self._steps, prefills=len(plan.prefills),
+                           lanes=len(decodes), finished=n_finished)
+            self.obs.step_timeline(
+                rec, occupancy=len(decodes), admitted=len(plan.prefills),
+                preempted=n_preempted, queue=len(self.scheduler.waiting),
+                running=len(self.scheduler.running),
+                kv_used=self.pool.used(), kv_free=self.pool.available(),
+                kv_frag_slots=self.scheduler.frag_slots())
+            return finished + failed
         finally:
+            self._rec = None
             self._lock.release()
+
+    # ---- the step's record (obs.LoopRecord) ---------------------------
+    def _book(self, field, span):
+        """Add a closed span's seconds to the step's record."""
+        rec = self._rec
+        if rec is not None and span.seconds is not None:
+            rec[field] += span.seconds
+
+    def _clock(self):
+        """``time.perf_counter`` while the step keeps a record, else a
+        clock that reads nothing: telemetry off costs no clock read."""
+        return time.perf_counter if self._rec is not None else _no_clock
+
+    def _fetched(self):
+        """A blocking fetch has just returned: nothing is in flight, and
+        the device idles from here until the next dispatch call returns.
+        Stale the day a fetch is made late (obs.LoopRecord)."""
+        if self._rec is not None:
+            self._fetch_end = time.perf_counter()
+            self._idle_s = 0.0
+
+    def _dispatched(self, span):
+        """A dispatch call has just returned, inside its open ``span``. If
+        it closes one of the step's two host gaps (the step's first
+        dispatch: the gap since the step before's last fetch, less the
+        loop's idle waits; the chunk's dispatch after a group of prefills:
+        the gap since the group's last fetch), book the gap and say on the
+        span where the host saw it end."""
+        rec, after = self._rec, self._gap_after
+        if rec is None or after is None:
+            return
+        self._gap_after = None
+        if self._fetch_end is None:
+            return      # nothing was fetched before: an engine's first step
+        gap = time.perf_counter() - self._fetch_end - self._idle_s
+        rec["gap_%s_s" % after] = gap
+        span.set(gap_us=int(gap * 1e6), after=after)
 
     def _schedule_span(self):
         return telemetry.span("serving.schedule", _CAT,
@@ -795,8 +881,10 @@ class ServingEngine:
                     self._refresh_throughput()
                     # an empty queue, by name: device idle under this span
                     # is idle that no change to the loop can take away
-                    with telemetry.span("serving.loop.idle", _CAT):
+                    with telemetry.span("serving.loop.idle", _CAT) as idle:
                         self._work.wait(timeout=idle_wait_s)
+                    # no fault of the loop's: out of the step's gap_chunk_s
+                    self._idle_s += idle.seconds or 0.0
                     if not self.scheduler.has_work():
                         continue
             self.step()
@@ -1229,6 +1317,8 @@ class ServingEngine:
         flights = [self._start_prefill(req, grouped) for req in reqs]
         for flight in flights:
             self._finish_prefill(*flight)
+        # the chunk's dispatch closes the gap the group's last fetch opened
+        self._gap_after = "group"
         self._prefill_prompts += len(reqs)
         self._prefill_groups += 1
         telemetry.histogram("serving.prefill.group").observe(len(reqs))
@@ -1246,7 +1336,7 @@ class ServingEngine:
         L = len(replay)
         S = _bucket_for(L, cfg.prefill_buckets())
         args = {"request_id": req.request_id, "prompt_len": L, "bucket": S}
-        with telemetry.span("serving.prefill.build", _CAT, **args):
+        with telemetry.span("serving.prefill.build", _CAT, **args) as build:
             toks = np.zeros((1, S), np.int32)
             toks[0, :L] = replay
             table = self._table_row(req.blocks, S // cfg.block_size)
@@ -1272,10 +1362,13 @@ class ServingEngine:
             # aborts the engine (the supervisor's restart trigger in the
             # chaos e2e)
             fault.hit("dispatch_error")
+        self._book("prefill_build_s", build)
         t0 = time.time()
-        with telemetry.span("serving.prefill.dispatch", _CAT, **args):
+        with telemetry.span("serving.prefill.dispatch", _CAT,
+                            **args) as dispatch:
             tok, _logits = self._dispatch_prefill(toks, L, write_table,
                                                   wtable, req.slot or 0)
+            self._dispatched(dispatch)
             if self._spec:
                 # the draft caches the same replay through the same write
                 # table into its OWN pages (its K/V never mixes with the
@@ -1287,6 +1380,7 @@ class ServingEngine:
                 self._draft_kp, self._draft_vp = dkp, dvp
             if grouped:
                 tok.copy_to_host_async()
+        self._book("prefill_dispatch_s", dispatch)
         stall = min(sum(j.compile_totals()[1] for j in jits) - s0,
                     time.time() - t0)
         return req, replay, args, tok, t0, stall
@@ -1299,12 +1393,15 @@ class ServingEngine:
         L = len(replay)
         with telemetry.span("serving.prefill.fetch", _CAT, **args) as fetch:
             # the per-step token egress: serving's output IS this transfer
-            tok, load = _unpack_fetch(np.asarray(tok), (1,), cfg)  # fwlint: disable=device-escape — token egress to the client is the product, one scalar per prefill (+ the experts' load in the same array)
+            tok = np.asarray(tok)  # fwlint: disable=device-escape — token egress to the client is the product, one scalar per prefill (+ the experts' load in the same array)
+            self._fetched()
+            tok, load = _unpack_fetch(tok, (1,), cfg)
             tok = int(tok[0])
             fetch.set(**_moe_args(load, self.config))
+        self._book("prefill_fetch_s", fetch)
         wall = time.time() - t0
         with telemetry.span("serving.retire", _CAT,
-                            request_id=req.request_id):
+                            request_id=req.request_id) as retire:
             self._note_moe(load, L)
             if cfg.latent:
                 self._latent["prefill_tokens"] += L
@@ -1328,6 +1425,7 @@ class ServingEngine:
             # produced (greedy replay recomputes the same cache;
             # tok == pending_token)
             self.obs.prefill_done(req, stall, was_replay)
+        self._book("prefill_retire_s", retire)
 
     def _run_decode(self, reqs):
         """Build, dispatch and fetch one CHUNK of fused decode steps: up
@@ -1373,19 +1471,25 @@ class ServingEngine:
             jit = self._decode_jits[B]
             c0, s0 = jit.compile_totals()
             fault.hit("dispatch_error")
+        self._book("decode_build_s", build)
         t0 = time.time()
-        with telemetry.span("serving.decode.dispatch", _CAT, **args):
+        with telemetry.span("serving.decode.dispatch", _CAT,
+                            **args) as dispatch:
             nxt, _logits = self._dispatch_decode(
                 toks, poss, tables, ctx, wtables, slots, left=left, eos=eos,
                 n=n)
+            self._dispatched(dispatch)
+        self._book("decode_dispatch_s", dispatch)
         with telemetry.span("serving.decode.fetch", _CAT, **args) as fetch:
             # the chunk's single device->host sync: its rows of next
             # tokens (with the experts' load of each step behind them,
             # where there are experts). Nothing is in flight after it
             fetched = np.asarray(nxt)  # fwlint: disable=device-escape — token egress to clients is the product, chunk x B int32s per dispatch
+            self._fetched()
             nxt, load = _unpack_fetch(fetched, (self._chunk, B), cfg,
                                       (self._chunk,))
             fetch.set(**_moe_args(load, self.config))
+        self._book("decode_fetch_s", fetch)
         wall = time.time() - t0
         c1, s1 = jit.compile_totals()
         if c1 > c0:
@@ -1398,15 +1502,21 @@ class ServingEngine:
         its death: its ``steps_left`` used up or its EOS) in
         ``serving.decode_batch``, the blocks and states they walked, the
         experts' load of that step against those lanes. Returns the
-        chunk's sums, for the step's ``serving.retire`` span."""
+        chunk's sums, for the step's ``serving.retire`` span and its
+        record, whose two interleaved parts are timed here (two clock reads
+        an inner step): booking the counters, and delivering the tokens
+        (the rest of the loop: the ``live`` list, ``_note_token``)."""
         nxt, load, left, n = fetched
         cfg = self.config
         noted = {"steps": n, "lane_steps": 0, "live_blocks": 0}
+        clock = self._clock()
+        counters, t_in = 0.0, clock()
         for j in range(n):
             live = [(i, req) for i, req in enumerate(reqs)
                     if j < left[i] and req.state == DECODING]
             if not live:
                 break       # every lane met its EOS: the rest ran dead
+            t0 = clock()
             ctx = np.array([req.context_len + 1 for _i, req in live])  # fwlint: disable=device-escape — host integers, nothing of the device's
             blocks = self._note_paged(ctx)
             noted["lane_steps"] += len(live)
@@ -1419,9 +1529,13 @@ class ServingEngine:
             if load is not None:
                 self._note_moe(load[j], int((ctx <= cfg.max_len).sum()))
             telemetry.histogram("serving.decode_batch").observe(len(live))
+            counters += clock() - t0
             for i, req in live:
                 req.context_len += 1
                 self._note_token(req, int(nxt[j, i]))
+        if self._rec is not None:
+            self._rec["retire_counters_s"] += counters
+            self._rec["retire_tokens_s"] += clock() - t_in - counters
         self._decode_dispatches += 1
         self._decode_inner_steps += n
         telemetry.counter("serving.decode.dispatches").inc()
@@ -1483,7 +1597,7 @@ class ServingEngine:
                 "live_blocks": self._note_paged(np.minimum(
                     np.add(base_ctx, k + 1), cfg.max_len))}
         with telemetry.span("serving.decode.build", _CAT, phase="draft",
-                            **args):
+                            **args) as build:
             tables = np.zeros((B, nb), np.int32)
             for i, req in enumerate(reqs):
                 tables[i] = self._table_row(req.blocks, nb)
@@ -1494,11 +1608,14 @@ class ServingEngine:
             djit = self._draft_decode_jits[B]
             c0, s0 = djit.compile_totals()
             fault.hit("dispatch_error")
+        self._book("decode_build_s", build)
         t0 = time.time()
         # one span over the k+1 inner steps: each proposal's fetch steers
-        # the next dispatch, so the two cannot be told apart per step
+        # the next dispatch, so the two cannot be told apart per step (the
+        # step's record books them all as dispatch; the gap before them
+        # ends at the first call's return)
         with telemetry.span("serving.decode.dispatch", _CAT, phase="draft",
-                            **args):
+                            **args) as dispatch:
             for j in range(k + 1):
                 toks = cur.copy()
                 poss = np.zeros(B, np.int32)
@@ -1510,6 +1627,7 @@ class ServingEngine:
                     self._draft_params, toks, poss, tables, ctx,
                     self._draft_kp, self._draft_vp)
                 self._draft_kp, self._draft_vp = dkp, dvp
+                self._dispatched(dispatch)     # the first call's, if any
                 if j < k:
                     # the proposal steers the NEXT inner step's input
                     # token — an unavoidable per-draft-step sync, B int32s
@@ -1517,6 +1635,7 @@ class ServingEngine:
                     for i in range(n):
                         proposals[i].append(int(dnxt[i]))
                         cur[i] = dnxt[i]
+        self._book("decode_dispatch_s", dispatch)
         draft_wall = time.time() - t0
         c1, s1 = djit.compile_totals()
         draft_stall = min(s1 - s0, draft_wall) if c1 > c0 else 0.0
@@ -1525,7 +1644,7 @@ class ServingEngine:
         # greedy argmax is the token the stream emits if lane j is reached
         T = k + 1
         with telemetry.span("serving.decode.build", _CAT, phase="verify",
-                            **args):
+                            **args) as build:
             toks2 = np.zeros((B, T), np.int32)
             poss2 = np.zeros((B, T), np.int32)
             ctx2 = np.ones((B, T), np.int32)
@@ -1538,17 +1657,22 @@ class ServingEngine:
                     ctx2[i, j] = base_ctx[i] + j + 1
             vjit = self._verify_jits[B]
             c0, s0 = vjit.compile_totals()
+        self._book("decode_build_s", build)
         t0 = time.time()
         with telemetry.span("serving.decode.dispatch", _CAT, phase="verify",
-                            **args):
+                            **args) as dispatch:
             nxt2, _logits, kp, vp = self._verify_fn(
                 self.params, toks2, poss2, tables, ctx2,
                 self.pool.k_pages, self.pool.v_pages)
             self.pool.k_pages, self.pool.v_pages = kp, vp
+        self._book("decode_dispatch_s", dispatch)
         with telemetry.span("serving.decode.fetch", _CAT, phase="verify",
                             **args) as fetch:
-            nxt2, load = _unpack_fetch(np.asarray(nxt2), (B, T), cfg)  # fwlint: disable=device-escape — token egress to clients is the product, B×(k+1) int32s per step
+            nxt2 = np.asarray(nxt2)  # fwlint: disable=device-escape — token egress to clients is the product, B×(k+1) int32s per step
+            self._fetched()
+            nxt2, load = _unpack_fetch(nxt2, (B, T), cfg)
             fetch.set(**_moe_args(load, self.config))
+        self._book("decode_fetch_s", fetch)
         verify_wall = time.time() - t0
         c1, s1 = vjit.compile_totals()
         verify_stall = min(s1 - s0, verify_wall) if c1 > c0 else 0.0
@@ -1557,21 +1681,26 @@ class ServingEngine:
         self._spec_draft_s += draft_wall
         self._spec_verify_s += verify_wall
         return (nxt2, proposals, draft_wall - draft_stall,
-                verify_wall - verify_stall, load)
+                verify_wall - verify_stall, load, args["live_blocks"])
 
     def _note_spec_decode(self, reqs, fetched):
         """Greedy acceptance — emit the TARGET's token at every reached
         lane. Lane j+1 is reached only if the draft's proposal d_{j+1}
         MATCHED the target's lane-j output (the window's K/V past a
         mismatch encodes the draft's wrong token, so stop there; the
-        stale writes are overwritten by the next step's lane 0)."""
-        nxt2, proposals, draft_s, verify_s, load = fetched
+        stale writes are overwritten by the next step's lane 0). Returns
+        what :meth:`_note_decode` returns, the window one step of a chunk,
+        and times the record's same two parts."""
+        nxt2, proposals, draft_s, verify_s, load, live_blocks = fetched
         k = self.spec_k
+        clock = self._clock()
+        t_in = clock()
         if load is not None:
             self._note_moe(load, sum(
                 min(k + 1, max(0, self.config.max_len - req.context_len))
                 for req in reqs))
         proposed = accepted = 0
+        t_tokens = clock()
         for i, req in enumerate(reqs):
             proposed += k
             for j in range(k + 1):
@@ -1584,10 +1713,17 @@ class ServingEngine:
                         or proposals[i][j] != tok:
                     break
                 accepted += 1
+        t_out = clock()
         self._spec_proposed += proposed
         self._spec_accepted += accepted
         telemetry.histogram("serving.decode_batch").observe(len(reqs))
         self.obs.spec_step(reqs, draft_s, verify_s, proposed, accepted)
+        if self._rec is not None:
+            self._rec["retire_tokens_s"] += t_out - t_tokens
+            self._rec["retire_counters_s"] += (
+                t_tokens - t_in + clock() - t_out)
+        return {"steps": 1, "lane_steps": len(reqs),
+                "live_blocks": live_blocks}
 
     def _note_paged(self, ctx):
         """Book one decode or verify pass of the paged kernel from the
@@ -1825,6 +1961,9 @@ class ServingEngine:
                 }} if self.config.num_experts else {}),
                 "slo": self.obs.slo_snapshot(),
                 "phases": self.obs.phase_snapshot(),
+                # the steps on record (obs.LoopRecord): sums, a step's
+                # mean milliseconds a section, the two host gaps
+                "loop": self.obs.loop_snapshot(),
                 "compiles": {n: {"count": p["compile_count"],
                                  "seconds": round(p["compile_seconds"], 3),
                                  "runs": p["run_count"]}

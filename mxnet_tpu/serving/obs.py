@@ -47,7 +47,9 @@ Structured events (``MXNET_TELEMETRY_FILE`` JSONL, rendered by
   failed); the terminal event carries the full phase breakdown
 * ``serving.step_timeline``  one per non-empty engine step: batch
   occupancy, admitted/preempted/finished counts, queue depth, KV-pool
-  used/free/frag — the occupancy time series
+  used/free/frag — the occupancy time series — and beside them the
+  fields of the step's :class:`LoopRecord` (section seconds, the two host
+  gaps)
 * ``serving.slo_burn``       attainment crossed below the burn threshold
 
 Thread model: every hook runs under the engine lock (the driver thread
@@ -57,13 +59,14 @@ transitions, ``telemetry.event`` is a no-op, and nothing here touches
 device values (no host syncs).
 """
 import time
-from collections import deque
+from collections import deque, namedtuple
 
 from .. import telemetry
 from ..base import env_float
 
 __all__ = ["PHASES", "SLO_WINDOW", "BURN_THRESHOLD", "RequestTrace",
-           "ServingObs"]
+           "ServingObs", "LoopRecord", "LOOP_RING", "open_record",
+           "loop_records"]
 
 #: Exhaustive phase set; every request's wall clock is partitioned over it.
 PHASES = ("queue_wait", "prefill", "decode", "replay", "compile_stall")
@@ -77,6 +80,105 @@ BURN_THRESHOLD = 0.9
 #: Minimum finished requests before burn-rate judgment (a 1-request window
 #: would fire on the first miss of the day).
 _BURN_MIN_SAMPLES = 8
+
+#: Steps an engine's ring of loop records keeps: the longest window a
+#: benchmark run may have (51 s) at the ~30 steps a second of its busiest
+#: cell, with set-up's steps before it. A record is ~0.8 KB (25 fields,
+#: most of them floats of their own): a full ring is ~6 MB.
+LOOP_RING = 8192
+
+#: Engines whose rings :func:`loop_records` still reaches (a supervisor's
+#: restart makes an engine; a test process makes hundreds, each with a
+#: handful of steps).
+_RINGS_KEPT = 32
+
+
+class LoopRecord(namedtuple("LoopRecord", (
+        "ts", "step",
+        "lock_s", "schedule_s",
+        "prefill_build_s", "prefill_dispatch_s", "prefill_fetch_s",
+        "prefill_retire_s", "prefills",
+        "decode_build_s", "decode_dispatch_s", "decode_fetch_s",
+        "chunk_steps", "lanes",
+        "retire_s", "retire_counters_s", "retire_tokens_s",
+        "retire_finish_s",
+        "lane_steps", "live_blocks", "window_live_blocks",
+        "full_live_blocks",
+        "gap_chunk_s", "gap_group_s", "finished"))):
+    """What the engine loop did in ONE non-empty step, on the host's clock
+    (docs/observability.md has the table of fields, where each is measured
+    and the benchmark metric that reads it).
+
+    ``*_s`` are seconds: a section's are its span's own (``_Span.seconds``:
+    the clock reads the histograms already had), summed where a step runs
+    the section more than once (both ``serving.schedule`` sections; the
+    four prefill sections over the step's group; draft and verify on the
+    speculative path). ``retire_s`` is the step's ``serving.retire`` whole;
+    of it ``retire_counters_s`` books the chunk's counters,
+    ``retire_tokens_s`` delivers its tokens and ``retire_finish_s`` (the
+    nested span ``serving.retire.finish``) finishes requests; the three
+    need not add up to it. Counts: ``prefills`` (the group's prompts),
+    ``chunk_steps`` (the decode dispatch's trip count, 0 for a step
+    without one), ``lanes``, and what the chunk's steps walked
+    (``lane_steps``, ``live_blocks``; a model with window layers also its
+    window and full-pool walks).
+
+    **The two gaps are the point.** The host is synchronous at every fetch:
+    from a blocking fetch's return to the next dispatch call's return the
+    device has nothing to run. ``gap_chunk_s``: from the return of the
+    step before's last blocking fetch (its chunk's, or its group's last
+    where it decoded nothing) to the return of this step's FIRST dispatch
+    call, whichever program it starts, less the time under
+    ``serving.loop.idle`` in between (an empty queue is no fault of the
+    loop's); None where no fetch came before (an engine's first step).
+    ``gap_group_s``: from the return of the group's last
+    ``serving.prefill.fetch`` to the return of the same step's decode
+    dispatch; None for a step without prefills or without a chunk. Their
+    sum is the host's part of the device's idle time, read on ONE clock.
+    What the device idles beyond it is the runtime's: the fetch's tail (the
+    device is done, ``np.asarray`` has not returned) and the launch. On the
+    speculative path the draft's inner fetches are inside
+    ``decode_dispatch_s`` and in neither gap.
+
+    **Sound only while every fetch blocks**: the day a fetch is made late
+    (a program in flight while the host schedules) a gap measured this way
+    overlaps device work and the record goes stale, as
+    ``ServingEngine._run_prefills`` says of its own ground."""
+
+    __slots__ = ()
+
+
+def open_record():
+    """The dict the engine fills during a step and hands to
+    :meth:`ServingObs.step_timeline`: a :class:`LoopRecord`'s fields, the
+    seconds and counts at zero, no gap yet, ``ts`` now (the step holds
+    the engine's lock: ``stats()`` reads under the same one)."""
+    return dict(_RECORD_OPEN, ts=time.time())
+
+
+_RECORD_OPEN = {f: None if f.startswith("gap_") else
+                0.0 if f.endswith("_s") else 0 for f in LoopRecord._fields}
+
+#: the fields a sum makes sense of (seconds and counts): ``stats()["loop"]``
+_SUMMED = tuple(f for f in LoopRecord._fields if f not in ("ts", "step"))
+
+# every engine's ring, the engine not held: ``benchmark/run.py`` reads after
+# its driver has shut the engine down, and an engine is a reference cycle
+# that must stay collectable
+_rings = deque(maxlen=_RINGS_KEPT)
+
+
+def loop_records(since=None, until=None):
+    """The :class:`LoopRecord` of every step whose entry (``ts``, wall
+    clock) lies in ``[since, until]``, over the rings of this process's
+    engines (the last ``_RINGS_KEPT`` made), oldest first. Each ring is
+    copied in one call, so a driver thread may be stepping meanwhile."""
+    out = [r for ring in list(_rings) for r in list(ring)
+           if (since is None or r.ts >= since)
+           and (until is None or r.ts <= until)]
+    out.sort(key=lambda r: r.ts)
+    return out
+
 
 
 # thread-confined: a trace is mutated only by the thread stepping its
@@ -145,7 +247,8 @@ class ServingObs:
     lifecycle transition plus one per step for the timeline."""
 
     __slots__ = ("engine_id", "slo_ttft_s", "slo_tpot_s", "_window",
-                 "_burning", "_good", "_total")
+                 "_burning", "_good", "_total", "_ring", "_loop_sums",
+                 "_loop_n")
 
     def __init__(self, engine_id, slo_ttft_ms=None, slo_tpot_ms=None):
         self.engine_id = str(engine_id)
@@ -162,6 +265,14 @@ class ServingObs:
         # inherits the first one's numbers
         self._good = {"ttft": 0, "tpot": 0}
         self._total = {"ttft": 0, "tpot": 0}
+        # the loop's record of every step: a ring the readers window
+        # (loop_records) and running sums for stats()["loop"], so that a
+        # /stats poll does not walk 8192 records under the step lock
+        self._ring = deque(maxlen=LOOP_RING)
+        _rings.append(self._ring)
+        self._loop_sums = dict.fromkeys(_SUMMED, 0)
+        self._loop_n = {"steps": 0, "gap_chunk_s": 0, "gap_group_s": 0,
+                        "chunks": 0, "groups": 0}
 
     # ---- lifecycle hooks (engine lock held) ----------------------------
     def request_submitted(self, req):
@@ -333,17 +444,32 @@ class ServingObs:
                               phase=phase).inc()
 
     # ---- step timeline ------------------------------------------------
-    def step_timeline(self, step, occupancy, admitted, preempted, finished,
-                      queue, running, kv_used, kv_free, kv_frag_slots):
-        """One occupancy sample per non-empty engine step (disabled
-        telemetry short-circuits before any field is assembled)."""
-        if not telemetry.enabled():
+    def step_timeline(self, record, occupancy, admitted, preempted, queue,
+                      running, kv_used, kv_free, kv_frag_slots):
+        """One sample per non-empty engine step, made from ``record`` (the
+        dict of :func:`open_record`, filled by the step; None while
+        telemetry is off: nothing is assembled): the :class:`LoopRecord`
+        into this engine's ring and sums, and the ``serving.step_timeline``
+        event with the occupancy beside the record's fields."""
+        if record is None:
             return
+        self._ring.append(LoopRecord(**record))
+        sums, n = self._loop_sums, self._loop_n
+        for f in _SUMMED:
+            v = record[f]
+            if v:
+                sums[f] += v
+        n["steps"] += 1
+        n["chunks"] += record["chunk_steps"] > 0
+        n["groups"] += record["prefills"] > 0
+        n["gap_chunk_s"] += record["gap_chunk_s"] is not None
+        n["gap_group_s"] += record["gap_group_s"] is not None
+        del record["ts"]    # the event's own is the instant it was made
         telemetry.event("serving.step_timeline", engine=self.engine_id,
-                        step=step, occupancy=occupancy, admitted=admitted,
-                        preempted=preempted, finished=finished, queue=queue,
+                        occupancy=occupancy, admitted=admitted,
+                        preempted=preempted, queue=queue,
                         running=running, kv_used=kv_used, kv_free=kv_free,
-                        kv_frag_slots=kv_frag_slots)
+                        kv_frag_slots=kv_frag_slots, **record)
 
     # ---- snapshots (stats() / serve.py / bench) -----------------------
     def slo_snapshot(self):
@@ -360,6 +486,30 @@ class ServingObs:
             "goodput": (sum(self._window) / len(self._window)
                         if self._window else None),
             "burning": self._burning,
+        }
+
+    def loop_snapshot(self):
+        """``stats()["loop"]``: this engine's steps on record, each
+        field's sum over them, a step's mean milliseconds a section, and
+        the two gaps' means over the steps that have one."""
+        n, sums = self._loop_n, self._loop_sums
+        steps = n["steps"]
+
+        def mean_ms(field, over):
+            return round(1e3 * sums[field] / over, 6) if over else None
+
+        return {
+            "steps": steps, "chunks": n["chunks"], "groups": n["groups"],
+            "sums": {f: (round(v, 6) if f.endswith("_s") else v)
+                     for f, v in sums.items()},
+            "mean_ms": {f[:-2]: mean_ms(f, steps) for f in _SUMMED
+                        if f.endswith("_s") and not f.startswith("gap_")},
+            "gap_after_chunk_ms": mean_ms("gap_chunk_s", n["gap_chunk_s"]),
+            "gap_after_group_ms": mean_ms("gap_group_s", n["gap_group_s"]),
+            "steps_per_dispatch":
+                sums["chunk_steps"] / n["chunks"] if n["chunks"] else 0.0,
+            "prompts_per_group":
+                sums["prefills"] / n["groups"] if n["groups"] else 0.0,
         }
 
     def phase_snapshot(self):

@@ -399,23 +399,30 @@ def reset():
 class _Span:
     """The one span class. ``ann`` is the profiler annotation it holds open
     (None for `profiler.record_span`'s per-operator spans, which get none);
-    the clock is read only while telemetry or the MXNet-API profiler is on."""
+    the clock is read only while telemetry or the MXNet-API profiler is on,
+    and the wall clock only for the profiler, whose chrome trace alone wants
+    it. ``seconds`` is the span's duration once it has closed (None before,
+    and None for a span that read no clock): whoever opened the span can
+    book what the histogram got without a second pair of clock reads, as
+    the serving engine's record of a step does."""
 
-    __slots__ = ("name", "category", "args", "_ann", "_hist", "_t0",
-                 "_wall0")
+    __slots__ = ("name", "category", "args", "seconds", "_ann", "_hist",
+                 "_t0", "_wall0")
 
     def __init__(self, name, category, args=None, ann=None, hist=True):
         self.name = name
         self.category = category
         self.args = args
+        self.seconds = None
         self._ann = ann
         self._hist = hist
 
     def __enter__(self):
         if self._ann is not None:
             self._ann.__enter__()
-        if _enabled or _profiler.is_running():
-            self._wall0 = time.time()
+        profiling = _profiler.is_running()
+        if _enabled or profiling:
+            self._wall0 = time.time() if profiling else None
             self._t0 = time.perf_counter()
         else:
             self._t0 = None
@@ -431,11 +438,12 @@ class _Span:
 
     def __exit__(self, *exc):
         if self._t0 is not None:
-            dur = time.perf_counter() - self._t0
+            dur = self.seconds = time.perf_counter() - self._t0
             if _enabled and self._hist:
                 histogram(self.name).observe(dur)
-            _profiler.emit_span(self.name, self.category, self._wall0, dur,
-                                self.args)
+            if self._wall0 is not None:   # the profiler ran at the entry
+                _profiler.emit_span(self.name, self.category, self._wall0,
+                                    dur, self.args)
         if self._ann is not None:
             self._ann.__exit__(*exc)
         return False
@@ -793,6 +801,9 @@ METRIC_HELP = {
     "serving.decode.fetch": "decode next-token blocking fetch (span)",
     "serving.retire":
         "token bookkeeping and retirement after a prefill or a step (span)",
+    "serving.retire.finish":
+        "the finished sweep inside a step's serving.retire: "
+        "scheduler.finish and _retire a finished request (span)",
     "serving.prefill_seconds":
         "per-request prefill wall, own dispatch to the end of its fetch "
         "(in a group: with what was left of the prompts queued before it)",

@@ -11,11 +11,13 @@ pytest.register_assert_rewrite("benchmark.tests.test_benchmark_harness",
                                "benchmark.tests.test_pool_copy_share",
                                "benchmark.tests.test_moe_metrics",
                                "benchmark.tests.test_ssm_metrics",
-                               "benchmark.tests.test_latent_metrics")
+                               "benchmark.tests.test_latent_metrics",
+                               "benchmark.tests.test_loop_metrics")
 
 from benchmark.tests.test_benchmark_harness import *  # noqa: E402,F401,F403
 from benchmark.tests.test_pool_copy_share import *  # noqa: E402,F401,F403
 from benchmark.tests.test_moe_metrics import *  # noqa: E402,F401,F403
+from benchmark.tests.test_loop_metrics import *  # noqa: E402,F401,F403
 from benchmark.tests import test_latent_metrics as _latent_tests  # noqa: E402
 from benchmark.tests import test_moe_metrics as _moe_tests  # noqa: E402
 from benchmark.tests import test_ssm_metrics as _ssm_tests  # noqa: E402

@@ -325,6 +325,15 @@ def test_window_blocks_are_freed_behind_the_window_and_used_again(chunk):
     assert eng.window_pool.used() == 0 and eng.state.used() == 0
     assert eng.pool.used() == 0
     assert st["full_pool_readers"] == 2 and st["full_pool_layers"] == 1
+    # the loop's record books the two walks apart: a full-pool reader's is
+    # the paged counter's, a window layer's at most the window and a block
+    recs = list(eng.obs._ring)
+    assert sum(r.lane_steps for r in recs) == 149
+    assert sum(r.full_live_blocks for r in recs) \
+        == sum(r.live_blocks for r in recs) \
+        == eng.stats()["paged"]["live_blocks"]
+    assert 149 <= sum(r.window_live_blocks for r in recs) \
+        <= 149 * (WINDOW + BS) // BS < sum(r.full_live_blocks for r in recs)
     # the full-length pool's bytes count the other two kinds too
     assert eng.pool.nbytes() == (
         2 * eng.pool.k_pages.size * 4 + eng.window_pool.nbytes()

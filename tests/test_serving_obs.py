@@ -13,6 +13,7 @@ Host-side only: part of tier-1 (tests/conftest.py pins jax to the CPU);
 `ci/run_tests.sh serving` runs the serving files alone, slow cases
 included.
 """
+import itertools
 import json
 import os
 import subprocess
@@ -28,8 +29,11 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 from mxnet_tpu import telemetry  # noqa: E402
 from mxnet_tpu.serving import ServingConfig, ServingEngine  # noqa: E402
+from mxnet_tpu.serving import engine as engine_mod  # noqa: E402
+from mxnet_tpu.serving import obs as obs_mod  # noqa: E402
 from mxnet_tpu.serving.obs import (  # noqa: E402
-    BURN_THRESHOLD, PHASES, RequestTrace, ServingObs)
+    BURN_THRESHOLD, LOOP_RING, PHASES, LoopRecord, RequestTrace, ServingObs,
+    loop_records, open_record)
 
 pytestmark = pytest.mark.serving
 
@@ -337,6 +341,482 @@ def test_disabled_telemetry_still_traces_and_judges():
 
 
 # ---------------------------------------------------------------------------
+# the loop's record of every step (LoopRecord, the ring, loop_records)
+# ---------------------------------------------------------------------------
+
+SECONDS = [f for f in LoopRecord._fields if f.endswith("_s")]
+SECTIONS = [f for f in SECONDS
+            if not f.startswith(("gap_", "retire_counters", "retire_tokens",
+                                 "retire_finish"))]
+
+
+class TickClock:
+    """``time`` for the two modules that time the loop, with a
+    ``perf_counter`` that advances one tick a read: every duration is a
+    count of the clock reads between its ends, and ``peek`` reads without
+    advancing. ``forbidden``: any read of it fails the test."""
+
+    def __init__(self, forbidden=False):
+        self.now, self.forbidden = 0, forbidden
+
+    def perf_counter(self):
+        assert not self.forbidden, "perf_counter read with telemetry off"
+        self.now += 1
+        return float(self.now)
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
+class FetchLog:
+    """``numpy`` for the engine, logging the tick at which each blocking
+    fetch of a device array returned."""
+
+    def __init__(self, clock):
+        self.clock, self.returned = clock, []
+
+    def asarray(self, a, *args, **kw):
+        out = np.asarray(a, *args, **kw)
+        if hasattr(a, "copy_to_host_async"):
+            self.returned.append(self.clock.now)
+        return out
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+
+@pytest.fixture
+def ticks(telem, monkeypatch):
+    """A tick clock under `telemetry.span` and the engine, a fetch log
+    under the engine, and a log of the tick at which each program call
+    returned (``wrap(engine)``)."""
+    clock = TickClock()
+    fetches = FetchLog(clock)
+    monkeypatch.setattr(telemetry, "time", clock)
+    monkeypatch.setattr(engine_mod, "time", clock)
+    monkeypatch.setattr(engine_mod, "np", fetches)
+    calls = []
+
+    def wrap(eng):
+        for name in ("_dispatch_prefill", "_dispatch_decode"):
+            def logged(*a, _f=getattr(eng, name), _n=name, **kw):
+                out = _f(*a, **kw)
+                calls.append((_n[len("_dispatch_"):], clock.now))
+                return out
+            monkeypatch.setattr(eng, name, logged)
+        return eng
+
+    return clock, fetches.returned, calls, wrap
+
+
+def _step_logged(eng, clock, fetches, calls):
+    """One step: its record, its wall in ticks, the ticks its program
+    calls and its fetches returned at."""
+    n_f, n_c, t0 = len(fetches), len(calls), clock.now
+    eng.step()
+    return (eng.obs._ring[-1], clock.now - t0, calls[n_c:], fetches[n_f:])
+
+
+def test_one_record_a_non_empty_step_with_every_field(ticks):
+    clock, fetches, calls, wrap = ticks
+    eng = wrap(ServingEngine(_config(), seed=SEED))
+    ring = eng.obs._ring
+    assert eng.step() == [] and not ring        # an empty step: no record
+    reqs = [eng.submit([1, 2, 3, 4 + i], 12) for i in range(2)]
+    before = time.time()
+    rec, wall, progs, fetched = _step_logged(eng, clock, fetches, calls)
+    assert len(ring) == 1 and ring[0] is rec
+    assert rec._fields == LoopRecord._fields and len(rec) == 25
+    assert before <= rec.ts <= time.time() and rec.step == eng._steps == 1
+    # two prompts as one group, then a chunk of their lanes
+    assert [p for p, _t in progs] == ["prefill", "prefill", "decode"]
+    assert (rec.prefills, rec.lanes, rec.finished) == (2, 2, 0)
+    assert 1 <= rec.chunk_steps <= engine_mod.DECODE_CHUNK
+    assert rec.lane_steps == 2 * rec.chunk_steps
+    assert rec.live_blocks == eng.stats()["paged"]["live_blocks"] > 0
+    assert (rec.window_live_blocks, rec.full_live_blocks) == (0, 0)
+    for f in SECTIONS:      # every section ran and was timed: whole ticks
+        assert getattr(rec, f) >= 1.0 and getattr(rec, f) % 1 == 0, f
+    assert rec.retire_counters_s >= rec.chunk_steps     # two reads a step
+    assert rec.retire_tokens_s >= 1 and rec.retire_finish_s >= 1
+    assert rec.retire_counters_s + rec.retire_tokens_s \
+        + rec.retire_finish_s <= rec.retire_s
+    # an engine's first step: no fetch came before it
+    assert rec.gap_chunk_s is None
+    # the group's gap: from its LAST fetch's return to the chunk's
+    # dispatch's return, to the tick
+    assert rec.gap_group_s == progs[2][1] - fetched[1]
+    assert sum(getattr(rec, f) for f in SECTIONS) <= wall
+    while eng.has_work():
+        eng.step()
+    assert all(len(r.generated) == 12 for r in reqs)
+    assert len(ring) == eng._steps
+    assert [r.step for r in ring] == list(range(1, eng._steps + 1))
+    assert ring[-1].finished == 2
+
+
+def test_gap_after_a_chunk_ends_at_the_first_dispatch_of_any_program(ticks):
+    """``gap_chunk_s`` is the ticks from the return of the step before's
+    last fetch to the return of this step's first program call: the decode
+    program's in a step that admits nothing, the first prefill's in one
+    that does (whose chunk then closes the group's gap)."""
+    clock, fetches, calls, wrap = ticks
+    eng = wrap(ServingEngine(_config(), seed=SEED))
+    eng.submit([1, 2, 3], 40)
+    _rec, _wall, _progs, last = _step_logged(eng, clock, fetches, calls)
+    # nothing admitted: the chunk's dispatch is the step's first
+    rec, wall, progs, fetched = _step_logged(eng, clock, fetches, calls)
+    assert [p for p, _t in progs] == ["decode"] and rec.prefills == 0
+    assert rec.gap_chunk_s == progs[0][1] - last[-1] > 0
+    assert rec.gap_group_s is None
+    assert sum(getattr(rec, f) for f in SECTIONS) <= wall
+    last = fetched
+    # two prompts admitted: the FIRST prefill's dispatch ends the gap, the
+    # second queues behind it and closes nothing
+    eng.submit([4, 5, 6, 7], 6)
+    eng.submit([8, 9], 6)
+    rec, wall, progs, fetched = _step_logged(eng, clock, fetches, calls)
+    assert [p for p, _t in progs] == ["prefill", "prefill", "decode"]
+    assert rec.gap_chunk_s == progs[0][1] - last[-1]
+    assert rec.gap_group_s == progs[2][1] - fetched[1]
+    assert len(fetched) == 3 and rec.lanes == 3
+    # sections and the group's gap lie inside the step; the chunk's gap
+    # began in the step before
+    assert sum(getattr(rec, f) for f in SECTIONS) <= wall
+    assert rec.gap_group_s < wall
+    # a step that only retires: its prompt's one token came from the
+    # prefill, nothing decodes, and the NEXT step's gap starts at that
+    # prefill's fetch
+    while eng.has_work():
+        eng.step()
+    eng.submit([1, 2], 1)
+    rec, _wall, progs, last = _step_logged(eng, clock, fetches, calls)
+    assert [p for p, _t in progs] == ["prefill"]
+    assert (rec.chunk_steps, rec.lanes, rec.finished) == (0, 0, 1)
+    assert rec.gap_group_s is None and rec.decode_fetch_s == 0.0
+    eng.submit([3, 4], 3)
+    rec, _wall, progs, _f = _step_logged(eng, clock, fetches, calls)
+    assert rec.gap_chunk_s == progs[0][1] - last[-1]
+
+
+def test_the_gaps_are_on_the_dispatch_spans(ticks, monkeypatch):
+    """The dispatch span that closes a gap carries it: `gap_us`,
+    `after`."""
+    clock, fetches, calls, wrap = ticks
+    seen = []
+    real = telemetry._Span.set
+
+    def set_(self, **args):
+        if "after" in args:
+            seen.append((self.name, args))
+        real(self, **args)
+
+    monkeypatch.setattr(telemetry._Span, "set", set_)
+    eng = wrap(ServingEngine(_config(), seed=SEED))
+    eng.submit([1, 2, 3], 30)
+    eng.step()
+    assert [(n, a["after"]) for n, a in seen] == [
+        ("serving.decode.dispatch", "group")]
+    eng.submit([1, 2, 3, 4], 3)
+    eng.step()
+    rec = eng.obs._ring[-1]
+    assert [(n, a["after"], a["gap_us"]) for n, a in seen[1:]] == [
+        ("serving.prefill.dispatch", "chunk", int(rec.gap_chunk_s * 1e6)),
+        ("serving.decode.dispatch", "group", int(rec.gap_group_s * 1e6))]
+
+
+def test_waits_on_an_empty_queue_are_not_in_the_gap(telem):
+    """`run_loop` parks under `serving.loop.idle` between two requests:
+    the next step's `gap_chunk_s` leaves that wait out."""
+    eng = ServingEngine(_config(), seed=SEED)
+    eng.generate([[1, 2, 3]], [4])              # compiled, and a fetch made
+    stop = threading.Event()
+    driver = threading.Thread(target=eng.run_loop, args=(stop, 0.1))
+    driver.start()
+    try:
+        first = eng.submit([1, 2, 3], 4)
+        assert first.done_event.wait(60)
+        idle0 = telemetry.totals("serving.loop.idle")[1]
+        time.sleep(0.5)                         # several idle waits
+        second = eng.submit([4, 5, 6], 4)
+        assert second.done_event.wait(60)
+    finally:
+        stop.set()
+        driver.join(60)
+    recs = list(eng.obs._ring)
+    k = next(i for i, r in enumerate(recs) if r.ts >= second.arrival_t)
+    rec, prev = recs[k], recs[k - 1]
+    idled = telemetry.totals("serving.loop.idle")[1] - idle0
+    assert rec.ts - prev.ts > 0.5 <= idled + 0.1
+    assert 0 < rec.gap_chunk_s < rec.ts - prev.ts - 0.4
+
+
+def test_the_record_is_filled_at_every_chunk(chunk, telem):
+    eng = ServingEngine(_config(), seed=SEED)
+    reqs = [eng.submit(list(range(1, 4 + i)), 7 + i) for i in range(3)]
+    while eng.has_work():
+        eng.step()
+    recs, st = list(eng.obs._ring), eng.stats()
+    with_chunk = [r for r in recs if r.chunk_steps]
+    assert all(1 <= r.chunk_steps <= chunk for r in with_chunk)
+    assert max(r.chunk_steps for r in recs) == chunk
+    assert len(with_chunk) == st["decode"]["dispatches"]
+    assert sum(r.chunk_steps for r in recs) == st["decode"]["inner_steps"]
+    assert sum(r.prefills for r in recs) == st["prefill"]["prompts"] == 3
+    assert sum(r.prefills > 0 for r in recs) == st["prefill"]["groups"]
+    assert sum(r.live_blocks for r in recs) == st["paged"]["live_blocks"]
+    # a token a live lane and step, and each prompt's first from its prefill
+    assert sum(r.lane_steps for r in recs) + 3 == st["tokens_total"] \
+        == sum(len(r.generated) for r in reqs)
+    assert sum(r.finished for r in recs) == 3
+    loop = st["loop"]
+    assert (loop["steps"], loop["chunks"], loop["groups"]) == (
+        len(recs), len(with_chunk), st["prefill"]["groups"])
+    assert loop["steps_per_dispatch"] == st["decode"]["steps_per_dispatch"]
+    assert loop["prompts_per_group"] == st["prefill"]["prompts_per_group"]
+    for f in SECONDS:
+        assert loop["sums"][f] == pytest.approx(
+            sum(getattr(r, f) or 0.0 for r in recs), abs=1e-5), f
+    assert set(loop["mean_ms"]) == {f[:-2] for f in SECONDS
+                                    if not f.startswith("gap_")}
+    assert loop["mean_ms"]["retire"] == pytest.approx(
+        1e3 * loop["sums"]["retire_s"] / len(recs), abs=1e-3)
+    assert loop["gap_after_chunk_ms"] == pytest.approx(
+        1e3 * loop["sums"]["gap_chunk_s"] / (len(recs) - 1), abs=1e-3)
+    assert loop["gap_after_group_ms"] > 0
+    json.dumps(loop)
+
+
+def test_a_window_of_records_is_the_window_of_two_stats_reads(telem):
+    """What the benchmark does: `stats()` and the wall clock, twice, while
+    the driver steps and callers submit; the records whose `ts` lies
+    between the two instants hold exactly what the two `stats()` differ by
+    (a step opens its record once it holds the lock `stats()` reads under).
+    Here the clock is read with that lock still held, so that no scheduler
+    can put a step's `ts` between a `stats()` and its instant; the
+    benchmark reads it a few microseconds after, which a waiting step
+    cannot beat unless the machine takes the thread away there."""
+    eng = ServingEngine(_config(max_batch=4), seed=SEED)
+    eng.warmup()                    # no step waits for a compile
+    stop = threading.Event()
+    driver = threading.Thread(target=eng.run_loop, args=(stop, 0.05))
+    driver.start()
+
+    closed = threading.Event()
+
+    def caller(k):
+        for i in itertools.count():
+            req = eng.submit([1 + k, 2 + i % 5, 3], 4 + (i + k) % 9)
+            assert req.done_event.wait(60)
+            if closed.is_set():
+                break
+
+    callers = [threading.Thread(target=caller, args=(k,)) for k in range(6)]
+    for c in callers:
+        c.start()
+
+    def snapshot():
+        with eng._lock:
+            return time.time(), eng.stats()
+
+    try:
+        time.sleep(0.1)
+        t0, before = snapshot()
+        time.sleep(0.3)
+        t1, after = snapshot()
+    finally:
+        closed.set()
+        for c in callers:
+            c.join(120)
+        stop.set()
+        driver.join(60)
+    recs = loop_records(t0, t1)
+    recs = [r for r in recs if r in set(eng.obs._ring)]
+    assert len(recs) == after["steps"] - before["steps"] > 3
+    for field, block, key in (("chunk_steps", "decode", "inner_steps"),
+                              ("prefills", "prefill", "prompts"),
+                              ("live_blocks", "paged", "live_blocks")):
+        assert sum(getattr(r, field) for r in recs) \
+            == after[block][key] - before[block][key], field
+    assert sum(r.chunk_steps > 0 for r in recs) \
+        == after["decode"]["dispatches"] - before["decode"]["dispatches"]
+    assert sum(r.prefills > 0 for r in recs) \
+        == after["prefill"]["groups"] - before["prefill"]["groups"]
+    for field in ("gap_chunk_s", "retire_s", "lock_s"):
+        assert sum(getattr(r, field) or 0.0 for r in recs) \
+            == pytest.approx(after["loop"]["sums"][field]
+                             - before["loop"]["sums"][field], abs=1e-4)
+    # the gaps are part of the window's wall clock, the sections too
+    assert sum((r.gap_chunk_s or 0) + (r.gap_group_s or 0)
+               for r in recs) < t1 - t0 + 0.1
+
+
+def test_the_speculative_path_fills_the_record(telem):
+    eng = ServingEngine(_config(spec_k=2, draft="self"), seed=SEED)
+    reqs = [eng.submit([1, 2, 3 + i], 9) for i in range(2)]
+    while eng.has_work():
+        eng.step()
+    recs = list(eng.obs._ring)
+    assert all(len(r.generated) == 9 for r in reqs)
+    assert recs[0].prefills == 2 and recs[0].gap_chunk_s is None
+    for r in recs:
+        # a draft-and-verify window is one step of two lanes
+        assert (r.chunk_steps, r.lanes, r.lane_steps) == (1, 2, 2)
+        assert r.live_blocks > 0
+        for f in ("decode_build_s", "decode_dispatch_s", "decode_fetch_s",
+                  "retire_s", "retire_counters_s", "retire_tokens_s"):
+            assert getattr(r, f) > 0, f
+        assert r.decode_dispatch_s + r.decode_fetch_s <= sum(
+            getattr(r, f) for f in SECTIONS)
+    assert recs[0].gap_group_s > 0
+    assert all(r.gap_chunk_s > 0 and r.gap_group_s is None
+               for r in recs[1:])
+    assert sum(r.live_blocks for r in recs) \
+        == eng.stats()["paged"]["live_blocks"]
+
+
+def test_the_ring_is_bounded_and_windowed_over_two_engines(telem,
+                                                           monkeypatch):
+    monkeypatch.setattr(obs_mod, "LOOP_RING", 5)
+    assert LOOP_RING == 8192
+    a, b = ServingObs("a"), ServingObs("b")
+    assert a._ring.maxlen == 5
+    timeline = dict(occupancy=1, admitted=0, preempted=0, queue=0,
+                    running=1, kv_used=1, kv_free=1, kv_frag_slots=0)
+    t0 = time.time()
+    for i in range(8):
+        for o in (a, b):
+            rec = open_record()
+            # b's steps fall between a's
+            rec.update(ts=t0 + i + (0.5 if o is b else 0.0), step=i + 1,
+                       chunk_steps=2, retire_s=0.25)
+            o.step_timeline(rec, **timeline)
+    assert [r.step for r in a._ring] == [4, 5, 6, 7, 8]   # the last five
+    assert a.loop_snapshot()["steps"] == 8      # the sums forget nothing
+    assert a.loop_snapshot()["sums"]["retire_s"] == 2.0
+    # (a window, always: the rings of other tests' engines are out there)
+    both = loop_records(t0, t0 + 50)
+    assert [r.ts - t0 for r in both] == [3, 3.5, 4, 4.5, 5, 5.5, 6, 6.5,
+                                         7, 7.5]
+    assert [r.ts - t0 for r in loop_records(t0 + 4.5, t0 + 6)] == [
+        4.5, 5, 5.5, 6]                         # both ends inside
+    assert [r.ts - t0 for r in loop_records(until=t0 + 3.2)
+            if r.ts >= t0] == [3]
+    assert loop_records(t0 + 50, t0 + 100) == []
+    assert len(loop_records()) >= len(loop_records(since=t0)) >= 10
+    # the rings outlive their engines: the benchmark reads after shutdown
+    del a, b
+    assert len(loop_records(t0, t0 + 50)) == 10
+    # the event carries the record's fields beside the occupancy
+    ev = telemetry.events("serving.step_timeline")[-1]
+    assert set(LoopRecord._fields) - {"ts"} <= set(ev)
+    assert set(timeline) <= set(ev) and ev["engine"] == "b"
+    assert (ev["step"], ev["chunk_steps"], ev["gap_chunk_s"]) == (8, 2, None)
+    assert ev["ts"] >= t0                       # the event's own instant
+
+
+def test_telemetry_off_makes_no_record_and_reads_no_clock(monkeypatch):
+    telemetry.disable()
+    telemetry.reset()
+    clock = TickClock(forbidden=True)
+    monkeypatch.setattr(telemetry, "time", clock)
+    monkeypatch.setattr(engine_mod, "time", clock)
+    monkeypatch.setattr(obs_mod, "open_record", None)    # never called
+    try:
+        eng = ServingEngine(_config(), seed=SEED, enable_telemetry=False)
+        reqs = [eng.submit([1, 2, 3 + i], 6) for i in range(2)]
+        while eng.has_work():
+            eng.step()
+        assert all(len(r.generated) == 6 for r in reqs)
+        assert not eng.obs._ring and eng._fetch_end is None
+        loop = eng.stats()["loop"]
+        assert loop["steps"] == 0 and loop["gap_after_chunk_ms"] is None
+        assert not any(loop["sums"].values())
+    finally:
+        telemetry.reset()
+
+
+def test_assembling_a_record_costs_under_20_us(telem):
+    """What a step pays for its record when the spans have closed: the
+    dict, the tuple into the ring, the sums and the event. The best of
+    twenty batches, as `test_span_with_everything_off_is_only_an_annotation`
+    takes it: the gate's other workers take the cores away."""
+    o = ServingObs("cost")
+    timeline = dict(occupancy=32, admitted=2, preempted=0, queue=30,
+                    running=32, kv_used=400, kv_free=100, kv_frag_slots=9)
+    n, best = 500, float("inf")
+    for _ in range(20):
+        t0 = time.perf_counter()
+        for i in range(n):
+            rec = open_record()
+            rec.update(step=i, prefills=2, lanes=32, chunk_steps=8,
+                       finished=1, retire_s=3e-3, gap_chunk_s=6e-3)
+            o.step_timeline(rec, **timeline)
+        best = min(best, (time.perf_counter() - t0) / n)
+    assert best < 20e-6, "a record costs %.1f us" % (best * 1e6)
+    assert len(o._ring) == min(20 * n, LOOP_RING)
+
+
+def test_serving_report_prints_the_loops_record(tmp_path, monkeypatch):
+    """The operator's use of the record: `tools/serving_report.py` prints
+    a step's section milliseconds and its two gaps beside its occupancy,
+    from the `serving.step_timeline` events of the sink file."""
+    import io
+
+    sink = tmp_path / "serving.jsonl"
+    monkeypatch.setenv("MXNET_TELEMETRY_FILE", str(sink))
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        eng = ServingEngine(_config(), seed=SEED)
+        eng.generate([[1, 2, 3], [4, 5, 6, 7]], [9, 12])
+        recs = list(eng.obs._ring)
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    monkeypatch.syspath_prepend(os.path.join(ROOT, "tools"))
+    import serving_report
+
+    _events, steps = serving_report.load_events(str(sink))
+    assert [s["step"] for s in steps] == [r.step for r in recs]
+    for ev, rec in zip(steps, recs):        # the sink carries the record
+        for f in LoopRecord._fields:
+            if f != "ts":
+                assert ev[f] == getattr(rec, f), f
+    rows = serving_report.loop_rows(steps)
+    assert [(step, pr, n) for _e, step, pr, n, _ms in rows] == [
+        (r.step, r.prefills, r.chunk_steps) for r in recs]
+    first = rows[0][4]
+    assert first["gap_chunk"] is None       # no fetch before the first step
+    assert first["gap_group"] == pytest.approx(1e3 * recs[0].gap_group_s)
+    assert first["prefill"] == pytest.approx(1e3 * (
+        recs[0].prefill_build_s + recs[0].prefill_dispatch_s
+        + recs[0].prefill_fetch_s + recs[0].prefill_retire_s))
+    assert rows[1][4]["gap_chunk"] == pytest.approx(
+        1e3 * recs[1].gap_chunk_s)
+    out = io.StringIO()
+    serving_report.render([], steps, file=out)
+    text = out.getvalue()
+    assert "occupancy timeline" in text and "engine loop" in text
+    table = text[text.index("engine loop"):].splitlines()
+    assert table[1].split() == ["step", "pr", "n", "lock", "sched",
+                                "prefill", "build", "disp", "fetch",
+                                "retire", "counters", "gap_chunk",
+                                "gap_group"]
+    assert len(table) == 2 + len(recs) + 1 and table[2].split()[-2] == "--"
+    assert table[-1].startswith("loop totals: %d steps, %d chunks"
+                                % (len(recs), len(recs)))
+    assert "% of the" in table[-1] and "retire" in table[-1]
+    # events of a program from before the record: the old table alone
+    old = [{k: v for k, v in s.items() if k not in LoopRecord._fields
+            or k in ("step", "finished")} for s in steps]
+    out = io.StringIO()
+    serving_report.render([], old, file=out)
+    assert "occupancy timeline" in out.getvalue()
+    assert "engine loop" not in out.getvalue()
+
+
+# ---------------------------------------------------------------------------
 # the shared segment walker (serving_report.py + trace_merge.py lanes)
 # ---------------------------------------------------------------------------
 
@@ -499,6 +979,10 @@ def test_e2e_waterfall_attribution_closes(tmp_path, monkeypatch):
         "cold-bucket compiles must surface as compile_stall"
     assert rep["steps"], "step timeline must be populated"
     assert max(s["occupancy"] for s in rep["steps"]) >= 1
+    # each step's event carries the loop's record: sections and gaps
+    assert all(s["retire_s"] > 0 for s in rep["steps"])
+    assert any(s["gap_chunk_s"] for s in rep["steps"])
+    assert len(serving_report.loop_rows(rep["steps"])) == len(rep["steps"])
     assert rep["slo"]["judged"] >= 2
 
     # the CLI renders the same stream (human waterfall + --json)
@@ -508,9 +992,11 @@ def test_e2e_waterfall_attribution_closes(tmp_path, monkeypatch):
         capture_output=True, text=True, check=True)
     cli = json.loads(out.stdout)
     assert {r["request_id"] for r in cli["requests"]} == {"long-a", "short-b"}
-    subprocess.run(
+    human = subprocess.run(
         [sys.executable, os.path.join(ROOT, "tools", "serving_report.py"),
          str(sink)], capture_output=True, text=True, check=True)
+    assert "engine loop (per step" in human.stdout
+    assert "loop totals:" in human.stdout
 
     # chrome trace: one lane per request, schema-valid, replay span present
     trace = trace_merge.merge([trace_merge.load_input(str(sink))],

@@ -36,7 +36,8 @@ SECTIONS = ["serving.step.lock", "serving.schedule",
             "serving.prefill.fetch", "serving.decode.build",
             "serving.decode.dispatch", "serving.decode.fetch",
             "serving.retire"]
-SPAN_NAMES = ["serving.loop.idle", "serving.step"] + SECTIONS
+NESTED = "serving.retire.finish"      # inside the step's serving.retire
+SPAN_NAMES = ["serving.loop.idle", "serving.step", NESTED] + SECTIONS
 #: the fixture's requests: ten tokens each, one from the prefill and nine
 #: decode steps, in dispatches of DECODE_CHUNK steps and the rest
 N_NEW = 10
@@ -162,6 +163,50 @@ def test_sections_nest_in_the_step_and_do_not_overlap(serving_trace):
     for n, s, d, _ in evs:
         if n == "serving.loop.idle":
             assert not any(a < s + d / 2 < b for a, b in steps)
+
+
+def test_the_finished_sweep_nests_in_the_steps_retire(serving_trace):
+    """`serving.retire.finish` is one stretch of the step's `serving.retire`
+    and a span of its own: the trace names it, and a device gap whose
+    middle falls in it is the host's."""
+    slack = 1e3
+    evs = serving_trace["driver"]
+    retires = [(s, s + d) for n, s, d, st in evs
+               if n == "serving.retire" and "request_id" not in st]
+    sweeps = [(s, s + d) for n, s, d, _ in evs if n == NESTED]
+    steps = [e for e in evs if e[0] == "serving.step"]
+    assert len(sweeps) == len(retires) == len(steps)    # one a step
+    for (a, b), (s0, s1) in zip(sorted(retires), sorted(sweeps)):
+        assert a - slack <= s0 and s1 <= b + slack
+    assert _gaps.classify(NESTED) == "host"
+    assert NESTED in telemetry.METRIC_HELP
+    assert "`%s`" % NESTED in open(
+        os.path.join(ROOT, "docs", "observability.md")).read()
+
+
+def test_the_dispatch_that_closes_a_gap_says_so(serving_trace):
+    """The loop's record measures its two gaps on the host's clock; the
+    dispatch span that closes one carries it (`gap_us`, `after`), so
+    whoever opens the trace sees where the host says the gap ended on the
+    device's clock. The fixture's first step admits three prompts: its
+    first prefill dispatch has no fetch before it (no gap), its chunk
+    closes the group's; every later chunk closes the one before's."""
+    closing = [(n, st["after"], st["gap_us"])
+               for n, _s, _d, st in sorted(serving_trace["driver"],
+                                           key=lambda e: e[1])
+               if "after" in st]
+    assert [(n, after) for n, after, _us in closing] == [
+        ("serving.decode.dispatch", "group")] + [
+        ("serving.decode.dispatch", "chunk")] * (len(CHUNKS) - 1)
+    assert all(us >= 0 for _n, _a, us in closing)
+    loop = serving_trace["stats"]["loop"]
+    assert loop["chunks"] == len(CHUNKS) and loop["groups"] == 1
+    assert loop["steps_per_dispatch"] == (N_NEW - 1) / len(CHUNKS)
+    assert loop["prompts_per_group"] == 3.0
+    # the spans' microseconds are the record's seconds
+    assert sum(us for _n, _a, us in closing) == pytest.approx(
+        1e6 * (loop["sums"]["gap_chunk_s"] + loop["sums"]["gap_group_s"]),
+        abs=len(closing) + 2)       # a span's are whole, the sums rounded
 
 
 def test_arguments_are_readable_from_the_events_stats(serving_trace):
@@ -410,6 +455,74 @@ def test_span_with_everything_off_is_only_an_annotation():
     assert s._ann is not None and s._t0 is None      # no clock was read
     assert set(telemetry.dump()["histograms"]) == before
     assert not profiler._state["events"]             # no chrome event
+
+
+class CountedTime:
+    """``time`` for telemetry.py, counting the wall-clock reads."""
+
+    def __init__(self):
+        self.walls = 0
+
+    def time(self):
+        self.walls += 1
+        return time.time()
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
+def test_a_closed_span_keeps_its_seconds_and_reads_no_wall_clock(
+        monkeypatch, tmp_path):
+    """`span.seconds` is what the histogram got; the wall clock is the
+    chrome trace's alone and is read only while the MXNet-API profiler
+    runs."""
+    clock = CountedTime()
+    monkeypatch.setattr(telemetry, "time", clock)
+    was = telemetry.enabled()
+    telemetry.enable()
+    try:
+        s = telemetry.span("spans.seconds", "test")
+        assert s.seconds is None
+        with s:
+            assert s.seconds is None            # still open
+            time.sleep(0.002)
+        h = telemetry.histogram("spans.seconds")
+        assert 0.002 <= s.seconds == h.sum and h.count == 1
+        assert clock.walls == 0 and s._wall0 is None
+        # the profiler on: one wall-clock read a span, for its event's ts
+        profiler.profiler_set_config(mode="all",
+                                     filename=str(tmp_path / "c.json"))
+        profiler.profiler_set_state("run")
+        try:
+            t0 = time.time()
+            with telemetry.span("spans.seconds", "test") as s2:
+                pass
+            assert clock.walls == 1 and s2.seconds > 0
+            ev = [e for e in profiler._state["events"]
+                  if e["name"] == "spans.seconds"]
+            assert len(ev) == 1 and t0 * 1e6 <= ev[0]["ts"] \
+                <= time.time() * 1e6
+            # a span the profiler started under: no event, no error
+            profiler.profiler_set_state("stop")
+            with telemetry.span("spans.late", "test") as s3:
+                profiler.profiler_set_state("run")
+            assert s3.seconds > 0 and clock.walls == 1
+            assert not [e for e in profiler._state["events"]
+                        if e["name"] == "spans.late"]
+        finally:
+            profiler.profiler_set_state("stop")
+    finally:
+        if not was:
+            telemetry.disable()
+    # everything off: no clock at all, no seconds
+    telemetry.disable()
+    try:
+        with telemetry.span("spans.off", "test") as off:
+            pass
+        assert off.seconds is None and off._t0 is None
+    finally:
+        if was:
+            telemetry.enable()
 
 
 def test_span_feeds_the_chrome_trace_with_late_arguments(tmp_path):

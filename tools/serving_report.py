@@ -14,6 +14,12 @@ The serving engine (``mxnet_tpu/serving/obs.py``) emits one
   proportional phase bar;
 * the **occupancy timeline** — per step: batch occupancy, admitted /
   preempted / finished counts, queue depth, KV-pool used/frag;
+* the **engine loop** — per step, from the loop's own record on the same
+  events (docs/observability.md §The engine loop's record): milliseconds
+  in each section (lock, schedule, the group's prefills, the decode
+  chunk's build / dispatch / fetch, retire and its counters' part) and the
+  two host gaps — fetch's return to next dispatch's return, when the device
+  has nothing to run — with their share of the wall clock;
 * **totals** — SLO attainment, total replay overhead (what preemptions
   cost), total compile stall (what cold buckets cost).
 
@@ -195,6 +201,69 @@ def render(requests, steps, bar_width=32, file=sys.stdout):
                  rec.get("admitted", 0), rec.get("preempted", 0),
                  rec.get("finished", 0), rec.get("queue", 0),
                  rec.get("kv_used", 0), rec.get("kv_frag_slots", 0)))
+        render_loop(steps, file)
+
+
+#: the loop table's columns: header, the record's fields summed into it
+_LOOP_COLUMNS = (
+    ("lock", ("lock_s",)), ("sched", ("schedule_s",)),
+    ("prefill", ("prefill_build_s", "prefill_dispatch_s", "prefill_fetch_s",
+                 "prefill_retire_s")),
+    ("build", ("decode_build_s",)), ("disp", ("decode_dispatch_s",)),
+    ("fetch", ("decode_fetch_s",)), ("retire", ("retire_s",)),
+    ("counters", ("retire_counters_s",)),
+    ("gap_chunk", ("gap_chunk_s",)), ("gap_group", ("gap_group_s",)))
+
+
+def loop_rows(steps):
+    """(engine, step, prompts, chunk steps, {column: ms or None}) for each
+    step event that carries the loop's record (a program from before it
+    sends none), in engine and step order."""
+    rows = []
+    for rec in sorted(steps, key=lambda r: (str(r.get("engine", "")),
+                                            r.get("step", 0))):
+        if "gap_chunk_s" not in rec:
+            continue
+        ms = {}
+        for col, fields in _LOOP_COLUMNS:
+            vals = [rec.get(f) for f in fields]
+            ms[col] = (None if all(v is None for v in vals)
+                       else 1e3 * sum(v or 0.0 for v in vals))
+        rows.append((str(rec.get("engine", "")), rec.get("step", 0),
+                     rec.get("prefills", 0), rec.get("chunk_steps", 0), ms))
+    return rows
+
+
+def render_loop(steps, file=sys.stdout):
+    """The engine loop's table and its one line of totals."""
+    rows = loop_rows(steps)
+    if not rows:
+        return
+    w = file.write
+    w("\nengine loop (per step, milliseconds; a gap runs from a blocking "
+      "fetch's return to the next dispatch's return, on the host's "
+      "clock):\n")
+    w("%6s %3s %3s " % ("step", "pr", "n")
+      + " ".join("%9s" % col for col, _f in _LOOP_COLUMNS) + "\n")
+    for _engine, step, prompts, n, ms in rows:
+        w("%6s %3d %3d " % (step, prompts, n)
+          + " ".join("%9s" % ("--" if ms[col] is None else "%.3f" % ms[col])
+                     for col, _f in _LOOP_COLUMNS) + "\n")
+    gaps = sum((ms["gap_chunk"] or 0.0) + (ms["gap_group"] or 0.0)
+               for *_r, ms in rows)
+    chunks = sum(1 for _e, _s, _p, n, _ms in rows if n)
+    wall = (max(float(r["ts"]) for r in steps)
+            - min(float(r["ts"]) for r in steps)) if len(steps) > 1 else 0.0
+    w("loop totals: %d steps, %d chunks | host gaps %.3f ms"
+      % (len(rows), chunks, gaps))
+    if wall > 0:
+        w(" = %.1f%% of the %.3f s from the first step's event to the "
+          "last's" % (100.0 * gaps / 1e3 / wall, wall))
+    if chunks:
+        w(" | retire %.3f ms a chunk, its counters %.3f"
+          % (sum(ms["retire"] or 0.0 for *_r, ms in rows) / chunks,
+             sum(ms["counters"] or 0.0 for *_r, ms in rows) / chunks))
+    w("\n")
 
 
 def report(path):
