@@ -36,6 +36,10 @@ def mix(name):
     return json.load(open(os.path.join(BENCH, "traffic", name + ".json")))
 
 
+CLOSED_CELLS = [c["name"] for c in MANIFEST["workloads"]
+                if mix(c["traffic"]).get("loop") == "closed"]
+
+
 def cpu_env(**extra):
     env = dict(os.environ, JAX_PLATFORMS="cpu", **extra)
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
@@ -105,6 +109,43 @@ def test_every_cell_reports_enough():
         assert "setup_s" in e2e and len(e2e) >= 2, c["name"]
         assert any(c["name"] in m.get("workloads", [c["name"]])
                    for m in MANIFEST["per_layer"])
+
+
+def test_the_compile_metrics_list_every_cell():
+    cells = [c["name"] for c in MANIFEST["workloads"]]
+    by_name = {m["name"]: m for m in MANIFEST["per_layer"]}
+    for name in ("compile_s", "compiles_in_window"):
+        assert by_name[name]["workloads"] == cells
+
+
+def test_plan_used_share_is_listed_in_the_closed_loops_alone():
+    by_name = {m["name"]: m for m in MANIFEST["per_layer"]}
+    m = by_name["plan_used_share"]
+    assert set(m) == {"name", "unit", "better", "source", "layer", "moves",
+                      "workloads"}
+    assert (m["unit"], m["better"], m["source"], m["moves"]) == (
+        "%", "lower", "host_clock", "serve_out_tok_per_s")
+    # the load generator's other guard spells the layer
+    assert m["layer"] == by_name["gen_late_p95_ms"]["layer"] \
+        == "Load generator"
+    moved = next(e for e in MANIFEST["end_to_end"]
+                 if e["name"] == m["moves"])
+    assert set(m["workloads"]) <= set(moved["workloads"])
+    # every closed loop the manifest has, those of later PRs too, and no
+    # other cell
+    assert m["workloads"] == CLOSED_CELLS
+    assert "gpt2m-longprompt-open" not in m["workloads"]
+
+
+def test_every_metric_lists_its_cells_in_the_manifests_order():
+    """A later PR appends its cell behind the ones that are there, in
+    ``workloads`` and in every metric's list alike: nothing stands between,
+    nothing is listed twice, nothing that is no cell."""
+    cells = [c["name"] for c in MANIFEST["workloads"]]
+    for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]:
+        listed = m.get("workloads")
+        if listed is not None:
+            assert listed == [c for c in cells if c in listed], m["name"]
 
 
 def test_cells_files_and_the_one_four_chip_cell():
@@ -211,6 +252,151 @@ def test_warm_up_covers_the_buckets_the_mix_can_reach():
     buckets = [16, 32, 64, 128, 256, 512, 1024]
     assert reachable_buckets(mix("longprompt-open"), buckets) == [512, 1024]
     assert reachable_buckets(mix("chat-closed64"), buckets) == buckets
+
+
+# ------------------------------------------------ a closed loop's plan --
+def test_the_chat_plan_is_one_no_engine_can_use_up():
+    m = mix("chat-closed64")
+    cfg = json.load(open(os.path.join(BENCH, "configs",
+                                      "gpt2-medium-fp32.json")))
+    new = traffic.plan(m, 11, 30, 50257)
+    assert len(new) == 30 * 200 + 64
+    # what a run serves is what it served under the cap of 40: the callers
+    # consume the plan from its head, and a full block of 64 holds the same
+    # lengths however many blocks follow (the old plan: 19 full blocks + 48)
+    old = traffic.plan(dict(m, request_rate_cap=40), 11, 30, 50257)
+    assert len(old) == 30 * 40 + 64 == 19 * traffic.STRATUM + 48
+    head = 19 * traffic.STRATUM
+    for key in (lambda r: len(r["tokens"]), lambda r: r["max_new_tokens"]):
+        assert sorted(map(key, new[:head])) == sorted(map(key, old[:head]))
+        assert sorted(map(key, new[:64])) == \
+            sorted(map(key, new[head:head + 64]))
+    # where the plan ends: every request but the callers' last in flight
+    # answered inside the window. One chip cannot decode this configuration
+    # that fast: max_batch streams a step of the weights' bytes at the HBM
+    # peak, before a byte of cache is read or a prompt prefilled
+    mean_reply = sum(r["max_new_tokens"] for r in new[:64]) / 64.0
+    ends_at = (len(new) - m["clients"]) * mean_reply / 30
+    peak = peaks.peaks_for("TPU v5 lite")
+    weights = 4 * flops.lm_matmul_params(cfg["model"])       # float32
+    ceiling = cfg["engine"]["max_batch"] * peak["hbm_bytes_per_s"] / weights
+    assert 113 < mean_reply < 114 and 18000 < ceiling < 19000
+    assert ends_at > 1.2 * ceiling
+    # the old plan ended within reach of the cell's level (4,190-4,340)
+    assert 4400 < (len(old) - 64) * mean_reply / 30 < 4600
+
+
+def closed_obs(sent, cap=40, clients=64, window_s=30.0, **over):
+    out = {"kind": "serve", "window_s": window_s,
+           "mix": {"loop": "closed", "request_rate_cap": cap,
+                   "clients": clients},
+           "late_s": [0.0] * sent}
+    out.update(over)
+    return out
+
+
+@pytest.mark.parametrize("obs,want", [
+    (closed_obs(1216), 100 * 1216 / 1264.0),            # part used
+    (closed_obs(1216, cap=200), 100 * 1216 / 6064.0),
+    (closed_obs(1264), 100.0),                          # used up
+    (closed_obs(77, cap=30, clients=6, window_s=2.5), 100 * 77 / 81.0),
+    (closed_obs(0), None),                              # nothing sent
+    (closed_obs(300, mix={"loop": "open", "rate_per_s": 10.4}), None),
+    ({"kind": "fit", "window_s": 30.0}, None),
+], ids=["part-used", "part-used-cap200", "used-up", "short-window",
+        "empty", "open-loop", "fit"])
+def test_plan_used_share_on_hand_made_observations(obs, want):
+    from benchmark.layer_metrics import plan_used_share
+    got = plan_used_share.read(obs)
+    assert got is None if want is None else got == pytest.approx(want)
+
+
+@pytest.mark.parametrize("cell", CLOSED_CELLS)
+def test_a_closed_plan_is_one_no_engine_can_use_up(cell):
+    """Every closed loop of the manifest, those of later PRs too: the plan
+    ends (every request but the callers' last in flight answered inside the
+    window) above 1.2 times what one chip can decode of the configuration,
+    ``max_batch`` streams a step of the weights' bytes at the HBM peak,
+    before a byte of cache is read or a prompt prefilled. The bytes are
+    every parameter's (shapes alone, from the configuration's own
+    ``init_params``): an embedding of which a step gathers rows is in them,
+    3 to 13% of these four, so the bound is that much lower than the
+    streamed bytes' (the chat cell's own case holds it to those)."""
+    import jax
+
+    from benchmark import run
+    cfg, mod, m, _path, _driver = run.resolve(
+        MANIFEST, run.find_cell(MANIFEST, cell))
+    shapes = jax.eval_shape(lambda: mod.init_params(cfg, 0))
+    weights = sum(leaf.size * leaf.dtype.itemsize
+                  for leaf in jax.tree_util.tree_leaves(shapes))
+    peak = peaks.peaks_for("TPU v5 lite")
+    ceiling = cfg["engine"]["max_batch"] * peak["hbm_bytes_per_s"] / weights
+    seconds = MANIFEST["run_seconds"]
+    block = traffic.plan(m, 5, seconds, 97)[:traffic.STRATUM]
+    mean_reply = sum(r["max_new_tokens"] for r in block) / float(len(block))
+    ends_at = (traffic.planned(m, seconds) - m["clients"]) \
+        * mean_reply / seconds
+    assert ends_at >= 1.2 * ceiling, (ends_at, ceiling)
+
+
+@pytest.mark.parametrize("cap,clients,seconds,want", [
+    (40, 64, 30, 1264), (200, 64, 30, 6064), (30, 6, 2.5, 81),
+    (0.5, 3, 3, 5)])
+def test_planned_is_the_length_of_the_plan(cap, clients, seconds, want):
+    m = {"loop": "closed", "clients": clients, "request_rate_cap": cap,
+         "prompt_len": {"dist": "uniform", "min": 2, "max": 4},
+         "output_len": {"dist": "uniform", "min": 2, "max": 4},
+         "max_total": 16}
+    assert traffic.planned(m, seconds) == want \
+        == len(traffic.plan(m, 3, seconds, 97))
+
+
+@pytest.mark.parametrize("unsent,correct", [(0, False), (1, True)],
+                         ids=["plan-used-up", "one-request-left"])
+def test_judge_refuses_a_run_whose_callers_used_up_the_plan(unsent, correct):
+    """``serve.judge`` over a hand-made report: every reply sound, so the
+    plan's end is the one thing that can be wrong with the run."""
+    import types
+
+    from benchmark.drivers import serve
+    m = {"driver": "serve", "loop": "closed", "clients": 4,
+         "request_rate_cap": 10,
+         "prompt_len": {"dist": "uniform", "min": 4, "max": 9},
+         "output_len": {"dist": "uniform", "min": 2, "max": 5},
+         "max_total": 32, "rescore": [{}, {}]}
+    seconds, vocab, seed = 2, 97, 5
+    planned = traffic.plan(m, seed, seconds, vocab)
+    assert len(planned) == 24
+    records = [{"i": r["i"], "due": 0.01 * r["i"], "sent": 0.01 * r["i"],
+                "done": 0.01 * r["i"] + 0.005, "status": "ok",
+                "asked": r["max_new_tokens"], "n_out": r["max_new_tokens"],
+                "tokens": [1] * r["max_new_tokens"], "ttft_s": 0.001,
+                "latency_s": 0.004, "preemptions": 0}
+               for r in planned[:len(planned) - unsent]]
+    report = {"t0": 0.0, "seconds": seconds, "late_start_s": 0.0,
+              "hung_threads": 0, "planned": len(planned),
+              "requests": records}
+    snap = {"compile": (3, 1.5), "steps": 0, "preemptions": 0,
+            "restarts": 0, "resilience": {}}
+    said = {}
+    ctx = types.SimpleNamespace(
+        config={"model": {"vocab": vocab}}, mix=m, seed=seed,
+        seconds=seconds, trace=False, t_start=-1.0,
+        say=lambda what, **fields: said.update({what: fields}))
+    sup = types.SimpleNamespace(engine=types.SimpleNamespace(params=None))
+    result = serve.judge(
+        ctx, sup, lambda params, prompt, tokens: ([], len(tokens)),
+        pool_tokens=0, tracer=None, report=report, before=snap, after=snap,
+        final=snap, t0=0.0)
+    assert result["correct"] is correct
+    assert result["attempted"] == len(records) and result["failed"] == 0
+    problems = said["client"]["problems"]
+    if correct:
+        assert problems == []
+    else:
+        assert len(problems) == 1 and "request_rate_cap" in problems[0] \
+            and "used up the plan's 24 requests" in problems[0]
 
 
 # ---------------------------------------------------------- arithmetic --

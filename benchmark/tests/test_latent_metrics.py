@@ -244,7 +244,7 @@ def test_the_mix_is_what_the_issue_says_and_its_picks_fit_the_reference():
                                       CONFIG + ".json")))
     assert (mix["driver"], mix["loop"], mix["clients"],
             mix["request_rate_cap"], mix["drain_s"]) == (
-        "serve_share", "closed", 256, 30, 60)
+        "serve_share", "closed", 256, 60, 60)
     assert mix["prompt_len"] == {"dist": "lognormal", "median": 512,
                                  "sigma": 0.8, "min": 64, "max": 2048}
     assert mix["output_len"] == {"dist": "lognormal", "median": 256,

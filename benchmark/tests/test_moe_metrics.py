@@ -138,13 +138,14 @@ def test_the_cell_is_in_the_manifest_with_its_files():
     for name in ("moe_time_share", "moe_roofline",
                  "moe_experts_touched_mean", "moe_load_max_over_mean"):
         m = by_name[name]
-        assert (m["layer"], m["moves"], m["workloads"]) == (
-            "Experts", "serve_out_tok_per_s", ["olmoe-chat-closed64"])
+        # this cell first; later cells with experts stand behind it
+        assert (m["layer"], m["moves"], m["workloads"][0]) == (
+            "Experts", "serve_out_tok_per_s", "olmoe-chat-closed64")
     for name in ("tpot_p50_ms", "decode_occupancy", "kv_pool_tokens",
                  "preemptions", "paged_attn_time_share", "decode_idle_share",
                  "decode_idle_host_share", "decode_idle_unnamed_share",
                  "peak_hbm_gb"):
-        assert by_name[name]["workloads"][-1] == "olmoe-chat-closed64"
+        assert "olmoe-chat-closed64" in by_name[name]["workloads"]
     cfg = json.load(open(os.path.join(
         ROOT, "benchmark", "configs", "olmoe-1b-7b-bf16.json")))
     # the catalog's numbers, under its keys, at the top level

@@ -117,9 +117,12 @@ def test_the_cell_is_in_the_manifest_with_its_files():
                  "decode_idle_host_share", "decode_idle_unnamed_share",
                  "peak_hbm_gb"):
         assert CELL in by_name[name]["workloads"]
+    # the two compile metrics list every cell there is, in the manifest's
+    # order (test_benchmark_harness.py holds "every"; here: as far as this)
+    cells = [c["name"] for c in manifest["workloads"]]
     for name in ("compile_s", "compiles_in_window"):
-        assert by_name[name]["workloads"] == [
-            c["name"] for c in manifest["workloads"]][:6]
+        listed = by_name[name]["workloads"]
+        assert CELL in listed and listed == cells[:len(listed)]
     for name in by_name:
         if name.startswith("moe_") or name.endswith("_pool_copy_share"):
             assert CELL not in by_name[name]["workloads"]

@@ -65,6 +65,12 @@ def arrival_times(mix, seconds, rng):
     return sorted(rng.random() * seconds for _ in range(n))
 
 
+def planned(mix, seconds):
+    """How many requests a closed loop's plan holds: what the callers can
+    consume at ``request_rate_cap`` a second, and one more a caller."""
+    return int(math.ceil(mix["request_rate_cap"] * seconds)) + mix["clients"]
+
+
 def plan(mix, seed, seconds, vocab):
     """The run's requests, in sending order:
     ``[{"i", "due" (open loop; seconds after the window opens), "tokens",
@@ -75,7 +81,7 @@ def plan(mix, seed, seconds, vocab):
         due = arrival_times(mix, seconds, rng)
         n = len(due)
     elif mix["loop"] == "closed":
-        n = int(math.ceil(mix["request_rate_cap"] * seconds)) + mix["clients"]
+        n = planned(mix, seconds)
         due = [None] * n
     else:
         raise ValueError("loop must be open or closed, not %r" % mix["loop"])
