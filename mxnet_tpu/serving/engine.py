@@ -320,7 +320,7 @@ class ServingEngine:
         self._n_timed_out = 0
         self._n_cancelled = 0
         self._n_shed = 0
-        self._token_window = []   # one timestamp per token, for tokens/sec
+        self._token_window = []   # (timestamp, tokens) a booking, for tokens/sec
         # routed experts: per-layer, per-expert tokens since the engine
         # started (the step's own (L, E) count rides in the token fetch)
         self._moe_load = np.zeros((cfg.expert_layers, cfg.experts_here[1]),
@@ -351,6 +351,10 @@ class ServingEngine:
         self._fetch_end = None
         self._idle_s = 0.0
         self._gap_after = None
+        # the last step's bookkeeping that no dispatch needs (_set_aside),
+        # until the next step carries it out under its own dispatch or a
+        # reader flushes it (_flush); whoever holds the step lock owns it
+        self._pending = None
         self._t_started = time.time()
         self._tokens_total = 0
         # per-engine identity: labels this engine's histograms/counters in
@@ -441,7 +445,7 @@ class ServingEngine:
         # model shape + pool geometry + bucket. aot=True: each bucket is a
         # single-signature site, the serialized-executable fast lane — a
         # warm replica's warmup() loads every bucket from disk instead of
-        # compiling it (tools/serve.py --warmup, bench_serving warmup_s).
+        # compiling it (tools/serve.py --warmup; benchmark/run.py's set-up).
         ckey_base = cfg.key() + (cfg.block_size, cfg.num_blocks,
                                  str(cfg.kv_dtype))
         if cfg.hybrid:
@@ -661,6 +665,7 @@ class ServingEngine:
                                 waiting=len(self.scheduler.waiting),
                                 active=len(self.scheduler.running))
                 self._work.notify_all()
+            self._flush()
 
     @property
     def draining(self):
@@ -683,30 +688,38 @@ class ServingEngine:
     def step(self):
         """One engine iteration: schedule, prefill admissions, fused decode,
         retire finished requests. Returns the requests that finished.
+        Whoever steps by hand reads the step whole when this returns:
+        what it set aside for later (:meth:`_set_aside`) is carried out
+        first; :meth:`run_loop` leaves it to the next step's dispatch.
 
         A failure escaping the step (device error, XLA crash) aborts the
         engine before re-raising — the pool pages may have been donated
         into the failed dispatch and cannot be trusted, so EVERY driver
-        (run_loop, :meth:`generate`, bench/step-polling loops) gets the
+        (run_loop, :meth:`generate`, step-polling loops) gets the
         same contract: pending requests fail loudly, waiters wake, later
         submits refuse."""
+        return self._guarded_step(defer=False)
+
+    def _guarded_step(self, defer):
         try:
             # the step span opens BEFORE the lock: a driver queued behind
             # submit() shows as serving.step.lock on the trace, not as a gap
             # fwlint: disable=unguarded-shared-write — _steps is read for a label on the trace; only the stepping thread writes it
             with telemetry.span("serving.step", _CAT, step=self._steps):
-                return self._step()
+                return self._step(defer)
         except Exception as exc:
             self.abort(exc)
             raise
 
-    def _step(self):
+    def _step(self, defer):
         """The step's sections, each under its span (docs/observability.md
         has the table): lock, schedule, prefills, schedule again after a
         prefill, decode, retire. While telemetry is on, a non-empty step
         also leaves ONE record of itself (``obs.LoopRecord``: each
         section's seconds from its span, the two host gaps) in the
-        engine's ring and on its ``serving.step_timeline`` event."""
+        engine's ring and on its ``serving.step_timeline`` event — once
+        what the step set aside has been carried out: during the next
+        step with ``defer``, before this returns without."""
         with telemetry.span("serving.step.lock", _CAT) as lock:
             self._lock.acquire()
         try:
@@ -735,7 +748,7 @@ class ServingEngine:
                     self.obs.request_admitted(req)
                 failed = self._drain_failed()
                 if plan.empty():
-                    return failed
+                    return self._step_end(failed, defer)
                 n_preempted = len(plan.preempted)
                 decodes = () if plan.prefills else self._decodable()
             self._book("schedule_s", sched)
@@ -760,16 +773,15 @@ class ServingEngine:
             elif decodes:
                 # fwlint: disable=lock-order — injected dispatch fault may stall; matches real device-dispatch blocking under the step lock
                 fetched = self._run_decode(decodes)
+            # the device idles from the chunk's fetch to the next step's
+            # first dispatch: this section holds what the scheduler, the
+            # next build or a waiting caller reads, and sets the rest aside
             with telemetry.span("serving.retire", _CAT) as retire:
+                booking = None
                 if decodes:
-                    if self._spec:
-                        noted = self._note_spec_decode(decodes, fetched)
-                    else:
-                        noted = self._note_decode(decodes, fetched)
-                    retire.set(**noted)
-                    if rec is not None:
-                        rec["chunk_steps"] = noted.pop("steps")
-                        rec.update(noted)
+                    note = (self._note_spec_decode if self._spec
+                            else self._note_decode)
+                    booking = note(decodes, fetched, retire)
                 # one stretch, so a span of its own: the trace and the
                 # idle gaps' labels name it too
                 with telemetry.span("serving.retire.finish", _CAT) as finish:
@@ -780,31 +792,83 @@ class ServingEngine:
                         self._retire(req)
                 self._book("retire_finish_s", finish)
                 self._steps += 1
-                clock = self._clock()
-                t0 = clock()
-                self._refresh_throughput()
                 n_finished = len(finished) + len(failed)
                 retire.set(finished=n_finished)
+                clock = self._clock()
+                t0 = clock()
+                # the record's counts and the event's occupancy, queue and
+                # pool are READ here, as the step leaves them; the record
+                # is closed and the event made when the item is carried out
+                self._set_aside(booking, None if rec is None else (
+                    dict(step=self._steps, prefills=len(plan.prefills),
+                         lanes=len(decodes), finished=n_finished),
+                    dict(occupancy=len(decodes),
+                         admitted=len(plan.prefills), preempted=n_preempted,
+                         queue=len(self.scheduler.waiting),
+                         running=len(self.scheduler.running),
+                         kv_used=self.pool.used(),
+                         kv_free=self.pool.available(),
+                         kv_frag_slots=self.scheduler.frag_slots())))
                 if rec is not None:
                     rec["retire_counters_s"] += clock() - t0
-            # the record is closed after the counters are booked and its
-            # last section has ended, under the same lock: what it costs
-            # (obs.step_timeline: the tuple, the sums, the event) is in the
-            # NEXT step's gap_chunk_s and in no section of this one
             self._book("retire_s", retire)
-            if rec is not None:
-                rec.update(step=self._steps, prefills=len(plan.prefills),
-                           lanes=len(decodes), finished=n_finished)
-            self.obs.step_timeline(
-                rec, occupancy=len(decodes), admitted=len(plan.prefills),
-                preempted=n_preempted, queue=len(self.scheduler.waiting),
-                running=len(self.scheduler.running),
-                kv_used=self.pool.used(), kv_free=self.pool.available(),
-                kv_frag_slots=self.scheduler.frag_slots())
-            return finished + failed
+            return self._step_end(finished + failed, defer)
         finally:
             self._rec = None
             self._lock.release()
+
+    def _step_end(self, out, defer):
+        """A step's return, its lock still held: without ``defer`` the
+        step's own item is carried out first, on no step's record."""
+        self._rec = None
+        if not defer:
+            self._flush()
+        return out
+
+    # ---- what a step sets aside ---------------------------------------
+    def _set_aside(self, booking, closing):
+        """Leave for later what no dispatch needs of the step that ends:
+        ``booking``, the deferred half of the chunk's bookkeeping (a
+        method and its arguments, from :meth:`_note_decode` or
+        :meth:`_note_spec_decode`; None for a step without a chunk), the
+        throughput window, and ``closing`` the step's record (its counts
+        and the ``serving.step_timeline`` event's readings; None while
+        telemetry is off). ONE item: one still waiting (no dispatch
+        followed it: a step whose prompts all ended at their first token)
+        is carried out first."""
+        self._flush()
+        self._pending = (self._rec, booking, closing)
+
+    def _flush(self, hidden=False):
+        """Carry out the item :meth:`_set_aside` left, if any. ``hidden``:
+        the caller has just returned from the step's last dispatch call
+        before a blocking fetch, so the device works meanwhile (step N's
+        item under step N+1's chunk) and none of this is in a host gap.
+        Every other caller — ``stats()``, the public ``step()``, a wait on
+        an empty queue, ``run_loop``'s return, ``abort``, a drain's start,
+        the programs run outside a step — holds the step lock and flushes
+        before it reads or runs anything: no reader sees a chunk unbooked
+        or half booked. The seconds go to the record of the step under
+        way, if one is (``deferred_s``); ``deferred_hidden`` to the item's
+        own."""
+        item, self._pending = self._pending, None
+        if item is None:
+            return
+        rec, booking, closing = item
+        with telemetry.span("serving.retire.deferred", _CAT,
+                            hidden=int(hidden)) as deferred:
+            noted = {}
+            if booking is not None:
+                book, args = booking
+                noted = book(*args)
+                deferred.set(**noted)
+            self._refresh_throughput()
+            if rec is not None:
+                counts, timeline = closing
+                rec["chunk_steps"] = noted.pop("steps", 0)
+                rec.update(noted, deferred_hidden=int(hidden), **counts)
+                self.obs.step_timeline(rec, **timeline)
+        self._book("deferred_s", deferred)
 
     # ---- the step's record (obs.LoopRecord) ---------------------------
     def _book(self, field, span):
@@ -871,23 +935,42 @@ class ServingEngine:
         FAILED with the error and woken, later submits refuse — and the
         re-raise propagates here so the driver thread's death is
         observable (``Thread.is_alive()`` backs serve.py's
-        ``/healthz``)."""
-        while stop_event is None or not stop_event.is_set():
-            with self._work:
-                if not self.scheduler.has_work():
-                    # idle steps never run, so decay the sliding
-                    # tokens/sec window here or it freezes at its last
-                    # loaded value on a quiet server
-                    self._refresh_throughput()
-                    # an empty queue, by name: device idle under this span
-                    # is idle that no change to the loop can take away
-                    with telemetry.span("serving.loop.idle", _CAT) as idle:
-                        self._work.wait(timeout=idle_wait_s)
-                    # no fault of the loop's: out of the step's gap_chunk_s
-                    self._idle_s += idle.seconds or 0.0
+        ``/healthz``).
+
+        What a step set aside (:meth:`_set_aside`) is carried out under
+        the next step's dispatch; before a wait on an empty queue and
+        before this returns, here."""
+        try:
+            while stop_event is None or not stop_event.is_set():
+                # one more take of the lock a step than the step's own, and
+                # it stays: the lock is not fair, and a caller blocked in
+                # submit() gets its second chance a step here. Asking under
+                # the step's own lock read +15 ms on the open loop's
+                # queue_wait_p95_ms, three seeds of three (PERF.md, PR 39)
+                with self._work:
                     if not self.scheduler.has_work():
-                        continue
-            self.step()
+                        # no step follows: the last one's item is carried
+                        # out on the idle device
+                        self._flush()
+                        # idle steps never run, so decay the sliding
+                        # tokens/sec window here or it freezes at its last
+                        # loaded value on a quiet server
+                        self._refresh_throughput()
+                        # an empty queue, by name: device idle under this
+                        # span is idle that no change to the loop can take
+                        # away
+                        with telemetry.span("serving.loop.idle",
+                                            _CAT) as idle:
+                            self._work.wait(timeout=idle_wait_s)
+                        # no fault of the loop's: out of the step's
+                        # gap_chunk_s
+                        self._idle_s += idle.seconds or 0.0
+                        if not self.scheduler.has_work():
+                            continue
+                self._guarded_step(defer=True)
+        finally:
+            with self._lock:
+                self._flush()
 
     def abort(self, exc):
         """Fail every queued and running request (the driver died mid-
@@ -904,6 +987,8 @@ class ServingEngine:
         greedy decode finishes them bit-identical to an unfaulted run."""
         msg = "serving engine aborted: %r" % (exc,)
         with self._lock:
+            # the chunks whose tokens were delivered are the chunks booked
+            self._flush()
             self._aborted = msg
             self._drain_failed()   # scheduler failures the step never saw
             reqs = list(self.scheduler.running) + list(self.scheduler.waiting)
@@ -985,6 +1070,7 @@ class ServingEngine:
             raise ValueError("no prefill bucket %s (buckets %s)"
                              % (unknown, cfg.prefill_buckets()))
         with self._lock:
+            self._flush()
             for S in prefill_buckets:
                 toks = np.zeros((1, S), np.int32)
                 table = np.zeros(S // cfg.block_size, np.int32)
@@ -1050,6 +1136,7 @@ class ServingEngine:
         toks[0, :n] = tokens
         table = np.zeros(S // cfg.block_size, np.int32)
         with self._lock:
+            self._flush()
             _t, logits = self._dispatch_prefill(toks, n, table)
         return np.asarray(logits, np.float32)[0]  # fwlint: disable=device-escape — the logits are what the caller asked for
 
@@ -1077,6 +1164,7 @@ class ServingEngine:
         lanes = [Request(text[:n], 1) for text, n in zip(texts, starts)]
         out = np.zeros((len(texts), cfg.vocab_size), np.float32)
         with self._lock:
+            self._flush()
             try:
                 for req, text, n in zip(lanes, texts, starts):
                     req.blocks = self.pool.alloc(
@@ -1312,9 +1400,18 @@ class ServingEngine:
         program, where k dispatch-fetch pairs exposed k. Every first token
         is on the host when this returns: nothing is in flight when the
         step schedules its decode. A group of one is a dispatch and its
-        fetch, as a prompt alone always was."""
+        fetch, as a prompt alone always was. Where no chunk follows the
+        group, its last dispatch is the step's last before a blocking
+        fetch: the step before's item (:meth:`_flush`) runs under it."""
         grouped = len(reqs) > 1
         flights = [self._start_prefill(req, grouped) for req in reqs]
+        # no chunk follows where every prompt ends at its first token and
+        # nothing else decodes (an EOS there cannot be foreseen: _set_aside
+        # then finds the item still waiting)
+        if not (any(r.pending_token is not None or r.max_new_tokens > 1
+                    for r in reqs)
+                or any(r.state == DECODING for r in self.scheduler.running)):
+            self._flush(hidden=True)
         for flight in flights:
             self._finish_prefill(*flight)
         # the chunk's dispatch closes the gap the group's last fetch opened
@@ -1434,7 +1531,7 @@ class ServingEngine:
         frees and schedules. A lane runs for its own ``steps_left``; the
         chunk is as long as its longest lane's, not cut to the first that
         finishes (a stream ends every few steps of a full batch, and the
-        chunk would never form). Returns what :meth:`_note_decode` books."""
+        chunk would never form). Returns what :meth:`_note_decode` takes."""
         cfg = self.config
         B = _bucket_for(len(reqs), cfg.decode_buckets())
         args = {"batch": len(reqs), "bucket": B}
@@ -1480,6 +1577,9 @@ class ServingEngine:
                 n=n)
             self._dispatched(dispatch)
         self._book("decode_dispatch_s", dispatch)
+        # the device has the chunk to run: the step before's bookkeeping
+        # goes here, ahead of the fetch the host would only wait in
+        self._flush(hidden=True)
         with telemetry.span("serving.decode.fetch", _CAT, **args) as fetch:
             # the chunk's single device->host sync: its rows of next
             # tokens (with the experts' load of each step behind them,
@@ -1496,30 +1596,53 @@ class ServingEngine:
             self.obs.decode_stall(reqs, min(s1 - s0, wall))
         return nxt, load, left, n
 
-    def _note_decode(self, reqs, fetched):
-        """Book a fetched chunk, inner step by inner step in order, each
-        as ONE decode step: the step's LIVE lanes (a lane's tokens up to
-        its death: its ``steps_left`` used up or its EOS) in
+    def _note_decode(self, reqs, fetched, retire):
+        """The gap's half of a fetched chunk: each lane takes its tokens,
+        in order, up to its death (its ``steps_left`` used up, its EOS or
+        its length), which is what the scheduler, the next build and a
+        waiting caller read. What the counters need of the chunk falls out
+        of that: each lane's context length at the chunk's start and the
+        tokens it took. Returns :meth:`_book_decode` and its arguments,
+        for :meth:`_set_aside`; the record's two parts of this section are
+        timed here (three clock reads a chunk)."""
+        nxt, load, left, n = fetched
+        clock = self._clock()
+        t_in = clock()
+        ctx0 = np.array([req.context_len for req in reqs])  # fwlint: disable=device-escape — host integers, nothing of the device's
+        alive = np.zeros_like(ctx0)
+        rows = nxt[:n, :len(reqs)].T.tolist()
+        t0 = clock()
+        for i, req in enumerate(reqs):
+            if req.state == DECODING:
+                alive[i] = took = self._deliver(req, rows[i][:left[i]])
+                req.context_len += took
+        stamp = time.time()
+        self._decode_dispatches += 1
+        self._decode_inner_steps += n
+        retire.set(steps=n, lane_steps=int(alive.sum()))
+        if self._rec is not None:
+            self._rec["retire_counters_s"] += t0 - t_in
+            self._rec["retire_tokens_s"] += clock() - t0
+        return self._book_decode, (ctx0, alive, n, load, stamp)
+
+    def _book_decode(self, ctx0, alive, n, load, stamp):
+        """The deferred half: book the chunk inner step by inner step in
+        order, each as ONE decode step. Step ``j``'s LIVE lanes are those
+        that took more than ``j`` tokens, at context ``ctx0 + j + 1``: in
         ``serving.decode_batch``, the blocks and states they walked, the
         experts' load of that step against those lanes. Returns the
-        chunk's sums, for the step's ``serving.retire`` span and its
-        record, whose two interleaved parts are timed here (two clock reads
-        an inner step): booking the counters, and delivering the tokens
-        (the rest of the loop: the ``live`` list, ``_note_token``)."""
-        nxt, load, left, n = fetched
+        chunk's sums, for the ``serving.retire.deferred`` span and the
+        step's record."""
         cfg = self.config
         noted = {"steps": n, "lane_steps": 0, "live_blocks": 0}
-        clock = self._clock()
-        counters, t_in = 0.0, clock()
         for j in range(n):
-            live = [(i, req) for i, req in enumerate(reqs)
-                    if j < left[i] and req.state == DECODING]
-            if not live:
+            live = alive > j
+            lanes = int(live.sum())
+            if not lanes:
                 break       # every lane met its EOS: the rest ran dead
-            t0 = clock()
-            ctx = np.array([req.context_len + 1 for _i, req in live])  # fwlint: disable=device-escape — host integers, nothing of the device's
+            ctx = ctx0[live] + (j + 1)
             blocks = self._note_paged(ctx)
-            noted["lane_steps"] += len(live)
+            noted["lane_steps"] += lanes
             noted["live_blocks"] += blocks
             if self.streams is not None:
                 for k, v in self._note_hybrid(ctx, blocks).items():
@@ -1528,16 +1651,8 @@ class ServingEngine:
                 self._note_latent(ctx, blocks)
             if load is not None:
                 self._note_moe(load[j], int((ctx <= cfg.max_len).sum()))
-            telemetry.histogram("serving.decode_batch").observe(len(live))
-            counters += clock() - t0
-            for i, req in live:
-                req.context_len += 1
-                self._note_token(req, int(nxt[j, i]))
-        if self._rec is not None:
-            self._rec["retire_counters_s"] += counters
-            self._rec["retire_tokens_s"] += clock() - t_in - counters
-        self._decode_dispatches += 1
-        self._decode_inner_steps += n
+            telemetry.histogram("serving.decode_batch").observe(lanes)
+        self._book_tokens(int(alive.sum()), stamp)
         telemetry.counter("serving.decode.dispatches").inc()
         telemetry.counter("serving.decode.inner_steps").inc(n)
         return noted
@@ -1592,10 +1707,10 @@ class ServingEngine:
         # ctx_* are the window's first lane's (a verify pass reads k more)
         # live_blocks is the verify pass's: its kernel walks to the window's
         # last lane (the draft's own pool is not booked)
+        window_ctx = np.minimum(np.add(base_ctx, k + 1), cfg.max_len)
         args = {"spec": 1, "batch": n, "bucket": B,
                 "ctx_tokens": sum(base_ctx) + B, "ctx_max": max(base_ctx) + 1,
-                "live_blocks": self._note_paged(np.minimum(
-                    np.add(base_ctx, k + 1), cfg.max_len))}
+                "live_blocks": self._blocks(window_ctx)}
         with telemetry.span("serving.decode.build", _CAT, phase="draft",
                             **args) as build:
             tables = np.zeros((B, nb), np.int32)
@@ -1666,6 +1781,8 @@ class ServingEngine:
                 self.pool.k_pages, self.pool.v_pages)
             self.pool.k_pages, self.pool.v_pages = kp, vp
         self._book("decode_dispatch_s", dispatch)
+        # the window's last dispatch: its fetch is the one the host waits in
+        self._flush(hidden=True)
         with telemetry.span("serving.decode.fetch", _CAT, phase="verify",
                             **args) as fetch:
             nxt2 = np.asarray(nxt2)  # fwlint: disable=device-escape — token egress to clients is the product, B×(k+1) int32s per step
@@ -1681,26 +1798,23 @@ class ServingEngine:
         self._spec_draft_s += draft_wall
         self._spec_verify_s += verify_wall
         return (nxt2, proposals, draft_wall - draft_stall,
-                verify_wall - verify_stall, load, args["live_blocks"])
+                verify_wall - verify_stall, load, base_ctx, window_ctx)
 
-    def _note_spec_decode(self, reqs, fetched):
+    def _note_spec_decode(self, reqs, fetched, retire):
         """Greedy acceptance — emit the TARGET's token at every reached
         lane. Lane j+1 is reached only if the draft's proposal d_{j+1}
         MATCHED the target's lane-j output (the window's K/V past a
         mismatch encodes the draft's wrong token, so stop there; the
-        stale writes are overwritten by the next step's lane 0). Returns
-        what :meth:`_note_decode` returns, the window one step of a chunk,
-        and times the record's same two parts."""
-        nxt2, proposals, draft_s, verify_s, load, live_blocks = fetched
+        stale writes are overwritten by the next step's lane 0). The
+        gap's half, as :meth:`_note_decode` is of a chunk: returns
+        :meth:`_book_window` and its arguments, and times the record's
+        same two parts."""
+        nxt2, proposals, draft_s, verify_s, load, base_ctx, window_ctx = \
+            fetched
         k = self.spec_k
         clock = self._clock()
         t_in = clock()
-        if load is not None:
-            self._note_moe(load, sum(
-                min(k + 1, max(0, self.config.max_len - req.context_len))
-                for req in reqs))
-        proposed = accepted = 0
-        t_tokens = clock()
+        proposed = accepted = emitted = 0
         for i, req in enumerate(reqs):
             proposed += k
             for j in range(k + 1):
@@ -1708,22 +1822,42 @@ class ServingEngine:
                 if tok < 0:
                     break   # overflow-poisoned lane (past max_len)
                 req.context_len += 1
-                self._note_token(req, tok)
+                emitted += self._deliver(req, (tok,))
                 if req.state != DECODING or j >= k \
                         or proposals[i][j] != tok:
                     break
                 accepted += 1
+        stamp = time.time()
         t_out = clock()
         self._spec_proposed += proposed
         self._spec_accepted += accepted
-        telemetry.histogram("serving.decode_batch").observe(len(reqs))
+        # the draft/verify split is on the phase clock of a request that
+        # finishes in this very section
         self.obs.spec_step(reqs, draft_s, verify_s, proposed, accepted)
+        retire.set(steps=1, lane_steps=len(reqs))
         if self._rec is not None:
-            self._rec["retire_tokens_s"] += t_out - t_tokens
-            self._rec["retire_counters_s"] += (
-                t_tokens - t_in + clock() - t_out)
-        return {"steps": 1, "lane_steps": len(reqs),
-                "live_blocks": live_blocks}
+            self._rec["retire_tokens_s"] += t_out - t_in
+            self._rec["retire_counters_s"] += clock() - t_out
+        return self._book_window, (base_ctx, window_ctx, load, emitted,
+                                   stamp)
+
+    def _book_window(self, base_ctx, window_ctx, load, emitted, stamp):
+        """The deferred half of a draft-and-verify window, booked as one
+        step of a chunk: the blocks the verify pass walked (to the
+        window's last lane; the draft's own pool is not booked) and the
+        experts' load against the positions it scored."""
+        k, max_len = self.spec_k, self.config.max_len
+        blocks = self._note_paged(window_ctx)
+        self._note_moe(load, sum(min(k + 1, max(0, max_len - ctx))
+                                 for ctx in base_ctx))
+        telemetry.histogram("serving.decode_batch").observe(len(base_ctx))
+        self._book_tokens(emitted, stamp)
+        return {"steps": 1, "lane_steps": len(base_ctx),
+                "live_blocks": blocks}
+
+    def _blocks(self, ctx):
+        """The blocks that hold contexts of ``ctx`` tokens, summed."""
+        return int((-(-ctx // self.config.block_size)).sum())
 
     def _note_paged(self, ctx):
         """Book one decode or verify pass of the paged kernel from the
@@ -1731,7 +1865,7 @@ class ServingEngine:
         (``ceil(ctx / block_size)`` a stream) and the table slots those
         streams hold (``nb_max`` each; what the kernel's grid walked
         before PR 27). Returns the blocks, for the step's spans."""
-        live = int((-(-ctx // self.config.block_size)).sum())
+        live = self._blocks(ctx)
         slots = len(ctx) * self._nb_max
         self._paged_live_blocks += live
         self._paged_table_slots += slots
@@ -1793,20 +1927,40 @@ class ServingEngine:
             _max_over_mean(load))
 
     def _note_token(self, req, tok):
-        now = time.time()
+        """A prompt's first token, delivered and booked at once (a group's
+        are booked under the device time of the prompts behind them)."""
+        self._deliver(req, (tok,))
+        self._book_tokens(1, req.first_token_t)
+
+    def _deliver(self, req, toks):
+        """Hand ``req`` its next tokens, in order, up to the one that ends
+        it (its length, its EOS); the number it took. This is what the
+        scheduler, the next build and the caller read of a token; its
+        telemetry is :meth:`_book_tokens`'. A stream's first token reads
+        the clock itself: the phase clock needs the instant."""
         if req.first_token_t is None:
-            req.first_token_t = now
+            req.first_token_t = now = time.time()
             telemetry.histogram("serving.ttft_seconds").observe(
                 now - req.arrival_t)
-        req.generated.append(tok)
-        req.pending_token = tok
-        self._tokens_total += 1
-        self._token_window.append(now)
-        telemetry.counter("serving.generated_tokens").inc()
-        if (len(req.generated) >= req.max_new_tokens
-                or (req.eos_id is not None and tok == req.eos_id)):
-            req.state = FINISHED
-            req.pending_token = None
+        generated, took = req.generated, 0
+        for tok in toks:
+            generated.append(tok)
+            took += 1
+            req.pending_token = tok
+            if (len(generated) >= req.max_new_tokens
+                    or (req.eos_id is not None and tok == req.eos_id)):
+                req.state = FINISHED
+                req.pending_token = None
+                break
+        self._tokens_total += took
+        return took
+
+    def _book_tokens(self, n, stamp):
+        """``n`` tokens delivered at ``stamp`` (wall clock), for the
+        counter and the sliding tokens/sec window."""
+        if n:
+            self._token_window.append((stamp, n))
+            telemetry.counter("serving.generated_tokens").inc(n)
 
     def _retire(self, req):
         req.finish_t = time.time()
@@ -1825,10 +1979,11 @@ class ServingEngine:
     def _refresh_throughput(self, window_s=10.0):
         now = time.time()
         cut = now - window_s
-        w = self._token_window = [t for t in self._token_window if t >= cut]
+        w = self._token_window = [e for e in self._token_window
+                                  if e[0] >= cut]
         span = now - max(cut, self._t_started)
         telemetry.gauge("serving.tokens_per_sec").set(
-            len(w) / span if span > 0 else 0.0)
+            sum(n for _t, n in w) / span if span > 0 else 0.0)
 
     # ------------------------------------------------------------ stats
     def _state_stats(self):
@@ -1860,6 +2015,7 @@ class ServingEngine:
         numbers (the bare-name histograms still aggregate process-wide
         for dashboards)."""
         with self._lock:
+            self._flush()       # every chunk delivered is a chunk booked
             self._refresh_throughput()   # a stale window must read as 0
             eid = str(self.engine_id)
             lat = telemetry.histogram("serving.request_latency_seconds",

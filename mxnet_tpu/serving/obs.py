@@ -83,8 +83,8 @@ _BURN_MIN_SAMPLES = 8
 
 #: Steps an engine's ring of loop records keeps: the longest window a
 #: benchmark run may have (51 s) at the ~30 steps a second of its busiest
-#: cell, with set-up's steps before it. A record is ~0.8 KB (25 fields,
-#: most of them floats of their own): a full ring is ~6 MB.
+#: cell, with set-up's steps before it. A record is ~0.8 KB (27 fields,
+#: most of them floats of their own): a full ring is ~7 MB.
 LOOP_RING = 8192
 
 #: Engines whose rings :func:`loop_records` still reaches (a supervisor's
@@ -104,7 +104,8 @@ class LoopRecord(namedtuple("LoopRecord", (
         "retire_finish_s",
         "lane_steps", "live_blocks", "window_live_blocks",
         "full_live_blocks",
-        "gap_chunk_s", "gap_group_s", "finished"))):
+        "gap_chunk_s", "gap_group_s", "finished",
+        "deferred_s", "deferred_hidden"))):
     """What the engine loop did in ONE non-empty step, on the host's clock
     (docs/observability.md has the table of fields, where each is measured
     and the benchmark metric that reads it).
@@ -113,15 +114,32 @@ class LoopRecord(namedtuple("LoopRecord", (
     the clock reads the histograms already had), summed where a step runs
     the section more than once (both ``serving.schedule`` sections; the
     four prefill sections over the step's group; draft and verify on the
-    speculative path). ``retire_s`` is the step's ``serving.retire`` whole;
-    of it ``retire_counters_s`` books the chunk's counters,
-    ``retire_tokens_s`` delivers its tokens and ``retire_finish_s`` (the
-    nested span ``serving.retire.finish``) finishes requests; the three
-    need not add up to it. Counts: ``prefills`` (the group's prompts),
-    ``chunk_steps`` (the decode dispatch's trip count, 0 for a step
-    without one), ``lanes``, and what the chunk's steps walked
-    (``lane_steps``, ``live_blocks``; a model with window layers also its
-    window and full-pool walks).
+    speculative path). ``retire_s`` is the step's ``serving.retire`` whole,
+    the section in the gap after the chunk; of it ``retire_tokens_s``
+    delivers the chunk's tokens, ``retire_finish_s`` (the nested span
+    ``serving.retire.finish``) finishes requests, and
+    ``retire_counters_s`` is what bookkeeping is LEFT there: the lanes'
+    context lengths taken before the tokens go out, and the readings the
+    step's record and event are later made from; the three need not add
+    up to it. Counts: ``prefills`` (the group's prompts), ``chunk_steps``
+    (the decode dispatch's trip count, 0 for a step without one),
+    ``lanes``, and what the chunk's steps walked (``lane_steps``,
+    ``live_blocks``; a model with window layers also its window and
+    full-pool walks).
+
+    **The rest of a step's bookkeeping is set aside** (the chunk's
+    counters an inner step, the tokens' telemetry, the throughput window,
+    closing this record and making its event:
+    ``ServingEngine._set_aside``) and carried out after the NEXT step's
+    last dispatch call before a blocking fetch has returned, while the
+    device works, under the span ``serving.retire.deferred``. So the
+    record of step N enters the ring during step N+1, or at the next read
+    (``stats()`` and every other reader flush first). ``deferred_s``: the
+    seconds THIS step spent carrying out the step before's item (0.0 where
+    something else flushed it). ``deferred_hidden``: 1 where this step's
+    OWN item was carried out under a dispatch, 0 where a read, a wait on an
+    empty queue or the loop's return flushed it; it sums to the items that
+    cost the device nothing.
 
     **The two gaps are the point.** The host is synchronous at every fetch:
     from a blocking fetch's return to the next dispatch call's return the
@@ -450,7 +468,9 @@ class ServingObs:
         dict of :func:`open_record`, filled by the step; None while
         telemetry is off: nothing is assembled): the :class:`LoopRecord`
         into this engine's ring and sums, and the ``serving.step_timeline``
-        event with the occupancy beside the record's fields."""
+        event with the occupancy beside the record's fields. Called when
+        the step's deferred item is carried out, with the occupancy as the
+        step read it."""
         if record is None:
             return
         self._ring.append(LoopRecord(**record))
@@ -471,9 +491,10 @@ class ServingObs:
                         running=running, kv_used=kv_used, kv_free=kv_free,
                         kv_frag_slots=kv_frag_slots, **record)
 
-    # ---- snapshots (stats() / serve.py / bench) -----------------------
+    # ---- snapshots (stats(): serve.py, the benchmark's drivers) --------
     def slo_snapshot(self):
-        """This engine's SLO block for ``stats()``/bench JSON."""
+        """This engine's SLO block for ``stats()`` (the benchmark's
+        drivers read it there)."""
         att = {ph: (self._good[ph] / self._total[ph]
                     if self._total[ph] else None)
                for ph in ("ttft", "tpot")}

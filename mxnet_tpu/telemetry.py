@@ -801,6 +801,11 @@ METRIC_HELP = {
     "serving.decode.fetch": "decode next-token blocking fetch (span)",
     "serving.retire":
         "token bookkeeping and retirement after a prefill or a step (span)",
+    "serving.retire.deferred":
+        "the bookkeeping a step set aside (the chunk's counters, the "
+        "tokens' telemetry, the throughput window, the step's record and "
+        "event), carried out under the next step's dispatch (hidden=1) or "
+        "by a read, an idle wait or the loop's return (span)",
     "serving.retire.finish":
         "the finished sweep inside a step's serving.retire: "
         "scheduler.finish and _retire a finished request (span)",

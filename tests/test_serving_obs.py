@@ -347,7 +347,7 @@ def test_disabled_telemetry_still_traces_and_judges():
 SECONDS = [f for f in LoopRecord._fields if f.endswith("_s")]
 SECTIONS = [f for f in SECONDS
             if not f.startswith(("gap_", "retire_counters", "retire_tokens",
-                                 "retire_finish"))]
+                                 "retire_finish", "deferred"))]
 
 
 class TickClock:
@@ -426,7 +426,7 @@ def test_one_record_a_non_empty_step_with_every_field(ticks):
     before = time.time()
     rec, wall, progs, fetched = _step_logged(eng, clock, fetches, calls)
     assert len(ring) == 1 and ring[0] is rec
-    assert rec._fields == LoopRecord._fields and len(rec) == 25
+    assert rec._fields == LoopRecord._fields and len(rec) == 27
     assert before <= rec.ts <= time.time() and rec.step == eng._steps == 1
     # two prompts as one group, then a chunk of their lanes
     assert [p for p, _t in progs] == ["prefill", "prefill", "decode"]
@@ -437,10 +437,15 @@ def test_one_record_a_non_empty_step_with_every_field(ticks):
     assert (rec.window_live_blocks, rec.full_live_blocks) == (0, 0)
     for f in SECTIONS:      # every section ran and was timed: whole ticks
         assert getattr(rec, f) >= 1.0 and getattr(rec, f) % 1 == 0, f
-    assert rec.retire_counters_s >= rec.chunk_steps     # two reads a step
+    # what is left of the counters in the gap: two stretches, a clock
+    # read at each end, whatever the chunk's length
+    assert rec.retire_counters_s == 2
     assert rec.retire_tokens_s >= 1 and rec.retire_finish_s >= 1
     assert rec.retire_counters_s + rec.retire_tokens_s \
         + rec.retire_finish_s <= rec.retire_s
+    # the public step() carried its own item out before it returned: under
+    # no dispatch, and on no step's time
+    assert (rec.deferred_s, rec.deferred_hidden) == (0.0, 0)
     # an engine's first step: no fetch came before it
     assert rec.gap_chunk_s is None
     # the group's gap: from its LAST fetch's return to the chunk's
@@ -666,6 +671,7 @@ def test_the_speculative_path_fills_the_record(telem):
         for f in ("decode_build_s", "decode_dispatch_s", "decode_fetch_s",
                   "retire_s", "retire_counters_s", "retire_tokens_s"):
             assert getattr(r, f) > 0, f
+        assert (r.deferred_s, r.deferred_hidden) == (0.0, 0)
         assert r.decode_dispatch_s + r.decode_fetch_s <= sum(
             getattr(r, f) for f in SECTIONS)
     assert recs[0].gap_group_s > 0
@@ -801,8 +807,8 @@ def test_serving_report_prints_the_loops_record(tmp_path, monkeypatch):
     table = text[text.index("engine loop"):].splitlines()
     assert table[1].split() == ["step", "pr", "n", "lock", "sched",
                                 "prefill", "build", "disp", "fetch",
-                                "retire", "counters", "gap_chunk",
-                                "gap_group"]
+                                "retire", "counters", "deferred",
+                                "gap_chunk", "gap_group"]
     assert len(table) == 2 + len(recs) + 1 and table[2].split()[-2] == "--"
     assert table[-1].startswith("loop totals: %d steps, %d chunks"
                                 % (len(recs), len(recs)))

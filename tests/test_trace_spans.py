@@ -37,7 +37,9 @@ SECTIONS = ["serving.step.lock", "serving.schedule",
             "serving.decode.dispatch", "serving.decode.fetch",
             "serving.retire"]
 NESTED = "serving.retire.finish"      # inside the step's serving.retire
-SPAN_NAMES = ["serving.loop.idle", "serving.step", NESTED] + SECTIONS
+DEFERRED = "serving.retire.deferred"  # a step's item, under the next's chunk
+SPAN_NAMES = ["serving.loop.idle", "serving.step", NESTED, DEFERRED] \
+    + SECTIONS
 #: the fixture's requests: ten tokens each, one from the prefill and nine
 #: decode steps, in dispatches of DECODE_CHUNK steps and the rest
 N_NEW = 10
@@ -317,7 +319,7 @@ def test_paged_counters_count_the_blocks_the_kernel_walks(serving_trace):
     ends = np.cumsum(CHUNKS)
     assert [st["live_blocks"] for name, _s, _d, st
             in serving_trace["driver"]
-            if name == "serving.retire" and "live_blocks" in st] == [
+            if name == DEFERRED and "live_blocks" in st] == [
         sum(by_step[e - n:e]) for n, e in zip(CHUNKS, ends)]
     for name in ("serving.paged.live_blocks", "serving.paged.table_slots"):
         assert name in telemetry.METRIC_HELP

@@ -17,7 +17,8 @@ The serving engine (``mxnet_tpu/serving/obs.py``) emits one
 * the **engine loop** — per step, from the loop's own record on the same
   events (docs/observability.md §The engine loop's record): milliseconds
   in each section (lock, schedule, the group's prefills, the decode
-  chunk's build / dispatch / fetch, retire and its counters' part) and the
+  chunk's build / dispatch / fetch, retire and its counters' part, the
+  step before's bookkeeping carried out under this step's dispatch) and the
   two host gaps — fetch's return to next dispatch's return, when the device
   has nothing to run — with their share of the wall clock;
 * **totals** — SLO attainment, total replay overhead (what preemptions
@@ -211,7 +212,7 @@ _LOOP_COLUMNS = (
                  "prefill_retire_s")),
     ("build", ("decode_build_s",)), ("disp", ("decode_dispatch_s",)),
     ("fetch", ("decode_fetch_s",)), ("retire", ("retire_s",)),
-    ("counters", ("retire_counters_s",)),
+    ("counters", ("retire_counters_s",)), ("deferred", ("deferred_s",)),
     ("gap_chunk", ("gap_chunk_s",)), ("gap_group", ("gap_group_s",)))
 
 
