@@ -273,7 +273,8 @@ class ServingEngine:
                                     cfg.block_size, cfg.num_heads,
                                     cfg.head_dim,
                                     dtype=cfg.kv_dtype, device=device,
-                                    prefix_cache=cfg.prefix_cache)
+                                    prefix_cache=cfg.prefix_cache,
+                                    parts=cfg.loop_steps)
             self.window_pool = self.state = self.streams = None
         else:
             (self.pool, self.window_pool, self.state,
@@ -336,6 +337,10 @@ class ServingEngine:
         # that hold a live stream's context, of the table slots it holds
         self._paged_live_blocks = 0
         self._paged_table_slots = 0
+        # a looped stack: passes run, over the program steps that ran them
+        # (a prefill, an inner decode step, a verify pass)
+        self._looped_passes = 0
+        self._looped_steps = 0
         # how often the chunk engages: device steps over dispatches
         self._decode_dispatches = 0
         self._decode_inner_steps = 0
@@ -498,9 +503,9 @@ class ServingEngine:
                     device=device)
             import jax.numpy as jnp
 
-            dshape = (dcfg.num_layers, cfg.num_blocks, cfg.block_size
-                      ) + KVBlockPool.page_shape(dcfg.num_heads,
-                                                 dcfg.head_dim)
+            dshape = (dcfg.num_layers, dcfg.loop_steps * cfg.num_blocks,
+                      cfg.block_size) + KVBlockPool.page_shape(
+                          dcfg.num_heads, dcfg.head_dim)
             dk = jnp.zeros(dshape, cfg.kv_dtype)
             dv = jnp.zeros(dshape, cfg.kv_dtype)
             if device is not None:
@@ -866,6 +871,7 @@ class ServingEngine:
             if rec is not None:
                 counts, timeline = closing
                 rec["chunk_steps"] = noted.pop("steps", 0)
+                rec["passes"] += noted.pop("passes", 0)
                 rec.update(noted, deferred_hidden=int(hidden), **counts)
                 self.obs.step_timeline(rec, **timeline)
         self._book("deferred_s", deferred)
@@ -1500,6 +1506,7 @@ class ServingEngine:
         with telemetry.span("serving.retire", _CAT,
                             request_id=req.request_id) as retire:
             self._note_moe(load, L)
+            self._note_passes(1)
             if cfg.latent:
                 self._latent["prefill_tokens"] += L
                 telemetry.counter("serving.latent.prefill_tokens").inc(L)
@@ -1652,6 +1659,8 @@ class ServingEngine:
             if load is not None:
                 self._note_moe(load[j], int((ctx <= cfg.max_len).sum()))
             telemetry.histogram("serving.decode_batch").observe(lanes)
+        # the device ran the chunk's n steps whether a lane lived or not
+        noted.update(self._note_passes(n, rec=False))
         self._book_tokens(int(alive.sum()), stamp)
         telemetry.counter("serving.decode.dispatches").inc()
         telemetry.counter("serving.decode.inner_steps").inc(n)
@@ -1679,10 +1688,10 @@ class ServingEngine:
                         if self._draft_kp is not None:
                             # draft pages share the block table, so the
                             # draft copy rides the same COW decision
-                            self._draft_kp = self._draft_kp.at[:, nb].set(
-                                self._draft_kp[:, b])
-                            self._draft_vp = self._draft_vp.at[:, nb].set(
-                                self._draft_vp[:, b])
+                            self._draft_kp = self.pool.copy_block(
+                                self._draft_kp, b, nb)
+                            self._draft_vp = self.pool.copy_block(
+                                self._draft_vp, b, nb)
                         req.blocks[idx] = nb
 
     def _run_spec_decode(self, reqs):
@@ -1852,21 +1861,40 @@ class ServingEngine:
                                  for ctx in base_ctx))
         telemetry.histogram("serving.decode_batch").observe(len(base_ctx))
         self._book_tokens(emitted, stamp)
-        return {"steps": 1, "lane_steps": len(base_ctx),
-                "live_blocks": blocks}
+        return dict(self._note_passes(1, rec=False), steps=1,
+                    lane_steps=len(base_ctx), live_blocks=blocks)
 
     def _blocks(self, ctx):
         """The blocks that hold contexts of ``ctx`` tokens, summed."""
         return int((-(-ctx // self.config.block_size)).sum())
 
+    def _note_passes(self, steps, rec=True):
+        """Book ``steps`` program steps of a looped stack (a prefill, an
+        inner decode step, a verify pass): ``loop_steps`` passes each.
+        Returns ``{"passes": n}`` for the step's span and record, {} for
+        a stack that runs once; ``rec``: add them to the record of the
+        step under way (the chunk's are added when its item is carried
+        out)."""
+        r = self.config.loop_steps
+        if r == 1:
+            return {}
+        self._looped_steps += steps
+        self._looped_passes += r * steps
+        telemetry.counter("serving.looped.passes").inc(r * steps)
+        if rec and self._rec is not None:
+            self._rec["passes"] += r * steps
+        return {"passes": r * steps}
+
     def _note_paged(self, ctx):
-        """Book one decode or verify pass of the paged kernel from the
+        """Book one decode or verify step of the paged kernel from the
         live streams' context lengths: the blocks it walks
-        (``ceil(ctx / block_size)`` a stream) and the table slots those
-        streams hold (``nb_max`` each; what the kernel's grid walked
-        before PR 27). Returns the blocks, for the step's spans."""
-        live = self._blocks(ctx)
-        slots = len(ctx) * self._nb_max
+        (``ceil(ctx / block_size)`` a stream, once a PASS of a looped
+        stack: times ``num_layers`` it is what the kernel walked) and the
+        table slots those streams hold (``nb_max`` each and pass; what the
+        kernel's grid walked before PR 27). Returns the blocks, for the
+        step's spans."""
+        live = self._blocks(ctx) * self.config.loop_steps
+        slots = len(ctx) * self._nb_max * self.config.loop_steps
         self._paged_live_blocks += live
         self._paged_table_slots += slots
         telemetry.counter("serving.paged.live_blocks").inc(live)
@@ -2095,6 +2123,15 @@ class ServingEngine:
                     "syncs_saved":
                         self._prefill_prompts - self._prefill_groups,
                 },
+                # only for a stack that runs several times
+                **({"looped": {
+                    "passes": self._looped_passes,
+                    "steps": self._looped_steps,
+                    "passes_per_step":
+                        (self._looped_passes / self._looped_steps)
+                        if self._looped_steps else 0.0,
+                    "cache_layers": self.pool.cache_layers,
+                }} if self.config.loop_steps > 1 else {}),
                 # only for a model with window or state layers
                 **({"state": self._state_stats()}
                    if self.streams is not None else {}),

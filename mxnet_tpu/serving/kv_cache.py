@@ -26,6 +26,16 @@ padded table entries and padded batch rows point at it, so masked lanes of
 a bucketed step scatter their garbage somewhere no reader ever trusts
 (readers mask by context length; the pool hands block 0 to no request).
 
+A model whose stack runs several times (``ModelConfig.loop_steps`` = R)
+caches every pass apart. Its pool is R PARTS side by side along the block
+axis, ``(num_layers, R x num_blocks, ...)``: block ``b``'s rows of pass
+``r`` are block ``r x num_blocks + b`` of the arrays, which the step
+programs reach by adding ``r x num_blocks`` to the block table (block 0 of
+each part is that pass's trash). A block id still names ONE allocation
+unit, a token's rows in all ``R x num_layers`` cache layers: the free
+list, refcounts and the prefix index know nothing of parts; ``cow``
+copies, and ``nbytes`` / ``block_nbytes`` count, every part of a block.
+
 Prefix sharing (docs/serving.md §prefix-sharing): every allocated block
 carries a REFCOUNT. Full prefill blocks are content-hashed into a pool-
 level prefix index — the digest chains token ids through the block's
@@ -66,8 +76,10 @@ class KVBlockPool:
 
     def __init__(self, num_layers, num_blocks, block_size, num_heads,
                  head_dim, dtype=np.float32, device=None,
-                 prefix_cache=True, rows=None, gauges=True, v_rows=None):
-        """``rows``: the page rows ``(G, W)`` where the model decides them
+                 prefix_cache=True, rows=None, gauges=True, v_rows=None,
+                 parts=1):
+        """``parts``: the passes of a looped stack (module docstring).
+        ``rows``: the page rows ``(G, W)`` where the model decides them
         (``ModelConfig.kv_rows``: a differential-attention K/V pair a
         row); ``num_heads x head_dim`` are then ``G x W``. ``gauges``: a
         second pool of an engine (the window layers') leaves the
@@ -84,6 +96,7 @@ class KVBlockPool:
 
         self.num_layers = int(num_layers)
         self.num_blocks = int(num_blocks)
+        self.parts = int(parts)
         self.block_size = int(block_size)
         self.num_heads = int(num_heads)
         self.head_dim = int(head_dim)
@@ -108,7 +121,8 @@ class KVBlockPool:
             else self.page_rows
 
         def pages(g, w):
-            return jnp.zeros((self.num_layers, self.num_blocks) + (
+            return jnp.zeros((self.num_layers,
+                              self.parts * self.num_blocks) + (
                 (g, self.block_size, w) if self.is_head_major
                 else (self.block_size, g, w)), self.dtype)
 
@@ -196,12 +210,25 @@ class KVBlockPool:
         beside it for the same streams (``extra_nbytes``)."""
         return self.block_nbytes() * self.num_blocks + self.extra_nbytes
 
+    @property
+    def cache_layers(self):
+        """Cache layers a block holds rows in: a layer and part."""
+        return self.num_layers * self.parts
+
     def block_nbytes(self):
-        """Device bytes ONE block pins across layers (K + V) — the unit
-        every shared reference saves."""
+        """Device bytes ONE block pins across cache layers (K + V) — the
+        unit every shared reference saves."""
         (g, w), (gv, wv) = self.page_rows, self.v_page_rows
-        return (self.num_layers * self.block_size * (g * w + gv * wv)
+        return (self.cache_layers * self.block_size * (g * w + gv * wv)
                 * self.dtype.itemsize)
+
+    def copy_block(self, pages, src, dst):
+        """``pages`` with block ``src``'s rows copied bit-exactly over
+        block ``dst``'s, in every layer and in every part ``pages`` has
+        (the pool's own, or a draft model's over the same block ids)."""
+        for at in range(0, pages.shape[1], self.num_blocks):
+            pages = pages.at[:, at + dst].set(pages[:, at + src])
+        return pages
 
     def blocks_for(self, num_tokens):
         """Blocks needed to hold ``num_tokens`` cache slots."""
@@ -317,8 +344,8 @@ class KVBlockPool:
             self._ref[b] = rc - 1
             # eager device-side page copy — bit-exact K/V into the private
             # block; the writer's table swaps b -> nb after this returns
-            self.k_pages = self.k_pages.at[:, nb].set(self.k_pages[:, b])
-            self.v_pages = self.v_pages.at[:, nb].set(self.v_pages[:, b])
+            self.k_pages = self.copy_block(self.k_pages, b, nb)
+            self.v_pages = self.copy_block(self.v_pages, b, nb)
             self.cow_copies += 1
             telemetry.counter("serving.prefix_cow_copies").inc()
             telemetry.counter("serving.kv_blocks_allocs").inc()

@@ -128,6 +128,23 @@ class ModelConfig:
                     of an expert-parallel layout and holds ``count`` of
                     the ``num_experts`` the router chooses among
 
+    A one-block model may also be a stack that every token goes through
+    SEVERAL times (a looped language model), and may norm a sub-layer's
+    output as well as its input; ``ffn_gated`` and ``norm_eps`` above are
+    a one-block model's to set too:
+
+    loop_steps      passes through the stack (1 = once). Every pass uses the
+                    same weights, closes with the final norm, hands its result
+                    to the next as input, and has a K/V cache OF ITS OWN:
+                    ``cache_layers = loop_steps x num_layers``. The head reads
+                    the last pass
+    post_norm       a second norm on each sub-layer's OUTPUT, before the
+                    residual add (``layer%d_ln1_post_*``, ``_ln2_post_*``)
+    early_exit_threshold
+                    1.0: no token leaves before the last pass (the exit
+                    gate's two parameters are loaded and nothing reads them);
+                    anything less is refused
+
     ``max_len`` bounds every stream's total length (the position table's
     rows, or the positions the rotary model was trained for)."""
 
@@ -139,10 +156,17 @@ class ModelConfig:
                  "ssm_conv", "ssm_expand", "ssm_dt_rank", "q_rank", "kv_rank",
                  "rope_dim", "v_dim", "rope_yarn", "norm_eps", "first_dense",
                  "dense_ffn_dim", "shared_experts", "router", "n_group",
-                 "topk_group", "route_scale", "experts_held")
+                 "topk_group", "route_scale", "experts_held", "loop_steps",
+                 "post_norm", "early_exit_threshold")
     #: the fields of one-block models: their ``key()`` is these alone, so
     #: that the programs' cache keys are what they were before ``layer_kinds``
     _BLOCK_FIELDS = 14
+    #: a model with kinds' key: the fields there were before ``loop_steps``
+    _KIND_FIELDS = 38
+    #: what a one-block model may set beyond its fourteen; one that does
+    #: has them in its key
+    _BLOCK_EXTRAS = (("ffn_gated", False), ("norm_eps", 1e-5),
+                     ("loop_steps", 1), ("post_norm", False))
     #: what ``layer_kinds`` may name
     KINDS = ("mamba", "swa", "full", "cross", "gmu", "mla")
 
@@ -156,7 +180,8 @@ class ModelConfig:
                  q_rank=0, kv_rank=0, rope_dim=0, v_dim=None, rope_yarn=None,
                  norm_eps=1e-5, first_dense=0, dense_ffn_dim=None,
                  shared_experts=0, router="softmax", n_group=1, topk_group=1,
-                 route_scale=1.0, experts_held=None):
+                 route_scale=1.0, experts_held=None, loop_steps=1,
+                 post_norm=False, early_exit_threshold=1.0):
         self.vocab_size = int(vocab_size)
         self.num_layers = int(num_layers)
         self.model_dim = int(model_dim)
@@ -202,6 +227,9 @@ class ModelConfig:
         self.route_scale = float(route_scale)
         self.experts_held = (None if experts_held is None
                              else tuple(int(v) for v in experts_held))
+        self.loop_steps = int(loop_steps)
+        self.post_norm = bool(post_norm)
+        self.early_exit_threshold = float(early_exit_threshold)
         if self.norm not in ("layer", "rms"):
             raise ValueError("norm must be 'layer' or 'rms', not %r" % norm)
         if self.pos not in ("learned", "rope", "none"):
@@ -214,6 +242,24 @@ class ModelConfig:
             raise ValueError("experts_per_tok must be in 1..num_experts")
         self._check_kinds()
         self._check_ffn()
+        self._check_loop()
+
+    def _check_loop(self):
+        if self.loop_steps < 1:
+            raise ValueError("loop_steps must be >= 1")
+        if self.early_exit_threshold < 1.0:
+            raise ValueError(
+                "early_exit_threshold %g: a token that leaves the stack "
+                "before its last pass needs what the step programs do not "
+                "have yet (lanes of one batch leaving at different passes, "
+                "the exit gate evaluated); only 1.0, every pass for every "
+                "token, is served" % self.early_exit_threshold)
+        if (self.loop_steps > 1 or self.post_norm) and self.hybrid:
+            raise ValueError("loop_steps and post_norm belong to a one-block "
+                             "model, not to one with layer_kinds")
+        if self.loop_steps > 1 and self.num_experts:
+            raise ValueError("a looped stack takes no routed experts (their "
+                             "load is booked a layer, not a layer and pass)")
 
     def _check_ffn(self):
         if self.router not in ("softmax", "sigmoid_group"):
@@ -245,15 +291,14 @@ class ModelConfig:
         kinds = self.kinds()
         by_layer = (self.first_dense or self.shared_experts
                     or self.experts_held or self.router != "softmax"
-                    or self.rope_yarn or self.norm_eps != 1e-5)
+                    or self.rope_yarn)
         if self.layer_kinds is None:
             if (self.num_kv_heads != self.num_heads or self.pos == "none"
-                    or self.ffn_gated or self.tie_embed or self.attn_bias
-                    or by_layer):
+                    or self.tie_embed or self.attn_bias or by_layer):
                 raise ValueError(
-                    "num_kv_heads, pos='none', ffn_gated, tie_embed, "
-                    "attn_bias, norm_eps, rope_yarn and an FFN that differs "
-                    "by layer belong to a model with layer_kinds")
+                    "num_kv_heads, pos='none', tie_embed, attn_bias, "
+                    "rope_yarn and an FFN that differs by layer belong to a "
+                    "model with layer_kinds")
             return
         if len(kinds) != self.num_layers or set(kinds) - set(self.KINDS):
             raise ValueError("layer_kinds must name each of the %d layers "
@@ -318,6 +363,12 @@ class ModelConfig:
         return bool(self.layers_of("mla"))
 
     @property
+    def cache_layers(self):
+        """K/V cache layers a token's block holds rows in: a layer and
+        pass (the pool, the walks' booking and ``stats()`` read this)."""
+        return self.loop_steps * self.num_layers
+
+    @property
     def expert_layers(self):
         """How many layers have experts (those behind ``first_dense``)."""
         return self.num_layers - self.first_dense if self.num_experts else 0
@@ -363,10 +414,14 @@ class ModelConfig:
 
     def key(self):
         """What the programs are a function of. A one-block model's key is
-        its first fourteen fields, as before there were others."""
-        names = ModelConfig.__slots__
+        its first fourteen fields, as before there were others, and what
+        it sets of ``_BLOCK_EXTRAS`` behind them if it sets any; a model
+        with kinds' is the thirty-eight it always was."""
+        names = ModelConfig.__slots__[:self._KIND_FIELDS]
         if not self.hybrid:
             names = names[:self._BLOCK_FIELDS]
+            if any(getattr(self, k) != v for k, v in self._BLOCK_EXTRAS):
+                names += tuple(k for k, _v in self._BLOCK_EXTRAS)
         return tuple(getattr(self, k) for k in names)
 
     def _slot_names(self):
@@ -406,18 +461,21 @@ def param_shapes(cfg):
         shapes["final_ln_beta"] = (1, 1, m)
     if cfg.bias:
         shapes["lm_head_bias"] = (v,)
+    if cfg.loop_steps > 1:      # loaded; read by no program at threshold 1
+        shapes.update({"early_exit_gate_weight": (1, m),
+                       "early_exit_gate_bias": (1,)})
+    norms = ("_ln1", "_ln2") + (("_ln1_post", "_ln2_post")
+                                if cfg.post_norm else ())
     for i, kind in enumerate(cfg.kinds()):
         p = "layer%d" % i
-        shapes.update({
-            p + "_ln1_gamma": (1, 1, m), p + "_ln2_gamma": (1, 1, m)})
+        shapes.update({p + n + "_gamma": (1, 1, m) for n in norms})
         if kind == "attn":
             shapes.update({p + "_attn_in_weight": (3 * hm, m),
                            p + "_attn_out_weight": (m, hm)})
         else:
             shapes.update(_mixer_shapes(cfg, kind, p))
         if cfg.norm == "layer":
-            shapes.update({p + "_ln1_beta": (1, 1, m),
-                           p + "_ln2_beta": (1, 1, m)})
+            shapes.update({p + n + "_beta": (1, 1, m) for n in norms})
         if cfg.qk_norm:
             shapes.update({p + "_q_norm_gamma": (hm,),
                            p + "_k_norm_gamma": (hm,)})
@@ -440,8 +498,9 @@ def param_shapes(cfg):
                 p + "_ffn1_weight": ((2 if cfg.ffn_gated else 1) * fd, m),
                 p + "_ffn2_weight": (m, fd)})
             if cfg.bias:
-                shapes.update({p + "_ffn1_bias": (fd,),
-                               p + "_ffn2_bias": (m,)})
+                shapes.update({
+                    p + "_ffn1_bias": ((2 if cfg.ffn_gated else 1) * fd,),
+                    p + "_ffn2_bias": (m,)})
     return shapes
 
 
@@ -950,6 +1009,8 @@ def _layer(x, params, i, cfg, prec, positions, valid, attend, state,
     else:
         mix, state = _mix_diff(h, params, p, i, kind, cfg, prec, attend,
                                state)
+    if cfg.post_norm:
+        mix = _norm(mix, params, p + "_ln1_post", cfg)
     x = x + mix
     h = _norm(x, params, p + "_ln2", cfg)
     a, b, m = x.shape
@@ -975,23 +1036,49 @@ def _layer(x, params, i, cfg, prec, positions, valid, attend, state,
                            params[p + "_shared_down_weight"], prec)
     else:
         f = _ffn(h2, params, p, cfg, prec)
-    return x + f.reshape(a, b, m), state, load
+    f = f.reshape(a, b, m)
+    if cfg.post_norm:
+        f = _norm(f, params, p + "_ln2_post", cfg)
+    return x + f, state, load
 
 
 def _layers(x, params, cfg, prec, positions, valid, attend, state,
             recur=None):
-    """Every layer in turn: ``(x, state, loads)`` with ``loads`` the
-    expert layers' ``tokens_per_expert`` stacked ``(expert_layers, E)``,
-    or () without experts."""
+    """Every layer in turn and the final norm: ``(x, state, loads)`` with
+    ``loads`` the expert layers' ``tokens_per_expert`` stacked
+    ``(expert_layers, E)``, or () without experts.
+
+    A looped model (``loop_steps`` > 1) goes through that ``loop_steps``
+    times in ONE ``lax.fori_loop``: the program holds one pass's layer
+    bodies however many passes it runs, a pass's normed result is the
+    next's input, and ``attend`` is handed the pass ``r`` (traced) to find
+    that pass's cache by; ``state`` rides the loop, so it keeps its
+    structure (the pages, not K/V collected along the way)."""
+    import functools
+
+    import jax
     import jax.numpy as jnp
 
-    loads = []
-    for i in range(cfg.num_layers):
-        x, state, load = _layer(x, params, i, cfg, prec, positions, valid,
-                                attend, state, recur)
-        if load is not None:
-            loads.append(load)
-    return x, state, ((jnp.stack(loads),) if cfg.num_experts else ())
+    def stack(x, state, attend):
+        loads = []
+        for i in range(cfg.num_layers):
+            x, state, load = _layer(x, params, i, cfg, prec, positions,
+                                    valid, attend, state, recur)
+            if load is not None:
+                loads.append(load)
+        return _norm(x, params, "final_ln", cfg), state, loads
+
+    if cfg.loop_steps == 1:
+        x, state, loads = stack(x, state, attend)
+        return x, state, ((jnp.stack(loads),) if cfg.num_experts else ())
+
+    def one_pass(r, carry):
+        with jax.named_scope("looped_pass"):
+            x, state, _ = stack(*carry, functools.partial(attend, r=r))
+        return x, state
+
+    x, state = jax.lax.fori_loop(0, cfg.loop_steps, one_pass, (x, state))
+    return x, state, ()
 
 
 def prefill(params, tokens, length, block_table, k_pages, v_pages, cfg,
@@ -1015,6 +1102,10 @@ def prefill(params, tokens, length, block_table, k_pages, v_pages, cfg,
     but cannot reach rows < length (causal mask) and their cache writes
     land in trash-table blocks.
 
+    A looped model (``loop_steps`` = R) takes pages of R parts,
+    ``(L, R x N, bs, G, W)``, and writes layer i's K/V of pass r into blocks
+    ``block_table + r x N`` as the layer is computed (``KVBlockPool``).
+
     A model with ``layer_kinds`` takes ``aux`` — ``wtable`` (S // bs,) the
     stream's window-pool blocks (0 for those behind the window: a long
     prompt keeps its tail only), ``slot`` () its state slot, and the
@@ -1036,25 +1127,41 @@ def prefill(params, tokens, length, block_table, k_pages, v_pages, cfg,
     def split_heads(t):
         return t.reshape(1, S, hh, hd).transpose(0, 2, 1, 3)   # (1, H, S, hd)
 
-    def attend(i, q, k, v, kv):
+    def flash(q, k, v):
         attn = flash_attention(split_heads(q), split_heads(k),
                                split_heads(v), True)
-        return (attn.transpose(0, 2, 1, 3).reshape(1, S, hh * hd),
-                (kv[0] + (k,), kv[1] + (v,)))
+        return attn.transpose(0, 2, 1, 3).reshape(1, S, hh * hd)
+
+    def attend(i, q, k, v, kv):
+        return flash(q, k, v), (kv[0] + (k,), kv[1] + (v,))
+
+    def attend_pass(i, q, k, v, pages, r):
+        """A looped model's: layer i's K/V of pass r go into that pass's
+        part of the pool as the layer is computed (a pass's K/V, let alone
+        every pass's, is never held beside the pool)."""
+        table = block_table + r * (k_pages.shape[1] // cfg.loop_steps)
+        return flash(q, k, v), tuple(
+            _put_blocks(pg, i, table, t.reshape(S // bs, bs, rows, lanes))
+            for pg, t in zip(pages, (k, v)))
 
     x = _embed(params, tokens, None, cfg)                      # (1, S, M)
-    x, (k_all, v_all), loads = _layers(
-        x, params, cfg, prec, positions, positions < length, attend,
-        ((), ()))
+    if cfg.loop_steps > 1:
+        x, (k_pages, v_pages), loads = _layers(
+            x, params, cfg, prec, positions, positions < length,
+            attend_pass, (k_pages, v_pages))
+    else:
+        x, (k_all, v_all), loads = _layers(
+            x, params, cfg, prec, positions, positions < length, attend,
+            ((), ()))
+        # scatter every layer's K/V through the block table (trash entries
+        # absorb the padded tail)
+        kw = jnp.stack(k_all).reshape(cfg.num_layers, S // bs, bs, rows,
+                                      lanes)
+        vw = jnp.stack(v_all).reshape(cfg.num_layers, S // bs, bs, rows,
+                                      lanes)
+        k_pages = k_pages.at[:, block_table].set(kw.astype(k_pages.dtype))
+        v_pages = v_pages.at[:, block_table].set(vw.astype(v_pages.dtype))
 
-    # scatter every layer's K/V through the block table (trash entries
-    # absorb the padded tail)
-    kw = jnp.stack(k_all).reshape(cfg.num_layers, S // bs, bs, rows, lanes)
-    vw = jnp.stack(v_all).reshape(cfg.num_layers, S // bs, bs, rows, lanes)
-    k_pages = k_pages.at[:, block_table].set(kw.astype(k_pages.dtype))
-    v_pages = v_pages.at[:, block_table].set(vw.astype(v_pages.dtype))
-
-    x = _norm(x, params, "final_ln", cfg)
     h_last = jnp.take(x[0], length - 1, axis=0)                # (M,)
     logits = _head(h_last[None], params, cfg, prec)            # (1, V)
     next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -1085,6 +1192,20 @@ def _block_rows(t, bs, cfg, rows=None):
     return t.transpose(0, 2, 1, 3) if _head_major(cfg) else t
 
 
+def _put_blocks(pages, li, table, rows):
+    """One layer's K or V of a prompt, ``rows`` (S // bs, ...) in the
+    pool's own block order, into the blocks ``table`` names."""
+    import jax
+
+    rows = rows.astype(pages.dtype)
+    if rows.shape[0] == 1:
+        # a scatter of ONE block is rewritten by the compiler into a
+        # form that copies the pool in and out; the slice update it is
+        return jax.lax.dynamic_update_slice(
+            pages, rows[None], (li, table[0], 0, 0, 0))
+    return pages.at[li, table].set(rows)
+
+
 def _prefill_hybrid(params, tokens, length, block_table, k_pages, v_pages,
                     cfg, aux):
     """:func:`prefill` for a model with ``layer_kinds``. A layer's K/V is
@@ -1109,13 +1230,7 @@ def _prefill_hybrid(params, tokens, length, block_table, k_pages, v_pages,
 
     def put(pages, li, table, t, rows=None):
         """One layer's K or V of the S tokens into its blocks."""
-        rows = _block_rows(t, bs, cfg, rows).astype(pages.dtype)
-        if S == bs:
-            # a scatter of ONE block is rewritten by the compiler into a
-            # form that copies the pool in and out; the slice update it is
-            return jax.lax.dynamic_update_slice(
-                pages, rows[None], (li, table[0], 0, 0, 0))
-        return pages.at[li, table].set(rows)
+        return _put_blocks(pages, li, table, _block_rows(t, bs, cfg, rows))
 
     def attend_mla(i, q, c, kr, st):
         """Cache the latent and the rotated key; attend over the prompt's
@@ -1186,7 +1301,6 @@ def _prefill_hybrid(params, tokens, length, block_table, k_pages, v_pages,
              "conv": aux["conv"], "ssm": aux["ssm"]}
     x, state, loads = _layers(x, params, cfg, prec, positions,
                               positions < length, attend, state, recur)
-    x = _norm(x, params, "final_ln", cfg)
     h_last = jnp.take(x[0], length - 1, axis=0)                # (M,)
     logits = _head(h_last[None], params, cfg, prec)            # (1, V)
     next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -1220,21 +1334,26 @@ def _paged_step(params, tokens, positions, block_tables, context_lens,
     # all-trash tables
     valid = in_range & (block_tables[:, :1] > 0)
 
-    def attend(i, q, k_new, v_new, pages):
+    def attend(i, q, k_new, v_new, pages, r=None):
         kp, vp = pages
-        kp = kp.at[i, page_ids.reshape(-1), slots.reshape(-1)].set(
+        ids, tables = page_ids, block_tables
+        if r is not None:
+            # a looped model's pass r: its own part of the pool, found
+            # through the table (block 0 of a part is that pass's trash)
+            part = r * (kp.shape[1] // cfg.loop_steps)
+            ids, tables = page_ids + part, block_tables + part
+        kp = kp.at[i, ids.reshape(-1), slots.reshape(-1)].set(
             k_new.reshape(B * T, rows, lanes).astype(kp.dtype))
-        vp = vp.at[i, page_ids.reshape(-1), slots.reshape(-1)].set(
+        vp = vp.at[i, ids.reshape(-1), slots.reshape(-1)].set(
             v_new.reshape(B * T, rows, lanes).astype(vp.dtype))
         attn = paged_attention_multi(q.reshape(B, T, hh, hd), kp, vp,
-                                     block_tables, context_lens, layer=i)
+                                     tables, context_lens, layer=i)
         return attn.reshape(B, T, hh * hd), (kp, vp)
 
     x = _embed(params, tokens, safe_pos, cfg)                   # (B, T, M)
     x, (k_pages, v_pages), loads = _layers(
         x, params, cfg, prec, safe_pos, valid, attend, (k_pages, v_pages))
 
-    x = _norm(x, params, "final_ln", cfg)
     logits = _head(x.reshape(B * T, cfg.model_dim), params, cfg,
                    prec).reshape(B, T, -1)                      # (B, T, V)
     next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -1364,7 +1483,6 @@ def _paged_step_hybrid(params, tokens, positions, block_tables,
              "conv": aux["conv"], "ssm": aux["ssm"]}
     x, state, loads = _layers(x, params, cfg, prec, safe_pos, valid, attend,
                               state, recur)
-    x = _norm(x, params, "final_ln", cfg)
     logits = _head(x.reshape(B, cfg.model_dim), params, cfg,
                    prec).reshape(B, 1, -1)
     next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)

@@ -83,7 +83,7 @@ _BURN_MIN_SAMPLES = 8
 
 #: Steps an engine's ring of loop records keeps: the longest window a
 #: benchmark run may have (51 s) at the ~30 steps a second of its busiest
-#: cell, with set-up's steps before it. A record is ~0.8 KB (27 fields,
+#: cell, with set-up's steps before it. A record is ~0.8 KB (28 fields,
 #: most of them floats of their own): a full ring is ~7 MB.
 LOOP_RING = 8192
 
@@ -105,7 +105,7 @@ class LoopRecord(namedtuple("LoopRecord", (
         "lane_steps", "live_blocks", "window_live_blocks",
         "full_live_blocks",
         "gap_chunk_s", "gap_group_s", "finished",
-        "deferred_s", "deferred_hidden"))):
+        "deferred_s", "deferred_hidden", "passes"))):
     """What the engine loop did in ONE non-empty step, on the host's clock
     (docs/observability.md has the table of fields, where each is measured
     and the benchmark metric that reads it).
@@ -125,7 +125,10 @@ class LoopRecord(namedtuple("LoopRecord", (
     (the decode dispatch's trip count, 0 for a step without one),
     ``lanes``, and what the chunk's steps walked (``lane_steps``,
     ``live_blocks``; a model with window layers also its window and
-    full-pool walks).
+    full-pool walks). A stack that runs several times (``loop_steps`` > 1)
+    books a walk a PASS in ``live_blocks`` and counts in ``passes`` the
+    stack passes the step's programs ran, ``loop_steps`` a prefill and an
+    inner decode step (0 for every other model).
 
     **The rest of a step's bookkeeping is set aside** (the chunk's
     counters an inner step, the tokens' telemetry, the throughput window,

@@ -848,6 +848,9 @@ METRIC_HELP = {
     "serving.moe.load_max_over_mean":
         "busiest expert's tokens over the mean expert's, averaged over "
         "the layers of the last step",
+    "serving.looped.passes":
+        "passes through a looped stack (loop_steps > 1) the step programs "
+        "ran: loop_steps a prefill, an inner decode step, a verify pass",
     "serving.latent.ctx_tokens":
         "cached tokens the latent decode kernel read: the live lanes' "
         "context lengths summed over decode steps (x 'mla' layers = rows)",

@@ -158,6 +158,15 @@ def _serving_program(chip, name, page, arch="gpt2"):
         cfg, dtype, bs, blocks = (
             M.ModelConfig(50257, 2, 1024, 16, 4096, 1024), jnp.float32, 16,
             513)
+    elif arch == "ouro":
+        # Ouro-2.6B's widths and four passes over blocks of 32: a pool of
+        # four parts of 145 blocks, one pass's two layer bodies a program
+        cfg, dtype, bs, blocks = (
+            M.ModelConfig(49152, 2, 2048, 16, 5632, 4096, norm="rms",
+                          pos="rope", rope_theta=1e6, head_dim=128,
+                          bias=False, ffn_gated=True, norm_eps=1e-6,
+                          loop_steps=4, post_norm=True),
+            jnp.bfloat16, 32, 4 * 145)
     else:
         cfg, dtype, bs, blocks = (
             M.ModelConfig(50304, 2, 2048, 16, 1024, 4096, norm="rms",
@@ -209,7 +218,8 @@ def _entry_layouts(text, pool_shape):
 
 
 @pytest.mark.parametrize("arch,heads,head_dim,page", [
-    ("gpt2", 16, 64, (8, 128)), ("olmoe", 16, 128, (16, 128))])
+    ("gpt2", 16, 64, (8, 128)), ("olmoe", 16, 128, (16, 128)),
+    ("ouro", 16, 128, (16, 128))])
 @pytest.mark.parametrize("name", ["decode", "chunk", "prefill"])
 def test_serving_programs_leave_the_pool_in_place(v5e, name, arch, heads,
                                                   head_dim, page):
@@ -223,13 +233,19 @@ def test_serving_programs_leave_the_pool_in_place(v5e, name, arch, heads,
     ``(16, 128)`` rows (r = 1) in bf16, whose programs also hold the
     experts' grouped-matmul kernels. The decode CHUNK carries the pool
     through a loop on the device: no copy inside or around the loop
-    either, and temporaries within 0.1 GB of the single step's."""
+    either, and temporaries within 0.1 GB of the single step's. A looped
+    stack (Ouro's widths, four passes) carries it through the passes' loop
+    too, inside the chunk's, each pass reaching its part of the pool through
+    the block table, and holds ONE pass's paged calls."""
     from mxnet_tpu.serving.kv_cache import KVBlockPool
 
     assert KVBlockPool.page_shape(heads, head_dim) == page
     compiled, pool_shape, itemsize = _serving_program(v5e, name, page, arch)
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 2
+    if arch == "ouro":      # a flash or paged call a layer, not a layer and pass
+        assert text.count("tpu_custom_call") == 2
+        assert text.count(" while(") == (2 if name == "chunk" else 1)
     if arch == "olmoe":     # three grouped matmuls a layer, by their name
         assert len(re.findall(r"%gmm[.\d]* = ", text)) == 6
     assert _pool_copies(text, pool_shape) == []

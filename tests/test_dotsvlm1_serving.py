@@ -600,7 +600,9 @@ def test_serving_config_refuses_what_latents_cannot_do_yet():
     bad("router must be", router="argmax")
     with pytest.raises(ValueError, match="belong to a model with "
                                          "layer_kinds"):
-        M.ModelConfig(norm_eps=1e-6)
+        M.ModelConfig(tie_embed=True)
+    # ... and since PR 40 a one-block model may set its norms' epsilon
+    assert M.ModelConfig(norm_eps=1e-6).key()[-4:] == (False, 1e-6, 1, False)
     with pytest.raises(ValueError, match="only 'mla' has rotary"):
         M.ModelConfig(97, 1, 64, 4, 128, 64, layer_kinds=["mamba"],
                       pos="rope")

@@ -426,7 +426,7 @@ def test_one_record_a_non_empty_step_with_every_field(ticks):
     before = time.time()
     rec, wall, progs, fetched = _step_logged(eng, clock, fetches, calls)
     assert len(ring) == 1 and ring[0] is rec
-    assert rec._fields == LoopRecord._fields and len(rec) == 27
+    assert rec._fields == LoopRecord._fields and len(rec) == 28
     assert before <= rec.ts <= time.time() and rec.step == eng._steps == 1
     # two prompts as one group, then a chunk of their lanes
     assert [p for p, _t in progs] == ["prefill", "prefill", "decode"]

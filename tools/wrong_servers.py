@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
-"""Wrong servers for a latent-attention, shared-expert configuration: plant
-ONE fault in the served program (or its weights), run the configuration's
-own dense probe over it, print what ``correct`` would compare.
+"""Wrong servers for a latent-attention, shared-expert configuration (and,
+through ``--cell``, for a stack that runs several times): plant ONE fault in
+the served program (or its weights), run the configuration's own dense
+probe over it, print what ``correct`` would compare.
 
     python3 tools/wrong_servers.py --config benchmark/configs/dots-vlm1-ep16-bf16.json \\
         --faults sound,float8_experts,zeroed_expert,no_shared --seed 7 [--gaps]
@@ -43,7 +44,9 @@ sys.path.insert(0, ROOT)
 WEIGHTS = ("sound", "float8_all", "float8_experts", "zeroed_expert",
            "no_shared")
 PROGRAM = ("no_group_limit", "no_renorm", "no_scale", "no_mscale",
-           "unrotated_key", "latent_before_norm", "latent_not_written")
+           "unrotated_key", "latent_before_norm", "latent_not_written",
+           # of a stack that runs several times (ouro-chat-closed32)
+           "float8_pages", "three_passes", "shared_cache", "no_post_norm")
 
 
 def load_config(path):
@@ -148,6 +151,49 @@ def planted(name, model):
             self.pool.k_pages = kept
             return out
         patch(ServingEngine, "_dispatch_decode", dispatch)
+    elif name == "float8_pages":
+        # every K and V rounded to float8 on its way to the pages (and to
+        # prefill's attention, which reads what it caches)
+        sound = M._mix_attn
+
+        def to8(t):
+            # float8_e4m3's four exponent and three mantissa bits, by an op
+            # the compiler may not take for excess precision and drop (a
+            # pair of converts it does: PERF.md section 6, PR 40)
+            return jax.lax.reduce_precision(t, exponent_bits=4,
+                                            mantissa_bits=3)
+
+        def mix(h, params, p, i, cfg, prec, positions, attend, state):
+            return sound(h, params, p, i, cfg, prec, positions,
+                         lambda i, q, k, v, st, **kw: attend(
+                             i, q, to8(k), to8(v), st, **kw), state)
+        patch(M, "_mix_attn", mix)
+    elif name in ("three_passes", "shared_cache", "no_post_norm"):
+        sound = M._layers
+
+        class Other:
+            """The configuration but for one field."""
+
+            def __init__(self, cfg, **fields):
+                self.__dict__.update(fields, _cfg=cfg)
+
+            def __getattr__(self, key):
+                return getattr(self._cfg, key)
+
+        def layers(x, params, cfg, prec, positions, valid, attend, state,
+                   recur=None):
+            if name == "three_passes":      # the last pass left out
+                cfg = Other(cfg, loop_steps=cfg.loop_steps - 1)
+            elif name == "no_post_norm":
+                cfg = Other(cfg, post_norm=False)
+            else:           # every pass reads and writes pass 0's cache
+                served = attend
+
+                def attend(i, q, k, v, st, r):  # noqa: F811
+                    return served(i, q, k, v, st, r * 0)
+            return sound(x, params, cfg, prec, positions, valid, attend,
+                         state, recur)
+        patch(M, "_layers", layers)
     elif name not in WEIGHTS:
         raise ValueError("no fault %r (weights: %s; program: %s)"
                          % (name, WEIGHTS, PROGRAM))
