@@ -893,25 +893,42 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
     stream's context costs nothing: no grid step, no copy, no compute.
 
     Each block takes the per-block arithmetic as it arrives (its own
-    semaphore): online-softmax state (m, l, acc) in VMEM scratch, one
-    (G, W) row set per lane; a head's score is summed over its own D lanes
-    and kept broadcast across them (:func:`_head_sums`), so every line
-    after it is elementwise on full (8, 128) registers whatever r is.
-    Masking is per lane (``context_lens`` is (B, T)): the lanes take the
-    block one after another (T is static and small), the loop is bounded
-    by the LONGEST lane and shorter lanes mask the tail with -1e30.
-    float32 scores and accumulators; bf16 pages are read as bf16 and
-    widened on the chip. VMEM: ``4 c`` pages (2 MB at the default) plus
-    O(T·G·W), independent of sequence length, table width and pool size.
+    semaphore): online-softmax state (m, l, acc) in VMEM scratch, float32
+    scores and accumulators; bf16 pages are read as bf16 and widened on
+    the chip. Masking is per lane (``context_lens`` is (B, T)): the loop is
+    bounded by the LONGEST lane and shorter lanes mask the tail with -1e30.
+    The page's LAYOUT decides the unit the block's two products run on:
 
-    An async copy cuts HBM between whole (8, 128) tiles, so page rows with
-    ``G % 8`` or ``W % 128`` left over are padded at the edge — a copy of
-    the layer's pages a call; ``(8, 128)`` and ``(16, 128)`` rows (both
-    served configurations of the benchmark) are read where they lie. So
-    are ``head_major`` pages ``(G, bs, W)`` of any G (ten rows of 128): a
-    row's ``(bs, W)`` slab is whole tiles, the per-block arithmetic is the
-    same with the slots along the sublanes, and the state is
-    ``(T, G, 1, W)``.
+    * token-major pages ``(bs, G, W)``, r heads a row — the VPU: the lanes
+      take the block one after another (T is static and small), one
+      (G, W) row set of state a lane; a head's score is summed over its own
+      D lanes and kept broadcast across them (:func:`_head_sums`), so
+      every line after it is elementwise on full (8, 128) registers
+      whatever r is. An MXU form would need a relayout a block.
+    * ``head_major`` pages ``(G, bs, W)``, one head a row — the MXU: a
+      row's T query lanes ride side by side (``q`` as ``(G, Tp, W)``, T
+      padded to eight sublanes) against the row's ``(bs, W)`` slab, which
+      is whole tiles as it lies: ``S = einsum("gtw,gsw->gts")``, ONE
+      softmax over ``(G, Tp, bs)`` (a score is computed, masked and
+      exponentiated once, not once a lane of its row), then
+      ``einsum("gts,gsw->gtw", P, V)`` with P in float32: on bf16 pages
+      its three bf16 parts (24 bits between them) ride one matmul against
+      the V tile, which is exact in bf16, and meet again in the float32
+      accumulator; a bf16 ``q`` meets bf16 pages as it is (the product of
+      two bf16 values is exact in float32), any other pair of types in
+      float32 at full precision. State ``(G, Tp, 1)`` and ``(G, Tp, W)``.
+      Four lanes on ten rows of 128: 0.50–0.54 us a 320 KB block where the
+      lane-by-lane sweeps took 0.93–0.98 (PERF.md section 6, PR 41).
+
+    VMEM: ``4 c`` pages (2 MB at the default) plus O(T·G·W), independent
+    of sequence length, table width and pool size.
+
+    An async copy cuts HBM between whole (8, 128) tiles, so token-major
+    page rows with ``G % 8`` or ``W % 128`` left over are padded at the
+    edge — a copy of the layer's pages a call; ``(8, 128)`` and
+    ``(16, 128)`` rows (both served configurations of the benchmark) are
+    read where they lie. So are ``head_major`` pages of any G (ten rows of
+    128): a row's ``(bs, W)`` slab is whole tiles.
 
     ``window``: a stream's walk starts at the block that holds position
     ``min_t context_lens[i, t] - window`` instead of at its first, and a
@@ -924,6 +941,7 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
     r = _heads_per_row(q, k_pages, head_major)
     k_pages, v_pages, layer = _whole_pool(k_pages, v_pages, layer)
     b, tq, h, d = q.shape
+    out_dtype = q.dtype
     nb = block_tables.shape[1]
     if head_major:
         g, bs, w = k_pages.shape[2:]
@@ -931,13 +949,19 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
         if w % 128 or bs % sublanes:
             raise ValueError("head-major pages need whole (%d, 128) tiles a "
                              "row, not (%d, %d)" % (sublanes, bs, w))
-        rows = (g, w)
-        q = q.reshape(b, tq, g, 1, w)
+        # bf16 queries on bf16 pages meet on the MXU as they are (a product
+        # of two bf16 values is exact in float32); anything else in float32
+        mxu = (jnp.bfloat16 if q.dtype == k_pages.dtype == jnp.bfloat16
+               else jnp.float32)
+        prec = None if mxu == jnp.bfloat16 else jax.lax.Precision.HIGHEST
+        # a row's query lanes side by side, padded to eight sublanes
+        tp = -(-tq // 8) * 8
+        q = jnp.pad(q.transpose(0, 2, 1, 3).astype(mxu),
+                    ((0, 0), (0, 0), (0, tp - tq), (0, 0)))  # (B, G, Tp, W)
         # a row's slab is whole tiles: the page's bytes as they are
         c = _paged_blocks_per_fetch(bs * g // sublanes, sublanes, w,
                                     k_pages.dtype, nb)
-        page, state = (g, bs, w), (tq, g, 1, w)
-        slot_axis = 1
+        page, state, stat = (g, bs, w), (g, tp, w), (g, tp, 1)
     else:
         rows = k_pages.shape[3:]
         q = q.reshape((b, tq) + rows)
@@ -952,7 +976,7 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
         bs = k_pages.shape[2]
         c = _paged_blocks_per_fetch(bs, g, w, k_pages.dtype, nb)
         page, state = (bs, g, w), (tq, g, w)
-        slot_axis = 0
+        stat = state
 
     def kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref,
                k_buf, v_buf, sems, slot_ref, m_ref, l_ref, acc_ref):
@@ -997,8 +1021,8 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
             slot_ref[0] = 0
             start_fetch(0, 0, 0, live_blocks(0))
 
-        m_ref[:] = jnp.full(state, _NEG_INF, jnp.float32)
-        l_ref[:] = jnp.zeros(state, jnp.float32)
+        m_ref[:] = jnp.full(stat, _NEG_INF, jnp.float32)
+        l_ref[:] = jnp.zeros(stat, jnp.float32)
         acc_ref[:] = jnp.zeros(state, jnp.float32)
         ctx = [cl_ref[i, t] for t in range(tq)]
         n_blk = live_blocks(i)
@@ -1006,6 +1030,77 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
         slot0 = slot_ref[0]
         nxt = jnp.minimum(i + 1, b - 1)
         nxt_blk = live_blocks(nxt)
+
+        def lanes_ctx(shape):
+            """The lanes' context lengths down axis 1 of ``shape``, 0 (an
+            empty lane) on the rows that pad T to a tile."""
+            lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            return functools.reduce(
+                lambda x, t: jnp.where(lane == t, ctx[t], x), range(tq),
+                jnp.zeros(shape, jnp.int32))
+
+        def mxu_block(first, k, v):
+            """A head-major block ``(G, bs, W)`` whose first slot holds
+            position ``first``: every row's T lanes against its slab in two
+            batched matmuls around ONE softmax over ``(G, Tp, bs)``."""
+            s = jax.lax.dot_general(
+                q_ref[0], k.astype(mxu), (((2,), (2,)), ((0,), (0,))),
+                precision=prec,
+                preferred_element_type=jnp.float32) * sm_scale
+            pos = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+            upto = lanes_ctx(s.shape)
+            seen = pos < upto
+            if window is not None:
+                seen = seen & (pos >= upto - window)
+            s = jnp.where(seen, s, _NEG_INF)
+            m = m_ref[:]                                     # (G, Tp, 1)
+            m_new = jnp.maximum(m, jnp.max(s, axis=2, keepdims=True))
+            p = jnp.exp(s - m_new)
+            scale = jnp.exp(m - m_new)
+            m_ref[:] = m_new
+            l_ref[:] = l_ref[:] * scale + jnp.sum(p, axis=2, keepdims=True)
+            pv_dims = (((2,), (1,)), ((0,), (0,)))
+            if mxu == jnp.float32:
+                pv = jax.lax.dot_general(
+                    p, v.astype(mxu), pv_dims, precision=prec,
+                    preferred_element_type=jnp.float32)
+            else:
+                # P stays float32: its three bf16 parts (24 bits between
+                # them) ride one matmul against the V tile, which is exact
+                # in bf16, and meet again in the float32 accumulator
+                parts, rest = [], p
+                for _ in range(3):
+                    parts.append(rest.astype(mxu))
+                    rest = rest - parts[-1].astype(jnp.float32)
+                pv = jax.lax.dot_general(
+                    jnp.concatenate(parts, axis=1), v, pv_dims,
+                    preferred_element_type=jnp.float32)
+                pv = pv[:, :tp] + pv[:, tp:2 * tp] + pv[:, 2 * tp:]
+            acc_ref[:] = acc_ref[:] * scale + pv
+
+        def vpu_block(first, k, v):
+            """A token-major block ``(bs, G, W)``, r heads a row: the lanes
+            one after another, elementwise on whole registers."""
+            kv = k.astype(jnp.float32)
+            vv = v.astype(jnp.float32)
+            pos = first + jax.lax.broadcasted_iota(jnp.int32, page, 0)
+            for t in range(tq):
+                qv = q_ref[0, t].astype(jnp.float32)             # (G, W)
+                s = _head_sums(qv[None] * kv, r) * sm_scale      # (bs, G, W)
+                if window is None:
+                    s = jnp.where(pos < ctx[t], s, _NEG_INF)
+                else:
+                    s = jnp.where((pos < ctx[t])
+                                  & (pos >= ctx[t] - window), s, _NEG_INF)
+                m = m_ref[t]
+                m_new = jnp.maximum(m, jnp.max(s, axis=0))
+                p = jnp.exp(s - m_new[None])
+                scale = jnp.exp(m - m_new)
+                m_ref[t] = m_new
+                l_ref[t] = l_ref[t] * scale + jnp.sum(p, axis=0)
+                acc_ref[t] = acc_ref[t] * scale + jnp.sum(p * vv, axis=0)
+
+        block_math = mxu_block if head_major else vpu_block
 
         def fetch_step(it, _):
             slot = (slot0 + it) % 2
@@ -1024,44 +1119,9 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
                 blk = it * c + j
                 for cp in copies(i, blk, slot, j):
                     cp.wait()
-                kv = k_buf[slot, j].astype(jnp.float32)   # (bs, G, W)
-                vv = v_buf[slot, j].astype(jnp.float32)
                 if window is not None:
                     blk = blk + first_block(i)
-                pos = blk * bs + jax.lax.broadcasted_iota(
-                    jnp.int32, page, slot_axis)
-                for t in range(tq):
-                    qv = q_ref[0, t].astype(jnp.float32)            # (G, W)
-                    if head_major:      # the same lines, slots on axis 1
-                        s = _head_sums(qv * kv, r) * sm_scale    # (G, bs, W)
-                        seen = pos < ctx[t]
-                        if window is not None:
-                            seen = seen & (pos >= ctx[t] - window)
-                        s = jnp.where(seen, s, _NEG_INF)
-                        m = m_ref[t]                             # (G, 1, W)
-                        m_new = jnp.maximum(
-                            m, jnp.max(s, axis=1, keepdims=True))
-                        p = jnp.exp(s - m_new)
-                        scale = jnp.exp(m - m_new)
-                        m_ref[t] = m_new
-                        l_ref[t] = l_ref[t] * scale + jnp.sum(
-                            p, axis=1, keepdims=True)
-                        acc_ref[t] = acc_ref[t] * scale + jnp.sum(
-                            p * vv, axis=1, keepdims=True)
-                        continue
-                    s = _head_sums(qv[None] * kv, r) * sm_scale  # (bs, G, W)
-                    if window is None:
-                        s = jnp.where(pos < ctx[t], s, _NEG_INF)
-                    else:
-                        s = jnp.where((pos < ctx[t])
-                                      & (pos >= ctx[t] - window), s, _NEG_INF)
-                    m = m_ref[t]
-                    m_new = jnp.maximum(m, jnp.max(s, axis=0))
-                    p = jnp.exp(s - m_new[None])
-                    scale = jnp.exp(m - m_new)
-                    m_ref[t] = m_new
-                    l_ref[t] = l_ref[t] * scale + jnp.sum(p, axis=0)
-                    acc_ref[t] = acc_ref[t] * scale + jnp.sum(p * vv, axis=0)
+                block_math(blk * bs, k_buf[slot, j], v_buf[slot, j])
                 return 0
 
             jax.lax.fori_loop(0, jnp.minimum(c, n_blk - it * c), block_step, 0)
@@ -1069,11 +1129,16 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
 
         jax.lax.fori_loop(0, n_fetch, fetch_step, 0)
         slot_ref[0] = (slot0 + n_fetch) % 2
+        # a lane that never saw a valid position accumulated
+        # exp(-1e30 - -1e30) = 1 weights over garbage — pin it to the
+        # oracle's empty-lane zero
+        if head_major:
+            out = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
+            o_ref[0] = jnp.where(lanes_ctx(state) > 0, out,
+                                 0.0).astype(o_ref.dtype)
+            return
         for t in range(tq):
             out = acc_ref[t] / jnp.maximum(l_ref[t], 1e-30)
-            # a lane that never saw a valid position accumulated
-            # exp(-1e30 - -1e30) = 1 weights over garbage — pin it to
-            # the oracle's empty-lane zero
             out = jnp.where(ctx[t] > 0, out, 0.0)
             o_ref[0, t] = out.astype(o_ref.dtype)
 
@@ -1088,13 +1153,15 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
         scratch_shapes=[pltpu.VMEM((2, c) + page, k_pages.dtype),
                         pltpu.VMEM((2, c) + page, v_pages.dtype),
                         pltpu.SemaphoreType.DMA((2, c)),
-                        pltpu.SMEM((1,), jnp.int32)]
-        + [pltpu.VMEM(state, jnp.float32)] * 3,
+                        pltpu.SMEM((1,), jnp.int32),
+                        pltpu.VMEM(stat, jnp.float32),
+                        pltpu.VMEM(stat, jnp.float32),
+                        pltpu.VMEM(state, jnp.float32)],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b,) + state, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b,) + state, out_dtype),
         # the scratch carries one stream's prefetch into the next step
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
@@ -1102,7 +1169,7 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
       q, k_pages, v_pages)
     if head_major:
-        return out.reshape(b, tq, h, d)
+        return out[:, :, :tq].transpose(0, 2, 1, 3).reshape(b, tq, h, d)
     return out[:, :, :rows[0], :rows[1]].reshape(b, tq, h, d)
 
 

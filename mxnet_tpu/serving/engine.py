@@ -2034,6 +2034,29 @@ class ServingEngine:
             "full_pool_layers": self.pool.num_layers,
         }
 
+    def _mxu_share(self):
+        """Of the block walks counted since start — a walk a live lane,
+        step and layer that attends: the full pool's
+        (``_paged_live_blocks``) and a window pool's (the loop records'
+        ``window_live_blocks``) — the share on head-major pages, whose
+        block is two matmuls on the MXU (``ops/attention.py``: the paged
+        kernel's head-major form, ``latent_paged``); a token-major block
+        is elementwise work. From what is already counted: nothing is
+        booked a step for it."""
+        cfg, st = self.config, self.streams
+        readers = (len(cfg.layers_of("full", "cross", "mla")) if cfg.hybrid
+                   else cfg.num_layers)
+        walks = [(readers * self._paged_live_blocks, self.pool)]
+        if st is not None and st.pool is not None:
+            walks.append((len(cfg.layers_of("swa"))
+                          * self.obs.loop_snapshot()["sums"][
+                              "window_live_blocks"], st.pool))
+        total = sum(n for n, _pool in walks)
+        share = (sum(n for n, pool in walks if pool.is_head_major) / total
+                 if total else 0.0)
+        telemetry.gauge("serving.paged.mxu_share").set(share)
+        return share
+
     def stats(self):
         """One dashboard snapshot (serve.py columns, /stats endpoint).
 
@@ -2104,6 +2127,7 @@ class ServingEngine:
                     "live_share":
                         (self._paged_live_blocks / self._paged_table_slots)
                         if self._paged_table_slots else 0.0,
+                    "mxu_share": self._mxu_share(),
                 },
                 "decode": {
                     "dispatches": self._decode_dispatches,

@@ -829,6 +829,10 @@ METRIC_HELP = {
     "serving.paged.table_slots":
         "block-table slots those streams held (live streams x table "
         "width a pass): live_blocks / this = how full the tables ran",
+    "serving.paged.mxu_share":
+        "of the paged block walks counted since start, the share on "
+        "head-major pages (a block is two MXU matmuls there, elementwise "
+        "work on token-major pages); set when stats() is read",
     "serving.moe.pairs":
         "token-expert pairs the routed FFN computed (live tokens x "
         "experts per token x layers; nothing is dropped)",

@@ -122,6 +122,27 @@ def test_paged_kernels_compile_for_v5e(v5e, h, d, rows, layers, blocks, dtype,
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("window", [None, 512], ids=["full", "w512"])
+@pytest.mark.parametrize("lanes", [4, 1, 3])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+def test_head_major_paged_kernel_compiles_for_v5e(v5e, dtype, lanes, window):
+    """Phi-4-mini-flash's pages, ten rows of 128 lanes as ``(G, bs, W)``
+    blocks of 64: the block's two batched matmuls (bf16 operands as they
+    are, float32 at full precision) and the softmax over ``(G, Tp, bs)``
+    between them, T a tile's worth or not."""
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=v5e)
+
+    pages = s((8, 641, 10, 64, 128), dtype)
+    text = _compiled_text(
+        functools.partial(A._paged_pallas_multi, sm_scale=0.125, layer=1,
+                          window=window, head_major=True),
+        s((64, lanes, 10, 128), dtype), pages, pages,
+        s((64, 40), jnp.int32), s((64, lanes), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize("page,dtype,c", [
     ((16, 8, 128), jnp.float32, 8),      # GPT-2 medium: 64 KB pages
     ((64, 16, 128), jnp.bfloat16, 2),    # OLMoE: 256 KB pages

@@ -308,7 +308,9 @@ def test_cow_and_prefix_hit_on_a_packed_pool(r):
 # 128 lanes as (G, bs, W) slabs; the kernel in interpret mode against the
 # gathered reference, and the reference against a dense computation.
 def _head_major(case, dtype, g=10, w=128, N=12, L=2, lanes=4):
-    cl = np.repeat(np.asarray(RAGGED[case], np.int32)[:, :1], lanes, axis=1)
+    cl = np.asarray(RAGGED[case], np.int32)
+    # one context a stream for all its lanes, or the case's own per lane
+    cl = cl[:, :lanes] if cl.shape[1] > 1 else np.repeat(cl, lanes, axis=1)
     rng = np.random.RandomState(len(case))
     B = len(cl)
     q = rng.randn(B, lanes, g, w)
@@ -322,16 +324,33 @@ def _head_major(case, dtype, g=10, w=128, N=12, L=2, lanes=4):
     return q, kp, vp, jnp.asarray(bt), jnp.asarray(cl)
 
 
+# four lanes that share a context (the four query heads of a differential
+# K/V row); since PR 41 also one lane and three (a T that is no tile), lanes
+# whose contexts differ inside a stream ("lanes": an empty lane beside a
+# long one, contexts that end on a block's last slot, a whole stream empty)
+# and a window whose start falls inside a block (24 of blocks of 16)
+_HM_CASES = [(4, case, window)
+             for case in ("boundaries", "last-longest", "first-longest",
+                          "dead-1e30")
+             for window in (None, 40, 16)]
+_HM_CASES += [(lanes, case, window) for lanes in (1, 3)
+              for case in ("boundaries", "lanes", "dead-1e30")
+              for window in (None, 24)]
+_HM_CASES += [(4, "lanes", window) for window in (None, 40, 16, 24)]
+_HM_CASES += [(4, "boundaries", 24)]
+
+
 @pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("window", [None, 40, 16], ids=["full", "w40", "w16"])
-@pytest.mark.parametrize("case", ["boundaries", "last-longest",
-                                  "first-longest", "dead-1e30"])
-def test_head_major_kernel_is_the_reference(case, window, dtype):
-    """The same walk over ``(G, bs, W)`` blocks: four query lanes a stream
-    (the four query heads of a differential K/V row), from the block that
-    holds ``context - window``; slots behind the window are never read."""
+@pytest.mark.parametrize(
+    "lanes,case,window", _HM_CASES,
+    ids=["T%d-%s-%s" % (t, c, "full" if w is None else "w%d" % w)
+         for t, c, w in _HM_CASES])
+def test_head_major_kernel_is_the_reference(lanes, case, window, dtype):
+    """The same walk over ``(G, bs, W)`` blocks, a block's arithmetic on
+    the MXU: T query lanes a stream, from the block that holds the shortest
+    lane's ``context - window``; slots behind the window are never read."""
     dt, tol = DTYPES[dtype]
-    q, kp, vp, bt, cl = _head_major(case, dt)
+    q, kp, vp, bt, cl = _head_major(case, dt, lanes=lanes)
     if window is not None and case == "dead-1e30":
         # blocks wholly behind every lane's window may name anything too
         bt = np.array(bt)

@@ -536,3 +536,28 @@ def test_telemetry_off_books_the_engines_own_tallies(monkeypatch):
         assert st["decode"]["inner_steps"] == 11
     finally:
         telemetry.reset()
+
+
+@pytest.mark.parametrize("name,share", [("one_block", 0.0), ("experts", 0.0),
+                                        ("hybrid", 1.0), ("latent", 1.0)])
+def test_mxu_share_is_read_off_what_is_already_counted(name, share, telem):
+    """``stats()["paged"]["mxu_share"]``: of the block walks counted, the
+    share on head-major pages (two MXU matmuls a block; a token-major
+    block is elementwise work). Nothing is booked a step for it: read
+    straight after the last dispatch, with the last chunk still set aside,
+    it is whole; before any walk it is 0."""
+    eng = ServingEngine(family(FAMILIES[name]), seed=SEED)
+    assert eng.stats()["paged"]["mxu_share"] == 0.0
+    with eng._lock:
+        for i in range(3):
+            eng.submit([1, 2, 3 + i], 12)
+    while eng.has_work():
+        eng._guarded_step(defer=True)
+    assert eng._pending is not None
+    paged = eng.stats()["paged"]
+    assert paged["live_blocks"] > 0
+    assert paged["mxu_share"] == share == float(eng.pool.is_head_major)
+    assert telem.gauge("serving.paged.mxu_share").value == share
+    if name == "hybrid":            # the window pool's walks are in it
+        assert eng.stats()["loop"]["sums"]["window_live_blocks"] > 0
+        assert eng.streams.pool.is_head_major

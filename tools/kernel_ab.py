@@ -228,6 +228,74 @@ def latent_ladder(block_sizes=(64, 128, 256), contexts=(512, 2048),
     return rows
 
 
+# ---------------------------------------------------------------------------
+# python3 tools/kernel_ab.py --paged-lanes: the paged kernel on Phi-4's
+# head-major pages, a K/V row's four query heads as four query lanes
+# ---------------------------------------------------------------------------
+def paged_lanes_case(ctx, streams=64, lanes=4, rows=10, bs=64, w=128, seed=0):
+    """One layer's head-major pages ``(N, rows, bs, w)`` in bfloat16 for
+    ``streams`` streams of ``ctx`` cached tokens (distinct blocks, block 0
+    trash) and ``lanes`` query lanes a stream that share the context:
+    ``(q, k_pages, v_pages, tables, context_lens)``."""
+    import jax.numpy as jnp
+
+    per = -(-ctx // bs)
+    n = streams * per + 1
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    bf = jnp.bfloat16
+    kp, vp = (jax.random.normal(k, (n, rows, bs, w), bf) for k in ks[:2])
+    q = jax.random.normal(ks[2], (streams, lanes, rows, w), bf)
+    tables = 1 + np.arange(streams * per, dtype=np.int32).reshape(streams, per)
+    return (q, kp, vp, jnp.asarray(tables),
+            jnp.full((streams, lanes), ctx, jnp.int32))
+
+
+def paged_lanes_ladder(contexts=(512, 1536, 2560), windows=(512, None),
+                       iters=10):
+    """The paged kernel at Phi-4's decode shapes, chained (memory-bound):
+    one JSON-able row a rung with the kernel's microseconds a call and a
+    live block, the block's bytes (K and V) over the HBM peak's time beside
+    it, and the largest difference from the gather reference."""
+    import functools
+
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import attention as A
+
+    rows = []
+    for ctx in contexts:
+        q, kp, vp, bt, cl = paged_lanes_case(ctx)
+        bs = kp.shape[2]
+        block_bytes = 2 * kp[0].size * kp.dtype.itemsize
+        for window in windows:
+            kw = dict(sm_scale=0.125, window=window, head_major=True)
+
+            def body(i, kp, vp, bt, cl, q):
+                return A._paged_pallas_multi(
+                    q * (1 + 1e-3 * i).astype(q.dtype), kp, vp, bt, cl, **kw)
+
+            call_us, per = device_time_us_chained(body, (kp, vp, bt, cl, q),
+                                                  iters=iters)
+            kernel_us = max(per.values())    # the custom call, by far
+            first = 0 if window is None else max(ctx - window, 0) // bs
+            live = q.shape[0] * (-(-ctx // bs) - first)
+            bound = block_bytes / 819e9 * 1e6
+            got = A._paged_pallas_multi(q, kp, vp, bt, cl, **kw)
+            want = A.paged_attention_multi_reference(q, kp, vp, bt, cl, **kw)
+            rows.append({
+                "ctx": ctx, "window": window, "streams": q.shape[0],
+                "lanes": q.shape[1], "live_blocks": live,
+                "block_bytes": block_bytes,
+                "call_us": round(call_us, 1),
+                "kernel_us": round(kernel_us, 1),
+                "us_per_live_block": round(kernel_us / live, 4),
+                "hbm_peak_us_per_block": round(bound, 4),
+                "share_of_peak": round(bound / (kernel_us / live), 3),
+                "max_diff": float(jnp.max(jnp.abs(
+                    got.astype(jnp.float32) - want.astype(jnp.float32))))})
+    return rows
+
+
 def flash_two_widths(seq=2048, heads=128, dk=192, dv=128, iters=5):
     """The flash forward at latent attention's expanded heads (keys ``dk``,
     values ``dv``): microseconds a call, kernel and XLA scan."""
@@ -260,6 +328,9 @@ def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--latent", action="store_true",
                     help="latent_paged against its XLA path, the ladder")
+    ap.add_argument("--paged-lanes", action="store_true",
+                    help="the paged kernel on Phi-4's head-major pages, four "
+                         "query lanes a stream, against the bytes bound")
     ap.add_argument("--flash", action="store_true",
                     help="the flash forward at keys 192 / values 128")
     ap.add_argument("--no-xla", action="store_true")
@@ -277,6 +348,9 @@ def main(argv=None):
                 fetch_rows=ints(args.fetch_rows) if args.fetch_rows
                 else (None,)):
             print(json.dumps(dict(row, kab="latent_paged")), flush=True)
+    if args.paged_lanes:
+        for row in paged_lanes_ladder():
+            print(json.dumps(dict(row, kab="paged_lanes")), flush=True)
     if args.flash:
         print(json.dumps(dict(flash_two_widths(), kab="flash_forward")),
               flush=True)
