@@ -35,7 +35,8 @@ from jax import lax
 
 from .registry import Param, fp32_precision, register
 
-__all__ = ["flash_attention", "attention_reference", "paged_attention",
+__all__ = ["flash_attention", "flash_attention_gqa", "attention_reference",
+           "paged_attention",
            "paged_attention_reference", "paged_attention_multi",
            "paged_attention_multi_reference", "latent_paged",
            "latent_paged_reference"]
@@ -133,7 +134,7 @@ def _scan_forward(q, k, v, causal, sm_scale, block_k, window=None):
 
 
 def _pallas_forward(q, k, v, causal, sm_scale, block_q=512, block_k=1024,
-                    interpret=False, window=None):
+                    interpret=False, window=None, kv_group=1, name=None):
     """Pallas TPU flash-attention forward.
 
     ``window`` (sliding-window attention): key j is visible to query i only
@@ -148,6 +149,13 @@ def _pallas_forward(q, k, v, causal, sm_scale, block_q=512, block_k=1024,
     KV step. Returns (out, lse) float32, identical residuals to
     ``_scan_forward``. The values may be of another width ``dv`` than the
     keys (latent attention's expanded heads: keys 192, values 128).
+
+    ``kv_group`` > 1 (grouped queries): k and v are ``(B, H / kv_group, S,
+    .)`` and the index map names query head i's K/V head, ``i //
+    kv_group``: a K/V head is not written ``kv_group`` times to HBM first.
+    ``name``: the custom call's name on a trace (without one it is
+    ``branch_0_fun``, as every earlier call's). The defaults leave the
+    program every earlier caller traced as it was.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -212,8 +220,8 @@ def _pallas_forward(q, k, v, causal, sm_scale, block_q=512, block_k=1024,
 
     bh = b * h
     qr = q.reshape(bh, sq, d)
-    kr = k.reshape(bh, sk, d)
-    vr = v.reshape(bh, sk, dv)
+    kr = k.reshape(bh // kv_group, sk, d)
+    vr = v.reshape(bh // kv_group, sk, dv)
     pad_q = n_q * block_q - sq
     pad_k = n_k * block_k - sk
     if pad_q:
@@ -222,13 +230,17 @@ def _pallas_forward(q, k, v, causal, sm_scale, block_q=512, block_k=1024,
         kr = jnp.pad(kr, ((0, 0), (0, pad_k), (0, 0)))
         vr = jnp.pad(vr, ((0, 0), (0, pad_k), (0, 0)))
     grid = (bh, n_q, n_k)
+
+    def kv_at(i, j, kk):
+        return (i // kv_group if kv_group > 1 else i), kk, 0
+
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j, kk: (i, kk, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda i, j, kk: (i, kk, 0)),
+            pl.BlockSpec((1, block_k, d), kv_at),
+            pl.BlockSpec((1, block_k, dv), kv_at),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, dv), lambda i, j, kk: (i, j, 0)),
@@ -244,6 +256,7 @@ def _pallas_forward(q, k, v, causal, sm_scale, block_q=512, block_k=1024,
             pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         interpret=interpret,
+        **({} if name is None else {"name": name}),
     )(qr, kr, vr)
     out = out[:, :sq].reshape(b, h, sq, dv)
     lse = lse[:, 0, :sq].reshape(b, h, sq)
@@ -510,6 +523,43 @@ def _fa_bwd(causal, sm_scale, block_k, window, res, g):
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
+def flash_attention_gqa(q, k, v, sm_scale=None, window=None, sink=None,
+                        block_k=256):
+    """Causal grouped-query attention, forward only (serving prefill):
+    q ``(B, H, S, D)`` over k ``(B, Hkv, S, D)`` and v ``(B, Hkv, S, Dv)``,
+    query head j reading K/V head ``j // (H / Hkv)``.
+
+    ``window``: key j is visible to query i only if ``i - j < window``.
+    ``sink`` (H,) float32: a scalar a query head that joins the softmax's
+    denominator and no numerator, ``p_ij = exp(s_ij) / (exp(b) + sum_j'
+    exp(s_ij'))``: the plain result times ``sigmoid(lse - b)``.
+
+    On the TPU the Pallas forward with the K/V head named by the index map
+    (K is not repeated in HBM; a window's blocks wholly behind the band are
+    skipped), its custom call named ``flash_gqa_fwd`` on a trace; elsewhere
+    the scan over repeated K/V."""
+    sm_scale = _scale(sm_scale, q.shape[-1])
+    group = q.shape[1] // k.shape[1]
+    kw = {"causal": True, "sm_scale": sm_scale,
+          "window": None if window is None else int(window)}
+
+    def scan(q, k, v):
+        return _scan_forward(q, jnp.repeat(k, group, axis=1),
+                             jnp.repeat(v, group, axis=1), block_k=block_k,
+                             **kw)
+
+    if _pallas_shapes_ok(q, k):
+        out, lse = lax.platform_dependent(
+            q, k, v, tpu=functools.partial(_pallas_forward, kv_group=group,
+                                           name="flash_gqa_fwd", **kw),
+            default=scan)
+    else:
+        out, lse = scan(q, k, v)
+    if sink is not None:
+        out = out * jax.nn.sigmoid(lse - sink[None, :, None])[..., None]
+    return out.astype(q.dtype)
+
+
 # ------------------------------------------------------------- registered ops
 @register(
     "_contrib_FlashAttention",
@@ -710,11 +760,13 @@ def _whole_pool(k_pages, v_pages, layer):
 
 def _gather_tokens(pages, block_tables, h, d, head_major=False):
     """Each sequence's pages (N, bs, G, W) in position order, unpacked:
-    (B, T, H, D) f32."""
+    (B, T, H, D) f32; head-major pages' rows are ``W`` wide whatever ``d``
+    (V pages may be narrower than K pages)."""
     b, nb = block_tables.shape
     x = jnp.take(pages, block_tables, axis=0)       # (B, nb, bs, G, W)
     if head_major:                                  # (B, nb, G, bs, W)
         x = x.transpose(0, 1, 3, 2, 4)
+        d = pages.shape[-1]
     return x.reshape(b, -1, h, d).astype(jnp.float32)
 
 
@@ -782,7 +834,8 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
 # --------------------------------------------- paged multi-query (verify)
 def paged_attention_multi_reference(q, k_pages, v_pages, block_tables,
                                     context_lens, sm_scale=None, layer=None,
-                                    window=None, head_major=False):
+                                    window=None, head_major=False, sink=None,
+                                    name=None):
     """Pure-XLA multi-query paged attention — q-length > 1 per sequence
     with PER-LANE context lengths. The speculative-decoding verify pass
     and the CPU/CI lowering of the Pallas kernel below.
@@ -803,7 +856,13 @@ def paged_attention_multi_reference(q, k_pages, v_pages, block_tables,
     window:       a lane reads only its last ``window`` positions, those
                   ``>= context_len - window`` (table slots wholly behind
                   every lane's window may name any block)
-    head_major:   pages are ``(.., N, G, bs, W)``
+    head_major:   pages are ``(.., N, G, bs, W)``; ``v_pages`` may then
+                  be narrower than ``k_pages`` (the result is as wide as
+                  they), and ``context_lens`` (B, 1) is every lane's
+    sink:         (T, H) float32, a scalar a lane and head that joins the
+                  softmax's denominator and no numerator (here: one more
+                  column of the scores, dropped after the softmax)
+    name:         the Pallas call's name on a trace (nothing here)
 
     Returns (B, T, H, D) in q.dtype. T == 1 with context_lens (B, 1)
     is :func:`paged_attention_reference`. A lane with context_len == 0
@@ -823,7 +882,12 @@ def paged_attention_multi_reference(q, k_pages, v_pages, block_tables,
     if window is not None:
         valid = valid & (pos >= context_lens[:, :, None] - window)
     s = jnp.where(valid[:, None, :, :], s, _NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
+    if sink is None:
+        p = jax.nn.softmax(s, axis=-1)
+    else:
+        col = jnp.broadcast_to(sink.astype(jnp.float32).T[None, :, :, None],
+                               s.shape[:3] + (1,))
+        p = jax.nn.softmax(jnp.concatenate([s, col], -1), axis=-1)[..., :-1]
     # all-masked lanes (context_len == 0) softmax to uniform and would
     # average gathered garbage — pin them to zero
     p = jnp.where((context_lens > 0)[:, None, :, None], p, 0.0)
@@ -873,7 +937,7 @@ def _paged_blocks_per_fetch(bs, g, w, dtype, nb):
 
 def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
                         sm_scale, layer=None, interpret=False, window=None,
-                        head_major=False):
+                        head_major=False, sink=None, name=None):
     """Pallas TPU ragged-paged-attention kernel, T query lanes per sequence
     (T = 1 is the decode step, T = k + 1 the speculative verify pass).
 
@@ -934,6 +998,16 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
     ``min_t context_lens[i, t] - window`` instead of at its first, and a
     lane masks what lies before its own ``context_len - window``: table
     slots behind the window are never read and may name any block.
+
+    Head-major pages also take (plain grouped-query attention's calls):
+    ``v_pages`` NARROWER than ``k_pages`` (keys of 192 lanes in a 256-lane
+    row beside values of 128: the second matmul, the accumulator and the
+    result are as wide as V's rows); ``context_lens`` (B, 1) for T lanes
+    that share it (a K/V row's query heads: one SMEM read, one select);
+    ``sink`` (T, G) float32, the online softmax's START STATE — m0 the
+    sink, l0 = 1, acc0 = 0: it joins the denominator and no numerator, and
+    the score needs no column for it; ``name``, the custom call's name on a
+    trace (without one it is ``branch_0_fun``, as every earlier call's).
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -961,8 +1035,16 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
         # a row's slab is whole tiles: the page's bytes as they are
         c = _paged_blocks_per_fetch(bs * g // sublanes, sublanes, w,
                                     k_pages.dtype, nb)
+        wv = v_pages.shape[-1]
         page, state, stat = (g, bs, w), (g, tp, w), (g, tp, 1)
+        v_page, o_state = (g, bs, wv), (g, tp, wv)
+        if sink is not None:
+            sink = jnp.pad(sink.astype(jnp.float32).T,
+                           ((0, 0), (0, tp - tq)))[..., None]    # (G, Tp, 1)
     else:
+        if sink is not None or v_pages.shape[-1] != k_pages.shape[-1]:
+            raise ValueError("a sink and V pages narrower than K pages "
+                             "need head-major pages")
         rows = k_pages.shape[3:]
         q = q.reshape((b, tq) + rows)
         # rows that do not fill their tiles ((6, 128), (12, 64)): see above
@@ -976,23 +1058,29 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
         bs = k_pages.shape[2]
         c = _paged_blocks_per_fetch(bs, g, w, k_pages.dtype, nb)
         page, state = (bs, g, w), (tq, g, w)
-        stat = state
+        stat, v_page, o_state = state, page, state
+    # T lanes of one context length (B, 1): lane 0's is every lane's
+    shared = context_lens.shape[1] == 1 and tq > 1
+    ncl = 1 if shared else tq
 
-    def kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref,
-               k_buf, v_buf, sems, slot_ref, m_ref, l_ref, acc_ref):
+    def kernel(bt_ref, cl_ref, q_ref, *refs):
+        if sink is not None:
+            sink_ref, *refs = refs
+        (k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref, m_ref, l_ref,
+         acc_ref) = refs
         i = pl.program_id(0)  # sequence
 
         def first_block(seq):
             """The table slot a stream's walk starts at: the block of the
             shortest lane's first visible position."""
             shortest = functools.reduce(
-                jnp.minimum, [cl_ref[seq, t] for t in range(tq)])
+                jnp.minimum, [cl_ref[seq, t] for t in range(ncl)])
             return jnp.maximum(shortest - window, 0) // bs
 
         def live_blocks(seq):
             # SMEM yields scalars only: one read per lane, T is static
             longest = functools.reduce(
-                jnp.maximum, [cl_ref[seq, t] for t in range(tq)])
+                jnp.maximum, [cl_ref[seq, t] for t in range(ncl)])
             # one block for an empty stream; never past the table
             n = jnp.clip(pl.cdiv(longest, bs), 1, nb)
             return n if window is None else n - first_block(seq)
@@ -1021,10 +1109,14 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
             slot_ref[0] = 0
             start_fetch(0, 0, 0, live_blocks(0))
 
-        m_ref[:] = jnp.full(stat, _NEG_INF, jnp.float32)
-        l_ref[:] = jnp.zeros(stat, jnp.float32)
-        acc_ref[:] = jnp.zeros(state, jnp.float32)
-        ctx = [cl_ref[i, t] for t in range(tq)]
+        if sink is None:
+            m_ref[:] = jnp.full(stat, _NEG_INF, jnp.float32)
+            l_ref[:] = jnp.zeros(stat, jnp.float32)
+        else:           # the sink is the softmax's start state
+            m_ref[:] = sink_ref[...]
+            l_ref[:] = jnp.ones(stat, jnp.float32)
+        acc_ref[:] = jnp.zeros(o_state, jnp.float32)
+        ctx = [cl_ref[i, t] for t in range(ncl)]
         n_blk = live_blocks(i)
         n_fetch = pl.cdiv(n_blk, c)
         slot0 = slot_ref[0]
@@ -1035,6 +1127,8 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
             """The lanes' context lengths down axis 1 of ``shape``, 0 (an
             empty lane) on the rows that pad T to a tile."""
             lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            if shared:
+                return jnp.where(lane < tq, ctx[0], 0)
             return functools.reduce(
                 lambda x, t: jnp.where(lane == t, ctx[t], x), range(tq),
                 jnp.zeros(shape, jnp.int32))
@@ -1134,7 +1228,7 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
         # oracle's empty-lane zero
         if head_major:
             out = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
-            o_ref[0] = jnp.where(lanes_ctx(state) > 0, out,
+            o_ref[0] = jnp.where(lanes_ctx(o_state) > 0, out,
                                  0.0).astype(o_ref.dtype)
             return
         for t in range(tq):
@@ -1142,53 +1236,61 @@ def _paged_pallas_multi(q, k_pages, v_pages, block_tables, context_lens,
             out = jnp.where(ctx[t] > 0, out, 0.0)
             o_ref[0, t] = out.astype(o_ref.dtype)
 
-    q_spec = pl.BlockSpec((1,) + state,
-                          lambda i, bt, cl: (i,) + (0,) * len(state))
+    def lane_spec(shape):
+        return pl.BlockSpec((1,) + shape,
+                            lambda i, bt, cl: (i,) + (0,) * len(shape))
+
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    sink_arg = [] if sink is None else [sink]
+    sink_spec = [pl.BlockSpec(stat, lambda i, bt, cl: (0, 0, 0))
+                 for _ in sink_arg]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
-        in_specs=[q_spec, pool_spec, pool_spec],
-        out_specs=q_spec,
+        in_specs=[lane_spec(state)] + sink_spec + [pool_spec, pool_spec],
+        out_specs=lane_spec(o_state),
         scratch_shapes=[pltpu.VMEM((2, c) + page, k_pages.dtype),
-                        pltpu.VMEM((2, c) + page, v_pages.dtype),
+                        pltpu.VMEM((2, c) + v_page, v_pages.dtype),
                         pltpu.SemaphoreType.DMA((2, c)),
                         pltpu.SMEM((1,), jnp.int32),
                         pltpu.VMEM(stat, jnp.float32),
                         pltpu.VMEM(stat, jnp.float32),
-                        pltpu.VMEM(state, jnp.float32)],
+                        pltpu.VMEM(o_state, jnp.float32)],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b,) + state, out_dtype),
+        out_shape=jax.ShapeDtypeStruct((b,) + o_state, out_dtype),
         # the scratch carries one stream's prefetch into the next step
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        **({} if name is None else {"name": name}),
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-      q, k_pages, v_pages)
+      q, *sink_arg, k_pages, v_pages)
     if head_major:
-        return out[:, :, :tq].transpose(0, 2, 1, 3).reshape(b, tq, h, d)
+        return out[:, :, :tq].transpose(0, 2, 1, 3).reshape(b, tq, h, wv)
     return out[:, :, :rows[0], :rows[1]].reshape(b, tq, h, d)
 
 
 def paged_attention_multi(q, k_pages, v_pages, block_tables, context_lens,
                           sm_scale=None, layer=None, window=None,
-                          head_major=False):
+                          head_major=False, sink=None, name=None):
     """Multi-query paged attention over a shared KV block pool: q is
     (B, T, H, D), context_lens (B, T) per lane — the speculative-decoding
     verify pass scores all T = k+1 window positions in this ONE dispatch.
 
     Platform selected at LOWERING time like :func:`flash_attention`: the
     Pallas kernel on TPU, the pure-XLA gather reference everywhere else.
-    Serving-only (no vjp). ``window`` and ``head_major``: see
-    :func:`paged_attention_multi_reference`.
+    Serving-only (no vjp). ``window``, ``head_major``, ``sink`` and
+    ``name``: see :func:`paged_attention_multi_reference`.
     """
     sm_scale = _scale(sm_scale, q.shape[-1])
     kw = {"sm_scale": sm_scale, "layer": layer}
     if window is not None or head_major:    # the existing calls' jaxprs stay
         kw.update(window=window, head_major=head_major)
+    if sink is not None or name is not None:
+        kw.update(sink=sink, name=name)
     if _paged_shapes_ok(q, k_pages):
         return lax.platform_dependent(
             q, k_pages, v_pages, block_tables, context_lens,

@@ -142,6 +142,21 @@ class ServingConfig(_model.ModelConfig):
                 "max_len (%d) must be a multiple of block_size (%d): "
                 "prefill buckets and the decode block table are sized in "
                 "whole blocks" % (self.max_len, self.block_size))
+        if self.gqa and (self.prefix_cache or self.spec_k):
+            # before the two refusals of a stateful model: they would say
+            # less, and a model of "full" layers alone passes them
+            raise ValueError(
+                "%s needs what plain grouped-query attention's step program "
+                "(one query lane a stream) does not have yet: %s"
+                % (("prefix_cache", "an extend step for a prompt that "
+                    "starts from shared blocks; besides, a shared prefix's "
+                    "window blocks are freed as its first reader's window "
+                    "slides, so a second stream cannot start from them")
+                   if self.prefix_cache
+                   else ("spec_k > 0", "a verify pass against two pools "
+                         "(several query lanes a stream over the window "
+                         "pool, and the freed blocks back when a speculated "
+                         "window is rejected)")))
         if self.stateful and self.prefix_cache:
             raise ValueError(
                 "prefix_cache needs what a model with state or window "
@@ -327,6 +342,12 @@ class ServingEngine:
         self._moe_load = np.zeros((cfg.expert_layers, cfg.experts_here[1]),
                                   np.int64)
         self._moe_routed = 0    # every choice the router made, here or not
+        # plain grouped-query attention: the keys one layer's walk of each
+        # pool read, summed over decode steps and live lanes
+        self._hybrid = {"full_ctx_tokens": 0, "window_ctx_tokens": 0,
+                        "lane_steps": 0}
+        self._full_readers = len(cfg.layers_of("full", "cross", "mla")) \
+            if cfg.hybrid else 0
         # latent attention: what the decode kernel read
         self._latent = {"ctx_tokens": 0, "lane_steps": 0, "live_blocks": 0,
                         "prefill_tokens": 0}
@@ -1299,14 +1320,20 @@ class ServingEngine:
         that books a stream's share of the last two."""
         from .kv_cache import StateSlots, StreamState
 
-        heads = rows = cfg.kv_rows()    # num_heads x head_dim = G x W
+        def rows_of(kind):
+            """``(G, W)`` (num_heads x head_dim = G x W) and, where V's rows
+            are not K's (the latent format; plain grouped-query attention,
+            whose two pools' rows differ besides), ``v_rows=``."""
+            other = cfg.latent or cfg.gqa
+            return cfg.kv_rows(kind), (
+                {"v_rows": cfg.v_rows(kind)} if other else {})
+
         n_full = len(cfg.layers_of("full", "mla"))
-        # the latent format: a second row shape for v_pages
-        latent = {"v_rows": cfg.v_rows()} if cfg.latent else {}
         n_win = len(cfg.layers_of("swa"))
         n_ssm = len(cfg.layers_of("mamba"))
+        rows, latent = rows_of("full")
         pool = KVBlockPool(max(n_full, 1), cfg.num_blocks, cfg.block_size,
-                           *heads, dtype=cfg.kv_dtype, device=device,
+                           *rows, dtype=cfg.kv_dtype, device=device,
                            prefix_cache=False, rows=rows, **latent)
         # max_batch streams of window + one block of tokens and the slots
         # a decode chunk writes beyond its first, max_batch slots, and the
@@ -1315,9 +1342,10 @@ class ServingEngine:
         # that the step programs have one signature
         per_stream = -(-(cfg.window + cfg.block_size + self._chunk - 1)
                        // cfg.block_size)
+        rows, latent = rows_of("swa")
         window_pool = KVBlockPool(
             max(n_win, 1), cfg.max_batch * per_stream + 1 if n_win else 2,
-            cfg.block_size, *heads, dtype=cfg.kv_dtype, device=device,
+            cfg.block_size, *rows, dtype=cfg.kv_dtype, device=device,
             prefix_cache=False, rows=rows, gauges=False, **latent)
         state = StateSlots(
             max(n_ssm, 1), cfg.max_batch + 1 if n_ssm else 2,
@@ -1512,6 +1540,8 @@ class ServingEngine:
                 telemetry.counter("serving.latent.prefill_tokens").inc(L)
             telemetry.histogram("serving.prefill_seconds").observe(wall)
             telemetry.counter("serving.prefill_tokens").inc(L)
+            if self._rec is not None:
+                self._rec["prefill_tokens"] += L
             if self.streams is not None and self.streams.slots is not None:
                 telemetry.counter("serving.ssm.prefill_tokens").inc(L)
             # register this prefix's full blocks for later admissions
@@ -1905,15 +1935,28 @@ class ServingEngine:
         """Book one decode pass of a model with ``layer_kinds`` from the
         live streams' context lengths: a state update a stream and "mamba"
         layer, and the blocks a window layer's walk and a full-pool
-        reader's walk take. Returns the spans' two block counts."""
+        reader's walk take; for plain grouped-query attention also the
+        keys those walks read (``stats()["hybrid"]``). Returns what the
+        spans and the step's record take."""
         cfg = self.config
         if self.streams.slots is not None:
             telemetry.counter("serving.ssm.stream_steps").inc(len(ctx))
         first = np.maximum(ctx - cfg.window, 0) // cfg.block_size
         window_live = int((-(-ctx // cfg.block_size) - first).sum()) \
             if self.streams.pool is not None else 0
-        return {"window_live_blocks": window_live,
-                "full_live_blocks": full_live}
+        noted = {"window_live_blocks": window_live,
+                 "full_live_blocks": full_live}
+        if cfg.gqa:     # the keys ONE layer's walk of each pool read
+            keys = {"full_ctx_tokens": int(ctx.sum()) if self._full_readers
+                    else 0,
+                    "window_ctx_tokens":
+                        int(np.minimum(ctx, cfg.window).sum())
+                        if self.streams.pool is not None else 0}
+            noted.update(keys)
+            for name, n in dict(keys, lane_steps=len(ctx)).items():
+                self._hybrid[name] += n
+                telemetry.counter("serving.hybrid." + name).inc(n)
+        return noted
 
     def _note_latent(self, ctx, live_blocks):
         """Book one decode step of the latent kernel, a call a "mla"
@@ -2030,7 +2073,7 @@ class ServingEngine:
             "window_blocks_a_stream": st.max_blocks_held,
             "window_blocks_freed": st.blocks_freed,
             # model layers that read the full-length pool's K/V
-            "full_pool_readers": len(cfg.layers_of("full", "cross", "mla")),
+            "full_pool_readers": self._full_readers,
             "full_pool_layers": self.pool.num_layers,
         }
 
@@ -2159,6 +2202,13 @@ class ServingEngine:
                 # only for a model with window or state layers
                 **({"state": self._state_stats()}
                    if self.streams is not None else {}),
+                # only for plain grouped-query attention's two kinds
+                **({"hybrid": dict(
+                    self._hybrid,
+                    full_blocks_used=self.pool.used(),
+                    window_blocks_used=self.streams.pool.used()
+                    if self.streams.pool is not None else 0)}
+                   if self.config.gqa else {}),
                 # only for a model with "mla" layers
                 **({"latent": dict(self._latent)}
                    if self.config.latent else {}),

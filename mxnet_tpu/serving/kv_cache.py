@@ -88,7 +88,9 @@ class KVBlockPool:
         format (``ModelConfig.v_rows``): ``k_pages`` hold a token's
         latent, one ``kv_rank``-wide row, ``v_pages`` its rotated key in
         one 128-lane row; such a block is head-major (one row a token is
-        the ``(bs, W)`` slab either way)."""
+        the ``(bs, W)`` slab either way). Plain grouped-query attention's
+        pools (``attn_form`` "gqa") use it too: a K head's row of 256
+        lanes beside a V head's of 128, head-major."""
         if num_blocks < 2:
             raise ValueError("KVBlockPool needs >= 2 blocks (block 0 is the "
                              "reserved trash block)")

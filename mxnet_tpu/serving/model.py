@@ -33,8 +33,8 @@ as ``_contrib_CachedMultiHeadAttention``.
 """
 import numpy as np
 
-from ..ops.attention import (flash_attention, latent_paged,
-                             paged_attention_multi)
+from ..ops.attention import (flash_attention, flash_attention_gqa,
+                             latent_paged, paged_attention_multi)
 from ..ops.moe import moe_ffn
 from ..ops.registry import fp32_precision
 from ..ops.ssm import ssm_scan, ssm_step
@@ -145,6 +145,25 @@ class ModelConfig:
                     gate's two parameters are loaded and nothing reads them);
                     anything less is refused
 
+    The "swa" and "full" kinds say WHERE a layer's K/V lives and how far a
+    query reads; the head's form is a second property:
+
+    attn_form       "diff" (the differential attention above) or "gqa": plain
+                    softmax attention, query head ``j`` reading K/V head
+                    ``j // (num_heads / kv heads)``. A "gqa" model's kinds
+                    are "swa" and "full" alone, and it may set
+    swa_kv_heads    K/V heads of a "swa" layer (default ``num_kv_heads``,
+                    which stays a "full" layer's): the two pools' rows differ
+    pos="rope"      rotate-half rotary position on the FIRST ``rope_dim``
+                    lanes of every q and k head (0: the whole head), the
+                    others pass through; base ``rope_theta`` in a "full" layer,
+    swa_rope_theta  in a "swa" layer (default ``rope_theta``)
+    v_dim           a value's width, which need not be the key's ``head_dim``
+    swa_sink        a learned scalar a query head in the "swa" layers
+                    (``layer%d_attn_sink``) that joins the softmax's
+                    denominator and no numerator
+    value_scale     the heads' results are multiplied by it
+
     ``max_len`` bounds every stream's total length (the position table's
     rows, or the positions the rotary model was trained for)."""
 
@@ -157,7 +176,8 @@ class ModelConfig:
                  "rope_dim", "v_dim", "rope_yarn", "norm_eps", "first_dense",
                  "dense_ffn_dim", "shared_experts", "router", "n_group",
                  "topk_group", "route_scale", "experts_held", "loop_steps",
-                 "post_norm", "early_exit_threshold")
+                 "post_norm", "early_exit_threshold", "attn_form",
+                 "swa_kv_heads", "swa_rope_theta", "swa_sink", "value_scale")
     #: the fields of one-block models: their ``key()`` is these alone, so
     #: that the programs' cache keys are what they were before ``layer_kinds``
     _BLOCK_FIELDS = 14
@@ -167,6 +187,10 @@ class ModelConfig:
     #: has them in its key
     _BLOCK_EXTRAS = (("ffn_gated", False), ("norm_eps", 1e-5),
                      ("loop_steps", 1), ("post_norm", False))
+    #: a model with kinds whose ``attn_form`` is not "diff" has these in its
+    #: key behind the thirty-eight
+    _FORM_FIELDS = ("attn_form", "swa_kv_heads", "swa_rope_theta",
+                    "swa_sink", "value_scale")
     #: what ``layer_kinds`` may name
     KINDS = ("mamba", "swa", "full", "cross", "gmu", "mla")
 
@@ -181,7 +205,9 @@ class ModelConfig:
                  norm_eps=1e-5, first_dense=0, dense_ffn_dim=None,
                  shared_experts=0, router="softmax", n_group=1, topk_group=1,
                  route_scale=1.0, experts_held=None, loop_steps=1,
-                 post_norm=False, early_exit_threshold=1.0):
+                 post_norm=False, early_exit_threshold=1.0, attn_form="diff",
+                 swa_kv_heads=None, swa_rope_theta=None, swa_sink=False,
+                 value_scale=1.0):
         self.vocab_size = int(vocab_size)
         self.num_layers = int(num_layers)
         self.model_dim = int(model_dim)
@@ -230,6 +256,13 @@ class ModelConfig:
         self.loop_steps = int(loop_steps)
         self.post_norm = bool(post_norm)
         self.early_exit_threshold = float(early_exit_threshold)
+        self.attn_form = str(attn_form)
+        self.swa_kv_heads = int(swa_kv_heads if swa_kv_heads is not None
+                                else self.num_kv_heads)
+        self.swa_rope_theta = float(swa_rope_theta if swa_rope_theta
+                                    is not None else self.rope_theta)
+        self.swa_sink = bool(swa_sink)
+        self.value_scale = float(value_scale)
         if self.norm not in ("layer", "rms"):
             raise ValueError("norm must be 'layer' or 'rms', not %r" % norm)
         if self.pos not in ("learned", "rope", "none"):
@@ -237,6 +270,9 @@ class ModelConfig:
                              % pos)
         if self.pos == "rope" and self.head_dim % 2:
             raise ValueError("rotary position needs an even head_dim")
+        if self.attn_form not in ("diff", "gqa"):
+            raise ValueError("attn_form must be 'diff' or 'gqa', not %r"
+                             % attn_form)
         if self.num_experts and not (
                 1 <= self.experts_per_tok <= self.num_experts):
             raise ValueError("experts_per_tok must be in 1..num_experts")
@@ -294,11 +330,12 @@ class ModelConfig:
                     or self.rope_yarn)
         if self.layer_kinds is None:
             if (self.num_kv_heads != self.num_heads or self.pos == "none"
-                    or self.tie_embed or self.attn_bias or by_layer):
+                    or self.tie_embed or self.attn_bias or by_layer
+                    or self.gqa):
                 raise ValueError(
                     "num_kv_heads, pos='none', tie_embed, attn_bias, "
-                    "rope_yarn and an FFN that differs by layer belong to a "
-                    "model with layer_kinds")
+                    "rope_yarn, attn_form and an FFN that differs by layer "
+                    "belong to a model with layer_kinds")
             return
         if len(kinds) != self.num_layers or set(kinds) - set(self.KINDS):
             raise ValueError("layer_kinds must name each of the %d layers "
@@ -314,10 +351,13 @@ class ModelConfig:
                     or self.q_rank < 1 or self.kv_rank < 1:
                 raise ValueError("'mla' layers need pos='rope', an even "
                                  "rope_dim, q_rank and kv_rank")
+        elif self.gqa:
+            self._check_gqa(kinds)
         elif self.pos == "rope" or self.rope_yarn:
             raise ValueError("of the layer kinds only 'mla' has rotary "
-                             "position")
-        if set(kinds) & {"swa", "full", "cross"}:
+                             "position (and 'swa' / 'full' where attn_form "
+                             "is 'gqa')")
+        if set(kinds) & {"swa", "full", "cross"} and not self.gqa:
             if self.num_heads % 2 or self.num_kv_heads % 2 or \
                     (self.num_heads // 2) % (self.num_kv_heads // 2):
                 raise ValueError(
@@ -334,6 +374,23 @@ class ModelConfig:
                 raise ValueError("layer %d is 'gmu' with no 'mamba' layer "
                                  "before it" % i)
 
+    def _check_gqa(self, kinds):
+        if set(kinds) - {"swa", "full"}:
+            raise ValueError("attn_form 'gqa' takes 'swa' and 'full' layers "
+                             "alone, not %r" % (kinds,))
+        for kind in set(kinds):
+            if self.num_heads % self.kv_heads_of(kind):
+                raise ValueError(
+                    "%d query heads do not share %d K/V heads of a %r layer"
+                    % (self.num_heads, self.kv_heads_of(kind), kind))
+        if self.attn_bias or self.rope_yarn:
+            raise ValueError("attn_form 'gqa' takes no attn_bias and no "
+                             "rope_yarn")
+        if self.pos == "learned" or self.rope_dim % 2 \
+                or not 0 <= self.rope_dim <= self.head_dim:
+            raise ValueError("attn_form 'gqa' takes pos 'rope' or 'none' and "
+                             "an even rope_dim of at most head_dim")
+
     # ---- what the layers' kinds imply (static, python) ------------------
     def kinds(self):
         return self.layer_kinds or ("attn",) * self.num_layers
@@ -347,6 +404,19 @@ class ModelConfig:
         """A model of several layer kinds: its step programs take the
         window pool's pages and the state slots beside the full pool."""
         return self.layer_kinds is not None
+
+    @property
+    def gqa(self):
+        """The "swa" and "full" layers are plain grouped-query softmax
+        attention (``attn_form``)."""
+        return self.attn_form == "gqa"
+
+    def kv_heads_of(self, kind):
+        """K/V heads of a layer of ``kind``."""
+        return self.swa_kv_heads if kind == "swa" else self.num_kv_heads
+
+    def rope_theta_of(self, kind):
+        return self.swa_rope_theta if kind == "swa" else self.rope_theta
 
     @property
     def stateful(self):
@@ -389,39 +459,52 @@ class ModelConfig:
         return max(i for i in self.layers_of("mamba") if i < gmu[0]) \
             if gmu else None
 
-    def kv_rows(self):
+    def kv_rows(self, kind="full"):
         """``(G, W)``: the page rows of one token's K (or V). Differential
         attention: a K/V pair is one row (``[v1, v2]`` is the row, ``k1``
-        and ``k2`` its halves)."""
+        and ``k2`` its halves). ``attn_form`` "gqa": a K/V head of ``kind``
+        is a row, its ``head_dim`` lanes padded to whole 128-lane tiles
+        (192 -> 256: a head-major slab ``(bs, W)`` is then whole tiles for
+        the copy and the MXU, which pads 192 to 256 itself; a 192-lane
+        minor dimension is padded to 256 in HBM's tiled layout anyway)."""
         from .kv_cache import KVBlockPool
 
+        if self.gqa:
+            return self.kv_heads_of(kind), -(-self.head_dim // 128) * 128
         if self.latent:         # k_pages hold the latent, one row a token
             return 1, self.kv_rank
         if set(self.kinds()) & {"swa", "full", "cross"}:
             return self.num_kv_heads // 2, 2 * self.head_dim
         return KVBlockPool.page_shape(self.num_kv_heads, self.head_dim)
 
-    def v_rows(self):
+    def v_rows(self, kind="full"):
         """The rows of ``v_pages`` where they are not ``kv_rows()``: an
         "mla" model keeps a token's rotated key there, its ``rope_dim``
         lanes padded to whole 128-lane tiles (a 64-lane row is half a
         tile: the pool's layout would not be row-major, PR 25; two tokens
         a row would save a tenth of the cache's bytes and make every
-        decode write half a row)."""
+        decode write half a row); ``attn_form`` "gqa" keeps a value in its
+        own ``v_dim`` lanes (padded to whole tiles), narrower than the
+        key's row."""
         if self.latent:
             return 1, -(-self.rope_dim // 128) * 128
+        if self.gqa:
+            return self.kv_heads_of(kind), -(-self.v_dim // 128) * 128
         return self.kv_rows()
 
     def key(self):
         """What the programs are a function of. A one-block model's key is
         its first fourteen fields, as before there were others, and what
         it sets of ``_BLOCK_EXTRAS`` behind them if it sets any; a model
-        with kinds' is the thirty-eight it always was."""
+        with kinds' is the thirty-eight it always was, with ``_FORM_FIELDS``
+        behind them where ``attn_form`` is not "diff"."""
         names = ModelConfig.__slots__[:self._KIND_FIELDS]
         if not self.hybrid:
             names = names[:self._BLOCK_FIELDS]
             if any(getattr(self, k) != v for k, v in self._BLOCK_EXTRAS):
                 names += tuple(k for k, _v in self._BLOCK_EXTRAS)
+        elif self.gqa:
+            names += self._FORM_FIELDS
         return tuple(getattr(self, k) for k in names)
 
     def _slot_names(self):
@@ -530,6 +613,13 @@ def _mixer_shapes(cfg, kind, p):
                 # a head's rows: its key part without position, its value
                 p + "_mla_kv_up_weight": (h * (hd + dv), cfg.kv_rank),
                 p + "_attn_out_weight": (m, h * dv)}
+    if cfg.gqa:
+        hk, dv = cfg.kv_heads_of(kind), cfg.v_dim
+        shapes = {p + "_attn_in_weight": (hq + hk * (hd + dv), m),
+                  p + "_attn_out_weight": (m, cfg.num_heads * dv)}
+        if kind == "swa" and cfg.swa_sink:
+            shapes[p + "_attn_sink"] = (cfg.num_heads,)
+        return shapes
     shapes = {p + "_attn_out_weight": (m, hq),
               p + "_diff_norm_gamma": (2 * hd,)}
     shapes.update({p + "_diff_lambda_" + v: (hd,)
@@ -571,6 +661,10 @@ def random_params(cfg, seed=0, dtype=np.float32):
             out[name] = (rng.randn(*shape) * 0.01).astype(dtype)
         elif name.endswith(("_beta", "_bias")):
             out[name] = np.zeros(shape, dtype)
+        elif name.endswith("_attn_sink"):
+            # a learned sink is large: zero would be one part in window + 1
+            # of the softmax, and its absence unseen
+            out[name] = (2.0 + rng.randn(*shape)).astype(dtype)
         elif "_diff_lambda_" in name:
             out[name] = (rng.randn(*shape) * 0.1).astype(dtype)
         else:
@@ -839,6 +933,54 @@ def _mix_mla(h, params, p, i, cfg, prec, positions, attend, state):
     return proj(att, "_attn_out_weight"), state
 
 
+def _rope_part(t, positions, theta, dr):
+    """Rotate-half rotary position on the first ``dr`` lanes of every head
+    of ``t`` (A, B, H, hd) at ``positions`` (A, B); the rest pass through."""
+    import jax.numpy as jnp
+
+    dr = dr or t.shape[-1]
+    inv_freq = theta ** (-jnp.arange(dr // 2, dtype=jnp.float32) * 2.0 / dr)
+    turned = _rotate(t[..., :dr], positions, inv_freq)
+    return turned if dr == t.shape[-1] \
+        else jnp.concatenate([turned, t[..., dr:]], -1)
+
+
+def _mix_gqa(h, params, p, i, kind, cfg, prec, positions, attend, state):
+    """The "swa" / "full" kinds of ``attn_form`` "gqa": plain softmax
+    attention, ``num_heads`` query heads over the kind's K/V heads, values
+    ``v_dim`` wide. ``attend(i, q, k, v, state)`` gets ``(A, B, H, hd)``,
+    ``(A, B, Hkv, hd)`` and ``(A, B, Hkv, dv)`` (q and k turned on their
+    rotary lanes, K cached turned) and returns the heads' results
+    ``(A, B, H, dv)``; the sink is ``attend``'s to apply."""
+    import jax.numpy as jnp
+
+    a, b, _ = h.shape
+    hh, hd, dv, hk = (cfg.num_heads, cfg.head_dim, cfg.v_dim,
+                      cfg.kv_heads_of(kind))
+    qkv = jnp.einsum("bsm,nm->bsn", h, params[p + "_attn_in_weight"],
+                     precision=prec)
+    q, k, v = jnp.split(qkv, [hh * hd, (hh + hk) * hd], axis=-1)
+    q, k = q.reshape(a, b, hh, hd), k.reshape(a, b, hk, hd)
+    if cfg.pos == "rope":
+        theta = cfg.rope_theta_of(kind)
+        q = _rope_part(q, positions, theta, cfg.rope_dim)
+        k = _rope_part(k, positions, theta, cfg.rope_dim)
+    att, state = attend(i, q, k, v.reshape(a, b, hk, dv), state)
+    if cfg.value_scale != 1.0:
+        att = att * jnp.asarray(cfg.value_scale, att.dtype)
+    return jnp.einsum("bsm,nm->bsn", att.reshape(a, b, hh * dv),
+                      params[p + "_attn_out_weight"], precision=prec), state
+
+
+def _sink(params, i, cfg):
+    """Layer i's sink a query head, float32 ``(H,)``; None without one."""
+    import jax.numpy as jnp
+
+    if not (cfg.swa_sink and cfg.kinds()[i] == "swa"):
+        return None
+    return params["layer%d_attn_sink" % i].astype(jnp.float32)
+
+
 def _pad_lanes(t, lanes):
     import jax.numpy as jnp
 
@@ -978,8 +1120,8 @@ def _layer(x, params, i, cfg, prec, positions, valid, attend, state,
     :func:`extend`: norm -> the layer's mixer -> norm -> FFN or routed
     experts. The mixer is the layer's KIND's (``ModelConfig``): q/k/v
     projections (QK-norm, position) -> ``attend`` -> output projection
-    for "attn", and :func:`_mix_diff`, :func:`_mix_mamba`,
-    :func:`_mix_gmu` for the others.
+    for "attn", and :func:`_mix_diff` (or :func:`_mix_gqa`),
+    :func:`_mix_mamba`, :func:`_mix_gmu` for the others.
 
     x:         (A, B, M) — (1, S, M) in prefill, (B, 1, M) in decode,
                (B, T, M) in the verify pass
@@ -1006,6 +1148,13 @@ def _layer(x, params, i, cfg, prec, positions, valid, attend, state,
     elif kind == "mla":
         mix, state = _mix_mla(h, params, p, i, cfg, prec, positions, attend,
                               state)
+    elif cfg.gqa:
+        import jax
+
+        # the two kinds' ops under two names on a trace
+        with jax.named_scope("gqa_" + kind):
+            mix, state = _mix_gqa(h, params, p, i, kind, cfg, prec,
+                                  positions, attend, state)
     else:
         mix, state = _mix_diff(h, params, p, i, kind, cfg, prec, attend,
                                state)
@@ -1170,10 +1319,12 @@ def prefill(params, tokens, length, block_table, k_pages, v_pages, cfg,
 
 def _head_major(cfg):
     """A latent's one row a token is head-major at any width: the block is
-    the ``(bs, W)`` slab either way."""
+    the ``(bs, W)`` slab either way. So are ``attn_form`` "gqa"'s pools,
+    whose K and V rows differ."""
     from .kv_cache import KVBlockPool
 
-    return cfg.latent or KVBlockPool.head_major(*cfg.kv_rows())
+    return cfg.latent or cfg.gqa \
+        or KVBlockPool.head_major(*cfg.kv_rows())
 
 
 def _cache_layers(cfg):
@@ -1254,10 +1405,31 @@ def _prefill_hybrid(params, tokens, length, block_table, k_pages, v_pages,
                               True, mla_sm_scale(cfg))  # (1, H, S, dv)
         return att[0].transpose(1, 0, 2).reshape(1, S, hh * dv), st
 
+    def attend_gqa(i, q, k, v, st):
+        """Cache K (its lanes padded to the page's) and V, each in its own
+        rows, "swa" layers into the window pool; attend over the prompt
+        with the K/V heads named by the kernel's index map, not repeated."""
+        kind = cfg.kinds()[i]
+        if kind == "full":
+            kp, vp, table, li = "k", "v", block_table, full_at[i]
+        else:
+            kp, vp, table, li = "wk", "wv", wtable, win_at[i]
+        krows, vrows = cfg.kv_rows(kind), cfg.v_rows(kind)
+        st = dict(st, **{
+            kp: put(st[kp], li, table, _pad_lanes(k[0], krows[1]), krows),
+            vp: put(st[vp], li, table, _pad_lanes(v[0], vrows[1]), vrows)})
+        att = flash_attention_gqa(
+            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3), sm_scale,
+            cfg.window if kind == "swa" else None, _sink(params, i, cfg))
+        return att.transpose(0, 2, 1, 3), st            # (1, S, H, dv)
+
     def attend(i, q, k, v, st):
         kind = cfg.kinds()[i]
         if kind == "mla":
             return attend_mla(i, q, k, v, st)
+        if cfg.gqa:
+            return attend_gqa(i, q, k, v, st)
         if kind == "cross":
             k, v = st["kv"]
         else:
@@ -1441,10 +1613,44 @@ def _paged_step_hybrid(params, tokens, positions, block_tables,
         out = jnp.einsum("bhc,hec->bhe", out, w, precision=prec)[..., dn:]
         return out.reshape(B, 1, cfg.num_heads * dv), st
 
+    def attend_gqa(i, q, k, v, st):
+        """Write this token's K and V rows, then the ONE paged kernel: the
+        R = H / Hkv query heads of a K/V row ride as its R query lanes
+        (one context length a stream), V pages narrower than K pages, the
+        sink as the online softmax's start state; a "swa" layer's walk and
+        a "full" layer's are two ops on a trace."""
+        kind = cfg.kinds()[i]
+        if kind == "swa":
+            kp, vp, tables, li, window = ("wk", "wv", wtables, win_at[i],
+                                          cfg.window)
+        else:
+            kp, vp, tables, li, window = ("k", "v", block_tables,
+                                          full_at[i], None)
+        (hk, wk), vrows = cfg.kv_rows(kind), cfg.v_rows(kind)
+        r = cfg.num_heads // hk
+        ids = page_ids(tables)
+        st = dict(st, **{
+            kp: write(st[kp], li, ids, _pad_lanes(k, wk), (hk, wk)),
+            vp: write(st[vp], li, ids, _pad_lanes(v, vrows[1]), vrows)})
+        # (B, R, Hkv, W): query head g R + r is lane r of K/V row g
+        qr = _pad_lanes(q[:, 0], wk).reshape(B, hk, r, wk).transpose(
+            0, 2, 1, 3)
+        sink = _sink(params, i, cfg)
+        att = paged_attention_multi(
+            qr, st[kp], st[vp], tables, context_lens, sm_scale=sm_scale,
+            layer=li, window=window, head_major=True,
+            sink=None if sink is None else sink.reshape(hk, r).T,
+            name="paged_window_walk" if kind == "swa" else "paged_full_walk")
+        att = att.transpose(0, 2, 1, 3).reshape(B, 1, cfg.num_heads,
+                                                vrows[1])
+        return att[..., :cfg.v_dim], st
+
     def attend(i, q, k, v, st):
         kind = cfg.kinds()[i]
         if kind == "mla":
             return attend_mla(i, q, k, v, st)
+        if cfg.gqa:
+            return attend_gqa(i, q, k, v, st)
         if kind == "swa":
             kp, vp, tables, li, window = ("wk", "wv", wtables, win_at[i],
                                           cfg.window)

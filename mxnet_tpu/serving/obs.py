@@ -83,7 +83,7 @@ _BURN_MIN_SAMPLES = 8
 
 #: Steps an engine's ring of loop records keeps: the longest window a
 #: benchmark run may have (51 s) at the ~30 steps a second of its busiest
-#: cell, with set-up's steps before it. A record is ~0.8 KB (28 fields,
+#: cell, with set-up's steps before it. A record is ~0.8 KB (31 fields,
 #: most of them floats of their own): a full ring is ~7 MB.
 LOOP_RING = 8192
 
@@ -105,7 +105,8 @@ class LoopRecord(namedtuple("LoopRecord", (
         "lane_steps", "live_blocks", "window_live_blocks",
         "full_live_blocks",
         "gap_chunk_s", "gap_group_s", "finished",
-        "deferred_s", "deferred_hidden", "passes"))):
+        "deferred_s", "deferred_hidden", "passes",
+        "full_ctx_tokens", "window_ctx_tokens", "prefill_tokens"))):
     """What the engine loop did in ONE non-empty step, on the host's clock
     (docs/observability.md has the table of fields, where each is measured
     and the benchmark metric that reads it).
@@ -121,11 +122,17 @@ class LoopRecord(namedtuple("LoopRecord", (
     ``retire_counters_s`` is what bookkeeping is LEFT there: the lanes'
     context lengths taken before the tokens go out, and the readings the
     step's record and event are later made from; the three need not add
-    up to it. Counts: ``prefills`` (the group's prompts), ``chunk_steps``
+    up to it. Counts: ``prefills`` (the group's prompts) and
+    ``prefill_tokens`` (the tokens its prefill programs took, a replay
+    after a preemption included: every model's), ``chunk_steps``
     (the decode dispatch's trip count, 0 for a step without one),
     ``lanes``, and what the chunk's steps walked (``lane_steps``,
     ``live_blocks``; a model with window layers also its window and
-    full-pool walks). A stack that runs several times (``loop_steps`` > 1)
+    full-pool walks, and the KEYS a walk read: ``full_ctx_tokens``, a live
+    lane's context a step, and ``window_ctx_tokens``, at most ``window`` of
+    it — one layer's; 0 for any model but plain grouped-query
+    attention's). A stack that
+    runs several times (``loop_steps`` > 1)
     books a walk a PASS in ``live_blocks`` and counts in ``passes`` the
     stack passes the step's programs ran, ``loop_steps`` a prefill and an
     inner decode step (0 for every other model).
@@ -476,7 +483,9 @@ class ServingObs:
         step read it."""
         if record is None:
             return
-        self._ring.append(LoopRecord(**record))
+        # the dict is :func:`open_record`'s: its keys are the fields, in
+        # their order (a key the step added besides is a TypeError here)
+        self._ring.append(LoopRecord._make(record.values()))
         sums, n = self._loop_sums, self._loop_n
         for f in _SUMMED:
             v = record[f]

@@ -472,6 +472,100 @@ def test_latent_programs_leave_the_pool_in_place(v5e, name):
                                     else 64 << 20)
 
 
+_MIMO_KINDS = ("full", "swa", "swa", "swa", "swa", "swa", "full")
+
+
+def _gqa_program(chip, name, kinds=_MIMO_KINDS, batch=64, prompt=2048,
+                 blocks=5121, chunk=8):
+    """``model.decode_chunk`` (B=``batch``) or ``model.prefill``
+    (S=``prompt``) of MiMo-V2.5's block at published widths — 64 query
+    heads of 192 (64 rotary lanes) over 4 K/V heads in a full layer and 8 in
+    a window layer, values of 128, a window of 128 keys with a sink, 16 of
+    256 experts held behind a leading dense layer — the benchmark's seven
+    layers (``kinds``) and its plan (327,680 tokens of full pool, four
+    window blocks a stream), the two pools of UNEQUAL rows donated (K rows
+    256 lanes, V rows 128), compiled for the chip. Returns (compiled, the four page arrays'
+    shapes)."""
+    from mxnet_tpu.serving import model as M
+
+    def s(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(tuple(shape), dt, sharding=chip)
+
+    bf, bs, layers = jnp.bfloat16, 64, len(kinds)
+    cfg = M.ModelConfig(
+        19072, layers, 4096, 64, 2048, 8960, norm="rms", pos="rope",
+        rope_theta=1e7, bias=False, head_dim=192, layer_kinds=list(kinds),
+        attn_form="gqa", num_kv_heads=4, swa_kv_heads=8, swa_rope_theta=1e4,
+        rope_dim=64, v_dim=128, window=128, swa_sink=True, value_scale=0.707,
+        ffn_gated=True, norm_eps=1e-5, first_dense=1, dense_ffn_dim=16384,
+        num_experts=256, experts_per_tok=8, router="sigmoid_group",
+        experts_held=(0, 16))
+    assert (cfg.kv_rows("full"), cfg.v_rows("full"),
+            cfg.kv_rows("swa"), cfg.v_rows("swa")) == (
+        (4, 256), (4, 128), (8, 256), (8, 128))
+    n_full, n_win = kinds.count("full"), kinds.count("swa")
+    wblocks = batch * 4 + 1
+    pages = {"k": s((n_full, blocks, 4, bs, 256), bf),
+             "v": s((n_full, blocks, 4, bs, 128), bf),
+             "wk": s((n_win, wblocks, 8, bs, 256), bf),
+             "wv": s((n_win, wblocks, 8, bs, 128), bf)}
+    stand_ins = (s((1, 2, 3 * cfg.d_inner), bf),
+                 s((1, 2, 16, cfg.d_inner), jnp.float32))
+    params = {k: s(v, bf) for k, v in M.param_shapes(cfg).items()}
+    aux = ("wk", "wv", "conv", "ssm")
+    nb = cfg.max_len // bs
+    if name == "chunk":
+        def fn(params, toks, poss, tables, ctx, left, eos, n, kp, vp, wt,
+               slots, *arrays):
+            return M.decode_chunk(
+                params, toks, poss, tables, ctx, left, eos, n, kp, vp, cfg,
+                chunk, dict(zip(aux, arrays), wtables=wt, slots=slots))
+        args = (s((batch,)), s((batch,)), s((batch, nb)), s((batch,)),
+                s((batch,)), s((batch,)), s(()))
+        more, donate = (s((batch, nb)), s((batch,))), (8, 9, 12, 13)
+    else:
+        def fn(params, toks, n, table, kp, vp, wt, slot, *arrays):
+            return M.prefill(params, toks, n, table, kp, vp, cfg,
+                             dict(zip(aux, arrays), wtable=wt, slot=slot))
+        args = (s((1, prompt)), s(()), s((prompt // bs,)))
+        more, donate = (s((prompt // bs,)), s(())), (4, 5, 8, 9)
+    compiled = jax.jit(fn, donate_argnums=donate).lower(
+        params, *args, pages["k"], pages["v"], *more, pages["wk"],
+        pages["wv"], *stand_ins).compile()
+    return compiled, {k: v.shape for k, v in pages.items()}
+
+
+@pytest.mark.parametrize("name", ["chunk", "prefill"])
+def test_gqa_programs_leave_both_pools_in_place(v5e, name):
+    """Two pools of unequal rows — four K/V heads a token in the full pool,
+    eight in the window pool, and in both a K row of 256 lanes (192 padded
+    to whole tiles) beside a V row of 128 — are head-major blocks of whole
+    tiles: no program copies or slices a page array or a layer of one, all
+    four are donated and aliased. The decode program holds the ONE paged
+    kernel under two names (a full walk, a window walk: 16 and 8 query
+    lanes a K/V row, the sink as the start state), the prefill the flash
+    forward with the K/V head named by the index map (K is not repeated in
+    HBM: the temporaries stay a 2,048-token prompt's)."""
+    compiled, shapes = _gqa_program(v5e, name)
+    text = compiled.as_text()
+    assert len(re.findall(r"%gmm[.\d]* = ", text)) == 18   # six layers'
+    if name == "chunk":
+        assert len(re.findall(r"%paged_full_walk[.\d]* = ", text)) == 2
+        assert len(re.findall(r"%paged_window_walk[.\d]* = ", text)) == 5
+        assert " while(" in text
+    else:       # a flash forward a layer, by its name on a trace
+        assert len(re.findall(r"%flash_gqa_fwd[.\d]* = ", text)) == 7
+        assert text.count("tpu_custom_call") >= 7 + 18
+    for key in shapes:
+        assert _pool_copies(text, shapes[key]) == [], key
+        assert _entry_layouts(text, shapes[key]) == {"4,3,2,1,0"}
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= 2 * sum(
+        math.prod(sh) for sh in shapes.values())
+    assert ma.temp_size_in_bytes < (1536 << 20 if name == "prefill"
+                                    else 96 << 20)
+
+
 @pytest.mark.parametrize("bs", [64, 128, 256])
 def test_latent_kernel_compiles_for_v5e(v5e, bs):
     """The block-size ladder's three rungs, 128 streams of 128 heads."""

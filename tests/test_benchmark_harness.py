@@ -13,7 +13,8 @@ pytest.register_assert_rewrite("benchmark.tests.test_benchmark_harness",
                                "benchmark.tests.test_ssm_metrics",
                                "benchmark.tests.test_latent_metrics",
                                "benchmark.tests.test_loop_metrics",
-                               "benchmark.tests.test_looped_metrics")
+                               "benchmark.tests.test_looped_metrics",
+                               "benchmark.tests.test_hybrid_metrics")
 
 from benchmark.tests.test_benchmark_harness import *  # noqa: E402,F401,F403
 from benchmark.tests.test_pool_copy_share import *  # noqa: E402,F401,F403
@@ -24,16 +25,19 @@ from benchmark.tests import test_latent_metrics as _latent_tests  # noqa: E402
 from benchmark.tests import test_loop_metrics as _loop_tests  # noqa: E402
 from benchmark.tests import test_looped_metrics as _looped_tests  # noqa: E402
 from benchmark.tests.test_looped_metrics import looped_records  # noqa: E402,F401
+from benchmark.tests.test_hybrid_metrics import hybrid_records  # noqa: E402,F401
 from benchmark.tests import test_moe_metrics as _moe_tests  # noqa: E402
 from benchmark.tests import test_ssm_metrics as _ssm_tests  # noqa: E402
+from benchmark.tests import test_hybrid_metrics as _hybrid_tests  # noqa: E402
 
-# test_moe_metrics, test_ssm_metrics, test_latent_metrics and
-# test_looped_metrics each have a
+# test_moe_metrics, test_ssm_metrics, test_latent_metrics,
+# test_looped_metrics and test_hybrid_metrics each have a
 # ``test_the_cell_is_in_the_manifest_with_its_files`` and a
 # ``test_the_mix_is_what_the_issue_says...``: the later files' cases come in
 # under names of their own, so that each file's still counts.
 for _prefix, _module in (("ssm", _ssm_tests), ("latent", _latent_tests),
-                         ("looped", _looped_tests)):
+                         ("looped", _looped_tests),
+                         ("hybrid", _hybrid_tests)):
     for _name in dir(_module):
         if _name.startswith("test_"):
             globals()["test_%s_%s" % (_prefix, _name[len("test_"):])] = \
@@ -58,7 +62,7 @@ def _manifest_case_of(monkeypatch, module, cell,
     def load(f):
         doc = json.load(f)
         if f.name.endswith("BENCHMARK.json"):
-            for m in doc["per_layer"]:
+            for m in doc["per_layer"] + doc["end_to_end"]:
                 if "workloads" in m:
                     m["workloads"] = [c for c in m["workloads"]
                                       if c not in later]
@@ -78,6 +82,14 @@ def test_the_cell_is_in_the_manifest_with_its_files(monkeypatch):  # noqa: F811
 
 def test_ssm_the_cell_is_in_the_manifest_with_its_files(monkeypatch):  # noqa: F811
     _manifest_case_of(monkeypatch, _ssm_tests, "phi4flash-reason-closed128")
+
+
+def test_latent_the_cell_is_in_the_manifest_with_its_files(monkeypatch):  # noqa: F811
+    _manifest_case_of(monkeypatch, _latent_tests, "dotsvlm1-chat-closed256")
+
+
+def test_looped_the_cell_is_in_the_manifest_with_its_files(monkeypatch):  # noqa: F811
+    _manifest_case_of(monkeypatch, _looped_tests, "ouro-chat-closed32")
 
 
 def test_the_manifest_appends_the_nine_loop_metrics(monkeypatch):  # noqa: F811

@@ -559,5 +559,12 @@ def test_mxu_share_is_read_off_what_is_already_counted(name, share, telem):
     assert paged["mxu_share"] == share == float(eng.pool.is_head_major)
     assert telem.gauge("serving.paged.mxu_share").value == share
     if name == "hybrid":            # the window pool's walks are in it
-        assert eng.stats()["loop"]["sums"]["window_live_blocks"] > 0
+        sums = eng.stats()["loop"]["sums"]
+        assert sums["window_live_blocks"] > 0
         assert eng.streams.pool.is_head_major
+        # the keys a walk read are plain grouped-query attention's to
+        # book: this model's decode steps pay for none of it
+        assert "hybrid" not in eng.stats()
+        assert (sums["full_ctx_tokens"], sums["window_ctx_tokens"]) == (0, 0)
+        assert telem.counter("serving.hybrid.lane_steps").value == 0
+    assert eng.stats()["loop"]["sums"]["prefill_tokens"] == 3 * 3

@@ -426,7 +426,9 @@ def test_one_record_a_non_empty_step_with_every_field(ticks):
     before = time.time()
     rec, wall, progs, fetched = _step_logged(eng, clock, fetches, calls)
     assert len(ring) == 1 and ring[0] is rec
-    assert rec._fields == LoopRecord._fields and len(rec) == 28
+    assert rec._fields == LoopRecord._fields and len(rec) == 31
+    # the ring's tuple is made from the dict's values as they stand
+    assert tuple(open_record()) == LoopRecord._fields
     assert before <= rec.ts <= time.time() and rec.step == eng._steps == 1
     # two prompts as one group, then a chunk of their lanes
     assert [p for p, _t in progs] == ["prefill", "prefill", "decode"]
@@ -435,6 +437,8 @@ def test_one_record_a_non_empty_step_with_every_field(ticks):
     assert rec.lane_steps == 2 * rec.chunk_steps
     assert rec.live_blocks == eng.stats()["paged"]["live_blocks"] > 0
     assert (rec.window_live_blocks, rec.full_live_blocks) == (0, 0)
+    assert (rec.full_ctx_tokens, rec.window_ctx_tokens) == (0, 0)
+    assert rec.prefill_tokens == sum(len(r.prompt) for r in reqs) > 0
     for f in SECTIONS:      # every section ran and was timed: whole ticks
         assert getattr(rec, f) >= 1.0 and getattr(rec, f) % 1 == 0, f
     # what is left of the counters in the gap: two stretches, a clock

@@ -1,6 +1,7 @@
 #!/usr/bin/env python3
 """Wrong servers for a latent-attention, shared-expert configuration (and,
-through ``--cell``, for a stack that runs several times): plant ONE fault in
+through ``--cell``, for a stack that runs several times and for window and
+full layers of plain grouped-query attention over two pools): plant ONE fault in
 the served program (or its weights), run the configuration's own dense
 probe over it, print what ``correct`` would compare.
 
@@ -46,7 +47,11 @@ WEIGHTS = ("sound", "float8_all", "float8_experts", "zeroed_expert",
 PROGRAM = ("no_group_limit", "no_renorm", "no_scale", "no_mscale",
            "unrotated_key", "latent_before_norm", "latent_not_written",
            # of a stack that runs several times (ouro-chat-closed32)
-           "float8_pages", "three_passes", "shared_cache", "no_post_norm")
+           "float8_pages", "three_passes", "shared_cache", "no_post_norm",
+           # of plain grouped-query attention in window and full layers
+           # over two pools (mimov25-mixed-closed128)
+           "no_sink", "window_64", "bases_swapped", "rope_all_lanes",
+           "freed_block_read", "window_kv_not_written")
 
 
 def load_config(path):
@@ -113,6 +118,15 @@ def planted(name, model):
         undo.append((owner, attr, getattr(owner, attr)))
         setattr(owner, attr, new)
 
+    class Other:
+        """The configuration but for some fields."""
+
+        def __init__(self, cfg, **fields):
+            self.__dict__.update(fields, _cfg=cfg)
+
+        def __getattr__(self, key):
+            return getattr(self._cfg, key)
+
     sound_route = moe.route
     if name == "no_group_limit":        # plain top-k of s + b
         patch(moe, "route", lambda x, router, k, **kw: sound_route(
@@ -171,15 +185,6 @@ def planted(name, model):
     elif name in ("three_passes", "shared_cache", "no_post_norm"):
         sound = M._layers
 
-        class Other:
-            """The configuration but for one field."""
-
-            def __init__(self, cfg, **fields):
-                self.__dict__.update(fields, _cfg=cfg)
-
-            def __getattr__(self, key):
-                return getattr(self._cfg, key)
-
         def layers(x, params, cfg, prec, positions, valid, attend, state,
                    recur=None):
             if name == "three_passes":      # the last pass left out
@@ -194,6 +199,43 @@ def planted(name, model):
             return sound(x, params, cfg, prec, positions, valid, attend,
                          state, recur)
         patch(M, "_layers", layers)
+    elif name == "no_sink":         # the window layers' softmax without it
+        patch(M, "_sink", lambda params, i, cfg: None)
+    elif name == "window_64":       # the programs' window, not the pools'
+        for fn, at in (("_prefill_hybrid", 6), ("_paged_step_hybrid", 7)):
+            def short(*a, _sound=getattr(M, fn), _at=at):
+                return _sound(*a[:_at], Other(a[_at], window=64),
+                              *a[_at + 1:])
+            patch(M, fn, short)
+    elif name == "bases_swapped":   # a full layer's base in a window layer
+        sound = M.ModelConfig.rope_theta_of
+        patch(M.ModelConfig, "rope_theta_of", lambda self, kind: sound(
+            self, "full" if kind == "swa" else "swa"))
+    elif name == "rope_all_lanes":  # the whole head turned, not its 64
+        sound = M._rope_part
+        patch(M, "_rope_part", lambda t, pos, theta, dr: sound(
+            t, pos, theta, 0))
+    elif name == "freed_block_read":
+        # a window layer's walk starts one block early and its lanes read
+        # what lies there: a block the stream has given back
+        sound = M.paged_attention_multi
+
+        def early(*a, **kw):
+            if kw.get("window") is not None:
+                kw["window"] += a[1].shape[-2]          # one block of keys
+            return sound(*a, **kw)
+        patch(M, "paged_attention_multi", early)
+    elif name == "window_kv_not_written":
+        # every decode step's K/V of the window layers is lost
+        sound = ServingEngine._dispatch_decode
+
+        def dispatch(self, *a, **kw):
+            pool = self.window_pool     # the arguments are donated
+            kept = jnp.copy(pool.k_pages), jnp.copy(pool.v_pages)
+            out = sound(self, *a, **kw)
+            pool.k_pages, pool.v_pages = kept
+            return out
+        patch(ServingEngine, "_dispatch_decode", dispatch)
     elif name not in WEIGHTS:
         raise ValueError("no fault %r (weights: %s; program: %s)"
                          % (name, WEIGHTS, PROGRAM))
