@@ -67,6 +67,13 @@ _AUX = ("wk", "wv", "conv", "ssm")
 #: DECODE_CHUNK - 1 steps and an arrival waits at most that many more
 DECODE_CHUNK = 8
 
+#: rows from which the prefill ladder takes a half step between two
+#: doublings (``ServingConfig.prefill_buckets``). One value for every
+#: model: below it a program more costs a short set-up more than its padded
+#: rows cost the device (PERF.md section 6, PRs 45 and 46: half steps from
+#: 512 cost OLMoE's 20 s set-up two programs and its gate)
+HALF_STEP_FROM = 1024
+
 
 class ServingConfig(_model.ModelConfig):
     """Model shape + engine knobs. Engine knobs default from the
@@ -205,11 +212,20 @@ class ServingConfig(_model.ModelConfig):
         return out
 
     def prefill_buckets(self):
-        """Padded prompt lengths: block_size doublings up to max_len."""
+        """Padded prompt lengths: block_size doublings up to max_len, and
+        from ``HALF_STEP_FROM`` rows up the half step between two
+        doublings (1,024, 1,536, 2,048, 3,072, ...). A short program is
+        bound by its weights' bytes and a padded row costs nothing; a
+        long one pays for every row, and a prompt one token past a
+        doubling would run half a program of padding."""
         out = []
         s = self.block_size
         while s < self.max_len:
             out.append(s)
+            # (a half step is whole blocks from the second doubling on)
+            if (s >= max(HALF_STEP_FROM, 2 * self.block_size)
+                    and s + s // 2 < self.max_len):
+                out.append(s + s // 2)
             s *= 2
         out.append(self.max_len)
         return out
@@ -1540,8 +1556,10 @@ class ServingEngine:
                 telemetry.counter("serving.latent.prefill_tokens").inc(L)
             telemetry.histogram("serving.prefill_seconds").observe(wall)
             telemetry.counter("serving.prefill_tokens").inc(L)
+            telemetry.counter("serving.prefill_rows").inc(args["bucket"])
             if self._rec is not None:
                 self._rec["prefill_tokens"] += L
+                self._rec["prefill_rows"] += args["bucket"]
             if self.streams is not None and self.streams.slots is not None:
                 telemetry.counter("serving.ssm.prefill_tokens").inc(L)
             # register this prefix's full blocks for later admissions

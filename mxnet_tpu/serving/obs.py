@@ -83,7 +83,7 @@ _BURN_MIN_SAMPLES = 8
 
 #: Steps an engine's ring of loop records keeps: the longest window a
 #: benchmark run may have (51 s) at the ~30 steps a second of its busiest
-#: cell, with set-up's steps before it. A record is ~0.8 KB (31 fields,
+#: cell, with set-up's steps before it. A record is ~0.8 KB (32 fields,
 #: most of them floats of their own): a full ring is ~7 MB.
 LOOP_RING = 8192
 
@@ -106,7 +106,8 @@ class LoopRecord(namedtuple("LoopRecord", (
         "full_live_blocks",
         "gap_chunk_s", "gap_group_s", "finished",
         "deferred_s", "deferred_hidden", "passes",
-        "full_ctx_tokens", "window_ctx_tokens", "prefill_tokens"))):
+        "full_ctx_tokens", "window_ctx_tokens", "prefill_tokens",
+        "prefill_rows"))):
     """What the engine loop did in ONE non-empty step, on the host's clock
     (docs/observability.md has the table of fields, where each is measured
     and the benchmark metric that reads it).
@@ -124,7 +125,10 @@ class LoopRecord(namedtuple("LoopRecord", (
     step's record and event are later made from; the three need not add
     up to it. Counts: ``prefills`` (the group's prompts) and
     ``prefill_tokens`` (the tokens its prefill programs took, a replay
-    after a preemption included: every model's), ``chunk_steps``
+    after a preemption included: every model's) beside ``prefill_rows``
+    (the rows those programs computed: each prompt's rung of
+    ``ServingConfig.prefill_buckets``, so ``1 - prefill_tokens /
+    prefill_rows`` is the share of them that was padding), ``chunk_steps``
     (the decode dispatch's trip count, 0 for a step without one),
     ``lanes``, and what the chunk's steps walked (``lane_steps``,
     ``live_blocks``; a model with window layers also its window and

@@ -813,6 +813,9 @@ METRIC_HELP = {
         "per-request prefill wall, own dispatch to the end of its fetch "
         "(in a group: with what was left of the prompts queued before it)",
     "serving.prefill_tokens": "prompt+replay tokens prefilled",
+    "serving.prefill_rows":
+        "rows the prefill programs computed for them: each prompt's rung "
+        "of the prefill ladder (the rest of a rung is padding)",
     "serving.decode_batch":
         "live streams per fused decode step (one observation an inner "
         "step of a decode chunk, dead lanes not counted)",

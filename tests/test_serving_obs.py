@@ -426,7 +426,7 @@ def test_one_record_a_non_empty_step_with_every_field(ticks):
     before = time.time()
     rec, wall, progs, fetched = _step_logged(eng, clock, fetches, calls)
     assert len(ring) == 1 and ring[0] is rec
-    assert rec._fields == LoopRecord._fields and len(rec) == 31
+    assert rec._fields == LoopRecord._fields and len(rec) == 32
     # the ring's tuple is made from the dict's values as they stand
     assert tuple(open_record()) == LoopRecord._fields
     assert before <= rec.ts <= time.time() and rec.step == eng._steps == 1
@@ -439,6 +439,8 @@ def test_one_record_a_non_empty_step_with_every_field(ticks):
     assert (rec.window_live_blocks, rec.full_live_blocks) == (0, 0)
     assert (rec.full_ctx_tokens, rec.window_ctx_tokens) == (0, 0)
     assert rec.prefill_tokens == sum(len(r.prompt) for r in reqs) > 0
+    # each prompt's rung of the ladder: four tokens in a block of eight
+    assert rec.prefill_rows == len(reqs) * eng.config.block_size
     for f in SECTIONS:      # every section ran and was timed: whole ticks
         assert getattr(rec, f) >= 1.0 and getattr(rec, f) % 1 == 0, f
     # what is left of the counters in the gap: two stretches, a clock
