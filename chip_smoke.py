@@ -210,7 +210,6 @@ def size_pool(model, max_batch, budget_bytes):
     import jax.numpy as jnp
 
     from mxnet_tpu.serving import model as lm
-    from mxnet_tpu.serving.kv_cache import KVBlockPool
 
     cfg = lm.ModelConfig(model["vocab"], model["num_layers"],
                          model["model_dim"], model["num_heads"],
@@ -218,8 +217,7 @@ def size_pool(model, max_batch, budget_bytes):
     bs = model["block_size"]
     # the engine's own page format: sized against (H, D) rows the ladder
     # would plan for programs that copy the whole pool (PERF.md, PR 25)
-    page = KVBlockPool.page_shape(cfg.num_heads,
-                                  cfg.model_dim // cfg.num_heads)
+    pages_of = cfg.cache_specs().full
 
     spec = jax.ShapeDtypeStruct
     params = {k: spec(v, jnp.float32)
@@ -228,7 +226,7 @@ def size_pool(model, max_batch, budget_bytes):
     tables = spec((max_batch, cfg.max_len // bs), jnp.int32)
     tried = []
     for n in POOL_LADDER:
-        pages = spec((cfg.num_layers, n, bs) + page, jnp.float32)
+        pages = spec(pages_of.shape(n, bs)[0], jnp.float32)
         ma = jax.jit(functools.partial(lm.decode, cfg=cfg),
                      donate_argnums=(5, 6)).lower(
             params, ints, ints, tables, ints, pages, pages

@@ -14,7 +14,9 @@ three happened.
 """
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import fcntl
 import logging
 import os
 import subprocess
@@ -50,6 +52,27 @@ def _build():
             "%s", type(e).__name__,
             (getattr(e, "stderr", None) or b"").decode(errors="replace")[-500:])
         return False
+
+
+@contextlib.contextmanager
+def _other_processes_wait():
+    """An exclusive lock among PROCESSES over the look, the build and the
+    load: workers that start together in a fresh checkout (six of pytest-
+    xdist's) would each run ``make`` over the same files, and one would load
+    what another's linker is still writing. The second waits for the first's
+    ``make`` and loads the finished library. The lock is a file beside the
+    library; a tree that cannot be written to has nothing to build either."""
+    try:
+        os.makedirs(os.path.dirname(_LIB_PATH), exist_ok=True)
+        fd = os.open(_LIB_PATH + ".lock", os.O_CREAT | os.O_RDWR, 0o644)
+    except OSError:
+        yield
+        return
+    try:
+        fcntl.flock(fd, fcntl.LOCK_EX)
+        yield
+    finally:
+        os.close(fd)        # closing the description releases the lock
 
 
 def _declare(lib):
@@ -177,16 +200,17 @@ def get_lib():
             return _lib
         _tried = True
         _status = "absent"
-        built = not os.path.exists(_LIB_PATH)
-        if built:
-            from .base import env_flag
+        with _other_processes_wait():
+            built = not os.path.exists(_LIB_PATH)
+            if built:
+                from .base import env_flag
 
-            if env_flag("MXNET_TPU_NO_NATIVE") or not _build():
+                if env_flag("MXNET_TPU_NO_NATIVE") or not _build():
+                    return None
+            try:
+                _lib = _declare(ctypes.CDLL(_LIB_PATH))
+            except OSError:
                 return None
-        try:
-            _lib = _declare(ctypes.CDLL(_LIB_PATH))
-        except OSError:
-            return None
         _status = "built" if built else "loaded"
         return _lib
 

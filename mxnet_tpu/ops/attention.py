@@ -718,7 +718,7 @@ get_op("_contrib_CachedMultiHeadAttention")._infer_shape = _cached_mha_infer
 # ------------------------------------------------------- paged (ragged) decode
 # Page format. A page row is ``(G, W)``: ``r = W // D`` consecutive heads of
 # ``D`` lanes side by side, ``G = H // r`` rows (``serving/kv_cache.py``
-# ``KVBlockPool.page_shape`` picks r so that W fills the TPU's 128 lanes;
+# ``PageSpec.lane_dense`` picks r so that W fills the TPU's 128 lanes;
 # r = 1 is the plain ``(H, D)`` row). ``(H, D) -> (G, W)`` is a row-major
 # reshape, so q, the new K/V rows and the output are packed and unpacked by
 # reshapes at the edges and r is read off the shapes. Pages come as one

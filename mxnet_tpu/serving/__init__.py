@@ -10,7 +10,8 @@ multiplex one set of weights.
 
 Layers:
 
-* :mod:`.kv_cache`  — the device block pool + host allocator
+* :mod:`.kv_cache`  — what a K/V page is (:class:`PageSpec`, one a cache)
+  and the device block pool + host allocator of such pages
   (``serving.kv_blocks_*`` accounting).
 * :mod:`.model`     — the functional Transformer-LM forward sharing
   ``models/transformer_lm.py`` parameter names: full-sequence prefill
@@ -33,18 +34,19 @@ Layers:
   bit-identically. docs/serving.md §resilience.
 
 Front ends: ``tools/serve.py`` (HTTP/JSON standing server with live stat
-columns), ``tools/bench_serving.py`` (offline BENCH headline), and
+columns), ``benchmark/run.py`` (the serving cells' drivers), and
 ``tools/serving_report.py`` (per-request waterfalls + occupancy timeline
 from telemetry JSONL). See docs/serving.md.
 """
 from .engine import ServingConfig, ServingEngine
-from .kv_cache import KVBlockPool, KVCacheOOM
+from .kv_cache import KVBlockPool, KVCacheOOM, PageSpec
 from .obs import PHASES, RequestTrace, ServingObs
 from .resilience import EngineSupervisor, ServingOverloadError, retry_after_s
 from .scheduler import (CANCELLED, FAILED, FINISHED, TIMED_OUT, Request,
                         Scheduler)
 
 __all__ = ["ServingConfig", "ServingEngine", "KVBlockPool", "KVCacheOOM",
+           "PageSpec",
            "Request", "Scheduler", "ServingObs", "RequestTrace", "PHASES",
            "EngineSupervisor", "ServingOverloadError", "retry_after_s",
            "FINISHED", "FAILED", "TIMED_OUT", "CANCELLED"]

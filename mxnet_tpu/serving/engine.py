@@ -86,7 +86,7 @@ class ServingConfig(_model.ModelConfig):
     A model with "swa" layers has a second pool for them, sized for
     ``max_batch`` streams of ``window + block_size`` tokens and the trash
     block; one with "mamba" layers ``max_batch`` state slots and the trash
-    slot (``_hybrid_caches``). ``num_blocks`` stays the full-length
+    slot (``_build_caches``). ``num_blocks`` stays the full-length
     pool's."""
 
     __slots__ = ("block_size", "num_blocks", "max_batch",
@@ -299,17 +299,8 @@ class ServingEngine:
         if arg_params is None:
             arg_params = _model.random_params(cfg, seed=seed)
         self.params = _model.as_device_params(arg_params, cfg, device=device)
-        if not cfg.hybrid:
-            self.pool = KVBlockPool(cfg.num_layers, cfg.num_blocks,
-                                    cfg.block_size, cfg.num_heads,
-                                    cfg.head_dim,
-                                    dtype=cfg.kv_dtype, device=device,
-                                    prefix_cache=cfg.prefix_cache,
-                                    parts=cfg.loop_steps)
-            self.window_pool = self.state = self.streams = None
-        else:
-            (self.pool, self.window_pool, self.state,
-             self.streams) = self._hybrid_caches(cfg, device)
+        (self.pool, self.window_pool, self.state,
+         self.streams) = self._build_caches(cfg, device)
         # a step writes up to a decode chunk's slots a stream, or the
         # spec_k+1 of a draft+verify window: headroom covers either
         self._spec = cfg.spec_k > 0
@@ -540,9 +531,8 @@ class ServingEngine:
                     device=device)
             import jax.numpy as jnp
 
-            dshape = (dcfg.num_layers, dcfg.loop_steps * cfg.num_blocks,
-                      cfg.block_size) + KVBlockPool.page_shape(
-                          dcfg.num_heads, dcfg.head_dim)
+            dshape, _ = dcfg.cache_specs().full.shape(cfg.num_blocks,
+                                                      cfg.block_size)
             dk = jnp.zeros(dshape, cfg.kv_dtype)
             dv = jnp.zeros(dshape, cfg.kv_dtype)
             if device is not None:
@@ -1328,29 +1318,25 @@ class ServingEngine:
         row[:n] = blocks[:n]
         return row
 
-    def _hybrid_caches(self, cfg, device):
-        """The caches of a model with ``layer_kinds``: the full-length
-        pool (one layer a "full" layer: the "cross" layers read
-        it and have none of their own), the window pool of the "swa"
-        layers, the state slots of the "mamba" layers, and the manager
-        that books a stream's share of the last two."""
+    def _build_caches(self, cfg, device):
+        """``(pool, window_pool, state, streams)``, each of the page spec
+        the configuration names (``cache_specs``). Every model has the
+        full-length pool; a one-block model has nothing else (``None``). A
+        model with ``layer_kinds`` — one cache layer a "full" layer: the
+        "cross" layers read it and have none of their own — has beside it
+        the window pool of its "swa" layers, the state slots of its
+        "mamba" layers, and the manager that books a stream's share of
+        the two."""
         from .kv_cache import StateSlots, StreamState
 
-        def rows_of(kind):
-            """``(G, W)`` (num_heads x head_dim = G x W) and, where V's rows
-            are not K's (the latent format; plain grouped-query attention,
-            whose two pools' rows differ besides), ``v_rows=``."""
-            other = cfg.latent or cfg.gqa
-            return cfg.kv_rows(kind), (
-                {"v_rows": cfg.v_rows(kind)} if other else {})
-
-        n_full = len(cfg.layers_of("full", "mla"))
+        full, window = cfg.cache_specs()
+        pool = KVBlockPool(
+            full, cfg.num_blocks, cfg.block_size, dtype=cfg.kv_dtype,
+            device=device, prefix_cache=cfg.prefix_cache and not cfg.hybrid)
+        if not cfg.hybrid:
+            return pool, None, None, None
         n_win = len(cfg.layers_of("swa"))
         n_ssm = len(cfg.layers_of("mamba"))
-        rows, latent = rows_of("full")
-        pool = KVBlockPool(max(n_full, 1), cfg.num_blocks, cfg.block_size,
-                           *rows, dtype=cfg.kv_dtype, device=device,
-                           prefix_cache=False, rows=rows, **latent)
         # max_batch streams of window + one block of tokens and the slots
         # a decode chunk writes beyond its first, max_batch slots, and the
         # trash of each: running <= max_batch, so neither runs short. A
@@ -1358,11 +1344,10 @@ class ServingEngine:
         # that the step programs have one signature
         per_stream = -(-(cfg.window + cfg.block_size + self._chunk - 1)
                        // cfg.block_size)
-        rows, latent = rows_of("swa")
         window_pool = KVBlockPool(
-            max(n_win, 1), cfg.max_batch * per_stream + 1 if n_win else 2,
-            cfg.block_size, *rows, dtype=cfg.kv_dtype, device=device,
-            prefix_cache=False, rows=rows, gauges=False, **latent)
+            window, cfg.max_batch * per_stream + 1 if n_win else 2,
+            cfg.block_size, dtype=cfg.kv_dtype, device=device,
+            prefix_cache=False, gauges=False)
         state = StateSlots(
             max(n_ssm, 1), cfg.max_batch + 1 if n_ssm else 2,
             (cfg.ssm_conv - 1) * cfg.d_inner, (cfg.ssm_state, cfg.d_inner),
@@ -2092,7 +2077,7 @@ class ServingEngine:
             "window_blocks_freed": st.blocks_freed,
             # model layers that read the full-length pool's K/V
             "full_pool_readers": self._full_readers,
-            "full_pool_layers": self.pool.num_layers,
+            "full_pool_layers": self.pool.spec.layers,
         }
 
     def _mxu_share(self):
@@ -2113,7 +2098,7 @@ class ServingEngine:
                           * self.obs.loop_snapshot()["sums"][
                               "window_live_blocks"], st.pool))
         total = sum(n for n, _pool in walks)
-        share = (sum(n for n, pool in walks if pool.is_head_major) / total
+        share = (sum(n for n, pool in walks if pool.spec.head_major) / total
                  if total else 0.0)
         telemetry.gauge("serving.paged.mxu_share").set(share)
         return share
@@ -2146,10 +2131,10 @@ class ServingEngine:
                 "kv_pool_bytes": self.pool.nbytes(),
                 # 1 = the plain (H, D) page row; at head_dim 64 that row
                 # is half a lane tile and every program copies the pool
-                "kv_heads_per_row": self.pool.heads_per_row,
-                "kv_page_shape": list(self.pool.page_rows),
+                "kv_heads_per_row": self.pool.spec.heads_per_row,
+                "kv_page_shape": list(self.pool.spec.k_rows),
                 # a block is (G, bs, W): rows that do not fill their tiles
-                "kv_head_major": self.pool.is_head_major,
+                "kv_head_major": self.pool.spec.head_major,
                 "tokens_total": self._tokens_total,
                 "tokens_per_sec":
                     telemetry.gauge("serving.tokens_per_sec").value,
@@ -2215,7 +2200,7 @@ class ServingEngine:
                     "passes_per_step":
                         (self._looped_passes / self._looped_steps)
                         if self._looped_steps else 0.0,
-                    "cache_layers": self.pool.cache_layers,
+                    "cache_layers": self.pool.spec.cache_layers,
                 }} if self.config.loop_steps > 1 else {}),
                 # only for a model with window or state layers
                 **({"state": self._state_stats()}

@@ -10,30 +10,41 @@ pool blocks hold the request's tokens, in position order. Device memory
 scales with tokens actually cached, admission is a free-list pop, and
 release is O(blocks) with zero copying.
 
-Layout (one pool per engine): ``(num_layers, num_blocks, block_size, G, W)``
-for K and V, where a page row ``(G, W)`` holds a token's ``num_heads x
-head_dim`` values lane-dense: :meth:`KVBlockPool.page_shape` puts ``r``
-consecutive heads side by side so that ``W = r * head_dim`` fills the TPU's
-128 lanes (16 heads of 64 -> 8 rows of 128). A 64-wide minor dimension fills
-half of every (8, 128) tile: the device's default layout for such a pool is
-not row-major, the Pallas kernels want row-major, and every program that
-touched the pool copied all of it in and out (PERF.md, PR 25). With full
-lanes the default layout, the scatter's and the kernel's are one, and the
-donated pool is updated where it lies. ``(H, D) -> (G, W)`` is a row-major
-reshape, so a block's bytes are what they always were. Block 0 is the
-reserved TRASH block —
-padded table entries and padded batch rows point at it, so masked lanes of
-a bucketed step scatter their garbage somewhere no reader ever trusts
+Who decides what a page is, arrows one way::
+
+    ModelConfig.cache_specs()          serving/model.py: the ONE family
+      |  names a PageSpec a cache      switch (rows, widths, order, parts)
+      v
+    KVBlockPool(spec, ...)             this module: holds ``k_pages`` /
+      |  hands out pages of the spec   ``v_pages`` of ``spec.shape(...)``
+      v
+    step programs and kernels          serving/model.py, ops/attention.py:
+                                       read the spec (and the pages' own
+                                       shape); conclude nothing themselves
+
+Layout (one pool per engine): ``(layers, parts x num_blocks) + block`` for
+K and V, where a block is ``(block_size, G, W)`` — or ``(G, block_size,
+W)`` where the spec is head-major — and a page row ``(G, W)`` holds a
+token's K (or V) lane-dense (:class:`PageSpec`; 16 heads of 64 -> 8 rows
+of 128). A 64-wide minor dimension fills half of every (8, 128) tile: the
+device's default layout for such a pool is not row-major, the Pallas
+kernels want row-major, and every program that touched the pool copied all
+of it in and out (PERF.md, PR 25). With full lanes the default layout, the
+scatter's and the kernel's are one, and the donated pool is updated where
+it lies. ``(H, D) -> (G, W)`` is a row-major reshape, so a block's bytes
+are what they always were. Block 0 is the reserved TRASH block — padded
+table entries and padded batch rows point at it, so masked lanes of a
+bucketed step scatter their garbage somewhere no reader ever trusts
 (readers mask by context length; the pool hands block 0 to no request).
 
 A model whose stack runs several times (``ModelConfig.loop_steps`` = R)
-caches every pass apart. Its pool is R PARTS side by side along the block
-axis, ``(num_layers, R x num_blocks, ...)``: block ``b``'s rows of pass
-``r`` are block ``r x num_blocks + b`` of the arrays, which the step
-programs reach by adding ``r x num_blocks`` to the block table (block 0 of
-each part is that pass's trash). A block id still names ONE allocation
-unit, a token's rows in all ``R x num_layers`` cache layers: the free
-list, refcounts and the prefix index know nothing of parts; ``cow``
+caches every pass apart. Its pool is R PARTS (``PageSpec.parts``) side by
+side along the block axis, ``(layers, R x num_blocks, ...)``: block ``b``'s
+rows of pass ``r`` are block ``r x num_blocks + b`` of the arrays, which
+the step programs reach by adding ``r x num_blocks`` to the block table
+(block 0 of each part is that pass's trash). A block id still names ONE
+allocation unit, a token's rows in all ``R x layers`` cache layers: the
+free list, refcounts and the prefix index know nothing of parts; ``cow``
 copies, and ``nbytes`` / ``block_nbytes`` count, every part of a block.
 
 Prefix sharing (docs/serving.md §prefix-sharing): every allocated block
@@ -54,6 +65,7 @@ reduces to accounting for INTERNAL fragmentation — allocated-but-unused
 slots in each request's tail block — exposed as the
 ``serving.kv_blocks_frag_slots`` gauge (the engine refreshes it each step).
 """
+import dataclasses
 import hashlib
 import threading
 
@@ -70,66 +82,108 @@ class KVCacheOOM(MXNetError):
     dying inside a step)."""
 
 
+@dataclasses.dataclass(frozen=True)
+class PageSpec:
+    """What a page of ONE cache is: everything about ``k_pages`` /
+    ``v_pages`` but how many blocks there are and how long. The model's
+    configuration names it (``ModelConfig.cache_specs``), the pool builds
+    its arrays from it, the step programs and the tests read it.
+
+    layers          cache layers, axis 0 of the pages (a pool's layers are
+                    not the model's: one a layer that WRITES the pool)
+    k_rows, v_rows  ``(G, W)``: the rows and lanes one token's K, and V,
+                    take in a page
+    head_major      a block is ``(G, bs, W)`` — each row a ``(bs, W)``
+                    slab — and not ``(bs, G, W)``
+    parts           a looped stack's passes, side by side along the block
+                    axis (module docstring)
+    heads_per_row   heads side by side in one page row (1 = a row the
+                    model named, or the plain ``(H, D)`` row)"""
+    layers: int
+    k_rows: tuple
+    v_rows: tuple
+    head_major: bool
+    parts: int = 1
+    heads_per_row: int = 1
+
+    @classmethod
+    def lane_dense(cls, layers, num_heads, head_dim, parts=1):
+        """The one-block models' rows, token-major always (their step
+        programs know no other order): ``r = 128 // head_dim`` consecutive
+        heads share a row when that fills the 128 lanes exactly and
+        divides the heads; any other shape (``head_dim`` >= 128, an odd
+        head count) keeps ``(H, D)``, r = 1. One layout for the pool, the
+        scatter and the kernels (PR 25): the model and the kernels read r
+        off the pages they are handed."""
+        r = 128 // head_dim if head_dim < 128 and 128 % head_dim == 0 else 1
+        if num_heads % r:
+            r = 1
+        rows = (num_heads // r, r * head_dim)
+        return cls(layers, rows, rows, False, parts, r)
+
+    @classmethod
+    def tiled(cls, layers, rows):
+        """Rows the model names, K's and V's alike, in the order their
+        tiles ask for: head-major when the lanes are full and the rows do
+        not fill their sublane tiles (ten rows of 128: twenty K/V heads of
+        64). Token-major, such a block is padded to sixteen rows in HBM
+        and copied by every kernel call (PR 31); head-major it is whole
+        tiles for any G. The formats that were, ``(8, 128)`` and
+        ``(16, 128)``, stay token-major."""
+        g, w = rows
+        return cls(layers, rows, rows, w % 128 == 0 and g % 8 != 0)
+
+    @property
+    def block_axis(self):
+        """The axis of the pages the block size stands at."""
+        return 3 if self.head_major else 2
+
+    @property
+    def cache_layers(self):
+        """Cache layers a block holds rows in: a layer and part."""
+        return self.layers * self.parts
+
+    def shape(self, num_blocks, block_size):
+        """``(k_pages.shape, v_pages.shape)`` of a pool of ``num_blocks``
+        blocks of ``block_size`` token slots."""
+        return tuple(
+            (self.layers, self.parts * num_blocks)
+            + ((g, block_size, w) if self.head_major else (block_size, g, w))
+            for g, w in (self.k_rows, self.v_rows))
+
+    def block_nbytes(self, block_size, itemsize):
+        """Device bytes ONE block pins across cache layers (K + V)."""
+        (g, w), (gv, wv) = self.k_rows, self.v_rows
+        return self.cache_layers * block_size * (g * w + gv * wv) * itemsize
+
+
 class KVBlockPool:
     """Device KV block pool + thread-safe host-side free-list allocator
     with block refcounts and a content-hash prefix index."""
 
-    def __init__(self, num_layers, num_blocks, block_size, num_heads,
-                 head_dim, dtype=np.float32, device=None,
-                 prefix_cache=True, rows=None, gauges=True, v_rows=None,
-                 parts=1):
-        """``parts``: the passes of a looped stack (module docstring).
-        ``rows``: the page rows ``(G, W)`` where the model decides them
-        (``ModelConfig.kv_rows``: a differential-attention K/V pair a
-        row); ``num_heads x head_dim`` are then ``G x W``. ``gauges``: a
+    def __init__(self, spec, num_blocks, block_size, dtype=np.float32,
+                 device=None, prefix_cache=True, gauges=True):
+        """``spec``: the :class:`PageSpec` of this cache. ``gauges``: a
         second pool of an engine (the window layers') leaves the
-        process's ``serving.kv_blocks_*`` gauges to the first.
-        ``v_rows``: the rows of ``v_pages`` where they differ — the LATENT
-        format (``ModelConfig.v_rows``): ``k_pages`` hold a token's
-        latent, one ``kv_rank``-wide row, ``v_pages`` its rotated key in
-        one 128-lane row; such a block is head-major (one row a token is
-        the ``(bs, W)`` slab either way). Plain grouped-query attention's
-        pools (``attn_form`` "gqa") use it too: a K head's row of 256
-        lanes beside a V head's of 128, head-major."""
+        process's ``serving.kv_blocks_*`` gauges to the first."""
         if num_blocks < 2:
             raise ValueError("KVBlockPool needs >= 2 blocks (block 0 is the "
                              "reserved trash block)")
         import jax.numpy as jnp
 
-        self.num_layers = int(num_layers)
+        #: what a page is: rows, widths, order, layers and parts are read
+        #: HERE, by the engine's ``stats()`` as by everything else
+        self.spec = spec
         self.num_blocks = int(num_blocks)
-        self.parts = int(parts)
         self.block_size = int(block_size)
-        self.num_heads = int(num_heads)
-        self.head_dim = int(head_dim)
         self.dtype = np.dtype(dtype)
         self.prefix_cache = bool(prefix_cache)
         self._gauges = bool(gauges)
         #: device bytes the engine holds beside this pool for the same
         #: streams (a window pool, state slots): :meth:`nbytes` counts them
         self.extra_nbytes = 0
-        #: a block is (G, bs, W), not (bs, G, W): only where the model
-        #: names its rows (the one-block models' programs are token-major)
-        self.is_head_major = v_rows is not None or (
-            rows is not None and self.head_major(*rows))
-        rows, lanes = (rows if rows is not None
-                       else self.page_shape(self.num_heads, self.head_dim))
-        #: heads side by side in one page row (1 = the plain (H, D) row)
-        self.heads_per_row = self.num_heads // rows
-        #: (G, W), wherever the block's slots stand
-        self.page_rows = (rows, lanes)
-        #: (G, W) of ``v_pages`` (the same as ``page_rows`` but for latents)
-        self.v_page_rows = tuple(v_rows) if v_rows is not None \
-            else self.page_rows
-
-        def pages(g, w):
-            return jnp.zeros((self.num_layers,
-                              self.parts * self.num_blocks) + (
-                (g, self.block_size, w) if self.is_head_major
-                else (self.block_size, g, w)), self.dtype)
-
-        k = pages(*self.page_rows)
-        v = pages(*self.v_page_rows)
+        k, v = (jnp.zeros(shape, self.dtype)
+                for shape in spec.shape(self.num_blocks, self.block_size))
         if device is not None:
             import jax
 
@@ -160,39 +214,12 @@ class KVBlockPool:
         if self._gauges:
             telemetry.gauge("serving.kv_blocks_total").set(self.num_usable)
             telemetry.gauge("serving.kv_heads_per_row").set(
-                self.heads_per_row)
+                spec.heads_per_row)
         # the pool may be constructed on a supervisor thread while handler
         # threads already poll the gauges of a predecessor — honor the
         # _locked suffix even on the init path
         with self._lock:
             self._refresh_gauges_locked()
-
-    # ---- format ---------------------------------------------------------
-    @staticmethod
-    def page_shape(num_heads, head_dim):
-        """``(G, W)``: the rows and lanes one token's K (or V) takes in a
-        page. ``r = 128 // head_dim`` consecutive heads share a row when
-        that fills the 128 lanes exactly and divides the heads; any other
-        shape (``head_dim`` >= 128, an odd head count) keeps ``(H, D)``,
-        r = 1. The one place the format is decided: the model and the
-        kernels read r off the pages they are handed."""
-        r = 128 // head_dim if head_dim < 128 and 128 % head_dim == 0 else 1
-        if num_heads % r:
-            r = 1
-        return num_heads // r, r * head_dim
-
-    @staticmethod
-    def head_major(rows, lanes):
-        """Whether a block of ``(rows, lanes)`` page rows is laid out
-        ``(G, bs, W)`` — each row a ``(bs, W)`` slab — instead of
-        ``(bs, G, W)``: when the lanes are full and the rows do not fill
-        their sublane tiles (ten rows of 128: twenty K/V heads of 64).
-        Token-major, such a block is padded to sixteen rows in HBM and
-        copied by every kernel call; head-major it is whole tiles for any
-        G. The formats that were, ``(8, 128)`` and ``(16, 128)``, stay
-        token-major, and so does every pool of a one-block model (their
-        step programs know no other order)."""
-        return lanes % 128 == 0 and rows % 8 != 0
 
     @property
     def num_usable(self):
@@ -212,17 +239,10 @@ class KVBlockPool:
         beside it for the same streams (``extra_nbytes``)."""
         return self.block_nbytes() * self.num_blocks + self.extra_nbytes
 
-    @property
-    def cache_layers(self):
-        """Cache layers a block holds rows in: a layer and part."""
-        return self.num_layers * self.parts
-
     def block_nbytes(self):
         """Device bytes ONE block pins across cache layers (K + V) — the
         unit every shared reference saves."""
-        (g, w), (gv, wv) = self.page_rows, self.v_page_rows
-        return (self.cache_layers * self.block_size * (g * w + gv * wv)
-                * self.dtype.itemsize)
+        return self.spec.block_nbytes(self.block_size, self.dtype.itemsize)
 
     def copy_block(self, pages, src, dst):
         """``pages`` with block ``src``'s rows copied bit-exactly over
@@ -434,7 +454,8 @@ class KVBlockPool:
             self._prefix.pop(d, None)
 
     def prefix_stats(self):
-        """This pool's prefix-sharing snapshot (engine stats() / bench)."""
+        """This pool's prefix-sharing snapshot: ``ServingEngine.stats()``
+        ["prefix"], which ``tools/serve.py`` serves at ``/stats``."""
         with self._lock:
             shared = [rc for rc in self._ref.values() if rc > 1]
             saved_blocks = sum(rc - 1 for rc in shared)

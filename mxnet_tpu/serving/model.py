@@ -15,7 +15,7 @@ The step functions are PURE (params and pages in, logits and pages out):
 the engine wraps them in ``compileobs.jit`` with the pool pages donated, so
 each shape bucket compiles exactly once. The pages are the pool's
 ``(L, N, bs, G, W)`` arrays in whatever row format they were built
-(``KVBlockPool.page_shape``; ``(H, D)`` rows are the r = 1 case): new K/V
+(``PageSpec.lane_dense``; ``(H, D)`` rows are the r = 1 case): new K/V
 rows are reshaped to ``(G, W)`` before the scatter and attention reads the
 WHOLE pool at a static layer index, so that a lane-dense pool is scattered
 into and read where it lies — ``k_pages[i]`` is a copy of a layer, and a
@@ -31,6 +31,8 @@ routed to the trash block and its lane's outputs poisoned (token -1,
 logits NaN): the paged path upholds the same graph-level overflow contract
 as ``_contrib_CachedMultiHeadAttention``.
 """
+import collections
+
 import numpy as np
 
 from ..ops.attention import (flash_attention, flash_attention_gqa,
@@ -38,6 +40,11 @@ from ..ops.attention import (flash_attention, flash_attention_gqa,
 from ..ops.moe import moe_ffn
 from ..ops.registry import fp32_precision
 from ..ops.ssm import ssm_scan, ssm_step
+from .kv_cache import PageSpec
+
+#: what :meth:`ModelConfig.cache_specs` answers: the full-length pool's
+#: spec and the window pool's
+CacheSpecs = collections.namedtuple("CacheSpecs", "full window")
 
 #: parameter init scale matching models/transformer_lm.py's Normal(0.02)
 #: pos-embed init; used by random_params for self-contained serving runs
@@ -433,12 +440,6 @@ class ModelConfig:
         return bool(self.layers_of("mla"))
 
     @property
-    def cache_layers(self):
-        """K/V cache layers a token's block holds rows in: a layer and
-        pass (the pool, the walks' booking and ``stats()`` read this)."""
-        return self.loop_steps * self.num_layers
-
-    @property
     def expert_layers(self):
         """How many layers have experts (those behind ``first_dense``)."""
         return self.num_layers - self.first_dense if self.num_experts else 0
@@ -459,38 +460,60 @@ class ModelConfig:
         return max(i for i in self.layers_of("mamba") if i < gmu[0]) \
             if gmu else None
 
-    def kv_rows(self, kind="full"):
-        """``(G, W)``: the page rows of one token's K (or V). Differential
-        attention: a K/V pair is one row (``[v1, v2]`` is the row, ``k1``
-        and ``k2`` its halves). ``attn_form`` "gqa": a K/V head of ``kind``
-        is a row, its ``head_dim`` lanes padded to whole 128-lane tiles
-        (192 -> 256: a head-major slab ``(bs, W)`` is then whole tiles for
-        the copy and the MXU, which pads 192 to 256 itself; a 192-lane
-        minor dimension is padded to 256 in HBM's tiled layout anyway)."""
-        from .kv_cache import KVBlockPool
+    def cache_specs(self):
+        """``(full, window)``: the :class:`~.kv_cache.PageSpec` of the
+        full-length pool and of the window pool — the ONE place a model
+        family decides its page format (the pools, the step programs and
+        the engine read the specs). A one-block model has no window pool
+        (``None``); a model with ``layer_kinds`` but no "swa" (or no
+        "full" / "mla") layer gets a one-layer stand-in of the same rows,
+        so that the step programs have one signature.
 
-        if self.gqa:
-            return self.kv_heads_of(kind), -(-self.head_dim // 128) * 128
-        if self.latent:         # k_pages hold the latent, one row a token
-            return 1, self.kv_rank
-        if set(self.kinds()) & {"swa", "full", "cross"}:
-            return self.num_kv_heads // 2, 2 * self.head_dim
-        return KVBlockPool.page_shape(self.num_kv_heads, self.head_dim)
+        * one block: the lane-dense rows, ``loop_steps`` parts;
+        * ``attn_form`` "gqa": a K/V head of the pool's layer kind is a
+          row, K's ``head_dim`` and V's ``v_dim`` lanes each padded to
+          whole 128-lane tiles (192 -> 256: a head-major slab ``(bs, W)``
+          is then whole tiles for the copy and the MXU, which pads 192 to
+          256 itself; a 192-lane minor dimension is padded to 256 in HBM's
+          tiled layout anyway), V's row narrower than K's;
+        * "mla" layers, the LATENT format: ``k_pages`` hold a token's
+          normalised latent, one ``kv_rank``-wide row; ``v_pages`` its
+          rotated key, ``rope_dim`` lanes padded to whole tiles (a 64-lane
+          row is half a tile: the pool's layout would not be row-major, PR
+          25; two tokens a row would save a tenth of the cache's bytes and
+          make every decode write half a row);
+        * differential attention: a K/V pair is one row (``[v1, v2]`` is
+          the row, ``k1`` and ``k2`` its halves), in the order its tiles
+          ask for (``PageSpec.tiled``).
 
-    def v_rows(self, kind="full"):
-        """The rows of ``v_pages`` where they are not ``kv_rows()``: an
-        "mla" model keeps a token's rotated key there, its ``rope_dim``
-        lanes padded to whole 128-lane tiles (a 64-lane row is half a
-        tile: the pool's layout would not be row-major, PR 25; two tokens
-        a row would save a tenth of the cache's bytes and make every
-        decode write half a row); ``attn_form`` "gqa" keeps a value in its
-        own ``v_dim`` lanes (padded to whole tiles), narrower than the
-        key's row."""
-        if self.latent:
-            return 1, -(-self.rope_dim // 128) * 128
-        if self.gqa:
-            return self.kv_heads_of(kind), -(-self.v_dim // 128) * 128
-        return self.kv_rows()
+        The latent's one row a token is head-major at any width (the block
+        is the ``(bs, W)`` slab either way); so are "gqa"'s pools, whose K
+        and V rows differ."""
+        if not self.hybrid:
+            return CacheSpecs(PageSpec.lane_dense(
+                self.num_layers, self.num_heads, self.head_dim,
+                self.loop_steps), None)
+
+        def whole_tiles(lanes):
+            return -(-lanes // 128) * 128
+
+        def spec(layers, kind):
+            layers, heads = max(layers, 1), self.kv_heads_of(kind)
+            if self.gqa:
+                return PageSpec(layers, (heads, whole_tiles(self.head_dim)),
+                                (heads, whole_tiles(self.v_dim)), True)
+            if self.latent:
+                return PageSpec(layers, (1, self.kv_rank),
+                                (1, whole_tiles(self.rope_dim)), True)
+            if set(self.kinds()) & {"swa", "full", "cross"}:
+                return PageSpec.tiled(layers, (self.num_kv_heads // 2,
+                                               2 * self.head_dim))
+            # no layer reads a pool: stand-ins of the lane-dense rows
+            return PageSpec.tiled(layers, PageSpec.lane_dense(
+                layers, self.num_kv_heads, self.head_dim).k_rows)
+
+        return CacheSpecs(spec(len(self.layers_of("full", "mla")), "full"),
+                          spec(len(self.layers_of("swa")), "swa"))
 
     def key(self):
         """What the programs are a function of. A one-block model's key is
@@ -1317,16 +1340,6 @@ def prefill(params, tokens, length, block_table, k_pages, v_pages, cfg,
     return (next_token, logits, k_pages, v_pages) + loads
 
 
-def _head_major(cfg):
-    """A latent's one row a token is head-major at any width: the block is
-    the ``(bs, W)`` slab either way. So are ``attn_form`` "gqa"'s pools,
-    whose K and V rows differ."""
-    from .kv_cache import KVBlockPool
-
-    return cfg.latent or cfg.gqa \
-        or KVBlockPool.head_major(*cfg.kv_rows())
-
-
 def _cache_layers(cfg):
     """Model layer -> its layer of the full pool, of the window pool, of
     the state slots: three dicts (the pools' layers are not the model's)."""
@@ -1334,13 +1347,12 @@ def _cache_layers(cfg):
                  for kinds in (("full", "mla"), ("swa",), ("mamba",)))
 
 
-def _block_rows(t, bs, cfg, rows=None):
-    """K or V of S tokens ``(S, Hkv hd)`` as S // bs blocks in the pool's
-    own order: ``(S // bs, bs, G, W)``, or ``(S // bs, G, bs, W)``;
-    ``rows``: the page rows where they are not ``kv_rows()``."""
-    g, w = rows or cfg.kv_rows()
-    t = t.reshape(t.shape[0] // bs, bs, g, w)
-    return t.transpose(0, 2, 1, 3) if _head_major(cfg) else t
+def _block_rows(t, bs, rows, head_major):
+    """K or V of S tokens ``(S, G W)`` as S // bs blocks of page rows
+    ``rows`` in the pool's own order: ``(S // bs, bs, G, W)``, or
+    ``(S // bs, G, bs, W)``."""
+    t = t.reshape(t.shape[0] // bs, bs, *rows)
+    return t.transpose(0, 2, 1, 3) if head_major else t
 
 
 def _put_blocks(pages, li, table, rows):
@@ -1369,8 +1381,9 @@ def _prefill_hybrid(params, tokens, length, block_table, k_pages, v_pages,
     import jax.numpy as jnp
 
     _, S = tokens.shape
-    bs = k_pages.shape[3 if _head_major(cfg) else 2]
-    g, w = cfg.kv_rows()
+    full, win = cfg.cache_specs()
+    bs = k_pages.shape[full.block_axis]
+    g, w = full.k_rows
     rq = cfg.num_heads // g             # query heads reading one K/V row
     prec = fp32_precision(k_pages.dtype)
     positions = jnp.arange(S, dtype=jnp.int32)[None]
@@ -1379,9 +1392,10 @@ def _prefill_hybrid(params, tokens, length, block_table, k_pages, v_pages,
     wtable, slot = aux["wtable"], aux["slot"]
     taps = cfg.ssm_conv
 
-    def put(pages, li, table, t, rows=None):
+    def put(pages, li, table, t, rows=full.k_rows):
         """One layer's K or V of the S tokens into its blocks."""
-        return _put_blocks(pages, li, table, _block_rows(t, bs, cfg, rows))
+        return _put_blocks(pages, li, table,
+                           _block_rows(t, bs, rows, full.head_major))
 
     def attend_mla(i, q, c, kr, st):
         """Cache the latent and the rotated key; attend over the prompt's
@@ -1391,7 +1405,7 @@ def _prefill_hybrid(params, tokens, length, block_table, k_pages, v_pages,
         li = full_at[i]
         st = dict(st, k=put(st["k"], li, block_table, c[0]),
                   v=put(st["v"], li, block_table,
-                        _pad_lanes(kr[0], cfg.v_rows()[1]), cfg.v_rows()))
+                        _pad_lanes(kr[0], full.v_rows[1]), full.v_rows))
         kv = jnp.einsum("sc,nc->sn", c[0],
                         params["layer%d_mla_kv_up_weight" % i],
                         precision=prec).reshape(S, hh, cfg.head_dim + dv)
@@ -1411,10 +1425,10 @@ def _prefill_hybrid(params, tokens, length, block_table, k_pages, v_pages,
         with the K/V heads named by the kernel's index map, not repeated."""
         kind = cfg.kinds()[i]
         if kind == "full":
-            kp, vp, table, li = "k", "v", block_table, full_at[i]
+            kp, vp, table, li, spec = "k", "v", block_table, full_at[i], full
         else:
-            kp, vp, table, li = "wk", "wv", wtable, win_at[i]
-        krows, vrows = cfg.kv_rows(kind), cfg.v_rows(kind)
+            kp, vp, table, li, spec = "wk", "wv", wtable, win_at[i], win
+        krows, vrows = spec.k_rows, spec.v_rows
         st = dict(st, **{
             kp: put(st[kp], li, table, _pad_lanes(k[0], krows[1]), krows),
             vp: put(st[vp], li, table, _pad_lanes(v[0], vrows[1]), vrows)})
@@ -1555,9 +1569,10 @@ def _paged_step_hybrid(params, tokens, positions, block_tables,
     if T != 1:
         raise ValueError("a model with state or window layers decodes one "
                          "token a step (no verify pass)")
-    hm = _head_major(cfg)
-    bs = k_pages.shape[3 if hm else 2]
-    g, w = cfg.kv_rows()
+    full, win = cfg.cache_specs()
+    hm = full.head_major
+    bs = k_pages.shape[full.block_axis]
+    g, w = full.k_rows
     rq = cfg.num_heads // g
     prec = fp32_precision(k_pages.dtype)
     sm_scale = 1.0 / float(np.sqrt(cfg.head_dim))
@@ -1575,8 +1590,8 @@ def _paged_step_hybrid(params, tokens, positions, block_tables,
         ids = jnp.take_along_axis(tables, safe_pos // bs, axis=1)
         return jnp.where(in_range, ids, 0).reshape(-1)  # overflow -> trash
 
-    def write(pages, li, ids, new, rows=None):
-        g, w = rows or cfg.kv_rows()
+    def write(pages, li, ids, new, rows=full.k_rows):
+        g, w = rows
         new = new.reshape(B, g, w).astype(pages.dtype)
         if hm:
             # every (page, row, slot) named: the scatter's windows are the
@@ -1595,7 +1610,7 @@ def _paged_step_hybrid(params, tokens, positions, block_tables,
         ``kv_rank``-wide results go through ``W_uv``."""
         li = full_at[i]
         ids = page_ids(block_tables)
-        vrows = cfg.v_rows()
+        vrows = full.v_rows
         st = dict(st, k=write(st["k"], li, ids, c),
                   v=write(st["v"], li, ids, _pad_lanes(kr, vrows[1]), vrows))
         dn, dv = cfg.head_dim, cfg.v_dim
@@ -1621,12 +1636,12 @@ def _paged_step_hybrid(params, tokens, positions, block_tables,
         a "full" layer's are two ops on a trace."""
         kind = cfg.kinds()[i]
         if kind == "swa":
-            kp, vp, tables, li, window = ("wk", "wv", wtables, win_at[i],
-                                          cfg.window)
+            kp, vp, tables, li, window, spec = ("wk", "wv", wtables,
+                                                win_at[i], cfg.window, win)
         else:
-            kp, vp, tables, li, window = ("k", "v", block_tables,
-                                          full_at[i], None)
-        (hk, wk), vrows = cfg.kv_rows(kind), cfg.v_rows(kind)
+            kp, vp, tables, li, window, spec = ("k", "v", block_tables,
+                                                full_at[i], None, full)
+        (hk, wk), vrows = spec.k_rows, spec.v_rows
         r = cfg.num_heads // hk
         ids = page_ids(tables)
         st = dict(st, **{

@@ -7,7 +7,7 @@
   this CPU-default process must hold the kernel (``tpu_custom_call``), not
   the reference lowering.
 * The serving programs leave the KV pool where it lies: over a pool from
-  ``KVBlockPool.page_shape`` the compiled ``decode`` and ``prefill`` hold no
+  ``PageSpec.lane_dense`` the compiled ``decode`` and ``prefill`` hold no
   copy and no slice the size of the pool or of a layer of it.
 * ``chip_smoke.py`` fails fast and says so when jax has no TPU.
 * The compile-cache directory is decided by the environment, then by one
@@ -258,10 +258,13 @@ def test_serving_programs_leave_the_pool_in_place(v5e, name, arch, heads,
     stack (Ouro's widths, four passes) carries it through the passes' loop
     too, inside the chunk's, each pass reaching its part of the pool through
     the block table, and holds ONE pass's paged calls."""
-    from mxnet_tpu.serving.kv_cache import KVBlockPool
+    from mxnet_tpu.serving.kv_cache import PageSpec
 
-    assert KVBlockPool.page_shape(heads, head_dim) == page
+    spec = PageSpec.lane_dense(2, heads, head_dim, 4 if arch == "ouro" else 1)
+    assert spec.k_rows == spec.v_rows == page and not spec.head_major
     compiled, pool_shape, itemsize = _serving_program(v5e, name, page, arch)
+    assert spec.shape(pool_shape[1] // spec.parts,
+                      pool_shape[2]) == (pool_shape, pool_shape)
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 2
     if arch == "ouro":      # a flash or paged call a layer, not a layer and pass
@@ -316,11 +319,15 @@ def _hybrid_program(chip, name):
         head_dim=64, num_kv_heads=20, window=512, attn_bias=True,
         ffn_gated=True, tie_embed=True, ssm_dt_rank=160,
         layer_kinds=["mamba", "swa", "mamba", "full", "gmu", "cross"])
-    bs, (g, w) = 64, cfg.kv_rows()
+    bs, g, w = 64, 10, 128
     caches = {"pool": s((1, 2561, g, bs, w), bf),
               "window": s((1, 1153, g, bs, w), bf),
               "conv": s((2, 513, 3 * cfg.d_inner), bf),
               "ssm": s((2, 513, 16, cfg.d_inner), jnp.float32)}
+    full, window = cfg.cache_specs()
+    assert full.shape(2561, bs) == (caches["pool"].shape,) * 2
+    assert window.shape(1153, bs) == (caches["window"].shape,) * 2
+    assert full.head_major and full.block_axis == 3
     params = {k: s(v, bf) for k, v in M.param_shapes(cfg).items()}
     aux = ("wk", "wv", "conv", "ssm")
     if name == "decode":
@@ -362,9 +369,9 @@ def test_hybrid_programs_leave_every_cache_in_place(v5e, name):
     where they lie — no copy or slice the size of one of them or of a
     layer, everything donated and aliased, the state-space kernels by
     their names."""
-    from mxnet_tpu.serving.kv_cache import KVBlockPool
+    from mxnet_tpu.serving.kv_cache import PageSpec
 
-    assert KVBlockPool.head_major(10, 128)
+    assert PageSpec.tiled(1, (10, 128)).head_major
     compiled, shapes = _hybrid_program(v5e, name)
     text = compiled.as_text()
     kernel = "ssm_step" if name in ("decode", "chunk") else "ssm_scan"
@@ -414,13 +421,16 @@ def _latent_program(chip, name):
         dense_ffn_dim=18432, num_experts=256, experts_per_tok=8,
         shared_experts=1, router="sigmoid_group", n_group=8, topk_group=4,
         route_scale=2.5, experts_held=(0, 16))
-    assert (cfg.kv_rows(), cfg.v_rows()) == ((1, 512), (1, 128))
+    full, window = cfg.cache_specs()
+    assert (full.k_rows, full.v_rows) == ((1, 512), (1, 128))
     pages = {"k": s((layers, 2049, 1, bs, 512), bf),
              "v": s((layers, 2049, 1, bs, 128), bf)}
     # the kinds this model lacks keep their two-block stand-ins
     stand_ins = (s((1, 2, 1, bs, 512), bf), s((1, 2, 1, bs, 128), bf),
                  s((1, 2, 3 * cfg.d_inner), bf),
                  s((1, 2, 16, cfg.d_inner), jnp.float32))
+    assert full.shape(2049, bs) == (pages["k"].shape, pages["v"].shape)
+    assert window.shape(2, bs) == tuple(a.shape for a in stand_ins[:2])
     params = {k: s(v, bf) for k, v in M.param_shapes(cfg).items()}
     aux = ("wk", "wv", "conv", "ssm")
     nb = cfg.max_len // bs
@@ -500,8 +510,8 @@ def _gqa_program(chip, name, kinds=_MIMO_KINDS, batch=64, prompt=2048,
         ffn_gated=True, norm_eps=1e-5, first_dense=1, dense_ffn_dim=16384,
         num_experts=256, experts_per_tok=8, router="sigmoid_group",
         experts_held=(0, 16))
-    assert (cfg.kv_rows("full"), cfg.v_rows("full"),
-            cfg.kv_rows("swa"), cfg.v_rows("swa")) == (
+    full, window = cfg.cache_specs()
+    assert (full.k_rows, full.v_rows, window.k_rows, window.v_rows) == (
         (4, 256), (4, 128), (8, 256), (8, 128))
     n_full, n_win = kinds.count("full"), kinds.count("swa")
     wblocks = batch * 4 + 1
@@ -509,6 +519,9 @@ def _gqa_program(chip, name, kinds=_MIMO_KINDS, batch=64, prompt=2048,
              "v": s((n_full, blocks, 4, bs, 128), bf),
              "wk": s((n_win, wblocks, 8, bs, 256), bf),
              "wv": s((n_win, wblocks, 8, bs, 128), bf)}
+    assert full.shape(blocks, bs) == (pages["k"].shape, pages["v"].shape)
+    assert window.shape(wblocks, bs) == (pages["wk"].shape,
+                                         pages["wv"].shape)
     stand_ins = (s((1, 2, 3 * cfg.d_inner), bf),
                  s((1, 2, 16, cfg.d_inner), jnp.float32))
     params = {k: s(v, bf) for k, v in M.param_shapes(cfg).items()}

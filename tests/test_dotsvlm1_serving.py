@@ -583,7 +583,9 @@ def test_serving_config_refuses_what_latents_cannot_do_yet():
     scfg = C.serving_config(cfg)
     assert scfg.prefix_cache is False and scfg.latent and scfg.hybrid
     assert not scfg.stateful
-    assert scfg.kv_rows() == (1, 128) and scfg.v_rows() == (1, 128)
+    full = scfg.cache_specs().full
+    assert (full.k_rows, full.v_rows, full.head_major) == (
+        (1, 128), (1, 128), True)
     assert scfg.expert_layers == 2 and scfg.experts_here == (0, 4)
     model = {k: v for k, v in cfg["model"].items() if k != "vocab"}
 

@@ -235,11 +235,11 @@ def test_two_pools_of_unequal_rows_are_booked_and_freed_together():
 
     scfg = C.serving_config(tiny())
     eng = ServingEngine(scfg, seed=2)
-    assert eng.pool.page_rows == (4, 128) and eng.pool.v_page_rows == (4, 128)
-    assert eng.window_pool.page_rows == (8, 128)
+    assert eng.pool.spec.k_rows == eng.pool.spec.v_rows == (4, 128)
+    assert eng.window_pool.spec.k_rows == (8, 128)
     assert eng.pool.k_pages.shape[2:] == (4, BS, 128)
     assert eng.window_pool.k_pages.shape[2:] == (8, BS, 128)
-    assert eng.pool.is_head_major and eng.window_pool.is_head_major
+    assert eng.pool.spec.head_major and eng.window_pool.spec.head_major
     # a dry window pool refuses the admission and books nothing
     hog = eng.window_pool.alloc(eng.window_pool.available())
     req = eng.submit(list(range(1, 20)), 4)
@@ -374,10 +374,9 @@ def test_serving_config_refuses_what_two_pools_cannot_do_yet():
     scfg = C.serving_config(cfg)
     assert scfg.prefix_cache is False and scfg.gqa and scfg.hybrid
     assert scfg.stateful and not scfg.latent
-    assert (scfg.kv_rows("full"), scfg.v_rows("full")) == ((4, 128),
-                                                              (4, 128))
-    assert (scfg.kv_rows("swa"), scfg.v_rows("swa")) == ((8, 128),
-                                                            (8, 128))
+    full, window = scfg.cache_specs()
+    assert (full.k_rows, full.v_rows) == ((4, 128), (4, 128))
+    assert (window.k_rows, window.v_rows) == ((8, 128), (8, 128))
     assert scfg.expert_layers == 6 and scfg.experts_here == (0, 4)
     model = {k: v for k, v in cfg["model"].items() if k != "vocab"}
 

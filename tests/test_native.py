@@ -5,7 +5,11 @@ threaded_engine_test.cc: randomized dependency workloads checked against a
 serial oracle) plus recordio round-trips through the native sharded reader.
 """
 import os
+import shutil
+import subprocess
+import sys
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -220,3 +224,51 @@ def test_engine_naive_fallback():
     eng.push(lambda: out.append(1))
     eng.wait_all()
     assert out == [1]
+
+
+_GET_LIB_TOGETHER = """
+import os, sys, time
+from mxnet_tpu import _native
+src, go = sys.argv[1:]
+_native._SRC_DIR = src
+_native._LIB_PATH = os.path.join(src, "build", "libmxtpu.so")
+open(go + ".%d" % os.getpid(), "w").close()
+while not os.path.exists(go):
+    time.sleep(0.001)
+lib = _native.get_lib()
+print(_native.status(), lib is not None and lib.mxt_pool_in_use() >= 0)
+"""
+
+
+@pytest.mark.skipif(shutil.which("g++") is None or shutil.which("make") is None,
+                    reason="no toolchain")
+def test_processes_that_start_together_build_the_library_once(tmp_path):
+    """Six xdist workers import this file at once in a fresh checkout: one
+    of them runs ``make``, the others wait for it and load what it linked
+    (before, each built over the others' files and a ``CDLL`` of a half-
+    written library made that worker skip its ``needs_native`` cases)."""
+    src = str(tmp_path / "src")
+    shutil.copytree(os.path.dirname(recordio.__file__) + "/src", src,
+                    ignore=shutil.ignore_patterns("build"))
+    go = str(tmp_path / "go")
+    env = dict(os.environ, PYTHONPATH=os.path.dirname(
+        os.path.dirname(recordio.__file__)))
+    env.pop("MXNET_TPU_NO_NATIVE", None)
+    procs = [subprocess.Popen([sys.executable, "-c", _GET_LIB_TOGETHER, src,
+                               go], env=env, stdout=subprocess.PIPE,
+                              text=True) for _ in range(3)]
+    try:
+        deadline = time.time() + 120
+        while not all(os.path.exists("%s.%d" % (go, p.pid)) for p in procs):
+            assert time.time() < deadline, "the processes did not start"
+            assert all(p.poll() is None for p in procs)
+            time.sleep(0.01)
+        open(go, "w").close()
+        said = sorted(p.communicate(timeout=300)[0].split() for p in procs)
+    finally:
+        for p in procs:
+            p.kill()
+    assert said == [["built", "True"], ["loaded", "True"],
+                    ["loaded", "True"]]
+    left = os.listdir(os.path.join(src, "build"))
+    assert "libmxtpu.so" in left and not [f for f in left if ".tmp" in f]

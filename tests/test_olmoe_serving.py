@@ -34,7 +34,6 @@ from mxnet_tpu.ops import moe
 from mxnet_tpu.serving import ServingConfig, ServingEngine
 from mxnet_tpu.serving import engine as E
 from mxnet_tpu.serving import model as M
-from mxnet_tpu.serving.kv_cache import KVBlockPool
 
 from chunk_cases import chunk_equals_single_steps, lane, tables_for
 
@@ -248,8 +247,9 @@ def test_extend_is_four_decode_steps(dtype):
     cfg = tiny(dtype)
     scfg = ServingConfig.from_json(cfg)
     params = weights(cfg)
-    pool = jnp.zeros((2, 9, 8) + KVBlockPool.page_shape(4, 16),
-                     jnp.dtype(dtype))
+    shape, _ = scfg.cache_specs().full.shape(9, 8)
+    assert shape == (2, 9, 8, 4, 16)
+    pool = jnp.zeros(shape, jnp.dtype(dtype))
     prompt = prompts_of([11])[0]
     toks = np.zeros((1, 16), np.int32)
     toks[0, :11] = prompt
@@ -291,7 +291,8 @@ def test_chunk_program_equals_single_steps(chunk):
              lane(2, scfg.max_len - 2, 9), lane(0, 0, 0)]
     tables = tables_for(lanes, nb, scfg.block_size)
     rng = np.random.RandomState(5)
-    shape = (2, 65, 8) + KVBlockPool.page_shape(4, 16)
+    shape, _ = scfg.cache_specs().full.shape(65, 8)
+    assert shape == (2, 65, 8, 4, 16)
     caches = {k: jnp.asarray(rng.randn(*shape), jnp.float32) for k in "kv"}
     step = jax.jit(lambda *a: M.decode_chunk(params, *a, scfg, chunk))
 

@@ -32,7 +32,6 @@ from mxnet_tpu import telemetry
 from mxnet_tpu.serving import ServingConfig, ServingEngine
 from mxnet_tpu.serving import engine as E
 from mxnet_tpu.serving import model as M
-from mxnet_tpu.serving.kv_cache import KVBlockPool
 from mxnet_tpu.serving.obs import loop_records
 
 from chunk_cases import chunk_equals_single_steps, lane, tables_for
@@ -198,8 +197,9 @@ def test_the_verify_pass_is_four_decode_steps(loops, dtype):
     cfg = tiny(dtype, loops)
     scfg = C.serving_config(cfg)
     params = weights(cfg)
-    pool = jnp.zeros((LAYERS, loops * 9, 8) + KVBlockPool.page_shape(4, 16),
-                     jnp.dtype(dtype))
+    shape, _ = scfg.cache_specs().full.shape(9, 8)
+    assert shape == (LAYERS, loops * 9, 8, 4, 16)
+    pool = jnp.zeros(shape, jnp.dtype(dtype))
     prompt = prompts_of([11])[0]
     toks = np.zeros((1, 16), np.int32)
     toks[0, :11] = prompt
@@ -244,7 +244,8 @@ def test_chunk_program_equals_single_steps(chunk, loops):
              lane(2, scfg.max_len - 2, 9), lane(0, 0, 0)]
     tables = tables_for(lanes, nb, scfg.block_size)
     rng = np.random.RandomState(5)
-    shape = (LAYERS, loops * 65, 8) + KVBlockPool.page_shape(4, 16)
+    shape, _ = scfg.cache_specs().full.shape(65, 8)
+    assert shape == (LAYERS, loops * 65, 8, 4, 16)
     caches = {k: jnp.asarray(rng.randn(*shape), jnp.float32) for k in "kv"}
     step = jax.jit(lambda *a: M.decode_chunk(params, *a, scfg, chunk))
 
@@ -309,8 +310,10 @@ def test_the_pool_holds_every_pass_and_cow_copies_every_part():
     cfg = tiny("bfloat16", 4)
     eng = engine(cfg)
     pool = eng.pool
-    assert (pool.num_layers, pool.parts, pool.cache_layers) == (LAYERS, 4, 12)
+    assert (pool.spec.layers, pool.spec.parts, pool.spec.cache_layers) == (
+        LAYERS, 4, 12)
     assert pool.k_pages.shape == (LAYERS, 4 * 33, 8, 4, 16)
+    assert pool.spec.shape(33, 8) == (pool.k_pages.shape, pool.v_pages.shape)
     per_token = 12 * 2 * 4 * 16 * 2             # cache layers x K, V x H hd
     assert pool.block_nbytes() == 8 * per_token
     assert pool.nbytes() == 33 * 8 * per_token \
@@ -465,7 +468,7 @@ def test_what_a_looped_stack_refuses():
         M.ModelConfig(loop_steps=2, num_experts=4, experts_per_tok=2, **base)
     ok = M.ModelConfig(loop_steps=4, post_norm=True, ffn_gated=True,
                        norm="rms", pos="rope", bias=False, **base)
-    assert ok.cache_layers == 8
+    assert ok.cache_specs().full.cache_layers == 8
     names = set(M.param_shapes(ok))
     assert {"early_exit_gate_weight", "early_exit_gate_bias",
             "layer0_ln1_post_gamma", "layer1_ln2_post_gamma"} <= names
@@ -486,7 +489,7 @@ def test_tools_serve_builds_the_engine_from_the_configuration_file():
                                   "ouro-tiny.json"),
         checkpoint=None, seed=3, max_queue=None, default_timeout_ms=None))
     assert (eng.config.loop_steps, eng.config.post_norm,
-            eng.pool.cache_layers) == (3, True, 9)
+            eng.pool.spec.cache_layers) == (3, True, 9)
     assert eng.params["layer0_ln1_post_gamma"].dtype == jnp.bfloat16
     assert "early_exit_gate_weight" in eng.params
     (out,) = eng.generate([[1, 2, 3, 4, 5]], [6])

@@ -1,4 +1,4 @@
-"""The lane-dense KV page format on the CPU: ``KVBlockPool.page_shape``
+"""The lane-dense KV page format on the CPU: ``PageSpec.lane_dense``
 puts ``r`` heads side by side in one page row, and everything that touches a
 page — the references, the Pallas kernels (interpret mode), the model's
 scatter, copy-on-write, the prefix index, the engine — gives what the plain
@@ -13,9 +13,9 @@ import jax.numpy as jnp
 from mxnet_tpu.ops import attention as A
 from mxnet_tpu.serving import ServingConfig, ServingEngine
 from mxnet_tpu.serving import model as smodel
-from mxnet_tpu.serving.kv_cache import KVBlockPool
+from mxnet_tpu.serving.kv_cache import KVBlockPool, PageSpec
 
-# r -> (heads, head_dim) that page_shape packs r to a row
+# r -> (heads, head_dim) that lane_dense packs r to a row
 HEADS = {1: (3, 32), 2: (4, 64), 4: (4, 32)}
 # plain (H, D) rows built by hand, one head a row, as the serving engine's
 # own suites build them (the cases came from tests/test_serving.py and
@@ -38,11 +38,62 @@ DTYPES = {"fp32": (jnp.float32, 1e-6), "bf16": (jnp.bfloat16, 2e-2)}
     (4, 48, (4, 48)),        # 128 % 48 != 0
 ])
 def test_page_shape(heads, head_dim, want):
-    assert KVBlockPool.page_shape(heads, head_dim) == want
-    pool = KVBlockPool(2, 3, 4, heads, head_dim)
+    spec = PageSpec.lane_dense(2, heads, head_dim)
+    assert spec.k_rows == spec.v_rows == want and spec.block_axis == 2
+    pool = KVBlockPool(spec, 3, 4)
     assert pool.k_pages.shape == (2, 3, 4) + want == pool.v_pages.shape
-    assert pool.heads_per_row == heads // want[0]
+    assert spec.shape(3, 4) == (pool.k_pages.shape, pool.v_pages.shape)
+    assert spec.heads_per_row == heads // want[0]
     assert pool.nbytes() == 2 * pool.k_pages.size * 4
+
+
+# name -> (full pool, window pool): each the blocks the engine gives the
+# pool, then k_pages.shape, v_pages.shape, head_major, parts — read off the
+# pools PR 46's engine built for benchmark/configs/<name>.json
+PUBLISHED = {
+    "gpt2-medium-fp32": (
+        (513, (24, 513, 16, 8, 128), (24, 513, 16, 8, 128), False, 1), None),
+    "olmoe-1b-7b-bf16": (
+        (1025, (8, 1025, 64, 16, 128), (8, 1025, 64, 16, 128), False, 1),
+        None),
+    "ouro-2.6b-bf16": (
+        (145, (48, 580, 32, 16, 128), (48, 580, 32, 16, 128), False, 4),
+        None),
+    "phi4-mini-flash-bf16": (
+        (2561, (1, 2561, 10, 64, 128), (1, 2561, 10, 64, 128), True, 1),
+        (641, (8, 641, 10, 64, 128), (8, 641, 10, 64, 128), True, 1)),
+    "dots-vlm1-ep16-bf16": (
+        (2049, (5, 2049, 1, 128, 512), (5, 2049, 1, 128, 128), True, 1),
+        (2, (1, 2, 1, 128, 512), (1, 2, 1, 128, 128), True, 1)),
+    "mimo-v2.5-ep16-bf16": (
+        (5121, (2, 5121, 4, 64, 256), (2, 5121, 4, 64, 128), True, 1),
+        (257, (5, 257, 8, 64, 256), (5, 257, 8, 64, 128), True, 1)),
+}
+
+
+@pytest.mark.parametrize("name", PUBLISHED)
+def test_cache_specs_at_published_widths(name):
+    """``cache_specs()`` is the one family switch: at each serving
+    configuration's published widths it names the pages the engine has
+    always built there. Arithmetic only — no array is allocated."""
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           name + ".json")) as f:
+        cfg = ServingConfig.from_json(json.load(f))
+    assert cfg.num_blocks == PUBLISHED[name][0][0]
+    for spec, want in zip(cfg.cache_specs(), PUBLISHED[name]):
+        if want is None:
+            assert spec is None
+            continue
+        blocks, k_shape, v_shape, head_major, parts = want
+        assert spec.shape(blocks, cfg.block_size) == (k_shape, v_shape)
+        assert (spec.head_major, spec.parts) == (head_major, parts)
+        assert k_shape[spec.block_axis] == cfg.block_size
+        assert spec.block_nbytes(cfg.block_size, 1) * blocks == sum(
+            int(np.prod(shape)) for shape in (k_shape, v_shape))
 
 
 def _rand(r, dtype, lanes, whole_pool, seed=0, B=3, bs=16, N=7, nb=3, L=3,
@@ -238,14 +289,14 @@ def _greedy(cfg, params, prompt, n, pages):
 
 @pytest.mark.parametrize("r", HEADS)
 def test_model_reads_the_format_off_its_pages(r):
-    """``prefill`` and ``decode`` over a pool from ``page_shape`` and over
+    """``prefill`` and ``decode`` over a pool from ``lane_dense`` and over
     pages built ``(H, D)`` by hand: the same logits bit for bit."""
     cfg = smodel.ModelConfig(**_lm(r))
     H, D = HEADS[r]
     params = smodel.as_device_params(smodel.random_params(cfg, seed=5), cfg)
     prompt = list(np.random.RandomState(r).randint(0, cfg.vocab_size, 11))
-    packed = KVBlockPool(cfg.num_layers, 9, 8, H, D)
-    assert packed.heads_per_row == r
+    packed = KVBlockPool(cfg.cache_specs().full, 9, 8)
+    assert packed.spec.heads_per_row == r
     plain = jnp.zeros((cfg.num_layers, 9, 8, H, D), jnp.float32)
     got, got_logits = _greedy(cfg, params, prompt, 6,
                               (packed.k_pages, packed.v_pages))
@@ -265,7 +316,7 @@ def test_engine_serves_and_reports_the_format(r):
     eng = ServingEngine(cfg, seed=5)
     st = eng.stats()
     assert st["kv_heads_per_row"] == r
-    assert st["kv_page_shape"] == list(KVBlockPool.page_shape(H, D))
+    assert st["kv_page_shape"] == list(cfg.cache_specs().full.k_rows)
     assert st["kv_page_shape"] == ([H // r, 128] if r > 1 else [H, D])
     shared = list(range(1, 17))                 # two full blocks
     prompts = [shared + tail for tail in ([], [17], [18, 19])]
@@ -285,11 +336,11 @@ def test_engine_serves_and_reports_the_format(r):
 @pytest.mark.parametrize("r", [2, 4])
 def test_cow_and_prefix_hit_on_a_packed_pool(r):
     H, D = HEADS[r]
-    pool = KVBlockPool(2, 9, 4, H, D)
+    pool = KVBlockPool(PageSpec.lane_dense(2, H, D), 9, 4)
     (b,) = pool.alloc(1)
     rng = np.random.RandomState(0)
     kv = rng.randn(2, 4, H, D).astype(np.float32)
-    rows = kv.reshape((2, 4) + KVBlockPool.page_shape(H, D))
+    rows = kv.reshape((2, 4) + pool.spec.k_rows)
     pool.k_pages = pool.k_pages.at[:, b].set(rows)
     pool.v_pages = pool.v_pages.at[:, b].set(2.0 * rows)
     tokens = [5, 6, 7, 8, 9]
@@ -529,22 +580,26 @@ def test_latent_pool_format():
     """``k_pages`` one ``kv_rank``-wide row a token, ``v_pages`` the rotary
     key's 128-lane row, both head-major (a block is the ``(bs, W)`` slab);
     the bytes are the two arrays'."""
-    pool = KVBlockPool(5, 9, 16, 1, 512, dtype=jnp.bfloat16,
-                       prefix_cache=False, rows=(1, 512), v_rows=(1, 128))
+    cfg = smodel.ModelConfig(
+        97, 5, 64, 8, 32, 256, norm="rms", pos="rope", bias=False,
+        head_dim=128, layer_kinds=["mla"] * 5, q_rank=48, kv_rank=512,
+        rope_dim=64, v_dim=128)
+    spec = cfg.cache_specs().full
+    assert spec == PageSpec(5, (1, 512), (1, 128), True)
+    assert spec.block_axis == 3
+    pool = KVBlockPool(spec, 9, 16, dtype=jnp.bfloat16, prefix_cache=False)
     assert pool.k_pages.shape == (5, 9, 1, 16, 512)
     assert pool.v_pages.shape == (5, 9, 1, 16, 128)
-    assert pool.is_head_major and pool.page_rows == (1, 512)
+    assert spec.shape(9, 16) == (pool.k_pages.shape, pool.v_pages.shape)
+    assert pool.spec is spec
     assert pool.nbytes() == pool.k_pages.nbytes + pool.v_pages.nbytes \
         == 5 * 9 * 16 * 640 * 2
     assert pool.block_nbytes() == 5 * 16 * 640 * 2
-    cfg = smodel.ModelConfig(
-        97, 1, 64, 8, 32, 256, norm="rms", pos="rope", bias=False,
-        head_dim=128, layer_kinds=["mla"], q_rank=48, kv_rank=512,
-        rope_dim=64, v_dim=128)
-    assert cfg.kv_rows() == (1, 512) and cfg.v_rows() == (1, 128)
+    # the window stand-in of a model without "swa" layers: one layer
+    assert cfg.cache_specs().window == PageSpec(1, (1, 512), (1, 128), True)
     # every other pool keeps two arrays of one shape and its old bytes
-    plain = KVBlockPool(2, 3, 4, 16, 64)
-    assert plain.v_page_rows == plain.page_rows == (8, 128)
+    plain = KVBlockPool(PageSpec.lane_dense(2, 16, 64), 3, 4)
+    assert plain.spec.v_rows == plain.spec.k_rows == (8, 128)
     assert plain.nbytes() == 2 * plain.k_pages.size * 4
 
 

@@ -37,7 +37,8 @@ from mxnet_tpu.serving import (EngineSupervisor, KVCacheOOM, ServingConfig,
                                ServingEngine)
 from mxnet_tpu.serving import engine as E
 from mxnet_tpu.serving import model as M
-from mxnet_tpu.serving.kv_cache import KVBlockPool, StateSlots, StreamState
+from mxnet_tpu.serving.kv_cache import (KVBlockPool, PageSpec, StateSlots,
+                                        StreamState)
 from mxnet_tpu.serving.scheduler import FINISHED, Request
 
 from chunk_cases import chunk_equals_single_steps, lane, tables_for
@@ -355,10 +356,46 @@ def test_a_long_prompt_keeps_only_its_tail_in_the_window_pool():
     assert req.state == FINISHED
 
 
+@pytest.mark.parametrize("kv_heads,rows,head_major", [
+    (20, (10, 128), True),      # ten rows do not fill their sublane tiles
+    (16, (8, 128), False)])     # eight do: the block stays token-major
+def test_a_model_with_kinds_is_served_in_either_block_order(kv_heads, rows,
+                                                            head_major):
+    """The order of a block is the spec's, and the pools and the step
+    programs read it there: a prefill and two decode steps through a full
+    and a window pool of either order give the tokens the prefill program
+    gives over the whole text."""
+    cfg = tiny()
+    cfg["model"].update(num_layers=3, layer_kinds=["swa", "full", "cross"],
+                        num_heads=kv_heads, num_kv_heads=kv_heads)
+    scfg = C.serving_config(cfg)
+    full, window = scfg.cache_specs()
+    assert full.k_rows == full.v_rows == window.k_rows == rows
+    assert full.head_major is window.head_major is head_major
+    assert full.block_axis == (3 if head_major else 2)
+    eng = ServingEngine(scfg, arg_params=C.init_params(cfg, 3), seed=3)
+    assert eng.pool.k_pages.shape == (
+        (1, 65, 10, BS, 128) if head_major else (1, 65, BS, 8, 128))
+    for pool, spec in ((eng.pool, full), (eng.window_pool, window)):
+        assert spec.shape(pool.num_blocks, BS) == (pool.k_pages.shape,
+                                                   pool.v_pages.shape)
+        assert pool.k_pages.shape[spec.block_axis] == BS
+        assert pool.spec == spec
+    text = [int(t) for t in
+            np.random.RandomState(kv_heads).randint(0, VOCAB, 40)]
+    req = eng.submit(text, 3)
+    _drain(eng)
+    assert req.state == FINISHED and len(req.generated) == 3
+    for tok in req.generated:
+        assert tok == int(np.argmax(eng.prefill_logits(text)))
+        text.append(tok)
+
+
 def test_admission_is_atomic_over_the_three_kinds():
     """Each kind short in turn: the head waits with NOTHING booked, and is
     admitted once the kind is there."""
-    wpool = KVBlockPool(1, 4, BS, 1, 128, rows=(1, 128), gauges=False)
+    wpool = KVBlockPool(PageSpec.tiled(1, (1, 128)), 4, BS, gauges=False)
+    assert wpool.k_pages.shape == (1, 4, 1, BS, 128) and wpool.spec.head_major
     slots = StateSlots(1, 2, 8, (16, 128))
     st = StreamState(wpool, slots, WINDOW)
     a, b = Request([1] * 20, 4), Request([1] * 20, 4)
@@ -566,7 +603,8 @@ def test_one_block_models_keep_their_keys_and_shapes():
     scfg = C.serving_config(tiny())
     # the fields there were before a one-block model's loop_steps
     assert len(scfg.key()) == M.ModelConfig._KIND_FIELDS == 38
-    assert scfg.kv_rows() == (1, 128) and scfg.memory_layer == 4
+    assert scfg.cache_specs().full.k_rows == (1, 128)
+    assert scfg.memory_layer == 4
     shapes = M.param_shapes(scfg)
     assert "lm_head_weight" not in shapes and "pos_embed_weight" not in shapes
     assert shapes["layer0_ssm_a_log"] == (16, 128)
@@ -581,9 +619,9 @@ def test_one_block_models_keep_their_keys_and_shapes():
         full = C.serving_config(json.load(f))
     count = sum(int(np.prod(s)) for s in M.param_shapes(full).values())
     assert 3.84e9 < count < 3.86e9
-    assert full.kv_rows() == (10, 128)
-    assert KVBlockPool.head_major(10, 128)
-    assert not KVBlockPool.head_major(8, 128)
-    assert not KVBlockPool.head_major(16, 128)
+    assert full.cache_specs().full.k_rows == (10, 128)
+    assert PageSpec.tiled(1, (10, 128)).head_major
+    assert not PageSpec.tiled(1, (8, 128)).head_major
+    assert not PageSpec.tiled(1, (16, 128)).head_major
     assert full.layers_of("mamba") == list(range(0, 17, 2))
     assert full.layers_of("full") == [17] and full.memory_layer == 16

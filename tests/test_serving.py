@@ -30,6 +30,7 @@ from mxnet_tpu.ops import attention as A  # noqa: E402
 from mxnet_tpu.serving import (  # noqa: E402
     KVBlockPool, KVCacheOOM, Request, Scheduler, ServingConfig, ServingEngine)
 from mxnet_tpu.serving import model as smodel  # noqa: E402
+from mxnet_tpu.serving.kv_cache import PageSpec  # noqa: E402
 
 from chunk_cases import (  # noqa: E402
     chunk_equals_single_steps, lane, tables_for)
@@ -44,6 +45,8 @@ tlm = importlib.import_module("mxnet_tpu.models.transformer_lm")
 CFG = dict(vocab_size=23, num_layers=2, model_dim=32, num_heads=2,
            ffn_dim=48, max_len=64)
 SEED = 3
+# the allocator's cases: one layer of two heads of 8 a token
+SPEC = PageSpec.lane_dense(1, 2, 8)
 
 
 def _config(**over):
@@ -216,8 +219,8 @@ def test_paged_decode_matches_contiguous_decode_probs():
     ex = _decode_executor(params_np)
     for a in ex.aux_dict.values():
         a[:] = 0
-    pool = KVBlockPool(cfg.num_layers, cfg.num_blocks, cfg.block_size,
-                       cfg.num_heads, cfg.model_dim // cfg.num_heads)
+    pool = KVBlockPool(cfg.cache_specs().full, cfg.num_blocks,
+                       cfg.block_size)
     nb_max = cfg.max_len // cfg.block_size
     blocks = pool.alloc(nb_max)
     table = np.zeros((1, nb_max), np.int32)
@@ -276,8 +279,8 @@ def test_paged_overflow_cannot_corrupt_pool():
     cfg = _config()
     params = smodel.as_device_params(smodel.random_params(cfg, seed=SEED),
                                      cfg)
-    pool = KVBlockPool(cfg.num_layers, cfg.num_blocks, cfg.block_size,
-                       cfg.num_heads, cfg.model_dim // cfg.num_heads)
+    pool = KVBlockPool(cfg.cache_specs().full, cfg.num_blocks,
+                       cfg.block_size)
     nb_max = cfg.max_len // cfg.block_size
     table = np.zeros((1, nb_max), np.int32)
     table[0] = pool.alloc(nb_max)
@@ -304,7 +307,7 @@ def test_paged_overflow_cannot_corrupt_pool():
 
 
 def test_pool_alloc_free_accounting():
-    pool = KVBlockPool(1, 9, 4, 2, 8)
+    pool = KVBlockPool(SPEC, 9, 4)
     assert pool.num_usable == 8
     assert pool.available() == 8
     a = pool.alloc(3)
@@ -323,7 +326,7 @@ def test_pool_alloc_free_accounting():
 def test_pool_oom_is_atomic():
     """A failed alloc takes NOTHING (no partial grab), raises classified
     KVCacheOOM, and bumps the always-on failure counter."""
-    pool = KVBlockPool(1, 5, 4, 2, 8)
+    pool = KVBlockPool(SPEC, 5, 4)
     pool.alloc(2)
     fails0 = telemetry.counter("serving.kv_blocks_alloc_failures").value
     with pytest.raises(KVCacheOOM):
@@ -336,7 +339,7 @@ def test_pool_oom_is_atomic():
 
 
 def test_pool_double_free_and_bad_ids_rejected():
-    pool = KVBlockPool(1, 5, 4, 2, 8)
+    pool = KVBlockPool(SPEC, 5, 4)
     a = pool.alloc(2)
     pool.free(a)
     with pytest.raises(ValueError, match="double free"):
@@ -348,7 +351,7 @@ def test_pool_double_free_and_bad_ids_rejected():
 
 
 def test_blocks_for():
-    pool = KVBlockPool(1, 5, 8, 2, 8)
+    pool = KVBlockPool(SPEC, 5, 8)
     assert pool.blocks_for(1) == 1
     assert pool.blocks_for(8) == 1
     assert pool.blocks_for(9) == 2
@@ -363,7 +366,7 @@ def test_blocks_for():
 def test_scheduler_fcfs_admission_no_skip_ahead():
     """Under mixed load the waiting queue admits head-first: a short prompt
     arriving later can NEVER overtake a long one blocked on blocks."""
-    pool = KVBlockPool(1, 6, 4, 2, 8)   # 5 usable blocks
+    pool = KVBlockPool(SPEC, 6, 4)   # 5 usable blocks
     sched = Scheduler(pool, max_batch=8, prefills_per_step=8)
     big = Request([1] * 16, 4)          # 16 tokens + decode slot = 5 blocks
     sched.add(big)
@@ -392,7 +395,7 @@ def test_scheduler_preempts_youngest_and_replays():
     """Pool exhaustion preempts the LATEST-admitted stream: its blocks come
     back, its tokens-so-far become the replay prompt at the head of the
     queue, and the victim's output stream is preserved."""
-    pool = KVBlockPool(1, 6, 4, 2, 8)   # 5 usable
+    pool = KVBlockPool(SPEC, 6, 4)   # 5 usable
     sched = Scheduler(pool, max_batch=4, prefills_per_step=4)
     old = Request([1] * 7, 8)           # 2 blocks (7 tokens + decode slot)
     young = Request([2] * 8, 8)         # 3 blocks (8 tokens + decode slot)
@@ -426,7 +429,7 @@ def test_scheduler_preempts_youngest_and_replays():
 
 
 def test_scheduler_lone_oversized_request_fails_not_wedges():
-    pool = KVBlockPool(1, 3, 4, 2, 8)   # 2 usable blocks = 8 slots
+    pool = KVBlockPool(SPEC, 3, 4)   # 2 usable blocks = 8 slots
     sched = Scheduler(pool, max_batch=4, prefills_per_step=4)
     req = Request([1] * 8, 4)   # 8-token replay + decode slot = 3 blocks
     sched.add(req)
@@ -610,8 +613,8 @@ def test_chunk_program_equals_single_steps(chunk):
              lane(0, 0, 0)]                  # a padded row
     tables = tables_for(lanes, nb, cfg.block_size)
     rng = np.random.RandomState(5)
-    shape = (cfg.num_layers, cfg.num_blocks, cfg.block_size) \
-        + KVBlockPool.page_shape(cfg.num_heads, cfg.head_dim)
+    shape, _ = cfg.cache_specs().full.shape(cfg.num_blocks, cfg.block_size)
+    assert shape == (2, 64, 8, 2, 16)
     caches = {k: jnp.asarray(rng.randn(*shape), jnp.float32) for k in "kv"}
     step = jax.jit(lambda *a: smodel.decode_chunk(params, *a, cfg, chunk))
 

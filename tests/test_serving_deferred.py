@@ -556,12 +556,12 @@ def test_mxu_share_is_read_off_what_is_already_counted(name, share, telem):
     assert eng._pending is not None
     paged = eng.stats()["paged"]
     assert paged["live_blocks"] > 0
-    assert paged["mxu_share"] == share == float(eng.pool.is_head_major)
+    assert paged["mxu_share"] == share == float(eng.pool.spec.head_major)
     assert telem.gauge("serving.paged.mxu_share").value == share
     if name == "hybrid":            # the window pool's walks are in it
         sums = eng.stats()["loop"]["sums"]
         assert sums["window_live_blocks"] > 0
-        assert eng.streams.pool.is_head_major
+        assert eng.streams.pool.spec.head_major
         # the keys a walk read are plain grouped-query attention's to
         # book: this model's decode steps pay for none of it
         assert "hybrid" not in eng.stats()

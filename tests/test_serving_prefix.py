@@ -20,6 +20,7 @@ from mxnet_tpu import telemetry  # noqa: E402
 from mxnet_tpu.serving import (  # noqa: E402
     KVBlockPool, KVCacheOOM, Request, Scheduler, ServingConfig, ServingEngine)
 from mxnet_tpu.serving import model as smodel  # noqa: E402
+from mxnet_tpu.serving.kv_cache import PageSpec  # noqa: E402
 
 pytestmark = pytest.mark.serving
 
@@ -41,9 +42,9 @@ def _pool(**over):
     kw = dict(num_layers=1, num_blocks=9, block_size=4, num_heads=2,
               head_dim=8)
     kw.update(over)
-    return KVBlockPool(kw.pop("num_layers"), kw.pop("num_blocks"),
-                       kw.pop("block_size"), kw.pop("num_heads"),
-                       kw.pop("head_dim"), **kw)
+    spec = PageSpec.lane_dense(kw.pop("num_layers"), kw.pop("num_heads"),
+                               kw.pop("head_dim"))
+    return KVBlockPool(spec, kw.pop("num_blocks"), kw.pop("block_size"), **kw)
 
 
 def _decode_executor(params):
@@ -142,8 +143,9 @@ def test_cow_shared_block_copies_pages_bit_exactly():
     pool = _pool()
     (b,) = pool.alloc(1)
     rng = np.random.RandomState(0)
-    kv = rng.randn(pool.num_layers, pool.block_size, pool.num_heads,
-                   pool.head_dim).astype(pool.dtype)
+    assert pool.spec.shape(9, 4) == ((1, 9, 4, 2, 8),) * 2
+    kv = rng.randn(*pool.k_pages.shape[:1] + pool.k_pages.shape[2:]).astype(
+        pool.dtype)
     pool.k_pages = pool.k_pages.at[:, b].set(kv)
     pool.v_pages = pool.v_pages.at[:, b].set(2.0 * kv)
     pool.incref([b])

@@ -37,6 +37,7 @@ from mxnet_tpu.serving import (  # noqa: E402
     CANCELLED, FAILED, FINISHED, TIMED_OUT, EngineSupervisor, KVBlockPool,
     KVCacheOOM, Request, Scheduler, ServingConfig, ServingEngine,
     ServingOverloadError, retry_after_s)
+from mxnet_tpu.serving.kv_cache import PageSpec  # noqa: E402
 
 pytestmark = pytest.mark.serving
 
@@ -158,8 +159,8 @@ def test_cancel_running_and_waiting_requests(telem):
 
 
 def test_scheduler_sweep_is_a_unit(telem):
-    pool = KVBlockPool(num_layers=1, num_blocks=8, block_size=8,
-                       num_heads=1, head_dim=4)
+    pool = KVBlockPool(PageSpec.lane_dense(1, 1, 4), num_blocks=8,
+                       block_size=8)
     sched = Scheduler(pool, max_batch=4)
     fresh = Request([1], 4, timeout_s=60.0)
     stale = Request([2], 4, timeout_s=60.0)
