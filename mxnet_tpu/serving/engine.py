@@ -35,6 +35,7 @@ import numpy as np
 from .. import compile_cache, compileobs, fault, telemetry
 from ..analysis import witness
 from ..base import env_bool, env_int, env_str
+from ..ops.kda import CHUNK as _KDA_CHUNK
 from . import model as _model
 from .kv_cache import KVBlockPool
 from .obs import ServingObs, open_record
@@ -85,8 +86,8 @@ class ServingConfig(_model.ModelConfig):
 
     A model with "swa" layers has a second pool for them, sized for
     ``max_batch`` streams of ``window + block_size`` tokens and the trash
-    block; one with "mamba" layers ``max_batch`` state slots and the trash
-    slot (``_build_caches``). ``num_blocks`` stays the full-length
+    block; one with "mamba" or "kda" layers ``max_batch`` state slots and
+    the trash slot (``_build_caches``). ``num_blocks`` stays the full-length
     pool's."""
 
     __slots__ = ("block_size", "num_blocks", "max_batch",
@@ -149,6 +150,20 @@ class ServingConfig(_model.ModelConfig):
                 "max_len (%d) must be a multiple of block_size (%d): "
                 "prefill buckets and the decode block table are sized in "
                 "whole blocks" % (self.max_len, self.block_size))
+        if self.linear and (self.prefix_cache or self.spec_k):
+            raise ValueError(
+                "%s needs what a model with 'kda' layers does not have yet: "
+                "%s" % (("prefix_cache", "a snapshot of every linear layer's "
+                         "matrix state (heads x keys x values, float32) and "
+                         "conv tail at a block boundary, to start a stream "
+                         "from a shared prefix: the state after a prefix is "
+                         "no function of any block's K/V")
+                        if self.prefix_cache
+                        else ("spec_k > 0", "a verify pass over state: "
+                              "several tokens a stream through the delta "
+                              "rule with the state kept at each, and the "
+                              "state put back when a speculated window is "
+                              "rejected")))
         if self.gqa and (self.prefix_cache or self.spec_k):
             # before the two refusals of a stateful model: they would say
             # less, and a model of "full" layers alone passes them
@@ -358,6 +373,11 @@ class ServingEngine:
         # latent attention: what the decode kernel read
         self._latent = {"ctx_tokens": 0, "lane_steps": 0, "live_blocks": 0,
                         "prefill_tokens": 0}
+        # linear attention: the states the decode steps rewrote, the rows
+        # and chunks the prefill kernel took, a "kda" layer each
+        self._linear = {"state_updates": 0, "prefill_tokens": 0,
+                        "prefill_chunks": 0}
+        self._linear_layers = len(cfg.layers_of("kda"))
         self._moe_layer_steps = 0
         self._moe_layer_tokens = 0
         self._moe_touched = 0
@@ -1325,7 +1345,7 @@ class ServingEngine:
         model with ``layer_kinds`` — one cache layer a "full" layer: the
         "cross" layers read it and have none of their own — has beside it
         the window pool of its "swa" layers, the state slots of its
-        "mamba" layers, and the manager that books a stream's share of
+        "mamba" or "kda" layers, and the manager that books a stream's share of
         the two."""
         from .kv_cache import StateSlots, StreamState
 
@@ -1336,7 +1356,7 @@ class ServingEngine:
         if not cfg.hybrid:
             return pool, None, None, None
         n_win = len(cfg.layers_of("swa"))
-        n_ssm = len(cfg.layers_of("mamba"))
+        n_ssm = len(cfg.layers_of("mamba", "kda"))
         # max_batch streams of window + one block of tokens and the slots
         # a decode chunk writes beyond its first, max_batch slots, and the
         # trash of each: running <= max_batch, so neither runs short. A
@@ -1350,7 +1370,7 @@ class ServingEngine:
             prefix_cache=False, gauges=False)
         state = StateSlots(
             max(n_ssm, 1), cfg.max_batch + 1 if n_ssm else 2,
-            (cfg.ssm_conv - 1) * cfg.d_inner, (cfg.ssm_state, cfg.d_inner),
+            *cfg.slot_shapes(),
             conv_dtype=self.params["embed_weight"].dtype, device=device)
         streams = StreamState(window_pool if n_win else None,
                               state if n_ssm else None, cfg.window)
@@ -1545,7 +1565,14 @@ class ServingEngine:
             if self._rec is not None:
                 self._rec["prefill_tokens"] += L
                 self._rec["prefill_rows"] += args["bucket"]
-            if self.streams is not None and self.streams.slots is not None:
+            if cfg.linear:
+                for name, n in (
+                        ("prefill_tokens", L * self._linear_layers),
+                        ("prefill_chunks",
+                         -(-L // _KDA_CHUNK) * self._linear_layers)):
+                    self._linear[name] += n
+                    telemetry.counter("serving.linear." + name).inc(n)
+            elif self.streams is not None and self.streams.slots is not None:
                 telemetry.counter("serving.ssm.prefill_tokens").inc(L)
             # register this prefix's full blocks for later admissions
             # (first writer wins; the blocks it itself mapped shared are
@@ -1942,7 +1969,11 @@ class ServingEngine:
         keys those walks read (``stats()["hybrid"]``). Returns what the
         spans and the step's record take."""
         cfg = self.config
-        if self.streams.slots is not None:
+        if cfg.linear:
+            n = len(ctx) * self._linear_layers
+            self._linear["state_updates"] += n
+            telemetry.counter("serving.linear.state_updates").inc(n)
+        elif self.streams.slots is not None:
             telemetry.counter("serving.ssm.stream_steps").inc(len(ctx))
         first = np.maximum(ctx - cfg.window, 0) // cfg.block_size
         window_live = int((-(-ctx // cfg.block_size) - first).sum()) \
@@ -2215,6 +2246,12 @@ class ServingEngine:
                 # only for a model with "mla" layers
                 **({"latent": dict(self._latent)}
                    if self.config.latent else {}),
+                # only for a model with "kda" layers
+                **({"linear": dict(
+                    self._linear, layers=self._linear_layers,
+                    slots_used=self.streams.slots.used(),
+                    slot_bytes=self.streams.slots.slot_nbytes())}
+                   if self.config.linear else {}),
                 # only for a model with experts
                 **({"moe": {
                     "num_experts": self.config.num_experts,

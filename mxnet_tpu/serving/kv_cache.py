@@ -497,17 +497,21 @@ class KVBlockPool:
 
 
 class StateSlots:
-    """Per-stream recurrent state of the "mamba" layers: for each of ``Ls``
-    layers a conv tail ``((K - 1) Dn,)`` in the activations' type and an
-    SSM state ``(N, Dn)`` in float32, ``num_slots`` of each. A stream
-    holds ONE slot for all its layers, fixed in size whatever its length;
-    the step programs update the slots in place (donated, aliased). Slot 0
-    is the trash slot: padded batch rows and scratch programs point at it.
+    """Per-stream recurrent state of the "mamba" layers, or of the "kda"
+    layers: for each of ``Ls`` layers a conv tail ``((K - 1) Dn,)`` in the
+    activations' type and a state in float32, ``num_slots`` of each
+    (``ModelConfig.slot_shapes`` names the two). A stream holds ONE slot for
+    all its layers, fixed in size whatever its length; the step programs
+    update the slots in place (donated, aliased). Slot 0 is the trash slot:
+    padded batch rows and scratch programs point at it.
 
     The conv tails are ``(Ls, NS, (K - 1) Dn)``: a slot is a row, so that
-    the three-row tail is not padded to a sixteen-row tile. The states are
-    ``(Ls, NS, N, Dn)``: states along the sublanes, channels along the
-    lanes (``ops/ssm.py``)."""
+    the three-row tail is not padded to a sixteen-row tile. A "mamba"
+    layer's states are ``(Ls, NS, N, Dn)``: states along the sublanes,
+    channels along the lanes (``ops/ssm.py``); a "kda" layer's ``(Ls, NS, H,
+    dk, dv)``: a head's matrix is whole tiles, the values along the lanes
+    (``ops/kda.py``), and its tail holds the q, k and v projections' rows
+    side by side."""
 
     def __init__(self, num_layers, num_slots, conv_width, state_shape,
                  conv_dtype=np.float32, device=None):
@@ -569,7 +573,8 @@ class StreamState:
     its full-pool blocks, booked by one manager so that admission is
     atomic over the three kinds:
 
-    * a state slot (:class:`StateSlots`), if the model has "mamba" layers;
+    * a state slot (:class:`StateSlots`), if the model has "mamba" or "kda"
+      layers;
     * window-pool blocks ``req.wblocks``, by position like ``req.blocks``
       but only for the last ``window`` keys: entry ``j`` is the block of
       positions ``j * bs ..``, or 0 once it lies wholly behind the window

@@ -37,6 +37,7 @@ import numpy as np
 
 from ..ops.attention import (flash_attention, flash_attention_gqa,
                              latent_paged, paged_attention_multi)
+from ..ops.kda import kda_chunk, kda_step
 from ..ops.moe import moe_ffn
 from ..ops.registry import fp32_precision
 from ..ops.ssm import ssm_scan, ssm_step
@@ -171,6 +172,26 @@ class ModelConfig:
                     denominator and no numerator
     value_scale     the heads' results are multiplied by it
 
+    attn_gate       the heads' results are multiplied elementwise by
+                    ``sigmoid(W_gate h)`` (``layer%d_attn_gate_weight``)
+                    before the output projection
+
+    A "gqa" model may also have layers of LINEAR attention beside its "full"
+    (and "swa") ones:
+
+    ``"kda"``    a gated delta-rule layer (Kimi Delta Attention,
+                 ``ops/kda.py``): q, k and v each through a causal depthwise
+                 conv of ``kda_conv`` taps and SiLU, ``kda_heads`` heads of
+                 ``kda_head_dim`` keys (q and k L2-normalised a head) and as
+                 many values; a log decay a key channel through a
+                 rank-``kda_head_dim`` pair, ``-exp(A_log) softplus(. +
+                 dt_bias)``; a step size ``beta = sigmoid(W_b h)`` a head,
+                 doubled where ``kda_neg_eigval``; per stream a conv tail of
+                 the three projections and a float32 state ``(heads, keys,
+                 values)`` in the stream's slot, no K/V; the heads' results
+                 RMS-normed a head and gated by a sigmoid through a second
+                 pair of that rank
+
     ``max_len`` bounds every stream's total length (the position table's
     rows, or the positions the rotary model was trained for)."""
 
@@ -184,7 +205,9 @@ class ModelConfig:
                  "dense_ffn_dim", "shared_experts", "router", "n_group",
                  "topk_group", "route_scale", "experts_held", "loop_steps",
                  "post_norm", "early_exit_threshold", "attn_form",
-                 "swa_kv_heads", "swa_rope_theta", "swa_sink", "value_scale")
+                 "swa_kv_heads", "swa_rope_theta", "swa_sink", "value_scale",
+                 "attn_gate", "kda_heads", "kda_head_dim", "kda_conv",
+                 "kda_neg_eigval")
     #: the fields of one-block models: their ``key()`` is these alone, so
     #: that the programs' cache keys are what they were before ``layer_kinds``
     _BLOCK_FIELDS = 14
@@ -198,8 +221,12 @@ class ModelConfig:
     #: key behind the thirty-eight
     _FORM_FIELDS = ("attn_form", "swa_kv_heads", "swa_rope_theta",
                     "swa_sink", "value_scale")
+    #: a "gqa" model with "kda" layers or an output gate has these behind
+    #: those
+    _LINEAR_FIELDS = ("attn_gate", "kda_heads", "kda_head_dim", "kda_conv",
+                      "kda_neg_eigval")
     #: what ``layer_kinds`` may name
-    KINDS = ("mamba", "swa", "full", "cross", "gmu", "mla")
+    KINDS = ("mamba", "swa", "full", "cross", "gmu", "mla", "kda")
 
     def __init__(self, vocab_size=32000, num_layers=4, model_dim=256,
                  num_heads=4, ffn_dim=1024, max_len=128, norm="layer",
@@ -214,7 +241,8 @@ class ModelConfig:
                  route_scale=1.0, experts_held=None, loop_steps=1,
                  post_norm=False, early_exit_threshold=1.0, attn_form="diff",
                  swa_kv_heads=None, swa_rope_theta=None, swa_sink=False,
-                 value_scale=1.0):
+                 value_scale=1.0, attn_gate=False, kda_heads=None,
+                 kda_head_dim=None, kda_conv=4, kda_neg_eigval=False):
         self.vocab_size = int(vocab_size)
         self.num_layers = int(num_layers)
         self.model_dim = int(model_dim)
@@ -270,6 +298,13 @@ class ModelConfig:
                                     is not None else self.rope_theta)
         self.swa_sink = bool(swa_sink)
         self.value_scale = float(value_scale)
+        self.attn_gate = bool(attn_gate)
+        self.kda_heads = int(kda_heads if kda_heads is not None
+                             else self.num_heads)
+        self.kda_head_dim = int(kda_head_dim if kda_head_dim is not None
+                                else self.head_dim)
+        self.kda_conv = int(kda_conv)
+        self.kda_neg_eigval = bool(kda_neg_eigval)
         if self.norm not in ("layer", "rms"):
             raise ValueError("norm must be 'layer' or 'rms', not %r" % norm)
         if self.pos not in ("learned", "rope", "none"):
@@ -338,11 +373,11 @@ class ModelConfig:
         if self.layer_kinds is None:
             if (self.num_kv_heads != self.num_heads or self.pos == "none"
                     or self.tie_embed or self.attn_bias or by_layer
-                    or self.gqa):
+                    or self.gqa or self.attn_gate):
                 raise ValueError(
                     "num_kv_heads, pos='none', tie_embed, attn_bias, "
-                    "rope_yarn, attn_form and an FFN that differs by layer "
-                    "belong to a model with layer_kinds")
+                    "rope_yarn, attn_form, attn_gate and an FFN that differs "
+                    "by layer belong to a model with layer_kinds")
             return
         if len(kinds) != self.num_layers or set(kinds) - set(self.KINDS):
             raise ValueError("layer_kinds must name each of the %d layers "
@@ -364,6 +399,9 @@ class ModelConfig:
             raise ValueError("of the layer kinds only 'mla' has rotary "
                              "position (and 'swa' / 'full' where attn_form "
                              "is 'gqa')")
+        if not self.gqa and ("kda" in kinds or self.attn_gate):
+            raise ValueError("'kda' layers and attn_gate belong to a model "
+                             "whose attn_form is 'gqa'")
         if set(kinds) & {"swa", "full", "cross"} and not self.gqa:
             if self.num_heads % 2 or self.num_kv_heads % 2 or \
                     (self.num_heads // 2) % (self.num_kv_heads // 2):
@@ -382,10 +420,14 @@ class ModelConfig:
                                  "before it" % i)
 
     def _check_gqa(self, kinds):
-        if set(kinds) - {"swa", "full"}:
-            raise ValueError("attn_form 'gqa' takes 'swa' and 'full' layers "
-                             "alone, not %r" % (kinds,))
-        for kind in set(kinds):
+        if set(kinds) - {"swa", "full", "kda"}:
+            raise ValueError("attn_form 'gqa' takes 'swa', 'full' and 'kda' "
+                             "layers alone, not %r" % (kinds,))
+        if "kda" in kinds and (self.kda_conv < 2 or min(
+                self.kda_heads, self.kda_head_dim) < 1):
+            raise ValueError("'kda' layers need kda_conv >= 2 taps and "
+                             "positive kda_heads and kda_head_dim")
+        for kind in set(kinds) - {"kda"}:
             if self.num_heads % self.kv_heads_of(kind):
                 raise ValueError(
                     "%d query heads do not share %d K/V heads of a %r layer"
@@ -430,7 +472,32 @@ class ModelConfig:
         """Per-stream state that no block-aligned prefix determines (a
         recurrent state, a window that has slid): no prefix sharing, no
         roll-back of a speculated window."""
-        return bool(self.layers_of("mamba", "swa"))
+        return bool(self.layers_of("mamba", "swa", "kda"))
+
+    @property
+    def linear(self):
+        """The model has "kda" layers: a matrix state a head in the stream's
+        slot, rewritten every step."""
+        return bool(self.layers_of("kda"))
+
+    @property
+    def kda_channels(self):
+        """Channels of a "kda" layer's conv: q, k and v side by side."""
+        return 3 * self.kda_heads * self.kda_head_dim
+
+    def slot_shapes(self):
+        """``(conv_width, state_shape)`` of one layer's part of a stream's
+        state slot (``StateSlots``): the conv tail's values, in the
+        activations' type, and the float32 state — a "mamba" layer's ``(N,
+        Dn)`` (states along the sublanes, channels along the lanes), a "kda"
+        layer's ``(heads, keys, values)`` (a head is whole ``(8, 128)`` tiles,
+        the values along the lanes). A model with neither keeps the "mamba"
+        shapes for its two-slot stand-in."""
+        if self.linear:
+            return ((self.kda_conv - 1) * self.kda_channels,
+                    (self.kda_heads, self.kda_head_dim, self.kda_head_dim))
+        return ((self.ssm_conv - 1) * self.d_inner,
+                (self.ssm_state, self.d_inner))
 
     @property
     def latent(self):
@@ -520,7 +587,9 @@ class ModelConfig:
         its first fourteen fields, as before there were others, and what
         it sets of ``_BLOCK_EXTRAS`` behind them if it sets any; a model
         with kinds' is the thirty-eight it always was, with ``_FORM_FIELDS``
-        behind them where ``attn_form`` is not "diff"."""
+        behind them where ``attn_form`` is not "diff" and
+        ``_LINEAR_FIELDS`` behind those where it has "kda" layers or an
+        output gate."""
         names = ModelConfig.__slots__[:self._KIND_FIELDS]
         if not self.hybrid:
             names = names[:self._BLOCK_FIELDS]
@@ -528,6 +597,8 @@ class ModelConfig:
                 names += tuple(k for k, _v in self._BLOCK_EXTRAS)
         elif self.gqa:
             names += self._FORM_FIELDS
+            if self.linear or self.attn_gate:
+                names += self._LINEAR_FIELDS
         return tuple(getattr(self, k) for k in names)
 
     def _slot_names(self):
@@ -636,12 +707,28 @@ def _mixer_shapes(cfg, kind, p):
                 # a head's rows: its key part without position, its value
                 p + "_mla_kv_up_weight": (h * (hd + dv), cfg.kv_rank),
                 p + "_attn_out_weight": (m, h * dv)}
+    if kind == "kda":
+        # keys and values are one width, which is both gate pairs' rank
+        r = cfg.kda_head_dim
+        hk = hv = cfg.kda_heads * r
+        return {p + "_kda_in_weight": (cfg.kda_channels, m),    # [q; k; v]
+                p + "_kda_conv_weight": (cfg.kda_conv, cfg.kda_channels),
+                p + "_kda_f1_weight": (r, m), p + "_kda_f2_weight": (hk, r),
+                p + "_kda_a_log": (cfg.kda_heads,),
+                p + "_kda_dt_bias": (hk,),
+                p + "_kda_b_weight": (cfg.kda_heads, m),
+                p + "_kda_g1_weight": (r, m), p + "_kda_g2_weight": (hv, r),
+                p + "_kda_g_bias": (hv,),
+                p + "_kda_norm_gamma": (r,),
+                p + "_kda_out_weight": (m, hv)}
     if cfg.gqa:
         hk, dv = cfg.kv_heads_of(kind), cfg.v_dim
         shapes = {p + "_attn_in_weight": (hq + hk * (hd + dv), m),
                   p + "_attn_out_weight": (m, cfg.num_heads * dv)}
         if kind == "swa" and cfg.swa_sink:
             shapes[p + "_attn_sink"] = (cfg.num_heads,)
+        if cfg.attn_gate:
+            shapes[p + "_attn_gate_weight"] = (cfg.num_heads * dv, m)
         return shapes
     shapes = {p + "_attn_out_weight": (m, hq),
               p + "_diff_norm_gamma": (2 * hd,)}
@@ -670,11 +757,13 @@ def random_params(cfg, seed=0, dtype=np.float32):
     for name, shape in sorted(param_shapes(cfg).items()):
         if name.endswith(("_gamma", "_ssm_d")):
             out[name] = np.ones(shape, dtype)
-        elif name.endswith("_ssm_dt_bias"):
-            # Mamba's init: dt log-uniform in [1e-3, 1e-1], through the
-            # inverse of the softplus
+        elif name.endswith(("_ssm_dt_bias", "_kda_dt_bias")):
+            # Mamba's init (and the delta rule's): dt log-uniform in [1e-3,
+            # 1e-1], through the inverse of the softplus
             dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), shape))
             out[name] = (dt + np.log(-np.expm1(-dt))).astype(dtype)
+        elif name.endswith("_kda_a_log"):
+            out[name] = np.log(rng.uniform(1.0, 16.0, shape)).astype(dtype)
         elif name.endswith("_ssm_a_log"):
             out[name] = np.broadcast_to(np.log(np.arange(
                 1, shape[0] + 1, dtype=np.float64))[:, None],
@@ -991,8 +1080,15 @@ def _mix_gqa(h, params, p, i, kind, cfg, prec, positions, attend, state):
     att, state = attend(i, q, k, v.reshape(a, b, hk, dv), state)
     if cfg.value_scale != 1.0:
         att = att * jnp.asarray(cfg.value_scale, att.dtype)
-    return jnp.einsum("bsm,nm->bsn", att.reshape(a, b, hh * dv),
-                      params[p + "_attn_out_weight"], precision=prec), state
+    att = att.reshape(a, b, hh * dv)
+    if cfg.attn_gate:
+        import jax
+
+        att = att * jax.nn.sigmoid(jnp.einsum(
+            "bsm,nm->bsn", h, params[p + "_attn_gate_weight"],
+            precision=prec))
+    return jnp.einsum("bsm,nm->bsn", att, params[p + "_attn_out_weight"],
+                      precision=prec), state
 
 
 def _sink(params, i, cfg):
@@ -1125,6 +1221,74 @@ def _mix_mamba(h, params, p, i, cfg, prec, recur, state):
                       precision=prec), state
 
 
+def _kda_conv(window, w):
+    """silu(depthwise conv) of ``window``, the ``K`` taps' rows oldest first
+    (each ``(.., C)``), under taps ``w`` (K, C): K shifted multiply-adds in
+    float32, no bias. The caller rounds."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    w = w.astype(f32)
+    return jax.nn.silu(sum(t.astype(f32) * w[j] for j, t in enumerate(window)))
+
+
+def _kda_heads(xc, cfg):
+    """The conv'd rows ``(R, C)`` as heads: q ``(R, H, dk)`` L2-normalised
+    and scaled by ``dk ** -0.5``, k ``(R, H, dk)`` L2-normalised, v ``(R, H,
+    dk)``; the norms in float32, the results in the rows' type."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    hh, dk = cfg.kda_heads, cfg.kda_head_dim     # values are dk wide too
+    q, k, v = jnp.split(xc, [hh * dk, 2 * hh * dk], axis=-1)
+
+    def unit(t, scale):
+        t = t.reshape(-1, hh, dk).astype(f32)
+        return (t * (jax.lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+                     * scale)).astype(xc.dtype)
+
+    return unit(q, float(dk) ** -0.5), unit(k, 1.0), v.reshape(-1, hh, dk)
+
+
+def _mix_kda(h, params, p, i, cfg, prec, recur, state):
+    """The "kda" kind: ``[q, k, v] = W_in h`` -> ``recur`` (the conv over the
+    stream's tail, the heads' norms and the delta rule over the stream's
+    state) -> a head's RMSNorm, the sigmoid gate, ``W_out``. The decay and
+    the step size are the token's own (no state) and are made here, in
+    float32: ``g = -exp(A_log) softplus(W_f2 W_f1 h + dt_bias)`` a key
+    channel, ``beta = sigmoid(W_b h)`` a head, doubled where
+    ``kda_neg_eigval`` (``I - beta k k^T`` then reaches eigenvalue -1)."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    a, b, _ = h.shape
+    hh, dk = cfg.kda_heads, cfg.kda_head_dim     # values are dk wide too
+
+    def proj(t, name, **kw):
+        return jnp.einsum("bsm,nm->bsn", t, params[p + name], precision=prec,
+                          **kw)
+
+    qkv = proj(h, "_kda_in_weight")
+    f = proj(proj(h, "_kda_f1_weight"), "_kda_f2_weight",
+             preferred_element_type=f32)
+    g = -jnp.exp(params[p + "_kda_a_log"].astype(f32))[:, None] \
+        * jax.nn.softplus(f + params[p + "_kda_dt_bias"].astype(f32)
+                          ).reshape(a, b, hh, dk)
+    beta = jax.nn.sigmoid(proj(h, "_kda_b_weight",
+                               preferred_element_type=f32))
+    if cfg.kda_neg_eigval:
+        beta = beta * 2.0
+    _y, o, state = recur(i, qkv, (g, beta), state)      # (A, B, H, dk)
+    o = _rms_norm(o, params[p + "_kda_norm_gamma"], cfg.norm_eps)
+    gate = jax.nn.sigmoid(
+        proj(proj(h, "_kda_g1_weight"), "_kda_g2_weight")
+        + params[p + "_kda_g_bias"])
+    return proj(o.reshape(a, b, hh * dk) * gate, "_kda_out_weight"), state
+
+
 def _mix_gmu(h, params, p, prec, state):
     """The "gmu" kind: ``W_out (silu(W_in h) * m)``, ``m`` the memory
     layer's scan output of the same token."""
@@ -1155,7 +1319,9 @@ def _layer(x, params, i, cfg, prec, positions, valid, attend, state,
                which attention reads them. ``state`` is the caller's (the
                pages, or the K/V collected so far).
     recur:     ``(i, x, z, state) -> (y, out, state)``: the same for a
-               "mamba" layer's conv tail and state.
+               "mamba" layer's conv tail and state, and for a "kda" layer's
+               (``x`` its three projections, ``z`` its decay and step size,
+               ``y`` None).
 
     Returns ``(x, state, tokens_per_expert (E,) or None)``."""
     p = "layer%d" % i
@@ -1171,6 +1337,11 @@ def _layer(x, params, i, cfg, prec, positions, valid, attend, state,
     elif kind == "mla":
         mix, state = _mix_mla(h, params, p, i, cfg, prec, positions, attend,
                               state)
+    elif kind == "kda":
+        import jax
+
+        with jax.named_scope("kda"):
+            mix, state = _mix_kda(h, params, p, i, cfg, prec, recur, state)
     elif cfg.gqa:
         import jax
 
@@ -1344,7 +1515,7 @@ def _cache_layers(cfg):
     """Model layer -> its layer of the full pool, of the window pool, of
     the state slots: three dicts (the pools' layers are not the model's)."""
     return tuple({i: n for n, i in enumerate(cfg.layers_of(*kinds))}
-                 for kinds in (("full", "mla"), ("swa",), ("mamba",)))
+                 for kinds in (("full", "mla"), ("swa",), ("mamba", "kda")))
 
 
 def _block_rows(t, bs, rows, head_major):
@@ -1464,7 +1635,24 @@ def _prefill_hybrid(params, tokens, length, block_table, k_pages, v_pages,
         return (att[0].transpose(1, 0, 2).reshape(
             1, S, cfg.num_heads // 2, 2, w), st)
 
+    def recur_kda(i, qkv, gb, st):
+        """The conv from an empty history, the chunkwise delta rule from an
+        empty state; the prompt's last state and conv tail into the slot."""
+        p, li, kt = "layer%d" % i, ssm_at[i], cfg.kda_conv
+        xp = jnp.pad(qkv[0], ((kt - 1, 0), (0, 0)))         # (S + K - 1, C)
+        xc = _kda_conv([xp[t:t + S] for t in range(kt)],
+                       params[p + "_kda_conv_weight"]).astype(qkv.dtype)
+        q, k, v = _kda_heads(xc, cfg)
+        o, ssm = kda_chunk(q, k, v, gb[0][0], gb[1][0], length, st["ssm"],
+                           slot, li)
+        tail = jax.lax.dynamic_slice_in_dim(xp, length, kt - 1, axis=0)
+        st = dict(st, ssm=ssm, conv=st["conv"].at[li, slot].set(
+            tail.reshape(-1).astype(st["conv"].dtype)))
+        return None, o[None], st
+
     def recur(i, xin, z, st):
+        if cfg.kinds()[i] == "kda":
+            return recur_kda(i, xin, z, st)
         p, li = "layer%d" % i, ssm_at[i]
         # causal depthwise conv over time from an empty history
         xp = jnp.pad(xin[0], ((taps - 1, 0), (0, 0)))       # (S + K - 1, Dn)
@@ -1686,7 +1874,24 @@ def _paged_step_hybrid(params, tokens, positions, block_tables,
         return (att.transpose(0, 2, 1, 3).reshape(
             B, 1, cfg.num_heads // 2, 2, w), st)
 
+    def recur_kda(i, qkv, gb, st):
+        """Shift the stream's conv tail, one delta-rule update of its state
+        where it lies."""
+        p, li, kt = "layer%d" % i, ssm_at[i], cfg.kda_conv
+        tail = st["conv"][li, slots].reshape(B, kt - 1, -1)
+        window = jnp.concatenate([tail.astype(qkv.dtype), qkv], axis=1)
+        xc = _kda_conv([window[:, t] for t in range(kt)],
+                       params[p + "_kda_conv_weight"]).astype(qkv.dtype)
+        q, k, v = _kda_heads(xc, cfg)
+        o, ssm = kda_step(q, k, v, gb[0][:, 0], gb[1][:, 0], st["ssm"],
+                          slots, li)
+        st = dict(st, ssm=ssm, conv=st["conv"].at[li, slots].set(
+            window[:, 1:].reshape(B, -1).astype(st["conv"].dtype)))
+        return None, o[:, None], st
+
     def recur(i, xin, z, st):
+        if cfg.kinds()[i] == "kda":
+            return recur_kda(i, xin, z, st)
         p, li = "layer%d" % i, ssm_at[i]
         tail = st["conv"][li, slots].reshape(B, taps - 1, -1)
         window = jnp.concatenate([tail.astype(xin.dtype), xin], axis=1)

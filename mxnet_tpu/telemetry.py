@@ -872,12 +872,21 @@ METRIC_HELP = {
         "state-space layers (x its 'mamba' layers = slot updates)",
     "serving.ssm.prefill_tokens":
         "prompt+replay tokens scanned by the state-space layers' prefill",
+    "serving.linear.state_updates":
+        "matrix states the decode steps rewrote: live lanes x 'kda' layers, "
+        "summed over decode steps (each is one kda_step slot update)",
+    "serving.linear.prefill_tokens":
+        "prompt+replay rows through the 'kda' layers' chunkwise prefill "
+        "(tokens x 'kda' layers)",
+    "serving.linear.prefill_chunks":
+        "chunks of ops.kda.CHUNK rows the chunkwise prefill took "
+        "(ceil(tokens / CHUNK) x 'kda' layers a prompt)",
     "serving.window.blocks_freed":
         "window-pool blocks returned during decode because they fell "
         "wholly behind a stream's sliding window",
     "serving.state_slots_used":
-        "state slots (conv tail + SSM state a 'mamba' layer) held by "
-        "running streams",
+        "state slots (conv tail + float32 state a 'mamba' or 'kda' layer) "
+        "held by running streams",
     "serving.ttft_seconds": "request time-to-first-token "
         "(bare = process-wide; engine label = per-engine)",
     "serving.request_latency_seconds": "request end-to-end latency "

@@ -579,6 +579,99 @@ def test_gqa_programs_leave_both_pools_in_place(v5e, name):
                                     else 96 << 20)
 
 
+def _linear_program(chip, name, batch=96, prompt=4096, blocks=4097,
+                    chunk=8):
+    """``model.decode_chunk`` (B=``batch``) or ``model.prefill``
+    (S=``prompt``) of Solar-Open2's block at published widths — three gated
+    delta-rule layers (64 heads of 128 keys and 128 values, a conv of 4 taps
+    over 24,576 channels) to every gated position-free GQA layer (64 query
+    heads over 8 K/V heads of 128), 20 of 320 experts held beside a shared
+    one — the benchmark's two periods and its plan (262,144 tokens of full
+    pool, 97 slots of six 4 MB float32 states), the pool and both slot
+    arrays donated, compiled for the chip. Returns (compiled, the cache
+    arrays' shapes)."""
+    from mxnet_tpu.serving import model as M
+
+    def s(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(tuple(shape), dt, sharding=chip)
+
+    bf, bs = jnp.bfloat16, 64
+    cfg = M.ModelConfig(
+        24576, 8, 4096, 64, 1280, 5120, norm="rms", pos="none", bias=False,
+        head_dim=128, num_kv_heads=8, attn_form="gqa", attn_gate=True,
+        layer_kinds=["full", "kda", "kda", "kda"] * 2, kda_heads=64,
+        kda_head_dim=128, kda_conv=4, kda_neg_eigval=True, ffn_gated=True, norm_eps=1e-5, num_experts=320,
+        experts_per_tok=8, shared_experts=1, router="sigmoid_group",
+        experts_held=(0, 20))
+    full, window = cfg.cache_specs()
+    assert (full.k_rows, full.v_rows, full.layers) == ((8, 128), (8, 128), 2)
+    conv_width, state_shape = cfg.slot_shapes()
+    assert (conv_width, state_shape) == (3 * 24576, (64, 128, 128))
+    caches = {"k": s(full.shape(blocks, bs)[0], bf),
+              "v": s(full.shape(blocks, bs)[1], bf),
+              "conv": s((6, batch + 1, conv_width), bf),
+              "ssm": s((6, batch + 1) + state_shape, jnp.float32)}
+    stand_ins = tuple(s(sh, bf) for sh in window.shape(2, bs))
+    params = {k: s(v, bf) for k, v in M.param_shapes(cfg).items()}
+    aux = ("wk", "wv", "conv", "ssm")
+    nb = cfg.max_len // bs
+    if name == "chunk":
+        def fn(params, toks, poss, tables, ctx, left, eos, n, kp, vp, wt,
+               slots, *arrays):
+            return M.decode_chunk(
+                params, toks, poss, tables, ctx, left, eos, n, kp, vp, cfg,
+                chunk, dict(zip(aux, arrays), wtables=wt, slots=slots))
+        args = (s((batch,)), s((batch,)), s((batch, nb)), s((batch,)),
+                s((batch,)), s((batch,)), s(()))
+        more, donate = (s((batch, nb)), s((batch,))), (8, 9, 14, 15)
+    else:
+        def fn(params, toks, n, table, kp, vp, wt, slot, *arrays):
+            return M.prefill(params, toks, n, table, kp, vp, cfg,
+                             dict(zip(aux, arrays), wtable=wt, slot=slot))
+        args = (s((1, prompt)), s(()), s((prompt // bs,)))
+        more, donate = (s((prompt // bs,)), s(())), (4, 5, 10, 11)
+    compiled = jax.jit(fn, donate_argnums=donate).lower(
+        params, *args, caches["k"], caches["v"], *more, *stand_ins,
+        caches["conv"], caches["ssm"]).compile()
+    return compiled, {k: v.shape for k, v in caches.items()}
+
+
+@pytest.mark.parametrize("name", ["chunk", "prefill"])
+def test_linear_programs_leave_the_pool_and_the_states_in_place(v5e, name):
+    """2.4 GB of float32 matrix states (97 slots x 6 layers x 64 heads of
+    128 x 128) beside a 2.1 GB pool: no program copies or slices the pool,
+    the states or a layer of either (a copy of the states would be a sixth
+    of a decode step), all are donated and aliased; the decode chunk's
+    eight steps carry them through the loop. The decode program holds a
+    ``kda_step`` a linear layer and a ``paged_full_walk`` a GQA layer, the
+    prefill a ``kda_chunk`` and a ``flash_gqa_fwd``, by their names on a
+    trace."""
+    compiled, shapes = _linear_program(v5e, name)
+    text = compiled.as_text()
+    assert len(re.findall(r"%gmm[.\d]* = ", text)) == 24    # eight layers'
+    if name == "chunk":
+        assert len(re.findall(r"%kda_step[.\d]* = ", text)) == 6
+        assert len(re.findall(r"%paged_full_walk[.\d]* = ", text)) == 2
+        assert " while(" in text
+    else:
+        assert len(re.findall(r"%kda_chunk[.\d]* = ", text)) == 6
+        assert len(re.findall(r"%flash_gqa_fwd[.\d]* = ", text)) == 2
+    # the conv tails (86 MB) are the one array the compiler may stage around
+    # its row scatters, as Phi-4's: no layout of theirs is checked here
+    for key in ("k", "v", "ssm"):
+        assert _pool_copies(text, shapes[key]) == [], key
+    for key in ("k", "v"):
+        assert _entry_layouts(text, shapes[key]) == {"4,3,2,1,0"}
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= (
+        2 * 2 * math.prod(shapes["k"]) + 2 * math.prod(shapes["conv"])
+        + 4 * math.prod(shapes["ssm"]))
+    # a 4,096-token prompt's activations (24,576 conv channels of them), no
+    # cache; the decode chunk's 96 lanes
+    assert ma.temp_size_in_bytes < (1536 << 20 if name == "prefill"
+                                    else 256 << 20)
+
+
 @pytest.mark.parametrize("bs", [64, 128, 256])
 def test_latent_kernel_compiles_for_v5e(v5e, bs):
     """The block-size ladder's three rungs, 128 streams of 128 heads."""

@@ -15,7 +15,8 @@ pytest.register_assert_rewrite("benchmark.tests.test_benchmark_harness",
                                "benchmark.tests.test_loop_metrics",
                                "benchmark.tests.test_looped_metrics",
                                "benchmark.tests.test_hybrid_metrics",
-                               "benchmark.tests.test_prefill_padded_share")
+                               "benchmark.tests.test_prefill_padded_share",
+                               "benchmark.tests.test_linear_metrics")
 
 from benchmark.tests.test_benchmark_harness import *  # noqa: E402,F401,F403
 from benchmark.tests.test_pool_copy_share import *  # noqa: E402,F401,F403
@@ -32,9 +33,11 @@ from benchmark.tests import test_ssm_metrics as _ssm_tests  # noqa: E402
 from benchmark.tests import test_hybrid_metrics as _hybrid_tests  # noqa: E402
 from benchmark.tests.test_prefill_padded_share import padded_records  # noqa: E402,F401
 from benchmark.tests import test_prefill_padded_share as _padded_tests  # noqa: E402
+from benchmark.tests.test_linear_metrics import linear_records  # noqa: E402,F401
+from benchmark.tests import test_linear_metrics as _linear_tests  # noqa: E402
 
 # test_moe_metrics, test_ssm_metrics, test_latent_metrics,
-# test_looped_metrics and test_hybrid_metrics each have a
+# test_looped_metrics, test_hybrid_metrics and test_linear_metrics each have a
 # ``test_the_cell_is_in_the_manifest_with_its_files`` and a
 # ``test_the_mix_is_what_the_issue_says...``: the later files' cases come in
 # under names of their own, so that each file's still counts
@@ -42,7 +45,8 @@ from benchmark.tests import test_prefill_padded_share as _padded_tests  # noqa: 
 for _prefix, _module in (("ssm", _ssm_tests), ("latent", _latent_tests),
                          ("looped", _looped_tests),
                          ("hybrid", _hybrid_tests),
-                         ("padded", _padded_tests)):
+                         ("padded", _padded_tests),
+                         ("linear", _linear_tests)):
     for _name in dir(_module):
         if _name.startswith("test_"):
             globals()["test_%s_%s" % (_prefix, _name[len("test_"):])] = \
@@ -59,7 +63,8 @@ _APPENDED_SINCE = ("prefill_padded_share",)
 
 
 def _manifest_case_of(monkeypatch, module, cell,
-                      case="test_the_cell_is_in_the_manifest_with_its_files"):
+                      case="test_the_cell_is_in_the_manifest_with_its_files",
+                      appended=_APPENDED_SINCE):
     """A cell's ``test_the_cell_is_in_the_manifest_with_its_files`` (or
     another ``case`` of its file), every assert of it, over the manifest
     LESS the cells that later PRs appended behind it. OLMoE's case asks that its cell be the LAST of each shared
@@ -77,7 +82,7 @@ def _manifest_case_of(monkeypatch, module, cell,
         doc = json.load(f)
         if f.name.endswith("BENCHMARK.json"):
             doc["per_layer"] = [m for m in doc["per_layer"]
-                                if m["name"] not in _APPENDED_SINCE]
+                                if m["name"] not in appended]
             for m in doc["per_layer"] + doc["end_to_end"]:
                 if "workloads" in m:
                     m["workloads"] = [c for c in m["workloads"]
@@ -106,6 +111,21 @@ def test_latent_the_cell_is_in_the_manifest_with_its_files(monkeypatch):  # noqa
 
 def test_looped_the_cell_is_in_the_manifest_with_its_files(monkeypatch):  # noqa: F811
     _manifest_case_of(monkeypatch, _looped_tests, "ouro-chat-closed32")
+
+
+def test_hybrid_the_cell_is_in_the_manifest_with_its_files(monkeypatch):  # noqa: F811
+    """MiMo's case holds its seven metrics to list its cell ALONE: true
+    until the tenth cell, whose "full" layers are the same kernel calls,
+    was appended behind it."""
+    _manifest_case_of(monkeypatch, _hybrid_tests, "mimov25-mixed-closed128")
+
+
+def test_padded_the_manifest_appends_the_metric_behind_what_was_there(monkeypatch):  # noqa: F811,E501
+    """PR 46's case names the six cells the metric had when it was
+    appended; the tenth cell stands behind them."""
+    _manifest_case_of(
+        monkeypatch, _padded_tests, "mimov25-mixed-closed128",
+        "test_the_manifest_appends_the_metric_behind_what_was_there", ())
 
 
 def test_the_manifest_appends_the_nine_loop_metrics(monkeypatch):  # noqa: F811

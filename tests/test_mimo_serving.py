@@ -384,7 +384,7 @@ def test_serving_config_refuses_what_two_pools_cannot_do_yet():
         with pytest.raises(ValueError, match=match):
             ServingConfig(**dict(model, vocab_size=VOCAB, **changed))
 
-    bad("takes 'swa' and 'full' layers alone",
+    bad("takes 'swa', 'full' and 'kda' layers alone",
         layer_kinds=["full", "swa", "swa", "mamba", "swa", "swa", "full"])
     bad("do not share 3 K/V heads", swa_kv_heads=3)
     bad("an even rope_dim of at most head_dim", rope_dim=50)

@@ -1,6 +1,6 @@
 """The prefill ladder (``ServingConfig.prefill_buckets``): block_size
 doublings up to ``max_len`` and, from ``HALF_STEP_FROM`` (1,024) rows up, the
-half step between two doublings — over the six serving configurations of
+half step between two doublings — over the seven serving configurations of
 the benchmark and four made-up ones, then a long prompt through the 1,536
 program of a tiny model on the CPU (docs/serving.md, "Shape buckets").
 """
@@ -55,8 +55,8 @@ def doublings(block_size, max_len):
     return out + [max_len]
 
 
-def test_the_benchmark_serves_six_configurations():
-    assert len(SERVED) == 6 and len({c[1:] for c in SERVED}) == 5
+def test_the_benchmark_serves_seven_configurations():
+    assert len(SERVED) == 7 and len({c[1:] for c in SERVED}) == 6
 
 
 @pytest.mark.parametrize("name,block_size,max_len", SERVED + MADE_UP,
@@ -98,6 +98,9 @@ def test_the_cells_lists_are_the_issues():
     assert by_name["mimo-v2.5-ep16-bf16"] == [
         64, 128, 256, 512, 1024, 1536, 2048, 3072, 4096, 6144, 8192, 8960]
     assert by_name["ouro-2.6b-bf16"][:6] == [32, 64, 128, 256, 512, 1024]
+    # since PR 48: ten rungs, 5,120 (the mix's max_total) the top one
+    assert by_name["solar-open2-ep16-bf16"] == [
+        64, 128, 256, 512, 1024, 1536, 2048, 3072, 4096, 5120]
 
 
 # --------------------------------------- a long prompt through the engine

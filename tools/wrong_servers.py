@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
 """Wrong servers for a latent-attention, shared-expert configuration (and,
-through ``--cell``, for a stack that runs several times and for window and
-full layers of plain grouped-query attention over two pools): plant ONE fault in
+through ``--cell``, for a stack that runs several times, for window and
+full layers of plain grouped-query attention over two pools, and for gated
+delta-rule linear layers beside gated attention): plant ONE fault in
 the served program (or its weights), run the configuration's own dense
 probe over it, print what ``correct`` would compare.
 
@@ -43,7 +44,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 WEIGHTS = ("sound", "float8_all", "float8_experts", "zeroed_expert",
-           "no_shared")
+           "no_shared",
+           # of gated delta-rule layers (solaropen2-reason-closed192): the
+           # decay left out (alpha = 1), the output gate left out (gate = 1)
+           "no_decay", "no_linear_gate")
 PROGRAM = ("no_group_limit", "no_renorm", "no_scale", "no_mscale",
            "unrotated_key", "latent_before_norm", "latent_not_written",
            # of a stack that runs several times (ouro-chat-closed32)
@@ -51,7 +55,12 @@ PROGRAM = ("no_group_limit", "no_renorm", "no_scale", "no_mscale",
            # of plain grouped-query attention in window and full layers
            # over two pools (mimov25-mixed-closed128)
            "no_sink", "window_64", "bases_swapped", "rope_all_lanes",
-           "freed_block_read", "window_kv_not_written")
+           "freed_block_read", "window_kv_not_written",
+           # of gated delta-rule layers beside gated attention
+           # (solaropen2-reason-closed192)
+           "beta_not_doubled", "qk_not_normalised", "conv_tail_not_carried",
+           "chunk_state_not_handed", "state_not_written", "no_gqa_gate",
+           "bf16_state")
 
 
 def load_config(path):
@@ -99,6 +108,11 @@ def alter(name, *holders):
             change(key, lambda w: w.at[w.shape[0] // 2].set(0))  # one held
         if name == "no_shared" and key.endswith("_shared_down_weight"):
             change(key, jnp.zeros_like)
+        if name == "no_decay" and key.endswith("_kda_a_log"):
+            # g = -exp(A_log) softplus(.) = 0: every alpha is 1
+            change(key, lambda a: jnp.full_like(a, -1e4))
+        if name == "no_linear_gate" and key.endswith("_kda_g_bias"):
+            change(key, lambda b: jnp.full_like(b, 1e4))    # sigmoid = 1
 
 
 @contextlib.contextmanager
@@ -236,6 +250,73 @@ def planted(name, model):
             pool.k_pages, pool.v_pages = kept
             return out
         patch(ServingEngine, "_dispatch_decode", dispatch)
+    elif name in ("beta_not_doubled", "no_gqa_gate"):
+        fn, field = (("_mix_kda", "kda_neg_eigval")
+                     if name == "beta_not_doubled"
+                     else ("_mix_gqa", "attn_gate"))
+        at = 4 if fn == "_mix_kda" else 5       # where cfg rides
+
+        def without(*a, _sound=getattr(M, fn), **kw):
+            return _sound(*a[:at], Other(a[at], **{field: False}),
+                          *a[at + 1:], **kw)
+        patch(M, fn, without)
+    elif name == "qk_not_normalised":   # q keeps its scale, neither its norm
+        def heads(xc, cfg):
+            hh, dk = cfg.kda_heads, cfg.kda_head_dim
+            q, k, v = jnp.split(xc, [hh * dk, 2 * hh * dk], axis=-1)
+            return ((q * float(dk) ** -0.5).astype(xc.dtype).reshape(
+                -1, hh, dk), k.reshape(-1, hh, dk),
+                v.reshape(-1, hh, dk))
+        patch(M, "_kda_heads", heads)
+    elif name == "chunk_state_not_handed":
+        # every chunk of a prompt starts from an empty state: the slot ends
+        # with the last live chunk's own
+        from mxnet_tpu.ops.kda import CHUNK
+        sound = M.kda_chunk
+
+        def alone(q, k, v, g, beta, length, state, slot, layer):
+            outs = []
+            for at in range(0, q.shape[0], CHUNK):
+                live = jnp.clip(length - at, 0, CHUNK)
+                o, state = sound(
+                    *(t[at:at + CHUNK] for t in (q, k, v, g, beta)), live,
+                    state, jnp.where(live > 0, slot, 0), layer)
+                outs.append(o)
+            return jnp.concatenate(outs), state
+        patch(M, "kda_chunk", alone)
+    elif name == "bf16_state":
+        # the state keeps bfloat16's eight bits after every decode update
+        # and after a prompt (reduce_precision: a pair of converts is
+        # dropped as excess precision, PERF.md section 6, PR 40)
+        def rounded(state, layer, slots):
+            return state.at[layer, slots].set(jax.lax.reduce_precision(
+                state[layer, slots], exponent_bits=8, mantissa_bits=7))
+
+        step, chunk = M.kda_step, M.kda_chunk
+
+        def kda_step(q, k, v, g, beta, state, slots, layer):
+            o, state = step(q, k, v, g, beta, state, slots, layer)
+            return o, rounded(state, layer, slots)
+
+        def kda_chunk(q, k, v, g, beta, length, state, slot, layer):
+            o, state = chunk(q, k, v, g, beta, length, state, slot, layer)
+            return o, rounded(state, layer, slot)
+        patch(M, "kda_step", kda_step)
+        patch(M, "kda_chunk", kda_chunk)
+    elif name in ("conv_tail_not_carried", "state_not_written"):
+        # the programs are sound; the engine loses what one of them wrote:
+        # a prompt's conv tail (decode starts from what the slot held), or
+        # every decode step's states
+        lost, fn = (("conv", "_dispatch_prefill")
+                    if name == "conv_tail_not_carried"
+                    else ("ssm", "_dispatch_decode"))
+
+        def dispatch(self, *a, _sound=getattr(ServingEngine, fn), **kw):
+            kept = jnp.copy(getattr(self.state, lost))  # donated below
+            out = _sound(self, *a, **kw)
+            setattr(self.state, lost, kept)
+            return out
+        patch(ServingEngine, fn, dispatch)
     elif name not in WEIGHTS:
         raise ValueError("no fault %r (weights: %s; program: %s)"
                          % (name, WEIGHTS, PROGRAM))
