@@ -108,9 +108,14 @@ class ServingConfig(_model.ModelConfig):
                               else env_int("MXNET_SERVING_NUM_BLOCKS", 257))
         self.max_batch = int(max_batch if max_batch is not None
                              else env_int("MXNET_SERVING_MAX_BATCH", 32))
-        self.prefills_per_step = int(
-            prefills_per_step if prefills_per_step is not None
-            else env_int("MXNET_SERVING_PREFILLS_PER_STEP", 4))
+        # None (nothing given, nothing in the environment): a step admits
+        # every waiting prompt that the lanes, the pool and the state
+        # slots hold; an integer caps a step's group, for a caller who
+        # would bound the stall of the streams that decode
+        if prefills_per_step is None:
+            prefills_per_step = env_int("MXNET_SERVING_PREFILLS_PER_STEP")
+        self.prefills_per_step = (None if prefills_per_step is None
+                                  else int(prefills_per_step))
         self.kv_dtype = np.dtype(kv_dtype)
         # prefix sharing (docs/serving.md §prefix-sharing): content-hash
         # full prefill blocks so same-prefix admissions map cached blocks
@@ -860,7 +865,8 @@ class ServingEngine:
                          running=len(self.scheduler.running),
                          kv_used=self.pool.used(),
                          kv_free=self.pool.available(),
-                         kv_frag_slots=self.scheduler.frag_slots())))
+                         kv_frag_slots=self.scheduler.frag_slots(),
+                         stopped_by=self.scheduler.last_stop)))
                 if rec is not None:
                     rec["retire_counters_s"] += clock() - t0
             self._book("retire_s", retire)
@@ -2223,6 +2229,9 @@ class ServingEngine:
                     # every prompt of a group but its last
                     "syncs_saved":
                         self._prefill_prompts - self._prefill_groups,
+                    # admission passes that found a request waiting, by
+                    # what ended them (scheduler.STOP_REASONS)
+                    "stopped_by": dict(self.scheduler.stopped_by),
                 },
                 # only for a stack that runs several times
                 **({"looped": {

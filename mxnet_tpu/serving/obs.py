@@ -47,7 +47,9 @@ Structured events (``MXNET_TELEMETRY_FILE`` JSONL, rendered by
   failed); the terminal event carries the full phase breakdown
 * ``serving.step_timeline``  one per non-empty engine step: batch
   occupancy, admitted/preempted/finished counts, queue depth, KV-pool
-  used/free/frag — the occupancy time series — and beside them the
+  used/free/frag — the occupancy time series —, ``stopped_by`` (what
+  ended the step's admission pass: ``scheduler.STOP_REASONS``; None where
+  nothing was waiting) and beside them the
   fields of the step's :class:`LoopRecord` (section seconds, the two host
   gaps)
 * ``serving.slo_burn``       attainment crossed below the burn threshold
@@ -477,13 +479,15 @@ class ServingObs:
 
     # ---- step timeline ------------------------------------------------
     def step_timeline(self, record, occupancy, admitted, preempted, queue,
-                      running, kv_used, kv_free, kv_frag_slots):
+                      running, kv_used, kv_free, kv_frag_slots,
+                      stopped_by=None):
         """One sample per non-empty engine step, made from ``record`` (the
         dict of :func:`open_record`, filled by the step; None while
         telemetry is off: nothing is assembled): the :class:`LoopRecord`
         into this engine's ring and sums, and the ``serving.step_timeline``
-        event with the occupancy beside the record's fields. Called when
-        the step's deferred item is carried out, with the occupancy as the
+        event with the occupancy, and what ended the step's admission pass
+        (``stopped_by``), beside the record's fields. Called when the
+        step's deferred item is carried out, with the occupancy as the
         step read it."""
         if record is None:
             return
@@ -505,7 +509,8 @@ class ServingObs:
                         occupancy=occupancy, admitted=admitted,
                         preempted=preempted, queue=queue,
                         running=running, kv_used=kv_used, kv_free=kv_free,
-                        kv_frag_slots=kv_frag_slots, **record)
+                        kv_frag_slots=kv_frag_slots, stopped_by=stopped_by,
+                        **record)
 
     # ---- snapshots (stats(): serve.py, the benchmark's drivers) --------
     def slo_snapshot(self):

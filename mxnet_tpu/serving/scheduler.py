@@ -27,10 +27,11 @@ Each engine step the scheduler produces one :class:`StepPlan`:
   queue for deterministic re-prefill) until the older ones fit. FCFS both
   ways: oldest requests never starve behind younger ones.
 * **admit** — waiting requests are admitted head-first while the batch cap,
-  the per-step prefill budget, and the free list allow; the queue head
-  blocks admission when its prompt doesn't fit (no skip-ahead — a short
-  prompt can never overtake a long one, which is the fairness contract
-  tests pin down).
+  the free list and the state slots allow (and a ``prefills_per_step``,
+  where one was given: there is none by default, so a free lane waits for
+  no count); the queue head blocks admission when its prompt doesn't fit
+  (no skip-ahead — a short prompt can never overtake a long one, which is
+  the fairness contract tests pin down).
 
 Preemption is recompute-style (vLLM's recompute mode): a victim's
 generated-so-far tokens become its new prompt; greedy decoding makes the
@@ -59,6 +60,13 @@ _TERMINAL_COUNTERS = {
     TIMED_OUT: "serving.timeouts",
     CANCELLED: "serving.cancelled",
 }
+
+#: what can end an admission pass that found a request waiting
+#: (``Scheduler.stopped_by``, counter ``serving.admit.stopped_by``): no free
+#: lane, the full pool's free list, ``streams.can_admit`` (a state slot, the
+#: window pool), an explicit ``prefills_per_step``, a head preempted in this
+#: pass, the queue's end
+STOP_REASONS = ("lanes", "pool", "slots", "cap", "preempted", "queue")
 
 _rid_counter = itertools.count()
 
@@ -174,14 +182,17 @@ class StepPlan:
 class Scheduler:
     """FCFS continuous-batching scheduler over one :class:`KVBlockPool`."""
 
-    def __init__(self, pool, max_batch=32, prefills_per_step=4,
+    def __init__(self, pool, max_batch=32, prefills_per_step=None,
                  lookahead=1, max_positions=None, streams=None):
         self.pool = pool
         # kv_cache.StreamState of a model with window or state layers: the
         # slot and window blocks a stream holds beside its blocks of `pool`
         self.streams = streams
         self.max_batch = int(max_batch)
-        self.prefills_per_step = int(prefills_per_step)
+        # the most prompts one step admits; None, the default: no count,
+        # a pass ends only on what the engine holds (STOP_REASONS)
+        self.prefills_per_step = (None if prefills_per_step is None
+                                  else int(prefills_per_step))
         # write slots a decoding stream may consume per engine step: the
         # steps of a decode chunk, or spec_k + 1 for speculative decoding
         # (the draft + verify window writes positions context_len ..
@@ -196,6 +207,10 @@ class Scheduler:
         self.failed = []           # _fail victims awaiting engine drain
         self.preempt_count = 0     # this scheduler only (the registry
                                    # counter is process-global)
+        # passes that found a request waiting, by what ended them, and the
+        # last pass's reason (None: nothing was waiting)
+        self.stopped_by = dict.fromkeys(STOP_REASONS, 0)
+        self.last_stop = None
 
     # ---- intake ---------------------------------------------------------
     def add(self, req):
@@ -377,24 +392,43 @@ class Scheduler:
         return swept
 
     def _admit(self, preempted=()):
-        """FCFS head-first admission into PREFILL, bounded by the batch
-        cap, the per-step prefill budget, and the free list. The
-        admission grant covers the replay tokens PLUS the first decode
-        token's write slot — without that headroom a boundary-length
-        prompt prefills, loses the decode-slot race to the next
-        admission, and thrashes prefill->preempt every step on a tight
-        pool. The head blocks the queue when it doesn't fit: no
+        """FCFS head-first admission into PREFILL: every waiting request
+        that the lanes (the batch cap), the free list and the state slots
+        hold, and no more than ``prefills_per_step`` where a count was
+        given. What ended a pass that found a request waiting is counted
+        under its name (``stopped_by``, :data:`STOP_REASONS`).
+
+        The admission grant covers the replay tokens PLUS the first
+        decode token's write slot — without that headroom a
+        boundary-length prompt prefills, loses the decode-slot race to
+        the next admission, and thrashes prefill->preempt every step on a
+        tight pool. The head blocks the queue when it doesn't fit: no
         skip-ahead. A head the pool could never hold even when empty is
         failed outright (wedging the queue behind it forever serves no
         one). A request preempted THIS pass sits the step out —
         re-admitting it at once would re-grab the blocks the eviction
         just reclaimed."""
+        self.last_stop = None
+        if not self.waiting:
+            return []
+        prefills, why = self._admit_waiting(preempted)
+        self.last_stop = why
+        self.stopped_by[why] += 1
+        telemetry.counter("serving.admit.stopped_by", reason=why).inc()
+        return prefills
+
+    def _admit_waiting(self, preempted):
+        """The pass itself: the requests admitted, and what ended it."""
         prefills = []
-        while (self.waiting and len(self.running) < self.max_batch
-               and len(prefills) < self.prefills_per_step):
+        cap = self.prefills_per_step
+        while self.waiting:
+            if len(self.running) >= self.max_batch:
+                return prefills, "lanes"
+            if cap is not None and len(prefills) >= cap:
+                return prefills, "cap"
             req = self.waiting[0]
             if req in preempted:
-                break
+                return prefills, "preempted"
             replay = req.replay_tokens()
             need = self.pool.blocks_for(len(replay) + 1)
             if need > self.pool.num_usable:
@@ -408,7 +442,7 @@ class Scheduler:
                     len(replay)):
                 # no state slot, or too few window blocks: the head waits
                 # (nothing is booked in part: not the full pool either)
-                break
+                return prefills, "slots"
             # prefix sharing: map the longest indexed block-aligned prefix
             # into the table (refcounted), allocate only the tail. The
             # match can never cover the first write slot — it spans full
@@ -420,7 +454,7 @@ class Scheduler:
             if fresh > self.pool.available():
                 if shared:   # drop our references; other holders keep them
                     self.pool.free(shared)
-                break
+                return prefills, "pool"
             self.waiting.popleft()
             try:
                 fresh_blocks = self.pool.alloc(fresh)
@@ -448,7 +482,7 @@ class Scheduler:
             self.running.append(req)
             telemetry.counter("serving.requests_admitted").inc()
             prefills.append(req)
-        return prefills
+        return prefills, "queue"
 
     def pop_failed(self):
         """Drain requests FAILED by the scheduler itself (pool too small,

@@ -796,6 +796,10 @@ METRIC_HELP = {
     "serving.prefill.syncs_saved":
         "prefill fetches that exposed no host gap of their own: a group's "
         "prompts less one, summed",
+    "serving.admit.stopped_by":
+        "admission passes that found a request waiting, by what ended them "
+        "(label reason: lanes, pool, slots, cap, preempted, queue); cap "
+        "counts only where a prefills_per_step was given",
     "serving.decode.build": "decode step host-input build (span)",
     "serving.decode.dispatch": "decode program call (span)",
     "serving.decode.fetch": "decode next-token blocking fetch (span)",

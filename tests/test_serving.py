@@ -31,6 +31,7 @@ from mxnet_tpu.serving import (  # noqa: E402
     KVBlockPool, KVCacheOOM, Request, Scheduler, ServingConfig, ServingEngine)
 from mxnet_tpu.serving import model as smodel  # noqa: E402
 from mxnet_tpu.serving.kv_cache import PageSpec  # noqa: E402
+from mxnet_tpu.serving.scheduler import STOP_REASONS  # noqa: E402
 
 from chunk_cases import (  # noqa: E402
     chunk_equals_single_steps, lane, tables_for)
@@ -674,6 +675,196 @@ def test_chunked_serving_equals_sequential_decoding(chunk):
 
 
 # ---------------------------------------------------------------------------
+# admission: a free lane waits for no count
+# ---------------------------------------------------------------------------
+
+
+def _queue(sched, lengths, n_new=4):
+    reqs = [Request([1] * n, n_new) for n in lengths]
+    for r in reqs:
+        sched.add(r)
+    return reqs
+
+
+def _stops(**counts):
+    """``stopped_by`` with ``counts`` and every other reason at zero."""
+    return dict(dict.fromkeys(STOP_REASONS, 0), **counts)
+
+
+def _decoding(req):
+    """What the engine leaves of an admitted prompt after its prefill."""
+    req.state = "decoding"
+    req.context_len = len(req.prompt)
+    req.generated = [9]
+    req.pending_token = 9
+
+
+@pytest.mark.parametrize("waiting", [8, 11])
+def test_no_count_one_step_takes_every_prompt_the_lanes_hold(waiting):
+    """No ``prefills_per_step``: 8 free lanes and a roomy pool are ONE
+    step's group of 8, whatever the pool has left over; the queue's end or
+    the last lane ends the pass, never a count."""
+    pool = KVBlockPool(SPEC, 129, 4)
+    sched = Scheduler(pool, max_batch=8)
+    assert sched.prefills_per_step is None
+    reqs = _queue(sched, [5] * waiting)
+    plan = sched.schedule()
+    assert plan.prefills == reqs[:8]
+    assert pool.available() > 64, "a roomy pool is no reason to stop"
+    assert sched.last_stop == ("queue" if waiting == 8 else "lanes")
+    assert sched.stopped_by == _stops(**{sched.last_stop: 1})
+    assert list(sched.waiting) == reqs[8:]
+
+
+@pytest.mark.parametrize("cap", [1, 2, 4])
+def test_an_explicit_count_still_caps_a_step(cap):
+    """``prefills_per_step=2`` means what it always did: groups of two,
+    head first, and the pass it ended says ``cap``."""
+    pool = KVBlockPool(SPEC, 129, 4)
+    sched = Scheduler(pool, max_batch=8, prefills_per_step=cap)
+    reqs = _queue(sched, [5] * 8)
+    groups = []
+    while sched.waiting:
+        groups.append(sched.schedule().prefills)
+    assert [len(g) for g in groups] == [cap] * (8 // cap)
+    assert [r for g in groups for r in g] == reqs
+    # the last pass emptied the queue: its end is named before the count
+    assert sched.stopped_by["cap"] == 8 // cap - 1
+    assert sched.stopped_by["queue"] == 1
+
+
+class _Slots:
+    """``StreamState`` as the scheduler sees it: ``free`` state slots, no
+    window pool."""
+
+    def __init__(self, free):
+        self.free = free
+
+    def can_admit(self, n_tokens):
+        return self.free > 0
+
+    def admit(self, req, n_tokens):
+        self.free -= 1
+
+    def release(self, req):
+        self.free += 1
+
+    def ensure(self, req, pos, last_pos=None):
+        pass
+
+
+@pytest.mark.parametrize("bound", ["pool", "slots", "preempted"])
+def test_no_count_a_pass_still_ends_on_what_the_engine_holds(bound):
+    """With no count, the pool's free list, a state slot
+    (``streams.can_admit``) and a request preempted in the pass each still
+    end it, first come first served: the short prompt behind the head
+    that does not fit is never taken in its place."""
+    pool = KVBlockPool(SPEC, 9, 4)              # 8 usable blocks
+    streams = _Slots(2) if bound == "slots" else None
+    sched = Scheduler(pool, max_batch=8, streams=streams)
+    if bound == "preempted":
+        old, young = _queue(sched, [15, 15], n_new=8)   # 4 blocks each
+        assert sched.schedule().prefills == [old, young]
+        _decoding(old)
+        _decoding(young)
+        old.context_len = young.context_len = 16        # next slot: block 5
+        short, = _queue(sched, [1])
+        plan = sched.schedule()
+        assert plan.preempted == [young] and plan.decodes == [old]
+        # the victim heads the queue and sits this step out; the blocks it
+        # gave back are not the short prompt's to take past it
+        assert plan.prefills == []
+        assert list(sched.waiting) == [young, short]
+    else:
+        # pool: 3 + 3 blocks, then the head needs 3 of the 2 left;
+        # slots: two slots, then the head has none
+        a, b, head, short = _queue(sched, [9, 9, 9, 1])
+        plan = sched.schedule()
+        assert plan.prefills == [a, b]
+        assert list(sched.waiting) == [head, short]
+        assert pool.available() == 2
+    assert sched.last_stop == bound
+    assert sched.stopped_by[bound] == 1 and sched.stopped_by["cap"] == 0
+
+
+@pytest.mark.parametrize("reason", STOP_REASONS)
+def test_stopped_by_names_what_ended_each_pass(reason):
+    """``stats()["prefill"]["stopped_by"]``: one increment a pass that
+    found a request waiting, under the name of what ended it; a case
+    built for each of the six."""
+    family = "phi4flash" if reason == "slots" else "gpt2"
+    over = dict(prefills_per_step=2 if reason == "cap" else None)
+    if reason == "pool":
+        over.update(num_blocks=4)               # 3 usable blocks of 8
+    eng = ServingEngine(_family(family, **over), seed=SEED)
+    sched = eng.scheduler
+    c0 = telemetry.counter("serving.admit.stopped_by", reason=reason).value
+    assert sched.schedule().empty() and sched.last_stop is None
+    assert eng.stats()["prefill"]["stopped_by"] == _stops(), \
+        "nothing waiting: no pass to count"
+    if reason == "slots":
+        eng.streams.slots.alloc()               # one of the four is held
+    # prompts submitted, and how many of them the pass takes
+    n, admitted = {"lanes": (5, 4), "pool": (3, 1), "slots": (4, 3),
+                   "cap": (3, 2), "preempted": (0, 0),
+                   "queue": (3, 3)}[reason]
+    reqs = [eng.submit([1] * 9, 4) for _ in range(n)]
+    if reason == "preempted":
+        old, young = reqs = [eng.submit([1] * 7, 16) for _ in range(2)]
+        assert sched.schedule().prefills == reqs
+        _decoding(old)
+        _decoding(young)
+        eng.pool.alloc(eng.pool.available())    # the pool runs dry
+        old.context_len = young.context_len = 8
+        plan = sched.schedule()
+        assert plan.preempted == [young] and plan.prefills == []
+        want = {"queue": 1, "preempted": 1}
+    else:
+        assert sched.schedule().prefills == reqs[:admitted]
+        want = {reason: 1}
+    assert sched.last_stop == reason
+    assert eng.stats()["prefill"]["stopped_by"] == _stops(**want)
+    assert telemetry.counter("serving.admit.stopped_by",
+                             reason=reason).value == c0 + 1
+
+
+def test_no_count_is_the_default_and_the_environment_gives_one(monkeypatch):
+    """Nothing given and nothing in the environment: no count. An integer,
+    from either, caps a step as it always did."""
+    monkeypatch.delenv("MXNET_SERVING_PREFILLS_PER_STEP", raising=False)
+    assert ServingConfig(**CFG).prefills_per_step is None
+    assert _config(prefills_per_step=None).prefills_per_step is None
+    assert _config(prefills_per_step=3).prefills_per_step == 3
+    monkeypatch.setenv("MXNET_SERVING_PREFILLS_PER_STEP", "2")
+    assert ServingConfig(**CFG).prefills_per_step == 2
+    assert _config(prefills_per_step=5).prefills_per_step == 5
+    eng = ServingEngine(_config(prefills_per_step=None), seed=SEED)
+    assert eng.scheduler.prefills_per_step == 2
+
+
+def test_no_count_eight_prompts_are_one_group_and_the_event_says_why():
+    """The engine with no count: 8 prompts and 8 lanes are one step's
+    group, every first token the prompt's own; the step's
+    ``serving.step_timeline`` event names what ended its pass."""
+    eng = ServingEngine(_config(prefills_per_step=None), seed=SEED)
+    reqs = [eng.submit(list(range(1, 4 + i)), 12) for i in range(8)]
+    eng.step()
+    assert eng.stats()["prefill"]["prompts_per_group"] == 8.0
+    assert [r.state for r in reqs] == ["decoding"] * 8
+    ev = [e for e in telemetry.events("serving.step_timeline")
+          if e["engine"] == str(eng.engine_id)]
+    assert [(e["admitted"], e["stopped_by"]) for e in ev] == [(8, "queue")]
+    while eng.has_work():
+        eng.step()
+    ev = [e for e in telemetry.events("serving.step_timeline")
+          if e["engine"] == str(eng.engine_id)]
+    assert {e["stopped_by"] for e in ev[1:]} == {None}
+    alone = ServingEngine(_config(), seed=SEED)
+    assert [list(r.generated) for r in reqs] == [
+        alone.generate([list(r.prompt)], 12)[0] for r in reqs]
+
+
+# ---------------------------------------------------------------------------
 # a step's prefills are one group: dispatched back to back, then fetched
 # ---------------------------------------------------------------------------
 
@@ -733,7 +924,7 @@ def _family(name, **engine):
     window and state layers (Phi-4-mini-flash's), latent attention beside a
     share of the experts (dots.vlm1's)."""
     if name == "gpt2":
-        return _config(max_batch=4, **engine)
+        return _config(**{"max_batch": 4, **engine})
     path = os.path.join(ROOT, "benchmark", "rehearsal", "configs",
                         name + "-tiny.json")
     with open(path) as f:
@@ -770,14 +961,16 @@ def _serve_spied(scfg, prompts, n_new, hog):
                          ["gpt2", "olmoe", "phi4flash", "dotsvlm1"])
 def test_grouped_prefills_serve_the_tokens_of_one_prompt_a_step(family,
                                                                 pool):
-    """Up to four prompts a step, their prefills dispatched back to back
-    and fetched after the last dispatch, against ``prefills_per_step=1``
-    (a dispatch and its fetch a step: the sequence before groups): the
-    tokens are the same to the bit, for each layer family. Two prompts
-    with a common two-block prefix are admitted in the SAME step (neither
-    can map the other's blocks: the index is read at admission and written
-    after the fetch), a third with that prefix later; in the tight pool
-    streams are preempted and their replays run inside a group."""
+    """Every waiting prompt the lanes and the pool hold in one step (no
+    count: six lanes take all six prompts at once), up to four a step,
+    their prefills dispatched back to back and fetched after the last
+    dispatch, against ``prefills_per_step=1`` (a dispatch and its fetch a
+    step: the sequence before groups): the tokens are the same to the
+    bit, for each layer family. Two prompts with a common two-block prefix
+    are admitted in the SAME step (neither can map the other's blocks: the
+    index is read at admission and written after the fetch), a third with
+    that prefix later; in the tight pool streams are preempted and their
+    replays run inside a group."""
     scfg = _family(family)
     bs, rng = scfg.block_size, np.random.RandomState(5)
     draw = lambda n: [int(t) for t in rng.randint(0, scfg.vocab_size, n)]
@@ -789,17 +982,28 @@ def test_grouped_prefills_serve_the_tokens_of_one_prompt_a_step(family,
     n_new = [bs, 3 * bs, 3 * bs, bs + 2, 2 * bs, 2 * bs]
     hog = 14 if pool == "tight" else None
     served = {}
-    for pps in (4, 1):
+    for pps in (None, 4, 1):
+        # no count shows only where lanes are left over after four
+        over = dict(max_batch=6) if pps is None else {}
         eng, reqs, groups = _serve_spied(
-            _family(family, prefills_per_step=pps), prompts, n_new, hog)
+            _family(family, prefills_per_step=pps, **over), prompts, n_new,
+            hog)
         served[pps] = [list(r.generated) for r in reqs]
         stats = eng.stats()["prefill"]
         assert stats["prompts"] == sum(len(g) for g in groups)
         assert stats["groups"] == len(groups)
         assert stats["syncs_saved"] == stats["prompts"] - stats["groups"]
+        assert pps is not None or stats["stopped_by"]["cap"] == 0
         if pps == 1:
             assert {len(g) for g in groups} == {1}
             assert stats["prompts_per_group"] == 1.0
+            continue
+        if pps is None:
+            # one step takes what the pool holds of the six: all of them
+            # where it is roomy
+            assert groups[0] == [(i, False)
+                                 for i in range(len(groups[0]))]
+            assert len(groups[0]) == 6 or pool == "tight"
             continue
         # the first step admits the batch's four: both prompts of the
         # common prefix among them, fresh, side by side
@@ -814,6 +1018,7 @@ def test_grouped_prefills_serve_the_tokens_of_one_prompt_a_step(family,
             # the late third prompt of the prefix maps the two blocks the
             # first of the group wrote (its own writes to them go to trash)
             assert eng.pool.prefix_stats()["hit_blocks"] >= 2
+    assert served[None] == served[1]
     assert served[4] == served[1]
 
 
@@ -856,11 +1061,13 @@ def test_prefill_group_stats_by_hand():
     s0 = telemetry.counter("serving.prefill.syncs_saved").value
     assert eng.stats()["prefill"] == {
         "prompts": 0, "groups": 0, "prompts_per_group": 0.0,
-        "syncs_saved": 0}
+        "syncs_saved": 0, "stopped_by": _stops()}
     eng.generate([[1 + i, 2, 3] for i in range(5)], 3)
+    # the count of four ended the first pass, the queue's end the second;
+    # a pass that finds nothing waiting is no pass
     assert eng.stats()["prefill"] == {
         "prompts": 5, "groups": 2, "prompts_per_group": 2.5,
-        "syncs_saved": 3}
+        "syncs_saved": 3, "stopped_by": _stops(cap=1, queue=1)}
     g1 = telemetry.totals("serving.prefill.group")
     assert (g1[0] - g0[0], g1[1] - g0[1]) == (2, 5.0)
     assert telemetry.counter("serving.prefill.groups").value - n0 == 2

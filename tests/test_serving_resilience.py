@@ -121,6 +121,47 @@ def test_expired_request_times_out_and_frees_blocks():
     assert res["timed_out"] == 1 and res["cancelled"] == 0
 
 
+def test_deadlines_hold_when_one_steps_group_lasts_long():
+    """No ``prefills_per_step``: eight prompts are ONE group and the step
+    lasts as long as its eight prefills. A deadline that passes inside the
+    step is swept at the top of the NEXT one (there is no sweep inside a
+    step), admitted or waiting alike: the admitted stream's blocks and lane
+    come back before that step's admission pass, which hands the lane to
+    the live request behind it, and an expired request that waited is
+    never prefilled."""
+    eng = ServingEngine(_config(prefills_per_step=None), seed=SEED)
+    dispatch, prefilled = eng._dispatch_prefill, []
+
+    def slow(toks, length, *rest):
+        prefilled.append(int(length))
+        time.sleep(0.03)
+        return dispatch(toks, length, *rest)
+
+    eng._dispatch_prefill = slow
+    live = [eng.submit([1, 2, 3], 12) for _ in range(7)]
+    doomed = eng.submit([4, 5, 6, 7], 12, timeout_s=0.1)
+    stale = eng.submit([8] * 5, 12, timeout_s=0.1)      # no lane: it waits
+    heir = eng.submit([9] * 6, 2)
+    t0 = time.time()
+    eng.step()
+    assert time.time() - t0 >= 8 * 0.03
+    assert prefilled == [3] * 7 + [4], "one group of the eight lanes"
+    assert doomed.state == "decoding" and doomed.expired()
+    assert stale.state == "waiting" and stale.expired()
+    eng.step()
+    assert doomed.state == TIMED_OUT and stale.state == TIMED_OUT
+    assert doomed.blocks == [] and "deadline" in stale.error
+    assert prefilled == [3] * 7 + [4, 6], \
+        "the freed lane is the live heir's; the stale request never ran"
+    assert eng.stats()["prefill"]["stopped_by"] == {
+        "lanes": 1, "pool": 0, "slots": 0, "cap": 0, "preempted": 0,
+        "queue": 1}
+    _drain(eng)
+    assert [r.state for r in live + [heir]] == [FINISHED] * 8
+    assert eng.pool.used() == 0 and _pool_consistent(eng.pool)
+    assert eng.stats()["resilience"]["timed_out"] == 2
+
+
 def test_default_timeout_comes_from_config():
     eng = ServingEngine(_config(default_timeout_ms=50), seed=SEED)
     req = eng.submit([1, 2], 30)

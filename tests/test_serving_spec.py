@@ -247,6 +247,27 @@ def test_spec_decode_bit_identical_self_draft(k):
     assert 0 < spec["accepted_tokens"] <= spec["proposed_tokens"]
 
 
+@pytest.mark.parametrize("pps", [None, 4, 1])
+def test_spec_decode_same_tokens_whatever_a_step_admits(pps):
+    """The speculative path admits through the same pass: with no
+    ``prefills_per_step`` seven prompts and eight lanes are ONE group (the
+    draft's prefills dispatched beside the target's), with 4 two groups,
+    with 1 seven, and every stream is target-only decoding's to the bit."""
+    rng = np.random.RandomState(23)
+    prompts = _workload(rng, 6, CFG["vocab_size"])
+    prompts.append([1] * 8)     # block-boundary prompt
+    n_new = [int(x) for x in rng.randint(2, 14, len(prompts))]
+    want = ServingEngine(_config(spec_k=0), seed=SEED).generate(
+        prompts, n_new)
+    eng = ServingEngine(_config(spec_k=2, prefills_per_step=pps), seed=SEED)
+    assert eng.generate(prompts, n_new) == want
+    stats = eng.stats()
+    assert stats["spec"]["accepted_tokens"] > 0
+    assert stats["prefill"]["groups"] == {None: 1, 4: 2, 1: 7}[pps]
+    assert stats["prefill"]["stopped_by"]["cap"] == {None: 0, 4: 1, 1: 6}[pps]
+    assert eng.pool.used() == 0
+
+
 def test_spec_decode_bit_identical_tiny_draft():
     """A WRONG draft (tiny random preset, disjoint weights) must not
     change a single emitted token — greedy acceptance emits only the

@@ -297,7 +297,9 @@ def test_a_groups_dispatches_precede_its_fetches(serving_trace):
                              if n == "serving.prefill.fetch")
     assert serving_trace["stats"]["prefill"] == {
         "prompts": 3, "groups": 1, "prompts_per_group": 3.0,
-        "syncs_saved": 2}
+        "syncs_saved": 2,
+        "stopped_by": {"lanes": 0, "pool": 0, "slots": 0, "cap": 0,
+                       "preempted": 0, "queue": 1}}
 
 
 def test_paged_counters_count_the_blocks_the_kernel_walks(serving_trace):
