@@ -410,6 +410,15 @@ class ObservedJit:
             return rec.compile_count, rec.compile_seconds
 
     @property
+    def compiled(self):
+        """Whether a call of this wrapper has compiled or loaded its
+        program: a resident executable (the AOT lane) or an entry in jax's
+        own cache. The serving engine lays prompts into a prefill rung of
+        several only where this holds: a pack compiles nothing."""
+        return (self._aot_exe is not None or bool(self._cache_size())
+                or bool(self._own_sigs))
+
+    @property
     def __wrapped__(self):
         return self._jitted
 

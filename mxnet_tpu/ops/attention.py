@@ -48,15 +48,22 @@ def _scale(sm_scale, d):
     return 1.0 / np.sqrt(d) if sm_scale is None else sm_scale
 
 
-def attention_reference(q, k, v, causal=False, sm_scale=None):
-    """Naive softmax attention — the numeric oracle for tests (O(S^2) memory)."""
+def attention_reference(q, k, v, causal=False, sm_scale=None, window=None,
+                        first_key=None):
+    """Naive softmax attention — the numeric oracle for tests (O(S^2) memory).
+    ``window`` / ``first_key`` (Sq,): key j is visible to query i only if
+    ``i - j < window`` / ``j >= first_key[i]``."""
     sm_scale = _scale(sm_scale, q.shape[-1])
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32),
                    precision=lax.Precision.HIGHEST) * sm_scale
+    qi = jnp.arange(q.shape[2])[:, None]
+    ki = jnp.arange(k.shape[2])[None, :]
     if causal:
-        qi = jnp.arange(q.shape[2])[:, None]
-        ki = jnp.arange(k.shape[2])[None, :]
         s = jnp.where(qi >= ki, s, _NEG_INF)
+    if window is not None:
+        s = jnp.where(qi - ki < window, s, _NEG_INF)
+    if first_key is not None:
+        s = jnp.where(ki >= first_key[:, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32),
                       precision=lax.Precision.HIGHEST).astype(q.dtype)
@@ -87,9 +94,11 @@ def _block_update(q, k_blk, v_blk, m, l, acc, sm_scale, mask=None,
     return m_new, l_new, acc_new
 
 
-def _scan_forward(q, k, v, causal, sm_scale, block_k, window=None):
+def _scan_forward(q, k, v, causal, sm_scale, block_k, window=None,
+                  first_key=None):
     """Pure-XLA flash forward: lax.scan over KV blocks. Returns (out, lse) f32.
     ``window``: key j is visible to query i only if ``i - j < window``.
+    ``first_key`` (Sq,) int32: and only if ``j >= first_key[i]``.
     The values may be of another width than the keys."""
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
@@ -119,6 +128,8 @@ def _scan_forward(q, k, v, causal, sm_scale, block_k, window=None):
             mask = jnp.broadcast_to(mask, (sq, block_k))
         if window is not None:
             mask = mask & (qi[:, None] - ki[None, :] < window)
+        if first_key is not None:
+            mask = mask & (ki[None, :] >= first_key[:, None])
         m, l, acc = _block_update(qf, k_blk, v_blk, m, l, acc, sm_scale, mask,
                                   precision=prec)
         return (m, l, acc), None
@@ -134,13 +145,24 @@ def _scan_forward(q, k, v, causal, sm_scale, block_k, window=None):
 
 
 def _pallas_forward(q, k, v, causal, sm_scale, block_q=512, block_k=1024,
-                    interpret=False, window=None, kv_group=1, name=None):
+                    interpret=False, window=None, kv_group=1, name=None,
+                    first_key=None):
     """Pallas TPU flash-attention forward.
 
     ``window`` (sliding-window attention): key j is visible to query i only
     if ``i - j < window``; KV blocks wholly behind a q block's band are
     skipped like those above the diagonal, and the KV block is ``block_q``
     long so that the band skips something.
+
+    ``first_key`` (Sq,) int32, a floor a query row that does not decrease
+    along the rows (several texts end to end, each row's floor its own
+    text's first row): key j is visible to query i only if ``j >=
+    first_key[i]``, of which ``window`` is the case ``i - window + 1``; both
+    hold together. KV blocks wholly below the floor of a q block's FIRST
+    row are skipped like those above the diagonal, and are not fetched (the
+    index map names the first block that is read in their place), so the
+    texts cost about the sum of their own attention and not the square of
+    their sum; the KV block is ``block_q`` long here too.
 
     Grid (batch*heads, q_blocks, kv_blocks) with the KV axis innermost: TPU
     executes the grid sequentially along the last axis, so (m, l, acc) live in
@@ -162,14 +184,20 @@ def _pallas_forward(q, k, v, causal, sm_scale, block_q=512, block_k=1024,
 
     b, h, sq, d = q.shape
     sk, dv = k.shape[2], v.shape[3]
-    if window is not None:
+    floored = first_key is not None
+    if window is not None or floored:
         block_k = min(block_k, block_q)
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     n_q = -(-sq // block_q)  # ragged tails are masked inside the kernel
     n_k = -(-sk // block_k)
 
-    def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref):
+    def kernel(*refs):
+        if floored:     # the q blocks' first floors (SMEM), the rows' own
+            floor0_ref, q_ref, k_ref, v_ref, floor_ref, *refs = refs
+        else:
+            q_ref, k_ref, v_ref, *refs = refs
+        o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
         qi_blk = pl.program_id(1)
         kj = pl.program_id(2)
 
@@ -186,6 +214,9 @@ def _pallas_forward(q, k, v, causal, sm_scale, block_q=512, block_k=1024,
             # the block's last key is inside the first row's window
             run = run & (kj * block_k + block_k - 1
                          > qi_blk * block_q - window)
+        if floored:
+            # the block's last key is at or above the first row's floor
+            run = run & (kj * block_k + block_k - 1 >= floor0_ref[qi_blk])
 
         @pl.when(run)
         def _step():
@@ -200,6 +231,8 @@ def _pallas_forward(q, k, v, causal, sm_scale, block_q=512, block_k=1024,
                 mask = mask & (q_pos >= k_pos)
             if window is not None:
                 mask = mask & (q_pos - k_pos < window)
+            if floored:
+                mask = mask & (k_pos >= floor_ref[0, 0][:, None])
             s = jnp.where(mask, s, _NEG_INF)
             m = m_ref[:]
             m_blk = jnp.max(s, axis=-1)
@@ -231,33 +264,55 @@ def _pallas_forward(q, k, v, causal, sm_scale, block_q=512, block_k=1024,
         vr = jnp.pad(vr, ((0, 0), (0, pad_k), (0, 0)))
     grid = (bh, n_q, n_k)
 
-    def kv_at(i, j, kk):
+    def kv_at(i, j, kk, *floor0):
+        if floored:     # a block below the floor: the first that is read
+            kk = jnp.maximum(kk, floor0[0][j] // block_k)
         return (i // kv_group if kv_group > 1 else i), kk, 0
 
+    def q_at(i, j, kk, *_floor0):
+        return i, j, 0
+
+    def row_at(i, j, kk, *_floor0):
+        return i, 0, j
+
+    in_specs = [
+        pl.BlockSpec((1, block_q, d), q_at),
+        pl.BlockSpec((1, block_k, d), kv_at),
+        pl.BlockSpec((1, block_k, dv), kv_at),
+    ]
+    out_specs = [
+        pl.BlockSpec((1, block_q, dv), q_at),
+        pl.BlockSpec((1, 1, block_q), row_at),
+    ]
+    scratch_shapes = [
+        pltpu.VMEM((block_q,), jnp.float32),
+        pltpu.VMEM((block_q,), jnp.float32),
+        pltpu.VMEM((block_q, dv), jnp.float32),
+    ]
+    operands = (qr, kr, vr)
+    if floored:
+        # the rows' floors as the lse leaves: a q block's a lane vector;
+        # the padded tail keeps the last row's (the floors do not decrease)
+        floors = jnp.pad(first_key.astype(jnp.int32), (0, pad_q), mode="edge")
+        in_specs.append(pl.BlockSpec(
+            (1, 1, block_q), lambda i, j, kk, _floor0: (0, 0, j)))
+        operands = (floors[::block_q],) + operands + (floors[None, None],)
+        how = {"grid_spec": pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch_shapes)}
+    else:
+        how = {"grid": grid, "in_specs": in_specs, "out_specs": out_specs,
+               "scratch_shapes": scratch_shapes}
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), kv_at),
-            pl.BlockSpec((1, block_k, dv), kv_at),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, dv), lambda i, j, kk: (i, j, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda i, j, kk: (i, 0, j)),
-        ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, n_q * block_q, dv), jnp.float32),
             jax.ShapeDtypeStruct((bh, 1, n_q * block_q), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q, dv), jnp.float32),
-        ],
         interpret=interpret,
+        **how,
         **({} if name is None else {"name": name}),
-    )(qr, kr, vr)
+    )(*operands)
     out = out[:, :sq].reshape(b, h, sq, dv)
     lse = lse[:, 0, :sq].reshape(b, h, sq)
     return out, lse
@@ -464,26 +519,43 @@ def _scan_backward(q, k, v, out, lse, g, causal, sm_scale, block_k):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal=False, sm_scale=None, block_k=256,
-                    window=None):
+                    window=None, first_key=None):
     """Memory-efficient attention over (batch, heads, seq, head_dim).
     ``window`` (forward only): key j is visible to query i only if
     ``i - j < window`` — with ``causal``, a sliding window of ``window``
-    keys that ends at the query's own."""
-    out, _ = _forward_impl(q, k, v, causal, sm_scale, block_k, window)
+    keys that ends at the query's own. ``first_key`` (seq,) int32 (forward
+    only): and only if ``j >= first_key[i]``, a floor a query row that does
+    not decrease along the rows — several texts end to end in one call,
+    each attending to itself alone. None is the program there always was."""
+    out, _ = _forward_impl(q, k, v, causal, sm_scale, block_k, window,
+                           first_key)
     return out
 
 
-def _forward_impl(q, k, v, causal, sm_scale, block_k, window=None):
+def _with_floor(fn, first_key, **kw):
+    """``(fn with kw bound, the operands behind q, k and v)``: a floor rides
+    as a fourth array operand (``lax.platform_dependent``'s branches take
+    arrays alone); without one ``fn`` is the partial it always was."""
+    if first_key is None:
+        return functools.partial(fn, **kw), ()
+    return (lambda q, k, v, floor: fn(q, k, v, first_key=floor, **kw),
+            (first_key,))
+
+
+def _forward_impl(q, k, v, causal, sm_scale, block_k, window=None,
+                  first_key=None):
     sm_scale = _scale(sm_scale, q.shape[-1])
-    if window is not None:
-        kw = {"causal": causal, "sm_scale": sm_scale, "window": int(window)}
+    if window is not None or first_key is not None:
+        kw = {"causal": causal, "sm_scale": sm_scale,
+              "window": None if window is None else int(window)}
+        scan, floor = _with_floor(_scan_forward, first_key, block_k=block_k,
+                                  **kw)
         if _pallas_shapes_ok(q, k):
             out, lse = lax.platform_dependent(
-                q, k, v, tpu=functools.partial(_pallas_forward, **kw),
-                default=functools.partial(_scan_forward, block_k=block_k,
-                                          **kw))
+                q, k, v, *floor, default=scan,
+                tpu=_with_floor(_pallas_forward, first_key, **kw)[0])
         else:
-            out, lse = _scan_forward(q, k, v, block_k=block_k, **kw)
+            out, lse = scan(q, k, v, *floor)
     elif _pallas_shapes_ok(q, k):
         # platform selected at LOWERING time, not trace time: the same traced
         # function may compile for the TPU (Pallas kernel) or for CPU (scan) —
@@ -499,37 +571,43 @@ def _forward_impl(q, k, v, causal, sm_scale, block_k, window=None):
     return out.astype(q.dtype), lse
 
 
-def _fa_fwd(q, k, v, causal, sm_scale, block_k, window):
-    out, lse = _forward_impl(q, k, v, causal, sm_scale, block_k, window)
-    return out, (q, k, v, out, lse)
+def _fa_fwd(q, k, v, causal, sm_scale, block_k, window, first_key=None):
+    out, lse = _forward_impl(q, k, v, causal, sm_scale, block_k, window,
+                             first_key)
+    return out, (q, k, v, out, lse, first_key)
 
 
 def _fa_bwd(causal, sm_scale, block_k, window, res, g):
-    if window is not None:
+    q, k, v, out, lse, first_key = res
+    if window is not None or first_key is not None:
         raise NotImplementedError(
-            "flash_attention(window=) is forward-only (serving prefill)")
-    q, k, v, out, lse = res
+            "flash_attention(window=) and (first_key=) are forward-only "
+            "(serving prefill)")
     scale = _scale(sm_scale, q.shape[-1])
     if _pallas_shapes_ok(q, k):
-        return lax.platform_dependent(
+        grads = lax.platform_dependent(
             q, k, v, out, lse, g,
             tpu=functools.partial(_pallas_backward, causal=causal, sm_scale=scale),
             default=functools.partial(_scan_backward, causal=causal,
                                       sm_scale=scale, block_k=block_k),
         )
-    return _scan_backward(q, k, v, out, lse, g, causal, scale, block_k)
+    else:
+        grads = _scan_backward(q, k, v, out, lse, g, causal, scale, block_k)
+    return tuple(grads) + (None,)       # first_key: None has no cotangent
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
 def flash_attention_gqa(q, k, v, sm_scale=None, window=None, sink=None,
-                        block_k=256):
+                        block_k=256, first_key=None):
     """Causal grouped-query attention, forward only (serving prefill):
     q ``(B, H, S, D)`` over k ``(B, Hkv, S, D)`` and v ``(B, Hkv, S, Dv)``,
     query head j reading K/V head ``j // (H / Hkv)``.
 
     ``window``: key j is visible to query i only if ``i - j < window``.
+    ``first_key`` (S,) int32: and only if ``j >= first_key[i]``
+    (:func:`flash_attention`'s; None is the program there always was).
     ``sink`` (H,) float32: a scalar a query head that joins the softmax's
     denominator and no numerator, ``p_ij = exp(s_ij) / (exp(b) + sum_j'
     exp(s_ij'))``: the plain result times ``sigmoid(lse - b)``.
@@ -543,18 +621,19 @@ def flash_attention_gqa(q, k, v, sm_scale=None, window=None, sink=None,
     kw = {"causal": True, "sm_scale": sm_scale,
           "window": None if window is None else int(window)}
 
-    def scan(q, k, v):
+    def repeated(q, k, v, **floor):
         return _scan_forward(q, jnp.repeat(k, group, axis=1),
                              jnp.repeat(v, group, axis=1), block_k=block_k,
-                             **kw)
+                             **kw, **floor)
 
+    scan, floor = _with_floor(repeated, first_key)
     if _pallas_shapes_ok(q, k):
         out, lse = lax.platform_dependent(
-            q, k, v, tpu=functools.partial(_pallas_forward, kv_group=group,
-                                           name="flash_gqa_fwd", **kw),
-            default=scan)
+            q, k, v, *floor, default=scan,
+            tpu=_with_floor(_pallas_forward, first_key, kv_group=group,
+                            name="flash_gqa_fwd", **kw)[0])
     else:
-        out, lse = scan(q, k, v)
+        out, lse = scan(q, k, v, *floor)
     if sink is not None:
         out = out * jax.nn.sigmoid(lse - sink[None, :, None])[..., None]
     return out.astype(q.dtype)

@@ -44,6 +44,7 @@ from .scheduler import (CANCELLED, DECODING, FAILED, FINISHED, TIMED_OUT,
                         WAITING, Request, Scheduler)
 
 _SITE = "serving/engine.py"
+_PACK_ALIGN = _model.PACK_ALIGN
 _CAT = "serving"   # the chrome-trace category of the engine's spans
 
 
@@ -305,6 +306,40 @@ def _bucket_for(n, buckets):
     raise ValueError("no bucket holds %d (buckets %s)" % (n, buckets))
 
 
+def cut_packs(need, buckets, warm, most):
+    """Prompts of ``need`` rows each (whole ``PACK_ALIGN`` tiles), in plan
+    order, cut into runs of neighbours, a run a prefill program:
+    ``[(first, end, rung), ...]``, the cut that computes the fewest ROWS —
+    the sum of the runs' rungs: a row costs the device the same in any
+    program, so the rows a pack does not compute are what it saves — and
+    among equals the fewest programs. A prompt alone runs the smallest of
+    ``buckets`` that holds it; a run of several, at most ``most`` prompts,
+    the smallest of ``warm`` (ascending: the rungs whose program is
+    compiled), and none where no such rung holds it. One pass, each prompt
+    trying the at most ``most - 1`` before it. (Closing a pack greedily
+    when the next prompt would pass the top rung computes 6% more rows on
+    dots.vlm1's lengths: ``tests/test_prefill_pack.py``.)"""
+    # least[i]: (rows, programs, where the last run starts, its rung) of
+    # the best cut of the first i prompts
+    least = [(0, 0, 0, 0)]
+    for i in range(1, len(need) + 1):
+        rows, cuts = 0, []
+        for j in range(i - 1, max(i - most, 0) - 1, -1):
+            rows += need[j]
+            rung = (_bucket_for(rows, buckets) if j == i - 1
+                    else next((b for b in warm if rows <= b), None))
+            if rung is None:
+                break
+            cuts.append((least[j][0] + rung, least[j][1] + 1, j, rung))
+        least.append(min(cuts))
+    runs, i = [], len(need)
+    while i:
+        _rows, _programs, j, rung = least[i]
+        runs.append((j, i, rung))
+        i = j
+    return runs[::-1]
+
+
 class ServingEngine:
     """Continuous-batching inference over the Transformer-LM zoo model."""
 
@@ -397,9 +432,11 @@ class ServingEngine:
         # how often the chunk engages: device steps over dispatches
         self._decode_dispatches = 0
         self._decode_inner_steps = 0
-        # how often a step's prefills form a group: prompts over groups
+        # how often a step's prefills form a group: prompts over groups;
+        # and how often a group's prompts share a program: over programs
         self._prefill_prompts = 0
         self._prefill_groups = 0
+        self._prefill_programs = 0
         # the loop's record of the step under way (obs.LoopRecord's fields;
         # None outside a step and while telemetry is off) and what its two
         # gaps are measured from: the host's clock at the last blocking
@@ -522,11 +559,6 @@ class ServingEngine:
                               cache_key=("serving.decode",) + ckey_base
                               + (B, chunk), **decode_donate)
             for B in cfg.decode_buckets()}
-        # bucket dispatch: call sites pad to an exact bucket shape, so the
-        # padded dims index the wrapper table directly
-        self._prefill_fn = lambda params, toks, L, table, kp, vp, *aux: \
-            self._prefill_jits[toks.shape[1]](params, toks, L, table,
-                                              kp, vp, *aux)
 
         # ---- speculative decoding: draft model + verify pass ----------
         # two more compileobs program families riding the same nonce-free
@@ -617,8 +649,8 @@ class ServingEngine:
                 for B in cfg.decode_buckets()}
             self._draft_prefill_fn = \
                 lambda params, toks, L, table, kp, vp: \
-                self._draft_prefill_jits[toks.shape[1]](
-                    params, toks, L, table, kp, vp)
+                self._draft_prefill_jits[toks.shape[1]](*self._as_pack(
+                    dcfg, params, toks, L, table, kp, vp))
             self._draft_decode_fn = \
                 lambda params, toks, poss, tables, ctx, kp, vp: \
                 self._draft_decode_jits[toks.shape[0]](
@@ -1133,7 +1165,7 @@ class ServingEngine:
             for S in prefill_buckets:
                 toks = np.zeros((1, S), np.int32)
                 table = np.zeros(S // cfg.block_size, np.int32)
-                self._dispatch_prefill(toks, 1, table)
+                self._dispatch_prefill(toks, [(0, 1)], table)
             for B in cfg.decode_buckets():
                 toks = np.zeros(B, np.int32)
                 poss = np.zeros(B, np.int32)
@@ -1196,14 +1228,15 @@ class ServingEngine:
         table = np.zeros(S // cfg.block_size, np.int32)
         with self._lock:
             self._flush()
-            _t, logits = self._dispatch_prefill(toks, n, table)
+            _t, logits = self._dispatch_prefill(toks, [(0, n)], table)
         return np.asarray(logits, np.float32)[0]  # fwlint: disable=device-escape — the logits are what the caller asked for
 
     def decode_logits(self, texts, starts):
         """Each text's next-token logits ``(len(texts), V)`` float32, its
         first ``starts[i]`` tokens through prefill into a scratch stream of
         its own (blocks and, for a model that has them, a state slot and
-        window blocks, booked for the call and returned after it) and the
+        window blocks, booked for the call and returned after it; the
+        prefixes laid end to end in the programs a step's packs run) and the
         rest forced one step at a time through the decode program — every
         text TOGETHER, a lane a text, at the batch bucket of ``len(texts)``:
         ragged contexts side by side as a serving step has them, a lane
@@ -1230,13 +1263,13 @@ class ServingEngine:
                         self.pool.blocks_for(len(text)))
                     if self.streams is not None:
                         self.streams.admit(req, n)
-                    S = _bucket_for(n, cfg.prefill_buckets())
-                    toks = np.zeros((1, S), np.int32)
-                    toks[0, :n] = text[:n]
-                    width = S // cfg.block_size
-                    self._dispatch_prefill(
-                        toks, n, self._table_row(req.blocks, width),
-                        self._table_row(req.wblocks, width), req.slot or 0)
+                # the texts' prefixes through the programs a step's packs
+                # run, cut as a step cuts them: what reads these logits
+                # holds a PACK to its reference
+                for pack, S in self._cut_packs(lanes):
+                    toks, spans, table, wtable = self._lay_pack(pack, S)
+                    self._dispatch_prefill(toks, spans, table, wtable,
+                                           pack[0][0].slot or 0)
                 for step in range(max(len(t) - n
                                       for t, n in zip(texts, starts))):
                     # fresh arrays a step: a dispatch may still read the last
@@ -1383,25 +1416,60 @@ class ServingEngine:
         pool.extra_nbytes = window_pool.nbytes() + state.nbytes()
         return pool, window_pool, state, streams
 
-    def _dispatch_prefill(self, toks, length, table, wtable=None, slot=0):
+    def _dispatch_prefill(self, toks, spans, table, wtable=None, slot=0):
         """Run the prefill program of ``toks``' bucket over the caches and
-        keep what it hands back; ``(next token, logits)``, both on the
-        device. ``wtable`` / ``slot``: a hybrid model's window-pool table
-        and state slot (default: all trash)."""
+        keep what it hands back; ``(next tokens, logits)``, both on the
+        device, a row a prompt the program takes (``model.pack_width``).
+        ``spans``: the prompts' ``(first row, length)`` in ``toks``, one
+        ``(0, n)`` for a prompt alone. ``wtable`` / ``slot``: a hybrid
+        model's window-pool table and state slot (default: all trash)."""
+        length = _model.pack_of(self.config, toks.shape[1], spans)
         if self.streams is None:
             tok, logits, kp, vp = self._prefill_fn(
-                self.params, toks, np.int32(length), table,
+                self.params, toks, length, table,
                 self.pool.k_pages, self.pool.v_pages)
         else:
             if wtable is None:
                 wtable = np.zeros_like(table)
             tok, logits, kp, vp, *aux = self._prefill_fn(
-                self.params, toks, np.int32(length), table,
+                self.params, toks, length, table,
                 self.pool.k_pages, self.pool.v_pages, wtable,
                 np.int32(slot), *self._aux())
             self._keep_aux(aux)
         self.pool.k_pages, self.pool.v_pages = kp, vp
         return tok, logits
+
+    def _prefill_fn(self, params, toks, length, table, kp, vp, *aux):
+        """The prefill program of ``toks``' rung (bucket dispatch: call
+        sites pad to an exact bucket shape, so the padded dim indexes the
+        wrapper table directly). ``length`` and ``table`` (and a hybrid
+        model's window table, the first of ``aux``): what
+        ``model.pack_of`` makes and ``model.pack_blocks`` entries, or a
+        prompt's bare length and the blocks of its rung, which are made a
+        pack of one here — the one prompt from row 0 that every rung's
+        program takes, and the very executable a step's pack runs, loads
+        or compiles."""
+        return self._prefill_jits[toks.shape[1]](*self._as_pack(
+            self.config, params, toks, length, table, kp, vp, *aux))
+
+    def _as_pack(self, cfg, params, toks, length, table, *rest):
+        """A prefill program's arguments with a bare length and a table of
+        the rung's own blocks made the pack of one (:meth:`_prefill_fn`);
+        a table as wide as ``cfg``'s program takes (a draft that takes one
+        prompt is handed the target's, wider by empty entries)."""
+        S = toks.shape[1]
+        if np.ndim(length) == 0:
+            length = _model.pack_of(cfg, S, [(0, int(length))])
+        width = _model.pack_blocks(cfg, S, self.config.block_size)
+
+        def whole(t):
+            return np.concatenate(
+                [t, np.zeros(max(width - len(t), 0), np.int32)])[:width]
+
+        if cfg.hybrid:
+            kp, vp, wtable, *rest = rest
+            rest = [kp, vp, whole(wtable)] + rest
+        return (params, toks, length, whole(table)) + tuple(rest)
 
     def _decode_fn(self, params, toks, poss, tables, ctx, kp, vp, *aux,
                    left=None, eos=None, n=1):
@@ -1451,21 +1519,24 @@ class ServingEngine:
          self.state.conv, self.state.ssm) = aux
 
     def _run_prefills(self, reqs):
-        """Run the step's admitted prompts as ONE GROUP: every prompt's
-        program is dispatched, in plan order, before the first blocking
-        fetch. Prompt i+1's program queues behind prompt i's on the device
-        (the pages and a hybrid model's caches chain from one call into
-        the next, device to device), the host builds prompt i+1's arrays
-        while the device runs prompt i, and books prompt i while it runs
-        prompt i+1: a group of k exposes one host gap, after its last
-        program, where k dispatch-fetch pairs exposed k. Every first token
-        is on the host when this returns: nothing is in flight when the
-        step schedules its decode. A group of one is a dispatch and its
-        fetch, as a prompt alone always was. Where no chunk follows the
-        group, its last dispatch is the step's last before a blocking
+        """Run the step's admitted prompts as ONE GROUP of PACKS: the
+        prompts, in plan order, are laid end to end into as few prefill
+        programs as hold them (:meth:`_cut_packs`), and every pack's
+        program is dispatched before the first blocking fetch. Pack i+1's
+        program queues behind pack i's on the device (the pages and a
+        hybrid model's caches chain from one call into the next, device to
+        device), the host builds pack i+1's arrays while the device runs
+        pack i, and books pack i's requests while it runs pack i+1: a
+        group exposes one host gap, after its last program. Every first
+        token is on the host when this returns: nothing is in flight when
+        the step schedules its decode. A group of one prompt is a dispatch
+        and its fetch, as a prompt alone always was. Where no chunk follows
+        the group, its last dispatch is the step's last before a blocking
         fetch: the step before's item (:meth:`_flush`) runs under it."""
-        grouped = len(reqs) > 1
-        flights = [self._start_prefill(req, grouped) for req in reqs]
+        packs = self._cut_packs(reqs)
+        grouped = len(packs) > 1
+        flights = [self._start_prefill(pack, S, grouped)
+                   for pack, S in packs]
         # no chunk follows where every prompt ends at its first token and
         # nothing else decodes (an EOS there cannot be foreseen: _set_aside
         # then finds the item still waiting)
@@ -1479,40 +1550,92 @@ class ServingEngine:
         self._gap_after = "group"
         self._prefill_prompts += len(reqs)
         self._prefill_groups += 1
+        self._prefill_programs += len(packs)
         telemetry.histogram("serving.prefill.group").observe(len(reqs))
         telemetry.counter("serving.prefill.groups").inc()
         telemetry.counter("serving.prefill.syncs_saved").inc(len(reqs) - 1)
+        for pack, _S in packs:
+            telemetry.histogram("serving.prefill.pack").observe(len(pack))
 
-    def _start_prefill(self, req, grouped):
-        """Build and dispatch ``req``'s prefill; nothing of it is waited
-        for. Returns what :meth:`_finish_prefill` takes. ``grouped``:
-        other prompts' programs run before the fetch, so the first
-        token's copy to the host is asked for now, to start when the
-        program ends and not when the host gets round to it."""
+    def _cut_packs(self, reqs):
+        """The step's prompts, in plan order, as ``(pack, rung)``: a pack
+        the ``(request, the tokens it prefills)`` of neighbours that share
+        the program of ``rung`` rows, cut to compute the fewest rows
+        (:func:`cut_packs`). A pack of several holds at most the prompts its
+        program takes — one, for a model with state slots
+        (``model.pack_width``) — and runs only in a rung whose program is
+        compiled (``warmup(prefill_buckets=)`` or an earlier step's call: a
+        deployment warms the rungs its lengths reach); a prompt alone runs,
+        and compiles, its own rung as it always did: a pack compiles
+        nothing."""
         cfg = self.config
-        replay = req.replay_tokens()
-        L = len(replay)
-        S = _bucket_for(L, cfg.prefill_buckets())
-        args = {"request_id": req.request_id, "prompt_len": L, "bucket": S}
-        with telemetry.span("serving.prefill.build", _CAT, **args) as build:
-            toks = np.zeros((1, S), np.int32)
-            toks[0, :L] = replay
-            table = self._table_row(req.blocks, S // cfg.block_size)
-            wtable = self._table_row(req.wblocks, S // cfg.block_size)
+        jits = [self._prefill_jits] + (
+            [self._draft_prefill_jits] if self._spec else [])
+        warm = [S for S in cfg.prefill_buckets()
+                if all(j[S].compiled for j in jits)]
+        replays = [req.replay_tokens() for req in reqs]
+        # (a draft that takes one prompt a program holds the target to one)
+        models = [cfg] + ([self.draft_config] if self._spec else [])
+        runs = cut_packs(
+            [-(-len(t) // _PACK_ALIGN) * _PACK_ALIGN for t in replays],
+            cfg.prefill_buckets(), warm,
+            min(_model.pack_width(m, warm[-1]) for m in models)
+            if warm else 1)
+        return [(list(zip(reqs[j:i], replays[j:i])), rung)
+                for j, i, rung in runs]
+
+    def _lay_pack(self, pack, S):
+        """A pack's arrays: its prompts' tokens end to end in the rung of
+        ``S`` rows, each from a multiple of ``PACK_ALIGN``; ``(tokens
+        (1, S), spans [(first row, length), ...], write table, window
+        table)``. In the tables a prompt's blocks stand behind those of
+        the prompts before it, whole blocks each (``model.pack_blocks``
+        entries); the entries behind the last stay 0: their writes go to
+        the trash block."""
+        cfg = self.config
+        bs = cfg.block_size
+        toks = np.zeros((1, S), np.int32)
+        write_table, wtable = (
+            np.zeros(_model.pack_blocks(cfg, S, bs), np.int32)
+            for _ in range(2))
+        spans, row, block = [], 0, 0
+        for req, replay in pack:
+            L = len(replay)
+            nb = -(-L // bs)
+            at = slice(block, block + nb)
+            toks[0, row:row + L] = replay
+            write_table[at] = self._table_row(req.blocks, nb)
+            wtable[at] = self._table_row(req.wblocks, nb)
             # prefix sharing: blocks mapped from the index already hold
             # this prefix's K/V — route their WRITE entries to the trash
             # block so the scatter cannot touch a shared block
             # (copy-on-write contract; the logits are untouched, the
             # table only steers the scatter)
-            write_table = table
             if req.shared_blocks:
-                write_table = table.copy()
-                write_table[:min(req.shared_blocks, len(write_table))] = 0
+                write_table[at][:req.shared_blocks] = 0
+            spans.append((row, L))
+            row += -(-L // _PACK_ALIGN) * _PACK_ALIGN
+            block += nb
+        return toks, spans, write_table, wtable
+
+    def _start_prefill(self, pack, S, grouped):
+        """Build (:meth:`_lay_pack`) and dispatch ONE program, the rung of
+        ``S`` rows, for ``pack``'s prompts; nothing of it is waited for.
+        Returns what
+        :meth:`_finish_prefill` takes.
+        ``grouped``: other packs' programs run before the fetch, so the
+        first tokens' copy to the host is asked for now, to start when the
+        program ends and not when the host gets round to it."""
+        args = {"request_id": pack[0][0].request_id,
+                "prompt_len": sum(len(replay) for _req, replay in pack),
+                "bucket": S, "prompts": len(pack)}
+        with telemetry.span("serving.prefill.build", _CAT, **args) as build:
+            toks, spans, write_table, wtable = self._lay_pack(pack, S)
             # compile-tally delta around the dispatch CALL (jax compiles
             # inside it): a bump means THIS call sat behind a cold prefill
-            # bucket — that wall is the request's compile_stall, not
-            # honest prefill time, and no part of it is the device time of
-            # the prompts queued before it
+            # bucket — that wall is the compile_stall of the requests in
+            # it, not honest prefill time, and no part of it is the device
+            # time of the prompts queued before it
             jits = [self._prefill_jits[S]] + (
                 [self._draft_prefill_jits[S]] if self._spec else [])
             s0 = sum(j.compile_totals()[1] for j in jits)
@@ -1524,16 +1647,17 @@ class ServingEngine:
         t0 = time.time()
         with telemetry.span("serving.prefill.dispatch", _CAT,
                             **args) as dispatch:
-            tok, _logits = self._dispatch_prefill(toks, L, write_table,
-                                                  wtable, req.slot or 0)
+            tok, _logits = self._dispatch_prefill(
+                toks, spans, write_table, wtable, pack[0][0].slot or 0)
             self._dispatched(dispatch)
             if self._spec:
-                # the draft caches the same replay through the same write
+                # the draft caches the same replays through the same write
                 # table into its OWN pages (its K/V never mixes with the
                 # target's); shared blocks were draft-cached by the
                 # prefix's original prefill, same as the target pages
                 _dt, _dl, dkp, dvp = self._draft_prefill_fn(
-                    self._draft_params, toks, np.int32(L), write_table,
+                    self._draft_params, toks, _model.pack_of(
+                        self.draft_config, S, spans), write_table,
                     self._draft_kp, self._draft_vp)
                 self._draft_kp, self._draft_vp = dkp, dvp
             if grouped:
@@ -1541,36 +1665,48 @@ class ServingEngine:
         self._book("prefill_dispatch_s", dispatch)
         stall = min(sum(j.compile_totals()[1] for j in jits) - s0,
                     time.time() - t0)
-        return req, replay, args, tok, t0, stall
+        return pack, args, tok, t0, stall
 
-    def _finish_prefill(self, req, replay, args, tok, t0, stall):
-        """Fetch a started prefill's first token (the blocking sync: it
-        returns when THIS prompt's program has ended, whatever is queued
-        behind it) and book the request."""
+    def _finish_prefill(self, pack, args, tok, t0, stall):
+        """Fetch a started pack's first tokens (the blocking sync: it
+        returns when THIS pack's program has ended, whatever is queued
+        behind it) and book its requests, each as a prompt alone was."""
         cfg = self.config
-        L = len(replay)
         with telemetry.span("serving.prefill.fetch", _CAT, **args) as fetch:
             # the per-step token egress: serving's output IS this transfer
-            tok = np.asarray(tok)  # fwlint: disable=device-escape — token egress to the client is the product, one scalar per prefill (+ the experts' load in the same array)
+            tok = np.asarray(tok)  # fwlint: disable=device-escape — token egress to the client is the product, one scalar per prompt (+ the experts' load in the same array)
             self._fetched()
-            tok, load = _unpack_fetch(tok, (1,), cfg)
-            tok = int(tok[0])
+            toks, load = _unpack_fetch(
+                tok, (_model.pack_width(cfg, args["bucket"]),), cfg)
             fetch.set(**_moe_args(load, self.config))
         self._book("prefill_fetch_s", fetch)
         wall = time.time() - t0
+        # the program's own: the experts' load counts the pack's valid
+        # rows, the rung's rows were computed once; every prompt of it
+        # took every pass of a looped stack
+        self._note_moe(load, args["prompt_len"])
+        self._note_passes(len(pack))
+        telemetry.counter("serving.prefill_rows").inc(args["bucket"])
+        if self._rec is not None:
+            self._rec["prefill_rows"] += args["bucket"]
+            self._rec["prefill_programs"] += 1
+        for (req, replay), tok in zip(pack, toks):
+            self._book_prefill(req, replay, int(tok), wall, stall)
+
+    def _book_prefill(self, req, replay, tok, wall, stall):
+        """A request's share of a finished pack: its first token ``tok``,
+        the wall it waited, the compile stall it sat behind."""
+        cfg = self.config
+        L = len(replay)
         with telemetry.span("serving.retire", _CAT,
                             request_id=req.request_id) as retire:
-            self._note_moe(load, L)
-            self._note_passes(1)
             if cfg.latent:
                 self._latent["prefill_tokens"] += L
                 telemetry.counter("serving.latent.prefill_tokens").inc(L)
             telemetry.histogram("serving.prefill_seconds").observe(wall)
             telemetry.counter("serving.prefill_tokens").inc(L)
-            telemetry.counter("serving.prefill_rows").inc(args["bucket"])
             if self._rec is not None:
                 self._rec["prefill_tokens"] += L
-                self._rec["prefill_rows"] += args["bucket"]
             if cfg.linear:
                 for name, n in (
                         ("prefill_tokens", L * self._linear_layers),
@@ -1935,8 +2071,9 @@ class ServingEngine:
         return int((-(-ctx // self.config.block_size)).sum())
 
     def _note_passes(self, steps, rec=True):
-        """Book ``steps`` program steps of a looped stack (a prefill, an
-        inner decode step, a verify pass): ``loop_steps`` passes each.
+        """Book ``steps`` program steps of a looped stack (a prompt's
+        prefill, an inner decode step, a verify pass): ``loop_steps``
+        passes each.
         Returns ``{"passes": n}`` for the step's span and record, {} for
         a stack that runs once; ``rec``: add them to the record of the
         step under way (the chunk's are added when its item is carried
@@ -2225,6 +2362,10 @@ class ServingEngine:
                     "prompts_per_group":
                         (self._prefill_prompts / self._prefill_groups)
                         if self._prefill_groups else 0.0,
+                    "programs": self._prefill_programs,
+                    "prompts_per_program":
+                        (self._prefill_prompts / self._prefill_programs)
+                        if self._prefill_programs else 0.0,
                     # blocking fetches that no longer expose a host gap:
                     # every prompt of a group but its last
                     "syncs_saved":

@@ -1424,26 +1424,149 @@ def _layers(x, params, cfg, prec, positions, valid, attend, state,
     return x, state, ()
 
 
+#: the most prompts one prefill program takes (:func:`pack_width`): a pack
+#: of sixteen short prompts already fills a long rung, and the head's rows
+#: and the fetch grow with it
+PACK_MAX = 16
+
+#: a pack's prompts start on whole sublane tiles of rows, not on blocks: a
+#: row costs the device the same in any program (PERF.md section 6, PR 51),
+#: so what a pack saves is the rows it does not compute
+PACK_ALIGN = 8
+
+
+def _one_prompt(cfg):
+    """Whether a prefill program of this model takes ONE prompt, its bare
+    length the argument — the program it always traced, to the character.
+    A model with state slots ("mamba" and "kda" layers): its scan and its
+    chunkwise delta rule start from an empty state and conv tail at row 0
+    only. And a model without experts: a row costs the device the same in
+    any program, so a pack saves the rows it does not compute and nothing
+    else — within the noise where it was read (plain +0.9%, looped +0.6%) —
+    while a prompt ALONE pays the pack's gather, floor and wider table (+6.8%
+    on ``gpt2m-longprompt-open``'s median latency: PERF.md section 6, PR
+    51). With experts a pack also reads every held expert's weights once
+    and not once a prompt (``gmm`` -43% in dots.vlm1's prefill)."""
+    return bool(cfg.layers_of("mamba", "kda")) or not cfg.num_experts
+
+
+def pack_width(cfg, rows):
+    """P, the prompts the prefill program of ``rows`` rows takes end to end:
+    a function of the program's shape alone, so a rung stays ONE executable;
+    1 for a model that is not packed (:func:`_one_prompt`)."""
+    if _one_prompt(cfg):
+        return 1
+    return min(rows // PACK_ALIGN, PACK_MAX)
+
+
+def pack_blocks(cfg, rows, block_size):
+    """The width of that program's block table: every prompt's rows are
+    rounded up to whole blocks in the CACHE (not along the program's rows),
+    so P prompts in ``rows`` rows take up to ``rows // block_size + P - 1``."""
+    return rows // block_size + pack_width(cfg, rows) - 1
+
+
+def pack_of(cfg, rows, spans):
+    """What the prefill program of ``rows`` rows is told of the prompts at
+    ``spans``, ``[(first row, length), ...]`` in row order (its ``length``
+    argument, host side): a model that takes one prompt a program gets the
+    prompt's length, () int32 — the argument it always had; every other the
+    pack, (2, P) int32 ``[starts, lengths]``, its unused entries empty at
+    ``rows``. A pack of one is the same executable as a pack of P."""
+    if _one_prompt(cfg):
+        (_start, length), = spans
+        return np.int32(length)
+    pack = np.zeros((2, pack_width(cfg, rows)), np.int32)
+    pack[0] = rows
+    pack[:, :len(spans)] = np.asarray(spans, np.int32).reshape(-1, 2).T
+    return pack
+
+
+def _pack_rows(length, S, bs):
+    """What a prefill program of ``S`` rows knows of each row, from what it
+    is told of its prompts: ``(positions (1, S), its table-lookup form or
+    None, first_key (S,) or None, cached, valid, last)`` — the last two made
+    when CALLED, where the program uses them: ``valid()`` (1, S) bool, the
+    rows that hold a prompt's token, and ``last()``, the prompts' last rows.
+    ``cached(t, axis=0)`` puts a layer's K or V rows (S of them along
+    ``axis``) in the order of the block table's slots.
+
+    ``length`` () int32 is ONE prompt from row 0 — rows 0..S-1 are its
+    positions, no floor, its rows the table's slots as they stand,
+    ``last()`` () its last token's row: the program there always was,
+    operation for operation. ``length`` (2, P) int32 is a PACK, ``[starts,
+    lengths]``: prompt p holds rows ``starts[p] .. starts[p] + lengths[p] -
+    1``, the starts ascending; an unused entry has length 0 and starts at
+    ``S``. A row belongs to the last prompt that starts at or before it
+    (the gap up to the next start computes garbage, as a padded tail does):
+    its position counts from that start, which is also the first key it may
+    see; ``last()`` is (P,). In the CACHE a prompt starts on a block
+    boundary, behind the blocks of the prompts before it: slot c of the
+    table's ``S // bs + P - 1`` blocks of ``bs`` slots takes the row of its
+    prompt's token c counts to (a slot past the prompt's end: any row, as a
+    padded tail's)."""
+    import jax.numpy as jnp
+
+    rows = jnp.arange(S, dtype=jnp.int32)
+    if jnp.ndim(length) == 0:
+        positions = rows[None]
+        return (positions, None, None, lambda t, axis=0: t,
+                lambda: positions < length, lambda: length - 1)
+    starts, lengths = length[0], length[1]
+    prompt = jnp.sum(rows[:, None] >= starts[None], axis=1) - 1
+    first_key = jnp.take(starts, prompt)
+    positions = (rows - first_key)[None]
+    # a prompt's first block in the table: the blocks of those before it
+    blocks = -(-lengths // bs)
+    first_block = jnp.cumsum(blocks) - blocks
+    slots = jnp.arange((S // bs + lengths.shape[0] - 1) * bs, dtype=jnp.int32)
+    owner = jnp.sum(slots[:, None] // bs >= first_block[None], axis=1) - 1
+    slot_rows = jnp.minimum(
+        jnp.take(starts, owner) + slots - jnp.take(first_block, owner) * bs,
+        S - 1)
+    return (positions, positions, first_key,
+            lambda t, axis=0: jnp.take(t, slot_rows, axis=axis),
+            lambda: positions < jnp.take(lengths, prompt)[None],
+            lambda: jnp.clip(starts + lengths - 1, 0, S - 1))
+
+
+def _last_rows(x, last):
+    """The final-norm'd rows ``last()`` of ``x`` (1, S, M) for the head:
+    ``(P, M)``, a lone prompt's ``(1, M)``."""
+    import jax.numpy as jnp
+
+    h_last = jnp.take(x[0], last(), axis=0)
+    return h_last if h_last.ndim == 2 else h_last[None]
+
+
 def prefill(params, tokens, length, block_table, k_pages, v_pages, cfg,
             aux=None):
-    """Full-sequence prefill for ONE request at a padded bucket length.
+    """Full-sequence prefill at a padded bucket length, for ONE request or
+    for a PACK of them laid end to end along the rows (each from a row of
+    :data:`PACK_ALIGN`'s multiple) and attending to itself alone.
 
     tokens:      (1, S) int32, S a bucket multiple of the pool block size
-                 (prompt left-aligned, tail padded with 0s)
-    length:      () int32 — true prompt length (1 <= length <= S)
+                 (a prompt left-aligned at its start, gaps and the tail
+                 padded with 0s)
+    length:      () int32 — the one prompt's true length (1 <= length <=
+                 S), or (2, P) int32 — a pack's ``[starts, lengths]``
+                 (:func:`_pack_rows`; P = :func:`pack_width`)
     block_table: (S // block_size,) int32 — the request's allocated blocks
-                 in position order; tail entries past the prompt = 0 (trash)
+                 in position order, entries past the prompt = 0 (trash); a
+                 pack's (:func:`pack_blocks` entries): the prompts' tables
+                 one after another, each of whole blocks, then 0s
     k/v_pages:   the pool pages, (L, N, bs, G, W) — donated by the engine
 
-    Returns ``(next_token (1,) int32, logits (1, V), k_pages, v_pages)``
-    — and, for a config with experts, a fifth result: the per-layer
-    ``tokens_per_expert`` (L, E) int32 of the ``length`` live tokens.
-    Every layer's K/V for positions < S is scattered into the pool through
-    the table, and the greedy next token sampled at position
-    ``length - 1``. Attention is the training block's
-    ``flash_attention(causal=True)`` — padded tail rows compute garbage
-    but cannot reach rows < length (causal mask) and their cache writes
-    land in trash-table blocks.
+    Returns ``(next_token (P,) int32, logits (P, V), k_pages, v_pages)``
+    (P = 1 for the one prompt) — and, for a config with experts, a fifth
+    result: the per-layer ``tokens_per_expert`` (L, E) int32 of the live
+    tokens. Every layer's K/V for rows < S is scattered into the pool
+    through the table, and the greedy next token sampled at each prompt's
+    last row. Attention is the training block's
+    ``flash_attention(causal=True)``, with a floor a row for a pack
+    (``first_key``: a prompt's rows see no earlier prompt's) — padded rows
+    compute garbage but cannot reach a prompt's own rows (causal mask) and
+    their cache writes land in trash-table blocks.
 
     A looped model (``loop_steps`` = R) takes pages of R parts,
     ``(L, R x N, bs, G, W)``, and writes layer i's K/V of pass r into blocks
@@ -1465,18 +1588,22 @@ def prefill(params, tokens, length, block_table, k_pages, v_pages, cfg,
     hh, hd = cfg.num_heads, cfg.head_dim
     bs, rows, lanes = k_pages.shape[2:]
     prec = fp32_precision(k_pages.dtype)
-    positions = jnp.arange(S, dtype=jnp.int32)[None]
+    positions, table_pos, first_key, cached, valid, last = _pack_rows(
+        length, S, bs)
 
     def split_heads(t):
         return t.reshape(1, S, hh, hd).transpose(0, 2, 1, 3)   # (1, H, S, hd)
 
     def flash(q, k, v):
         attn = flash_attention(split_heads(q), split_heads(k),
-                               split_heads(v), True)
+                               split_heads(v), True, first_key=first_key)
         return attn.transpose(0, 2, 1, 3).reshape(1, S, hh * hd)
 
     def attend(i, q, k, v, kv):
-        return flash(q, k, v), (kv[0] + (k,), kv[1] + (v,))
+        # kept in the table's order layer by layer: no second copy of
+        # every layer's K/V stands beside the stack
+        return flash(q, k, v), (kv[0] + (cached(k, 1),),
+                                kv[1] + (cached(v, 1),))
 
     def attend_pass(i, q, k, v, pages, r):
         """A looped model's: layer i's K/V of pass r go into that pass's
@@ -1484,29 +1611,26 @@ def prefill(params, tokens, length, block_table, k_pages, v_pages, cfg,
         every pass's, is never held beside the pool)."""
         table = block_table + r * (k_pages.shape[1] // cfg.loop_steps)
         return flash(q, k, v), tuple(
-            _put_blocks(pg, i, table, t.reshape(S // bs, bs, rows, lanes))
+            _put_blocks(pg, i, table,
+                        cached(t, 1).reshape(-1, bs, rows, lanes))
             for pg, t in zip(pages, (k, v)))
 
-    x = _embed(params, tokens, None, cfg)                      # (1, S, M)
+    x = _embed(params, tokens, table_pos, cfg)                 # (1, S, M)
     if cfg.loop_steps > 1:
         x, (k_pages, v_pages), loads = _layers(
-            x, params, cfg, prec, positions, positions < length,
-            attend_pass, (k_pages, v_pages))
+            x, params, cfg, prec, positions, valid(), attend_pass,
+            (k_pages, v_pages))
     else:
         x, (k_all, v_all), loads = _layers(
-            x, params, cfg, prec, positions, positions < length, attend,
-            ((), ()))
+            x, params, cfg, prec, positions, valid(), attend, ((), ()))
         # scatter every layer's K/V through the block table (trash entries
         # absorb the padded tail)
-        kw = jnp.stack(k_all).reshape(cfg.num_layers, S // bs, bs, rows,
-                                      lanes)
-        vw = jnp.stack(v_all).reshape(cfg.num_layers, S // bs, bs, rows,
-                                      lanes)
+        kw = jnp.stack(k_all).reshape(cfg.num_layers, -1, bs, rows, lanes)
+        vw = jnp.stack(v_all).reshape(cfg.num_layers, -1, bs, rows, lanes)
         k_pages = k_pages.at[:, block_table].set(kw.astype(k_pages.dtype))
         v_pages = v_pages.at[:, block_table].set(vw.astype(v_pages.dtype))
 
-    h_last = jnp.take(x[0], length - 1, axis=0)                # (M,)
-    logits = _head(h_last[None], params, cfg, prec)            # (1, V)
+    logits = _head(_last_rows(x, last), params, cfg, prec)     # (P, V)
     next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return (next_token, logits, k_pages, v_pages) + loads
 
@@ -1547,7 +1671,9 @@ def _prefill_hybrid(params, tokens, length, block_table, k_pages, v_pages,
     through ``wtable``, "full" layers into the full pool); "cross" layers
     read the last "full" layer's K and V of this same program; a "mamba"
     layer scans from a zero state (a prefill always starts a stream) and
-    leaves its final state and conv tail in the stream's ``slot``."""
+    leaves its final state and conv tail in the stream's ``slot`` — which
+    is why a model with such layers is handed one prompt, ``length`` ()
+    (:func:`pack_width`)."""
     import jax
     import jax.numpy as jnp
 
@@ -1557,7 +1683,8 @@ def _prefill_hybrid(params, tokens, length, block_table, k_pages, v_pages,
     g, w = full.k_rows
     rq = cfg.num_heads // g             # query heads reading one K/V row
     prec = fp32_precision(k_pages.dtype)
-    positions = jnp.arange(S, dtype=jnp.int32)[None]
+    positions, table_pos, first_key, cached, valid, last = _pack_rows(
+        length, S, bs)
     sm_scale = 1.0 / float(np.sqrt(cfg.head_dim))
     full_at, win_at, ssm_at = _cache_layers(cfg)
     wtable, slot = aux["wtable"], aux["slot"]
@@ -1566,7 +1693,7 @@ def _prefill_hybrid(params, tokens, length, block_table, k_pages, v_pages,
     def put(pages, li, table, t, rows=full.k_rows):
         """One layer's K or V of the S tokens into its blocks."""
         return _put_blocks(pages, li, table,
-                           _block_rows(t, bs, rows, full.head_major))
+                           _block_rows(cached(t), bs, rows, full.head_major))
 
     def attend_mla(i, q, c, kr, st):
         """Cache the latent and the rotated key; attend over the prompt's
@@ -1587,7 +1714,8 @@ def _prefill_hybrid(params, tokens, length, block_table, k_pages, v_pages,
         att = flash_attention(qs.transpose(1, 0, 2)[None],
                               keys.transpose(1, 0, 2)[None],
                               kv[..., cfg.head_dim:].transpose(1, 0, 2)[None],
-                              True, mla_sm_scale(cfg))  # (1, H, S, dv)
+                              True, mla_sm_scale(cfg),
+                              first_key=first_key)      # (1, H, S, dv)
         return att[0].transpose(1, 0, 2).reshape(1, S, hh * dv), st
 
     def attend_gqa(i, q, k, v, st):
@@ -1606,7 +1734,16 @@ def _prefill_hybrid(params, tokens, length, block_table, k_pages, v_pages,
         att = flash_attention_gqa(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
             v.transpose(0, 2, 1, 3), sm_scale,
-            cfg.window if kind == "swa" else None, _sink(params, i, cfg))
+            cfg.window if kind == "swa" else None, _sink(params, i, cfg),
+            first_key=first_key)
+        if first_key is not None:
+            # a pack's cache writes (a gather, then the scatter) are put
+            # off by the compiler to the program's end, every layer's K
+            # and V alive till then (0.37 GB in MiMo's top rung, compiled
+            # for a v5e): they are done before the layer goes on
+            att, written = jax.lax.optimization_barrier(
+                (att, (st[kp], st[vp])))
+            st = dict(st, **dict(zip((kp, vp), written)))
         return att.transpose(0, 2, 1, 3), st            # (1, S, H, dv)
 
     def attend(i, q, k, v, st):
@@ -1631,7 +1768,8 @@ def _prefill_hybrid(params, tokens, length, block_table, k_pages, v_pages,
         kr, vr = (jnp.repeat(t[0].reshape(S, g, w).transpose(1, 0, 2), rq,
                              axis=0) for t in (k, v))
         att = flash_attention(qr[None], kr[None], vr[None], True, sm_scale,
-                              256, cfg.window if kind == "swa" else None)
+                              256, cfg.window if kind == "swa" else None,
+                              first_key)
         return (att[0].transpose(1, 0, 2).reshape(
             1, S, cfg.num_heads // 2, 2, w), st)
 
@@ -1670,13 +1808,12 @@ def _prefill_hybrid(params, tokens, length, block_table, k_pages, v_pages,
                   ssm=st["ssm"].at[li, slot].set(h))
         return y[None], out[None], st
 
-    x = _embed(params, tokens, None, cfg)                      # (1, S, M)
+    x = _embed(params, tokens, table_pos, cfg)                 # (1, S, M)
     state = {"k": k_pages, "v": v_pages, "wk": aux["wk"], "wv": aux["wv"],
              "conv": aux["conv"], "ssm": aux["ssm"]}
-    x, state, loads = _layers(x, params, cfg, prec, positions,
-                              positions < length, attend, state, recur)
-    h_last = jnp.take(x[0], length - 1, axis=0)                # (M,)
-    logits = _head(h_last[None], params, cfg, prec)            # (1, V)
+    x, state, loads = _layers(x, params, cfg, prec, positions, valid(),
+                              attend, state, recur)
+    logits = _head(_last_rows(x, last), params, cfg, prec)     # (P, V)
     next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return (next_token, logits, state["k"], state["v"],
             {k: state[k] for k in ("wk", "wv", "conv", "ssm")}) + loads

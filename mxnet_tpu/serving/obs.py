@@ -109,7 +109,7 @@ class LoopRecord(namedtuple("LoopRecord", (
         "gap_chunk_s", "gap_group_s", "finished",
         "deferred_s", "deferred_hidden", "passes",
         "full_ctx_tokens", "window_ctx_tokens", "prefill_tokens",
-        "prefill_rows"))):
+        "prefill_rows", "prefill_programs"))):
     """What the engine loop did in ONE non-empty step, on the host's clock
     (docs/observability.md has the table of fields, where each is measured
     and the benchmark metric that reads it).

@@ -793,6 +793,10 @@ METRIC_HELP = {
         "fetched after the last dispatch); one observation a step that "
         "admitted any",
     "serving.prefill.groups": "groups of prefills run",
+    "serving.prefill.pack":
+        "prompts in one prefill program: a group's prompts are laid end to "
+        "end into as few programs of the ladder as hold them; one "
+        "observation a program",
     "serving.prefill.syncs_saved":
         "prefill fetches that exposed no host gap of their own: a group's "
         "prompts less one, summed",

@@ -208,8 +208,12 @@ def _serving_program(chip, name, page, arch="gpt2"):
         args = (s((32,)), s((32,)), s((32, cfg.max_len // bs)), s((32,)),
                 s((32,)), s((32,)), s(()))
     else:
+        # the program the engine runs: a pack's [starts, lengths] for the
+        # model with experts, one prompt's bare length for the two without
         fn, donate = M.prefill, (4, 5)
-        args = (s((1, 512)), s(()), s((512 // bs,)))
+        width = M.pack_width(cfg, 512)
+        args = (s((1, 512)), s((2, width) if width > 1 else ()),
+                s((M.pack_blocks(cfg, 512, bs),)))
     jitted = jax.jit(functools.partial(fn, cfg=cfg), donate_argnums=donate)
     return (jitted.lower(params, *args, pool, pool).compile(), pool.shape,
             jnp.dtype(dtype).itemsize)
@@ -447,8 +451,9 @@ def _latent_program(chip, name):
         def fn(params, toks, n, table, kp, vp, wt, slot, *arrays):
             return M.prefill(params, toks, n, table, kp, vp, cfg,
                              dict(zip(aux, arrays), wtable=wt, slot=slot))
-        args = (s((1, 2048)), s(()), s((2048 // bs,)))
-        more, donate = (s((2048 // bs,)), s(())), (4, 5)
+        args = (s((1, 2048)), s((2, M.pack_width(cfg, 2048))),
+                s((M.pack_blocks(cfg, 2048, bs),)))
+        more, donate = (s((M.pack_blocks(cfg, 2048, bs),)), s(())), (4, 5)
     compiled = jax.jit(fn, donate_argnums=donate).lower(
         params, *args, pages["k"], pages["v"], *more, *stand_ins).compile()
     return compiled, {k: v.shape for k, v in pages.items()}
@@ -540,8 +545,10 @@ def _gqa_program(chip, name, kinds=_MIMO_KINDS, batch=64, prompt=2048,
         def fn(params, toks, n, table, kp, vp, wt, slot, *arrays):
             return M.prefill(params, toks, n, table, kp, vp, cfg,
                              dict(zip(aux, arrays), wtable=wt, slot=slot))
-        args = (s((1, prompt)), s(()), s((prompt // bs,)))
-        more, donate = (s((prompt // bs,)), s(())), (4, 5, 8, 9)
+        args = (s((1, prompt)), s((2, M.pack_width(cfg, prompt))),
+                s((M.pack_blocks(cfg, prompt, bs),)))
+        more, donate = ((s((M.pack_blocks(cfg, prompt, bs),)), s(())),
+                        (4, 5, 8, 9))
     compiled = jax.jit(fn, donate_argnums=donate).lower(
         params, *args, pages["k"], pages["v"], *more, pages["wk"],
         pages["wv"], *stand_ins).compile()
@@ -575,8 +582,12 @@ def test_gqa_programs_leave_both_pools_in_place(v5e, name):
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes >= 2 * sum(
         math.prod(sh) for sh in shapes.values())
-    assert ma.temp_size_in_bytes < (1536 << 20 if name == "prefill"
-                                    else 96 << 20)
+    # the pack's program at 2,048 rows: 557 MiB with each layer's cache
+    # writes done before the layer goes on (`attend_gqa`'s barrier), 643 MiB
+    # where the compiler puts them off to the program's end (0.37 GB in the
+    # top rung: PERF.md section 6, PR 51); a prompt's bare-length form 608
+    assert ma.temp_size_in_bytes < (600 << 20 if name == "prefill"
+                                    else 96 << 20), ma.temp_size_in_bytes
 
 
 def _linear_program(chip, name, batch=96, prompt=4096, blocks=4097,
@@ -696,6 +707,33 @@ def test_flash_forward_with_two_widths_compiles_for_v5e(v5e):
         functools.partial(A._pallas_forward, causal=True, sm_scale=0.135),
         s(192), s(192), s(128))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("heads,kv_heads,window", [
+    (128, 128, None),       # dots.vlm1: the expanded heads of latent attention
+    (64, 4, None),          # MiMo-V2.5's "full" layers
+    (64, 8, 128),           # and its "swa" layers: the floor beside the band
+], ids=["dotsvlm1", "mimo_full", "mimo_swa"])
+def test_flash_forward_with_a_floor_compiles_for_v5e(v5e, heads, kv_heads,
+                                                     window):
+    """A packed prefill's flash forward — a floor a query row
+    (``first_key``), the q blocks' first floors prefetched as scalars, the
+    K/V index map clamped to the first block that is read — at the two
+    configurations' head widths (keys 192, values 128) in their top rungs'
+    neighbourhood: interpret mode cannot see what Mosaic refuses."""
+    def s(h, d):
+        return jax.ShapeDtypeStruct((1, h, 3072, d), jnp.bfloat16,
+                                    sharding=v5e)
+
+    how = {} if heads == kv_heads else {"kv_group": heads // kv_heads,
+                                        "name": "flash_gqa_fwd"}
+    text = _compiled_text(
+        lambda q, k, v, floor: A._pallas_forward(
+            q, k, v, True, 0.072, window=window, first_key=floor, **how),
+        s(heads, 192), s(kv_heads, 192), s(kv_heads, 128),
+        jax.ShapeDtypeStruct((3072,), jnp.int32, sharding=v5e))
+    assert "tpu_custom_call" in text
+    assert ("flash_gqa_fwd" in text) == bool(how)
 
 
 def test_the_benchmarks_warm_up_call_warms_the_chunk_program():

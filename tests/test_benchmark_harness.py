@@ -16,7 +16,8 @@ pytest.register_assert_rewrite("benchmark.tests.test_benchmark_harness",
                                "benchmark.tests.test_looped_metrics",
                                "benchmark.tests.test_hybrid_metrics",
                                "benchmark.tests.test_prefill_padded_share",
-                               "benchmark.tests.test_linear_metrics")
+                               "benchmark.tests.test_linear_metrics",
+                               "benchmark.tests.test_prefill_prompts_per_program")
 
 from benchmark.tests.test_benchmark_harness import *  # noqa: E402,F401,F403
 from benchmark.tests.test_pool_copy_share import *  # noqa: E402,F401,F403
@@ -35,6 +36,7 @@ from benchmark.tests.test_prefill_padded_share import padded_records  # noqa: E4
 from benchmark.tests import test_prefill_padded_share as _padded_tests  # noqa: E402
 from benchmark.tests.test_linear_metrics import linear_records  # noqa: E402,F401
 from benchmark.tests import test_linear_metrics as _linear_tests  # noqa: E402
+from benchmark.tests.test_prefill_prompts_per_program import *  # noqa: E402,F401,F403
 
 # test_moe_metrics, test_ssm_metrics, test_latent_metrics,
 # test_looped_metrics, test_hybrid_metrics and test_linear_metrics each have a
@@ -54,12 +56,13 @@ for _prefix, _module in (("ssm", _ssm_tests), ("latent", _latent_tests),
 
 
 #: per-layer metrics that a later PR appended over cells that were there
-#: (PR 46: ``prefill_padded_share``, the six cells of ``serve_out_tok_per_s``).
+#: (PR 46: ``prefill_padded_share``, the six cells of ``serve_out_tok_per_s``;
+#: PR 51: ``prefill_prompts_per_program``, the seven).
 #: Ouro's case counts the metrics its cell shares with the chat cell (21
 #: when it was written), so the cells' cases run over the manifest less
 #: these; where each stands is its own file's case
 #: (``benchmark/tests/test_prefill_padded_share.py``).
-_APPENDED_SINCE = ("prefill_padded_share",)
+_APPENDED_SINCE = ("prefill_padded_share", "prefill_prompts_per_program")
 
 
 def _manifest_case_of(monkeypatch, module, cell,

@@ -112,8 +112,11 @@ class Capture:
 
         def _prefill_fn(params, toks, length, *rest):
             out = prefill_fn(params, toks, length, *rest)
-            self.rows.setdefault(now["req"].rid, []).append(
-                (int(length), np.asarray(out[1], np.float32)[0]))
+            logits = np.asarray(out[1], np.float32)
+            # a row of logits a prompt of the pack, in the pack's order
+            for i, (req, replay) in enumerate(now["pack"]):
+                self.rows.setdefault(req.rid, []).append(
+                    (len(replay), logits[i]))
             return out
 
         def _decode_fn(params, toks, poss, tables, ctx, *rest, **chunk):
@@ -123,9 +126,9 @@ class Capture:
                 self.rows[req.rid].append((int(ctx[i]), logits[i]))
             return out
 
-        def _start_prefill(req, grouped):
-            now["req"] = req
-            return start_prefill(req, grouped)
+        def _start_prefill(pack, rung, grouped):
+            now["pack"] = pack
+            return start_prefill(pack, rung, grouped)
 
         def _run_decode(reqs):
             now["reqs"] = list(reqs)

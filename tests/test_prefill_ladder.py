@@ -166,5 +166,7 @@ def test_a_prompt_of_1100_tokens_runs_the_1536_program():
     eng.generate([prompt[:70], prompt[:1025], prompt + prompt[:500]],
                  [2, 2, 2])
     assert _prefill_compiles() == c0 + len(scfg.prefill_buckets())
+    # 70 and 1,025 tokens share the 1,536 program (one step's prompts are
+    # packed where that computes fewer rows: 128 + 1,536 alone)
     assert eng.stats()["loop"]["sums"]["prefill_rows"] \
-        == 1536 + 128 + 1536 + 2048
+        == 1536 + 1536 + 2048

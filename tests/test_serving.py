@@ -1033,9 +1033,9 @@ def test_a_dispatch_error_inside_a_group_fails_the_whole_group():
     reqs = [eng.submit(list(range(1, 5 + i)), 4) for i in range(3)]
     started, start = [], eng._start_prefill
 
-    def spy(req, grouped):
-        started.append(req)
-        return start(req, grouped)
+    def spy(pack, rung, grouped):
+        started.extend(req for req, _replay in pack)
+        return start(pack, rung, grouped)
 
     eng._start_prefill = spy
     with fault.inject("dispatch_error:raise=1,after=1,times=1"):
@@ -1061,12 +1061,15 @@ def test_prefill_group_stats_by_hand():
     s0 = telemetry.counter("serving.prefill.syncs_saved").value
     assert eng.stats()["prefill"] == {
         "prompts": 0, "groups": 0, "prompts_per_group": 0.0,
+        "programs": 0, "prompts_per_program": 0.0,
         "syncs_saved": 0, "stopped_by": _stops()}
     eng.generate([[1 + i, 2, 3] for i in range(5)], 3)
     # the count of four ended the first pass, the queue's end the second;
     # a pass that finds nothing waiting is no pass
     assert eng.stats()["prefill"] == {
         "prompts": 5, "groups": 2, "prompts_per_group": 2.5,
+        # a model without experts: each prompt in its own program
+        "programs": 5, "prompts_per_program": 1.0,
         "syncs_saved": 3, "stopped_by": _stops(cap=1, queue=1)}
     g1 = telemetry.totals("serving.prefill.group")
     assert (g1[0] - g0[0], g1[1] - g0[1]) == (2, 5.0)
@@ -1090,9 +1093,9 @@ def test_a_cold_buckets_compile_stall_is_its_own_requests_alone():
     walls = []
     start = eng._start_prefill
 
-    def timed(req, grouped):
+    def timed(pack, rung, grouped):
         t0 = time.time()
-        out = start(req, grouped)
+        out = start(pack, rung, grouped)
         walls.append(time.time() - t0)
         return out
 

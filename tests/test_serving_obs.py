@@ -426,7 +426,7 @@ def test_one_record_a_non_empty_step_with_every_field(ticks):
     before = time.time()
     rec, wall, progs, fetched = _step_logged(eng, clock, fetches, calls)
     assert len(ring) == 1 and ring[0] is rec
-    assert rec._fields == LoopRecord._fields and len(rec) == 32
+    assert rec._fields == LoopRecord._fields and len(rec) == 33
     # the ring's tuple is made from the dict's values as they stand
     assert tuple(open_record()) == LoopRecord._fields
     assert before <= rec.ts <= time.time() and rec.step == eng._steps == 1

@@ -132,10 +132,10 @@ def test_deadlines_hold_when_one_steps_group_lasts_long():
     eng = ServingEngine(_config(prefills_per_step=None), seed=SEED)
     dispatch, prefilled = eng._dispatch_prefill, []
 
-    def slow(toks, length, *rest):
-        prefilled.append(int(length))
+    def slow(toks, spans, *rest):
+        prefilled.extend(n for _row, n in spans)
         time.sleep(0.03)
-        return dispatch(toks, length, *rest)
+        return dispatch(toks, spans, *rest)
 
     eng._dispatch_prefill = slow
     live = [eng.submit([1, 2, 3], 12) for _ in range(7)]

@@ -222,6 +222,7 @@ def test_arguments_are_readable_from_the_events_stats(serving_trace):
         assert sorted(st["request_id"] for st in pre) == ["r0", "r1", "r2"]
         assert sorted(st["prompt_len"] for st in pre) == [5, 6, 7]
         assert {st["bucket"] for st in pre} == {8}
+        assert {st["prompts"] for st in pre} == {1}     # no experts: alone
         dec = by_name["serving.decode." + part]
         assert {st["batch"] for st in dec} == {3} \
             and {st["bucket"] for st in dec} == {4}
@@ -297,7 +298,7 @@ def test_a_groups_dispatches_precede_its_fetches(serving_trace):
                              if n == "serving.prefill.fetch")
     assert serving_trace["stats"]["prefill"] == {
         "prompts": 3, "groups": 1, "prompts_per_group": 3.0,
-        "syncs_saved": 2,
+        "programs": 3, "prompts_per_program": 1.0, "syncs_saved": 2,
         "stopped_by": {"lanes": 0, "pool": 0, "slots": 0, "cap": 0,
                        "preempted": 0, "queue": 1}}
 

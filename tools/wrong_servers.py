@@ -1,8 +1,9 @@
 #!/usr/bin/env python3
 """Wrong servers for a latent-attention, shared-expert configuration (and,
 through ``--cell``, for a stack that runs several times, for window and
-full layers of plain grouped-query attention over two pools, and for gated
-delta-rule linear layers beside gated attention): plant ONE fault in
+full layers of plain grouped-query attention over two pools, for gated
+delta-rule linear layers beside gated attention, and for the prefill
+program that takes several prompts end to end): plant ONE fault in
 the served program (or its weights), run the configuration's own dense
 probe over it, print what ``correct`` would compare.
 
@@ -60,7 +61,10 @@ PROGRAM = ("no_group_limit", "no_renorm", "no_scale", "no_mscale",
            # (solaropen2-reason-closed192)
            "beta_not_doubled", "qk_not_normalised", "conv_tail_not_carried",
            "chunk_state_not_handed", "state_not_written", "no_gqa_gate",
-           "bf16_state")
+           "bf16_state",
+           # of a packed prefill (every cell whose model packs): the floor
+           # dropped, so a prompt sees the prompts laid before it
+           "pack_sees_neighbours")
 
 
 def load_config(path):
@@ -317,6 +321,15 @@ def planted(name, model):
             setattr(self.state, lost, kept)
             return out
         patch(ServingEngine, fn, dispatch)
+    elif name == "pack_sees_neighbours":
+        # a pack's rows keep their positions and their prompts' lengths;
+        # the attention loses the floor, and is causal over the whole pack
+        sound = M._pack_rows
+
+        def rows(length, S, bs):
+            positions, table_pos, _first_key, *rest = sound(length, S, bs)
+            return (positions, table_pos, None) + tuple(rest)
+        patch(M, "_pack_rows", rows)
     elif name not in WEIGHTS:
         raise ValueError("no fault %r (weights: %s; program: %s)"
                          % (name, WEIGHTS, PROGRAM))
